@@ -3,7 +3,7 @@
 
 PY ?= python
 
-.PHONY: check check-quick test bench dryrun lint manifests chaos structured slo device-obs kvplane decisions durable perf-regress util moe pd
+.PHONY: check check-quick test bench dryrun lint manifests chaos structured slo device-obs kvplane decisions durable util moe pd
 
 # full gate: lint + manifests + suite + tiny bench + 8-device dryrun
 check:
@@ -75,12 +75,6 @@ util:
 # on capacity-starved einsum, counter == engine ledger
 moe:
 	JAX_PLATFORMS=cpu $(PY) tools/moe_check.py
-
-# perf contract: pinned campaign point vs pinned BENCH baseline under
-# per-metric tolerances (tools/perf_regress.py --run gates a fresh bench)
-perf-regress:
-	$(PY) tools/perf_regress.py --candidate BENCH_CAMPAIGN_r05.json \
-		--baseline BENCH_r05.json
 
 dryrun:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \

@@ -10,16 +10,28 @@ from __future__ import annotations
 import asyncio
 import threading
 import time
-from typing import AsyncIterator, Optional
+import traceback
+from typing import AsyncIterator, Callable, Optional
 
 from llmd_tpu.core.request import SamplingParams
 from llmd_tpu.engine.engine import EngineOutput, LLMEngine
+
+
+class EngineDeadError(RuntimeError):
+    """The step loop died on an exception; no request can complete."""
 
 
 class AsyncLLMEngine:
     def __init__(self, engine: LLMEngine, idle_sleep_s: float = 0.002) -> None:
         self.engine = engine
         self._idle_sleep = idle_sleep_s
+        # set once by the engine thread when step() raises (a Mosaic refusal
+        # at the first real step, a device fault): every open stream fails
+        # with it, new requests are refused, /health goes 503, and
+        # ``on_fatal`` lets the owning process exit non-zero instead of
+        # serving 200s in front of a dead loop
+        self.fatal: Optional[BaseException] = None
+        self.on_fatal: Optional[Callable[[BaseException], None]] = None
         self._lock = threading.Lock()
         # request_id -> (caller loop, stream queue); written from caller
         # event loops, drained/popped from the engine thread.
@@ -48,9 +60,14 @@ class AsyncLLMEngine:
             mon = getattr(self.engine, "monitor", None)
             if mon is not None:
                 mon.heartbeat()
-            with self._lock:
-                has_work = self.engine.has_work()
-                outputs = self.engine.step() if has_work else []
+            try:
+                with self._lock:
+                    has_work = self.engine.has_work()
+                    outputs = self.engine.step() if has_work else []
+            except Exception as e:  # boundary: the loop cannot continue
+                traceback.print_exc()
+                self._die(e)
+                return
             for out in outputs:
                 with self._lock:
                     entry = self._streams.get(out.request_id)
@@ -62,6 +79,17 @@ class AsyncLLMEngine:
                 loop.call_soon_threadsafe(q.put_nowait, out)
             if not has_work:
                 time.sleep(self._idle_sleep)
+
+    def _die(self, exc: BaseException) -> None:
+        with self._lock:
+            self.fatal = exc
+            streams, self._streams = self._streams, {}
+        dead = EngineDeadError(f"engine step loop died: "
+                               f"{type(exc).__name__}: {exc}")
+        for loop, q in streams.values():
+            loop.call_soon_threadsafe(q.put_nowait, dead)
+        if self.on_fatal is not None:
+            self.on_fatal(exc)
 
     # -- API ---------------------------------------------------------------
     async def generate(
@@ -78,6 +106,10 @@ class AsyncLLMEngine:
         q: asyncio.Queue = asyncio.Queue()
         try:
             with self._lock:  # stream registration + admission are atomic
+                if self.fatal is not None:
+                    raise EngineDeadError(
+                        f"engine step loop died: {type(self.fatal).__name__}: "
+                        f"{self.fatal}")
                 self._streams[request_id] = (loop, q)
                 self.engine.add_request(request_id, token_ids, sampling, lora_id,
                                         rank=rank, mm_items=mm_items,
@@ -89,13 +121,16 @@ class AsyncLLMEngine:
         try:
             while True:
                 out: EngineOutput = await q.get()
+                if isinstance(out, EngineDeadError):
+                    raise out
                 yield out
                 if out.finished:
                     return
         finally:
             with self._lock:
                 self._streams.pop(request_id, None)
-            if request_id in self.engine.seqs:
+                dead = self.fatal is not None
+            if not dead and request_id in self.engine.seqs:
                 with self._lock:
                     self.engine.abort(request_id)
 

@@ -29,6 +29,7 @@ import asyncio
 import json
 import socket
 import time
+import traceback
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -262,8 +263,13 @@ class DPAsyncEngine(AsyncLLMEngine):
             if not step:
                 time.sleep(self._idle_sleep)
                 continue
-            with self._lock:
-                outputs = self.engine.step()
+            try:
+                with self._lock:
+                    outputs = self.engine.step()
+            except Exception as e:  # boundary: same contract as the base loop
+                traceback.print_exc()
+                self._die(e)
+                break
             self.steps += 1
             if not has_work:
                 # joined the wave with an empty batch: locally that's a no-op, so

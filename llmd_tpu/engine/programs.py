@@ -34,11 +34,11 @@ compile against — not engine state.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
 import jax
-import jax.numpy as jnp
 
 
 @dataclass
@@ -149,15 +149,14 @@ def select_decode_attn_impl(engine, unified_attn):
     decode kernel (`ops.mla_decode`): the fused-decode batch is exactly
     its shape — one query row per slot over the single-plane latent pool —
     while unified/verify/embed (mixed chunk shapes) keep the XLA absorbed
-    reference. On success ``attn_backend`` becomes
-    ``pallas_mla_latent_decode`` and ``attn_fallback_reason`` stays None.
+    reference, and ``attn_backend`` becomes ``pallas_mla_latent_decode``.
 
     `attn_impl` semantics on MLA: "auto" takes the kernel on TPU only
     (interpreter-mode Pallas is orders of magnitude slower than the XLA
     reference on CPU meshes); explicit "pallas" forces it anywhere —
-    interpret mode off-TPU — and raises on smoke-compile failure, the
-    same hard guarantee the explicit mode carries for GQA; "reference"
-    keeps the XLA impl everywhere.
+    interpret mode on CPU; "reference" keeps the XLA impl everywhere. The
+    choice is a rule on the platform: a kernel that fails to compile
+    raises at the first decode step, it is never swapped for another.
     """
     if not engine.model_cfg.is_mla:
         return unified_attn
@@ -168,26 +167,7 @@ def select_decode_attn_impl(engine, unified_attn):
         return unified_attn
     from llmd_tpu.ops.mla_decode import mla_paged_attention_latent
 
-    try:  # smoke-compile tiny decode shapes so a Mosaic failure can't strand serving
-        c = engine.model_cfg
-        dhp = engine.cache.shape[-1]  # padded latent width == pool lane width
-        ps = engine.cfg.page_size
-        q = jnp.zeros((1, c.num_heads, dhp), c.jax_dtype)
-        cache = jnp.zeros((2, ps, 1, dhp), engine.kv_dtype)
-        mla_paged_attention_latent(
-            q, cache, jnp.zeros((1, 2), jnp.int32),
-            jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-            jnp.ones((1,), jnp.int32),
-            scale=(c.mla_qk_nope_dim + c.mla_rope_dim) ** -0.5,
-            cu_q_lens=jnp.array([0, 1], jnp.int32),
-            num_seqs=jnp.array([1], jnp.int32),
-        ).block_until_ready()
-        engine.attn_backend = "pallas_mla_latent_decode"
-        engine.attn_fallback_reason = None
-        return mla_paged_attention_latent
-    except Exception as e:  # noqa: BLE001 — any Mosaic/XLA compile error
-        if mode == "pallas":
-            raise
-        engine.attn_fallback_reason = (
-            f"mla latent decode smoke-compile failed: {type(e).__name__}: {e}")
-        return unified_attn
+    engine.attn_backend = "pallas_mla_latent_decode"
+    return functools.partial(
+        mla_paged_attention_latent,
+        interpret=engine._pallas_interpret, mesh=engine.mesh)

@@ -1060,6 +1060,10 @@ class EngineServer:
             return web.json_response(
                 {"status": "draining", "inflight": len(self.engine.seqs)},
                 status=503)
+        if self.async_engine.fatal is not None:
+            return web.json_response(
+                {"status": "unhealthy", "reason": "engine_dead",
+                 "error": repr(self.async_engine.fatal)}, status=503)
         mon = getattr(self.engine, "monitor", None)
         reason = mon.unhealthy_reason() if mon is not None else None
         if reason is not None:

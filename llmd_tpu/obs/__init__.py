@@ -53,7 +53,7 @@ annotations so dashboards can jump from a latency bucket to the trace.
 See observability/flight-recorder.md.
 """
 
-from llmd_tpu.obs.device import DeviceMonitor, fabric_alive_subprocess
+from llmd_tpu.obs.device import DeviceMonitor
 from llmd_tpu.obs.events import (
     EVENT_CATALOG,
     FlightRecorder,
@@ -94,7 +94,6 @@ __all__ = [
     "TracingConfig",
     "escape_label_value",
     "extract_traceparent",
-    "fabric_alive_subprocess",
     "format_traceparent",
     "register_device_metrics",
     "register_engine_metrics",

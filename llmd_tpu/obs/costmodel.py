@@ -106,18 +106,17 @@ def _peaks_overlay() -> Dict[str, Tuple[float, float]]:
 
 def chip_peaks(
     device_kind: str,
-    default: Optional[Tuple[float, float]] = None,
 ) -> Tuple[Optional[float], Optional[float]]:
-    """(bf16 TFLOP/s, HBM GB/s) for a device kind, or ``default`` (None,
-    None) when the generation is unknown — CPU and new chips export null
-    peaks so MFU/MBU gauges go absent rather than lie. bench.py passes the
-    v5e-class default to keep its historical off-table behavior."""
+    """(bf16 TFLOP/s, HBM GB/s) for a device kind, or (None, None) when the
+    generation is not in the table — the CPU and unlisted chips export null
+    peaks so MFU/MBU gauges go absent rather than lie, and bench.py refuses
+    to run on them. There is no default peak."""
     table = _peaks_overlay()
     # longest-match first so "TPU v5 lite" wins over a hypothetical "TPU v5"
     for k in sorted(table, key=len, reverse=True):
         if k.lower() in (device_kind or "").lower():
             return table[k]
-    return default if default is not None else (None, None)
+    return None, None
 
 
 # ---------------------------------------------------------------------------
@@ -250,7 +249,7 @@ def dispatch_cost(cfg, *, slot_tokens: int, weight_passes: int = 1,
 
 
 # ---------------------------------------------------------------------------
-# Goodput taxonomy
+# Goodput kinds
 # ---------------------------------------------------------------------------
 
 GOODPUT_KINDS = ("committed", "spec_rejected", "padding",

@@ -2,9 +2,8 @@
 and on-demand profiler capture.
 
 The host plane (metrics registry, flight recorder, tracing) sees everything
-*around* the accelerator but nothing *inside* it: the int8-b128 fabric death
-(PERF.md Round 6) was diagnosed only after a 1500s bench timeout via an
-out-of-band bash poller, and the pool controller's ``/health`` sweep retires
+*around* the accelerator but nothing *inside* it: a device that stops
+answering mid-run shows up only as a client timeout, and the pool controller's ``/health`` sweep retires
 killed replicas but cannot see an engine whose asyncio loop is alive while
 its TPU is hung mid-step. ``DeviceMonitor`` closes that gap with four
 coordinated parts, all surfaced through the same metrics/events/health
@@ -44,52 +43,22 @@ flight emissions happen *outside* ``self._lock`` — the registry has its own
 lock and the scrape path reads our HBM cache through it, so nesting them
 would order registry-lock → monitor-lock against monitor-lock →
 registry-lock.
-
-``fabric_alive_subprocess`` is the out-of-process variant shared with
-``tools/r05_campaign.py``: backend init is process-fatal when the fabric is
-wedged, so post-timeout probes from a bench harness must fork.
 """
 
 from __future__ import annotations
 
 import os
-import subprocess
-import sys
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 from llmd_tpu.obs.metrics import Registry, register_device_metrics
 
-__all__ = ["DeviceMonitor", "ProfileBusy", "fabric_alive_subprocess",
-           "default_probe_op"]
+__all__ = ["DeviceMonitor", "ProfileBusy", "default_probe_op"]
 
 
 class ProfileBusy(RuntimeError):
     """A profiler capture is already in progress (one window at a time)."""
-
-
-def fabric_alive_subprocess(timeout_s: float = 90.0,
-                            platform: str = "tpu",
-                            cwd: Optional[str] = None) -> bool:
-    """Probe the accelerator fabric in a throwaway subprocess.
-
-    Backend init is process-fatal when the fabric is wedged, so a probe
-    issued *after* something already timed out cannot run in-process — the
-    serving/bench process would hang or die with it. Much cheaper than a
-    full preflight: backend init + device count, nothing else. Shared by
-    ``tools/r05_campaign.py`` (post-timeout fast-skip decision) and operator
-    runbooks so bench and serving agree on what "fabric dead" means.
-    """
-    cmd = [sys.executable, "-c",
-           f"import jax; print(len(jax.devices({platform!r})))"]
-    try:
-        p = subprocess.run(cmd, cwd=cwd, capture_output=True, text=True,
-                           timeout=timeout_s)
-    except (subprocess.TimeoutExpired, OSError):
-        return False
-    out = p.stdout.strip()
-    return p.returncode == 0 and out.isdigit() and int(out) > 0
 
 
 def default_probe_op() -> None:

@@ -44,11 +44,9 @@ def grouped_gemm(
     x: jax.Array,  # [G, C, D]
     w: jax.Array,  # [G, D, F]
     counts: jax.Array,  # [G] int32 — tokens routed to each group this step
-    interpret: bool | None = None,
+    interpret: bool = False,  # the selecting caller passes True on CPU only
 ) -> jax.Array:  # [G, C, F]
     """Per-group matmul with zero-token groups skipped on the MXU."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     G, C, D = x.shape
     _, _, F = w.shape
 
@@ -100,15 +98,13 @@ def ragged_grouped_gemm(
     w: jax.Array,  # [S, D, F] — expert slot bank
     block_slot: jax.Array,  # [nb] int32 — expert slot owning each block
     block_rows: jax.Array,  # [nb] int32 — real rows in each block
-    interpret: bool | None = None,
+    interpret: bool = False,  # the selecting caller passes True on CPU only
 ) -> jax.Array:  # [nb, bc, F]
     """Block-ragged grouped GEMM for the token-sorted dispatch path
     (ops/moe_dispatch): each [bc, D] block multiplies the weight of the
     slot it belongs to — the slot id rides in scalar prefetch so the
     weight DMA is indexed per block, and fully-padded blocks skip their
     MXU work just like zero-count groups in ``grouped_gemm``."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     nb, bc, D = x.shape
     _, _, F = w.shape
 
@@ -136,7 +132,7 @@ def ragged_grouped_gemm(
     return out[:, :, :F]
 
 
-def make_moe_matmul(interpret: bool | None = None):
+def make_moe_matmul(interpret: bool = False):
     """Adapter with the ``moe_block`` matmul_impl signature."""
     def impl(xe, we, slot_counts):
         return grouped_gemm(xe, we, slot_counts, interpret=interpret)

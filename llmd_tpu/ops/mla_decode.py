@@ -23,9 +23,9 @@ Why a bespoke kernel is *easier* here than for GQA:
 
 Softmax is the standard online (flash) recurrence over pages with VMEM
 scratch carrying (m, l, acc) per sequence; rows whose kv_len is 0 (idle
-decode slots) produce exact zeros. Off-TPU the kernel runs in interpreter
-mode so CPU-mesh tests, parity pins, and the `bench-tiny-attn` CI stage
-execute the same code path the TPU compiles.
+decode slots) produce exact zeros. ``interpret`` is the caller's choice:
+the engine passes True only when the platform is CPU, so CPU-mesh tests and
+parity pins execute the same kernel body the TPU compiles.
 
 Scope: DECODE shapes only (one query per sequence, causality == attend to
 the whole resident prefix). Mixed prefill/chunk batches keep the XLA
@@ -35,6 +35,8 @@ alone (`engine._select_attn_impl`).
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax
@@ -42,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llmd_tpu.ops.paged_attention import VMEM_LIMIT
+from llmd_tpu.ops.paged_attention import VMEM_LIMIT, shard_over_heads
 
 # Large-negative finite mask value: -inf would make the m/alpha recurrence
 # produce nan on fully masked pages (exp(-inf - -inf)); masked probabilities
@@ -114,7 +116,7 @@ def mla_decode_pallas(
     kv_lens: jax.Array,      # [B] tokens resident incl. this step's
     *,
     scale: float,
-    interpret: "bool | None" = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Raw kernel invocation (decode shapes). Returns [B, H, Dhp]; lanes past
     the real latent width come back zero (acc only mixes stored rows, whose
@@ -123,8 +125,6 @@ def mla_decode_pallas(
     _, ps, planes, _ = layer_cache.shape
     assert planes == 1, "mla_decode_pallas serves the single-plane latent pool"
     maxp = page_tables.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     # fold sm_scale into q once (f32 exact: scale is a power-free float but
     # the same value the reference multiplies into the scores)
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
@@ -146,20 +146,18 @@ def mla_decode_pallas(
             pltpu.VMEM((H, Dhp), jnp.float32),     # acc
         ],
     )
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            # revisit-heavy grid: neither axis is parallelizable (scratch
-            # carries state across pages; output blocks revisit across b)
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT,
-        )
     kern = pl.pallas_call(
         _decode_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Dhp), q.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            # revisit-heavy grid: neither axis is parallelizable (scratch
+            # carries state across pages; output blocks revisit across b)
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
+        ),
+        name="mla_latent_decode_kernel",
     )
     return kern(page_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
                 q, layer_cache)
@@ -178,6 +176,8 @@ def mla_paged_attention_latent(
     num_seqs: "jax.Array | None" = None,    # unused (uniform impl signature)
     chunk_k: "jax.Array | None" = None,     # unused (ring-attn impls only)
     chunk_v: "jax.Array | None" = None,     # unused (ring-attn impls only)
+    interpret: bool = False,  # True only when the selecting platform is CPU
+    mesh=None,  # engine mesh: the kernel runs per device under shard_map
 ) -> jax.Array:
     """Uniform-signature adapter (drop-in for ragged_paged_attention_xla) for
     DECODE calls on MLA engines: one query row per batch slot. The engine
@@ -197,4 +197,10 @@ def mla_paged_attention_latent(
         # write_kv stores the latent at scale 1.0, so upcasting at use is the
         # whole dequant; the kernel's f32 compute path does it for free.
         layer_cache = layer_cache.astype(q.dtype)
-    return mla_decode_pallas(q, layer_cache, page_tables, kv_lens, scale=scale)
+    call = functools.partial(mla_decode_pallas, scale=scale,
+                             interpret=interpret)
+    if mesh is not None:
+        # heads split over tp; the latent plane is replicated (every head's
+        # shard needs the whole latent — the engine's MLA cache layout)
+        call = shard_over_heads(call, mesh, q, layer_cache, shard_kv=False)
+    return call(q, layer_cache, page_tables, kv_lens)

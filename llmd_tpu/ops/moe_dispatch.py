@@ -24,9 +24,7 @@ collective. ``ep > 1``: bounded per-rank buckets exchanged with
 ``1/ep`` slice of the token range, sends every routed copy to the rank
 owning its slot (capacity = all of a rank's copies, so nothing can
 drop), computes local experts token-sorted, and returns results over the
-same buckets. ``jax.lax.ragged_all_to_all`` (jax >= 0.5) is
-feature-detected and deliberately not required: the pinned jax 0.4.37
-predates it, so the bounded-bucket exchange is the portable layout.
+same buckets.
 
 DBO: callers split the batch in half and invoke this path per half; the
 two halves share no intermediate values, so half A's all-to-all is
@@ -45,12 +43,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from .grouped_gemm import ragged_grouped_gemm
-
-
-def has_ragged_all_to_all() -> bool:
-    """Newer jax ships a dedicated ragged collective; the pinned 0.4.37
-    does not — the bounded-bucket ``all_to_all`` below is the fallback."""
-    return hasattr(jax.lax, "ragged_all_to_all")
 
 
 def pick_block_size(tokens_k: int, slots: int, pallas: bool) -> int:
@@ -146,7 +138,7 @@ def dispatch_stage(x, idx, topw, valid, S: int, bc: int):
 
 def experts_stage(xs, block_slot, block_rows, wi, wo, wi_scale=None,
                   wo_scale=None, *, use_pallas: bool = False,
-                  interpret: Optional[bool] = None):
+                  interpret: bool = False):
     """Per-block expert MLP on the sorted buffer: [Tp, D] -> [Tp, D]."""
     Tp, D = xs.shape
     bc = Tp // block_slot.shape[0]
@@ -169,7 +161,7 @@ def combine_stage(ye, row, tok, wf, T: int):
 
 def sorted_moe_local(x, idx, topw, valid, wi, wo, wi_scale=None,
                      wo_scale=None, *, use_pallas: bool = False,
-                     interpret: Optional[bool] = None,
+                     interpret: bool = False,
                      bc: Optional[int] = None):
     """Single-shard token-sorted MoE: gather/scatter only, no collective."""
     T, D = x.shape
@@ -262,7 +254,7 @@ def _ep_moe_body(xl, idxl, wl, vl, wi_l, wo_l, wis_l, wos_l, *, ep: int,
 
 
 def make_sorted_dispatch(mesh=None, *, use_pallas: bool = False,
-                         interpret: Optional[bool] = None):
+                         interpret: bool = False):
     """Build a ``moe_block`` dispatch_impl closure.
 
     ``impl(x, idx, topw, valid, wi, wo, wi_scale, wo_scale) -> y``: the
@@ -281,7 +273,6 @@ def make_sorted_dispatch(mesh=None, *, use_pallas: bool = False,
                                     interpret=interpret)
         return impl
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     shape = dict(mesh.shape)
@@ -315,8 +306,8 @@ def make_sorted_dispatch(mesh=None, *, use_pallas: bool = False,
         if wi_scale is not None:
             in_specs += [P("ep", None), P("ep", None)]
             args += [wi_scale, wo_scale]
-        y = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                      out_specs=tok, check_rep=False)(*args)
+        y = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                          out_specs=tok, check_vma=False)(*args)
         return y[:T]
 
     return impl

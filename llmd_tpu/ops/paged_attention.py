@@ -44,6 +44,38 @@ def _kernel():
     return rpa
 
 
+def shard_over_heads(fn, mesh, q, layer_cache, *, shard_kv: bool):
+    """Run ``fn(q, layer_cache, *replicated)`` per device under ``shard_map``.
+
+    A Mosaic call inside a jit that spans several devices cannot be
+    partitioned by GSPMD, so the Pallas attention kernels carry their own
+    partitioning: query heads split over ``tp`` and the pool follows the
+    engine's own layout (``shard_kv``: combined KV heads over ``tp`` for
+    GQA; replicated for the MLA latent plane). Every other operand — and
+    the token dim on the dp/sp/ep axes — is replicated: the ragged kernel's
+    ``cu_q_lens`` contract does not split on a flat token boundary.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    tp = mesh.shape["tp"]
+    planes = layer_cache.shape[2]
+    if q.shape[1] % tp or (shard_kv and planes % (2 * tp)):
+        raise ValueError(
+            f"attention heads do not split over tp={tp}: {q.shape[1]} query "
+            f"heads, {planes} combined KV planes (K/V pairs must stay on one "
+            "device)")
+    heads = P(None, "tp", None)
+    cache_spec = P(None, None, "tp", None) if shard_kv else P()
+
+    def sharded(q, layer_cache, *rest):
+        return jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=(heads, cache_spec) + (P(),) * len(rest),
+            out_specs=heads, check_vma=False)(q, layer_cache, *rest)
+
+    return sharded
+
+
 def pick_block_sizes(num_tokens: int, page_size: int, pages_per_seq: int,
                      *, head_layout: "str | None" = None) -> tuple[int, int]:
     """(num_kv_pages_per_block, num_queries_per_block) for our serving shapes.
@@ -116,6 +148,7 @@ def paged_attention_tpu(
     num_seqs: jax.Array,  # [1]
     chunk_k: "jax.Array | None" = None,  # unused (ring-attn impls only)
     chunk_v: "jax.Array | None" = None,  # unused (ring-attn impls only)
+    mesh=None,  # engine mesh: the kernel runs per device under shard_map
 ) -> jax.Array:
     """Uniform-signature adapter over the Pallas kernel (drop-in for
     models.transformer.ragged_paged_attention_xla on TPU)."""
@@ -136,19 +169,23 @@ def paged_attention_tpu(
         # dynamic range covers K/V activations), halving the HBM KV stream.
         # Kernel precondition: combined heads % 4 == 0 (strided fp8 load
         # packing). True for llama-1b both padded (16) and packed (8); NOT for
-        # tiny CI models with 2 combined heads — there the engine's smoke
-        # compile fails and serving falls back to the XLA reference impl.
+        # tiny CI models with 2 combined heads, which the kernel rejects.
         extra = {"k_scale": 1.0, "v_scale": 1.0}
-    return _kernel()(
+    call = functools.partial(
+        _kernel(),
+        sm_scale=scale,
+        num_kv_pages_per_block=bkv,
+        num_queries_per_block=bq,
+        vmem_limit_bytes=VMEM_LIMIT,
+        **extra,
+    )
+    if mesh is not None:
+        call = shard_over_heads(call, mesh, q, layer_cache, shard_kv=True)
+    return call(
         q,
         layer_cache,
         kv_lens.astype(jnp.int32),
         page_tables.astype(jnp.int32),
         cu_q_lens.astype(jnp.int32),
         num_seqs.astype(jnp.int32),
-        sm_scale=scale,
-        num_kv_pages_per_block=bkv,
-        num_queries_per_block=bq,
-        vmem_limit_bytes=VMEM_LIMIT,
-        **extra,
     )

@@ -166,13 +166,9 @@ def ring_attention_sharded(q, k, v, *, axis_name: str, scale: float,
 
     # the zero-init carries are device-invariant but the loop outputs vary
     # over the ring axis — shard_map's varying-axes check requires the carry
-    # types to agree up front (pcast on current jax; pvary on older)
+    # types to agree up front
     def _mark_varying(x):
-        if hasattr(lax, "pcast"):
-            return lax.pcast(x, axis_name, to="varying")
-        if hasattr(lax, "pvary"):
-            return lax.pvary(x, axis_name)
-        return x  # pre-0.5 jax has no varying-axes check — nothing to satisfy
+        return lax.pcast(x, axis_name, to="varying")
 
     m0 = _mark_varying(jnp.full((S, Hk, G), NEG_INF, jnp.float32))
     l0 = _mark_varying(jnp.zeros((S, Hk, G), jnp.float32))
@@ -195,7 +191,6 @@ def sp_flash_prefill(q, k, v, mesh, *, scale: Optional[float] = None,
     ~equal per device per ring step — the contiguous layout leaves the last
     shard computing at every step while shard 0 idles behind the ppermute
     barrier, ~2× the wall clock for identical results."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if scale is None:
@@ -227,7 +222,7 @@ def sp_flash_prefill(q, k, v, mesh, *, scale: Optional[float] = None,
         perm = inv = None
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     def run(qs, ks, vs):
         return ring_attention_sharded(qs, ks, vs, axis_name=axis_name,
                                       scale=scale, zigzag=use_zigzag)

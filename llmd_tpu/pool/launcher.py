@@ -204,12 +204,15 @@ def engine_argv(model: str, port: int,
                 extra: Optional[list[str]] = None) -> tuple[list[str], bool]:
     """argv for an ``engine/serve.py`` replica, warm-start aware.
 
-    With a snapshot store, the materialized checkpoint and the persistent
-    JAX compilation cache live under the config fingerprint: the first
-    launch builds the checkpoint (testing/checkpoints.py for test models,
-    a straight copy of HF dirs otherwise happens at serve time) and every
-    relaunch reuses both — serve deserializes compiled programs instead of
-    tracing them. Returns ``(argv, warm)``.
+    With a snapshot store, the materialized checkpoint lives under the
+    config fingerprint: the first launch builds it (testing/checkpoints.py
+    for test models, a straight copy of HF dirs otherwise happens at serve
+    time) and every relaunch reuses it. Compiled programs come back from the
+    one compilation cache every entry point shares
+    (llmd_tpu/jax_init.py: ``JAX_COMPILATION_CACHE_DIR`` from the
+    replica's environment, else the fixed path in the checkout) — keyed by
+    the program's own hash, so it needs no per-fingerprint directory.
+    Returns ``(argv, warm)``.
     """
     cfg = dict(engine_config or {})
     cfg.setdefault("model", model)
@@ -219,7 +222,6 @@ def engine_argv(model: str, port: int,
     if snapshots is not None:
         fp = config_fingerprint(cfg)
         warm = snapshots.has(fp)
-        cache_dir = snapshots.path(fp, "compile_cache")
         if not os.path.isdir(model):  # test-model name → materialize once
             ckpt_dir = snapshots.path(fp, "checkpoint")
             if not os.path.exists(os.path.join(ckpt_dir, "config.json")):
@@ -227,7 +229,6 @@ def engine_argv(model: str, port: int,
 
                 make_hf_checkpoint(ckpt_dir)
             argv[argv.index("--model") + 1] = ckpt_dir
-        argv += ["--compile-cache-dir", cache_dir]
         if not warm:
             snapshots.save(fp, {"kind": "engine", "engine_config": cfg})
     argv += list(extra or [])
@@ -240,6 +241,12 @@ class ProcessReplicaLauncher(ReplicaLauncher):
     ``argv_fn(port) -> (argv, warm)`` (or ``argv`` alone, treated as cold)
     decouples the launcher from what it launches: ``fake_argv`` for CI,
     ``engine_argv`` for on-device pools.
+
+    A chip belongs to one process: children get the parent's environment
+    and no device assignment, so on an accelerator host this launcher runs
+    ONE engine replica per chip-owning host process (a second replica on
+    the same chips fails to initialise — its stderr is inherited, so the
+    reason is visible). Pinning replicas to devices is ROADMAP R4.
     """
 
     def __init__(self, argv_fn: Callable[[int], Any], host: str = "127.0.0.1",
@@ -260,9 +267,9 @@ class ProcessReplicaLauncher(ReplicaLauncher):
         built = self.argv_fn(port)
         argv, warm = built if isinstance(built, tuple) else (built, False)
         env = dict(os.environ, **(self.env or {}))
-        proc = subprocess.Popen(argv, env=env,
-                                stdout=subprocess.DEVNULL,
-                                stderr=subprocess.DEVNULL)
+        # stderr is inherited: a replica that cannot get the chip (or dies
+        # on a compile error) must say so where the operator can read it
+        proc = subprocess.Popen(argv, env=env, stdout=subprocess.DEVNULL)
         address = f"{self.host}:{port}"
         deadline = time.monotonic() + self.ready_timeout_s
         async with aiohttp.ClientSession() as sess:

@@ -1,20 +1,21 @@
 """Pool-level snapshot store: engine-config-fingerprinted warm-start state.
 
 A cold 0→1 transition pays the full engine build (checkpoint materialize +
-trace/compile + warmup — BENCH_r01 measured ~52s build + ~17s warmup on
-device). Everything in that path is a pure function of the engine config,
-so the pool controller snapshots the reusable artifacts once per config
-fingerprint and later launches against the snapshot:
+trace/compile + warmup; PERF.md records the measured cold and warm
+start-to-first-token). The checkpoint is a pure function of the engine
+config, so the pool controller snapshots it once per config fingerprint and
+later launches against the snapshot:
 
 - fake mode: the snapshot's existence itself is the signal — the simulated
   engine-build delay is skipped;
-- engine mode: the snapshot directory carries the materialized checkpoint
-  and the persistent JAX compilation cache, handed to ``engine/serve.py``
-  via ``--model`` / ``--compile-cache-dir`` so the relaunch deserializes
-  compiled programs instead of rebuilding them.
+- engine mode: the snapshot directory carries the materialized checkpoint,
+  handed to ``engine/serve.py`` via ``--model``. Compiled programs are not
+  part of a snapshot: the JAX compilation cache keys on the program's own
+  hash and lives at one place for every launch
+  (``llmd_tpu/jax_init.py``), so a relaunch hits it whatever its
+  fingerprint.
 
-Fingerprints are sha256 over the sorted-JSON engine config, mirroring how
-the engine's own compile cache keys on (program shape, flags).
+Fingerprints are sha256 over the sorted-JSON engine config.
 """
 
 from __future__ import annotations
@@ -37,9 +38,9 @@ class PoolSnapshotStore:
     """Filesystem store of per-fingerprint warm-start snapshots.
 
     Layout: ``<root>/<fingerprint>/meta.json`` plus whatever artifact
-    directories the launcher parks next to it (``checkpoint/``,
-    ``compile_cache/``). ``meta.json`` is written last, atomically, so a
-    half-built snapshot never reads as warm.
+    directories the launcher parks next to it (``checkpoint/``).
+    ``meta.json`` is written last, atomically, so a half-built snapshot
+    never reads as warm.
     """
 
     def __init__(self, root_dir: str) -> None:

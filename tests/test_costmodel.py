@@ -148,8 +148,6 @@ def test_chip_peaks_lookup_and_null_path():
     assert chip_peaks("tpu v4") == (275.0, 1228.0)  # case-insensitive
     assert chip_peaks("cpu") == (None, None)
     assert chip_peaks("") == (None, None)
-    # bench.py's historical behavior: explicit default for off-table kinds
-    assert chip_peaks("cpu", default=(197.0, 819.0)) == (197.0, 819.0)
 
 
 def test_peaks_file_overlay(tmp_path, monkeypatch):

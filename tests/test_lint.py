@@ -120,7 +120,7 @@ def test_ci_gate_pins_stage_roster():
     roster = ["lint-envvars", "lint-metrics", "lint-events", "llmd-lint",
               "validate-manifests", "chaos-check", "structured-check",
               "slo-check", "device-obs", "kv-plane-check", "decision-check",
-              "kv-durability-check", "pd-check", "perf-regress"]
+              "kv-durability-check", "pd-check"]
     positions = []
     for stage in roster:
         idx = src.find(f'"{stage}"')
@@ -146,7 +146,7 @@ def test_ci_gate_composes_stages():
         "lint-envvars", "lint-metrics", "lint-events", "llmd-lint",
         "validate-manifests", "chaos-check", "structured-check", "slo-check",
         "device-obs", "kv-plane-check", "decision-check",
-        "kv-durability-check", "pd-check", "perf-regress"]
+        "kv-durability-check", "pd-check"]
     assert all(s["ok"] for s in summary["stages"])
 
 

@@ -211,7 +211,7 @@ def _latent_parity(dtype, tol):
     kw = dict(positions=kv_lens - 1, seq_slots=jnp.arange(B, dtype=jnp.int32),
               kv_lens=kv_lens, scale=(64 + 16) ** -0.5)
     ref = ragged_paged_attention_xla(q, cache, pt, **kw)
-    got = mla_paged_attention_latent(q, cache, pt, **kw)  # interpret on CPU
+    got = mla_paged_attention_latent(q, cache, pt, interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
 
@@ -227,6 +227,31 @@ def test_latent_decode_kernel_parity_fp32():
 def test_latent_decode_kernel_parity_bf16():
     import jax.numpy as jnp
     _latent_parity(jnp.bfloat16, 2e-2)
+
+
+def test_latent_decode_kernel_tp4_split_matches_unsharded():
+    """Under a mesh the latent kernel runs per device (shard_over_heads:
+    query heads over tp, the latent plane replicated). One head per device
+    here; the sharded result must equal the single-device one."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from llmd_tpu.ops.mla_decode import mla_paged_attention_latent
+    from llmd_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    q, cache, pt, kv_lens = _latent_op_inputs(jnp.float32)
+    B = q.shape[0]
+    kw = dict(positions=kv_lens - 1, seq_slots=jnp.arange(B, dtype=jnp.int32),
+              kv_lens=kv_lens, scale=(64 + 16) ** -0.5)
+    want = mla_paged_attention_latent(q, cache, pt, interpret=True, **kw)
+    got = jax.jit(functools.partial(
+        mla_paged_attention_latent, scale=kw.pop("scale"), interpret=True,
+        mesh=build_mesh(MeshConfig(tp=4))))(q, cache, pt, **kw)
+    assert np.abs(np.asarray(want)).max() > 0.1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=2e-6)
 
 
 def test_explicit_pallas_latent_decode_serves_with_parity():

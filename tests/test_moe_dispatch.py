@@ -227,14 +227,6 @@ def test_dispatch_stage_places_every_valid_copy():
     np.testing.assert_allclose(np.asarray(y), want, rtol=0, atol=1e-6)
 
 
-def test_ragged_all_to_all_feature_detect():
-    from llmd_tpu.ops.moe_dispatch import has_ragged_all_to_all
-
-    # pinned jax 0.4.37 predates the collective; the bucket exchange must
-    # not depend on it either way
-    assert has_ragged_all_to_all() == hasattr(jax.lax, "ragged_all_to_all")
-
-
 # ----------------------------------------------------------------- ep axis
 
 

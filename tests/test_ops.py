@@ -2,9 +2,11 @@
 brute-force oracle, plus engine-level consistency between the unified (mixed
 prefill+decode) and fused-decode execution paths.
 
-The Pallas kernel itself (ops.paged_attention.paged_attention_tpu) is TPU-only —
-it is smoke-compiled by the engine at startup on TPU and falls back with recorded
-provenance elsewhere, so CPU CI exercises the identical-contract XLA reference.
+The Pallas kernel itself (ops.paged_attention.paged_attention_tpu) is TPU-only:
+the engine selects it by platform, so CPU CI exercises the identical-contract
+XLA reference. Its TPU lowering is checked from the CPU in
+tests/test_tpu_lowering.py, and its agreement with this reference on the chip
+by chip_smoke.py's parity phase.
 """
 
 import numpy as np
@@ -202,23 +204,69 @@ def test_pallas_adapter_glue_with_stub_kernel(monkeypatch):
     assert captured["vmem_limit_bytes"] == pa.VMEM_LIMIT
 
 
-@pytest.mark.tpu
-def test_pallas_kernel_matches_reference_on_tpu():
-    """On real TPU hardware: the Pallas kernel must agree with the XLA reference."""
+def _reference_as_kernel(q, kv, kv_lens, page_tables, cu_q_lens, num_seqs, *,
+                         sm_scale, **_):
+    """The XLA reference behind the upstream kernel's calling convention
+    (each sequence's queries are its last q_len tokens), so the adapter's
+    multi-device partitioning can be executed on the CPU mesh."""
+    del num_seqs
+    n = jnp.arange(q.shape[0], dtype=jnp.int32)
+    slots = jnp.searchsorted(cu_q_lens[1:], n, side="right").astype(jnp.int32)
+    slots = jnp.minimum(slots, kv_lens.shape[0] - 1)
+    q_lens = cu_q_lens[1:] - cu_q_lens[:-1]
+    pos = kv_lens[slots] - q_lens[slots] + (n - cu_q_lens[slots])
+    return ragged_paged_attention_xla(q, kv, page_tables, pos, slots, kv_lens,
+                                      scale=sm_scale)
+
+
+def test_paged_attention_tp4_split_keeps_each_head_with_its_kv(monkeypatch):
+    """paged_attention_tpu under a tp=4 mesh runs the kernel per device
+    (shard_over_heads). Every query head must still meet its own K/V planes:
+    against the brute-force oracle for the plain layout, and against the
+    unsharded call for the llama-1b packed layout (32/8 heads of 64, two
+    heads per lane row). The kernel is stubbed by the reference, so this
+    pins the split, not Mosaic — chip_smoke.py repeats it with the real
+    kernel on the chip."""
+    import functools
+
     import jax
 
-    if jax.default_backend() != "tpu":
-        pytest.skip("TPU only")
-    from llmd_tpu.ops.paged_attention import paged_attention_tpu
+    import llmd_tpu.ops.paged_attention as pa
+    from llmd_tpu.models import get_model_config
+    from llmd_tpu.ops.packed_kv import make_packed_attn, pack_factor
+    from llmd_tpu.parallel.mesh import MeshConfig, build_mesh
 
+    monkeypatch.setattr(pa, "_kernel", lambda: _reference_as_kernel)
+    mesh = build_mesh(MeshConfig(tp=4))
+    cu = jnp.asarray([0, 8, 9, 10], jnp.int32)
+    ns = jnp.asarray([3], jnp.int32)
+
+    # plain layout: 8 query heads over 4 KV heads of 128 -> one K/V pair
+    # and its two query heads per device
     q, kv, pt, pos, sids, lens = _mk_flat_case([40, 9, 21], [8, 1, 1], 8, 4, 128,
                                                P=32, ps=16, max_pages=4)
-    scale = 128 ** -0.5
-    cu = np.asarray([0, 8, 9, 10], np.int32)
-    got = np.asarray(paged_attention_tpu(
-        jnp.asarray(q, jnp.bfloat16), jnp.asarray(kv, jnp.bfloat16),
-        jnp.asarray(pt), jnp.asarray(pos), jnp.asarray(sids), jnp.asarray(lens),
-        scale=scale, cu_q_lens=jnp.asarray(cu), num_seqs=jnp.asarray([3], jnp.int32),
-    ), np.float32)
-    want = _np_oracle(q, kv, pt, pos, sids, lens, scale)
-    np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
+    args = [jnp.asarray(a) for a in (q, kv, pt, pos, sids, lens)]
+    got = jax.jit(functools.partial(pa.paged_attention_tpu, scale=0.09,
+                                    mesh=mesh))(*args, cu_q_lens=cu,
+                                                num_seqs=ns)
+    want = _np_oracle(q, kv, pt, pos, sids, lens, 0.09)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+    # llama-1b packed layout: 8 packed planes -> one packed K/V pair (two
+    # real KV heads) and its eight query heads per device
+    cfg = get_model_config("llama-1b")
+    f = pack_factor(cfg)
+    assert f == 2
+    q, kv, pt, pos, sids, lens = _mk_flat_case(
+        [40, 9, 21], [8, 1, 1], cfg.num_heads, cfg.num_kv_heads // f, 128,
+        P=32, ps=16, max_pages=4, seed=1)
+    q[..., cfg.head_dim:] = 0.0  # the padded-q contract forward_core keeps
+    args = [jnp.asarray(a) for a in (q, kv, pt, pos, sids, lens)]
+    kw = dict(scale=cfg.head_dim ** -0.5, cu_q_lens=cu, num_seqs=ns)
+    got = jax.jit(make_packed_attn(
+        functools.partial(pa.paged_attention_tpu, mesh=mesh), cfg, f),
+        static_argnames="scale")(*args, **kw)
+    want = make_packed_attn(ragged_paged_attention_xla, cfg, f)(*args, **kw)
+    assert np.abs(np.asarray(want)).max() > 0.1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)

@@ -41,8 +41,8 @@ def test_snapshot_store_roundtrip(tmp_path):
     store = PoolSnapshotStore(str(tmp_path))
     fp = config_fingerprint({"model": "m"})
     assert not store.has(fp) and store.load(fp) is None
-    cache = store.path(fp, "compile_cache")
-    assert os.path.isdir(cache)  # artifact dirs exist before meta commits
+    ckpt = store.path(fp, "checkpoint")
+    assert os.path.isdir(ckpt)  # artifact dirs exist before meta commits
     assert not store.has(fp)  # half-built snapshot never reads warm
     store.save(fp, {"kind": "fake"})
     assert store.has(fp)

@@ -1,0 +1,145 @@
+"""Lower every Pallas kernel for TPU from the CPU.
+
+The suite forces the CPU platform, where Pallas runs in interpret mode and
+the engine selects the XLA attention reference — so nothing that only
+breaks on the Mosaic path (a renamed compiler-params class, a kernel called
+bare inside a multi-device jit) is ever executed. Cross-lowering
+(``jax.export`` with ``platforms=["tpu"]``, ``interpret=False``) runs the
+real TPU lowering rules without a chip. It proves the call lowers; whether
+Mosaic then accepts the kernel (VMEM, tiling) only ``chip_smoke.py`` on the
+chip can say.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from llmd_tpu.models import get_model_config
+from llmd_tpu.models.transformer import padded_head_dim
+from llmd_tpu.ops.grouped_gemm import grouped_gemm, ragged_grouped_gemm
+from llmd_tpu.ops.mla_decode import mla_paged_attention_latent
+from llmd_tpu.ops.packed_kv import make_packed_attn, pack_factor
+from llmd_tpu.ops.paged_attention import paged_attention_tpu
+from llmd_tpu.parallel.mesh import MeshConfig, build_mesh
+
+
+def _lower_for_tpu(fn, *args):
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
+    text = exported.mlir_module()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the lowered module"
+    return text
+
+
+def _spec(shape, dtype, sharding=None):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _attn_args(q_shape, cache_shape, B, maxp, mesh=None, q_spec=None,
+               cache_spec=None):
+    def sh(spec):
+        return NamedSharding(mesh, spec) if mesh is not None else None
+
+    N = q_shape[0]
+    return (
+        _spec(q_shape, jnp.bfloat16, sh(q_spec)),
+        _spec(cache_shape, jnp.bfloat16, sh(cache_spec)),
+        _spec((B, maxp), jnp.int32, sh(P())),   # page_tables
+        _spec((N,), jnp.int32, sh(P())),        # positions
+        _spec((N,), jnp.int32, sh(P())),        # seq_slots
+        _spec((B,), jnp.int32, sh(P())),        # kv_lens
+        _spec((B + 1,), jnp.int32, sh(P())),    # cu_q_lens
+        _spec((1,), jnp.int32, sh(P())),        # num_seqs
+    )
+
+
+def _llama_packed_attn(mesh=None):
+    cfg = get_model_config("llama-1b")
+    f = pack_factor(cfg)
+    assert f == 2, "llama-1b must exercise the packed KV layout"
+    inner = functools.partial(paged_attention_tpu, mesh=mesh)
+    attn = make_packed_attn(inner, cfg, f)
+
+    def fn(q, cache, pt, pos, slots, lens, cu, ns):
+        return attn(q, cache, pt, pos, slots, lens, scale=cfg.head_dim ** -0.5,
+                    cu_q_lens=cu, num_seqs=ns)
+
+    dhp = padded_head_dim(cfg.head_dim)
+    q_shape = lambda n: (n, cfg.num_heads, dhp)  # noqa: E731
+    cache_shape = (64 * 4, 16, 2 * cfg.num_kv_heads // f, dhp)
+    return fn, q_shape, cache_shape
+
+
+def test_packed_paged_attention_lowers_for_tpu():
+    """llama-1b head layout (32/8 heads of 64, packed 2 per lane row) at the
+    decode shape (one token per slot) and a prefill chunk."""
+    fn, q_shape, cache_shape = _llama_packed_attn()
+    for n, B in ((64, 64), (256, 64)):
+        _lower_for_tpu(fn, *_attn_args(q_shape(n), cache_shape, B, 64))
+
+
+def test_paged_attention_lowers_under_tp4_sharding():
+    """The engine's tp layout: query heads and combined KV heads over tp.
+    Called bare, Mosaic refuses ("cannot be automatically partitioned");
+    the ops module wraps the kernel in shard_map."""
+    mesh = build_mesh(MeshConfig(tp=4))
+    fn, q_shape, cache_shape = _llama_packed_attn(mesh)
+    args = _attn_args(q_shape(64), cache_shape, 64, 64, mesh=mesh,
+                      q_spec=P(None, "tp", None),
+                      cache_spec=P(None, None, "tp", None))
+    _lower_for_tpu(fn, *args)
+
+
+def test_mla_latent_decode_lowers_for_tpu():
+    cfg = get_model_config("moe-wide-mla")
+    dhp = padded_head_dim(cfg.mla_kv_lora_rank + cfg.mla_rope_dim)
+    B, maxp = 16, 8
+
+    def fn(q, cache, pt, pos, slots, lens, cu, ns):
+        return mla_paged_attention_latent(
+            q, cache, pt, pos, slots, lens, scale=0.125, cu_q_lens=cu,
+            num_seqs=ns, interpret=False)
+
+    _lower_for_tpu(fn, *_attn_args((B, cfg.num_heads, dhp),
+                                   (B * maxp, 16, 1, dhp), B, maxp))
+
+
+def test_mla_latent_decode_lowers_under_tp4_sharding():
+    cfg = get_model_config("moe-wide-mla")
+    dhp = padded_head_dim(cfg.mla_kv_lora_rank + cfg.mla_rope_dim)
+    B, maxp = 16, 8
+    mesh = build_mesh(MeshConfig(tp=4))
+
+    def fn(q, cache, pt, pos, slots, lens, cu, ns):
+        return mla_paged_attention_latent(
+            q, cache, pt, pos, slots, lens, scale=0.125, cu_q_lens=cu,
+            num_seqs=ns, interpret=False, mesh=mesh)
+
+    args = _attn_args((B, cfg.num_heads, dhp), (B * maxp, 16, 1, dhp), B,
+                      maxp, mesh=mesh, q_spec=P(None, "tp", None),
+                      cache_spec=P())
+    _lower_for_tpu(fn, *args)
+
+
+def test_grouped_gemms_lower_for_tpu():
+    G, C, D, F = 8, 32, 256, 512
+    _lower_for_tpu(
+        functools.partial(grouped_gemm, interpret=False),
+        _spec((G, C, D), jnp.bfloat16), _spec((G, D, F), jnp.bfloat16),
+        _spec((G,), jnp.int32))
+    nb, bc = 12, 16
+    _lower_for_tpu(
+        functools.partial(ragged_grouped_gemm, interpret=False),
+        _spec((nb, bc, D), jnp.bfloat16), _spec((G, D, F), jnp.bfloat16),
+        _spec((nb,), jnp.int32), _spec((nb,), jnp.int32))
+
+
+def test_attention_heads_must_split_over_tp():
+    """K/V pairs of one head must stay on one device: a layout that cannot
+    split is an error at trace time, never a silently wrong shard."""
+    mesh = build_mesh(MeshConfig(tp=8))
+    fn, q_shape, cache_shape = _llama_packed_attn(mesh)  # 4 packed heads / 8
+    with pytest.raises(ValueError, match="do not split over tp=8"):
+        jax.eval_shape(fn, *_attn_args(q_shape(64), cache_shape, 64, 64))

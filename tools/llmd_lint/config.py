@@ -50,8 +50,8 @@ HOT_PATHS: dict[str, object] = {
     ],
     "llmd_tpu/engine/spec.py": "*",
     # step-program registry: the dispatch/complete ledger and routing run
-    # once per engine step. select_decode_attn_impl is startup-only (its
-    # smoke-compile block_until_ready is deliberate) and stays unchecked.
+    # once per engine step. select_decode_attn_impl is startup-only and
+    # stays unchecked.
     "llmd_tpu/engine/programs.py": [
         "record_dispatch",
         "record_complete",

@@ -1,29 +1,21 @@
 """Perf regression gate: compare a bench JSON against a pinned baseline.
 
-The pinned numbers (``BENCH_r05.json``, plus the campaign sweep in
-``BENCH_CAMPAIGN_r05.json``) are the repo's performance contract. This tool
-makes them enforceable: given a candidate bench payload — a ``bench.py``
-final-JSON line, a ``BENCH_*.json`` wrapper, or a campaign file — it compares
-every shared numeric metric against the baseline under per-metric tolerances
-and emits a machine verdict (JSON) plus a human one (markdown table).
+Given a candidate bench payload — a ``bench.py`` final-JSON line, a wrapper
+around one, or a multi-point results file — and a baseline of the same form,
+it compares every shared numeric metric under per-metric tolerances and emits
+a machine verdict (JSON) plus a human one (markdown table). The repo pins no
+baseline file: both sides are named on the command line.
 
 Provenance guard: bench numbers only compare like-for-like. When the
 candidate's ``device`` or ``point`` differs from the baseline's (the tiny CPU
 CI bench vs a TPU v5 baseline), throughput metrics are reported as
 ``skipped`` — the gate then checks *plumbing* (payload shape, counter sanity)
-without flagging hardware differences as regressions. CI wires this two ways
-(tools/ci_gate.py):
-
-* ``perf-regress`` — always-on, milliseconds: campaign point vs pinned
-  BENCH_r05 (same provenance, must agree within tolerance).
-* ``bench-tiny-cpu`` — ``--run`` mode: executes the tiny CPU bench and
-  gates its payload shape through the same comparator.
+without flagging hardware differences as regressions.
 
 Usage:
-  python tools/perf_regress.py --candidate BENCH_CAMPAIGN_r05.json \
-      --baseline BENCH_r05.json
-  python tools/perf_regress.py --run -- --tiny --cpu   # wrap bench.py
-  make perf-regress
+  python tools/perf_regress.py --candidate new.json --baseline old.json
+  python tools/perf_regress.py --baseline old.json --run -- --tiny --cpu
+  python tools/perf_regress.py --router-overhead
 """
 
 from __future__ import annotations
@@ -289,8 +281,8 @@ def main(argv=None) -> int:
         description="Compare bench JSON against a pinned baseline")
     ap.add_argument("--candidate",
                     help="bench/campaign JSON file (omit with --run)")
-    ap.add_argument("--baseline", default="BENCH_r05.json",
-                    help="pinned baseline JSON (default BENCH_r05.json)")
+    ap.add_argument("--baseline", default=None,
+                    help="baseline JSON to compare against")
     ap.add_argument("--point", default=None,
                     help="campaign point to select (default: the baseline's "
                          "own point when set, else the first result)")
@@ -321,6 +313,8 @@ def main(argv=None) -> int:
         print("perf-regress: PASS (router overhead)", file=sys.stderr)
         return 0
 
+    if not args.baseline:
+        ap.error("need --baseline FILE")
     with open(args.baseline) as f:
         baseline = extract_payload(json.load(f))
 
