@@ -13,6 +13,8 @@ import time
 import traceback
 from typing import AsyncIterator, Callable, Optional
 
+import jax
+
 from llmd_tpu.core.request import SamplingParams
 from llmd_tpu.engine.engine import EngineOutput, LLMEngine
 
@@ -53,6 +55,15 @@ class AsyncLLMEngine:
             self._thread.join(timeout=10)
 
     def _run(self) -> None:
+        # The loop from inside (PERF.md section 3): each iteration's wall
+        # time goes to one of four parts, as llmd.loop.* profiler spans
+        # (step() carries llmd.step itself) and, from the same readings, as
+        # llmd_tpu:engine_loop_seconds_total{part}.
+        m = self.engine.metrics
+        lock_s, step_s, deliver_s, idle_s = (
+            m.loop_seconds.labels(part=p)
+            for p in ("lock", "step", "deliver", "idle"))
+        delivered = m.outputs_delivered
         while not self._stop.is_set():
             # heartbeat BEFORE taking the lock: a step wedged on the device
             # holds the lock, so stamping inside it would mask the stall the
@@ -60,25 +71,44 @@ class AsyncLLMEngine:
             mon = getattr(self.engine, "monitor", None)
             if mon is not None:
                 mon.heartbeat()
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("llmd.loop.lock"):
+                self._lock.acquire()
+            t1 = time.perf_counter()
             try:
-                with self._lock:
+                try:
                     has_work = self.engine.has_work()
                     outputs = self.engine.step() if has_work else []
+                finally:
+                    self._lock.release()  # _die takes it again
             except Exception as e:  # boundary: the loop cannot continue
                 traceback.print_exc()
                 self._die(e)
                 return
-            for out in outputs:
-                with self._lock:
-                    entry = self._streams.get(out.request_id)
-                    if out.finished:
-                        self._streams.pop(out.request_id, None)
-                if entry is None:
-                    continue
-                loop, q = entry
-                loop.call_soon_threadsafe(q.put_nowait, out)
+            t2 = time.perf_counter()
+            if outputs:
+                n = 0
+                with jax.profiler.TraceAnnotation("llmd.loop.deliver"):
+                    for out in outputs:
+                        with self._lock:
+                            entry = self._streams.get(out.request_id)
+                            if out.finished:
+                                self._streams.pop(out.request_id, None)
+                        if entry is None:
+                            continue
+                        loop, q = entry
+                        loop.call_soon_threadsafe(q.put_nowait, out)
+                        n += 1
+                delivered.inc(n)
+            t3 = time.perf_counter()
             if not has_work:
-                time.sleep(self._idle_sleep)
+                with jax.profiler.TraceAnnotation("llmd.loop.idle"):
+                    time.sleep(self._idle_sleep)
+            t4 = time.perf_counter()
+            lock_s.inc(t1 - t0)
+            step_s.inc(t2 - t1)
+            deliver_s.inc(t3 - t2)
+            idle_s.inc(t4 - t3)
 
     def _die(self, exc: BaseException) -> None:
         with self._lock:

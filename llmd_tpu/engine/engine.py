@@ -81,6 +81,57 @@ def _profile_phase(name: str):
     return deco
 
 
+STEP_PARTS = ("plan", "pack", "dispatch", "sample", "wait", "apply", "book")
+
+
+class _StepParts:
+    """One step program's host time, split into STEP_PARTS on one set of
+    ``perf_counter`` readings: ``to(part)`` closes the running part and opens
+    the next (``None`` pauses, e.g. around a nested program). Each part is a
+    ``<span>.<part>`` profiler annotation while it runs (free with no
+    capture), and its seconds land in ``seconds`` for the part counter
+    (``LLMEngine._book_parts``) and the ``EngineStats.time_*`` splits."""
+
+    __slots__ = ("program", "span", "seconds", "_part", "_t", "_ann")
+
+    def __init__(self, program: str, span: str, part: Optional[str]) -> None:
+        self.program, self.span = program, span
+        self.seconds = dict.fromkeys(STEP_PARTS, 0.0)
+        self._part: Optional[str] = None
+        self._ann = None
+        self.to(part)
+
+    def to(self, part: Optional[str], annotate: bool = True) -> float:
+        now = time.perf_counter()
+        if self._part is not None:
+            self.seconds[self._part] += now - self._t
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+        self._part, self._t = part, now
+        if part is not None and annotate:
+            self._ann = jax.profiler.TraceAnnotation(f"{self.span}.{part}")
+            self._ann.__enter__()
+        return now
+
+
+def _step_phase(program: str, span: str, first: str, outer: bool = True):
+    """A step-loop phase that splits its time: the method gets a running
+    ``_StepParts`` as its first argument, closed on every way out. With
+    ``outer`` the whole call also sits in the ``span`` annotation, as
+    ``_profile_phase`` would put it."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def timed(self, *args, **kwargs):
+            parts = _StepParts(program, span, first)
+            try:
+                return fn(self, parts, *args, **kwargs)
+            finally:
+                parts.to(None)
+        return _profile_phase(span)(timed) if outer else timed
+    return deco
+
+
 @dataclass
 class EngineOutput:
     request_id: str
@@ -89,6 +140,9 @@ class EngineOutput:
     finish_reason: Optional[str] = None
     num_cached_prompt_tokens: int = 0
     prompt_len: int = 0
+    # perf_counter() at the end of the step() that produced this output: the
+    # server observes stamp -> chunk written (llmd_tpu:stream_lag_seconds)
+    t_step: float = 0.0
 
 
 @dataclass
@@ -120,10 +174,17 @@ class EngineStats:
     time_prefill_steps: float = 0.0  # wall inside unified (mixed/prefill) steps
     time_decode_steps: float = 0.0  # wall inside fused decode calls
     time_spec_steps: float = 0.0  # wall inside speculative verify steps
-    time_host_pack: float = 0.0  # host-side batch packing (numpy staging)
-    time_device: float = 0.0  # jitted call + device sync (incl. dispatch)
+    # The same readings feed engine_step_part_seconds_total, which splits
+    # them further (STEP_PARTS).
+    time_host_pack: float = 0.0  # row choice + numpy staging (plan + pack)
+    # unified/verify step: ENQUEUE time of the jitted call (the dispatch is
+    # asynchronous; a device sync only under cfg.instrument). Fused decode:
+    # the blocking read of the sampled tokens in _decode_process
+    time_device: float = 0.0
     time_device_decode: float = 0.0  # the decode-call share of time_device
-    time_postprocess: float = 0.0  # host output handling after device sync
+    # host handling after the dispatch; in the unified step this HOLDS the
+    # wait for the device (the np.asarray read in _sample_apply)
+    time_postprocess: float = 0.0
     n_unified_steps: int = 0
     n_decode_calls: int = 0  # fused decode calls PROCESSED (results applied)
     n_decode_dispatches: int = 0  # fused decode calls LAUNCHED; must equal
@@ -226,6 +287,10 @@ class LLMEngine:
         from llmd_tpu.obs.attribution import attach_phase_exporter
 
         attach_phase_exporter(self.flight, self.metrics.request_phase)
+        # every XLA compile of this process, step program or not
+        from llmd_tpu.obs.compiles import watch_xla_compiles
+
+        self._xla_compiles = watch_xla_compiles(self.metrics, self.flight)
         # decision plane, engine view (obs/decisions.py): spec-decode
         # economics folded per request at retirement. Chained after the
         # phase exporter (on_finish is a single slot). The knob is cached
@@ -327,6 +392,7 @@ class LLMEngine:
         self.latency_trace: deque[dict] = deque(maxlen=4096)
         self._key = jax.random.PRNGKey(seed)
         self._outputs: list[EngineOutput] = []
+        self._n_steps = 0  # step_num of the llmd.step annotation
         self._pending_decode: list[dict] = []  # in-flight pipelined decode calls
         # Device-resident decode steady state (PERF.md Lever 12): rotated
         # host-pack buffer sets — pipeline_depth+1 of them so the buffers a
@@ -767,67 +833,6 @@ class LLMEngine:
         self._embed_fn = _register("embed", jax.jit(_embed, **donate),
                                    attn="mixed")
 
-        # "attn" step-phase probe: a jitted attention-ONLY call at the live
-        # decode shape (real pool, layer-0 page tables), run every
-        # _attn_probe_every fused dispatches and observed into
-        # step_duration{phase="attn"} scaled by layers x k — an estimate of
-        # the fused call's attention share, directly comparable against the
-        # decode_dispatch samples (PERF.md roofline reconciliation). Sampled
-        # because a per-step device sync would serialize the pipelined
-        # dispatch path it is trying to measure.
-        dhp_kv = self.cache.shape[-1]
-        attn_probe_scale = ((cfg.mla_qk_nope_dim + cfg.mla_rope_dim) ** -0.5
-                            if cfg.is_mla else cfg.head_dim ** -0.5)
-
-        def _attn_probe(cache, page_tables, kv_lens):
-            q = jnp.zeros((B, cfg.num_heads, dhp_kv), cfg.jax_dtype)
-            return attn_decode(
-                q, cache, page_tables, kv_lens - 1,
-                jnp.arange(B, dtype=jnp.int32), kv_lens,
-                scale=attn_probe_scale,
-                cu_q_lens=jnp.arange(B + 1, dtype=jnp.int32),
-                num_seqs=jnp.array([B], jnp.int32))
-
-        self._attn_probe_fn = jax.jit(_attn_probe)
-        self._attn_probe_every = 64
-        self._attn_probe_warm = False
-
-        # MoE step-phase probe (sorted path only): jitted dispatch / experts /
-        # combine stage calls at the fused-decode token shape, sampled on the
-        # same cadence as the attn probe and observed into
-        # step_duration{phase="moe_dispatch"|"moe_experts"|"moe_combine"}
-        # scaled by layers x k. This is the DBO measurement surface: the
-        # dispatch sample bounds the all-to-all/permute wall a half-batch can
-        # hide behind the other half's expert GEMMs (experts sample), so the
-        # overlap claim is read off the phase ledger instead of asserted.
-        self._moe_probe_fns = None
-        self._moe_probe_warm = False
-        if cfg.is_moe and self.moe_dispatch == "sorted":
-            from llmd_tpu.ops import moe_dispatch as moe_dispatch_ops
-
-            probe_S = (self._eplb_slots if self._eplb is not None
-                       else cfg.moe_num_experts)
-            probe_pallas = self.moe_backend == "pallas_grouped_gemm"
-            probe_bc = moe_dispatch_ops.pick_block_size(
-                B * cfg.moe_top_k, probe_S, probe_pallas)
-
-            def _moe_dispatch_probe(x, idx, topw, valid):
-                return moe_dispatch_ops.dispatch_stage(
-                    x, idx, topw, valid, probe_S, probe_bc)
-
-            def _moe_experts_probe(xs, block_slot, block_rows, wi, wo,
-                                   wi_scale, wo_scale):
-                return moe_dispatch_ops.experts_stage(
-                    xs, block_slot, block_rows, wi, wo, wi_scale, wo_scale,
-                    use_pallas=probe_pallas,
-                    interpret=self._pallas_interpret)
-
-            def _moe_combine_probe(ye, row, tok, wf):
-                return moe_dispatch_ops.combine_stage(ye, row, tok, wf, B)
-
-            self._moe_probe_fns = (jax.jit(_moe_dispatch_probe),
-                                   jax.jit(_moe_experts_probe),
-                                   jax.jit(_moe_combine_probe))
         # SP long-context prefill: a second unified program whose attention is
         # the zig-zag ring over the sp axis (ops/ring_attention.py), engaged
         # host-side for self-contained single-sequence prefill steps only —
@@ -1562,24 +1567,50 @@ class LLMEngine:
         sequence is prefilling or a constrained row needs the unified
         degrade, speculative verify when spec_mode="ngram", fused decode
         otherwise)."""
-        self._outputs = []
-        if self.offload is not None:
-            self._offload_drain()
-        self._try_admit()
-        self.programs.route(self).run(self)
-        self.stats.num_waiting = sum(len(q) for q in self.waitq)
-        self.stats.num_running = sum(1 for s in self.running if s is not None)
-        self.stats.kv_utilization = (
-            sum(a.num_active for a in self.allocs) / max(1, self.cfg.num_pages))
-        m = self.metrics
-        m.requests_waiting.set(self.stats.num_waiting)
-        m.requests_running.set(self.stats.num_running)
-        m.kv_usage.set(self.stats.kv_utilization)
-        m.batch_occupancy.labels(kind="running").observe(self.stats.num_running)
-        m.batch_occupancy.labels(kind="waiting").observe(self.stats.num_waiting)
-        if self._eplb is not None:
-            self._eplb_tick()
-        return self._outputs
+        self._n_steps += 1
+        with jax.profiler.StepTraceAnnotation("llmd.step",
+                                              step_num=self._n_steps):
+            self._outputs = []
+            if self.offload is not None:
+                self._offload_drain()
+            with jax.profiler.TraceAnnotation("llmd.admit"):
+                self._try_admit()
+            with jax.profiler.TraceAnnotation("llmd.route"):
+                program = self.programs.route(self)
+            program.run(self)
+            self.stats.num_waiting = sum(len(q) for q in self.waitq)
+            self.stats.num_running = sum(
+                1 for s in self.running if s is not None)
+            self.stats.kv_utilization = (
+                sum(a.num_active for a in self.allocs)
+                / max(1, self.cfg.num_pages))
+            m = self.metrics
+            m.requests_waiting.set(self.stats.num_waiting)
+            m.requests_running.set(self.stats.num_running)
+            m.kv_usage.set(self.stats.kv_utilization)
+            m.batch_occupancy.labels(kind="running").observe(
+                self.stats.num_running)
+            m.batch_occupancy.labels(kind="waiting").observe(
+                self.stats.num_waiting)
+            if self._eplb is not None:
+                self._eplb_tick()
+            now = time.perf_counter()
+            for out in self._outputs:
+                out.t_step = now
+            return self._outputs
+
+    def _book_parts(self, parts: _StepParts) -> float:
+        """Close ``parts`` and add its seconds to the part counter; returns
+        their sum, which is what the phase's step_duration sample must be so
+        that the two agree."""
+        parts.to(None)
+        total = 0.0
+        for part, sec in parts.seconds.items():
+            if sec:
+                self.metrics.step_part_seconds.labels(
+                    program=parts.program, part=part).inc(sec)
+                total += sec
+        return total
 
     # ------------------------------------------------- step-program run hooks
     # Eligibility predicates + run hooks for the routable registry entries.
@@ -1616,14 +1647,16 @@ class LLMEngine:
         self._flush_pending_sample()
         self._step_decode()
 
-    def _emit_step_spans(self, phase: str, seqs: list[Sequence],
+    def _emit_step_spans(self, phase: str, seqs,
                          start_ns: int, batch_size: int, n_tokens: int) -> None:
         """Emit one `engine.step` child span per traced sequence in the batch,
         parented on the request span context carried in via add_request — the
-        engine's step work shows up nested under `engine.generate`."""
+        engine's step work shows up nested under `engine.generate`. ``seqs``
+        is any iterable (callers pass a generator, so a disabled tracer costs
+        no walk of the batch)."""
         tracer = self.tracer
-        if tracer is None:
-            return
+        if tracer is None or not tracer.cfg.enabled:
+            return  # no exporter: nothing would leave, so walk no batch
         for s in seqs:
             ctx = s.trace_ctx
             if ctx is None or not getattr(ctx, "sampled", False):
@@ -1681,11 +1714,13 @@ class LLMEngine:
             and s.num_computed >= s.prompt_len
         ]
 
-    @_profile_phase("llmd.unified")
-    def _step_unified(self) -> None:
+    @_step_phase("unified", "llmd.unified", "plan")
+    def _step_unified(self, parts: _StepParts) -> None:
         """Pack decode tokens + prefill chunks (across sequences) into the flat
-        token budget and run ONE compiled step."""
-        t0 = time.perf_counter()
+        token budget and run ONE compiled step. ``parts`` splits its host
+        time: plan (row choice, pages, preemption), pack (numpy staging),
+        dispatch (transfers + the asynchronous jitted call), apply (per-row
+        state), sample, wait (the blocking read of sampled tokens), book."""
         t0_ns = time.time_ns()
         NT = self.cfg.batched_tokens
         B = self.cfg.max_batch_size
@@ -1743,6 +1778,7 @@ class LLMEngine:
             self._flush_pending_sample()
             return
 
+        parts.to("pack")
         toks = np.zeros((NT,), np.int32)
         pos = np.full((NT,), -1, np.int32)
         sids = np.zeros((NT,), np.int32)
@@ -1760,8 +1796,11 @@ class LLMEngine:
             mm_embeds = np.zeros((NT, self.model_cfg.hidden_size), np.float32)
             mm_mask = np.zeros((NT,), np.bool_)
         off = 0
+        first_chunks: list[Sequence] = []
         for i, (s, n, is_decode) in enumerate(plan):
             start = len(s.token_ids) - 1 if is_decode else s.num_computed
+            if not is_decode and start == s.num_cached_prompt:
+                first_chunks.append(s)
             toks[off : off + n] = s.token_ids[start : start + n]
             pos[off : off + n] = np.arange(start, start + n)
             sids[off : off + n] = i
@@ -1783,7 +1822,11 @@ class LLMEngine:
             cu[i + 1] = off
         cu[len(plan) + 1 :] = off
 
-        t1 = time.perf_counter()
+        parts.to("dispatch")
+        for s in first_chunks:
+            # a (re)prefill's first chunk goes to the device now: the
+            # ledger's schedule phase ends here
+            self.flight.record(s.request_id, "dispatched")
         mm_args = ((jnp.asarray(mm_embeds), jnp.asarray(mm_mask)) if is_vl else ())
         # ring-eligible: ONE fresh self-contained prefill chunk at offset 0
         # (positions 0..n-1, no prior KV) — the only regime where causality by
@@ -1795,6 +1838,11 @@ class LLMEngine:
                 and pos[0] == 0 and not is_vl):
             step_fn, step_prog = self._unified_ring_fn, "unified_ring"
             self.stats.n_ring_prefill_steps += 1
+        parts.program = step_prog
+        kv_read_tokens = int(lens[: len(plan)].sum())
+        self.metrics.program_kv_read_tokens.labels(program=step_prog).inc(
+            kv_read_tokens)
+        self.metrics.program_rows.labels(program=step_prog).inc(len(plan))
         # synchronous program: the postprocess below consumes the logits this
         # same step, so dispatch and completion are recorded together
         self.programs.record_dispatch(step_prog)
@@ -1807,7 +1855,7 @@ class LLMEngine:
         if self.cfg.instrument:
             # llmd-lint: allow[hot-host-sync] instrument-gated timing barrier; off in production serving
             logits.block_until_ready()
-        t2 = time.perf_counter()
+        parts.to("apply")
         if self._eplb is not None:
             self._eplb_record(cnt)
         if self.model_cfg.is_moe:
@@ -1861,22 +1909,27 @@ class LLMEngine:
         # Mixed steps with decode rows apply synchronously: a deferred decode
         # row would sit out the following step, stalling steady-state ITL.
         prev, self._pending_sample = self._pending_sample, None
+        parts.to("sample")
         bias = self._build_bias(sample_list, logits.shape) if sample_list else None
         rec = (self._sample_dispatch(sample_list, logits, bias=bias)
                if sample_list else None)
         if prev is not None:
-            self._sample_apply(prev)
+            self._sample_apply(prev, parts)
         if rec is not None:
             if self.cfg.pipeline_prefill_sample and not has_decode_rows:
                 self._pending_sample = rec
             else:
-                self._sample_apply(rec)
-        t3 = time.perf_counter()
+                self._sample_apply(rec, parts)
+        parts.to("book")
+        sec = parts.seconds
+        host_pack = sec["plan"] + sec["pack"]
+        post = sec["apply"] + sec["sample"] + sec["wait"]
+        wall = host_pack + sec["dispatch"] + post
         st = self.stats
-        st.time_host_pack += t1 - t0
-        st.time_device += t2 - t1
-        st.time_postprocess += t3 - t2
-        st.time_prefill_steps += t3 - t0
+        st.time_host_pack += host_pack
+        st.time_device += sec["dispatch"]
+        st.time_postprocess += post
+        st.time_prefill_steps += wall
         st.n_unified_steps += 1
         n_dec = sum(1 for _, _, d in plan if d)
         n_pre = sum(n for _, n, d in plan if not d)
@@ -1884,8 +1937,6 @@ class LLMEngine:
             self.metrics.decode_tokens.inc(n_dec)
         if n_pre:
             self.metrics.prefill_tokens.inc(n_pre)
-        self.metrics.step_duration.labels(phase="unified").observe(
-            t3 - t0, exemplar=self._trace_exemplar([s for s, _, _ in plan]))
         if self.util is not None:
             # analytic cost from the PACKED shape: the program computes all
             # NT positions (padding included); KV reads ≈ one pass over each
@@ -1893,18 +1944,23 @@ class LLMEngine:
             # chunked prefill), writes = the real positions landed
             cost = self.util.cost(
                 step_prog, slot_tokens=NT, weight_passes=1,
-                kv_read_tokens=int(lens[: len(plan)].sum()),
-                kv_write_tokens=off)
+                kv_read_tokens=kv_read_tokens, kv_write_tokens=off)
             self.util.record(
-                step_prog, cost, t3 - t0,
+                step_prog, cost, wall,
                 committed=n_dec + n_pre - util_recompute,
                 preempted_recompute=util_recompute,
                 prefix_saved=util_saved,
                 compile_counts=self.programs.compile_counts())
-        self._emit_step_spans("unified", [s for s, _, _ in plan], t0_ns,
+        self._emit_step_spans("unified", (s for s, _, _ in plan), t0_ns,
                               len(plan), n_pre + n_dec)
+        # last, and from the parts' own sum, so that the histogram and the
+        # part counter cover the same seconds (bookkeeping included)
+        self.metrics.step_duration.labels(phase="unified").observe(
+            self._book_parts(parts),
+            exemplar=self._trace_exemplar([s for s, _, _ in plan]))
 
-    def _step_decode(self) -> None:
+    @_step_phase("decode", "llmd.decode_dispatch", "plan", outer=False)
+    def _step_decode(self, parts: _StepParts) -> None:
         """Fused multi-step decode with pipelined dispatch.
 
         Reading sampled tokens costs a host<->device round trip per call
@@ -1914,10 +1970,14 @@ class LLMEngine:
         N's results while N+1 runs — vLLM's async output processing, XLA-style.
         The chain holds only while the active set is unchanged; any membership
         change (finish, preemption, new prefill) flushes first.
+
+        ``parts`` times the dispatch side (plan here, the rest in
+        ``_decode_dispatch``); it is paused around every nested program,
+        which keeps its own.
         """
-        t0 = time.perf_counter()
         active = self._decode_ready()
         if not active:
+            parts.to(None)
             self._flush_pending_decode()
             return
         B = self.cfg.max_batch_size
@@ -1941,6 +2001,7 @@ class LLMEngine:
                     self.cfg.max_model_len - (len(s.token_ids) + off))
                 for s in active)
             if horizon <= 0:
+                parts.to(None)
                 self._decode_process(q.pop(0))
                 return
 
@@ -1954,6 +2015,7 @@ class LLMEngine:
             for s in active if s.slot >= 0
         )
         if not ok:
+            parts.to(None)
             self._flush_pending_decode()
             self._step_unified()
             return
@@ -1965,6 +2027,7 @@ class LLMEngine:
             # raced out of fused-mask eligibility (a preemption above changed
             # the batch): degrade like the pool-pressure path rather than
             # letting a constrained row decode unmasked
+            parts.to(None)
             self._flush_pending_decode()
             self._step_unified()
             return
@@ -1973,7 +2036,7 @@ class LLMEngine:
             same = {(s.request_id, s.slot) for s in active} == {
                 (s.request_id, slot) for s, slot in q[-1]["rows"]}
             if same and self.cfg.pipeline_decode:
-                rec = self._decode_dispatch(active, k, chain=q[-1], wall_start=t0,
+                rec = self._decode_dispatch(active, k, chain=q[-1], parts=parts,
                                             off=off)
                 q.append(rec)
                 # keep up to pipeline_depth calls in flight: the queued call
@@ -1982,12 +2045,14 @@ class LLMEngine:
                 if len(q) > max(1, self.cfg.pipeline_depth):
                     self._decode_process(q.pop(0))
                 return
+            parts.to(None)
             self._flush_pending_decode()
+            parts.to("plan")
             q = self._pending_decode  # flush rebinds the queue — drop the stale ref
             active = [s for s in self._decode_ready() if s.slot >= 0]
             if not active:
                 return
-        rec = self._decode_dispatch(active, k, chain=None, wall_start=t0)
+        rec = self._decode_dispatch(active, k, chain=None, parts=parts)
         if self.cfg.pipeline_decode:
             q.append(rec)
         else:
@@ -2340,7 +2405,7 @@ class LLMEngine:
                 committed=n_tokens,
                 spec_rejected=self.stats.spec_rejected - spec_rej0,
                 compile_counts=self.programs.compile_counts())
-        self._emit_step_spans("spec_verify", [s for s, _, _, _ in rows], t0_ns,
+        self._emit_step_spans("spec_verify", (s for s, _, _, _ in rows), t0_ns,
                               len(plan), n_tokens)
 
     def _spec_release_tail(self, s: Sequence) -> None:
@@ -2543,15 +2608,14 @@ class LLMEngine:
         if not self._pack_bufs:
             B = self.cfg.max_batch_size
             self._pack_bufs = [
-                {"steps_left": np.zeros((B,), np.int32),
-                 "lens": np.ones((B,), np.int32)}
+                {"steps_left": np.zeros((B,), np.int32)}
                 for _ in range(max(1, self.cfg.pipeline_depth) + 1)]
         return self._pack_bufs[
             self.stats.n_decode_dispatches % len(self._pack_bufs)]
 
     @_profile_phase("llmd.decode_dispatch")
     def _decode_dispatch(self, active: list[Sequence], k: int, chain: Optional[dict],
-                         wall_start: float, off: int = 0) -> dict:
+                         parts: _StepParts, off: int = 0) -> dict:
         """Pack host state (+ the un-processed offset across ALL in-flight calls)
         and launch one fused k-step decode chained on ``chain``'s device-resident
         outputs. Returns the in-flight record; results are NOT read.
@@ -2569,17 +2633,19 @@ class LLMEngine:
         """
         B = self.cfg.max_batch_size
         fast = chain is not None and self.cfg.pack_overlap
+        # the fast path's pack already sits in llmd.pack_overlap
+        parts.to("pack", annotate=not fast)
+        ctx_tokens = 0  # context the call's first step reads, over its rows
         if fast:
             with jax.profiler.TraceAnnotation("llmd.pack_overlap"):
-                bufs = self._pack_buf()
-                steps_left, lens_np = bufs["steps_left"], bufs["lens"]
+                steps_left = self._pack_buf()["steps_left"]
                 steps_left.fill(0)
                 sig = chain["pages_sig"]
                 pages_changed = False
                 for j, s in enumerate(active):
                     i = s.slot
                     eff_len = len(s.token_ids) + off  # host view + in-flight
-                    lens_np[i] = eff_len  # probe-only on this path (no upload)
+                    ctx_tokens += eff_len
                     gen = eff_len - s.prompt_len
                     steps_left[i] = max(0, min(s.max_tokens - gen,
                                                self.cfg.max_model_len - eff_len,
@@ -2618,6 +2684,7 @@ class LLMEngine:
             for s in active:
                 i = s.slot
                 eff_len = len(s.token_ids) + off  # host view + in-flight tokens
+                ctx_tokens += eff_len
                 toks[i] = s.token_ids[-1]  # unused when chaining (device wins)
                 pos[i] = eff_len - 1
                 pts_np[i, : len(s.pages)] = s.pages
@@ -2648,17 +2715,18 @@ class LLMEngine:
                     self.flight.record(s.request_id, "chain_dispatch", k=k,
                                        masked=mask is not None)
         self._key, sub = jax.random.split(self._key)
-        t1 = time.perf_counter()
+        parts.to("dispatch")
+        sec = parts.seconds
+        host_pack = sec["plan"] + sec["pack"]
         if fast:
             # the device is still executing chain N while this pack ran: its
             # wall is hidden, not serialized — keep time_host_pack honest
-            self.stats.time_pack_overlap += t1 - wall_start
+            self.stats.time_pack_overlap += host_pack
             self.metrics.step_duration.labels(phase="pack_overlap").observe(
-                t1 - wall_start)
+                host_pack)
         else:
-            self.stats.time_host_pack += t1 - wall_start
-            self.metrics.step_duration.labels(phase="pack").observe(
-                t1 - wall_start)
+            self.stats.time_host_pack += host_pack
+            self.metrics.step_duration.labels(phase="pack").observe(host_pack)
         if mask is not None:
             (toks_out, last_toks, pos_out, lens_out, fsm_out, self.cache,
              cnt, moe_drop) = self._decode_multi_masked_fn(
@@ -2675,24 +2743,15 @@ class LLMEngine:
                     lora_dev,
                 ))
             fsm_out = None
-        self.stats.time_decode_steps += time.perf_counter() - wall_start
+        parts.to("book")
+        self.stats.time_decode_steps += host_pack + sec["dispatch"]
         self.stats.n_decode_dispatches += 1
-        prog = "decode" if mask is None else "decode_masked"
+        prog = parts.program = "decode" if mask is None else "decode_masked"
         self.programs.record_dispatch(prog)
+        self.metrics.program_kv_read_tokens.labels(program=prog).inc(ctx_tokens)
+        self.metrics.program_rows.labels(program=prog).inc(len(active))
         if chain is not None:
             self.stats.n_chained_dispatches += 1
-        self.metrics.step_duration.labels(phase="decode_dispatch").observe(
-            time.perf_counter() - wall_start,
-            exemplar=self._trace_exemplar(active))
-        # first probe at dispatch _attn_probe_every, not 1: serving engines
-        # reach it in seconds, while short-lived engines (tests, tiny bench)
-        # never pay the probe's one-off compile
-        if (self._attn_probe_fn is not None
-                and self.stats.n_decode_dispatches % self._attn_probe_every == 0):
-            self._observe_attn_phase(pts_np, lens_np, k)
-        if (self._moe_probe_fns is not None
-                and self.stats.n_decode_dispatches % self._attn_probe_every == 0):
-            self._observe_moe_phase(k)
         # Start the device->host copy of everything _decode_process will read:
         # the tokens land on the host while the host loop does other work, so
         # the later np.asarray is a near-free read instead of a blocking one.
@@ -2715,9 +2774,11 @@ class LLMEngine:
         if self.util is not None:
             util_cost = self.util.cost(
                 prog, slot_tokens=B * k, weight_passes=k,
-                kv_read_tokens=k * int(sum(int(lens_np[s.slot])
-                                           for s in active)),
+                kv_read_tokens=k * ctx_tokens,
                 kv_write_tokens=int(steps_left.sum()))
+        # last, and from the parts' own sum (see _step_unified)
+        self.metrics.step_duration.labels(phase="decode_dispatch").observe(
+            self._book_parts(parts), exemplar=self._trace_exemplar(active))
         return {
             "util_cost": util_cost,
             "rows": [(s, s.slot) for s in active], "prog": prog,
@@ -2730,83 +2791,11 @@ class LLMEngine:
             "tp_dev": tp_dev, "lora_dev": lora_dev,
         }
 
-    def _observe_attn_phase(self, pts: np.ndarray, lens: np.ndarray, k: int) -> None:
-        """Sampled attention-share probe: time one attention-only jitted call at
-        the shapes the dispatch just ran, observe wall x layers x k as the
-        estimated attention share of a fused decode call. The first invocation
-        compiles and is discarded (a compile sample would dominate the
-        histogram); a probe failure disables further probes rather than
-        degrading serving — the step itself already ran."""
-        try:
-            args = (self.cache, jnp.asarray(pts), jnp.asarray(lens))
-            if not self._attn_probe_warm:
-                self._attn_probe_fn(*args).block_until_ready()
-                self._attn_probe_warm = True
-            t0 = time.perf_counter()
-            self._attn_probe_fn(*args).block_until_ready()
-            dt = time.perf_counter() - t0
-            self.metrics.step_duration.labels(phase="attn").observe(
-                dt * self.model_cfg.num_layers * k)
-        except Exception:  # noqa: BLE001 — observability must not take down serving
-            self._attn_probe_fn = None
-
-    def _observe_moe_phase(self, k: int) -> None:
-        """Sampled MoE stage probe (sorted dispatch only): time the jitted
-        dispatch / experts / combine stage calls at the fused-decode token
-        shape against the live expert bank, observe each wall x layers x k
-        into its step_duration phase. Synthetic uniform routing — the probe
-        measures the stage mechanics (sort/scatter, grouped GEMM, inverse
-        permute), not this step's skew; EPLB load stats come from the real
-        counts. First call compiles and is discarded; failure disables the
-        probe, never serving."""
-        try:
-            p = self._run_params()
-            if "moe_wi_q" in p:
-                wi, wo = p["moe_wi_q"][0], p["moe_wo_q"][0]
-                wi_s, wo_s = p["moe_wi_scale"][0], p["moe_wo_scale"][0]
-            else:
-                wi, wo = p["moe_wi"][0], p["moe_wo"][0]
-                wi_s = wo_s = None
-            cfg = self.model_cfg
-            B = self.cfg.max_batch_size
-            kk = cfg.moe_top_k
-            S = wi.shape[0]
-            x = jnp.zeros((B, cfg.hidden_size), cfg.jax_dtype)
-            idx = (jnp.arange(B * kk, dtype=jnp.int32) % S).reshape(B, kk)
-            topw = jnp.full((B, kk), 1.0 / kk, cfg.jax_dtype)
-            valid = jnp.ones((B, 1), jnp.int32)
-            fd, fe, fc = self._moe_probe_fns
-            if not self._moe_probe_warm:
-                staged = fd(x, idx, topw, valid)
-                ye = fe(staged[0], staged[4], staged[5], wi, wo, wi_s, wo_s)
-                fc(ye, staged[1], staged[2], staged[3]).block_until_ready()
-                self._moe_probe_warm = True
-            scale = cfg.num_layers * k
-            with jax.profiler.TraceAnnotation("llmd.moe_dispatch_probe"):
-                t0 = time.perf_counter()
-                staged = fd(x, idx, topw, valid)
-                jax.block_until_ready(staged)
-                self.metrics.step_duration.labels(phase="moe_dispatch").observe(
-                    (time.perf_counter() - t0) * scale)
-            xs, row, tok, wf, block_slot, block_rows = staged
-            with jax.profiler.TraceAnnotation("llmd.moe_experts_probe"):
-                t0 = time.perf_counter()
-                ye = fe(xs, block_slot, block_rows, wi, wo, wi_s, wo_s)
-                ye.block_until_ready()
-                self.metrics.step_duration.labels(phase="moe_experts").observe(
-                    (time.perf_counter() - t0) * scale)
-            with jax.profiler.TraceAnnotation("llmd.moe_combine_probe"):
-                t0 = time.perf_counter()
-                fc(ye, row, tok, wf).block_until_ready()
-                self.metrics.step_duration.labels(phase="moe_combine").observe(
-                    (time.perf_counter() - t0) * scale)
-        except Exception:  # noqa: BLE001 — observability must not take down serving
-            self._moe_probe_fns = None
-
-    @_profile_phase("llmd.decode_process")
-    def _decode_process(self, rec: dict) -> None:
-        """Read one in-flight decode call's results and apply them to host state."""
-        t1 = time.perf_counter()
+    @_step_phase("decode", "llmd.decode_process", "wait")
+    def _decode_process(self, parts: _StepParts, rec: dict) -> None:
+        """Read one in-flight decode call's results and apply them to host
+        state: wait (the blocking read), apply (per row), book."""
+        parts.program = rec["prog"]
         t1_ns = time.time_ns()
         n_tokens = 0
         if self._eplb is not None:
@@ -2817,7 +2806,7 @@ class LLMEngine:
             # the async copy was started at dispatch; toks_out above already
             # paid this step's sync, so the drop scalar read is free
             self._moe_record_dropped(rec["moe_drop"])
-        t2 = time.perf_counter()
+        parts.to("apply")
         now = time.monotonic()
         for s, slot in rec["rows"]:
             if s.finished or s.slot != slot or self.running[slot] is not s:
@@ -2864,27 +2853,40 @@ class LLMEngine:
                 finish_reason=reason, num_cached_prompt_tokens=s.num_cached_prompt,
                 prompt_len=s.prompt_len,
             ))
-        t3 = time.perf_counter()
+        parts.to("book")
+        sec = parts.seconds
+        wall = sec["wait"] + sec["apply"]
         st = self.stats
-        st.time_device += t2 - t1
-        st.time_device_decode += t2 - t1
-        st.time_postprocess += t3 - t2
-        st.time_decode_steps += t3 - t1
+        st.time_device += sec["wait"]
+        st.time_device_decode += sec["wait"]
+        st.time_postprocess += sec["apply"]
+        st.time_decode_steps += wall
         st.n_decode_calls += 1
         self.programs.record_complete(rec["prog"])
         if n_tokens:
             self.metrics.decode_tokens.inc(n_tokens)
-        self.metrics.step_duration.labels(phase="decode_process").observe(
-            t3 - t1, exemplar=self._trace_exemplar([s for s, _ in rec["rows"]]))
+        # the call ran k steps on every seat: tokens kept, step-slots of rows
+        # whose sequence finished before the k-th step or left in flight,
+        # and the slots of seats that held no row
+        k, n_rows = rec["k"], len(rec["rows"])
+        seats = self.metrics.decode_seat_steps
+        seats.labels(outcome="kept").inc(n_tokens)
+        seats.labels(outcome="finished").inc(k * n_rows - n_tokens)
+        seats.labels(outcome="empty").inc(
+            k * (self.cfg.max_batch_size - n_rows))
         if self.util is not None and rec.get("util_cost") is not None:
             # kept tokens commit; everything else the B x k scan computed
             # (masked slots, post-EOS steps, rows preempted in flight) is the
             # padding residual
             self.util.record(
-                rec["prog"], rec["util_cost"], t3 - t1, committed=n_tokens,
+                rec["prog"], rec["util_cost"], wall, committed=n_tokens,
                 compile_counts=self.programs.compile_counts())
-        self._emit_step_spans("decode", [s for s, _ in rec["rows"]], t1_ns,
+        self._emit_step_spans("decode", (s for s, _ in rec["rows"]), t1_ns,
                               len(rec["rows"]), n_tokens)
+        # last, and from the parts' own sum (see _step_unified)
+        self.metrics.step_duration.labels(phase="decode_process").observe(
+            self._book_parts(parts),
+            exemplar=self._trace_exemplar([s for s, _ in rec["rows"]]))
 
     def _retire(self, seq: Sequence, reason: Optional[str]) -> None:
         """Shared retirement path: free slot + pages, drop from the live map."""
@@ -3044,12 +3046,23 @@ class LLMEngine:
     def _flush_pending_sample(self) -> None:
         rec, self._pending_sample = self._pending_sample, None
         if rec is not None:
-            self._sample_apply(rec)
+            # outside a unified step (a decode or verify program flushes the
+            # last prefill's sample first): the read is still the unified
+            # program's, booked under program="sample" since no unified
+            # step_duration sample covers it
+            parts = _StepParts("sample", "llmd.unified", None)
+            try:
+                self._sample_apply(rec, parts)
+            finally:
+                self._book_parts(parts)
 
-    def _sample_apply(self, rec: dict) -> None:
-        """Read one dispatched sample's tokens (device sync point) and apply."""
+    def _sample_apply(self, rec: dict, parts: _StepParts) -> None:
+        """Read one dispatched sample's tokens (device sync point) and apply;
+        the read alone is ``parts``' wait, the per-row loop its apply."""
+        parts.to("wait")
         # llmd-lint: allow[hot-host-sync] designed sync point: deferred sample readback, overlapped with the next dispatch
         sampled = np.asarray(rec["sampled"])
+        parts.to("apply")
         self.programs.record_complete("sample")
         now = time.monotonic()
         for i, s, slot in rec["rows"]:

@@ -763,6 +763,7 @@ class EngineServer:
             })
             await resp.prepare(request)
             n_out = 0
+            lag, first = self.server_metrics.stream_lag, True
             async for out in gen:
                 piece = self.tokenizer.decode(out.new_token_ids)
                 n_out += len(out.new_token_ids)
@@ -784,6 +785,15 @@ class EngineServer:
                         "cached_tokens": out.num_cached_prompt_tokens,
                     }
                 await resp.write(f"data: {json.dumps(chunk)}\n\n".encode())
+                # step's end -> chunk written, for the first and the last
+                # chunk only (one chunk can be both)
+                if first:
+                    first = False
+                    lag.labels(at="first").observe(
+                        time.perf_counter() - out.t_step)
+                if out.finished:
+                    lag.labels(at="last").observe(
+                        time.perf_counter() - out.t_step)
             await resp.write(b"data: [DONE]\n\n")
             await resp.write_eof()
             span.set_attribute("llm_d.completion_tokens", n_out)
@@ -1133,9 +1143,11 @@ class EngineServer:
         return web.json_response(payload, status=status)
 
     async def _debug_profile(self, request: web.Request):
-        """GET /debug/profile?seconds=N — capture one jax.profiler window
-        into LLMD_PROFILE_DIR and describe the artifact. One at a time (409
-        while busy); the capture blocks in an executor, not on the loop."""
+        """GET /debug/profile?seconds=N[&python_tracer=0] — capture one
+        jax.profiler window into LLMD_PROFILE_DIR and describe the artifact
+        (with the two llmd.clock marks). One at a time (409 while busy); the
+        capture blocks in an executor, not on the loop. python_tracer=0
+        leaves the Python-frame tracer off (default on, as before)."""
         from llmd_tpu.obs.device import ProfileBusy
 
         mon = getattr(self.engine, "monitor", None)
@@ -1145,12 +1157,14 @@ class EngineServer:
                 status=503)
         try:
             seconds = float(request.query.get("seconds", "2"))
+            python_tracer = bool(int(request.query.get("python_tracer", "1")))
         except ValueError:
             return web.json_response(
-                {"error": {"message": "seconds must be numeric"}}, status=400)
+                {"error": {"message": "seconds and python_tracer must be "
+                                      "numeric"}}, status=400)
         try:
             result = await asyncio.get_running_loop().run_in_executor(
-                None, mon.capture_profile, seconds)
+                None, mon.capture_profile, seconds, python_tracer)
         except ProfileBusy as e:
             return web.json_response(
                 {"error": {"message": str(e)}}, status=409)

@@ -5,7 +5,7 @@ this module answers "where did the time GO". At retire, each request's event
 timeline is folded into a phase ledger — queue_wait, flow, schedule, retry,
 hedge, kv_pull, prefill, decode (serialized) vs decode_overlap (host pack
 hidden behind the in-flight device call), chain_stage, spec, preempted,
-upstream — whose entries sum to the wall clock **by construction**: every
+upstream, upstream_stream — whose entries sum to the wall clock **by construction**: every
 inter-event interval is attributed to exactly one phase, and anything the
 transition maps don't recognize lands in ``unattributed``. The residual is
 therefore a real series, not a rounding artifact: a growing unattributed
@@ -30,10 +30,12 @@ __all__ = ["PHASES", "build_ledger", "attach_phase_exporter"]
 PHASES = (
     "flow",           # router: parse + flow-control admission bookkeeping
     "queue_wait",     # router flow queue / engine waiting queue
-    "schedule",       # scheduler pick / admission → first compute
+    "schedule",       # scheduler pick / admission → first chunk dispatched
     "retry",          # router: backoff + re-pick after a failed attempt
     "hedge",          # router: racing a hedged second attempt
-    "upstream",       # router: time spent inside the forwarded engine call
+    "upstream",       # router: forwarded call until its first byte (or, not
+                      # streamed, its whole body)
+    "upstream_stream",  # router: first byte → last byte of a streamed answer
     "kv_pull",        # cross-engine prefix pull ahead of admission
     "prefill",        # prompt computation
     "decode",         # serialized decode steps (host pack on the hot path)
@@ -47,8 +49,8 @@ PHASES = (
 # Events only the router plane emits — their presence selects the router
 # transition map (the two planes share "arrival" with different meanings).
 _ROUTER_ONLY = {"flow_enqueue", "flow_dispatch", "flow_reject",
-                "routing_decision", "kv_pull_stamped", "forward", "response",
-                "retry", "hedge", "slo_breach"}
+                "routing_decision", "kv_pull_stamped", "forward", "first_byte",
+                "response", "retry", "hedge", "slo_breach"}
 
 _TERMINAL = {"response", "rejected", "error", "retired", "aborted"}
 
@@ -60,6 +62,7 @@ _ROUTER_MAP = {
     "routing_decision": "schedule",
     "kv_pull_stamped": "schedule",
     "forward": "upstream",
+    "first_byte": "upstream_stream",
     "retry": "retry",
     "hedge": "upstream",
     "deadline_exceeded": "unattributed",
@@ -72,6 +75,7 @@ _ENGINE_MAP = {
     "kv_pull": "queue_wait",
     "kv_reload": "schedule",
     "admitted": "schedule",
+    "dispatched": "prefill",
     "prefill_start": "prefill",
     "prefill_end": "prefill",
     "first_token": "decode",

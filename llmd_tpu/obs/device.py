@@ -29,11 +29,14 @@ contracts the rest of the stack already uses:
   replica retirement, no new control-plane machinery.
 * **Profiler capture** — ``capture_profile(seconds)`` wraps
   ``jax.profiler.start_trace``/``stop_trace`` into ``LLMD_PROFILE_DIR`` (one
-  capture at a time; the server returns 409 while busy). The engine step loop
-  is annotated per phase (``llmd.unified`` / ``llmd.decode_dispatch`` /
-  ``llmd.decode_process`` / ``llmd.spec_verify`` / ``llmd.mask_build``) so a
-  capture attributes device time to the same phase names the step-duration
-  histogram exports.
+  capture at a time; the server returns 409 while busy). The engine loop and
+  step are annotated (``llmd.step`` > ``llmd.admit`` / ``llmd.route`` /
+  ``llmd.unified`` > ``llmd.unified.<part>`` / ``llmd.decode_dispatch`` /
+  ``llmd.decode_process`` / ``llmd.spec_verify`` / ``llmd.mask_build``, and
+  ``llmd.loop.*`` between steps; observability/device-plane.md lists them)
+  so a capture names host and device time by the same phases and parts the
+  step histogram and part counter export. Two ``llmd.clock`` marks carry the
+  wall and monotonic clocks onto the trace.
 
 Threading: the watchdog and telemetry threads never touch the engine lock (a
 hung ``step()`` holds it — that's the failure being detected). Pending work
@@ -72,6 +75,17 @@ def default_probe_op() -> None:
 
     x = jnp.ones((8, 8), dtype=jnp.float32)
     jax.block_until_ready(x * 2.0)
+
+
+def _clock_mark() -> dict:
+    """Write one ``llmd.clock`` annotation whose arguments are the wall and
+    the monotonic clock, read together; returns the same pair."""
+    import jax
+
+    mark = {"unix_ns": time.time_ns(), "mono_ns": time.monotonic_ns()}
+    with jax.profiler.TraceAnnotation("llmd.clock", **mark):
+        pass
+    return mark
 
 
 def _env_f(name: str, default: str) -> float:
@@ -309,13 +323,21 @@ class DeviceMonitor:
                     "fabric_dead", probe_timeout_s=self.probe_timeout_s)
 
     # ------------------------------------------------------------ profile
-    def capture_profile(self, seconds: float) -> dict:
+    def capture_profile(self, seconds: float,
+                        python_tracer: bool = True) -> dict:
         """Capture one ``jax.profiler`` window into ``profile_dir``.
 
         Blocking (the caller runs it in an executor); one capture at a time —
         a concurrent call raises :class:`ProfileBusy` and the server maps
-        that to 409. Returns ``{dir, files, bytes, seconds}`` describing the
-        artifact."""
+        that to 409. Returns ``{dir, files, bytes, seconds, python_tracer,
+        clock}`` describing the artifact. ``python_tracer=False`` leaves the
+        profiler's Python-frame tracer off: the ``llmd.*`` annotations and
+        the device planes are unaffected, and the host runs at nearly its
+        untraced speed. ``clock`` holds the two ``llmd.clock`` marks written
+        into the trace (``start`` and ``end``, each ``unix_ns`` and
+        ``mono_ns`` read together), so flight-recorder events
+        (``time.monotonic``) and OTel spans (``time_ns``) can be laid on the
+        trace's own clock."""
         seconds = max(0.1, min(float(seconds), 60.0))
         with self._lock:
             if self._profiling:
@@ -327,9 +349,15 @@ class DeviceMonitor:
                 self.profile_dir,
                 time.strftime("%Y%m%d-%H%M%S", time.gmtime()))
             os.makedirs(out_dir, exist_ok=True)
-            jax.profiler.start_trace(out_dir)
+            options = jax.profiler.ProfileOptions()
+            if not python_tracer:
+                options.python_tracer_level = 0
+            jax.profiler.start_trace(out_dir, profiler_options=options)
+            clock = {}
             try:
+                clock["start"] = _clock_mark()
                 time.sleep(seconds)
+                clock["end"] = _clock_mark()
             finally:
                 jax.profiler.stop_trace()
             files: List[str] = []
@@ -345,7 +373,8 @@ class DeviceMonitor:
                     "profile_capture", seconds=seconds, dir=out_dir,
                     files=len(files), bytes=total)
             return {"dir": out_dir, "files": sorted(files), "bytes": total,
-                    "seconds": seconds}
+                    "seconds": seconds, "python_tracer": python_tracer,
+                    "clock": clock}
         finally:
             with self._lock:
                 self._profiling = False

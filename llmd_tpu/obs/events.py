@@ -51,6 +51,7 @@ EVENT_CATALOG = (
     "route_decision",
     "kv_pull_stamped",
     "forward",
+    "first_byte",
     "response",
     "rejected",
     "error",
@@ -63,6 +64,7 @@ EVENT_CATALOG = (
     "slo_breach",
     # engine plane
     "admitted",
+    "dispatched",
     "prefill_start",
     "prefill_end",
     "first_token",
@@ -95,6 +97,7 @@ EVENT_CATALOG = (
     "fabric_dead",
     "fabric_recovered",
     "profile_capture",
+    "xla_compile",
 )
 
 _TERMINAL_STATUS = {"finished", "aborted", "rejected", "error"}

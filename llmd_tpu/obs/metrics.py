@@ -462,12 +462,57 @@ class EngineMetrics:
             "(unified, decode_dispatch, decode_process, spec_verify; pack = "
             "serialized host pack at a chain boundary, pack_overlap = chained "
             "fast-path pack hidden behind the in-flight device call, "
-            "chain_stage = dense grammar/bias table staging per chain; attn = "
-            "sampled attention-only probe scaled to the fused call: "
-            "wall x layers x k; moe_dispatch / moe_experts / moe_combine = "
-            "sampled MoE stage probes scaled the same way — the measured DBO "
-            "overlap evidence)",
+            "chain_stage = dense grammar/bias table staging per chain). "
+            "engine_step_part_seconds_total splits unified, decode_dispatch "
+            "and decode_process",
             labelnames=("phase",))
+        # The step loop from inside (PERF.md section 3): host seconds of each
+        # step program by part, from the same perf_counter readings as the
+        # llmd.<phase>.<part> profiler spans. Per program the parts sum to
+        # step_duration_sum of its phases (unified; decode_dispatch +
+        # decode_process for decode).
+        self.step_part_seconds = reg.counter(
+            "llmd_tpu:engine_step_part_seconds_total",
+            "Host wall seconds of a step program by part: plan (row choice, "
+            "pages, preemption), pack (numpy staging), dispatch (transfers + "
+            "the asynchronous jitted call), sample, wait (the blocking read "
+            "of sampled tokens: the device's share), apply (per-row state), "
+            "book (metrics, flight, utilisation). program=sample is a "
+            "deferred prefill sample read outside a unified step",
+            labelnames=("program", "part"))
+        self.loop_seconds = reg.counter(
+            "llmd_tpu:engine_loop_seconds_total",
+            "Wall seconds of the engine loop thread by part: lock (waiting "
+            "for the engine lock), step (has_work + step()), deliver (the "
+            "hand-off of outputs to their streams), idle (sleep with no work)",
+            labelnames=("part",))
+        self.outputs_delivered = reg.counter(
+            "llmd_tpu:engine_outputs_delivered_total",
+            "EngineOutputs the loop handed to a request stream")
+        self.decode_seat_steps = reg.counter(
+            "llmd_tpu:decode_seat_steps_total",
+            "Step-slots of fused decode calls (k steps x max_batch_size "
+            "seats each) by outcome: kept (a token the request got), "
+            "finished (the row's sequence ended before the k-th step, or "
+            "left while the call was in flight), empty (the seat held no row)",
+            labelnames=("outcome",))
+        self.program_kv_read_tokens = reg.counter(
+            "llmd_tpu:program_kv_read_tokens_total",
+            "Context tokens (KV positions) over the rows of each dispatch, "
+            "as the call's first step reads them",
+            labelnames=("program",))
+        self.program_rows = reg.counter(
+            "llmd_tpu:program_rows_total",
+            "Sequences (rows) packed into each dispatch",
+            labelnames=("program",))
+        self.xla_compiles = reg.counter(
+            "llmd_tpu:xla_compiles_total",
+            "XLA executables built or loaded from the compile cache by this "
+            "process (jax.monitoring backend-compile event): every jitted "
+            "function, registered step program or not")
+        self.xla_compile_seconds = reg.counter(
+            "llmd_tpu:xla_compile_seconds_total",
+            "Wall seconds of those compiles")
         self.attn_backend_info = reg.gauge(
             "llmd_tpu:engine_attn_backend",
             "Resolved attention backend + active block-size tune-table hash "
@@ -710,6 +755,15 @@ class EngineServerMetrics:
         self.requests = reg.counter(
             "llmd_tpu:requests_total",
             "Generation requests accepted by this frontend")
+        self.stream_lag = reg.histogram(
+            "llmd_tpu:stream_lag_seconds",
+            "End of the engine step that produced an output to its SSE chunk "
+            "written, for a streamed request's first and last chunk "
+            "(at=first|last): the loop's hand-off, the event loop's queue "
+            "and the socket write",
+            labelnames=("at",),
+            buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
+                     0.25, 0.5, 1.0, 2.5, 5.0))
         self.transfer = {
             key: reg.counter(
                 f"llmd_tpu:kv_transfer_{key}_total",

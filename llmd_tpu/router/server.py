@@ -1069,6 +1069,9 @@ class RouterServer:
                             self.metrics.ttft.observe(t_first - t_start,
                                                       exemplar=exemplar)
                             self._observe_slo(req, "ttft", t_first - t_start)
+                            # the ledger's upstream phase ends here: what
+                            # follows is upstream_stream, the relayed body
+                            self.flight.record(req.request_id, "first_byte")
                         n_chunks += 1
                         await out.write(chunk)
                     await out.write_eof()

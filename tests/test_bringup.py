@@ -15,6 +15,7 @@ import pytest
 from llmd_tpu import jax_init
 from llmd_tpu.core.request import SamplingParams
 from llmd_tpu.engine.async_engine import AsyncLLMEngine, EngineDeadError
+from llmd_tpu.obs.metrics import Registry, register_engine_metrics
 from tests.conftest import run_async
 
 
@@ -93,6 +94,7 @@ class _RaisingEngine:
     def __init__(self):
         self.seqs = {}
         self.monitor = None
+        self.metrics = register_engine_metrics(Registry())
 
     def add_request(self, rid, *a, **kw):
         self.seqs[rid] = object()

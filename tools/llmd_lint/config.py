@@ -44,7 +44,6 @@ HOT_PATHS: dict[str, object] = {
         "_check_finish",
         "_prefilling_seqs",
         "_prefill_target",
-        "_observe_attn_phase",
         "_emit_step_spans",
         "_trace_exemplar",
     ],
