@@ -461,8 +461,12 @@ def main() -> None:
                 for k, v in saved.items():
                     os.environ.pop(k, None) if v is None else os.environ.__setitem__(k, v)
 
-        candidates = [(8, 32), (max(1, maxp // 2), 32), (maxp, 32), (8, 16)]
-        default = candidates[0]
+        from llmd_tpu.ops.paged_attention import pick_block_sizes
+        # what an untuned engine runs at this shape comes first: the others
+        # must beat it by a margin
+        default = pick_block_sizes(B, ps, maxp)
+        candidates = list(dict.fromkeys(
+            [default, (8, 32), (max(1, maxp // 2), 32), (maxp, 32), (8, 16)]))
         results: dict = {}
         for bkv, bq in candidates:
             results[(bkv, bq)] = timed(bkv, bq)

@@ -537,13 +537,15 @@ class LLMEngine:
         moe_dispatch_impl = self._select_moe_dispatch()
         self.stats.attn_backend = self.attn_backend
         self.stats.attn_tune_hash = self.attn_tune_hash
+        self.attn_geometry = self._attn_geometry()
         self.stats.moe_backend = self.moe_backend
         self.stats.moe_dispatch = self.moe_dispatch
         # kernel-vs-fallback visibility without scraping logs: an info-style
         # gauge keyed by the resolved backend + tune-table hash (value 1)
         self.metrics.attn_backend_info.labels(
             backend=self.attn_backend,
-            tune=self.attn_tune_hash or "none").set(1)
+            tune=self.attn_tune_hash or "none",
+            geometry=self.attn_geometry).set(1)
         self.stats.kv_cache_dtype = ("fp8" if self.kv_dtype == jnp.float8_e4m3fn
                                      else str(jnp.dtype(self.kv_dtype).name))
         self.stats.kv_layout = (f"packed-{self.kv_pack}" if self.kv_pack > 1
@@ -893,6 +895,23 @@ class LLMEngine:
 
         self.attn_backend = "pallas_ragged_paged_attention"
         return functools.partial(paged_attention_tpu, mesh=self.mesh)
+
+    def _attn_geometry(self) -> str:
+        """The (bkv, bq) block geometry the ragged Pallas kernel is traced
+        with in the two step programs that carry the load, as
+        ``unified=<bkv>x<bq> decode=<bkv>x<bq>``; ``none`` where another
+        backend serves. It is a function of static shapes (and of the tune
+        table and overrides read at trace time), so it is known here."""
+        if not self.attn_backend.startswith("pallas_ragged_paged_attention"):
+            return "none"
+        from llmd_tpu.ops.paged_attention import call_geometry
+
+        return " ".join(
+            "{}={}x{}".format(prog, *call_geometry(
+                (n, self.model_cfg.num_heads, self.cache.shape[-1]),
+                self.cache.shape, self.cfg.max_pages_per_seq))
+            for prog, n in (("unified", self.cfg.batched_tokens),
+                            ("decode", self.cfg.max_batch_size)))
 
     # (the fused-decode attention-impl selector lives in
     # llmd_tpu.engine.programs.select_decode_attn_impl — it is step-program

@@ -245,7 +245,8 @@ def main() -> None:
         print(f"llmd-tpu engine serving {server.model_name} on http://{server.address} "
               f"(kv-events port {server.kv_events_port}){prov} "
               f"[attn={eng.attn_backend}, moe={eng.moe_backend}, "
-              f"moe_dispatch={eng.moe_dispatch}]", flush=True)
+              f"moe_dispatch={eng.moe_dispatch}] "
+              f"[attn_geometry {eng.attn_geometry}]", flush=True)
         await _serve_until_fatal(server.async_engine, server.stop)
 
     asyncio.run(run())

@@ -9,11 +9,26 @@ module owns the serving-stack integration:
 
 - the uniform attention-impl signature shared with the XLA-reference fallback
   (`models.transformer.ragged_paged_attention_xla`) so the engine can swap impls,
-- **block-size selection**: the upstream tuned table has no entry for every
-  (chip, shape) pair and its default (128 KV pages/block) is pathological for
-  decode — measured on v5e (llama-1b shapes, B=32, kv_len 384): default blocks
-  1,676 µs/layer vs 15-18 µs/layer with (bkv=8, bq=32). We clamp KV pages per
-  block to the sequence page budget and keep it small,
+- **block-size selection** (`pick_block_sizes`): how many KV pages one block
+  fetches (bkv) and how many query rows one block carries (bq). The upstream
+  tuned table has no entry for our shapes (16-token pages at model lengths of
+  4,096-8,192; decode batches of 64 rows), so the rule here comes from a sweep
+  on the v5e at the four shapes the benchmark's cells serve
+  (`tools/attn_sweep.py`; chip, PR 25; us per layer call, contexts drawn from
+  the cells' traffic):
+
+      shape (heads q/kv, N, context tokens)   (8, 32) before   rule      us
+      Qwen2.5-1.5B 12/2, N=64 decode, 39.5k        566        (32, 8)    183
+      Qwen2.5-1.5B 12/2, N=256 unified, 40.7k      582        (32, 16)   212
+      Mistral-7B 32/8, N=64 decode, 129.7k        2215        (32, 8)    894
+      Mistral-7B 32/8, N=256 unified, 137.0k      2731        (32, 16)  1253
+
+  A KV block has a fixed cost (descriptors and DMA starts and waits for each
+  page, the loop turn, the l/m/acc update) that 128-token blocks never
+  amortised, and a decode row owns one query row however many its block
+  carries. Time per call falls to 32 pages a block, is flat to 64 and turns up
+  at 128 (the unrolled per-page DMA loop spills); PERF.md section 6 has the
+  whole table,
 - the VMEM budget (the kernel's scratch exceeds the 16 MB scoped-vmem default on
   larger head counts; vLLM-TPU ships 100 MB, we follow),
 - the combined KV layout contract [P, page_size, 2*Hk, Dhp] (K even / V odd) with
@@ -76,33 +91,46 @@ def shard_over_heads(fn, mesh, q, layer_cache, *, shard_kv: bool):
     return sharded
 
 
+# A KV block of 512 tokens, and never more than 32 pages: the fetch loop is
+# unrolled per page, and past 64 pages a block it spills (module docstring).
+KV_BLOCK_TOKENS = 512
+KV_BLOCK_MAX_PAGES = 32
+
+
 def pick_block_sizes(num_tokens: int, page_size: int, pages_per_seq: int,
                      *, head_layout: "str | None" = None) -> tuple[int, int]:
     """(num_kv_pages_per_block, num_queries_per_block) for our serving shapes.
 
     Resolution order, weakest to strongest:
 
-    1. **heuristic** — KV blocks sized ~128 tokens keep decode DMAs overlapped
-       without predicating past short sequences (v5e sweep above); q blocks of
-       32 cover a full decode batch row budget per program, 64+ for big
-       prefill batches,
+    1. **the rule**, a function of the call's static shapes only, from the
+       sweep in the module docstring. bkv: `KV_BLOCK_TOKENS` of context a
+       block, at most `KV_BLOCK_MAX_PAGES` pages and at most the sequence's
+       page budget (a short model length is one block a sequence; nothing
+       past its page table is fetched). bq by the token budget N, which is
+       all a trace can see of which step program calls: up to 128 (the fused
+       decode call, one query row a sequence) 8 rows; up to 512 (the unified
+       step: decode rows, then prefill chunks, each of which reads its whole
+       context once per query block) 16; larger prefill budgets keep the 64
+       they had (not swept). Both head layouts swept (12/2 and 32/8 heads of
+       128) want the same pair, so the rule does not read the layout,
     2. **auto-tune table** (`ops.attn_tune`, loaded from
        ``LLMD_ATTN_TUNE_FILE`` / `EngineConfig.attn_tune_file`) — bench.py's
        on-chip tuner's per-(batch, page_size, head layout) winners; an exact
-       batch match replaces the heuristic, so b128 and long-context shapes
-       stop running block sizes swept at b32,
+       batch match replaces the rule,
     3. ``LLMD_ATTN_BKV`` / ``LLMD_ATTN_BQ`` env overrides — the operator
        escape hatch (and the legacy single-shape tuner export), applied at
        decode-gate shapes only (see deploy/ENV_VARS.md).
     """
     import os
 
-    bkv = max(1, min(pages_per_seq, max(1, 128 // page_size)))
-    bq = 32 if num_tokens <= 512 else 64
+    bkv = max(1, min(pages_per_seq, KV_BLOCK_MAX_PAGES,
+                     KV_BLOCK_TOKENS // page_size))
+    bq = 8 if num_tokens <= 128 else 16 if num_tokens <= 512 else 64
     table = attn_tune.active_table()
     if table is not None:
         # exact (batch, page_size, head_layout) key; nearest pages_per_seq —
-        # non-tuned shapes (e.g. prefill token budgets) miss and keep policy
+        # non-tuned shapes (e.g. prefill token budgets) miss and keep the rule
         hit = table.lookup(num_tokens, page_size, pages_per_seq, head_layout)
         if hit is not None:
             bkv, bq = hit
@@ -135,6 +163,17 @@ def pick_block_sizes(num_tokens: int, page_size: int, pages_per_seq: int,
     return bkv, min(bq, num_tokens)
 
 
+def call_geometry(q_shape, cache_shape, pages_per_seq: int) -> tuple[int, int]:
+    """`pick_block_sizes` for a call with these static shapes: what
+    `paged_attention_tpu` traces a step program with, and what the engine
+    reports on ``llmd_tpu:engine_attn_backend{geometry}``."""
+    N, heads, width = q_shape
+    _, ps, planes, _ = cache_shape
+    return pick_block_sizes(
+        N, ps, pages_per_seq,
+        head_layout=attn_tune.head_layout_key(heads, width, planes))
+
+
 def paged_attention_tpu(
     q: jax.Array,  # [N, H, Dhp] flat query tokens (lane-padded)
     layer_cache: jax.Array,  # [P, ps, 2*Hk, Dhp]
@@ -153,11 +192,7 @@ def paged_attention_tpu(
     """Uniform-signature adapter over the Pallas kernel (drop-in for
     models.transformer.ragged_paged_attention_xla on TPU)."""
     del positions, seq_slots, chunk_k, chunk_v
-    N = q.shape[0]
-    _, ps, planes, _ = layer_cache.shape
-    bkv, bq = pick_block_sizes(
-        N, ps, page_tables.shape[1],
-        head_layout=attn_tune.head_layout_key(q.shape[1], q.shape[2], planes))
+    bkv, bq = call_geometry(q.shape, layer_cache.shape, page_tables.shape[1])
     # -1 marks unmapped table entries in engine convention; the kernel's scalar-
     # prefetched DMA would read out of bounds — clamp to page 0 (never attended:
     # those entries lie at/past kv_len).

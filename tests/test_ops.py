@@ -155,15 +155,16 @@ def test_engine_unified_vs_fused_decode_paths():
 
 def test_pick_block_sizes_bounds():
     """Block-size policy invariants the kernel's static validation requires:
-    1 <= bkv <= pages_per_seq, bkv*ps targets ~128 tokens, 1 <= bq <= N."""
+    1 <= bkv <= pages_per_seq, a KV block of at most 512 tokens and 32 pages,
+    1 <= bq <= N."""
     from llmd_tpu.ops.paged_attention import pick_block_sizes
 
-    for ps in (4, 8, 16, 32, 64, 128, 256):
+    for ps in (4, 8, 16, 32, 64, 128, 256, 1024):
         for pages in (1, 2, 7, 64, 512):
             for n in (1, 31, 512, 2048):
                 bkv, bq = pick_block_sizes(n, ps, pages)
-                assert 1 <= bkv <= pages
-                assert bkv * ps <= max(128, ps)  # ~128-token KV blocks
+                assert 1 <= bkv <= min(pages, 32)
+                assert bkv * ps <= max(512, ps)
                 assert 1 <= bq <= max(n, 1) and bq <= 64
 
 

@@ -3,7 +3,7 @@ cost-scaling regression.
 
 Three layers pinned here:
 
-1. `pick_block_sizes` resolution order — heuristic < shape-keyed tune table
+1. `pick_block_sizes` resolution order — the rule < shape-keyed tune table
    (ops/attn_tune) < `LLMD_ATTN_BKV`/`BQ` env overrides gated by
    `LLMD_ATTN_DECODE_N` — including every degradation path (missing file,
    corrupt file, malformed entries) landing back on the heuristic.
@@ -48,14 +48,40 @@ def _clean_tune_state(monkeypatch):
 # ------------------------------------------------------------ heuristic layer
 
 
-def test_heuristic_serving_shapes():
-    # decode at b64, 64-token pages: ~128-token KV blocks -> 2 pages
-    assert pick_block_sizes(64, 64, 8) == (2, 32)
-    # b128 on 16-token pages: 8 pages per block, clamped by pages_per_seq
-    assert pick_block_sizes(128, 16, 20) == (8, 32)
-    assert pick_block_sizes(128, 16, 4) == (4, 32)
-    # long-context prefill budgets take the wider q block
-    assert pick_block_sizes(1024, 16, 128) == (8, 64)
+QWEN, MISTRAL, LLAMA_PACKED = "h12x128kv4", "h32x128kv16", "h32x128kv8"
+
+
+@pytest.mark.parametrize("n,page_size,pages,layout,want", [
+    # the benchmark's cells: fused decode (N = 64 seats) and the unified step
+    # (N = 256 tokens) of Qwen2.5-1.5B (4,096 tokens: 256 pages a sequence)
+    # and Mistral-7B (8,192: 512), each layout at both lengths
+    (64, 16, 256, QWEN, (32, 8)),
+    (256, 16, 256, QWEN, (32, 16)),
+    (64, 16, 512, QWEN, (32, 8)),
+    (256, 16, 512, QWEN, (32, 16)),
+    (64, 16, 256, MISTRAL, (32, 8)),
+    (256, 16, 256, MISTRAL, (32, 16)),
+    (64, 16, 512, MISTRAL, (32, 8)),
+    (256, 16, 512, MISTRAL, (32, 16)),
+    # llama-1b as chip_smoke.py serves it: packed two heads to a lane row,
+    # 1,024-token model length
+    (64, 16, 64, LLAMA_PACKED, (32, 8)),
+    (256, 16, 64, LLAMA_PACKED, (32, 16)),
+    # a short model length is one block a sequence: bkv clamps to the budget
+    (64, 16, 4, QWEN, (4, 8)),
+    (256, 16, 20, MISTRAL, (20, 16)),
+    # 512 tokens a block whatever the page size, never more than 32 pages
+    (64, 64, 8, QWEN, (8, 8)),
+    (64, 128, 64, QWEN, (4, 8)),
+    (64, 8, 512, QWEN, (32, 8)),
+    # fewer tokens than a query block; a prefill budget past the swept shapes
+    (4, 16, 256, QWEN, (32, 4)),
+    (1024, 16, 128, MISTRAL, (32, 64)),
+])
+def test_rule_at_served_shapes(n, page_size, pages, layout, want):
+    bkv, bq = pick_block_sizes(n, page_size, pages, head_layout=layout)
+    assert (bkv, bq) == want
+    assert 1 <= bkv <= pages and 1 <= bq <= n
 
 
 def test_head_layout_key_format():
@@ -174,6 +200,24 @@ def test_engine_loads_table_with_hash_provenance(tmp_path):
     assert eng.stats.attn_tune_hash == t.sha
     out = eng.generate([[3, 5, 7]], SamplingParams(max_tokens=3, temperature=0.0))
     assert len(out["req-0"]) == 3
+
+
+def test_engine_reports_the_geometry_its_programs_trace():
+    """`engine_attn_backend{geometry}` is `pick_block_sizes` at the unified
+    step's and the fused decode call's static shapes; `none` where the XLA
+    reference serves."""
+    def mk(**kw):
+        return LLMEngine(get_model_config("tiny"), EngineConfig(
+            page_size=8, num_pages=32, max_model_len=64, max_batch_size=2,
+            prefill_chunk=16, **kw))
+
+    eng = mk(attn_impl="pallas")
+    # 8 pages a sequence (clamps bkv); N = 16 tokens unified, 2 seats decode
+    assert (pick_block_sizes(16, 8, 8), pick_block_sizes(2, 8, 8)) \
+        == ((8, 8), (8, 2))
+    assert eng.attn_geometry == "unified=8x8 decode=8x2"
+    assert 'geometry="unified=8x8 decode=8x2"' in eng.metrics.registry.expose()
+    assert mk().attn_geometry == "none"
 
 
 # -------------------------------------------------- b128 scaling regression

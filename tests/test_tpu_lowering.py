@@ -6,8 +6,9 @@ breaks on the Mosaic path (a renamed compiler-params class, a kernel called
 bare inside a multi-device jit) is ever executed. Cross-lowering
 (``jax.export`` with ``platforms=["tpu"]``, ``interpret=False``) runs the
 real TPU lowering rules without a chip. It proves the call lowers; whether
-Mosaic then accepts the kernel (VMEM, tiling) only ``chip_smoke.py`` on the
-chip can say.
+Mosaic then accepts the kernel (VMEM, tiling) is what the compile for a
+described v5e (the ``one_chip`` fixture) says for the attention kernel at the
+benchmark's shapes, and ``chip_smoke.py`` on the chip for the rest.
 """
 
 import functools
@@ -70,6 +71,93 @@ def _llama_packed_attn(mesh=None):
     q_shape = lambda n: (n, cfg.num_heads, dhp)  # noqa: E731
     cache_shape = (64 * 4, 16, 2 * cfg.num_kv_heads // f, dhp)
     return fn, q_shape, cache_shape
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described, not attached, v5e chip: the TPU's own compiler runs here
+    without one. Only in a fixture: the worker that is given this file loads
+    the TPU's library, and no other."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (query heads, KV heads, pages a sequence) of the benchmark's configurations:
+# heads of 128, 16-token pages, model lengths 4,096 and 8,192
+CELL_LAYOUTS = {"qwen2.5-1.5b": (12, 2, 256), "mistral-7b-v0.3": (32, 8, 512)}
+
+
+@pytest.mark.parametrize("n", [64, 256])  # fused decode seats; unified tokens
+@pytest.mark.parametrize("config", sorted(CELL_LAYOUTS))
+def test_cell_shapes_compile_for_v5e_with_the_rules_geometry(one_chip, config, n):
+    """The geometry `pick_block_sizes` gives the cells' step programs goes
+    through Mosaic and the TPU compiler here: one it refuses (VMEM, tiling, an
+    unaligned slice) fails this test and not the cell."""
+    heads, kv_heads, maxp = CELL_LAYOUTS[config]
+
+    def fn(q, cache, pt, pos, slots, lens, cu, ns):
+        return paged_attention_tpu(q, cache, pt, pos, slots, lens,
+                                   scale=128 ** -0.5, cu_q_lens=cu,
+                                   num_seqs=ns)
+
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in _attn_args((n, heads, 128), (1024, 16, 2 * kv_heads, 128),
+                                64, maxp)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "ragged_paged_attention_kernel" in text
+
+
+def test_rules_geometry_matches_xla_reference_in_interpret_mode(monkeypatch):
+    """The upstream kernel at the rule's geometry (512-token KV blocks of 32
+    pages, 8 query rows a block) against the XLA reference: decode rows whose
+    contexts span one, two and three KV blocks, and a prefill chunk that
+    crosses a query-block boundary, in one batch."""
+    from jax.experimental import pallas as pl
+
+    from llmd_tpu.models.transformer import ragged_paged_attention_xla
+    from llmd_tpu.ops.paged_attention import call_geometry
+
+    import numpy as np
+
+    ps, H, Hk, D, maxp, N, B = 16, 4, 2, 128, 96, 32, 8
+    seq_lens, q_lens = [300, 700, 1100, 600], [1, 1, 1, 20]
+    rng = np.random.default_rng(0)
+    P = sum(-(-L // ps) for L in seq_lens) + 3
+    assert call_geometry((N, H, D), (P, ps, 2 * Hk, D), maxp) == (32, 8)
+    free = rng.permutation(P)
+    pt = np.full((B, maxp), -1, np.int32)
+    lens, cu = np.ones((B,), np.int32), np.zeros((B + 1,), np.int32)
+    pos, sids = np.full((N,), -1, np.int32), np.zeros((N,), np.int32)
+    off = used = 0
+    for b, (L, qn) in enumerate(zip(seq_lens, q_lens)):
+        n = -(-L // ps)
+        pt[b, :n] = free[used:used + n]
+        pos[off:off + qn], sids[off:off + qn] = np.arange(L - qn, L), b
+        lens[b], used, off = L, used + n, off + qn
+        cu[b + 1] = off
+    cu[len(seq_lens) + 1:] = off
+    args = (jnp.asarray(rng.standard_normal((N, H, D)), jnp.bfloat16),
+            jnp.asarray(rng.standard_normal((P, ps, 2 * Hk, D)), jnp.bfloat16),
+            jnp.asarray(pt), jnp.asarray(pos), jnp.asarray(sids),
+            jnp.asarray(lens))
+    kw = dict(scale=D ** -0.5, cu_q_lens=jnp.asarray(cu),
+              num_seqs=jnp.asarray([len(seq_lens)], jnp.int32))
+    want = np.asarray(ragged_paged_attention_xla(*args, **kw), np.float32)
+    # the upstream wrapper takes no interpret flag: give its pallas_call one
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+    got = np.asarray(paged_attention_tpu(*args, **kw), np.float32)
+    np.testing.assert_allclose(got[:off], want[:off], atol=0.01)
 
 
 def test_packed_paged_attention_lowers_for_tpu():

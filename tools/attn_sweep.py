@@ -1,0 +1,301 @@
+"""Sweep the paged-attention kernel's block geometry at the served shapes.
+
+Times `paged_attention_tpu` exactly as the engine calls it (bf16 pool
+``[P, page, 2*Hk, 128]``, ``VMEM_LIMIT`` as is, page tables as wide as the
+model length) at the four shapes the benchmark's cells serve: the fused decode
+call (N = max_batch_size query rows, one a sequence) and the unified step
+(N = prefill_chunk tokens: decode rows first, then prefill chunks) of each
+configuration in ``perfbench/configs`` (and, on request, ``chunks``: a unified
+step with no decode rows). Contexts are drawn from the cells'
+own traffic files, so a row's context, the number of live rows and the chunk's
+history are what the cells see, not a uniform length.
+
+Every (bkv, bq) pair is one Mosaic compile and ``--reps`` dependent calls in a
+``fori_loop``; the report is microseconds a call and microseconds per 128
+tokens of context read, which says whether the fixed part of a KV block or the
+work per query row sets the time. ``pick_block_sizes`` (the rule) is marked in
+the table. A pair the compiler refuses is recorded with its error.
+
+    python tools/attn_sweep.py                  # on the chip
+    python tools/attn_sweep.py --compile-only   # here: which pairs Mosaic takes
+
+``--compile-only`` compiles for a described v5e without a chip and runs nothing.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import itertools
+import json
+import math
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+# (configuration file, traffic file) of each cell in BENCHMARK.json
+CELLS = {
+    "qwen": ("qwen2.5-1.5b", "offline-closed"),
+    "mistral": ("mistral-7b-v0.3", "sessions-closed"),
+}
+# live rows of a fused decode call and decode rows of a unified step
+# (PERF.md section 5: decode_seat_steps_total, program_rows_total)
+LIVE_DECODE = {"qwen": 48, "mistral": 32}
+UNIFIED_DECODE = {"qwen": 49, "mistral": 30}
+
+
+def _load(kind: str, name: str) -> dict:
+    with open(os.path.join(ROOT, "perfbench", kind, f"{name}.json")) as f:
+        return json.load(f)
+
+
+def _lognormal(rng, spec: dict, n: int):
+    import numpy as np
+
+    x = np.exp(rng.normal(math.log(spec["median"]), spec["sigma"], n))
+    return np.clip(x, spec["min"], spec["max"]).astype(np.int64)
+
+
+def _contexts(rng, traffic: dict, n: int):
+    """(history, new) per sequence: tokens already in the cache when the
+    request arrived (a prefix-cache hit) and the prompt tokens it brought."""
+    import numpy as np
+
+    sess = traffic.get("sessions")
+    if not sess:
+        return np.zeros(n, np.int64), _lognormal(rng, traffic["prompt"], n)
+    hist = np.full(n, sess["system_prompt"], np.int64)
+    for i, t in enumerate(rng.integers(0, sess["turns"], n)):
+        hist[i] += (_lognormal(rng, traffic["prompt"], t).sum()
+                    + _lognormal(rng, traffic["output"], t).sum())
+    return hist, _lognormal(rng, traffic["prompt"], n)
+
+
+def draw_batch(rng, cell: str, program: str, eng: dict, traffic: dict):
+    """kv_lens [B], cu_q_lens [B+1], num_seqs for one call of ``program``."""
+    import numpy as np
+
+    B, N = eng["max_batch_size"], eng["prefill_chunk"]
+    limit = eng["max_model_len"]
+    n_dec = {"decode": LIVE_DECODE, "unified": UNIFIED_DECODE}.get(
+        program, {}).get(cell, 0)  # "chunks": a unified step of prefill only
+    hist, new = _contexts(rng, traffic, n_dec)
+    out = _lognormal(rng, traffic["output"], n_dec)
+    dec = np.minimum(hist + new + (rng.random(n_dec) * out).astype(np.int64),
+                     limit)
+    kv_lens = np.ones(B, np.int64)  # an idle seat reads one token
+    q_lens = np.zeros(B, np.int64)
+    kv_lens[:n_dec], q_lens[:n_dec] = dec, 1
+    rows = n_dec
+    if program == "decode":
+        rows, q_lens[:] = B, 1  # the fused call runs every seat
+    else:
+        budget = N - n_dec
+        while budget > 0 and rows < B:
+            h, p = (int(a[0]) for a in _contexts(rng, traffic, 1))
+            done = int(rng.integers(0, p))  # prompt tokens of earlier chunks
+            n = min(budget, p - done, N)
+            kv_lens[rows], q_lens[rows] = min(h + done + n, limit), n
+            rows, budget = rows + 1, budget - n
+    cu = np.concatenate([[0], np.cumsum(q_lens)])
+    cu[rows + 1:] = cu[rows]
+    return kv_lens.astype(np.int32), cu.astype(np.int32), rows
+
+
+def build_case(cell: str, program: str, seed: int):
+    import numpy as np
+
+    cfg_name, traffic_name = CELLS[cell]
+    cfg, traffic = _load("configs", cfg_name), _load("traffic", traffic_name)
+    eng = cfg["engine"]
+    rng = np.random.default_rng(seed)
+    kv_lens, cu, rows = draw_batch(rng, cell, program, eng, traffic)
+    ps, P = eng["page_size"], eng["num_pages"]
+    maxp = eng["max_model_len"] // ps
+    pts = np.full((eng["max_batch_size"], maxp), -1, np.int32)
+    free = rng.permutation(P)  # a pool after churn: a sequence's pages scatter
+    off = 0
+    for i, n in enumerate(-(-kv_lens // ps)):
+        pts[i, :n] = free[off:off + n]
+        off += n
+    N = eng["max_batch_size"] if program == "decode" else eng["prefill_chunk"]
+    return dict(
+        cell=cell, program=program, N=N, heads=cfg["num_attention_heads"],
+        kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        page_size=ps, num_pages=P, pages_per_seq=maxp, kv_lens=kv_lens, cu=cu,
+        page_tables=pts, num_seqs=rows,
+        ctx_tokens=int(kv_lens[:rows].sum()),
+        kv_bytes_per_token=2 * cfg["num_key_value_heads"] * cfg["head_dim"] * 2)
+
+
+def _attn_fn(pa, case, reps):
+    import jax
+
+    scale = case["head_dim"] ** -0.5
+
+    def f(q, cache, pts, lens, cu, ns):
+        def body(_, qq):
+            o = pa.paged_attention_tpu(qq, cache, pts, None, None, lens,
+                                       scale=scale, cu_q_lens=cu, num_seqs=ns)
+            return (qq * 0.5 + o * 0.5).astype(qq.dtype)
+
+        return jax.lax.fori_loop(0, reps, body, q)
+
+    return jax.jit(f)
+
+
+def _shapes(case, sharding=None):
+    import jax
+    import jax.numpy as jnp
+
+    B = case["page_tables"].shape[0]
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    return (s((case["N"], case["heads"], case["head_dim"]), jnp.bfloat16),
+            s((case["num_pages"], case["page_size"], 2 * case["kv_heads"],
+               case["head_dim"]), jnp.bfloat16),
+            s(case["page_tables"].shape, jnp.int32), s((B,), jnp.int32),
+            s((B + 1,), jnp.int32), s((1,), jnp.int32))
+
+
+def _operands(case, seed):
+    import jax
+    import jax.numpy as jnp
+
+    q, cache = _shapes(case)[:2]
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    return (jax.random.normal(k1, q.shape, q.dtype),
+            jax.random.normal(k2, cache.shape, cache.dtype),
+            jnp.asarray(case["page_tables"]), jnp.asarray(case["kv_lens"]),
+            jnp.asarray(case["cu"]),
+            jnp.asarray([case["num_seqs"]], jnp.int32))
+
+
+def measure(pa, case, geometry, reps, operands, chip):
+    """One row of the report: compile (and, with operands, time) the kernel at
+    ``geometry``. ``pick_block_sizes`` is replaced for the trace, so the call
+    goes through `paged_attention_tpu` as the engine's does."""
+    import jax
+    import numpy as np
+
+    rule, row = pa.pick_block_sizes, dict(zip(("bkv", "bq"), geometry))
+    pa.pick_block_sizes = lambda *a, **k: geometry
+    try:
+        t0 = time.perf_counter()
+        fn = _attn_fn(pa, case, reps)
+        if operands is None:
+            fn.lower(*_shapes(case, chip)).compile()
+            row["compile_s"] = time.perf_counter() - t0
+            return row
+        out = jax.block_until_ready(fn(*operands))
+        row["compile_s"] = time.perf_counter() - t0
+        times = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*operands))
+            times.append(time.perf_counter() - t0)
+        us = min(times) / reps * 1e6
+        row.update(us_per_call=us, us_per_128_ctx=us / case["ctx_tokens"] * 128,
+                   roofline=case["floor_us"] / us,
+                   out=np.asarray(out[:case["cu"][case["num_seqs"]]],
+                                  np.float32))
+    except Exception as e:  # the compiler's words are the result
+        row["error"] = f"{type(e).__name__}: {e}"[:400]
+    finally:
+        pa.pick_block_sizes = rule
+    return row
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cells", default="qwen,mistral")
+    ap.add_argument("--programs", default="decode,unified",
+                    help="decode, unified, or chunks (a unified step that "
+                         "carries prefill chunks only: what the decode rows "
+                         "and the chunks each want of bq)")
+    ap.add_argument("--bkv", default="4,8,16,32,64")
+    ap.add_argument("--bq", default="8,16,32,64")
+    ap.add_argument("--reps", type=int, default=16)
+    ap.add_argument("--seeds", default="0", help="one drawn batch per seed")
+    ap.add_argument("--compile-only", action="store_true")
+    ap.add_argument("--out", default=os.path.join(
+        ROOT, "chiprun_out", "attn_sweep.json"))
+    args = ap.parse_args()
+
+    if args.compile_only:
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import numpy as np
+
+    import llmd_tpu.ops.paged_attention as pa
+    from llmd_tpu.obs.costmodel import chip_peaks
+
+    chip = None
+    if args.compile_only:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+        chip = SingleDeviceSharding(topo.devices[0])
+        device = "TPU v5e (described, compile only)"
+    else:
+        if jax.default_backend() != "tpu":
+            raise SystemExit("attn_sweep: no TPU (use --compile-only here)")
+        device = jax.devices()[0].device_kind
+    _, peak_gbs = chip_peaks(device)
+    if peak_gbs is None:
+        raise SystemExit(f"attn_sweep: no peaks for {device!r}")
+    print(f"# device: {device}, {peak_gbs:.0f} GB/s", flush=True)
+
+    report = {"device": device, "reps": args.reps, "shapes": []}
+    pairs = [(bkv, bq) for bkv in map(int, args.bkv.split(","))
+             for bq in map(int, args.bq.split(","))]
+    for cell, program, seed in itertools.product(
+            args.cells.split(","), args.programs.split(","),
+            map(int, args.seeds.split(","))):
+        case = build_case(cell, program, seed)
+        q, cache = _shapes(case)[:2]
+        chosen = pa.call_geometry(q.shape, cache.shape, case["pages_per_seq"])
+        case["floor_us"] = (case["ctx_tokens"] * case["kv_bytes_per_token"]
+                            / (peak_gbs * 1e9) * 1e6)
+        shape = {k: case[k] for k in (
+            "cell", "program", "N", "heads", "kv_heads", "page_size",
+            "pages_per_seq", "num_seqs", "ctx_tokens", "floor_us")}
+        shape.update(seed=seed, rule=list(chosen), results=[])
+        print(f"\n## {cell} {program} N={case['N']} seed={seed}: "
+              f"{case['num_seqs']} rows, {case['ctx_tokens']} context tokens, "
+              f"{case['floor_us']:.0f} us at {peak_gbs:.0f} GB/s; rule {chosen}",
+              flush=True)
+        operands = None if args.compile_only else _operands(case, seed)
+        first = None  # the first pair's output: every other is compared to it
+        for geometry in pairs + [chosen] * (chosen not in pairs):
+            bkv, bq = geometry
+            if bkv > case["pages_per_seq"] or bq > case["N"]:
+                continue
+            row = measure(pa, case, geometry, args.reps, operands, chip)
+            mark = " <- rule" if geometry == chosen else ""
+            if "us_per_call" in row:
+                out = row.pop("out")
+                first = out if first is None else first
+                row["max_diff"] = float(np.abs(out - first).max())
+                print(f"bkv={bkv:3d} bq={bq:3d}: {row['us_per_call']:8.1f} "
+                      f"us/call {row['us_per_128_ctx']:6.3f} us/128tok "
+                      f"{100 * row['roofline']:5.1f}% of bytes "
+                      f"diff {row['max_diff']:.4f} "
+                      f"(compile {row['compile_s']:.1f} s){mark}", flush=True)
+            else:
+                said = row.get("error") or f"compiled in {row['compile_s']:.1f} s"
+                print(f"bkv={bkv:3d} bq={bq:3d}: {said}{mark}", flush=True)
+            shape["results"].append(row)
+        report["shapes"].append(shape)
+        os.makedirs(os.path.dirname(args.out), exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(report, f, indent=1)
+    print(f"\n# wrote {args.out}")
+
+
+if __name__ == "__main__":
+    main()
