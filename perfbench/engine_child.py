@@ -6,7 +6,11 @@
 The program's ``python -m llmd_tpu.engine.serve`` takes a registry name or a
 checkpoint directory, not a file of sizes, so this is the benchmark's own thin
 launcher around the same classes: ``init_jax`` (platform rule, compile cache),
-a ``ModelConfig`` made from the configuration file, ``LLMEngine`` (which makes
+a ``ModelConfig`` that the configuration's family module
+(``reference/<conf["reference"]>.py``: ``model_config``, ``sizes``,
+``weight_leaves``, ``readings``) makes from the file, so that no model key and
+no leaf name lives here; an ``EngineConfig`` from the file's ``engine`` block;
+``LLMEngine`` (which makes
 the weights on the device from the seed with ``init_params`` and quantises
 them as the file says), ``AsyncLLMEngine`` and ``EngineServer``. It adds a
 second small HTTP server, the control port, for what only the process that
@@ -36,44 +40,33 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, HERE)
 
 
-def model_config(conf: dict):
-    """The program's ModelConfig from a configuration file's published keys."""
-    from llmd_tpu.models.config import ModelConfig
-
-    return ModelConfig(
-        name=conf["name"],
-        vocab_size=conf["vocab_size"],
-        hidden_size=conf["hidden_size"],
-        intermediate_size=conf["intermediate_size"],
-        num_layers=conf["num_hidden_layers"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf["head_dim"],
-        rope_theta=conf["rope_theta"],
-        rms_eps=conf["rms_norm_eps"],
-        max_position=conf["max_position_embeddings"],
-        tie_embeddings=conf["tie_word_embeddings"],
-        dtype=conf["weights"]["dtype"],
-        attn_bias=conf["attention_bias"],
-    )
-
-
-def reference_sizes(conf: dict) -> dict:
-    return {"layers": conf["num_hidden_layers"],
-            "heads": conf["num_attention_heads"],
-            "kv_heads": conf["num_key_value_heads"],
-            "head_dim": conf["head_dim"], "eps": conf["rms_norm_eps"],
-            "theta": conf["rope_theta"], "tied": conf["tie_word_embeddings"]}
-
-
-def served_dtype_ok(conf: dict, params: dict, cache=None) -> bool:
+def served_dtype_ok(conf: dict, leaves, params: dict, cache=None) -> bool:
     """The stack the engine serves has the weight type the file states, and
-    its KV pool the type the file states."""
+    its KV pool the type the file states. ``leaves`` are the family's
+    ``weight_leaves(conf)``: under ``weights.quantize == "int8"`` every one is
+    there as ``<leaf>_q`` of int8 and none as a float leaf; otherwise every
+    one is there with ``weights.dtype``."""
     if cache is not None and str(cache.dtype) != conf["engine"]["kv_cache_dtype"]:
         return False
     if conf["weights"]["quantize"] == "int8":
-        return "wi_q" in params and str(params["wi_q"].dtype) == "int8"
-    return "wi" in params and str(params["wi"].dtype) == conf["weights"]["dtype"]
+        return all(k not in params and k + "_q" in params
+                   and str(params[k + "_q"].dtype) == "int8" for k in leaves)
+    return all(k in params and str(params[k].dtype) == conf["weights"]["dtype"]
+               for k in leaves)
+
+
+def engine_config(conf: dict, **over):
+    """The program's EngineConfig from a configuration file's ``engine``
+    block and stated weight type; ``over`` replaces single fields."""
+    from llmd_tpu.engine.config import EngineConfig
+
+    e = conf["engine"]
+    fields = dict(
+        page_size=e["page_size"], num_pages=e["num_pages"],
+        max_model_len=e["max_model_len"], max_batch_size=e["max_batch_size"],
+        prefill_chunk=e["prefill_chunk"], decode_steps=e["decode_steps"],
+        quantize_weights=conf["weights"]["quantize"])
+    return EngineConfig(**{**fields, **over})
 
 
 def main() -> None:
@@ -100,17 +93,12 @@ def main() -> None:
 
     from idtok import IdTokenizer
     from llmd_tpu.engine.async_engine import AsyncLLMEngine
-    from llmd_tpu.engine.config import EngineConfig
     from llmd_tpu.engine.engine import LLMEngine
     from llmd_tpu.engine.server import EngineServer
 
-    mcfg = model_config(conf)
-    e = conf["engine"]
-    ecfg = EngineConfig(
-        page_size=e["page_size"], num_pages=e["num_pages"],
-        max_model_len=e["max_model_len"], max_batch_size=e["max_batch_size"],
-        prefill_chunk=e["prefill_chunk"], decode_steps=e["decode_steps"],
-        quantize_weights=conf["weights"]["quantize"])
+    family = importlib.import_module("reference." + conf["reference"])
+    mcfg = family.model_config(conf)
+    ecfg = engine_config(conf)
     tok = IdTokenizer(mcfg.vocab_size)
 
     t = time.time()
@@ -125,8 +113,10 @@ def main() -> None:
     server = EngineServer(mcfg, ecfg, model_name=conf["name"],
                           host="127.0.0.1", port=args.port, tokenizer=tok,
                           engine=engine, async_engine=AsyncLLMEngine(engine))
-    reference = importlib.import_module("reference." + conf["reference"])
-    sizes = reference_sizes(conf)
+    sizes, leaves = family.sizes(conf), family.weight_leaves(conf)
+    chosen = {"attn_backend": engine.attn_backend,
+              "moe_backend": engine.moe_backend,
+              "moe_dispatch": engine.moe_dispatch}
 
     def device_info() -> dict:
         stats = [s for s in (d.memory_stats() for d in jax.devices()) if s]
@@ -145,9 +135,8 @@ def main() -> None:
 
     async def h_setup(_req):
         return web.json_response(
-            {"split": split, "compile_cache": compile_cache_dir(),
-             "attn_backend": engine.attn_backend,
-             "served_dtype_ok": served_dtype_ok(conf, engine.params,
+            {"split": split, "compile_cache": compile_cache_dir(), **chosen,
+             "served_dtype_ok": served_dtype_ok(conf, leaves, engine.params,
                                                 engine.cache)})
 
     async def h_reference(req):
@@ -155,9 +144,9 @@ def main() -> None:
 
         def run():
             t0 = time.time()
-            out = reference.deficits(sizes, engine.params, body["prompts"],
-                                     body["served"])
-            return {"deficits": out, "seconds": time.time() - t0}
+            out = family.readings(sizes, engine.params, body["prompts"],
+                                  body["served"])
+            return {**out, "seconds": time.time() - t0}
 
         return web.json_response(
             await asyncio.get_running_loop().run_in_executor(None, run))
@@ -179,7 +168,7 @@ def main() -> None:
         ae.on_fatal = lambda exc: loop.call_soon_threadsafe(stop.set)
         split["to_ready"] = time.time() - T0
         print(json.dumps({"ready": True, "device": device_info(),
-                          "split": split}), flush=True)
+                          "split": split, **chosen}), flush=True)
         await stop.wait()
         fatal = ae.fatal
         try:

@@ -80,11 +80,14 @@ class Generator:
 
     # ------------------------------------------------------------ one call
     async def complete(self, prompt: list, max_tokens: int, rec: Record | None,
-                       index: int = -1) -> list:
-        """Stream one completion; returns the served token ids."""
+                       index: int = -1, bias: dict | None = None) -> list:
+        """Stream one completion; returns the served token ids. ``bias`` is an
+        OpenAI ``logit_bias`` (the check's gap probe; no traffic has one)."""
         body = {"model": self.model, "prompt_token_ids": prompt,
                 "max_tokens": max_tokens, "temperature": 0.0,
                 "ignore_eos": True, "stream": True}
+        if bias:
+            body["logit_bias"] = bias
         out: list = []
         async with self.session.post(self.url, json=body) as resp:
             if resp.status != 200:

@@ -70,7 +70,8 @@ def main() -> int:
                 # worst deficit and what the reference cost
                 if d.get("note") == "generator":
                     notes.update({k: d.get(k) for k in (
-                        "tpot_p95_ms", "ttft_p50_ms", "ttft_p95_ms")})
+                        "out_tok_s", "tpot_p50_ms", "tpot_p95_ms",
+                        "ttft_p50_ms", "ttft_p95_ms")})
                 if d.get("note") == "check":
                     notes.update({k: d.get(k) for k in (
                         "reference_worst_deficit", "reference_s")})

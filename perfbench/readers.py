@@ -25,6 +25,9 @@ Source kinds:
   kernel_roofline {kernel, pattern, module}    see ``kernels/``
   device {field}
   scale {of, by} / difference {a, b} / ratio {a, b}   arithmetic on sources
+  metric {name}                 what ``metrics/<name>.json`` reads: the same
+        quantity under a second name, for cells whose end-to-end metric (a
+        per-layer metric's ``moves``) is another one
 """
 
 from __future__ import annotations
@@ -78,6 +81,8 @@ def read(src: dict, ctx: dict):
         return sum(vs) / len(vs) if vs else None
     if kind == "device":
         return (ctx.get("device") or {}).get(src["field"])
+    if kind == "metric":
+        return read(load(src["name"])["reads"], ctx)
     if kind in ("scale", "difference", "ratio"):
         if kind == "scale":
             v = read(src["of"], ctx)

@@ -170,6 +170,56 @@ def check_prompts(chk: dict, seed: int, vocab: int) -> list:
     return groups
 
 
+LIFT = 50.0  # bias on both probed tokens, so that no third one is served
+
+
+def gap_summary(errors) -> dict:
+    """Of the positions' gap errors: the median, which is what is judged,
+    beside the lower quartile and the maximum."""
+    err = sorted(errors)
+    q25, q50, top = (err[round(f * (len(err) - 1))] for f in (0.25, 0.5, 1.0))
+    return {"positions": len(err), "median": q50, "q25": q25, "max": top}
+
+
+async def gap_probe(gen: Generator, probe: dict, prompts: list, served: list,
+                    top2: list) -> dict:
+    """How far the served path's logits lie from the reference's, read
+    through the router with nothing but served tokens. At each position that
+    served a token the reference names its two largest logits, tokens a and
+    b, and their gap. The same context is asked for one token with a
+    ``logit_bias`` that lifts a and b above every other token and b by a
+    further x: b is served exactly when x is over the served path's own gap
+    between the two. ``rounds`` bisections of x within ``width`` of the
+    reference's gap find that gap to ``width / 2**rounds``; the error of a
+    position is its distance from the reference's, at most ``width``.
+
+    The number judged is the median over the positions. Arithmetic of a
+    lower precision moves every position; an expert choice that flips at a
+    near tie of the router moves the positions it touches by far more and
+    leaves the others alone, so while under half the positions see a flip the
+    median reads the arithmetic and the maximum reads the ties."""
+    ctx = [(list(p) + list(s[:j]), *t) for p, s, ts in
+           zip(prompts, served, top2) for j, t in enumerate(ts)]
+    w = probe["width"]
+    lo, hi = [g - w for *_, g in ctx], [g + w for *_, g in ctx]
+    for _ in range(probe["rounds"]):
+        mid = [(l + h) / 2 for l, h in zip(lo, hi)]
+        got = await asyncio.gather(*(
+            gen.complete(p, 1, None, bias={str(a): LIFT, str(b): LIFT + x})
+            for (p, a, b, _), x in zip(ctx, mid)))
+        for i, ((_, a, b, _), out) in enumerate(zip(ctx, got)):
+            if out not in ([a], [b]):
+                raise SystemExit(f"gap probe: asked for {a} or {b}, "
+                                 f"served {out}")
+            if out == [b]:
+                hi[i] = mid[i]
+            else:
+                lo[i] = mid[i]
+    return {"gap_error": gap_summary(abs((l + h) / 2 - g) for l, h, (*_, g)
+                                     in zip(lo, hi, ctx)),
+            "limit": probe["limit"], "resolution": w / 2 ** probe["rounds"]}
+
+
 async def check_outputs(gen: Generator, session, control: str, eurl: str,
                         conf: dict, seed: int) -> dict:
     """(a) The check's prompts, at the cells' own lengths, are served twice
@@ -178,7 +228,10 @@ async def check_outputs(gen: Generator, session, control: str, eurl: str,
     at once from the prefix cache. (b) Every token of the first serving is
     teacher-forced through the float32 reference and its reference logit lies
     within the configuration's margin of the reference maximum at its
-    position; the served stack and KV pool have the stated types."""
+    position; the served stack and KV pool have the stated types. (c) Where
+    the configuration's check has a ``gap_probe``, the served path's gap
+    between the reference's two best tokens lies within its limit of the
+    reference's at the median of those positions (``gap_probe``)."""
     chk = conf["check"]
     groups = check_prompts(chk, seed, conf["vocab_size"])
     n_out = chk["served_tokens"]
@@ -205,6 +258,7 @@ async def check_outputs(gen: Generator, session, control: str, eurl: str,
                             json={"prompts": prompts, "served": cold},
                             timeout=aiohttp.ClientTimeout(total=900)) as r:
         ref = await r.json()
+    t3 = time.time()
     setup = await get_json(session, control + "/setup")
     worst = max(d for ds in ref["deficits"] for d in ds)
     agree = sum(d == 0.0 for ds in ref["deficits"] for d in ds)
@@ -219,10 +273,16 @@ async def check_outputs(gen: Generator, session, control: str, eurl: str,
         "served_dtype_ok": setup["served_dtype_ok"],
         "attn_backend": setup["attn_backend"],
         "first_requests_s": t1 - t0, "cached_requests_s": t2 - t1,
-        "reference_s": time.time() - t2,
+        "reference_s": t3 - t2,
     }
     out["ok"] = bool(out["cold_equals_cached"] and out["lengths_ok"]
                      and worst <= chk["margin"] and out["served_dtype_ok"])
+    if "gap_probe" in chk and out["lengths_ok"]:
+        out["gap_probe"] = await gap_probe(gen, chk["gap_probe"], prompts,
+                                           cold, ref["top2"])
+        out["gap_probe"]["seconds"] = time.time() - t3
+        read = out["gap_probe"]["gap_error"]["median"]
+        out["ok"] = bool(out["ok"] and read <= chk["gap_probe"]["limit"])
     out["engine_split"] = setup["split"]
     return out
 
@@ -239,14 +299,17 @@ def generator_facts(load, samples: list, until: float | None = None) -> dict:
     tpot = [est.tpot_ms(r.first, r.last, r.n_out) if r.ok else est.INF
             for r in win]
     ttft = [(r.first - r.due) * 1e3 if r.ok else est.INF for r in win]
+    tpot = [est.INF if v is None else v for v in tpot]
     facts = {
         "late_p99_ms": est.percentile(late, 99),
-        "tpot_p95_ms": est.finite(est.percentile(
-            [est.INF if v is None else v for v in tpot], 95)) if win else None,
+        "tpot_p50_ms": est.finite(est.percentile(tpot, 50)) if win else None,
+        "tpot_p95_ms": est.finite(est.percentile(tpot, 95)) if win else None,
         "ttft_p50_ms": est.finite(est.percentile(ttft, 50)) if win else None,
         "ttft_p95_ms": est.finite(est.percentile(ttft, 95)) if win else None,
         "request_mean_ms": (sum(r.last - r.sent for r in done) / len(done)
                             * 1e3) if done else None,
+        "out_tok_s": est.window_rate(load.events, load.t0,
+                                     until or load.t1)[0],
         "prompt_tokens_sent": sum(r.prompt_tokens for r in win),
         "lane_blocked": load.lane_blocked,
     }
@@ -258,11 +321,17 @@ def generator_facts(load, samples: list, until: float | None = None) -> dict:
 
 
 def end_to_end(load, cell_metrics: list) -> tuple:
-    """{name: value} of the end-to-end metrics, and the notes that go on
-    earlier lines (sample counts)."""
+    """{name: value} of the cell's end-to-end metrics, and the notes that go
+    on earlier lines (sample counts). ``out_tok_s`` is every token that
+    arrived in the window over the window's length; ``tpot_p95_ms`` the 95th
+    percentile, over every request due in the window, of the request's time
+    per output token after the first (a failed request stands at +inf). The
+    median of those times is on the ``generator`` line only: it spreads three
+    times as widely as the 95th percentile (PERF.md section 2)."""
     win = [r for r in load.records if r.in_window]
     rate, n_ev = est.window_rate(load.events, load.t0, load.t1)
-    vals = {"out_tok_s": rate}
+    vals = {"out_tok_s": rate,
+            "tpot_p95_ms": generator_facts(load, [])["tpot_p95_ms"]}
     notes = {"requests_due_in_window": len(win),
              "completed": sum(r.ok for r in win),
              "token_events_in_window": n_ev,
@@ -388,7 +457,8 @@ async def run(args, manifest: dict, cell: dict, conf: dict, mix: dict,
     kids.stop()
 
     cell_e2e = [m["name"] for m in manifest["end_to_end"]
-                if cell["name"] in m.get("workloads", [cell["name"]])]
+                if cell["metrics_of"] in m.get("workloads",
+                                               [cell["metrics_of"]])]
     vals, notes = end_to_end(load, cell_e2e)
     say(note="window", **notes)
     say(note="setup_split", setup_s=setup_s, **split,
@@ -401,7 +471,7 @@ async def run(args, manifest: dict, cell: dict, conf: dict, mix: dict,
                 or 0) - (prom.total(before["engine"],
                                     "llmd_tpu:program_compiles_total") or 0)
     correct = bool(check["ok"] and not failed and compiles == 0
-                   and vals["out_tok_s"] > 0)
+                   and notes["tokens_in_window"] > 0)
     units = {m["name"]: m["unit"] for m in manifest["end_to_end"]
              + manifest["per_layer"]}
     result = {"correct": correct, "attempted": len(win),
@@ -443,7 +513,9 @@ async def run(args, manifest: dict, cell: dict, conf: dict, mix: dict,
            "trace": trace, "device": device, "config": conf}
     say(note="generator", **ctx["gen"])
     for m in manifest["per_layer"]:
-        if cell["name"] not in m.get("workloads", [cell["name"]]):
+        # without a list of cells: every cell that reports what it moves
+        if (cell["metrics_of"] not in m["workloads"] if "workloads" in m
+                else m["moves"] not in cell_e2e):
             continue
         v = readers.read(readers.load(m["name"])["reads"], ctx)
         if v is None:
@@ -462,6 +534,12 @@ async def run(args, manifest: dict, cell: dict, conf: dict, mix: dict,
     # the end-to-end numbers of a traced run, for the tracing overhead
     say(note="traced_end_to_end", **{k: est.finite(v) for k, v in vals.items()})
     return result
+
+
+def with_overrides(conf: dict, over: dict) -> dict:
+    """``conf`` with the keys of ``over`` replaced, one level into a block."""
+    return {**conf, **{k: {**conf[k], **v} if isinstance(v, dict) else v
+                       for k, v in over.items()}}
 
 
 def main() -> int:
@@ -495,6 +573,21 @@ def main() -> int:
                            f"{cell['name']}-s{args.seed}-t{args.trace}")
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
+    # a rehearsal manifest's only, as the cell's overrides are: the metric
+    # lists are BENCHMARK.json's unless it has its own, a cell reads the
+    # metrics of the cell it names, and a configuration entry replaces keys
+    # of its file (one level into a block), which the engine child then reads
+    # from a copy in the run's directory
+    if "per_layer" not in manifest:
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            manifest.update({k: v for k, v in json.load(f).items()
+                             if k in ("end_to_end", "per_layer")})
+    cell["metrics_of"] = cell.get("metrics_of", cell["name"])
+    if "overrides" in cfg:
+        conf = with_overrides(conf, cfg["overrides"])
+        cell["config_file"] = os.path.join(out_dir, "config.json")
+        with open(cell["config_file"], "w") as f:
+            json.dump(conf, f)
     kids = Children(out_dir)
     try:
         result = asyncio.run(run(args, manifest, cell, conf, mix, kids,

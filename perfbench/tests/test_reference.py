@@ -74,15 +74,17 @@ def test_reference_agrees_with_forward_core_in_float32(tied, bias, quant):
 def test_reference_sees_a_lower_precision_than_stated():
     """bf16 activations stay inside the margin; a stack quantised to int8
     against the bf16 one it claims to be does not pass the dtype check the
-    launcher makes (engine_child.served_dtype_ok)."""
+    launcher makes (engine_child.served_dtype_ok over the family's leaves)."""
     import engine_child
 
     cfg = _cfg("bfloat16")
     params = init_params(cfg, jax.random.PRNGKey(2))
     q, _ = quantize_params(cfg, params)
+    leaves = dense_gqa.weight_leaves({})
+    assert leaves == ("wq", "wk", "wv", "wo", "wi", "wo_mlp")
     conf = {"weights": {"dtype": "bfloat16", "quantize": None}}
-    assert engine_child.served_dtype_ok(conf, params)
-    assert not engine_child.served_dtype_ok(conf, q)
+    assert engine_child.served_dtype_ok(conf, leaves, params)
+    assert not engine_child.served_dtype_ok(conf, leaves, q)
     conf8 = {"weights": {"dtype": "bfloat16", "quantize": "int8"}}
-    assert engine_child.served_dtype_ok(conf8, q)
-    assert not engine_child.served_dtype_ok(conf8, params)
+    assert engine_child.served_dtype_ok(conf8, leaves, q)
+    assert not engine_child.served_dtype_ok(conf8, leaves, params)
