@@ -49,13 +49,6 @@ class EngineConfig:
     # back-to-back and the host round-trip fully hides. Costs up to
     # depth*decode_steps speculative tokens per sequence at EOS.
     pipeline_depth: int = 2
-    # Pipelined prefill sampling: defer the (RTT-priced) host read of a pure-
-    # prefill step's sampled first tokens until the next step is on the device.
-    # Mixed steps (decode rows present) always apply synchronously — a deferred
-    # decode row would sit out the following step. The read costs one full
-    # host<->device round trip per prefill step (PERF.md has the measured
-    # round trip).
-    pipeline_prefill_sample: bool = True
     # KV offload tier (pages of CPU-side cache; 0 = disabled) — K3 equivalent
     # (TPU_OFFLOAD_NUM_CPU_CHUNKS / STAGING_BLOCKS knobs of the reference connector).
     cpu_offload_pages: int = 0

@@ -12,6 +12,12 @@ continuous batching on accelerator'), built XLA-first:
   to the token budget instead of one sequence per step,
 - prefill never pays the [N, vocab] logits matmul — only each sequence's last
   hidden row is unembedded,
+- the unified step runs one step ahead of the host: step n+1 is dispatched
+  before step n's sampled tokens are read, its rows that ride on take their
+  input token from step n's sampled array on the device, and the read, the
+  finish checks and the outputs of step n happen under step n+1's device time
+  (``_step_unified``; whoever needs host token state first calls
+  ``_flush_pending_sample``),
 - automatic prefix caching with chained block hashes + KV events (kv_manager),
 - preemption by recompute when pages run out (vLLM semantics),
 - kernel provenance: which attention / MoE implementation the platform/shape
@@ -183,7 +189,8 @@ class EngineStats:
     time_device: float = 0.0
     time_device_decode: float = 0.0  # the decode-call share of time_device
     # host handling after the dispatch; in the unified step this HOLDS the
-    # wait for the device (the np.asarray read in _sample_apply)
+    # wait for the device (the np.asarray read in _sample_apply: of the
+    # PREVIOUS step, since the unified step runs one ahead)
     time_postprocess: float = 0.0
     n_unified_steps: int = 0
     n_decode_calls: int = 0  # fused decode calls PROCESSED (results applied)
@@ -406,10 +413,16 @@ class LLMEngine:
         # prompt-lookup probe disarms that row until fresh tokens land for it,
         # removing redundant O(context) numpy scans without letting one
         # non-repetitive stream disarm drafting for the whole batch)
-        # one in-flight prefill-step sample read (pipelined like decode: the
-        # ~RTT-priced np.asarray of the sampled tokens defers until the NEXT
-        # unified step is on the device, hiding the read behind its compute)
+        # the unified step the host has not read yet (_step_unified runs one
+        # step ahead: its sampled tokens, and the MoE counts beside them, are
+        # read while the NEXT step is on the device). Its rows stay
+        # schedulable: the next step takes their input token on the device.
         self._pending_sample: Optional[dict] = None
+        # what a unified step is given for ``prev_sampled`` when no step is
+        # in flight: zeros of a sampled array's shape, type and placement,
+        # so the one compiled program serves both cases
+        self._zero_sampled = self._replicated(
+            jnp.zeros((engine_cfg.max_batch_size,), jnp.int32))
 
         if params is None:
             params = init_params(model_cfg, jax.random.PRNGKey(seed))
@@ -566,10 +579,20 @@ class LLMEngine:
 
         def _make_unified(attn_fn):
             def _unified(params, cache, tokens, positions, seq_slots, page_tables,
-                         kv_lens, cu_q_lens, num_seqs, lora_tok,
+                         kv_lens, cu_q_lens, num_seqs, lora_tok, prev_sampled,
                          mm_embeds=None, mm_mask=None):
                 """Flat mixed batch (prefill chunks + decode tokens); returns each
-                sequence's last-row logits [B, vocab]."""
+                sequence's last-row logits [B, vocab].
+
+                The step runs one ahead of the host: a decode row whose input
+                token is still on the device packs ``-(row + 1)``, the row it
+                had in the previous step, and takes the token from that
+                step's ``prev_sampled [B]`` here (zeros after a flush: no
+                token is negative then, and the program is the same)."""
+                tokens = jnp.where(
+                    tokens < 0,
+                    prev_sampled[jnp.clip(-tokens - 1, 0, B - 1)].astype(jnp.int32),
+                    tokens)
                 # flat token dim shards over dp×sp jointly: data-parallel decode
                 # rows and sequence-parallel long prefills ride the same constraint
                 tokens = _bind(tokens, ("dp", "sp"))
@@ -1346,7 +1369,8 @@ class LLMEngine:
 
     def has_work(self) -> bool:
         return (any(self.waitq) or any(s is not None for s in self.running)
-                or bool(self._pending_decode))
+                or bool(self._pending_decode)
+                or self._pending_sample is not None)
 
     # ------------------------------------------------------- scheduling core
     def _free_seq(self, seq: Sequence) -> None:
@@ -1550,13 +1574,14 @@ class LLMEngine:
         victim frees memory the caller can use. ``exclude`` is the seq the
         caller is trying to schedule: evicting it frees its own pages only to
         reset it to token zero — a thrash loop, never progress."""
-        # Bank any deferred first tokens BEFORE choosing a victim: a pending-
-        # sample seq is idle and page-holding (a prime victim), and evicting
-        # it would drop its un-applied token — full re-prefill, re-defer,
-        # re-evict, a tight-pool ping-pong with zero forward progress. The
-        # flush makes per-seq progress monotonic again (the recompute path
-        # preserves applied tokens); preemption is the rare slow path, so the
-        # extra device read here is noise.
+        # Read the unified step in flight BEFORE choosing a victim: evicting
+        # a row whose token is not applied yet would drop that token — full
+        # re-prefill, re-defer, re-evict, a tight-pool ping-pong with zero
+        # forward progress. The flush makes per-seq progress monotonic again
+        # (the recompute path preserves applied tokens), and leaves every
+        # row's last token on the host, so no row is ever evicted with a
+        # token in flight. Preemption is the rare slow path, so the extra
+        # device read here is noise.
         self._flush_pending_sample()
         victims = [s for s in self.running
                    if s is not None and s.rank == rank and s is not exclude]
@@ -1654,8 +1679,8 @@ class LLMEngine:
         self._step_unified()
 
     def _run_verify_program(self) -> None:
-        # decode/verify build their batch from host token state: the deferred
-        # prefill sample (first tokens) must land first
+        # decode/verify build their batch from host token state: the unified
+        # step in flight (its tokens) must land first
         self._flush_pending_sample()
         # a verify step replaces this step's fused decode call when
         # prompt-lookup drafts exist; otherwise fall through to fused decode
@@ -1726,21 +1751,66 @@ class LLMEngine:
         ]
         return sorted(cands, key=lambda s: s.arrival_time)
 
-    def _decode_ready(self) -> list[Sequence]:
-        return [
-            s for s in self.running
-            if s is not None and s.num_computed == len(s.token_ids) - 1
-            and s.num_computed >= s.prompt_len
-        ]
+    def _decode_ready(self, flying: Optional[dict] = None) -> list[Sequence]:
+        """Running rows that can take a decode step. The input token of each
+        is on the host (``num_computed == len(token_ids) - 1``); with
+        ``flying`` (``_flying_rows()``: the unified step's view), also the
+        rows of the step still in flight, whose token (position
+        ``len(token_ids)``) is on the device until that step is read. A row
+        that the token in flight ends by ``max_tokens`` or ``max_model_len``
+        is known here and left out; one that a stop token may end is taken,
+        and dropped at apply if it does."""
+        flying = flying or {}
+        out = []
+        for s in self.running:
+            if s is None or s.num_computed < s.prompt_len:
+                continue
+            n = len(s.token_ids)
+            if s.num_computed == n - 1 or (
+                    s.num_computed == n and id(s) in flying
+                    and n + 1 - s.prompt_len < s.max_tokens
+                    and n + 1 < self.cfg.max_model_len):
+                out.append(s)
+        return out
+
+    def _flying_rows(self) -> dict[int, int]:
+        """``id(seq)`` -> its row in the sampled array of the unified step in
+        flight, for the rows that are still what that step dispatched."""
+        rec = self._pending_sample
+        if rec is None:
+            return {}
+        return {id(s): i for i, s, slot, _ in rec["rows"]
+                if self._still_seated(s, slot)}
+
+    def _still_seated(self, s: Sequence, slot: int) -> bool:
+        """Whether ``s`` is the running sequence a step dispatched in
+        ``slot``: not finished, preempted or aborted since."""
+        return (not s.finished and s.slot == slot
+                and self.running[slot] is s)
 
     @_step_phase("unified", "llmd.unified", "plan")
     def _step_unified(self, parts: _StepParts) -> None:
         """Pack decode tokens + prefill chunks (across sequences) into the flat
-        token budget and run ONE compiled step. ``parts`` splits its host
-        time: plan (row choice, pages, preemption), pack (numpy staging),
-        dispatch (transfers + the asynchronous jitted call), apply (per-row
-        state), sample, wait (the blocking read of sampled tokens), book."""
+        token budget and run ONE compiled step, one step ahead of the host:
+        this step is dispatched BEFORE the previous one's sampled tokens are
+        read, and a row of that step rides along as a decode row whose input
+        token the program takes on the device (``_unified``). ``parts``
+        splits the host time in the order it runs: plan (row choice, pages,
+        preemption), pack (numpy staging), dispatch (transfers + the
+        asynchronous jitted call), apply (this step's per-row state), sample
+        (its sampler's dispatch), wait (the blocking read of the PREVIOUS
+        step's sampled tokens and MoE counts), apply again (that step's
+        tokens, finish checks, outputs), book. So everything between two
+        dispatches but the wait runs under device time, and so does what the
+        loop does with the outputs after ``step()`` returns."""
         t0_ns = time.time_ns()
+        if self._pending_sample is not None and any(
+                s is not None and (s.structured is not None or s.logit_bias)
+                for s in self.running):
+            # a constrained row's bias is built from its previous token on
+            # the host (_build_bias): such a batch reads before it plans
+            self._flush_pending_sample(parts)
+            parts.to("plan")
         NT = self.cfg.batched_tokens
         B = self.cfg.max_batch_size
         R = self.num_ranks
@@ -1751,11 +1821,15 @@ class LLMEngine:
         # decode rows first (keeps TPOT low while prompts stream in), then
         # prefill chunks oldest-first
         plan: list[tuple[Sequence, int, bool]] = []  # (seq, q_len, is_decode)
-        for s in self._decode_ready():
+        # id(seq) -> row, for the rows of the step in flight: valid until a
+        # preemption reads that step, after which no row needs it
+        flying = self._flying_rows()
+        for s in self._decode_ready(flying):
             if len(plan) >= B:
                 break
             if s.slot < 0:
-                # preempted while packing an earlier row: the snapshot is
+                # preempted (or, its token read by a preemption's flush,
+                # retired) while packing an earlier row: the snapshot is
                 # stale. Without this guard the zombie's _ensure_pages can
                 # re-acquire pages onto a seq whose ledger _free_seq already
                 # emptied — pages it carries into the waitq and leaks at
@@ -1764,11 +1838,13 @@ class LLMEngine:
                 continue
             if budgets[s.rank] <= 0:
                 continue
-            if not self._ensure_pages(s, len(s.token_ids)):
+            # the row computes position num_computed, whether its token is
+            # on the host (len(token_ids) - 1) or in flight (len(token_ids))
+            if not self._ensure_pages(s, s.num_computed + 1):
                 if not self._preempt_one(s.rank, exclude=s) or s.slot < 0:
                     self._finish_if_outgrew_pool(s)
                     continue
-                if not self._ensure_pages(s, len(s.token_ids)):
+                if not self._ensure_pages(s, s.num_computed + 1):
                     continue
             plan.append((s, 1, True))
             budgets[s.rank] -= 1
@@ -1791,11 +1867,14 @@ class LLMEngine:
             budgets[s.rank] -= n
         plan = [(s, n, d) for (s, n, d) in plan if s.slot >= 0]
         if not plan:
-            # nothing schedulable — a deferred sample may be WHY (its rows
-            # hold slots/pages until applied, and an apply can retire): flush
-            # it so the next step can make progress instead of spinning
+            # nothing schedulable — the step in flight may be WHY (rows it
+            # ends are not planned ahead, and hold slots/pages until it is
+            # read): read it so the next step can make progress
             self._flush_pending_sample()
             return
+        # the step in flight, as the plan left it: a preemption reads it
+        # first (_preempt_one), and then every row's token is on the host
+        prev, self._pending_sample = self._pending_sample, None
 
         parts.to("pack")
         toks = np.zeros((NT,), np.int32)
@@ -1816,11 +1895,17 @@ class LLMEngine:
             mm_mask = np.zeros((NT,), np.bool_)
         off = 0
         first_chunks: list[Sequence] = []
+        ahead_rows: set[int] = set()
         for i, (s, n, is_decode) in enumerate(plan):
-            start = len(s.token_ids) - 1 if is_decode else s.num_computed
+            start = s.num_computed
             if not is_decode and start == s.num_cached_prompt:
                 first_chunks.append(s)
-            toks[off : off + n] = s.token_ids[start : start + n]
+            if start == len(s.token_ids):
+                # token in flight: name its row in the previous step
+                toks[off] = -(flying[id(s)] + 1)
+                ahead_rows.add(i)
+            else:
+                toks[off : off + n] = s.token_ids[start : start + n]
             pos[off : off + n] = np.arange(start, start + n)
             sids[off : off + n] = i
             lora_tok[off : off + n] = self._lora_slot(s)
@@ -1862,23 +1947,27 @@ class LLMEngine:
         self.metrics.program_kv_read_tokens.labels(program=step_prog).inc(
             kv_read_tokens)
         self.metrics.program_rows.labels(program=step_prog).inc(len(plan))
-        # synchronous program: the postprocess below consumes the logits this
-        # same step, so dispatch and completion are recorded together
+        # dispatched here, complete when its record is read (_sample_apply),
+        # a step later: the ledger holds it in flight meanwhile
         self.programs.record_dispatch(step_prog)
-        self.programs.record_complete(step_prog)
+        n_dec = sum(1 for _, _, d in plan if d)
+        for where, n in (("device", len(ahead_rows)),
+                         ("host", n_dec - len(ahead_rows))):
+            if n:
+                self.metrics.unified_decode_rows.labels(token=where).inc(n)
+        prev_sampled = prev["sampled"] if prev is not None else None
+        if prev_sampled is None:
+            prev_sampled = self._zero_sampled
         logits, self.cache, cnt, moe_drop = step_fn(
             self._run_params(), self.cache, jnp.asarray(toks), jnp.asarray(pos),
             jnp.asarray(sids), jnp.asarray(pts), jnp.asarray(lens), jnp.asarray(cu),
-            jnp.asarray([len(plan)], jnp.int32), jnp.asarray(lora_tok), *mm_args,
+            jnp.asarray([len(plan)], jnp.int32), jnp.asarray(lora_tok),
+            prev_sampled, *mm_args,
         )
         if self.cfg.instrument:
             # llmd-lint: allow[hot-host-sync] instrument-gated timing barrier; off in production serving
             logits.block_until_ready()
         parts.to("apply")
-        if self._eplb is not None:
-            self._eplb_record(cnt)
-        if self.model_cfg.is_moe:
-            self._moe_record_dropped(moe_drop)
 
         # goodput classification reads pre-postprocess sequence state: the
         # first-chunk prefix credit (num_computed == num_cached_prompt only
@@ -1896,14 +1985,14 @@ class LLMEngine:
                         util_recompute += n
 
         sample_list: list[tuple[int, Sequence]] = []  # (batch row, seq)
-        has_decode_rows = False
         for i, (s, n, is_decode) in enumerate(plan):
             if is_decode:
-                s.num_computed = len(s.token_ids)
+                s.num_computed += 1
+                # commits stop at the tokens the host holds: an ahead row's
+                # block is committed when its token is read (_sample_apply)
                 s.maybe_commit_blocks(self.allocs[s.rank])
                 self.stats.total_decode_tokens += 1
                 sample_list.append((i, s))
-                has_decode_rows = True
             else:
                 if s.num_computed == s.num_cached_prompt:
                     # first chunk of a (re)prefill — cached==computed only holds
@@ -1920,25 +2009,23 @@ class LLMEngine:
                         and s.num_computed == s.prompt_len):
                     # fresh prefill complete: sample first token from last logits
                     sample_list.append((i, s))
-        # Pipelined sample read: dispatch this step's sampling (device-chained
-        # on step_fn), apply the PREVIOUS step's deferred sample while the
-        # device runs, and defer this one — its rows are unschedulable until
-        # applied (not prefilling: num_computed==target; not decode-ready:
-        # num_computed==len(token_ids)), so the next plan can't race them.
-        # Mixed steps with decode rows apply synchronously: a deferred decode
-        # row would sit out the following step, stalling steady-state ITL.
-        prev, self._pending_sample = self._pending_sample, None
+        # One step ahead: dispatch this step's sampling (device-chained on
+        # step_fn), and only then read and apply the PREVIOUS step, while the
+        # device runs this one. This step's record waits for the next step,
+        # or for whoever needs host token state first (_flush_pending_sample).
+        # Its rows stay schedulable meanwhile: _decode_ready(_flying_rows()).
         parts.to("sample")
         bias = self._build_bias(sample_list, logits.shape) if sample_list else None
-        rec = (self._sample_dispatch(sample_list, logits, bias=bias)
-               if sample_list else None)
+        rec = self._sample_dispatch(sample_list, logits, bias=bias,
+                                    ahead_rows=ahead_rows)
+        rec["prog"] = step_prog
+        if self._eplb is not None:
+            rec["cnt"] = cnt
+        if self.model_cfg.is_moe:
+            rec["moe_drop"] = moe_drop
         if prev is not None:
             self._sample_apply(prev, parts)
-        if rec is not None:
-            if self.cfg.pipeline_prefill_sample and not has_decode_rows:
-                self._pending_sample = rec
-            else:
-                self._sample_apply(rec, parts)
+        self._pending_sample = rec
         parts.to("book")
         sec = parts.seconds
         host_pack = sec["plan"] + sec["pack"]
@@ -1950,7 +2037,6 @@ class LLMEngine:
         st.time_postprocess += post
         st.time_prefill_steps += wall
         st.n_unified_steps += 1
-        n_dec = sum(1 for _, _, d in plan if d)
         n_pre = sum(n for _, n, d in plan if not d)
         if n_dec:
             self.metrics.decode_tokens.inc(n_dec)
@@ -3029,11 +3115,26 @@ class LLMEngine:
         self.metrics.structured_mask_seconds.observe(dt)
         return bias
 
+    def _replicated(self, x: jax.Array) -> jax.Array:
+        if self.mesh is None:
+            return x
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(x, NamedSharding(self.mesh, PartitionSpec()))
+
     def _sample_dispatch(self, rows_and_seqs: list[tuple[int, "Sequence"]],
                          logits: jax.Array,
-                         bias: Optional[np.ndarray] = None) -> dict:
+                         bias: Optional[np.ndarray] = None,
+                         ahead_rows: "set[int] | frozenset[int]" = frozenset(),
+                         ) -> dict:
         """Launch sampling on device (chains on the step that made ``logits``)
-        and start the device->host copy; no sync point here."""
+        and start the device->host copy; no sync point here. With no row to
+        sample (prefill chunks short of their prompts' ends) the record holds
+        no array, and reading it reads only what else the step left.
+        ``ahead_rows``: the batch rows whose input token was taken on the
+        device, for the count of what riding ahead kept."""
+        if not rows_and_seqs:
+            return {"sampled": None, "rows": []}
         B = logits.shape[0]
         temp = np.zeros((B,), np.float32)
         tk = np.zeros((B,), np.int32)
@@ -3054,41 +3155,69 @@ class LLMEngine:
         else:
             sampled = sample_tokens(logits.astype(jnp.float32), sub,
                                     jnp.asarray(temp), jnp.asarray(tk), jnp.asarray(tp))
+        sampled = self._replicated(sampled)  # the next step's prev_sampled
         try:
             sampled.copy_to_host_async()
         except (AttributeError, RuntimeError):
             pass
         self.programs.record_dispatch("sample")
         return {"sampled": sampled,
-                "rows": [(i, s, s.slot) for i, s in rows_and_seqs]}
+                "rows": [(i, s, s.slot, i in ahead_rows)
+                         for i, s in rows_and_seqs]}
 
-    def _flush_pending_sample(self) -> None:
+    def _flush_pending_sample(self, parts: Optional[_StepParts] = None) -> None:
+        """Read and apply the unified step in flight, if any: whoever builds
+        on host token state calls this first (the fused decode and verify
+        programs, a preemption, a batch with a constrained row, an empty
+        plan). After it every running row's last token is on the host."""
         rec, self._pending_sample = self._pending_sample, None
-        if rec is not None:
-            # outside a unified step (a decode or verify program flushes the
-            # last prefill's sample first): the read is still the unified
-            # program's, booked under program="sample" since no unified
-            # step_duration sample covers it
-            parts = _StepParts("sample", "llmd.unified", None)
-            try:
-                self._sample_apply(rec, parts)
-            finally:
-                self._book_parts(parts)
+        if rec is None:
+            return
+        if parts is not None:  # inside a unified step: its own wait and apply
+            self._sample_apply(rec, parts)
+            return
+        # outside a unified step the read is still the unified program's,
+        # booked under program="sample" since no unified step_duration
+        # sample covers it
+        parts = _StepParts("sample", "llmd.unified", None)
+        try:
+            self._sample_apply(rec, parts)
+        finally:
+            self._book_parts(parts)
 
     def _sample_apply(self, rec: dict, parts: _StepParts) -> None:
-        """Read one dispatched sample's tokens (device sync point) and apply;
-        the read alone is ``parts``' wait, the per-row loop its apply."""
+        """Read what one unified step left on the device (the sync point:
+        its sampled tokens, and with MoE its expert counts and drops) and
+        apply it; the reads alone are ``parts``' wait, the per-row loop its
+        apply. A row is skipped if its sequence left meanwhile; one that rode
+        ahead and is skipped was computed for nothing, and counts as such."""
         parts.to("wait")
-        # llmd-lint: allow[hot-host-sync] designed sync point: deferred sample readback, overlapped with the next dispatch
+        if "cnt" in rec:
+            self._eplb_record(rec["cnt"])
+        if "moe_drop" in rec:
+            self._moe_record_dropped(rec["moe_drop"])
+        self.programs.record_complete(rec["prog"])
+        if rec["sampled"] is None:
+            parts.to("apply")
+            return
+        # llmd-lint: allow[hot-host-sync] designed sync point: the previous step's sample readback, under the next step's device time
         sampled = np.asarray(rec["sampled"])
         parts.to("apply")
         self.programs.record_complete("sample")
         now = time.monotonic()
-        for i, s, slot in rec["rows"]:
-            if s.finished or s.slot != slot or self.running[slot] is not s:
-                continue  # aborted / preempted while the sample was in flight
+        kept = discarded = 0
+        for i, s, slot, ahead in rec["rows"]:
+            if not self._still_seated(s, slot):
+                # aborted / preempted while the sample was in flight, or (a
+                # row that rode ahead) ended by the stop token read since
+                discarded += ahead
+                continue
+            kept += ahead
             tok = int(sampled[i])
             s.token_ids.append(tok)
+            # the block this token completes, where the row's compute has
+            # run past it already (it rides ahead in the step in flight)
+            s.maybe_commit_blocks(self.allocs[s.rank])
             if not s.spec_armed:
                 s.spec_flips += 1
             s.spec_armed = True  # fresh token landed: re-probe this row's drafter
@@ -3110,6 +3239,11 @@ class LLMEngine:
                 finish_reason=reason, num_cached_prompt_tokens=s.num_cached_prompt,
                 prompt_len=s.prompt_len,
             ))
+        if kept:
+            self.metrics.unified_ahead_rows.labels(outcome="kept").inc(kept)
+        if discarded:
+            self.metrics.unified_ahead_rows.labels(
+                outcome="discarded").inc(discarded)
 
     def _check_finish(self, seq: Sequence, tok: int) -> tuple[bool, Optional[str]]:
         sp: SamplingParams = seq.sampling

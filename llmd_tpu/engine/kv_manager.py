@@ -279,11 +279,16 @@ class Sequence:
         return self.block_hashes[-1] if self.block_hashes else None
 
     def maybe_commit_blocks(self, alloc: PageAllocator) -> None:
-        """Hash+commit any newly completed pages (called after compute advances)."""
+        """Hash+commit any newly completed pages (called after compute
+        advances, or after a token lands that compute had run ahead of: a
+        block is committed once it is both computed and known to the host)."""
         ps = alloc.page_size
         committed = len(self.block_hashes)
+        done = min(self.num_computed, len(self.token_ids))
+        if (committed + 1) * ps > done:
+            return
         mm = self.mm_hashes()
-        while (committed + 1) * ps <= self.num_computed:
+        while (committed + 1) * ps <= done:
             start = committed * ps
             chunk = self.token_ids[start : start + ps]
             key = self.lora_key if self.lora_key is not None else self.lora_id

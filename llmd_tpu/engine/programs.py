@@ -110,7 +110,7 @@ class ProgramRegistry:
     # ------------------------------------------------------------- accounting
     def record_dispatch(self, name: str) -> None:
         """Count one issued call of ``name``. Unregistered names are allowed
-        (pseudo-programs like the deferred prefill sample read) — counters
+        (pseudo-programs like the unified step's sample read) — counters
         auto-create so the quiesce invariant covers them too."""
         c = self._counters.setdefault(name, _Counters())
         c.dispatched += 1

@@ -496,6 +496,21 @@ class EngineMetrics:
             "finished (the row's sequence ended before the k-th step, or "
             "left while the call was in flight), empty (the seat held no row)",
             labelnames=("outcome",))
+        self.unified_decode_rows = reg.counter(
+            "llmd_tpu:unified_decode_rows_total",
+            "Decode rows of unified steps by where the row's input token "
+            "was: device (the previous step's sampled array, not yet read: "
+            "the step ran one ahead of the host), host (packed from the "
+            "sequence's tokens, after a read)",
+            labelnames=("token",))
+        self.unified_ahead_rows = reg.counter(
+            "llmd_tpu:unified_ahead_rows_total",
+            "Decode rows that took their input token on the device, by what "
+            "became of them when their own step was read: kept (the token "
+            "went to the request), discarded (the sequence had ended on the "
+            "token read in between, a stop token, or left: computed for "
+            "nothing)",
+            labelnames=("outcome",))
         self.program_kv_read_tokens = reg.counter(
             "llmd_tpu:program_kv_read_tokens_total",
             "Context tokens (KV positions) over the rows of each dispatch, "

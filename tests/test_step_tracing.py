@@ -85,8 +85,10 @@ def test_decode_seat_steps_exact_for_a_constructed_batch():
     the prefill's sample, five from fused calls. Unpipelined, that is two
     calls: 4 kept a row, then 1 kept and 3 step-slots past the end."""
     eng = _engine(pipeline_decode=False)
-    # all three prefill in one unified step, so none decodes ahead of the rest
-    prompts = [list(range(10, 30)), list(range(40, 52)), list(range(60, 76))]
+    # all three prefill in one unified step (32 tokens, its whole budget): a
+    # prompt left for a second step would find the others riding in it as
+    # decode rows, ahead of the fused calls
+    prompts = [list(range(10, 22)), list(range(40, 48)), list(range(60, 72))]
     out = eng.generate(prompts, SamplingParams(max_tokens=6, **GREEDY))
     assert all(len(v) == 6 for v in out.values())
     seats = _samples(eng.registry, "llmd_tpu:decode_seat_steps_total")
