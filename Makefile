@@ -3,9 +3,9 @@
 
 PY ?= python
 
-.PHONY: check check-quick test bench dryrun lint manifests chaos structured slo device-obs kvplane decisions durable util moe pd
+.PHONY: check check-quick test dryrun lint manifests chaos structured slo device-obs kvplane decisions durable util moe pd
 
-# full gate: lint + manifests + suite + tiny bench + 8-device dryrun
+# full gate: lint + manifests + check tools + suite + 8-device dryrun
 check:
 	$(PY) tools/ci_gate.py
 
@@ -26,9 +26,6 @@ lint:
 
 manifests:
 	$(PY) tools/validate_manifests.py deploy
-
-bench:
-	$(PY) bench.py --tiny --cpu
 
 # router resilience vs fault-injected endpoints (goodput >= 99%, no 5xx)
 chaos:

@@ -37,18 +37,9 @@ class EngineConfig:
     max_queue: int = 1024
     # Multi-step decode: run N decode iterations in one on-device lax.scan (one host
     # round-trip per N tokens). Stop/max_tokens handled post-hoc by truncation.
+    # Calls are dispatched chained on the previous call's device-resident
+    # sampled tokens and read one call later (engine.DECODE_CHAIN_DEPTH).
     decode_steps: int = 1
-    # Pipelined decode dispatch (async output processing): launch call N+1 chained
-    # on call N's device-resident sampled tokens, read N's results while N+1 runs —
-    # hides the device→host round-trip that otherwise serializes every call.
-    pipeline_decode: bool = True
-    # In-flight fused-decode calls the host keeps queued (pipeline_decode only).
-    # Depth 1 leaves the device idle for one round trip between calls (N+1's
-    # launch only reaches the device around the time N's tokens reach the host);
-    # depth 2 keeps a launched call behind the running one, so the device goes
-    # back-to-back and the host round-trip fully hides. Costs up to
-    # depth*decode_steps speculative tokens per sequence at EOS.
-    pipeline_depth: int = 2
     # KV offload tier (pages of CPU-side cache; 0 = disabled) — K3 equivalent
     # (TPU_OFFLOAD_NUM_CPU_CHUNKS / STAGING_BLOCKS knobs of the reference connector).
     cpu_offload_pages: int = 0
@@ -75,21 +66,11 @@ class EngineConfig:
     # the fused-decode program takes the latent-width Pallas kernel
     # (ops/mla_decode) on TPU under "auto", anywhere under "pallas".
     attn_impl: str = "auto"
-    # Attention block-size auto-tune table (ops/attn_tune): path to the JSON
-    # cache bench.py's on-chip tuner exports; pick_block_sizes consults it per
-    # (batch, page_size, head layout) before its heuristic. None = resolve
-    # LLMD_ATTN_TUNE_FILE from the environment (missing/corrupt files degrade
-    # to the heuristic with a warning, never a startup failure).
-    attn_tune_file: "str | None" = None
     # Long-context sequence parallelism: when mesh.sp > 1, serve self-contained
     # single-sequence prefill steps through the zig-zag ring-attention program
     # (ops/ring_attention.py) instead of GSPMD-annotated paged attention. The
     # engine gates eligibility per step; decode always stays on the paged path.
     sp_ring_attention: bool = True
-    # Per-phase timing attribution (bench.py): forces a device sync after each
-    # unified step so host/device/post are separable. Off in production serving —
-    # the sync serializes host packing against in-flight device work.
-    instrument: bool = False
     # MoE expert GEMMs: "auto" = Pallas grouped GEMM on TPU / einsum elsewhere,
     # "pallas" = force (interpret mode on the CPU), "einsum" = XLA dot path.
     moe_matmul: str = "auto"
@@ -139,31 +120,15 @@ class EngineConfig:
     # biased sampler; everything else keeps the exact unbiased programs),
     # "off" = reject structured requests at admission (ValueError -> 400).
     structured_mode: str = "auto"
-    # Device-resident decode steady state (PERF.md Lever 12). pack_overlap:
-    # while chain N runs on device, the host packs chain N+1 into rotated
-    # pre-staged buffers and reuses the in-flight chain's device-resident
-    # pos/lens/token outputs, so only the rows that actually changed cross
-    # the host->device boundary; the pack wall is accounted as
-    # time_pack_overlap (hidden behind device compute) instead of
-    # time_host_pack. False restores the legacy serialized pack + accounting.
-    pack_overlap: bool = True
     # Constrained rows (grammar masks / logit_bias) ride the fused multi-step
     # decode program with the bias apply + FSM transition done on device
-    # (structured/grammar.py dense_tables), instead of degrading the whole
-    # batch to 1-token unified steps. Rows combining a grammar AND a
-    # logit_bias, or tables past structured_table_max_elems, still degrade.
-    structured_fused_decode: bool = True
-    # Upper bound on the staged mask-table size (G_pad * S_pad * V elements,
-    # f32 bias + i32 next ~= 8 bytes/element). Past this, constrained rows
-    # fall back to the unified path rather than staging a huge table.
+    # (structured/grammar.py dense_tables), and draft through the host
+    # automaton into the grammar-masked verify program. This is the upper
+    # bound on the staged mask-table size (G_pad * S_pad * V elements, f32
+    # bias + i32 next ~= 8 bytes/element): past it, and for a row combining a
+    # grammar AND a logit_bias, constrained rows fall back to 1-token unified
+    # steps rather than staging a huge table.
     structured_table_max_elems: int = 1 << 23
-    # Speculation × structured compose (PERF.md Lever 13): constrained rows
-    # draft through the host automaton (longest grammar-legal prefix of the
-    # n-gram continuation) and verify through the grammar-masked verify
-    # program, which returns each row's post-acceptance FSM state so the host
-    # resync becomes a recovery path. False restores the legacy behavior:
-    # constrained rows never draft and their presence disables verify steps.
-    spec_structured: bool = True
     # Debug cross-check: after every masked verify step, re-derive each
     # constrained row's FSM state on host (StructuredState.sync over the
     # accepted tokens) and compare against the device-returned state; a

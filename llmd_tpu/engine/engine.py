@@ -21,7 +21,7 @@ continuous batching on accelerator'), built XLA-first:
 - automatic prefix caching with chained block hashes + KV events (kv_manager),
 - preemption by recompute when pages run out (vLLM semantics),
 - kernel provenance: which attention / MoE implementation the platform/shape
-  rule selected is recorded on the engine and surfaced by bench.py — a perf
+  rule selected is recorded on the engine and exported on /metrics — a perf
   number without kernel provenance is undiagnosable,
 - P/D roles: ``role=prefill`` stops after prompt processing and exports KV metadata
   (disagg connector picks it up); ``role=decode`` can import KV (disagg/transfer.py).
@@ -88,6 +88,12 @@ def _profile_phase(name: str):
 
 
 STEP_PARTS = ("plan", "pack", "dispatch", "sample", "wait", "apply", "book")
+
+# Fused-decode calls kept in flight: one behind the running one, so the device
+# goes back-to-back while the finished call's tokens cross back to the host.
+# Costs up to DECODE_CHAIN_DEPTH * decode_steps speculative tokens a sequence
+# at EOS.
+DECODE_CHAIN_DEPTH = 2
 
 
 class _StepParts:
@@ -166,7 +172,6 @@ class EngineStats:
     total_offload_loads: int = 0  # blocks pulled back from CPU/FS tiers
     eplb_rebalances: int = 0  # wide-EP expert-placement recomputes
     attn_backend: str = ""  # kernel provenance (bench/debug)
-    attn_tune_hash: Optional[str] = None  # active block-size tune table (ops/attn_tune)
     moe_backend: str = ""
     moe_dispatch: str = ""  # "sorted" | "einsum" — routing-dispatch provenance
     moe_dropped_tokens: int = 0  # routed copies dropped past capacity (einsum
@@ -175,8 +180,8 @@ class EngineStats:
     kv_layout: str = ""  # "padded" | "packed-f" — pool lane layout provenance
     sp_attn_backend: Optional[str] = None  # ring layout when sp>1 wired in
     n_ring_prefill_steps: int = 0  # unified steps served by the ring program
-    # Per-phase wall-time attribution (bench.py breakdown — every serving-perf
-    # number must be decomposable into where the time actually went):
+    # Per-phase wall-time attribution (every serving-perf number must be
+    # decomposable into where the time actually went):
     time_prefill_steps: float = 0.0  # wall inside unified (mixed/prefill) steps
     time_decode_steps: float = 0.0  # wall inside fused decode calls
     time_spec_steps: float = 0.0  # wall inside speculative verify steps
@@ -184,7 +189,7 @@ class EngineStats:
     # them further (STEP_PARTS).
     time_host_pack: float = 0.0  # row choice + numpy staging (plan + pack)
     # unified/verify step: ENQUEUE time of the jitted call (the dispatch is
-    # asynchronous; a device sync only under cfg.instrument). Fused decode:
+    # asynchronous, no device sync). Fused decode:
     # the blocking read of the sampled tokens in _decode_process
     time_device: float = 0.0
     time_device_decode: float = 0.0  # the decode-call share of time_device
@@ -402,7 +407,7 @@ class LLMEngine:
         self._n_steps = 0  # step_num of the llmd.step annotation
         self._pending_decode: list[dict] = []  # in-flight pipelined decode calls
         # Device-resident decode steady state (PERF.md Lever 12): rotated
-        # host-pack buffer sets — pipeline_depth+1 of them so the buffers a
+        # host-pack buffer sets — DECODE_CHAIN_DEPTH+1 of them so the buffers a
         # still-in-flight dispatch was packed from are never mutated while
         # jnp.asarray may still alias them (the CPU backend zero-copies).
         self._pack_bufs: list[dict[str, "np.ndarray"]] = []
@@ -522,16 +527,6 @@ class LLMEngine:
 
         cfg = model_cfg
         mesh = self.mesh
-        # shape-keyed attention block-size tune table (bench.py's auto-tuner
-        # export, ops/attn_tune): an explicit config path pins the table;
-        # otherwise LLMD_ATTN_TUNE_FILE resolves lazily inside
-        # pick_block_sizes. The short hash rides provenance (stats/bench JSON)
-        # so every measured number traces to the table that shaped its kernels.
-        from llmd_tpu.ops import attn_tune
-
-        if engine_cfg.attn_tune_file:
-            attn_tune.activate(attn_tune.load_table(engine_cfg.attn_tune_file))
-        self.attn_tune_hash = attn_tune.active_hash()
         # Pallas kernels run in interpret mode on the CPU platform only (an
         # explicit attn_impl/moe_matmul="pallas" under tests); on a TPU the
         # selected kernel goes through Mosaic or the engine fails
@@ -549,15 +544,13 @@ class LLMEngine:
         moe_impl = self._select_moe_impl()
         moe_dispatch_impl = self._select_moe_dispatch()
         self.stats.attn_backend = self.attn_backend
-        self.stats.attn_tune_hash = self.attn_tune_hash
         self.attn_geometry = self._attn_geometry()
         self.stats.moe_backend = self.moe_backend
         self.stats.moe_dispatch = self.moe_dispatch
         # kernel-vs-fallback visibility without scraping logs: an info-style
-        # gauge keyed by the resolved backend + tune-table hash (value 1)
+        # gauge keyed by the resolved backend and its block geometry (value 1)
         self.metrics.attn_backend_info.labels(
             backend=self.attn_backend,
-            tune=self.attn_tune_hash or "none",
             geometry=self.attn_geometry).set(1)
         self.stats.kv_cache_dtype = ("fp8" if self.kv_dtype == jnp.float8_e4m3fn
                                      else str(jnp.dtype(self.kv_dtype).name))
@@ -923,8 +916,8 @@ class LLMEngine:
         """The (bkv, bq) block geometry the ragged Pallas kernel is traced
         with in the two step programs that carry the load, as
         ``unified=<bkv>x<bq> decode=<bkv>x<bq>``; ``none`` where another
-        backend serves. It is a function of static shapes (and of the tune
-        table and overrides read at trace time), so it is known here."""
+        backend serves. It is a function of static shapes, so it is known
+        here."""
         if not self.attn_backend.startswith("pallas_ragged_paged_attention"):
             return "none"
         from llmd_tpu.ops.paged_attention import call_geometry
@@ -1665,8 +1658,8 @@ class LLMEngine:
     def _unified_eligible(self) -> bool:
         """The unified mixed step serves prefill chunks, and remains the
         1-token degrade for constrained rows the dense-table scheme can't
-        express (structured_fused_decode off, a row combining grammar AND
-        logit_bias, or tables past the structured_table_max_elems gate)."""
+        express (a row combining grammar AND logit_bias, or tables past the
+        structured_table_max_elems gate)."""
         if self._prefilling_seqs():
             return True
         return (any(s is not None and (s.structured is not None or s.logit_bias)
@@ -1964,9 +1957,6 @@ class LLMEngine:
             jnp.asarray([len(plan)], jnp.int32), jnp.asarray(lora_tok),
             prev_sampled, *mm_args,
         )
-        if self.cfg.instrument:
-            # llmd-lint: allow[hot-host-sync] instrument-gated timing barrier; off in production serving
-            logits.block_until_ready()
         parts.to("apply")
 
         # goodput classification reads pre-postprocess sequence state: the
@@ -2069,12 +2059,12 @@ class LLMEngine:
         """Fused multi-step decode with pipelined dispatch.
 
         Reading sampled tokens costs a host<->device round trip per call
-        (PERF.md has the measured figure): with ``cfg.pipeline_decode`` the
-        host dispatches call
-        N+1 chained on call N's *device-resident* last tokens, then reads call
+        (PERF.md has the measured figure), so the host dispatches call N+1
+        chained on call N's *device-resident* last tokens, then reads call
         N's results while N+1 runs — vLLM's async output processing, XLA-style.
         The chain holds only while the active set is unchanged; any membership
-        change (finish, preemption, new prefill) flushes first.
+        change (finish, preemption, new prefill) flushes first. The
+        unpipelined reading is ``_flush_pending_decode()`` after every step.
 
         ``parts`` times the dispatch side (plan here, the rest in
         ``_decode_dispatch``); it is paused around every nested program,
@@ -2140,14 +2130,14 @@ class LLMEngine:
         if q:
             same = {(s.request_id, s.slot) for s in active} == {
                 (s.request_id, slot) for s, slot in q[-1]["rows"]}
-            if same and self.cfg.pipeline_decode:
+            if same:
                 rec = self._decode_dispatch(active, k, chain=q[-1], parts=parts,
                                             off=off)
                 q.append(rec)
-                # keep up to pipeline_depth calls in flight: the queued call
+                # keep DECODE_CHAIN_DEPTH calls in flight: the queued call
                 # behind the running one lets the device go back-to-back while
                 # the finished call's tokens cross back to the host
-                if len(q) > max(1, self.cfg.pipeline_depth):
+                if len(q) > DECODE_CHAIN_DEPTH:
                     self._decode_process(q.pop(0))
                 return
             parts.to(None)
@@ -2157,11 +2147,7 @@ class LLMEngine:
             active = [s for s in self._decode_ready() if s.slot >= 0]
             if not active:
                 return
-        rec = self._decode_dispatch(active, k, chain=None, parts=parts)
-        if self.cfg.pipeline_decode:
-            q.append(rec)
-        else:
-            self._decode_process(rec)
+        q.append(self._decode_dispatch(active, k, chain=None, parts=parts))
 
     def _flush_pending_decode(self) -> None:
         q, self._pending_decode = self._pending_decode, []
@@ -2213,10 +2199,7 @@ class LLMEngine:
         prefix of ``draft`` its constraint allows. Grammar rows walk the host
         automaton from the synced cursor (an idempotent ``sync`` first — the
         cursor must reflect every committed token before extrapolating);
-        logit_bias rows cut at the first effectively-banned token. Returns []
-        when spec_structured is off (legacy: constrained rows never draft)."""
-        if not self.cfg.spec_structured:
-            return []
+        logit_bias rows cut at the first effectively-banned token."""
         stt = s.structured
         if stt is not None:
             fresh = stt.sync(s.token_ids, s.prompt_len)
@@ -2246,14 +2229,12 @@ class LLMEngine:
             return False
         # Constrained rows ride verify ONLY through the masked verify program
         # (grammar bias + FSM advance fused per packed position). When the
-        # compose knob is off, or the batch's mask plan is inexpressible as
-        # dense tables (combined grammar+bias row, table-size gate), the
-        # batch falls back to the fused decode path, which has its own
-        # masked/degrade handling.
-        if any(s.structured is not None or s.logit_bias for s in active):
-            if not (self.cfg.spec_structured
-                    and self._plan_chain_masks(active) is not None):
-                return False
+        # batch's mask plan is inexpressible as dense tables (combined
+        # grammar+bias row, table-size gate), the batch falls back to the
+        # fused decode path, which has its own masked/degrade handling.
+        if (any(s.structured is not None or s.logit_bias for s in active)
+                and self._plan_chain_masks(active) is None):
+            return False
         # Greedy acceptance is only bitwise-equivalent to sequential decoding
         # for greedy rows; a batch with sampled sequences falls back to the
         # fused decode path.
@@ -2309,8 +2290,7 @@ class LLMEngine:
             # a constrained row may have become decode-ready during the flush:
             # re-check masked-verify eligibility on the FINAL plan — an
             # ineligible row must never ride the unmasked verify program
-            if not (self.cfg.spec_structured and self._plan_chain_masks(
-                    [s for s, _ in plan]) is not None):
+            if self._plan_chain_masks([s for s, _ in plan]) is None:
                 return False
         if not any(d for _, d in plan):
             # fresh state proposes nothing: plain decode instead — and no
@@ -2529,17 +2509,15 @@ class LLMEngine:
     def _plan_chain_masks(self, active: list[Sequence]) -> Optional[dict]:
         """Table-slot assignment + size gate for the fused masked decode
         program. None = this batch's constrained rows cannot ride it and must
-        degrade to 1-token unified steps: the knob is off, a row combines a
-        grammar AND a logit_bias (two bias sources, one table slot), or the
-        padded tables would exceed structured_table_max_elems.
+        degrade to 1-token unified steps: a row combines a grammar AND a
+        logit_bias (two bias sources, one table slot), or the padded tables
+        would exceed structured_table_max_elems.
 
         Tables are shared BY GRAMMAR, not by row — G is 1 (the zero no-op
         grammar unconstrained rows index) + distinct grammars + one slot per
         logit_bias row, so a batch of 64 rows sharing one JSON schema stages
         one [2ᵖ, S_pad, V] pair, not 64.
         """
-        if not self.cfg.structured_fused_decode:
-            return None
         entries: list[tuple] = []  # table slot -1 -> ("g", grammar)|("b", items)
         rows: list[tuple] = []  # (seq, table slot) for constrained rows
         gram_slot: dict[int, int] = {}
@@ -2704,7 +2682,7 @@ class LLMEngine:
 
     def _pack_buf(self) -> dict[str, np.ndarray]:
         """Rotated host-pack buffer set for the chained fast path. There are
-        pipeline_depth+1 sets, indexed by dispatch count: a set is never
+        DECODE_CHAIN_DEPTH+1 sets, indexed by dispatch count: a set is never
         refilled until the dispatch that uploaded from it has been processed
         (the readback in ``_decode_process`` forces that computation), so the
         CPU backend's zero-copy ``jnp.asarray`` aliasing can never observe a
@@ -2714,7 +2692,7 @@ class LLMEngine:
             B = self.cfg.max_batch_size
             self._pack_bufs = [
                 {"steps_left": np.zeros((B,), np.int32)}
-                for _ in range(max(1, self.cfg.pipeline_depth) + 1)]
+                for _ in range(DECODE_CHAIN_DEPTH + 1)]
         return self._pack_bufs[
             self.stats.n_decode_dispatches % len(self._pack_bufs)]
 
@@ -2727,8 +2705,8 @@ class LLMEngine:
 
         Two pack regimes (PERF.md Lever 12):
 
-        * chain start (or ``pack_overlap`` off): full host pack into fresh
-          arrays — the admission/retire boundary where the host owns the loop.
+        * chain start: full host pack into fresh arrays — the admission/retire
+          boundary where the host owns the loop.
         * chained fast path: the previous call's device-resident tokens,
           positions, kv lens, and FSM states feed straight back in; the host
           re-derives only ``steps_left`` (the per-row hard budget) and, when a
@@ -2737,7 +2715,7 @@ class LLMEngine:
           (accounted as time_pack_overlap, not time_host_pack).
         """
         B = self.cfg.max_batch_size
-        fast = chain is not None and self.cfg.pack_overlap
+        fast = chain is not None
         # the fast path's pack already sits in llmd.pack_overlap
         parts.to("pack", annotate=not fast)
         ctx_tokens = 0  # context the call's first step reads, over its rows
@@ -2790,7 +2768,7 @@ class LLMEngine:
                 i = s.slot
                 eff_len = len(s.token_ids) + off  # host view + in-flight tokens
                 ctx_tokens += eff_len
-                toks[i] = s.token_ids[-1]  # unused when chaining (device wins)
+                toks[i] = s.token_ids[-1]
                 pos[i] = eff_len - 1
                 pts_np[i, : len(s.pages)] = s.pages
                 lens_np[i] = eff_len
@@ -2807,18 +2785,14 @@ class LLMEngine:
                                         jnp.asarray(tp))
             lora_dev = jnp.asarray(lora_idx)
             steps_dev = jnp.asarray(steps_left)
-            toks_in = (chain["last_toks"] if chain is not None
-                       else jnp.asarray(toks))
-            if chain is not None:
-                mask, fsm_in = chain["mask"], chain["fsm_out"]
-            else:
-                mask = (self._stage_chain_masks(active)
-                        if any(s.structured is not None or s.logit_bias
-                               for s in active) else None)
-                fsm_in = mask["fsm0"] if mask is not None else None
-                for s in active:
-                    self.flight.record(s.request_id, "chain_dispatch", k=k,
-                                       masked=mask is not None)
+            toks_in = jnp.asarray(toks)
+            mask = (self._stage_chain_masks(active)
+                    if any(s.structured is not None or s.logit_bias
+                           for s in active) else None)
+            fsm_in = mask["fsm0"] if mask is not None else None
+            for s in active:
+                self.flight.record(s.request_id, "chain_dispatch", k=k,
+                                   masked=mask is not None)
         self._key, sub = jax.random.split(self._key)
         parts.to("dispatch")
         sec = parts.seconds

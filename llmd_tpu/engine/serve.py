@@ -58,10 +58,6 @@ def main() -> None:
                     help="attention kernel selection (EngineConfig.attn_impl);"
                          " MLA decode takes the latent Pallas kernel on TPU "
                          "under auto, anywhere under pallas")
-    ap.add_argument("--attn-tune-file",
-                    default=os.environ.get("LLMD_ATTN_TUNE_FILE"),
-                    help="shape-keyed attention block-size table "
-                         "(ops/attn_tune JSON, written by bench.py's tuner)")
     ap.add_argument("--moe-dispatch",
                     default=os.environ.get("LLMD_MOE_DISPATCH", "") or "auto",
                     choices=["auto", "sorted", "einsum"],
@@ -93,36 +89,11 @@ def main() -> None:
                     help="structured outputs (llmd_tpu/structured): 'auto' = "
                          "compile grammars for requests that ask, 'off' = "
                          "reject structured requests as 400")
-    ap.add_argument("--decode-chain-depth", type=int,
-                    default=int(os.environ.get("LLMD_DECODE_CHAIN_DEPTH", "2")),
-                    help="fused decode calls kept in flight per chain "
-                         "(EngineConfig.pipeline_depth); deeper chains hide "
-                         "more host pack/readback wall behind device compute")
-    ap.add_argument("--pack-overlap",
-                    default=os.environ.get("LLMD_PACK_OVERLAP", "on"),
-                    choices=["on", "off"],
-                    help="chained dispatches reuse the in-flight call's "
-                         "device-resident tokens/positions/kv-lens and pack "
-                         "only changed rows, overlapped with device compute; "
-                         "'off' restores the serialized full pack")
-    ap.add_argument("--structured-fused",
-                    default=os.environ.get("LLMD_STRUCTURED_FUSED", "on"),
-                    choices=["on", "off"],
-                    help="constrained rows ride the fused masked decode "
-                         "program (on-device bias + FSM transition); 'off' "
-                         "degrades them to 1-token unified steps")
     ap.add_argument("--structured-table-elems", type=int,
                     default=int(os.environ.get("LLMD_STRUCTURED_TABLE_ELEMS",
                                                str(1 << 23))),
                     help="max staged mask-table size (G_pad*S_pad*V elements) "
                          "before constrained rows degrade to unified steps")
-    ap.add_argument("--spec-structured",
-                    default=os.environ.get("LLMD_SPEC_STRUCTURED", "on"),
-                    choices=["on", "off"],
-                    help="constrained rows compose with speculation: drafts "
-                         "truncate to their grammar-legal prefix and verify "
-                         "through the grammar-masked verify program; 'off' "
-                         "restores the legacy never-draft behavior")
     ap.add_argument("--spec-structured-crosscheck",
                     default=os.environ.get("LLMD_SPEC_STRUCTURED_CROSSCHECK",
                                            "off"),
@@ -178,16 +149,11 @@ def main() -> None:
         kv_cache_dtype=args.kv_cache_dtype,
         kv_layout=args.kv_layout,
         attn_impl=args.attn_impl,
-        attn_tune_file=args.attn_tune_file,
         moe_dispatch=args.moe_dispatch,
         spec_mode=args.spec_mode, spec_tokens=args.spec_tokens,
         spec_ngram_max=args.spec_ngram_max, spec_ngram_min=args.spec_ngram_min,
         structured_mode=args.structured_mode,
-        pipeline_depth=max(1, args.decode_chain_depth),
-        pack_overlap=args.pack_overlap == "on",
-        structured_fused_decode=args.structured_fused == "on",
         structured_table_max_elems=args.structured_table_elems,
-        spec_structured=args.spec_structured == "on",
         spec_structured_crosscheck=args.spec_structured_crosscheck == "on",
     )
     if args.enable_lora:

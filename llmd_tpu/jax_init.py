@@ -1,5 +1,5 @@
-"""JAX start-up for entry points (``engine/serve.py``, ``bench.py``,
-``chip_smoke.py``'s children, the warm-start probe's child): which platform,
+"""JAX start-up for entry points (``engine/serve.py``, ``chip_smoke.py``'s
+children, the benchmark's engine child): which platform,
 and where compiled programs persist. One rule, one module.
 
 Platform. JAX falls back to the CPU by itself when it finds no accelerator,

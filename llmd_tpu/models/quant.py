@@ -1,8 +1,7 @@
 """Weight-only int8 quantization for the decode-bandwidth-bound serving regime.
 
 Decode reads every weight byte once per step — on a v5e the 819 GB/s HBM
-ceiling, not the MXU, bounds single-chip decode throughput (bench.py's
-weights-BW utilization). Symmetric per-output-channel int8 halves the weight
+ceiling, not the MXU, bounds single-chip decode throughput. Symmetric per-output-channel int8 halves the weight
 bytes against bf16, so the decode roofline doubles, at the cost of a <0.5%-
 scale per-channel rounding error. The reference's headline baselines serve
 fp8 on B200 (BASELINE.md row 5) — reduced-precision weights are parity, not

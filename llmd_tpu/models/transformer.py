@@ -45,25 +45,6 @@ from llmd_tpu.models.config import ModelConfig
 LANE = 128
 
 
-def layer_unroll(num_layers: Optional[int] = None) -> int:
-    """Effective layer-scan unroll width from ``LLMD_LAYER_UNROLL``.
-
-    Unrolling lets XLA overlap layer N+1's HBM weight stream with layer N's
-    compute (a scanned body is one program XLA cannot software-pipeline across
-    iterations); decode is weights-BW-bound, so hiding part of the stream
-    matters. Cost is compile time. Read at trace time — set before the engine
-    builds. The ONE parse used by both the trace site and bench provenance,
-    so an artifact can never label an unrolled run as baseline.
-    """
-    import os
-
-    try:
-        n = max(1, int(os.environ.get("LLMD_LAYER_UNROLL", "1")))
-    except ValueError:
-        n = 1
-    return min(n, num_layers) if num_layers else n
-
-
 def padded_head_dim(head_dim: int) -> int:
     """Head dim as stored in the KV cache: padded up to the 128-lane tile."""
     return max(LANE, ((head_dim + LANE - 1) // LANE) * LANE)
@@ -735,7 +716,6 @@ def forward_core(
         body,
         (x, cache.reshape(Ptot * ps, HkC, Dhp)),
         (layer_params, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
-        unroll=layer_unroll(cfg.num_layers),
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     return x, flat_cache.reshape(Ptot, ps, HkC, Dhp), expert_counts, dropped.sum()

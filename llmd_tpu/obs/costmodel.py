@@ -6,8 +6,7 @@ The live analog of PERF.md's paper math. Three pieces:
    the model config and the dispatch's PACKED shape (the work the compiled
    program executes, padding included: NT positions for the mixed-batch and
    verify programs, B x k slot-steps for fused decode). The matmul term is
-   ``2 * active_params`` per slot position — identical to bench.py's offline
-   ``flops_per_tok`` so the live MFU and the bench headline can never drift.
+   ``2 * active_params`` per slot position.
    The byte term is weight passes + KV page traffic (read + write) from the
    pool's per-token width. Attention score/value FLOPs are O(len * Dh) per
    token against the O(params) matmul term and are deliberately excluded,
@@ -28,10 +27,9 @@ The live analog of PERF.md's paper math. Three pieces:
    a compile-time histogram.
 
 3. **Peak table** — the single source of truth for device-generation peaks
-   (bf16 TFLOP/s, HBM GB/s), previously a private dict in bench.py;
-   ``LLMD_UTIL_PEAKS_FILE`` overlays a JSON map for new generations without
-   a code change. bench.py, tools/profile_decode.py and tools/membw.py all
-   consume :func:`chip_peaks`.
+   (bf16 TFLOP/s, HBM GB/s); ``LLMD_UTIL_PEAKS_FILE`` overlays a JSON map
+   for new generations without a code change. tools/membw.py consumes
+   :func:`chip_peaks` too.
 
 Off-switch contract (mirrors obs/decisions.py): ``LLMD_UTIL_LEDGER=0``
 (or ``off``/``false``/empty) is read ONCE at engine construction; the off
@@ -109,8 +107,8 @@ def chip_peaks(
 ) -> Tuple[Optional[float], Optional[float]]:
     """(bf16 TFLOP/s, HBM GB/s) for a device kind, or (None, None) when the
     generation is not in the table — the CPU and unlisted chips export null
-    peaks so MFU/MBU gauges go absent rather than lie, and bench.py refuses
-    to run on them. There is no default peak."""
+    peaks so MFU/MBU gauges go absent rather than lie. There is no default
+    peak."""
     table = _peaks_overlay()
     # longest-match first so "TPU v5 lite" wins over a hypothetical "TPU v5"
     for k in sorted(table, key=len, reverse=True):
@@ -125,10 +123,9 @@ def chip_peaks(
 
 
 def param_count(cfg) -> int:
-    """Total weight parameters (bench.py's formula, extended for MoE).
+    """Total weight parameters.
 
-    Dense: qkvo + swiglu per layer, plus (un)tied embeddings — byte-for-byte
-    the historical bench._param_count. MoE adds the expert banks (+ shared
+    Dense: qkvo + swiglu per layer, plus (un)tied embeddings. MoE adds the expert banks (+ shared
     experts) in place of the dense FFN, plus the router.
     """
     D, L = cfg.hidden_size, cfg.num_layers
@@ -190,7 +187,7 @@ def kv_bytes_per_token(cfg, kv_cache_dtype: Optional[str] = None) -> int:
 
 def decode_hbm_gb_per_token(cfg, quantize_weights: Optional[str],
                             max_batch_size: int) -> float:
-    """bench.py's offline per-token weights traffic: one full weight pass
+    """Offline per-token weights traffic: one full weight pass
     amortized over the decode batch (GB/token)."""
     return (weight_bytes(cfg, quantize_weights) / 1e9
             / max(1, max_batch_size))

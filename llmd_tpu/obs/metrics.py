@@ -530,11 +530,11 @@ class EngineMetrics:
             "Wall seconds of those compiles")
         self.attn_backend_info = reg.gauge(
             "llmd_tpu:engine_attn_backend",
-            "Resolved attention backend, active block-size tune-table hash "
-            "and the (KV pages, query rows) block geometry the unified and "
-            "fused-decode programs trace the ragged Pallas kernel with "
-            "(info-style: value 1 on the selected label set)",
-            labelnames=("backend", "tune", "geometry"))
+            "Resolved attention backend and the (KV pages, query rows) block "
+            "geometry the unified and fused-decode programs trace the ragged "
+            "Pallas kernel with (info-style: value 1 on the selected label "
+            "set)",
+            labelnames=("backend", "geometry"))
         self.batch_occupancy = reg.histogram(
             "llmd_tpu:engine_batch_occupancy",
             "Running/waiting sequence counts sampled once per engine step",

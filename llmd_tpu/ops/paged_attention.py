@@ -45,8 +45,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from llmd_tpu.ops import attn_tune
-
 VMEM_LIMIT = 100 * 1024 * 1024
 
 
@@ -97,69 +95,25 @@ KV_BLOCK_TOKENS = 512
 KV_BLOCK_MAX_PAGES = 32
 
 
-def pick_block_sizes(num_tokens: int, page_size: int, pages_per_seq: int,
-                     *, head_layout: "str | None" = None) -> tuple[int, int]:
+def pick_block_sizes(num_tokens: int, page_size: int,
+                     pages_per_seq: int) -> tuple[int, int]:
     """(num_kv_pages_per_block, num_queries_per_block) for our serving shapes.
 
-    Resolution order, weakest to strongest:
-
-    1. **the rule**, a function of the call's static shapes only, from the
-       sweep in the module docstring. bkv: `KV_BLOCK_TOKENS` of context a
-       block, at most `KV_BLOCK_MAX_PAGES` pages and at most the sequence's
-       page budget (a short model length is one block a sequence; nothing
-       past its page table is fetched). bq by the token budget N, which is
-       all a trace can see of which step program calls: up to 128 (the fused
-       decode call, one query row a sequence) 8 rows; up to 512 (the unified
-       step: decode rows, then prefill chunks, each of which reads its whole
-       context once per query block) 16; larger prefill budgets keep the 64
-       they had (not swept). Both head layouts swept (12/2 and 32/8 heads of
-       128) want the same pair, so the rule does not read the layout,
-    2. **auto-tune table** (`ops.attn_tune`, loaded from
-       ``LLMD_ATTN_TUNE_FILE`` / `EngineConfig.attn_tune_file`) — bench.py's
-       on-chip tuner's per-(batch, page_size, head layout) winners; an exact
-       batch match replaces the rule,
-    3. ``LLMD_ATTN_BKV`` / ``LLMD_ATTN_BQ`` env overrides — the operator
-       escape hatch (and the legacy single-shape tuner export), applied at
-       decode-gate shapes only (see deploy/ENV_VARS.md).
+    A function of the call's static shapes only, from the sweep in the module
+    docstring. bkv: `KV_BLOCK_TOKENS` of context a block, at most
+    `KV_BLOCK_MAX_PAGES` pages and at most the sequence's page budget (a
+    short model length is one block a sequence; nothing past its page table
+    is fetched). bq by the token budget N, which is all a trace can see of
+    which step program calls: up to 128 (the fused decode call, one query row
+    a sequence) 8 rows; up to 512 (the unified step: decode rows, then
+    prefill chunks, each of which reads its whole context once per query
+    block) 16; larger prefill budgets keep the 64 they had (not swept). Both
+    head layouts swept (12/2 and 32/8 heads of 128) want the same pair, so
+    the rule does not read the layout. `tools/attn_sweep.py` re-measures it.
     """
-    import os
-
     bkv = max(1, min(pages_per_seq, KV_BLOCK_MAX_PAGES,
                      KV_BLOCK_TOKENS // page_size))
     bq = 8 if num_tokens <= 128 else 16 if num_tokens <= 512 else 64
-    table = attn_tune.active_table()
-    if table is not None:
-        # exact (batch, page_size, head_layout) key; nearest pages_per_seq —
-        # non-tuned shapes (e.g. prefill token budgets) miss and keep the rule
-        hit = table.lookup(num_tokens, page_size, pages_per_seq, head_layout)
-        if hit is not None:
-            bkv, bq = hit
-    try:
-        decode_n = int(os.environ.get("LLMD_ATTN_DECODE_N", "128"))
-    except ValueError:
-        decode_n = 128
-    if num_tokens <= decode_n:
-        # overrides are tuned at the DECODE shape (one query per sequence,
-        # num_tokens == batch); the tuner exports that batch size as
-        # LLMD_ATTN_DECODE_N so the gate tracks the shape it validated.
-        # Token batches above it — prefill budgets — keep the swept policy
-        # (short tail chunks below the gate share the decode policy; a
-        # perf-only approximation on the rare last chunk of a prompt).
-        def _env_int(name: str):
-            raw = os.environ.get(name)
-            if not raw:
-                return None
-            try:
-                return int(raw)
-            except ValueError:
-                return None  # malformed operator input: keep the policy
-
-        env_bkv = _env_int("LLMD_ATTN_BKV")
-        env_bq = _env_int("LLMD_ATTN_BQ")
-        if env_bkv:
-            bkv = max(1, min(pages_per_seq, env_bkv))
-        if env_bq:
-            bq = max(1, env_bq)
     return bkv, min(bq, num_tokens)
 
 
@@ -167,11 +121,7 @@ def call_geometry(q_shape, cache_shape, pages_per_seq: int) -> tuple[int, int]:
     """`pick_block_sizes` for a call with these static shapes: what
     `paged_attention_tpu` traces a step program with, and what the engine
     reports on ``llmd_tpu:engine_attn_backend{geometry}``."""
-    N, heads, width = q_shape
-    _, ps, planes, _ = cache_shape
-    return pick_block_sizes(
-        N, ps, pages_per_seq,
-        head_layout=attn_tune.head_layout_key(heads, width, planes))
+    return pick_block_sizes(q_shape[0], cache_shape[1], pages_per_seq)
 
 
 def paged_attention_tpu(

@@ -433,3 +433,28 @@ def test_dump_flight_phases_and_decisions_compose(tmp_path, capsys):
 
     # unknown trace is an error, not an empty render
     assert dump_main([str(dump), "--trace", "nope", "--decisions"]) == 1
+
+
+def test_router_overhead_measures_both_sides_against_its_bounds():
+    """tools/decision_check.py's ledger-overhead reading: the scheduler timed
+    with the ledger off and on, and a verdict that is the documented bound
+    (<2% relative or <25us a call) applied to those two readings. Run in a
+    child: the tool sets the ledger's variables in its own environment."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import json; from tools.decision_check import router_overhead; "
+         "print(json.dumps(router_overhead(n_requests=30, rounds=1)))"],
+        cwd=Path(__file__).resolve().parent.parent, capture_output=True,
+        text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    v = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert v["schedule_us_off"] > 0 and v["schedule_us_on"] > 0
+    assert abs(v["delta_us"] - (v["schedule_us_on"] - v["schedule_us_off"])) < 0.02
+    assert (v["rel_bound"], v["abs_bound_us"]) == (0.02, 25.0)
+    assert v["ok"] == (v["rel_delta"] <= v["rel_bound"]
+                       or v["delta_us"] <= v["abs_bound_us"])
+    assert v["router_overhead"] == ("ok" if v["ok"] else "failed")

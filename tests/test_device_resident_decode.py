@@ -4,9 +4,10 @@ Constrained rows (grammar masks / logit_bias) ride the fused multi-step
 decode program with the bias gather, biased sample, and FSM transition done
 on device (`_decode_multi_masked`), and chained dispatches reuse the
 in-flight call's device-resident tokens/positions/kv-lens instead of a full
-host re-pack (`pack_overlap`). The contract: bitwise-identical greedy
-outputs against the legacy host paths, 100% conformance, zero violations,
-and the dispatch/process stats invariant at quiesce.
+host re-pack. The contract: bitwise-identical greedy outputs against the
+unified degrade and against the same engine read after every step, 100%
+conformance, zero violations, and the dispatch/process stats invariant at
+quiesce.
 """
 
 from __future__ import annotations
@@ -35,12 +36,17 @@ def _engine(**over) -> LLMEngine:
                      tokenizer=TOK)
 
 
-def _drain(eng: LLMEngine):
+def _drain(eng: LLMEngine, flush: bool = False):
+    """Step ``eng`` dry; ``flush`` reads every fused call before the next
+    step is planned, so no call is ever chained on another."""
     toks: dict[str, list[int]] = {}
     fins: dict[str, str] = {}
     steps = 0
     while eng.has_work():
-        for o in eng.step():
+        outs = eng.step()
+        if flush:
+            eng._flush_pending_decode()  # appends to the list step() returned
+        for o in outs:
             toks.setdefault(o.request_id, []).extend(o.new_token_ids)
             if o.finish_reason:
                 fins[o.request_id] = o.finish_reason
@@ -74,10 +80,11 @@ def _add_mixed(eng: LLMEngine) -> None:
 
 def test_fused_masked_decode_bitwise_matches_unified_degrade():
     """Mixed plain/structured/bias batch: the device-resident masked path and
-    the legacy 1-token unified degrade must produce identical greedy tokens."""
+    the 1-token unified degrade (forced through the table-size gate) must
+    produce identical greedy tokens."""
     outs = []
     for fused in (True, False):
-        eng = _engine(structured_fused_decode=fused)
+        eng = _engine() if fused else _engine(structured_table_max_elems=1)
         _add_mixed(eng)
         toks, fins = _drain(eng)
         outs.append(toks)
@@ -116,23 +123,26 @@ def test_masked_chain_stays_device_resident_across_dispatches():
 
 def test_pack_overlap_bitwise_parity_and_accounting():
     """Chained fast-path pack (device-resident pos/lens/tokens reuse) must be
-    invisible in the outputs; time_host_pack keeps meaning serialized wall."""
+    invisible in the outputs; time_host_pack keeps meaning serialized wall:
+    an engine read after every step never chains and overlaps nothing."""
     outs = []
-    for ov in (True, False):
-        eng = _engine(pack_overlap=ov)
+    for flush in (False, True):
+        eng = _engine()
         for i, p in enumerate(("alpha beta", "gamma delta", "epsilon zeta")):
             eng.add_request(f"req-{i}", TOK.encode(p),
                             _sp(max_tokens=48, stop_token_ids=(),
                                 ignore_eos=True))
-        toks, _ = _drain(eng)
+        toks, _ = _drain(eng, flush=flush)
         outs.append(toks)
         st = eng.stats
-        assert st.n_chained_dispatches > 0, "membership-stable batch never chained"
-        if ov:
-            assert st.time_pack_overlap > 0, "no pack wall was overlapped"
+        if flush:
+            assert st.n_chained_dispatches == 0 and st.time_pack_overlap == 0
+            assert st.time_host_pack > 0
         else:
-            assert st.time_pack_overlap == 0  # legacy serialized accounting
-    assert outs[0] == outs[1], "pack_overlap perturbed the token streams"
+            assert st.n_chained_dispatches > 0, (
+                "membership-stable batch never chained")
+            assert st.time_pack_overlap > 0, "no pack wall was overlapped"
+    assert outs[0] == outs[1], "chaining perturbed the token streams"
 
 
 def test_combined_grammar_and_bias_row_degrades_to_unified():

@@ -120,7 +120,7 @@ def test_ci_gate_pins_stage_roster():
     roster = ["lint-envvars", "lint-metrics", "lint-events", "llmd-lint",
               "validate-manifests", "chaos-check", "structured-check",
               "slo-check", "device-obs", "kv-plane-check", "decision-check",
-              "kv-durability-check", "pd-check"]
+              "kv-durability-check", "pd-check", "util-check", "moe-check"]
     positions = []
     for stage in roster:
         idx = src.find(f'"{stage}"')
@@ -129,7 +129,7 @@ def test_ci_gate_pins_stage_roster():
     assert positions == sorted(positions), "ci_gate.py stage order drifted"
 
 
-@pytest.mark.slow  # ~95s: actually runs the lint/check composite end to end
+@pytest.mark.slow  # minutes: actually runs the lint/check composite end to end
 def test_ci_gate_composes_stages():
     """tools/ci_gate.py (VERDICT r4 missing #3): one command, one exit code,
     a JSON stage summary on the last line."""
@@ -137,7 +137,7 @@ def test_ci_gate_composes_stages():
 
     proc = subprocess.run(
         [sys.executable, str(ROOT / "tools" / "ci_gate.py"),
-         "--skip-tests", "--skip-bench", "--skip-dryrun"],
+         "--skip-tests", "--skip-dryrun"],
         capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     summary = json.loads(proc.stdout.strip().splitlines()[-1])
@@ -146,22 +146,17 @@ def test_ci_gate_composes_stages():
         "lint-envvars", "lint-metrics", "lint-events", "llmd-lint",
         "validate-manifests", "chaos-check", "structured-check", "slo-check",
         "device-obs", "kv-plane-check", "decision-check",
-        "kv-durability-check", "pd-check"]
+        "kv-durability-check", "pd-check", "util-check", "moe-check"]
     assert all(s["ok"] for s in summary["stages"])
 
 
-def test_ci_gate_pins_bench_stages():
-    """The bench stage roster is a contract too: every tiny-bench smoke the
-    gate promises (including the structured x speculative compose smoke,
-    PERF.md Lever 13) must stay declared in ci_gate.py. Pinned by source
-    scan because actually running the bench stages is minutes of wall."""
+def test_ci_gate_stages_run_files_that_exist():
+    """Every script a stage names is in the tree, and the two gates that
+    build an engine (util-check, moe-check) are stages of their own."""
+    import re
+
     src = (ROOT / "tools" / "ci_gate.py").read_text()
-    for stage in ("util-check", "bench-tiny-cpu", "bench-tiny-spec",
-                  "bench-tiny-attn", "bench-tiny-structured",
-                  "bench-tiny-spec-structured", "bench-tiny-warmstart",
-                  "bench-tiny-moe"):
-        assert f'"{stage}"' in src, f"ci_gate.py lost bench stage {stage}"
-    # the compose smoke must keep its in-process enforcement flag: without
-    # it the stage only proves the bench ran, not that constrained rows
-    # accepted drafts with zero violations
-    assert '"--assert-spec-structured"' in src
+    scripts = set(re.findall(r'"((?:tools/)?[\w/]+\.py)"', src))
+    assert {"tools/util_check.py", "tools/moe_check.py"} <= scripts
+    for script in scripts:
+        assert (ROOT / script).is_file(), f"ci_gate.py runs missing {script}"

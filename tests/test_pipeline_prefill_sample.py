@@ -27,9 +27,11 @@ def _engine(**kw) -> LLMEngine:
     return LLMEngine(get_model_config("tiny"), EngineConfig(**base))
 
 
-def drive(eng: LLMEngine, oracle: bool = False, arrivals=None) -> dict:
-    """Step ``eng`` dry; ``oracle`` reads every step before the next is
-    planned. ``arrivals``: {step index: [(request_id, prompt, sampling) or
+def drive(eng: LLMEngine, oracle: bool = False, arrivals=None,
+          unchained: bool = False) -> dict:
+    """Step ``eng`` dry; ``oracle`` reads every unified step before the next
+    is planned, ``unchained`` every fused decode call (so none is chained on
+    another). ``arrivals``: {step index: [(request_id, prompt, sampling) or
     (request_id, prompt, sampling, add_request's other keywords)]}."""
     got: dict[str, list[int]] = {}
     steps = 0
@@ -39,17 +41,21 @@ def drive(eng: LLMEngine, oracle: bool = False, arrivals=None) -> dict:
         outs = eng.step()
         if oracle:
             eng._flush_pending_sample()  # appends to the list step() returned
+        if unchained:
+            eng._flush_pending_decode()  # as above
         for out in outs:
             got.setdefault(out.request_id, []).extend(out.new_token_ids)
         steps += 1
-    assert eng._pending_sample is None and eng.programs.quiesced()
+    assert eng._pending_sample is None and not eng._pending_decode
+    assert eng.programs.quiesced()
     return got
 
 
-def generate(eng: LLMEngine, prompts, sp, oracle: bool = False) -> dict:
+def generate(eng: LLMEngine, prompts, sp, oracle: bool = False,
+             unchained: bool = False) -> dict:
     for i, p in enumerate(prompts):
         eng.add_request(f"req-{i}", p, sp)
-    return drive(eng, oracle)
+    return drive(eng, oracle, unchained=unchained)
 
 
 PROMPTS = [list(range(3, 40)), list(range(50, 75)), list(range(80, 140)),

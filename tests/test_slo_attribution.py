@@ -14,8 +14,7 @@ Covers:
 - burn-rate minute-window boundaries with an injected clock, and series
   boundedness + idle-tenant pruning;
 - fleet rollup: tok/s from counter deltas (reset-safe), min-headroom
-  aggregation, and boundedness under 50 cycles of replica churn;
-- the perf_regress comparator: tolerance verdicts and the provenance guard.
+  aggregation, and boundedness under 50 cycles of replica churn.
 """
 
 import time
@@ -394,35 +393,6 @@ def test_fleet_bounded_under_replica_churn():
                 fleet.forget(a)
     assert len(fleet) == 4  # only the live generation remains
     assert fleet.snapshot()["replicas"] == 4
-
-
-# --------------------------------------------------------- perf comparator
-
-
-def test_perf_regress_verdicts_and_provenance_guard():
-    import tools.perf_regress as pr
-
-    base = {"device": "TPU v5 lite", "point": "int8-b64",
-            "value": 100.0, "wall_s": 2.0, "decode_tokens": 500}
-    # within tolerance + improvements pass
-    good = dict(base, value=95.0, wall_s=1.0)
-    assert pr.compare(good, base)["ok"] is True
-    # throughput collapse fails
-    v = pr.compare(dict(base, value=80.0), base)
-    assert v["ok"] is False
-    assert [r for r in v["rows"] if r["metric"] == "value"][0]["status"] == "fail"
-    # counter drift fails exactly
-    assert pr.compare(dict(base, decode_tokens=501), base)["ok"] is False
-    # different provenance: throughput skipped, not failed...
-    cpu = {"device": "cpu", "point": "tiny", "value": 1.0, "wall_s": 60.0,
-           "decode_tokens": 10}
-    v = pr.compare(cpu, base)
-    assert v["ok"] is True and v["comparable"] is False
-    assert all(r["status"] == "skipped" for r in v["rows"])
-    # ...but a missing metric is a payload-shape break even then
-    v = pr.compare({"device": "cpu", "point": "tiny"}, base)
-    assert v["ok"] is False
-    assert all(r["status"] == "missing" for r in v["rows"])
 
 
 # --------------------------------------------- P/D split stack (ISSUE 20)

@@ -40,7 +40,7 @@ def _drain(eng: LLMEngine) -> dict[str, list[int]]:
 
 
 def _echo_prompt(salt: int, n: int = 48, period: int = 3) -> list[int]:
-    """Periodic prompt (bench.py --workload echo shape): the suffix n-gram
+    """Periodic prompt: the suffix n-gram
     always has an earlier occurrence, so the drafter fires every step."""
     vocab = get_model_config("tiny").vocab_size
     return [(salt * 7919 + j % period) % (vocab - 2) + 1 for j in range(n)]

@@ -43,7 +43,7 @@ def _echo_schema(n_items: int, values=("on",)) -> dict:
 
 def _pattern_prompt(value: str = "on", reps: int = 4) -> list[int]:
     """Prompt carrying the serialized item pattern so the n-gram drafter
-    fires from the first generated tokens (bench.py json-echo shape)."""
+    fires from the first generated tokens."""
     return TOK.encode('[{"s":"%s"},' % value + ('{"s":"%s"},' % value) * reps)
 
 

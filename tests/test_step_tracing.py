@@ -26,6 +26,7 @@ from llmd_tpu.router.plugins import known_plugin_types
 from llmd_tpu.router.server import RouterServer
 from llmd_tpu.testing.fake_server import FakeModelServer, FakeServerConfig
 from tests.conftest import run_async
+from tests.test_pipeline_prefill_sample import generate
 from tests.test_router import CFG
 
 BASE = dict(page_size=8, num_pages=64, max_model_len=256, max_batch_size=4,
@@ -84,12 +85,13 @@ def test_decode_seat_steps_exact_for_a_constructed_batch():
     """Three rows on four seats, k = 4, six tokens each: the first comes from
     the prefill's sample, five from fused calls. Unpipelined, that is two
     calls: 4 kept a row, then 1 kept and 3 step-slots past the end."""
-    eng = _engine(pipeline_decode=False)
+    eng = _engine()
     # all three prefill in one unified step (32 tokens, its whole budget): a
     # prompt left for a second step would find the others riding in it as
     # decode rows, ahead of the fused calls
     prompts = [list(range(10, 22)), list(range(40, 48)), list(range(60, 72))]
-    out = eng.generate(prompts, SamplingParams(max_tokens=6, **GREEDY))
+    out = generate(eng, prompts, SamplingParams(max_tokens=6, **GREEDY),
+                   unchained=True)
     assert all(len(v) == 6 for v in out.values())
     seats = _samples(eng.registry, "llmd_tpu:decode_seat_steps_total")
     got = {o: seats[f'{{outcome="{o}"}}']
@@ -116,8 +118,9 @@ def test_kv_read_tokens_equal_the_context_lengths_dispatched():
     """One 20-token prompt, chunk 32, k = 4, six tokens, unpipelined: the
     unified step reads 20 positions; the fused calls start at contexts of 21
     (prompt + the sampled first token) and 25."""
-    eng = _engine(pipeline_decode=False)
-    eng.generate([list(range(10, 30))], SamplingParams(max_tokens=6, **GREEDY))
+    eng = _engine()
+    generate(eng, [list(range(10, 30))],
+             SamplingParams(max_tokens=6, **GREEDY), unchained=True)
     kv = _samples(eng.registry, "llmd_tpu:program_kv_read_tokens_total")
     rows = _samples(eng.registry, "llmd_tpu:program_rows_total")
     assert kv['{program="unified"}'] == 20

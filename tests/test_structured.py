@@ -392,9 +392,8 @@ def test_structured_off_bitwise_identical_and_no_biased_compile():
 
 
 def test_spec_decode_structured_rows_bitwise_parity():
-    """Mixed spec+structured batch: constrained rows now draft through the
-    grammar-masked verify program (spec_structured, on by default), and the
-    whole batch must still match the non-spec engine bitwise. The compose
+    """Mixed spec+structured batch: constrained rows draft through the
+    grammar-masked verify program, and the whole batch must still match the non-spec engine bitwise. The compose
     itself is pinned in depth by tests/test_spec_structured.py."""
     vocab = get_model_config("tiny").vocab_size
     echo = [(7919 + j % 3) % (vocab - 2) + 1 for j in range(48)]

@@ -56,7 +56,7 @@ def _arrivals(sp, every: int = 2, prompts=PROMPTS) -> dict:
     dict(prefill_chunk=8, max_batch_size=3),       # seats fewer than requests
     dict(prefill_chunk=32, max_num_batched_tokens=40),  # budget cuts chunks
     dict(page_size=4, num_pages=256),              # a block commits every 4
-    dict(decode_steps=1, pipeline_decode=False),
+    dict(decode_steps=1),
 ], ids=["base", "seats3", "budget40", "page4", "k1"])
 def test_greedy_mixed_traffic_equals_the_oracle_and_the_fused_path(kw):
     sp = SamplingParams(max_tokens=12, **GREEDY)
@@ -324,7 +324,7 @@ def test_a_constrained_row_makes_the_batch_read_before_it_plans(constraint):
 
     def engine():
         return LLMEngine(get_model_config("tiny"), EngineConfig(
-            **{**BASE, "structured_fused_decode": False}), tokenizer=TOK)
+            **{**BASE, "structured_table_max_elems": 1}), tokenizer=TOK)
 
     def run(oracle):
         eng = engine()

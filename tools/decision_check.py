@@ -15,8 +15,8 @@ replayed mixed trace (streamed + non-streamed), and the decision ledger
 3. regret is present on multi-endpoint schedules and exported bucketed by
    SLO breach,
 4. ZERO client-visible 5xx,
-5. the ledger's schedule-latency overhead stays inside the perf_regress
-   router-overhead bound (<2% relative or <25µs/call absolute).
+5. the ledger's schedule-latency overhead stays inside the router-overhead
+   bound (`router_overhead`: <2% relative or <25µs/call absolute).
 
 Run: python tools/decision_check.py  (CI: tools/ci_gate.py stage
 `decision-check`; ``make decisions``.)
@@ -55,6 +55,83 @@ schedulingProfiles:
       - {pluginRef: lat, weight: 2}
       - {pluginRef: queue, weight: 1}
 """
+
+
+ROUTER_OVERHEAD_REL = 0.02   # decision ledger must stay under +2% schedule cost
+ROUTER_OVERHEAD_ABS_S = 25e-6  # OR under 25µs/call absolute (timer-noise floor
+                               # for a schedule call measured in tens of µs)
+
+
+def router_overhead(n_endpoints: int = 6, n_requests: int = 400,
+                    rounds: int = 3) -> dict:
+    """CPU bench smoke for the decision-ledger overhead bound: build the same
+    scheduler twice (the knob is cached at construction), drive identical
+    request streams with LLMD_DECISION_LEDGER off then on, and compare
+    best-of-``rounds`` mean schedule latency. Passes when the ledger adds
+    <2% relative OR <25µs/call absolute — 2% of a ~50µs schedule call is
+    below timer noise, so the absolute epsilon is the honest floor."""
+    import time
+
+    from llmd_tpu.core.config import FrameworkConfig
+    from llmd_tpu.core.endpoint import Endpoint, EndpointPool
+    from llmd_tpu.core.metrics_contract import StdMetric
+    from llmd_tpu.core.request import InferenceRequest
+    from llmd_tpu.router import filters_pickers as _fp  # noqa: F401
+    from llmd_tpu.router import scorers as _s  # noqa: F401
+    from llmd_tpu.router.plugins import known_plugin_types
+    from llmd_tpu.router.scheduler import Scheduler
+
+    cfg_yaml = """
+plugins:
+  - {name: queue, type: queue-depth-scorer}
+  - {name: kv-util, type: kv-cache-utilization-scorer}
+schedulingProfiles:
+  - name: default
+    plugins:
+      - {pluginRef: queue, weight: 2}
+      - {pluginRef: kv-util, weight: 1}
+"""
+    pool = EndpointPool()
+    for i in range(n_endpoints):
+        ep = Endpoint(address=f"10.0.0.{i}:8000")
+        ep.attrs.put(StdMetric.QUEUED_REQUESTS, float(i))
+        ep.attrs.put(StdMetric.KV_UTILIZATION, 0.1 * i)
+        pool.upsert(ep)
+
+    def bench(enabled: bool) -> float:
+        os.environ["LLMD_DECISION_LEDGER"] = "1" if enabled else "0"
+        sched = Scheduler(
+            FrameworkConfig.from_yaml(cfg_yaml,
+                                      known_types=known_plugin_types()),
+            pool)
+        best = float("inf")
+        for _ in range(rounds):
+            reqs = [InferenceRequest(prompt=f"bench-{i}")
+                    for i in range(n_requests)]
+            t0 = time.perf_counter()
+            for req in reqs:
+                sched.schedule(req)
+            best = min(best, (time.perf_counter() - t0) / n_requests)
+        return best
+
+    bench(False)  # warm imports/allocators outside the measured rounds
+    off_s = bench(False)
+    on_s = bench(True)
+    delta_s = on_s - off_s
+    rel = delta_s / off_s if off_s > 0 else 0.0
+    ok = rel <= ROUTER_OVERHEAD_REL or delta_s <= ROUTER_OVERHEAD_ABS_S
+    return {
+        "router_overhead": "ok" if ok else "failed",
+        "schedule_us_off": round(off_s * 1e6, 2),
+        "schedule_us_on": round(on_s * 1e6, 2),
+        "delta_us": round(delta_s * 1e6, 2),
+        "rel_delta": round(rel, 4),
+        "rel_bound": ROUTER_OVERHEAD_REL,
+        "abs_bound_us": ROUTER_OVERHEAD_ABS_S * 1e6,
+        "n_endpoints": n_endpoints,
+        "n_requests": n_requests,
+        "ok": ok,
+    }
 
 
 async def _fake():
@@ -180,9 +257,7 @@ async def main_async() -> int:
 
         calibration = accuracy_from_metrics(metrics_text)
 
-        # ---- ledger overhead bound (perf_regress) -------------------------
-        from tools.perf_regress import router_overhead
-
+        # ---- ledger overhead bound ----------------------------------------
         # best-of-3 so one scheduler hiccup on a loaded box can't fail the
         # bound: only a consistent slowdown across rounds survives best-of
         overhead = router_overhead(n_requests=200, rounds=3)

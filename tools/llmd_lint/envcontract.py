@@ -28,7 +28,7 @@ from typing import Optional
 from .core import Finding, Project, dotted_name, const_str
 
 SOURCE_GLOBS = ("llmd_tpu/**/*.py", "tools/**/*.py", "helpers/**/*.py",
-                "bench.py", "__graft_entry__.py")
+                "__graft_entry__.py")
 VAR_PAT = re.compile(r"^[A-Z][A-Z0-9_]*$")
 ROW_PAT = re.compile(r"^\|\s*`([A-Z_][A-Z0-9_]*)`\s*\|\s*([^|]+)\|", re.M)
 CONSUMER_MODULE_PAT = re.compile(r"\bllmd_tpu(?:\.[a-zA-Z_][a-zA-Z0-9_]*)+")

@@ -1,9 +1,9 @@
-"""Materialise a serving-scale HF-format checkpoint for bench/serve runs.
+"""Materialise a serving-scale HF-format checkpoint for serve runs.
 
 The image is zero-egress, so published weights cannot be downloaded; this writes a
 genuine ``save_pretrained`` checkpoint (config.json + sharded safetensors +
 trained BPE tokenizer) at a registry shape so the full HF-load path — the one a
-real checkpoint takes — is what bench.py and `-m llmd_tpu.engine.serve` exercise.
+real checkpoint takes — is what `-m llmd_tpu.engine.serve` exercises.
 The loader itself is validated for logits parity against the HF reference in
 tests/test_hf_loader.py; with network access, point --model at any downloaded
 Llama/Qwen checkpoint instead.

@@ -2,7 +2,7 @@
 
 Measures what the chip actually delivers: pure streaming reads (sum over a big
 bf16 array), and the decode-shaped matmul [B, D] x [D, V] at serving sizes.
-bench.py's weights-BW utilization is only meaningful against the measured number.
+A weights-bandwidth utilization is only meaningful against the measured number.
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""MoE dispatch CI gate (stage ``bench-tiny-moe``, ``make moe``).
+"""MoE dispatch CI gate (stage ``moe-check``, ``make moe``).
 
 Two tiny-moe CPU engines run the same greedy workload — one on the legacy
 dense one-hot einsum dispatch (capacity-bounded, silently drops tokens past

@@ -13,9 +13,8 @@ utilization attribution plane's standing invariants are asserted end to end:
 4. the recompile counter stays flat across the steady-state round: every
    compiled program was built in warmup, so a delta is a recompile storm
 5. ledger totals and the scraped ``llmd_tpu:goodput_tokens_total`` counters
-   agree exactly, and the bench-style measured-window delta accounting
-   (bench.py's ``goodput_*`` provenance keys) reproduces the counter deltas
-   token for token — the "bench JSON and live /metrics agree" contract
+   agree exactly, and a measured window's delta accounting reproduces the
+   counter deltas token for token
 
 Run directly (CI) or via ``make util``. Exit 0 = all checks pass.
 """
