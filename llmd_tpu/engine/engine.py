@@ -12,6 +12,9 @@ continuous batching on accelerator'), built XLA-first:
   to the token budget instead of one sequence per step,
 - prefill never pays the [N, vocab] logits matmul — only each sequence's last
   hidden row is unembedded,
+- both programs pick their own tokens (``sampling.sample_tokens`` inlined): one
+  dispatch a step, and argmax alone when no row of the step samples; only a
+  batch with a grammar or ``logit_bias`` row samples in a second program,
 - the unified step runs one step ahead of the host: step n+1 is dispatched
   before step n's sampled tokens are read, its rows that ride on take their
   input token from step n's sampled array on the device, and the read, the
@@ -426,8 +429,17 @@ class LLMEngine:
         # what a unified step is given for ``prev_sampled`` when no step is
         # in flight: zeros of a sampled array's shape, type and placement,
         # so the one compiled program serves both cases
-        self._zero_sampled = self._replicated(
-            jnp.zeros((engine_cfg.max_batch_size,), jnp.int32))
+        seats = (engine_cfg.max_batch_size,)
+        self._zero_sampled = self._replicated(jnp.zeros(seats, jnp.int32))
+        # the sampling state of a step in which no row samples (temperature
+        # 0, top-k off, top-p 1, a key nothing draws from), resident on the
+        # device: such a step uploads none and splits no key
+        # (_sampling_state). The key is made as a sampling step makes its
+        # own, so the first such step finds the split compiled.
+        _, idle_key = jax.random.split(self._key)
+        self._greedy_state = tuple(self._replicated(x) for x in (
+            jnp.zeros(seats, jnp.float32), jnp.zeros(seats, jnp.int32),
+            jnp.ones(seats, jnp.float32), idle_key))
 
         if params is None:
             params = init_params(model_cfg, jax.random.PRNGKey(seed))
@@ -573,9 +585,13 @@ class LLMEngine:
         def _make_unified(attn_fn):
             def _unified(params, cache, tokens, positions, seq_slots, page_tables,
                          kv_lens, cu_q_lens, num_seqs, lora_tok, prev_sampled,
-                         mm_embeds=None, mm_mask=None):
+                         temp, top_k, top_p, key, mm_embeds=None, mm_mask=None):
                 """Flat mixed batch (prefill chunks + decode tokens); returns each
-                sequence's last-row logits [B, vocab].
+                sequence's last-row logits [B, vocab] and the token picked
+                from them [B], by the sampler every program shares
+                (``sample_tokens``: argmax alone when no row samples). The
+                logits are read only by a batch with a constrained row, whose
+                bias the host builds (``_sample_dispatch``).
 
                 The step runs one ahead of the host: a decode row whose input
                 token is still on the device packs ``-(row + 1)``, the row it
@@ -602,7 +618,9 @@ class LLMEngine:
                 )
                 last_rows = jnp.clip(cu_q_lens[1 : B + 1] - 1, 0, NT - 1)  # [B]
                 logits = unembed(cfg, params, hidden[last_rows])  # [B, vocab]
-                return logits, cache, cnt, drop
+                sampled = sample_tokens(logits.astype(jnp.float32), key, temp,
+                                        top_k, top_p)
+                return logits, sampled, cache, cnt, drop
 
             return _unified
 
@@ -1789,11 +1807,14 @@ class LLMEngine:
         read, and a row of that step rides along as a decode row whose input
         token the program takes on the device (``_unified``). ``parts``
         splits the host time in the order it runs: plan (row choice, pages,
-        preemption), pack (numpy staging), dispatch (transfers + the
-        asynchronous jitted call), apply (this step's per-row state), sample
-        (its sampler's dispatch), wait (the blocking read of the PREVIOUS
-        step's sampled tokens and MoE counts), apply again (that step's
-        tokens, finish checks, outputs), book. So everything between two
+        preemption), pack (numpy staging), dispatch (transfers, with the
+        sampling parameters of a step in which a row samples, + the
+        asynchronous jitted call, which picks the step's tokens too), apply
+        (this step's per-row state), sample (the record of what the step
+        left to read; for a batch with a constrained row also its bias and
+        the biased sampler's dispatch), wait (the blocking read of the
+        PREVIOUS step's sampled tokens and MoE counts), apply again (that
+        step's tokens, finish checks, outputs), book. So everything between two
         dispatches but the wait runs under device time, and so does what the
         loop does with the outputs after ``step()`` returns."""
         t0_ns = time.time_ns()
@@ -1889,10 +1910,16 @@ class LLMEngine:
         off = 0
         first_chunks: list[Sequence] = []
         ahead_rows: set[int] = set()
+        # (batch row, seq) of the rows whose last logits give a token: decode
+        # rows, and a fresh prefill that this step's chunk completes
+        sample_list: list[tuple[int, Sequence]] = []
         for i, (s, n, is_decode) in enumerate(plan):
             start = s.num_computed
             if not is_decode and start == s.num_cached_prompt:
                 first_chunks.append(s)
+            if is_decode or (len(s.token_ids) == s.prompt_len
+                             and start + n == s.prompt_len):
+                sample_list.append((i, s))
             if start == len(s.token_ids):
                 # token in flight: name its row in the previous step
                 toks[off] = -(flying[id(s)] + 1)
@@ -1951,11 +1978,12 @@ class LLMEngine:
         prev_sampled = prev["sampled"] if prev is not None else None
         if prev_sampled is None:
             prev_sampled = self._zero_sampled
-        logits, self.cache, cnt, moe_drop = step_fn(
+        sampling, samples = self._sampling_state(sample_list)
+        logits, sampled, self.cache, cnt, moe_drop = step_fn(
             self._run_params(), self.cache, jnp.asarray(toks), jnp.asarray(pos),
             jnp.asarray(sids), jnp.asarray(pts), jnp.asarray(lens), jnp.asarray(cu),
             jnp.asarray([len(plan)], jnp.int32), jnp.asarray(lora_tok),
-            prev_sampled, *mm_args,
+            prev_sampled, *sampling, *mm_args,
         )
         parts.to("apply")
 
@@ -1974,15 +2002,13 @@ class LLMEngine:
                     if len(s.token_ids) > s.prompt_len:
                         util_recompute += n
 
-        sample_list: list[tuple[int, Sequence]] = []  # (batch row, seq)
-        for i, (s, n, is_decode) in enumerate(plan):
+        for s, n, is_decode in plan:
             if is_decode:
                 s.num_computed += 1
                 # commits stop at the tokens the host holds: an ahead row's
                 # block is committed when its token is read (_sample_apply)
                 s.maybe_commit_blocks(self.allocs[s.rank])
                 self.stats.total_decode_tokens += 1
-                sample_list.append((i, s))
             else:
                 if s.num_computed == s.num_cached_prompt:
                     # first chunk of a (re)prefill — cached==computed only holds
@@ -1995,20 +2021,20 @@ class LLMEngine:
                 if s.num_computed >= self._prefill_target(s):
                     self.flight.record(s.request_id, "prefill_end",
                                        prefill_tokens=s.num_computed)
-                if (len(s.token_ids) == s.prompt_len
-                        and s.num_computed == s.prompt_len):
-                    # fresh prefill complete: sample first token from last logits
-                    sample_list.append((i, s))
-        # One step ahead: dispatch this step's sampling (device-chained on
-        # step_fn), and only then read and apply the PREVIOUS step, while the
-        # device runs this one. This step's record waits for the next step,
-        # or for whoever needs host token state first (_flush_pending_sample).
-        # Its rows stay schedulable meanwhile: _decode_ready(_flying_rows()).
+        # One step ahead: this step's tokens are picked on the device by the
+        # program that is running; only now read and apply the PREVIOUS step,
+        # under this one's device time. This step's record waits for the next
+        # step, or for whoever needs host token state first
+        # (_flush_pending_sample). Its rows stay schedulable meanwhile:
+        # _decode_ready(_flying_rows()).
         parts.to("sample")
-        bias = self._build_bias(sample_list, logits.shape) if sample_list else None
-        rec = self._sample_dispatch(sample_list, logits, bias=bias,
+        rec = self._sample_dispatch(sample_list, logits, sampled, sampling,
                                     ahead_rows=ahead_rows)
         rec["prog"] = step_prog
+        self.metrics.sampler_steps.labels(
+            program="unified",
+            path=("biased" if rec["biased"] else
+                  "topk" if samples else "argmax")).inc()
         if self._eplb is not None:
             rec["cnt"] = cnt
         if self.model_cfg.is_moe:
@@ -2754,6 +2780,7 @@ class LLMEngine:
                 steps_dev = jnp.asarray(steps_left)
                 mask = chain["mask"]
                 fsm_in = chain["fsm_out"]
+                sampler_path = chain["sampler_path"]
         else:
             pos = np.full((B,), -1, np.int32)
             pts_np = np.full((B, self.cfg.max_pages_per_seq), -1, np.int32)
@@ -2790,6 +2817,10 @@ class LLMEngine:
                     if any(s.structured is not None or s.logit_bias
                            for s in active) else None)
             fsm_in = mask["fsm0"] if mask is not None else None
+            # the branch each of the call's k scan steps takes in the
+            # sampler, by the test the unified step makes (_sampling_state)
+            sampler_path = ("biased" if mask is not None else
+                            "topk" if (temp > 0.0).any() else "argmax")
             for s in active:
                 self.flight.record(s.request_id, "chain_dispatch", k=k,
                                    masked=mask is not None)
@@ -2829,6 +2860,8 @@ class LLMEngine:
         self.programs.record_dispatch(prog)
         self.metrics.program_kv_read_tokens.labels(program=prog).inc(ctx_tokens)
         self.metrics.program_rows.labels(program=prog).inc(len(active))
+        self.metrics.sampler_steps.labels(program="decode",
+                                          path=sampler_path).inc(k)
         if chain is not None:
             self.stats.n_chained_dispatches += 1
         # Start the device->host copy of everything _decode_process will read:
@@ -2868,6 +2901,7 @@ class LLMEngine:
             "mask": mask, "pts_np": pts_np, "pts_dev": pts_dev,
             "pages_sig": pages_sig, "temp_dev": temp_dev, "tk_dev": tk_dev,
             "tp_dev": tp_dev, "lora_dev": lora_dev,
+            "sampler_path": sampler_path,
         }
 
     @_step_phase("decode", "llmd.decode_process", "wait")
@@ -3096,46 +3130,60 @@ class LLMEngine:
 
         return jax.device_put(x, NamedSharding(self.mesh, PartitionSpec()))
 
-    def _sample_dispatch(self, rows_and_seqs: list[tuple[int, "Sequence"]],
-                         logits: jax.Array,
-                         bias: Optional[np.ndarray] = None,
-                         ahead_rows: "set[int] | frozenset[int]" = frozenset(),
-                         ) -> dict:
-        """Launch sampling on device (chains on the step that made ``logits``)
-        and start the device->host copy; no sync point here. With no row to
-        sample (prefill chunks short of their prompts' ends) the record holds
-        no array, and reading it reads only what else the step left.
-        ``ahead_rows``: the batch rows whose input token was taken on the
-        device, for the count of what riding ahead kept."""
-        if not rows_and_seqs:
-            return {"sampled": None, "rows": []}
-        B = logits.shape[0]
+    def _sampling_state(self, rows_and_seqs: list[tuple[int, "Sequence"]],
+                        ) -> tuple[tuple[jax.Array, ...], bool]:
+        """``(temperature [B], top_k [B], top_p [B], key)`` for a step whose
+        sampler sees ``rows_and_seqs`` (row, sequence), and whether any of
+        them samples. Decided from the rows' own ``SamplingParams``: a step
+        of greedy rows gets the device's constants (no transfer, no key
+        split, and the sampler takes its argmax branch); one with a sampling
+        row uploads the three arrays and splits ``_key`` once."""
+        if not any(s.sampling.temperature > 0.0 for _, s in rows_and_seqs):
+            return self._greedy_state, False
+        B = self.cfg.max_batch_size
         temp = np.zeros((B,), np.float32)
         tk = np.zeros((B,), np.int32)
         tp = np.ones((B,), np.float32)
         for i, s in rows_and_seqs:
             sp: SamplingParams = s.sampling
-            temp[i] = sp.temperature
-            tk[i] = sp.top_k
-            tp[i] = sp.top_p
+            temp[i], tk[i], tp[i] = sp.temperature, sp.top_k, sp.top_p
         self._key, sub = jax.random.split(self._key)
+        # placed as the constants are, so both kinds of step are one program
+        return tuple(self._replicated(jnp.asarray(x))
+                     for x in (temp, tk, tp, sub)), True
+
+    def _sample_dispatch(self, rows_and_seqs: list[tuple[int, "Sequence"]],
+                         logits: jax.Array, sampled: jax.Array,
+                         sampling: tuple[jax.Array, ...],
+                         ahead_rows: "set[int] | frozenset[int]" = frozenset(),
+                         ) -> dict:
+        """The record of what a unified step left to read: ``sampled``, the
+        tokens its program picked, with the device->host copy started; no
+        sync point here. With no row to sample (prefill chunks short of
+        their prompts' ends) the record holds no array, and reading it reads
+        only what else the step left. A batch with a constrained row takes
+        its tokens from the biased sampler instead, over the step's logits
+        and the host-built bias (``_build_bias``): the one second dispatch
+        left, counted under ``program="sample"``.
+        ``ahead_rows``: the batch rows whose input token was taken on the
+        device, for the count of what riding ahead kept."""
+        if not rows_and_seqs:
+            return {"sampled": None, "rows": [], "biased": False}
+        bias = self._build_bias(rows_and_seqs, logits.shape)
         if bias is not None:
             # biased program: grammar masks / logit_bias add ON DEVICE before
             # argmax — logits never leave the accelerator. Lazily jitted, so
             # engines that never see a constrained request never compile it.
+            temp, tk, tp, key = sampling
             sampled = sample_tokens_biased(
-                logits.astype(jnp.float32), jnp.asarray(bias), sub,
-                jnp.asarray(temp), jnp.asarray(tk), jnp.asarray(tp))
-        else:
-            sampled = sample_tokens(logits.astype(jnp.float32), sub,
-                                    jnp.asarray(temp), jnp.asarray(tk), jnp.asarray(tp))
+                logits.astype(jnp.float32), jnp.asarray(bias), key, temp, tk, tp)
+            self.programs.record_dispatch("sample")
         sampled = self._replicated(sampled)  # the next step's prev_sampled
         try:
             sampled.copy_to_host_async()
         except (AttributeError, RuntimeError):
             pass
-        self.programs.record_dispatch("sample")
-        return {"sampled": sampled,
+        return {"sampled": sampled, "biased": bias is not None,
                 "rows": [(i, s, s.slot, i in ahead_rows)
                          for i, s in rows_and_seqs]}
 
@@ -3177,7 +3225,8 @@ class LLMEngine:
         # llmd-lint: allow[hot-host-sync] designed sync point: the previous step's sample readback, under the next step's device time
         sampled = np.asarray(rec["sampled"])
         parts.to("apply")
-        self.programs.record_complete("sample")
+        if rec["biased"]:
+            self.programs.record_complete("sample")
         now = time.monotonic()
         kept = discarded = 0
         for i, s, slot, ahead in rec["rows"]:

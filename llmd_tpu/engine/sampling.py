@@ -1,7 +1,9 @@
 """Jitted batched sampler: greedy / temperature / top-k / top-p, static shapes.
 
 One program for the whole decode batch; per-slot parameters arrive as arrays so a mixed
-batch (greedy + sampled + different temperatures) is a single XLA launch.
+batch (greedy + sampled + different temperatures) is a single XLA launch. The step
+programs inline it (jit-in-jit): the unified step and each scan step of the fused
+decode call pick their own tokens.
 """
 
 from __future__ import annotations
@@ -29,28 +31,41 @@ def _sample_core(
     top_p: jax.Array,  # [B] (1.0 = disabled)
     top_k_max: int,
 ) -> jax.Array:
-    B, V = logits.shape
-    greedy = jnp.argmax(logits, axis=-1)
+    """One program with a branch on what it was given: a batch in which some
+    row samples takes the whole sampler (a greedy row of it still gets its
+    argmax, from the final ``where``); a batch in which none does is argmax
+    over the same float32 logits and nothing else. The top-k_max over the
+    vocabulary is the sampler's cost, so it is not computed to be thrown
+    away, and the first sampling row to arrive compiles nothing."""
 
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    scaled = logits / temp
+    def _argmax(logits):
+        return jnp.argmax(logits, axis=-1)
 
-    # top-k_max candidates once; per-slot k masking inside.
-    topv, topi = jax.lax.top_k(scaled, min(top_k_max, V))  # [B, K]
-    K = topv.shape[1]
-    ranks = jnp.arange(K)[None, :]
-    k_eff = jnp.where(top_k > 0, jnp.minimum(top_k, K), K)[:, None]
-    topv = jnp.where(ranks < k_eff, topv, -jnp.inf)
+    def _sampled(logits):
+        V = logits.shape[1]
+        greedy = jnp.argmax(logits, axis=-1)
 
-    # top-p on the (sorted) candidates
-    probs = jax.nn.softmax(topv, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs) < top_p[:, None]  # keep tokens until mass reached (incl. first)
-    topv = jnp.where(keep, topv, -jnp.inf)
+        temp = jnp.maximum(temperature, 1e-6)[:, None]
+        scaled = logits / temp
 
-    choice = jax.random.categorical(key, topv, axis=-1)  # [B] index into candidates
-    sampled = jnp.take_along_axis(topi, choice[:, None], axis=1)[:, 0]
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+        # top-k_max candidates once; per-slot k masking inside.
+        topv, topi = jax.lax.top_k(scaled, min(top_k_max, V))  # [B, K]
+        K = topv.shape[1]
+        ranks = jnp.arange(K)[None, :]
+        k_eff = jnp.where(top_k > 0, jnp.minimum(top_k, K), K)[:, None]
+        topv = jnp.where(ranks < k_eff, topv, -jnp.inf)
+
+        # top-p on the (sorted) candidates
+        probs = jax.nn.softmax(topv, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = (cum - probs) < top_p[:, None]  # keep tokens until mass reached (incl. first)
+        topv = jnp.where(keep, topv, -jnp.inf)
+
+        choice = jax.random.categorical(key, topv, axis=-1)  # [B] index into candidates
+        sampled = jnp.take_along_axis(topi, choice[:, None], axis=1)[:, 0]
+        return jnp.where(temperature <= 0.0, greedy, sampled)
+
+    return jax.lax.cond(jnp.any(temperature > 0.0), _sampled, _argmax, logits)
 
 
 @partial(jax.jit, static_argnames=("top_k_max",))
@@ -86,9 +101,10 @@ def sample_tokens_biased(
     (engine.py `_decode_multi_masked`), which gathers each row's bias from
     the staged dense tables per scan step — same sampler, bitwise-identical
     tokens whether the bias rides a unified step or a device chain.
-    A separate jitted program so engines that never see a structured request
-    never compile it (the spec.py lazy-jit pattern): `sample_tokens` keeps its
-    exact HLO, and unbiased batches stay bitwise identical."""
+    After a unified step it is a program of its own, over the step's logits
+    and the host-built bias, so engines that never see a structured request
+    never compile it (the spec.py lazy-jit pattern) and the unified program
+    keeps its exact HLO: unbiased batches stay bitwise identical."""
     return _sample_core(logits + bias, key, temperature, top_k, top_p,
                         top_k_max)
 

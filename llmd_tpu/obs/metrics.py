@@ -475,7 +475,9 @@ class EngineMetrics:
             "llmd_tpu:engine_step_part_seconds_total",
             "Host wall seconds of a step program by part: plan (row choice, "
             "pages, preemption), pack (numpy staging), dispatch (transfers + "
-            "the asynchronous jitted call), sample, wait (the blocking read "
+            "the asynchronous jitted call, which picks the step's tokens), "
+            "sample (the record of the tokens to read; a constrained "
+            "batch's bias and biased sampler), wait (the blocking read "
             "of sampled tokens: the device's share), apply (per-row state), "
             "book (metrics, flight, utilisation). program=sample is a "
             "deferred prefill sample read outside a unified step",
@@ -511,6 +513,16 @@ class EngineMetrics:
             "token read in between, a stop token, or left: computed for "
             "nothing)",
             labelnames=("outcome",))
+        self.sampler_steps = reg.counter(
+            "llmd_tpu:sampler_steps_total",
+            "Steps whose program picked its tokens, by program (unified; "
+            "decode: k for each fused call) and by the branch the sampler "
+            "took: argmax (no row of the step samples: no top-k computed, "
+            "no sampling parameters sent, no key split), topk (a row has "
+            "temperature > 0: the whole sampler, in the same program), "
+            "biased (a constrained row: the biased sampler; for a unified "
+            "step a second dispatch over the step's logits)",
+            labelnames=("program", "path"))
         self.program_kv_read_tokens = reg.counter(
             "llmd_tpu:program_kv_read_tokens_total",
             "Context tokens (KV positions) over the rows of each dispatch, "

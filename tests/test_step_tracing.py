@@ -73,6 +73,16 @@ def test_parts_sum_to_the_step_histogram(program, phases):
             ("plan", "pack", "dispatch", "wait", "apply", "book"))
     for part in want:
         assert any(f'part="{part}"' in labels for labels in named), part
+    # the unified step's ``sample`` is the record of the tokens its own
+    # program picked, no dispatch of a sampler (ISSUE 31): every step of
+    # these greedy rows took the argmax branch, k a fused call
+    steps = _samples(eng.registry, "llmd_tpu:sampler_steps_total")
+    assert steps == {
+        '{program="unified",path="argmax"}': eng.stats.n_unified_steps,
+        '{program="decode",path="argmax"}':
+            BASE["decode_steps"] * eng.stats.n_decode_dispatches}
+    assert not _total(eng.registry, "llmd_tpu:engine_program_dispatches_total",
+                      'program="sample"')
     # the stats splits are the same readings: host pack + enqueue + the rest
     st = eng.stats
     assert st.time_host_pack > 0 and st.time_device > 0

@@ -399,7 +399,13 @@ def test_generate_quiesces_and_counts_balance():
     assert all(len(v) == 12 for v in out.values())
     c = eng.programs.counters()
     assert c["unified"][0] == c["unified"][1] == eng.stats.n_unified_steps
-    assert c["sample"][0] == c["sample"][1] > 0
+    # the unified program picks the step's tokens itself (ISSUE 31): an
+    # unconstrained run never dispatches the sampler as a program of its own
+    assert "sample" not in c
+    assert _count(eng, "engine_program_dispatches_total",
+                  'program="sample"') == 0
+    assert _count(eng, "sampler_steps_total", 'program="unified",path="argmax"'
+                  ) == eng.stats.n_unified_steps
     a = _ahead(eng)
     assert a["kept"] + a["discarded"] == a["device"]
     # a decode token of a unified step is one of its decode rows

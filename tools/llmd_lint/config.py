@@ -30,6 +30,7 @@ HOT_PATHS: dict[str, object] = {
         "_decode_ready",
         "_flush_pending_",  # _flush_pending_decode/_flush_pending_sample
         "_sample_dispatch",
+        "_sampling_state",
         "_sample_apply",
         "_plan_chain_masks",
         "_stage_chain_masks",
