@@ -600,7 +600,9 @@ class LLMEngine:
             from llmd_tpu.ops.paged_attention import window_align_pages
 
             self._window_align = window_align_pages(
-                engine_cfg.page_size, engine_cfg.max_pages_per_seq)
+                (engine_cfg.max_batch_size, model_cfg.num_heads,
+                 self.cache.shape[-1]),
+                self.cache.shape, engine_cfg.max_pages_per_seq)
         self.stats.moe_backend = self.moe_backend
         self.stats.moe_dispatch = self.moe_dispatch
         # kernel-vs-fallback visibility without scraping logs: an info-style

@@ -93,35 +93,56 @@ def one_chip():
 
 
 # (query heads, KV heads, pages a sequence) of the benchmark's configurations:
-# heads of 128, 16-token pages, model lengths 4,096 and 8,192
-CELL_LAYOUTS = {"qwen2.5-1.5b": (12, 2, 256), "mistral-7b-v0.3": (32, 8, 512)}
+# heads of 128, 16-token pages, model lengths 4,096, 8,192 and 16,384
+CELL_LAYOUTS = {"qwen2.5-1.5b": (12, 2, 256), "mistral-7b-v0.3": (32, 8, 512),
+                "smallthinker": (28, 4, 1024)}
+# the geometry the rule gives each at N = 64 (fused decode) and 256 (unified)
+CELL_GEOMETRY = {"qwen2.5-1.5b": {64: (32, 8), 256: (32, 16)},
+                 "mistral-7b-v0.3": {64: (32, 8), 256: (32, 16)},
+                 "smallthinker": {64: (64, 4), 256: (64, 8)}}
 
 
 @pytest.mark.parametrize("n", [64, 256])  # fused decode seats; unified tokens
-@pytest.mark.parametrize("config", sorted(CELL_LAYOUTS))
-def test_cell_shapes_compile_for_v5e_with_the_rules_geometry(one_chip, config, n):
+@pytest.mark.parametrize("config,window", [
+    (c, 0) for c in sorted(CELL_LAYOUTS)] + [("smallthinker", 4096)])
+def test_cell_shapes_compile_for_v5e_with_the_rules_geometry(one_chip, config,
+                                                             window, n):
     """The geometry `pick_block_sizes` gives the cells' step programs goes
     through Mosaic and the TPU compiler here: one it refuses (VMEM, tiling, an
-    unaligned slice) fails this test and not the cell."""
+    unaligned slice) fails this test and not the cell. A window layer's call
+    (the kernel's mask and page tables shifted by whole KV blocks) beside the
+    full layer's."""
+    from llmd_tpu.ops.paged_attention import call_geometry
+
     heads, kv_heads, maxp = CELL_LAYOUTS[config]
+    kw = {"sliding_window": window} if window else {}
 
     def fn(q, cache, pt, pos, slots, lens, cu, ns):
         return paged_attention_tpu(q, cache, pt, pos, slots, lens,
                                    scale=128 ** -0.5, cu_q_lens=cu,
-                                   num_seqs=ns)
+                                   num_seqs=ns, **kw)
 
+    q_shape, cache_shape = (n, heads, 128), (1024, 16, 2 * kv_heads, 128)
+    assert call_geometry(q_shape, cache_shape, maxp) == CELL_GEOMETRY[config][n]
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-            for a in _attn_args((n, heads, 128), (1024, 16, 2 * kv_heads, 128),
-                                64, maxp)]
+            for a in _attn_args(q_shape, cache_shape, 64, maxp)]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "ragged_paged_attention_kernel" in text
 
 
-def test_rules_geometry_matches_xla_reference_in_interpret_mode(monkeypatch):
-    """The upstream kernel at the rule's geometry (512-token KV blocks of 32
-    pages, 8 query rows a block) against the XLA reference: decode rows whose
-    contexts span one, two and three KV blocks, and a prefill chunk that
-    crosses a query-block boundary, in one batch."""
+@pytest.mark.parametrize("heads,kv_heads,geometry,block,window", [
+    (4, 2, (32, 8), 512, None),     # the even ratios' pair
+    (14, 2, (64, 4), 1024, None),   # seven query heads a KV head
+    (14, 2, (64, 4), 1024, 640),    # and as a window layer's call
+])
+def test_rules_geometry_matches_xla_reference_in_interpret_mode(
+        monkeypatch, heads, kv_heads, geometry, block, window):
+    """The upstream kernel at the rule's geometry (KV blocks of 32 or 64
+    pages) against the XLA reference: decode rows whose contexts span one,
+    two and three KV blocks, and a prefill chunk that crosses a query-block
+    boundary, in one batch; with a window, through page tables shifted by
+    whole KV blocks on the kernel's side and by whole pages on the
+    reference's."""
     from jax.experimental import pallas as pl
 
     from llmd_tpu.models.transformer import ragged_paged_attention_xla
@@ -129,11 +150,14 @@ def test_rules_geometry_matches_xla_reference_in_interpret_mode(monkeypatch):
 
     import numpy as np
 
-    ps, H, Hk, D, maxp, N, B = 16, 4, 2, 128, 96, 32, 8
-    seq_lens, q_lens = [300, 700, 1100, 600], [1, 1, 1, 20]
+    ps, H, Hk, D, N, B = 16, heads, kv_heads, 128, 32, 8
+    maxp = 3 * block // ps
+    seq_lens = [block * 19 // 32, block * 11 // 8, block * 17 // 8,
+                block * 19 // 16]
+    q_lens = [1, 1, 1, 20]
     rng = np.random.default_rng(0)
     P = sum(-(-L // ps) for L in seq_lens) + 3
-    assert call_geometry((N, H, D), (P, ps, 2 * Hk, D), maxp) == (32, 8)
+    assert call_geometry((N, H, D), (P, ps, 2 * Hk, D), maxp) == geometry
     free = rng.permutation(P)
     pt = np.full((B, maxp), -1, np.int32)
     lens, cu = np.ones((B,), np.int32), np.zeros((B + 1,), np.int32)
@@ -152,6 +176,8 @@ def test_rules_geometry_matches_xla_reference_in_interpret_mode(monkeypatch):
             jnp.asarray(lens))
     kw = dict(scale=D ** -0.5, cu_q_lens=jnp.asarray(cu),
               num_seqs=jnp.asarray([len(seq_lens)], jnp.int32))
+    if window:
+        kw["sliding_window"] = window
     want = np.asarray(ragged_paged_attention_xla(*args, **kw), np.float32)
     # the upstream wrapper takes no interpret flag: give its pallas_call one
     monkeypatch.setattr(pl, "pallas_call",

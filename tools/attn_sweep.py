@@ -13,8 +13,13 @@ history are what the cells see, not a uniform length.
 Every (bkv, bq) pair is one Mosaic compile and ``--reps`` dependent calls in a
 ``fori_loop``; the report is microseconds a call and microseconds per 128
 tokens of context read, which says whether the fixed part of a KV block or the
-work per query row sets the time. ``pick_block_sizes`` (the rule) is marked in
-the table. A pair the compiler refuses is recorded with its error.
+work per query row sets the time, and beside them the seconds the pair took to
+trace and lower (the kernel's page-fetch loop is unrolled in Python, so a
+larger block costs every launch that much more per call site, compile cache
+or not). ``pick_block_sizes`` (the rule) is marked in the table. A pair the
+compiler refuses is recorded with its error. ``--heads 12/4,24/4`` puts other
+head counts over a cell's pool and contexts: which property of a layout the
+best pair follows.
 
     python tools/attn_sweep.py                  # on the chip
     python tools/attn_sweep.py --compile-only   # here: which pairs Mosaic takes
@@ -44,8 +49,8 @@ CELLS = {
 }
 # live rows of a fused decode call and decode rows of a unified step
 # (PERF.md section 5: decode_seat_steps_total, program_rows_total)
-LIVE_DECODE = {"qwen": 48, "mistral": 32, "smallthinker": 32}
-UNIFIED_DECODE = {"qwen": 49, "mistral": 30, "smallthinker": 30}
+LIVE_DECODE = {"qwen": 48, "mistral": 32, "smallthinker": 53}
+UNIFIED_DECODE = {"qwen": 49, "mistral": 30, "smallthinker": 53}
 
 
 def _load(kind: str, name: str) -> dict:
@@ -106,11 +111,14 @@ def draw_batch(rng, cell: str, program: str, eng: dict, traffic: dict):
     return kv_lens.astype(np.int32), cu.astype(np.int32), rows
 
 
-def build_case(cell: str, program: str, seed: int):
+def build_case(cell: str, program: str, seed: int, heads: str = ""):
     import numpy as np
 
     cfg_name, traffic_name = CELLS[cell]
     cfg, traffic = _load("configs", cfg_name), _load("traffic", traffic_name)
+    if heads:  # another head layout over the cell's contexts and pages
+        cfg["num_attention_heads"], cfg["num_key_value_heads"] = map(
+            int, heads.split("/"))
     eng = cfg["engine"]
     rng = np.random.default_rng(seed)
     kv_lens, cu, rows = draw_batch(rng, cell, program, eng, traffic)
@@ -218,14 +226,16 @@ def measure(pa, case, geometry, reps, operands, chip):
     rule, row = pa.pick_block_sizes, dict(zip(("bkv", "bq"), geometry))
     pa.pick_block_sizes = lambda *a, **k: geometry
     try:
+        # timed apart: a launch pays trace + lowering even on a cache hit
         t0 = time.perf_counter()
-        fn = _attn_fn(pa, case, reps)
+        lowered = _attn_fn(pa, case, reps).lower(
+            *(_shapes(case, chip) if operands is None else operands))
+        t1 = time.perf_counter()
+        fn = lowered.compile()
+        row.update(lower_s=t1 - t0, compile_s=time.perf_counter() - t1)
         if operands is None:
-            fn.lower(*_shapes(case, chip)).compile()
-            row["compile_s"] = time.perf_counter() - t0
             return row
         out = jax.block_until_ready(fn(*operands))
-        row["compile_s"] = time.perf_counter() - t0
         times = []
         for _ in range(3):
             t0 = time.perf_counter()
@@ -258,6 +268,10 @@ def main() -> None:
                     help="sliding windows to time each shape with (0 = full "
                          "attention): a window layer's call, page tables "
                          "shifted as forward_core shifts them")
+    ap.add_argument("--heads", default="",
+                    help="query/KV head counts to put in the cells' place, "
+                         "e.g. 24/4,32/4: which property of a layout the "
+                         "best pair follows (empty = the configuration's own)")
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "attn_sweep.json"))
@@ -292,10 +306,11 @@ def main() -> None:
     report = {"device": device, "reps": args.reps, "shapes": []}
     pairs = [(bkv, bq) for bkv in map(int, args.bkv.split(","))
              for bq in map(int, args.bq.split(","))]
-    for cell, program, seed, window in itertools.product(
-            args.cells.split(","), args.programs.split(","),
-            map(int, args.seeds.split(",")), map(int, args.windows.split(","))):
-        case = build_case(cell, program, seed)
+    for cell, heads, program, seed, window in itertools.product(
+            args.cells.split(","), args.heads.split(","),
+            args.programs.split(","), map(int, args.seeds.split(",")),
+            map(int, args.windows.split(","))):
+        case = build_case(cell, program, seed, heads)
         case["window"] = window
         if window:  # what the window needs read (whole pages), and its floor
             from llmd_tpu.models.transformer import window_first_page
@@ -312,8 +327,8 @@ def main() -> None:
             "cell", "program", "N", "heads", "kv_heads", "page_size",
             "pages_per_seq", "num_seqs", "ctx_tokens", "floor_us", "window")}
         shape.update(seed=seed, rule=list(chosen), results=[])
-        print(f"\n## {cell} {program} N={case['N']} seed={seed} "
-              f"window={window}: "
+        print(f"\n## {cell} {case['heads']}/{case['kv_heads']} {program} "
+              f"N={case['N']} seed={seed} window={window}: "
               f"{case['num_seqs']} rows, {case['ctx_tokens']} context tokens, "
               f"{case['floor_us']:.0f} us at {peak_gbs:.0f} GB/s; rule {chosen}",
               flush=True)
@@ -333,9 +348,12 @@ def main() -> None:
                       f"us/call {row['us_per_128_ctx']:6.3f} us/128tok "
                       f"{100 * row['roofline']:5.1f}% of bytes "
                       f"diff {row['max_diff']:.4f} "
-                      f"(compile {row['compile_s']:.1f} s){mark}", flush=True)
+                      f"(trace+lower {row['lower_s']:.2f} s, compile "
+                      f"{row['compile_s']:.1f} s){mark}", flush=True)
             else:
-                said = row.get("error") or f"compiled in {row['compile_s']:.1f} s"
+                said = row.get("error") or (
+                    f"trace+lower {row['lower_s']:.2f} s, compiled in "
+                    f"{row['compile_s']:.1f} s")
                 print(f"bkv={bkv:3d} bq={bq:3d}: {said}{mark}", flush=True)
             shape["results"].append(row)
         if window and operands is not None:
