@@ -69,6 +69,7 @@ from llmd_tpu.models.transformer import (
     forward_core,
     init_cache,
     init_params,
+    init_state,
     param_logical_axes,
     ragged_paged_attention_xla,
     unembed,
@@ -310,11 +311,18 @@ class LLMEngine:
                 raise ValueError(
                     f"batched_tokens ({engine_cfg.batched_tokens}) must be at "
                     f"least dp_ranks={R} (each rank needs a token budget)")
+        if model_cfg.has_recurrent:
+            self._refuse_with_recurrent_layers(engine_cfg)
+        # A cached page stands for a prefix only where every layer's state at
+        # its boundary is in the pool: a recurrent layer's is not (snapshots
+        # at block boundaries are not written), so such a model reuses none.
+        self.prefix_reuse = (engine_cfg.enable_prefix_caching
+                             and not model_cfg.has_recurrent)
         ppr = engine_cfg.num_pages // R
         self.allocs = [
             PageAllocator(
                 ppr, engine_cfg.page_size,
-                enable_prefix_caching=engine_cfg.enable_prefix_caching,
+                enable_prefix_caching=self.prefix_reuse,
                 event_sink=event_sink, base_id=r * ppr,
             )
             for r in range(R)
@@ -539,6 +547,13 @@ class LLMEngine:
                     else P(None, None, "tp", None))
             self.cache = jax.device_put(
                 self.cache, NamedSharding(self.mesh, spec))
+        # the recurrent-state pool beside the KV pool: a seat owns a slot
+        # (no allocator), one more is the padding rows' scratch
+        self.state: dict[str, jax.Array] = {}
+        if model_cfg.has_recurrent:
+            self.state = init_state(model_cfg, engine_cfg.max_batch_size)
+            self.metrics.ssm_state_slots.set_function(
+                lambda: sum(s is not None for s in self.running))
 
         self._eplb = None
         if engine_cfg.eplb is not None and model_cfg.is_moe:
@@ -589,6 +604,28 @@ class LLMEngine:
             attn = make_packed_attn(attn, model_cfg, self.kv_pack)
             self.attn_backend += f"+packed{self.kv_pack}"
         attn_decode = select_decode_attn_impl(self, attn)
+        if model_cfg.has_recurrent and self.attn_backend.startswith("pallas"):
+            # a recurrent layer carries the last bit of an attention layer's
+            # result on, so a prompt's chunks are handed to the kernel cut at
+            # its KV blocks' ends (ops/paged_attention.split_rows_at_kv_blocks);
+            # the fused call's rows bring one query each and are never cut
+            attn = functools.partial(attn, split_at_kv_blocks=True)
+        # what only a model with recurrent layers hands forward_core: the
+        # selective scan (the Pallas kernel wherever the Pallas attention
+        # kernel serves, the XLA form elsewhere)
+        ssm_kw: dict = {}
+        self.ssm_backend: Optional[str] = None
+        if model_cfg.has_recurrent:
+            from llmd_tpu.ops.selective_scan import make_selective_scan
+
+            impl = "pallas" if self.attn_backend.startswith("pallas") else "xla"
+            ssm_kw["scan_impl"] = make_selective_scan(
+                impl, interpret=self._pallas_interpret)
+            self.ssm_backend = f"{impl}_selective_scan"
+            self.metrics.ssm_backend_info.labels(
+                impl=self.ssm_backend,
+                state_dtype=model_cfg.mamba_state_dtype,
+                prefix_reuse="off").set(1)
         moe_impl = self._select_moe_impl()
         moe_dispatch_impl = self._select_moe_dispatch()
         self.stats.attn_backend = self.attn_backend
@@ -631,7 +668,8 @@ class LLMEngine:
         def _make_unified(attn_fn):
             def _unified(params, cache, tokens, positions, seq_slots, page_tables,
                          kv_lens, cu_q_lens, num_seqs, lora_tok, prev_sampled,
-                         temp, top_k, top_p, key, mm_embeds=None, mm_mask=None):
+                         temp, top_k, top_p, key, mm_embeds=None, mm_mask=None,
+                         state_slots=None):
                 """Flat mixed batch (prefill chunks + decode tokens); returns each
                 sequence's last-row logits [B, vocab] and the token picked
                 from them [B], by the sampler every program shares
@@ -661,6 +699,9 @@ class LLMEngine:
                     lora_scale=lora_scale,
                     mm_embeds=mm_embeds, mm_mask=mm_mask,
                     moe_dispatch_impl=moe_dispatch_impl,
+                    # rows are in plan order, not seat order: a model with
+                    # recurrent layers is sent each row's state slot
+                    **(dict(ssm_kw, state_slots=state_slots) if ssm_kw else {}),
                 )
                 last_rows = jnp.clip(cu_q_lens[1 : B + 1] - 1, 0, NT - 1)  # [B]
                 logits = unembed(cfg, params, hidden[last_rows])  # [B, vocab]
@@ -757,6 +798,17 @@ class LLMEngine:
 
             return _verify_masked
 
+        def _live_pos(pos, i, steps_left):
+            """The positions a fused call's step ``i`` hands the model. A row
+            that has spent its steps keeps its position in the carry and
+            computes on (its KV write lands on a position it will write
+            again); a recurrent layer's state must not take that step, and
+            forward_core leaves the slot of a row at position -1 untouched:
+            the model with recurrent layers is told so."""
+            if not cfg.has_recurrent:
+                return pos
+            return jnp.where(i < steps_left, pos, -1)
+
         def _decode_multi(params, cache, tokens, positions, page_tables, kv_lens,
                           temp, top_k, top_p, key, steps_left, lora_idx):
             """k decode iterations fused on-device (lax.scan): feed sampled token back
@@ -779,12 +831,13 @@ class LLMEngine:
             def body(carry, i):
                 cache, toks, pos, lens, key = carry
                 hidden, cache, cnt, drop = forward_core(
-                    cfg, params, cache, toks, pos, seq_slots, page_tables, lens,
+                    cfg, params, cache, toks, _live_pos(pos, i, steps_left),
+                    seq_slots, page_tables, lens,
                     cu_q_lens=cu, num_seqs=ns, attn_impl=attn_decode,
                     moe_matmul_impl=moe_impl,
                     lora_indices=lora_idx if use_lora else None,
                     lora_scale=lora_scale,
-                    moe_dispatch_impl=moe_dispatch_impl,
+                    moe_dispatch_impl=moe_dispatch_impl, **ssm_kw,
                 )
                 logits = unembed(cfg, params, hidden)  # [B, vocab]
                 key, sub = jax.random.split(key)
@@ -833,12 +886,13 @@ class LLMEngine:
             def body(carry, i):
                 cache, toks, pos, lens, key, st = carry
                 hidden, cache, cnt, drop = forward_core(
-                    cfg, params, cache, toks, pos, seq_slots, page_tables, lens,
+                    cfg, params, cache, toks, _live_pos(pos, i, steps_left),
+                    seq_slots, page_tables, lens,
                     cu_q_lens=cu, num_seqs=ns, attn_impl=attn_decode,
                     moe_matmul_impl=moe_impl,
                     lora_indices=lora_idx if use_lora else None,
                     lora_scale=lora_scale,
-                    moe_dispatch_impl=moe_dispatch_impl,
+                    moe_dispatch_impl=moe_dispatch_impl, **ssm_kw,
                 )
                 logits = unembed(cfg, params, hidden).astype(jnp.float32)
                 row_bias = bias_tab[gidx, st]  # [B, vocab]
@@ -1275,6 +1329,59 @@ class LLMEngine:
             self.metrics.attn_kv_tokens.labels(program=program,
                                                layers=kind).inc(n)
 
+    def _count_ssm_tokens(self, program: str, chunk: int, decode: int) -> None:
+        """``ssm_scan_tokens_total`` of one dispatched call: the tokens one
+        mamba layer's scan is given, by the kind of row that brings them."""
+        for rows, n in (("chunk", chunk), ("decode", decode)):
+            if n:
+                self.metrics.ssm_scan_tokens.labels(program=program,
+                                                    rows=rows).inc(n)
+
+    def _pools(self):
+        """What a step program takes as its donated ``cache``: the KV pool,
+        with the recurrent-state pool beside it where the model has one."""
+        return {"kv": self.cache, **self.state} if self.state else self.cache
+
+    def _keep_pools(self, pools) -> None:
+        """Keep what a step program returned in ``_pools``'s place."""
+        if self.state:
+            self.state = {k: v for k, v in pools.items() if k != "kv"}
+            pools = pools["kv"]
+        self.cache = pools
+
+    @staticmethod
+    def _refuse_with_recurrent_layers(engine_cfg: EngineConfig) -> None:
+        """What a model with recurrent layers cannot be combined with, each
+        refused by its name: the state a seat's slot holds exists nowhere
+        else, so nothing that rolls a sequence back, moves it or splits its
+        channels can be served."""
+        why = {
+            "spec_mode": (engine_cfg.spec_mode != "off",
+                          "a verify step cannot roll a recurrent state back "
+                          "over the rejected tokens"),
+            "cpu_offload_pages": (
+                engine_cfg.cpu_offload_pages > 0
+                or bool(engine_cfg.offload_fs_path),
+                "an offloaded page would need the recurrent layers' state at "
+                "its boundary, which is not kept"),
+            "kv_connector": (bool(engine_cfg.kv_connector),
+                             "a transferred sequence would need its "
+                             "recurrent state sent too"),
+            "role": (engine_cfg.role != "both",
+                     "prefill/decode disaggregation transfers pages, not "
+                     "recurrent state"),
+            "lora": (engine_cfg.lora is not None,
+                     "the mamba mixer has no adapter hook"),
+            "mesh.tp": (engine_cfg.mesh.tp > 1,
+                        "one KV head and the mixer's channels are not "
+                        "sharded"),
+        }
+        for name, (bad, reason) in why.items():
+            if bad:
+                raise ValueError(
+                    f"{name}: not supported for a model with recurrent "
+                    f"layers ({reason})")
+
     def _moe_record(self, drop, cnt) -> None:
         """What a step's mixture layers report. ``drop``: every routed copy
         the legacy einsum path dropped past capacity C (the sorted path
@@ -1486,7 +1593,7 @@ class LLMEngine:
 
             keys = block_keys_for_tokens(seq.token_ids[: seq.prompt_len], ps,
                                          seq.lora_key, seq.mm_hashes())
-            hit_pages = alloc.match_prefix(keys) if self.cfg.enable_prefix_caching else []
+            hit_pages = alloc.match_prefix(keys) if self.prefix_reuse else []
             # never reuse the whole prompt — the final token's logits must be computed
             max_reuse = max(0, (seq.prompt_len - 1) // ps)
             hit_pages = hit_pages[:max_reuse]
@@ -1669,6 +1776,7 @@ class LLMEngine:
             self.lora_registry.on_finished(victim.lora_id)
             self.lora_registry.on_waiting(victim.lora_id)
         self._free_seq(victim)
+        victim.recompute = True  # its next first chunk is no admission
         victim.num_computed = 0
         victim.block_hashes = []
         victim.num_cached_prompt = 0
@@ -1962,6 +2070,10 @@ class LLMEngine:
         pts = np.full((B, self.cfg.max_pages_per_seq), -1, np.int32)
         lens = np.ones((B,), np.int32)
         cu = np.zeros((B + 1,), np.int32)
+        # a model with recurrent layers: the state slot of each row (its
+        # seat's; the scratch slot B for the rows the plan leaves empty)
+        recurrent = bool(self.state)
+        row_slots = np.full((B,), B, np.int32) if recurrent else None
         # only pay the mm staging buffers when this step actually carries media
         # prefill rows (text-only steps on a VL model jit a no-mm variant)
         is_vl = self.model_cfg.mm_tokens > 0 and any(
@@ -1995,6 +2107,12 @@ class LLMEngine:
             lora_tok[off : off + n] = self._lora_slot(s)
             pts[i, : len(s.pages)] = s.pages
             lens[i] = start + n
+            if recurrent:
+                row_slots[i] = s.slot
+                if start == 0:  # the row starts from a zero state
+                    self.metrics.ssm_state_resets.labels(
+                        cause="recompute" if s.recompute else "admit").inc()
+                    s.recompute = False
             if is_vl and s.mm_items and not is_decode:
                 ph = self.model_cfg.mm_placeholder_id
                 k = self.model_cfg.mm_tokens
@@ -2044,13 +2162,18 @@ class LLMEngine:
         prev_sampled = prev["sampled"] if prev is not None else None
         if prev_sampled is None:
             prev_sampled = self._zero_sampled
+        state_kw = {}
+        if recurrent:
+            state_kw["state_slots"] = jnp.asarray(row_slots)
+            self._count_ssm_tokens(step_prog, chunk=off - n_dec, decode=n_dec)
         sampling, samples = self._sampling_state(sample_list)
-        logits, sampled, self.cache, cnt, moe_drop = step_fn(
-            self._run_params(), self.cache, jnp.asarray(toks), jnp.asarray(pos),
+        logits, sampled, pools, cnt, moe_drop = step_fn(
+            self._run_params(), self._pools(), jnp.asarray(toks), jnp.asarray(pos),
             jnp.asarray(sids), jnp.asarray(pts), jnp.asarray(lens), jnp.asarray(cu),
             jnp.asarray([len(plan)], jnp.int32), jnp.asarray(lora_tok),
-            prev_sampled, *sampling, *mm_args,
+            prev_sampled, *sampling, *mm_args, **state_kw,
         )
+        self._keep_pools(pools)
         parts.to("apply")
 
         # goodput classification reads pre-postprocess sequence state: the
@@ -2909,21 +3032,22 @@ class LLMEngine:
             self.stats.time_host_pack += host_pack
             self.metrics.step_duration.labels(phase="pack").observe(host_pack)
         if mask is not None:
-            (toks_out, last_toks, pos_out, lens_out, fsm_out, self.cache,
+            (toks_out, last_toks, pos_out, lens_out, fsm_out, pools,
              cnt, moe_drop) = self._decode_multi_masked_fn(
-                self._run_params(), self.cache, toks_in, pos_in, pts_dev,
+                self._run_params(), self._pools(), toks_in, pos_in, pts_dev,
                 lens_in, temp_dev, tk_dev, tp_dev, sub, steps_dev, lora_dev,
                 fsm_in, mask["gidx"], mask["bias_tab"], mask["next_tab"],
             )
         else:
-            (toks_out, last_toks, pos_out, lens_out, self.cache, cnt,
+            (toks_out, last_toks, pos_out, lens_out, pools, cnt,
              moe_drop) = (
                 self._decode_multi_fn(
-                    self._run_params(), self.cache, toks_in, pos_in, pts_dev,
+                    self._run_params(), self._pools(), toks_in, pos_in, pts_dev,
                     lens_in, temp_dev, tk_dev, tp_dev, sub, steps_dev,
                     lora_dev,
                 ))
             fsm_out = None
+        self._keep_pools(pools)
         parts.to("book")
         self.stats.time_decode_steps += host_pack + sec["dispatch"]
         self.stats.n_decode_dispatches += 1
@@ -2932,6 +3056,9 @@ class LLMEngine:
         self.programs.record_dispatch(prog)
         self.metrics.program_kv_read_tokens.labels(program=prog).inc(ctx_tokens)
         self._count_attn_kv(prog, ctx_lens, np.ones(len(ctx_lens), np.int64))
+        if self.state:
+            # a row takes as many of the call's k steps as it has left
+            self._count_ssm_tokens(prog, chunk=0, decode=int(steps_left.sum()))
         self.metrics.program_rows.labels(program=prog).inc(len(active))
         self.metrics.sampler_steps.labels(program="decode",
                                           path=sampler_path).inc(k)
@@ -3361,6 +3488,10 @@ class LLMEngine:
         """
         if not token_ids:
             raise ValueError("empty input")
+        if self.state:
+            raise ValueError(
+                "embeddings: not supported for a model with recurrent layers "
+                "(the embed program borrows pages, and has no state slot)")
         token_ids = token_ids[: self.cfg.max_model_len - 1]
         chunk = self.cfg.prefill_chunk
         ps = self.cfg.page_size

@@ -230,6 +230,7 @@ class Sequence:
     num_computed: int = 0  # tokens whose KV is resident
     num_cached_prompt: int = 0  # tokens reused from prefix cache
     slot: int = -1  # decode batch slot
+    recompute: bool = False  # preempted, and not dispatched from position 0 since
     finished: bool = False
     finish_reason: Optional[str] = None
     block_hashes: list[int] = field(default_factory=list)  # chained hashes of committed blocks

@@ -208,11 +208,14 @@ def main() -> None:
             prov = (f" [weights={args.quantize or 'ckpt-dtype'}, "
                     f"kv={args.kv_cache_dtype or 'ckpt-dtype'}]")
         eng = server.engine
+        ssm = getattr(eng, "ssm_backend", None)
         print(f"llmd-tpu engine serving {server.model_name} on http://{server.address} "
               f"(kv-events port {server.kv_events_port}){prov} "
               f"[attn={eng.attn_backend}, moe={eng.moe_backend}, "
               f"moe_dispatch={eng.moe_dispatch}] "
-              f"[attn_geometry {eng.attn_geometry}]", flush=True)
+              f"[attn_geometry {eng.attn_geometry}]"
+              + (f" [ssm={ssm}, state={eng.model_cfg.mamba_state_dtype}, "
+                 "prefix_reuse=off]" if ssm else ""), flush=True)
         await _serve_until_fatal(server.async_engine, server.stop)
 
     asyncio.run(run())

@@ -36,6 +36,23 @@ class ModelConfig:
     attn_window_pattern: tuple = (0,)
     # False = the layer applies no positional encoding to q/k (NoPE).
     rope_pattern: tuple = (True,)
+    # Kinds of layer whose parameter SHAPES differ, as a repeating period:
+    # layer l is of kind layer_kinds[l % len]. "attention" is the block above;
+    # "mamba" is a selective state-space mixer (Mamba-1 with Jamba's three
+    # inner norms) in attention's place, over the same dense MLP. Each kind
+    # has its own stacked leaves (``[num_mamba_layers, ...]``,
+    # ``[num_attn_layers, ...]``; norms and the MLP ``[num_layers, ...]``),
+    # only attention layers have pages in the KV pool, and a mamba layer
+    # keeps a recurrent state per seat beside it (transformer.init_state).
+    layer_kinds: tuple = ("attention",)
+    mamba_d_inner: int = 0  # channels of the mixer (expand * hidden_size)
+    mamba_d_state: int = 16  # SSM state a channel
+    mamba_d_conv: int = 4  # taps of the causal depthwise conv
+    mamba_dt_rank: int = 0
+    mamba_conv_bias: bool = True
+    # What the recurrent SSM state is held in between steps; the conv window
+    # is held in the model's dtype.
+    mamba_state_dtype: str = "float32"
     # MoE (0 experts = dense). Every layer is a mixture layer of one shape.
     moe_num_experts: int = 0
     moe_top_k: int = 2
@@ -83,6 +100,34 @@ class ModelConfig:
                            tuple(int(w) for w in self.attn_window_pattern))
         object.__setattr__(self, "rope_pattern",
                            tuple(bool(r) for r in self.rope_pattern))
+        object.__setattr__(self, "layer_kinds",
+                           tuple(str(k) for k in self.layer_kinds))
+        if set(self.layer_kinds) - {"attention", "mamba"} or \
+                "attention" not in self.layer_kinds:
+            raise ValueError(
+                f"layer_kinds {self.layer_kinds}: a period of 'attention' and "
+                "'mamba' layers with at least one attention layer")
+        if self.has_recurrent:
+            if self.num_layers % len(self.layer_kinds) or \
+                    len(self.attn_window_pattern) != 1:
+                raise ValueError(
+                    f"layer_kinds {self.layer_kinds} must be one period that "
+                    f"divides num_layers={self.num_layers}, over attention "
+                    "layers of one kind (attn_window_pattern and rope_pattern "
+                    "of one entry)")
+            if min(self.mamba_d_inner, self.mamba_d_state, self.mamba_dt_rank,
+                   self.mamba_d_conv - 1) < 1:
+                raise ValueError(
+                    "a model with mamba layers states mamba_d_inner, "
+                    "mamba_d_state, mamba_dt_rank and mamba_d_conv >= 2")
+            if self.mamba_state_dtype not in ("float32", "bfloat16"):
+                raise ValueError(
+                    f"mamba_state_dtype={self.mamba_state_dtype!r}")
+            if self.is_moe or self.is_mla or self.qk_norm or self.attn_bias:
+                raise ValueError(
+                    "mamba layers stand over the dense MLP and beside plain "
+                    "GQA attention layers only (no mixture, MLA, q/k norm or "
+                    "attention bias)")
         if len(self.attn_window_pattern) != len(self.rope_pattern) or \
                 self.num_layers % len(self.rope_pattern):
             raise ValueError(
@@ -98,6 +143,33 @@ class ModelConfig:
     def layer_period(self) -> int:
         """Layers in one period of the attention pattern (1 = one kind)."""
         return len(self.attn_window_pattern)
+
+    @property
+    def has_recurrent(self) -> bool:
+        """Some layer keeps a recurrent state per sequence (mamba)."""
+        return "mamba" in self.layer_kinds
+
+    @property
+    def num_mamba_layers(self) -> int:
+        return (self.num_layers // len(self.layer_kinds)
+                * self.layer_kinds.count("mamba"))
+
+    @property
+    def num_attn_layers(self) -> int:
+        """Layers with pages in the KV pool: the pool folds this many."""
+        return self.num_layers - self.num_mamba_layers
+
+    @property
+    def layer_runs(self) -> tuple:
+        """One period of ``layer_kinds`` as runs of one kind:
+        ``((kind, first layer of the run within the period, layers), ...)``."""
+        runs: list = []
+        for j, kind in enumerate(self.layer_kinds):
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, j, 1])
+        return tuple(tuple(r) for r in runs)
 
     @property
     def has_window(self) -> bool:

@@ -12,6 +12,10 @@ Supported architectures (config.json ``architectures[0]``):
 - ``LlamaForCausalLM`` / ``MistralForCausalLM`` — GQA, SwiGLU, optional tied embeddings
 - ``Qwen2ForCausalLM`` — adds q/k/v projection biases
 - ``Qwen3ForCausalLM`` — adds per-head q/k RMSNorm and an explicit ``head_dim``
+- ``JambaForCausalLM`` with ``num_experts: 1`` — Mamba layers (Mamba-1 with
+  inner RMSNorms on dt, B and C) around one NoPE attention layer every
+  ``attn_layer_period``, a dense SwiGLU MLP in every layer, each kind's leaves
+  stacked over the layers of that kind (``_load_jamba_params``)
 
 Handles single-file ``model.safetensors`` and sharded
 ``model.safetensors.index.json`` checkpoints; weights are cast to the target dtype
@@ -36,6 +40,7 @@ _ARCH_FAMILY = {
     "MistralForCausalLM": "llama",
     "Qwen2ForCausalLM": "qwen2",
     "Qwen3ForCausalLM": "qwen3",
+    "JambaForCausalLM": "jamba",
 }
 
 
@@ -54,6 +59,8 @@ def config_from_hf(path: str, dtype: str = "bfloat16") -> ModelConfig:
         raise ValueError(
             f"unsupported architecture {arch!r}; supported: {sorted(_ARCH_FAMILY)}"
         )
+    if family == "jamba":
+        return _jamba_config(hf, path, arch, dtype)
     scaling = hf.get("rope_scaling")
     if scaling and scaling.get("rope_type", scaling.get("type", "default")) != "default":
         # Loading would succeed but produce silently wrong logits (scaled RoPE
@@ -96,6 +103,99 @@ def config_from_hf(path: str, dtype: str = "bfloat16") -> ModelConfig:
         # honour an explicit attention_bias on any family; qwen2's default is True
         attn_bias=bool(hf.get("attention_bias", family == "qwen2")),
     )
+
+
+def _jamba_config(hf: dict, path: str, arch: str, dtype: str) -> ModelConfig:
+    """JambaConfig -> ModelConfig; what the program cannot express is refused
+    by the key's name."""
+    only = {"num_experts": 1, "sliding_window": None, "mamba_proj_bias": False,
+            "hidden_act": "silu"}
+    for key, want in only.items():
+        if hf.get(key, want) != want:
+            raise ValueError(f"{key}={hf[key]!r} in {path}: the program has "
+                             f"only {key}={want!r} for {arch}")
+    D, H = int(hf["hidden_size"]), int(hf["num_attention_heads"])
+    per, off = int(hf["attn_layer_period"]), int(hf["attn_layer_offset"])
+    rank = hf.get("mamba_dt_rank", "auto")
+    return ModelConfig(
+        name=os.path.basename(os.path.normpath(path)) or arch,
+        vocab_size=int(hf["vocab_size"]),
+        hidden_size=D,
+        intermediate_size=int(hf["intermediate_size"]),
+        num_layers=int(hf["num_hidden_layers"]),
+        num_heads=H,
+        num_kv_heads=int(hf.get("num_key_value_heads", H)),
+        head_dim=D // H,
+        rms_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        max_position=int(hf.get("max_position_embeddings", 262144)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        dtype=dtype,
+        rope_pattern=(False,),  # no positional encoding on any layer
+        layer_kinds=tuple("attention" if l % per == off else "mamba"
+                          for l in range(per)),
+        mamba_d_inner=int(hf.get("mamba_expand", 2)) * D,
+        mamba_d_state=int(hf.get("mamba_d_state", 16)),
+        mamba_d_conv=int(hf.get("mamba_d_conv", 4)),
+        mamba_dt_rank=-(-D // 16) if rank == "auto" else int(rank),
+        mamba_conv_bias=bool(hf.get("mamba_conv_bias", True)),
+    )
+
+
+def _load_jamba_params(src: "_TensorSource", cfg: ModelConfig) -> dict:
+    """Jamba's published tensors onto the program's leaves: the norms and
+    the MLP stacked over all layers, the attention projections over the
+    attention layers, the mixer's tensors over the mamba layers. The mixer's
+    weights are transposed to matmul-ready [in, out]; ``conv1d.weight``
+    [Di, 1, K] becomes ``mamba_conv_w`` [K, Di] and ``A_log`` [Di, N] becomes
+    ``mamba_a_log`` [N, Di] (the state is held [N, Di], d_inner on the
+    lanes)."""
+    dt = cfg.jax_dtype
+    D, H, Hk, Dh = cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    kinds = [cfg.layer_kinds[l % len(cfg.layer_kinds)]
+             for l in range(cfg.num_layers)]
+    g = src.get
+
+    def stack(fn, kind=None) -> jax.Array:
+        return jnp.asarray(np.stack([
+            fn(f"model.layers.{l}.") for l in range(cfg.num_layers)
+            if kind is None or kinds[l] == kind]), dt)
+
+    def attn(fn):
+        return stack(fn, "attention")
+
+    def mamba(fn):
+        return stack(fn, "mamba")
+
+    p = {
+        "embed": jnp.asarray(g("model.embed_tokens.weight"), dt),
+        "final_norm": jnp.asarray(g("model.final_layernorm.weight"), dt),
+        "attn_norm": stack(lambda l: g(l + "input_layernorm.weight")),
+        "mlp_norm": stack(lambda l: g(l + "pre_ff_layernorm.weight")),
+        "wi": stack(lambda l: np.concatenate(
+            [g(l + "feed_forward.gate_proj.weight").T,
+             g(l + "feed_forward.up_proj.weight").T], axis=-1)),
+        "wo_mlp": stack(lambda l: g(l + "feed_forward.down_proj.weight").T),
+        "wq": attn(lambda l: g(l + "self_attn.q_proj.weight").T.reshape(D, H, Dh)),
+        "wk": attn(lambda l: g(l + "self_attn.k_proj.weight").T.reshape(D, Hk, Dh)),
+        "wv": attn(lambda l: g(l + "self_attn.v_proj.weight").T.reshape(D, Hk, Dh)),
+        "wo": attn(lambda l: g(l + "self_attn.o_proj.weight").T.reshape(H, Dh, D)),
+        "mamba_in": mamba(lambda l: g(l + "mamba.in_proj.weight").T),
+        "mamba_conv_w": mamba(lambda l: g(l + "mamba.conv1d.weight")[:, 0, :].T),
+        "mamba_x": mamba(lambda l: g(l + "mamba.x_proj.weight").T),
+        "mamba_dt_norm": mamba(lambda l: g(l + "mamba.dt_layernorm.weight")),
+        "mamba_b_norm": mamba(lambda l: g(l + "mamba.b_layernorm.weight")),
+        "mamba_c_norm": mamba(lambda l: g(l + "mamba.c_layernorm.weight")),
+        "mamba_dt": mamba(lambda l: g(l + "mamba.dt_proj.weight").T),
+        "mamba_dt_bias": mamba(lambda l: g(l + "mamba.dt_proj.bias")),
+        "mamba_a_log": mamba(lambda l: g(l + "mamba.A_log").T),
+        "mamba_d": mamba(lambda l: g(l + "mamba.D")),
+        "mamba_out": mamba(lambda l: g(l + "mamba.out_proj.weight").T),
+    }
+    if cfg.mamba_conv_bias:
+        p["mamba_conv_b"] = mamba(lambda l: g(l + "mamba.conv1d.bias"))
+    if not cfg.tie_embeddings:
+        p["unembed"] = jnp.asarray(g("lm_head.weight").T, dt)
+    return p
 
 
 class _TensorSource:
@@ -160,6 +260,8 @@ def load_params(
         cfg = config_from_hf(path, dtype=dtype or "bfloat16")
     dt = cfg.jax_dtype
     src = _TensorSource(path)
+    if cfg.has_recurrent:
+        return _load_jamba_params(src, cfg)
     D, H, Hk, Dh = cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     L, F = cfg.num_layers, cfg.intermediate_size
 

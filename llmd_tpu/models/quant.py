@@ -14,7 +14,8 @@ operand stream — no dequantized copy is ever materialised in HBM) and the
 scale applies to the matmul OUTPUT, a [*, out] elementwise multiply that
 fuses into the surrounding graph.
 
-Quantized: the dense per-layer projections (wq/wk/wv/wo, wi/wo_mlp), the
+Quantized: the dense per-layer projections (wq/wk/wv/wo, wi/wo_mlp; a mamba
+layer's in and out projections), the
 MoE expert banks and shared experts (per-expert per-output-channel scales;
 the expert GEMMs then run the einsum path — the Pallas grouped GEMM is
 bf16-only), and the unembedding. Kept bf16: norms, biases and the router
@@ -48,12 +49,15 @@ _CONTRACT: dict[str, tuple[str, ...]] = {
     "moe_wo": ("expert_mlp",),
     "shared_wi": ("embed",),
     "shared_wo": ("mlp",),
+    "mamba_in": ("embed",),
+    "mamba_out": ("mamba_inner",),
     # (the unembedding quantizes via its own branch below: its source can be
     # embed.T under tie_embeddings, which has no entry in the axes dict)
 }
 
 QUANTIZABLE_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wi", "wo_mlp",
-                          "moe_wi", "moe_wo", "shared_wi", "shared_wo")
+                          "moe_wi", "moe_wo", "shared_wi", "shared_wo",
+                          "mamba_in", "mamba_out")
 
 
 def _quantize_one(w: jax.Array, contract_axes: tuple[int, ...]):

@@ -91,6 +91,29 @@ MODEL_REGISTRY: dict[str, ModelConfig] = {
         moe_num_experts=32, moe_top_k=4, moe_intermediate_size=512,
         moe_num_shared_experts=1,
     ),
+    # Mamba layers around one NoPE attention layer a period, over dense MLPs
+    # (Jamba with num_experts 1), at CI size: (mamba, attention, mamba, mamba)
+    # twice. The recurrent-state pool, the selective scan and the per-kind
+    # stacked leaves on the serving surface.
+    "tiny-jamba": ModelConfig(
+        name="tiny-jamba", vocab_size=288, hidden_size=128,
+        intermediate_size=256, num_layers=8, num_heads=4, num_kv_heads=1,
+        head_dim=32, rope_pattern=(False,),
+        layer_kinds=("mamba", "attention", "mamba", "mamba"),
+        mamba_d_inner=256, mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=8,
+    ),
+    # AI21-Jamba2-3B at its published sizes (config.json: attn_layer_period
+    # 14, attn_layer_offset 7, num_experts 1): 26 Mamba layers and 2 NoPE
+    # attention layers (7 and 21), 3.03 B parameters, 6.06 GB of bf16.
+    "jamba2-3b": ModelConfig(
+        name="jamba2-3b", vocab_size=65536, hidden_size=2560,
+        intermediate_size=8192, num_layers=28, num_heads=20, num_kv_heads=1,
+        head_dim=128, max_position=262144, tie_embeddings=True,
+        rope_pattern=(False,),
+        layer_kinds=("mamba",) * 7 + ("attention",) + ("mamba",) * 6,
+        mamba_d_inner=5120, mamba_d_state=16, mamba_d_conv=4,
+        mamba_dt_rank=160,
+    ),
 }
 
 

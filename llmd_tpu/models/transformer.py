@@ -38,6 +38,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from llmd_tpu.models.config import ModelConfig
@@ -108,11 +109,100 @@ def param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
         axes |= {"wi": ("layers", "embed", "mlp"), "wo_mlp": ("layers", "mlp", "embed")}
     if not cfg.tie_embeddings:
         axes["unembed"] = ("embed", "vocab")
+    if cfg.has_recurrent:
+        # each kind's leaves are stacked over the layers of that kind (their
+        # leading axis is still 'layers': attention leaves [num_attn_layers,
+        # ...] as above, mamba leaves [num_mamba_layers, ...]); the mixer's
+        # channels are replicated (a tp mesh is refused for such a model)
+        axes |= {
+            "mamba_in": ("layers", "embed", "mamba_inner"),
+            "mamba_conv_w": ("layers", None, "mamba_inner"),
+            "mamba_x": ("layers", "mamba_inner", None),
+            "mamba_dt_norm": ("layers", None),
+            "mamba_b_norm": ("layers", None),
+            "mamba_c_norm": ("layers", None),
+            "mamba_dt": ("layers", None, "mamba_inner"),
+            "mamba_dt_bias": ("layers", "mamba_inner"),
+            "mamba_a_log": ("layers", None, "mamba_inner"),
+            "mamba_d": ("layers", "mamba_inner"),
+            "mamba_out": ("layers", "mamba_inner", "embed"),
+        }
+        if cfg.mamba_conv_bias:
+            axes["mamba_conv_b"] = ("layers", "mamba_inner")
     return axes
+
+
+def _init_hybrid_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
+    """Random-init params of a model with mamba layers. Each stacked leaf is
+    drawn a layer at a time inside one jitted ``lax.map`` (float32 draw, cast,
+    next layer): ``init_params``'s eager draw holds two float32 copies of a
+    whole stacked leaf, and ``wi`` at [28, 2560, 16384] would be 4.7 GB a copy
+    beside 6 GB of finished leaves on a 16 GB chip.
+
+    The mixer's leaves, for layer m of the mamba layers (published names in
+    models/hf_loader.py): ``mamba_in`` [D, 2*Di] (x half, then gate z),
+    ``mamba_conv_w`` [K, Di] (tap k multiplies the row K-1-k tokens back),
+    ``mamba_conv_b`` [Di], ``mamba_x`` [Di, R + 2N] (dt, B, C),
+    ``mamba_dt_norm`` / ``mamba_b_norm`` / ``mamba_c_norm`` (Jamba's inner
+    RMSNorms), ``mamba_dt`` [R, Di] + ``mamba_dt_bias`` [Di], ``mamba_a_log``
+    [N, Di] (A = -exp(.)), ``mamba_d`` [Di], ``mamba_out`` [Di, D]. The draw
+    follows Mamba's own initialisation where it matters for the recurrence:
+    A = -(1..N), dt's bias the inverse softplus of a step log-uniform in
+    [1e-3, 1e-1], D = 1; so a state remembers hundreds of tokens."""
+    dt = cfg.jax_dtype
+    L, La, Lm = cfg.num_layers, cfg.num_attn_layers, cfg.num_mamba_layers
+    D, H, Hk, Dh, F = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+                       cfg.head_dim, cfg.intermediate_size)
+    Di, N, K, R = (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv,
+                   cfg.mamba_dt_rank)
+    keys = iter(jax.random.split(key, 16))
+
+    def norm(n, shape, scale):
+        @jax.jit
+        def draw(ks):
+            return lax.map(lambda k: (jax.random.normal(k, shape, jnp.float32)
+                                      * scale).astype(dt), ks)
+        return draw(jax.random.split(next(keys), n))
+
+    s = D ** -0.5
+    p: dict[str, jax.Array] = {
+        "embed": norm(1, (cfg.vocab_size, D), 0.02)[0],
+        "final_norm": jnp.ones((D,), dt),
+        "attn_norm": jnp.ones((L, D), dt),
+        "mlp_norm": jnp.ones((L, D), dt),
+        "wq": norm(La, (D, H, Dh), s),
+        "wk": norm(La, (D, Hk, Dh), s),
+        "wv": norm(La, (D, Hk, Dh), s),
+        "wo": norm(La, (H, Dh, D), (H * Dh) ** -0.5),
+        "wi": norm(L, (D, 2 * F), s),
+        "wo_mlp": norm(L, (F, D), F ** -0.5),
+        "mamba_in": norm(Lm, (D, 2 * Di), s),
+        "mamba_conv_w": norm(Lm, (K, Di), K ** -0.5),
+        "mamba_x": norm(Lm, (Di, R + 2 * N), Di ** -0.5),
+        "mamba_dt_norm": jnp.ones((Lm, R), dt),
+        "mamba_b_norm": jnp.ones((Lm, N), dt),
+        "mamba_c_norm": jnp.ones((Lm, N), dt),
+        "mamba_dt": norm(Lm, (R, Di), R ** -0.5),
+        "mamba_d": jnp.ones((Lm, Di), dt),
+        "mamba_out": norm(Lm, (Di, D), Di ** -0.5),
+    }
+    if cfg.mamba_conv_bias:
+        p["mamba_conv_b"] = norm(Lm, (Di,), 0.2)
+    step = jnp.exp(jax.random.uniform(
+        next(keys), (Lm, Di), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    p["mamba_dt_bias"] = (step + jnp.log(-jnp.expm1(-step))).astype(dt)
+    p["mamba_a_log"] = jnp.broadcast_to(
+        jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[None, :, None],
+        (Lm, N, Di)).astype(dt)
+    if not cfg.tie_embeddings:
+        p["unembed"] = norm(1, (D, cfg.vocab_size), s)[0]
+    return p
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
     """Random-init params (scaled normal); shapes match param_logical_axes."""
+    if cfg.has_recurrent:
+        return _init_hybrid_params(cfg, key)
     dt = cfg.jax_dtype
     L, D, H, Hk, Dh = cfg.num_layers, cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     F = cfg.intermediate_size
@@ -389,10 +479,28 @@ def init_cache(cfg: ModelConfig, num_pages: int, page_size: int,
         assert cfg.kv_cache_heads % pack == 0
     rows = 1 if cfg.is_mla else 2 * (cfg.kv_cache_heads // pack)
     return jnp.zeros(
-        (cfg.num_layers * num_pages, page_size, rows,
+        (cfg.num_attn_layers * num_pages, page_size, rows,
          padded_head_dim(cfg.kv_cache_head_dim)),
         dtype if dtype is not None else cfg.jax_dtype,
     )
+
+
+def init_state(cfg: ModelConfig, seats: int) -> dict[str, jax.Array]:
+    """The recurrent-state pool of a model with mamba layers, a slot a seat:
+    ``ssm`` [Lm, seats + 1, N, Di] in ``cfg.mamba_state_dtype`` (d_state on
+    the sublanes, d_inner on the lanes: what ops/selective_scan reads) and
+    ``conv`` [Lm, seats + 1, K - 1, Di] in the model's dtype (the last K - 1
+    pre-conv rows). Slot ``seats`` is scratch: a unified step's padding rows
+    are mapped to it, so that every row of a call has a slot to name and none
+    of them a seat's. ``forward_core`` takes the pool in the ``cache``
+    argument, as ``{"kv": pool, **state}``."""
+    shape = (cfg.num_mamba_layers, seats + 1)
+    return {
+        "ssm": jnp.zeros(shape + (cfg.mamba_d_state, cfg.mamba_d_inner),
+                         jnp.dtype(cfg.mamba_state_dtype)),
+        "conv": jnp.zeros(shape + (cfg.mamba_d_conv - 1, cfg.mamba_d_inner),
+                          cfg.jax_dtype),
+    }
 
 
 # float8_e4m3fn has no inf: values past ±448 convert to nan, so fp8 cache
@@ -573,8 +681,231 @@ def window_view(page_tables: jax.Array, kv_lens: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# Mamba mixer over the recurrent-state pool
+# ---------------------------------------------------------------------------
+
+
+def _inner_norms(cfg: ModelConfig, dbc: jax.Array, w: jax.Array) -> tuple:
+    """Jamba's three inner RMSNorms at once on ``dbc`` [N, R + 2 Nst]
+    float32 (dt, B, C side by side; ``w`` their weights side by side):
+    returns (dt [N, R], B [N, Nst], C [N, Nst]).
+
+    The three sums of squares are one matrix product with a 0/1 matrix of
+    three columns (padded to a lane tile), at the highest precision: the
+    matrix unit adds a row's terms in one order whatever the number of rows.
+    A ``reduce`` is lowered as the array's shape suggests, and on the chip
+    the sums over a [64, 160] and a [256, 160] array parted in the last bit:
+    a decode row's norm then depended on whether the unified step or the
+    fused decode call brought it, the cast to the next product's type turned
+    the bit into a step of that type now and then, and the recurrent state
+    carried it on (greedy tokens served alone and in a batch parted). Written
+    as explicit adds in a fixed order the sums were the same too, as some
+    twenty device operations a layer where this is three."""
+    R, Nst = cfg.mamba_dt_rank, cfg.mamba_d_state
+    sizes = (R, Nst, Nst)
+    seg = np.zeros((R + 2 * Nst, 128), np.float32)
+    at = 0
+    for c, n in enumerate(sizes):
+        seg[at:at + n, c] = 1.0 / n
+        at += n
+    mean_sq = jnp.dot(dbc * dbc, jnp.asarray(seg),
+                      precision=lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)  # [N, 128]
+    r = lax.rsqrt(mean_sq[:, :3] + cfg.rms_eps)
+    scale = jnp.concatenate(
+        [jnp.broadcast_to(r[:, c:c + 1], (dbc.shape[0], n))
+         for c, n in enumerate(sizes)], axis=1)
+    out = dbc * scale * w
+    return out[:, :R], out[:, R:R + Nst], out[:, R + Nst:]
+
+
+def mamba_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
+                ssm: jax.Array, base, row_slots, seq_slots: jax.Array,
+                cu_q_lens: jax.Array, live: jax.Array, fresh: jax.Array,
+                scan_impl, mm):
+    """One mamba layer's mixer on the normed rows ``h`` [N, D] of a flat mixed
+    batch; returns (out [N, D], conv pool, ssm pool).
+
+    ``conv`` [Lm * S, K - 1, Di] and ``ssm`` [Lm * S, Nst, Di] are the pools
+    with the layer folded into the slot axis; ``base`` is this layer's first
+    row of them (traced). ``row_slots`` [B] names each batch row's slot, or is
+    None where row b is seat b and brings one token (the fused decode call:
+    the window is then a slice and the conv elementwise, no gather). Rows
+    that are not ``live`` leave both pools as they are; a ``fresh`` row (first
+    position 0) starts from a zero window and a zero state.
+
+    Matrix products run in the model's dtype with float32 results; the conv,
+    the three inner norms, softplus, the recurrence (``scan_impl``,
+    ops/selective_scan) and the gate are float32. ``mm(key, pattern, x)`` is
+    the layer's int8-aware weight product. ``lp`` holds the layer's matrices
+    and ``mamba_a_log`` as stored, and its vectors as ``_hybrid_stack`` packs
+    them: ``mamba_vec`` and ``mamba_norms``."""
+    N = h.shape[0]
+    B = live.shape[0]
+    Di, K, R, Nst = (cfg.mamba_d_inner, cfg.mamba_d_conv, cfg.mamba_dt_rank,
+                     cfg.mamba_d_state)
+    dt_ = cfg.jax_dtype
+    xz = mm("mamba_in", "nd,de->ne", h)
+    xr, z = xz[:, :Di], xz[:, Di:]  # pre-conv rows, gate (model dtype)
+    vec = lp["mamba_vec"]  # [K + 3, Di] float32: conv taps, conv bias, dt bias, D
+    w_c = vec[:K]
+    if row_slots is None:
+        assert N == B, "one token a seat where no row -> slot map is given"
+        win = lax.dynamic_slice_in_dim(conv, base, B, axis=0)  # [B, K-1, Di]
+        use = jnp.where(fresh[:, None, None], jnp.zeros_like(win), win)
+        taps = [use[:, k] for k in range(K - 1)] + [xr]
+        new_win = jnp.concatenate([use[:, 1:], xr[:, None]], axis=1)
+        conv = lax.dynamic_update_slice_in_dim(
+            conv, jnp.where(live[:, None, None], new_win, win), base, axis=0)
+        slots = base + jnp.arange(B, dtype=jnp.int32)
+    else:
+        slots = base + row_slots
+        win = conv[slots]  # [B, K-1, Di]
+        win = jnp.where(fresh[:, None, None], jnp.zeros_like(win), win)
+        b = jnp.clip(seq_slots, 0, B - 1)
+        off = jnp.arange(N, dtype=jnp.int32) - cu_q_lens[b]  # row offset
+        win_tok = win[b]  # [N, K-1, Di]
+        taps = []
+        for j in range(K - 1, 0, -1):  # the row j tokens back
+            tap = jnp.pad(xr, ((j, 0), (0, 0)))[:N]  # xr[i - j], in the chunk
+            for o in range(j):  # before the chunk: window row K-1-j+o
+                tap = jnp.where((off == o)[:, None], win_tok[:, K - 1 - j + o],
+                                tap)
+            taps.append(tap)
+        taps.append(xr)
+        # the window after the call: the last K-1 rows of [window ; chunk]
+        n = cu_q_lens[1:] - cu_q_lens[:-1]  # [B]
+        e = n[:, None] - (K - 1) + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+        from_x = xr[jnp.clip(cu_q_lens[:-1, None] + e, 0, N - 1)]
+        from_win = jnp.take_along_axis(
+            win, jnp.clip(e + (K - 1), 0, K - 2)[:, :, None], axis=1)
+        new_win = jnp.where((e >= 0)[:, :, None], from_x, from_win)
+        conv = conv.at[jnp.where(live, slots, conv.shape[0])].set(
+            new_win, mode="drop")
+    acc = vec[K]
+    for k in range(K):
+        acc = acc + w_c[k] * taps[k].astype(jnp.float32)
+    x = jax.nn.silu(acc)  # [N, Di] float32
+    dbc = mm("mamba_x", "ne,er->nr", x.astype(dt_), jnp.float32)
+    dlow, Bm, Cm = _inner_norms(cfg, dbc, lp["mamba_norms"])
+    delta = jax.nn.softplus(
+        mm("mamba_dt", "nr,re->ne", dlow.astype(dt_), jnp.float32)
+        + vec[K + 1])
+    A = -jnp.exp(lp["mamba_a_log"].astype(jnp.float32))  # [Nst, Di]
+    y, ssm = scan_impl(x, delta, Bm, Cm, A, ssm, slots, cu_q_lens, live, fresh)
+    y = y + vec[K + 2] * x
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    return mm("mamba_out", "ne,ed->nd", y.astype(dt_)), conv, ssm
+
+
+# ---------------------------------------------------------------------------
 # Full forward over the scanned layer stack
 # ---------------------------------------------------------------------------
+
+
+def _weight_mm(lp: dict, key: str, pattern: str, xin: jax.Array, out=None):
+    """``forward_core``'s int8-aware weight product for a layer's leaves
+    ``lp``, with the result's type (``preferred_element_type``)."""
+    if key in lp:
+        return jnp.einsum(pattern, xin, lp[key], preferred_element_type=out)
+    y = jnp.einsum(pattern, xin, lp[key + "_q"].astype(xin.dtype),
+                   preferred_element_type=out)
+    return y * lp[key + "_scale"].astype(y.dtype)
+
+
+def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
+                  positions, seq_slots, cu_q_lens, state_slots, scan_impl):
+    """The layer stack of a model whose layers differ in kind and in
+    parameter shapes: a scan over the periods of ``cfg.layer_kinds`` whose
+    body runs the period's runs of one kind (7 mamba, 1 attention, 6 mamba),
+    a run of several layers as an inner scan, so that no layer is traced more
+    than once a run. A layer takes its leaves from the whole stacks by index
+    (layer ``l`` of the norms and the MLP, ordinal ``m`` or ``a`` of its
+    kind's own leaves): the stacks stay loop invariants and the index folds
+    into the product that reads them, where a period's slice handed to an
+    inner loop would be copied out. ``attention_layer`` is ``forward_core``'s
+    own layer, given the attention ordinal as its pool offset.
+
+    Returns (x, flat KV pool, state)."""
+    from llmd_tpu.ops.selective_scan import row_flags, selective_scan_xla
+
+    assert cu_q_lens is not None, "a model with recurrent layers needs cu_q_lens"
+    scan_impl = scan_impl or selective_scan_xla
+    Lm, S1 = state["ssm"].shape[:2]
+    ssm = state["ssm"].reshape((Lm * S1,) + state["ssm"].shape[2:])
+    conv = state["conv"].reshape((Lm * S1,) + state["conv"].shape[2:])
+    live, fresh = row_flags(positions, cu_q_lens)
+
+    def present(*keys):
+        return tuple(v for k in keys for v in (k, k + "_q", k + "_scale")
+                     if v in params)
+
+    # a mamba layer's vectors, float32 and side by side: a layer then slices
+    # two arrays where it would slice and convert eight (each a device
+    # operation of its own a layer a step, which a trace pays for by the event)
+    f32 = lambda k: params[k].astype(jnp.float32)  # noqa: E731
+    bias = (f32("mamba_conv_b") if cfg.mamba_conv_bias
+            else jnp.zeros_like(f32("mamba_d")))
+    params = dict(params, mamba_vec=jnp.concatenate(
+        [f32("mamba_conv_w")] + [v[:, None] for v in (
+            bias, f32("mamba_dt_bias"), f32("mamba_d"))], axis=1),
+        mamba_norms=jnp.concatenate(
+            [f32("mamba_dt_norm"), f32("mamba_b_norm"), f32("mamba_c_norm")],
+            axis=-1))
+    shared = present("attn_norm", "mlp_norm", "wi", "wo_mlp")
+    own = {"mamba": present("mamba_in", "mamba_x", "mamba_dt", "mamba_a_log",
+                            "mamba_out", "mamba_vec", "mamba_norms"),
+           "attention": present("wq", "wk", "wv", "wo")}
+
+    def leaves(keys, i):
+        return {k: lax.dynamic_index_in_dim(params[k], i, 0, keepdims=False)
+                for k in keys}
+
+    def one_layer(kind, carry, l, o):
+        """Layer ``l``, the ``o``-th of its kind."""
+        x, flat_cache, conv, ssm = carry
+        lp = {**leaves(shared, l), **leaves(own[kind], o)}
+        if kind == "attention":
+            (x, flat_cache), _ = attention_layer(
+                (x, flat_cache), lp, o, cfg.attn_window_pattern[0],
+                cfg.rope_pattern[0])
+            return x, flat_cache, conv, ssm
+
+        def mm(key, pattern, xin, out=None):
+            return _weight_mm(lp, key, pattern, xin, out)
+
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        o_mix, conv, ssm = mamba_mixer(
+            cfg, lp, h, conv, ssm, o * S1, state_slots, seq_slots, cu_q_lens,
+            live, fresh, scan_impl, mm)
+        x = x + o_mix
+        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        y = swiglu(h, None, None, mm=mm) if "wi_q" in lp else swiglu(
+            h, lp["wi"], lp["wo_mlp"])
+        return x + y, flat_cache, conv, ssm
+
+    period = len(cfg.layer_kinds)
+    count = {k: cfg.layer_kinds.count(k) for k in own}
+
+    def one_period(carry, i):
+        seen = dict.fromkeys(own, 0)
+        for kind, j0, n in cfg.layer_runs:
+            l0, o0 = i * period + j0, i * count[kind] + seen[kind]
+            if n == 1:
+                carry = one_layer(kind, carry, l0, o0)
+            else:
+                carry, _ = lax.scan(
+                    lambda c, j, kind=kind, l0=l0, o0=o0: (
+                        one_layer(kind, c, l0 + j, o0 + j), None),
+                    carry, jnp.arange(n, dtype=jnp.int32))
+            seen[kind] += n
+        return carry, None
+
+    (x, flat_cache, conv, ssm), _ = lax.scan(
+        one_period, (x, flat_cache, conv, ssm),
+        jnp.arange(cfg.num_layers // period, dtype=jnp.int32))
+    return x, flat_cache, {"ssm": ssm.reshape(state["ssm"].shape),
+                           "conv": conv.reshape(state["conv"].shape)}
 
 
 def forward_core(
@@ -595,6 +926,8 @@ def forward_core(
     mm_embeds: Optional[jax.Array] = None,  # [N, D] encode-stage rows, row-aligned
     mm_mask: Optional[jax.Array] = None,  # [N] True where tokens[i] is a placeholder
     moe_dispatch_impl=None,
+    state_slots: Optional[jax.Array] = None,  # [B] row -> state slot (recurrent models)
+    scan_impl=None,  # ops/selective_scan impl (recurrent models)
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Run a flat mixed batch through the model, writing K/V into the paged cache.
 
@@ -613,11 +946,25 @@ def forward_core(
     EPLB mode: when ``params`` carries ``eplb_replica_slots``/``eplb_replica_counts``
     (engine-injected, see engine's rebalance path), ``moe_wi``/``moe_wo`` are physical
     slot weights and dispatch spreads tokens over replicas.
+
+    A model with recurrent layers (``cfg.has_recurrent``) takes its
+    recurrent-state pool in the same argument, ``cache = {"kv": pool,
+    **init_state(...)}``, and returns it so (both donated by the engine's
+    programs); the KV pool then folds the attention layers only, by their
+    ordinal. ``state_slots`` [B] names each batch row's state slot (rows in
+    plan order; padding rows the scratch slot); None means row b is seat b
+    with one token, the fused decode call. A row whose first position is -1
+    (padding, idle, frozen) leaves its slot untouched, one whose first
+    position is 0 starts from zero state. ``cu_q_lens`` is required.
     """
+    state = None
+    if cfg.has_recurrent:
+        state = {k: v for k, v in cache.items() if k != "kv"}
+        cache = cache["kv"]
     N = tokens.shape[0]
     Ptot, ps, HkC, Dhp = cache.shape
     Dh = cfg.head_dim
-    P = Ptot // cfg.num_layers  # pages per layer
+    P = Ptot // cfg.num_attn_layers  # pages per layer with pages
     B = page_tables.shape[0]
     if attn_impl is None:
         attn_impl = ragged_paged_attention_xla
@@ -829,6 +1176,15 @@ def forward_core(
         x = x + y
         return (x, flat_cache), (cnt, drop)
 
+    if state is not None:
+        x, flat_cache, state = _hybrid_stack(
+            cfg, params, layer, x, cache.reshape(Ptot * ps, HkC, Dhp), state,
+            positions, seq_slots, cu_q_lens, state_slots, scan_impl)
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        return (x, {"kv": flat_cache.reshape(Ptot, ps, HkC, Dhp), **state},
+                jnp.zeros((cfg.num_layers, 0), jnp.int32),
+                jnp.zeros((), jnp.int32))
+
     # One trace for any depth: the scan runs over periods of the attention
     # pattern, and a period's layers are written out in the body so that each
     # one's window and RoPE flag are static. Layer l = i * period + j keeps its
@@ -897,6 +1253,11 @@ def forward(
     no attn_impl override is accepted (engine callers use forward_core directly).
     Returns full logits [B, T, vocab] like the classic contract.
     """
+    if cfg.has_recurrent:
+        raise ValueError(
+            "forward(): a model with recurrent layers runs through "
+            "forward_core, which takes the rows' lengths (cu_q_lens) and "
+            "their state slots")
     B, T = tokens.shape
     seq_slots = jnp.repeat(jnp.arange(B, dtype=jnp.int32), T)
     lora_tok = jnp.repeat(lora_indices, T) if lora_indices is not None else None

@@ -538,6 +538,32 @@ class EngineMetrics:
             "(models.transformer.window_view). A model without window "
             "layers feeds full only",
             labelnames=("program", "layers"))
+        self.ssm_scan_tokens = reg.counter(
+            "llmd_tpu:ssm_scan_tokens_total",
+            "Tokens one mamba layer's selective scan is given, per dispatch, "
+            "from the lengths the step packed: rows=chunk the tokens of "
+            "prefill chunks, rows=decode those of decode rows (a fused decode "
+            "call: k times its live rows). A model without recurrent layers "
+            "feeds neither",
+            labelnames=("program", "rows"))
+        self.ssm_state_resets = reg.counter(
+            "llmd_tpu:ssm_state_resets_total",
+            "Rows dispatched from position 0, which start from a zero "
+            "recurrent state: cause=admit a sequence's first prefill chunk, "
+            "cause=recompute the first chunk of one that was preempted",
+            labelnames=("cause",))
+        self.ssm_state_slots = reg.gauge(
+            "llmd_tpu:ssm_state_slots_in_use",
+            "Recurrent-state slots whose seat holds a sequence (a seat owns "
+            "its slot; 0 for a model without recurrent layers)")
+        self.ssm_backend_info = reg.gauge(
+            "llmd_tpu:engine_ssm_backend",
+            "Resolved selective-scan implementation, the type the recurrent "
+            "state is held in, and that prefix reuse is off for a model with "
+            "recurrent layers (a cached page holds no layer's state at its "
+            "boundary) (info-style: value 1 on the selected label set; absent "
+            "for a model without recurrent layers)",
+            labelnames=("impl", "state_dtype", "prefix_reuse"))
         self.program_rows = reg.counter(
             "llmd_tpu:program_rows_total",
             "Sequences (rows) packed into each dispatch",

@@ -73,6 +73,25 @@ module owns the serving-stack integration:
   Qwen's 12/2 at its own short contexts did not (PR 25: flat): an even ratio
   keeps its pair until a sweep of its cell says otherwise (PERF.md section 7).
 
+  A fourth, 20/1 heads of 128 (Jamba2-3B's two attention layers of 28: twenty
+  query heads on one KV head, 4 KB a page; `--cells jamba --bkv 16,32,64 --bq
+  4,8,16,32`, chip, PR 34; 58 rows decoding, 47.8k tokens given to the decode
+  call and 48.4k to the unified call, 30 us at the byte bound; `.` = the
+  rule's pair):
+
+      us a call     N=64 decode              N=256 unified
+      bkv / bq      4     8     16    32      4     8     16    32
+      16           177   190   273   459     251   229   294   474
+      32           150  .160   221   368     189   178  .237   373
+      64           163   179   230   342     214   212   256   351
+
+  An even ratio, and it keeps the pair it has: (32, 4) would take 10 us off a
+  decode call; the unified step's (32, 16) reads 237 where (32, 8) reads 178,
+  59 us a call on two layers of 28, 0.12 ms of a step of 20 ms and
+  more, not worth a fourth branch of the rule. One KV head's pages are 4 KB
+  and a row's context 830 tokens, so every pair reads at a fifth of the byte
+  bound or less: the calls are the fixed costs of their blocks.
+
   **One bkv an engine.** The kernel's online softmax blocks a row's keys from
   its page table's first entry, so two programs, or a cold and a cached
   request, that block differently part at near ties (PR 32 read
@@ -215,6 +234,46 @@ def window_align_pages(q_shape, cache_shape, pages_per_seq: int) -> int:
     return call_geometry(q_shape, cache_shape, pages_per_seq)[0]
 
 
+def split_rows_at_kv_blocks(page_tables, kv_lens, cu_q_lens, num_seqs,
+                            block_tokens: int, num_tokens: int):
+    """A call's rows cut where their queries cross a KV block's end, so that
+    no query is handed a KV block past its own: ``(page_tables, kv_lens,
+    cu_q_lens, num_seqs)`` of ``B * parts`` rows, ``parts`` the most blocks
+    ``num_tokens`` queries of one row can touch.
+
+    The kernel walks every KV block of a row up to its ``kv_len`` for all the
+    row's queries and renormalises its accumulator a block (``(l * o) / l``):
+    a block that lies wholly past a query is masked out, but the
+    renormalisation re-rounds that query's result. Whether a prompt's token
+    meets such a block depends on where the chunk that brought it ends (a
+    chunk 298..552 hands token 349 the block from 512, a chunk 256..511 does
+    not), so a token's result depended on its prompt's chunking, in the last
+    bit and rarely (12/2 heads, chip, PR 34: 3 tokens of 939 by one bf16 step).
+    Cut at the block ends, a row's part ``[lo, hi)`` is a row of its own with
+    ``kv_len = hi`` over the same pages: every query sees the blocks up to its
+    own and no other, whatever the chunking. A decode row (one query) is
+    never cut."""
+    B = kv_lens.shape[0]
+    parts = (num_tokens - 1) // block_tokens + 2
+    q_len = cu_q_lens[1:] - cu_q_lens[:-1]
+    start = kv_lens - q_len  # the position of a row's first query
+    row = jnp.arange(B, dtype=jnp.int32)
+    cut = (row < num_seqs[0]) & (q_len > 0)
+    n = jnp.where(cut, (kv_lens - 1) // block_tokens - start // block_tokens + 1, 1)
+    first = jnp.cumsum(n) - n  # a row's first part among the new rows
+    j = jnp.arange(B * parts, dtype=jnp.int32)
+    owner = jnp.clip(jnp.searchsorted(first, j, side="right") - 1, 0, B - 1)
+    block = start[owner] // block_tokens + (j - first[owner])
+    lo = jnp.maximum(start[owner], block * block_tokens)
+    hi = jnp.minimum(kv_lens[owner], (block + 1) * block_tokens)
+    live = (j < first[B - 1] + n[B - 1]) & cut[owner]
+    new_q = jnp.where(live, hi - lo, 0)
+    new_cu = jnp.concatenate([cu_q_lens[:1], cu_q_lens[0] + jnp.cumsum(new_q)])
+    return (page_tables[owner], jnp.where(live, hi, kv_lens[owner]),
+            new_cu.astype(cu_q_lens.dtype),
+            (num_seqs + jnp.sum(jnp.where(cut, n - 1, 0))).astype(num_seqs.dtype))
+
+
 def paged_attention_tpu(
     q: jax.Array,  # [N, H, Dhp] flat query tokens (lane-padded)
     layer_cache: jax.Array,  # [P, ps, 2*Hk, Dhp]
@@ -230,9 +289,16 @@ def paged_attention_tpu(
     chunk_v: "jax.Array | None" = None,  # unused (ring-attn impls only)
     mesh=None,  # engine mesh: the kernel runs per device under shard_map
     sliding_window: "int | None" = None,  # static: a window layer's window
+    split_at_kv_blocks: bool = False,  # static: `split_rows_at_kv_blocks`
 ) -> jax.Array:
     """Uniform-signature adapter over the Pallas kernel (drop-in for
     models.transformer.ragged_paged_attention_xla on TPU).
+
+    ``split_at_kv_blocks`` hands the kernel each row cut at its KV blocks'
+    ends (`split_rows_at_kv_blocks`): what a model asks for whose greedy
+    tokens must not depend on a prompt's chunking to the last bit (recurrent
+    layers carry a one-step difference on; the engine sets it for them in the
+    unified step).
 
     ``sliding_window`` is the kernel's own static mask (key j visible to the
     query at position i iff i - window < j). The kernel still walks every KV
@@ -254,6 +320,11 @@ def paged_attention_tpu(
     # prefetched DMA would read out of bounds — clamp to page 0 (never attended:
     # those entries lie at/past kv_len).
     page_tables = jnp.maximum(page_tables, 0)
+    if split_at_kv_blocks:
+        assert sliding_window is None, "a window layer's rows are not cut"
+        page_tables, kv_lens, cu_q_lens, num_seqs = split_rows_at_kv_blocks(
+            page_tables, kv_lens, cu_q_lens, num_seqs,
+            bkv * layer_cache.shape[1], q.shape[0])
     extra = {}
     if layer_cache.dtype == jnp.float8_e4m3fn:
         # fp8 pages: unit scales make the kernel dequantize each KV block in

@@ -105,6 +105,18 @@ def make_hf_checkpoint(
             **common, head_dim=head_dim or hidden_size // num_heads
         )
         model = transformers.Qwen3ForCausalLM(cfg)
+    elif family == "jamba":
+        # Jamba's layer pattern at toy depth: (mamba, attention, mamba,
+        # mamba) a period, one dense MLP a layer, the plain-torch mixer
+        common.pop("rope_theta")
+        cfg = transformers.JambaConfig(
+            **common, num_experts=1, num_experts_per_tok=1,
+            attn_layer_period=4, attn_layer_offset=1, use_mamba_kernels=False,
+            mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
+            mamba_dt_rank=8, mamba_conv_bias=True, mamba_proj_bias=False,
+            sliding_window=None, pad_token_id=0, bos_token_id=1,
+            eos_token_id=2)
+        model = transformers.JambaForCausalLM(cfg)
     else:
         raise ValueError(f"unknown family {family!r}")
     model = model.to(getattr(torch, torch_dtype))

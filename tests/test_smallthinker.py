@@ -410,15 +410,21 @@ def test_model_config_refuses_a_pattern_that_does_not_divide_the_depth():
 
 # --------------------------- (g) the accepted configurations' step programs
 
-# sha256 of forward_core's StableHLO at the parent commit (b860fb4, the tree
-# before ISSUE 32), lowered as below: the scan over periods, the window view,
-# the early router and the activation's name leave a model with one kind of
-# layer the program it had, operation for operation.
+# sha256 of forward_core's StableHLO, lowered as below. The two dense
+# configurations' at b860fb4 (the tree before ISSUE 32): the scan over periods,
+# the window view, the early router and the activation's name left a model
+# with one kind of layer the program it had, operation for operation. All
+# three at f0f0e05 (the tree before ISSUE 34, where the dense two still read
+# the same): layer kinds of unequal parameter shapes, the recurrent-state
+# pool and the per-kind stacked leaves leave a model without recurrent layers
+# the program it had (SmallThinker through the sorted dispatch, as served).
 PARENT_STABLEHLO = {
     "qwen2.5-1.5b":
         "013a1476f5f5b99c3751ef04ac296a74c38b91a40b4de08b9dc62eacaeeb240c",
     "mistral-7b-v0.3":
         "d535f1c25e0d0d9aa4b104e5d944b5bf609b824cad6cb9392b4549e64ceada3b",
+    "smallthinker-21b-a3b":
+        "2e869e7d691ce7419c11a82a57c83b30ff71cc2dcdee0eca067adf5eb9e44e49",
 }
 
 
@@ -426,7 +432,9 @@ PARENT_STABLEHLO = {
 def test_accepted_configurations_lower_to_the_stablehlo_they_had(name):
     with open(os.path.join(ROOT, "perfbench", "configs", name + ".json")) as f:
         conf = json.load(f)
-    cfg = dense_gqa.model_config(conf)
+    family = {"dense_gqa": dense_gqa, "moe_swa_gqa": moe_swa_gqa}[
+        conf["reference"]]
+    cfg = family.model_config(conf)
 
     def make(key):
         p = init_params(cfg, key)
@@ -436,13 +444,14 @@ def test_accepted_configurations_lower_to_the_stablehlo_they_had(name):
     params = jax.eval_shape(make, jax.random.key(0))
     cache = jax.eval_shape(lambda: init_cache(cfg, 64, 16))
     N, B, maxp = 256, 64, 32
+    kw = {"moe_dispatch_impl": SORTED} if cfg.is_moe else {}
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
     def step(params, cache, tokens, positions, seq_slots, pt, lens, cu, ns):
         return forward_core(cfg, params, cache, tokens, positions, seq_slots,
-                            pt, lens, cu_q_lens=cu, num_seqs=ns)
+                            pt, lens, cu_q_lens=cu, num_seqs=ns, **kw)
 
     text = jax.jit(step).lower(params, cache, i32(N), i32(N), i32(N),
                                i32(B, maxp), i32(B), i32(B + 1),

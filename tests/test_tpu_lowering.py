@@ -95,27 +95,32 @@ def one_chip():
 # (query heads, KV heads, pages a sequence) of the benchmark's configurations:
 # heads of 128, 16-token pages, model lengths 4,096, 8,192 and 16,384
 CELL_LAYOUTS = {"qwen2.5-1.5b": (12, 2, 256), "mistral-7b-v0.3": (32, 8, 512),
-                "smallthinker": (28, 4, 1024)}
+                "smallthinker": (28, 4, 1024), "jamba2-3b": (20, 1, 192)}
 # the geometry the rule gives each at N = 64 (fused decode) and 256 (unified)
 CELL_GEOMETRY = {"qwen2.5-1.5b": {64: (32, 8), 256: (32, 16)},
                  "mistral-7b-v0.3": {64: (32, 8), 256: (32, 16)},
-                 "smallthinker": {64: (64, 4), 256: (64, 8)}}
+                 "smallthinker": {64: (64, 4), 256: (64, 8)},
+                 "jamba2-3b": {64: (32, 8), 256: (32, 16)}}
 
 
 @pytest.mark.parametrize("n", [64, 256])  # fused decode seats; unified tokens
 @pytest.mark.parametrize("config,window", [
-    (c, 0) for c in sorted(CELL_LAYOUTS)] + [("smallthinker", 4096)])
+    (c, 0) for c in sorted(CELL_LAYOUTS)] + [("smallthinker", 4096),
+                                             ("jamba2-3b", -1)])
 def test_cell_shapes_compile_for_v5e_with_the_rules_geometry(one_chip, config,
                                                              window, n):
     """The geometry `pick_block_sizes` gives the cells' step programs goes
     through Mosaic and the TPU compiler here: one it refuses (VMEM, tiling, an
     unaligned slice) fails this test and not the cell. A window layer's call
     (the kernel's mask and page tables shifted by whole KV blocks) beside the
-    full layer's."""
+    full layer's, and (window -1) the call whose rows are cut at their KV
+    blocks' ends, which a model with recurrent layers makes: twice the rows
+    in the kernel's scalar memory."""
     from llmd_tpu.ops.paged_attention import call_geometry
 
     heads, kv_heads, maxp = CELL_LAYOUTS[config]
-    kw = {"sliding_window": window} if window else {}
+    kw = ({"split_at_kv_blocks": True} if window < 0 else
+          {"sliding_window": window} if window else {})
 
     def fn(q, cache, pt, pos, slots, lens, cu, ns):
         return paged_attention_tpu(q, cache, pt, pos, slots, lens,
@@ -128,6 +133,48 @@ def test_cell_shapes_compile_for_v5e_with_the_rules_geometry(one_chip, config,
             for a in _attn_args(q_shape, cache_shape, 64, maxp)]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "ragged_paged_attention_kernel" in text
+
+
+@pytest.mark.parametrize("n,state", [(64, jnp.float32), (256, jnp.float32),
+                                     (256, jnp.bfloat16)],
+                         ids=["decode", "unified", "unified_bf16_state"])
+def test_selective_scan_compiles_for_v5e_at_the_cells_shapes(one_chip, n,
+                                                             state):
+    """The Mamba layers' kernel at jamba2-3b's widths (d_inner 5120, d_state
+    16, 26 layers of 65 slots folded into the pool) and both step programs'
+    token budgets goes through Mosaic and the TPU compiler here: the dynamic
+    single-row loads and stores, the [N, 1] columns of B and C and the VMEM
+    the resident blocks take are what interpret mode cannot refuse."""
+    from llmd_tpu.ops.selective_scan import channel_block, selective_scan_pallas
+
+    di, ns, seats = 5120, 16, 64
+    assert (channel_block(64, di), channel_block(256, di)) == (5120, 1280)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    args = (spec((n, di), f32), spec((n, di), f32), spec((n, ns), f32),
+            spec((n, ns), f32), spec((ns, di), f32),
+            spec((26 * (seats + 1), ns, di), state), spec((seats,), jnp.int32),
+            spec((seats + 1,), jnp.int32), spec((seats,), jnp.bool_),
+            spec((seats,), jnp.bool_))
+    compiled = jax.jit(selective_scan_pallas, donate_argnums=(5,)).lower(
+        *args).compile()
+    assert "selective_scan" in compiled.as_text()
+    # in place: the pool is neither copied nor a temporary of its own size
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+def test_selective_scan_lowers_for_tpu():
+    from llmd_tpu.ops.selective_scan import selective_scan_pallas
+
+    f32 = jnp.float32
+    _lower_for_tpu(
+        selective_scan_pallas, _spec((32, 256), f32), _spec((32, 256), f32),
+        _spec((32, 16), f32), _spec((32, 16), f32), _spec((16, 256), f32),
+        _spec((10, 16, 256), f32), _spec((4,), jnp.int32),
+        _spec((5,), jnp.int32), _spec((4,), jnp.bool_), _spec((4,), jnp.bool_))
 
 
 @pytest.mark.parametrize("heads,kv_heads,geometry,block,window", [

@@ -46,11 +46,12 @@ CELLS = {
     "qwen": ("qwen2.5-1.5b", "offline-closed"),
     "mistral": ("mistral-7b-v0.3", "sessions-closed"),
     "smallthinker": ("smallthinker-21b-a3b", "sessions-long-closed"),
+    "jamba": ("jamba2-3b", "reasoning-closed"),
 }
 # live rows of a fused decode call and decode rows of a unified step
 # (PERF.md section 5: decode_seat_steps_total, program_rows_total)
-LIVE_DECODE = {"qwen": 48, "mistral": 32, "smallthinker": 53}
-UNIFIED_DECODE = {"qwen": 49, "mistral": 30, "smallthinker": 53}
+LIVE_DECODE = {"qwen": 48, "mistral": 32, "smallthinker": 53, "jamba": 58}
+UNIFIED_DECODE = {"qwen": 49, "mistral": 30, "smallthinker": 53, "jamba": 58}
 
 
 def _load(kind: str, name: str) -> dict:
