@@ -642,6 +642,11 @@ class LLMEngine:
                 self.cache.shape, engine_cfg.max_pages_per_seq)
         self.stats.moe_backend = self.moe_backend
         self.stats.moe_dispatch = self.moe_dispatch
+        self.moe_gemm_geometry, self._moe_gemm_plan = self._moe_gemm_geometry()
+        if model_cfg.is_moe:
+            self.metrics.moe_backend_info.labels(
+                backend=self.moe_backend, dispatch=self.moe_dispatch,
+                gemm=self.moe_gemm_geometry).set(1)
         # kernel-vs-fallback visibility without scraping logs: an info-style
         # gauge keyed by the resolved backend and its block geometry (value 1)
         self.metrics.attn_backend_info.labels(
@@ -1087,6 +1092,41 @@ class LLMEngine:
         self.moe_backend = "pallas_grouped_gemm"
         return make_moe_matmul(interpret=self._pallas_interpret)
 
+    def _moe_gemm_geometry(self) -> tuple[str, Optional[Callable]]:
+        """(label, plan) of the ragged grouped GEMM in the unified step, both
+        functions of static shapes. The label is the kernel's grid, as
+        ``<order>x<bf of moe_wi>x<bf of moe_wo>``; ``none`` where the Pallas
+        kernel does not serve. The plan is `bank_fetch_plan` at the block rows
+        and blocks a layer of a unified step's sorted dispatch, which
+        `_moe_record` books ``moe_gemm_blocks_total`` with; None where the
+        step's [L, E] counts do not say what the plan held (a mesh's shards,
+        EPLB's replica slots, DBO's halves, the einsum dispatch)."""
+        from llmd_tpu.ops.grouped_gemm import (RGG_ORDER, bank_fetch_plan,
+                                               pick_bank_tile)
+        from llmd_tpu.ops.moe_dispatch import pick_block_size, plan_blocks
+
+        cfg = self.model_cfg
+        if self.moe_dispatch != "sorted":
+            return "none", None
+        pallas = self.moe_backend == "pallas_grouped_gemm"
+        copies = self.cfg.batched_tokens * cfg.moe_top_k
+        bc = pick_block_size(copies, cfg.moe_num_experts, pallas)
+        plan = None
+        if self.mesh is None and self._eplb is None and not cfg.moe_dbo:
+            plan = functools.partial(
+                bank_fetch_plan, bc=bc,
+                nb=plan_blocks(copies, cfg.moe_num_experts, bc))
+        if not pallas:
+            return "none", plan
+        item = jnp.dtype(cfg.jax_dtype).itemsize
+        label = "{}x{}x{}".format(
+            RGG_ORDER,
+            pick_bank_tile(cfg.hidden_size, 2 * cfg.moe_intermediate_size,
+                           bc, item),
+            pick_bank_tile(cfg.moe_intermediate_size, cfg.hidden_size, bc,
+                           item))
+        return label, plan
+
     def _select_moe_dispatch(self):
         """Pick the MoE routing-dispatch path (orthogonal to the expert-GEMM
         backend above): token-sorted drop-free (ops/moe_dispatch) vs the
@@ -1382,7 +1422,7 @@ class LLMEngine:
                     f"{name}: not supported for a model with recurrent "
                     f"layers ({reason})")
 
-    def _moe_record(self, drop, cnt) -> None:
+    def _moe_record(self, drop, cnt, gemm_plan=None) -> None:
         """What a step's mixture layers report. ``drop``: every routed copy
         the legacy einsum path dropped past capacity C (the sorted path
         returns a structural 0 — moe_check asserts the scrape stays 0).
@@ -1390,16 +1430,24 @@ class LLMEngine:
         expert over the mean, averaged over layers, is the step's
         ``moe_expert_load_max_over_mean``. Called where the step's outputs
         are already host-synced (or one call behind on the pipelined decode
-        path), so the two small reads add no device sync of their own."""
+        path), so the two small reads add no device sync of their own.
+        ``gemm_plan``: `bank_fetch_plan` at the geometry those counts were
+        laid out in, where one step's counts say it (a unified step's:
+        `_moe_gemm_geometry`), for ``moe_gemm_blocks_total``."""
         if not self.model_cfg.is_moe:
             return
         n = int(np.asarray(drop))
         self.stats.moe_dropped_tokens += n
         self.metrics.moe_dropped_tokens.labels(
             path=self.stats.moe_dispatch or "einsum").inc(n)
-        ratio = expert_load_max_over_mean(np.asarray(cnt))
+        cnt = np.asarray(cnt)
+        ratio = expert_load_max_over_mean(cnt)
         if ratio is not None:
             self.metrics.moe_expert_load.set(ratio)
+        if gemm_plan is not None and ratio is not None:
+            for outcome, blocks in zip(("fetch", "reuse", "padding"),
+                                       gemm_plan(cnt)):
+                self.metrics.moe_gemm_blocks.labels(outcome=outcome).inc(blocks)
 
     def _eplb_tick(self) -> None:
         # Count only steps that routed tokens — idle wave steps (DP lockstep with
@@ -3415,7 +3463,8 @@ class LLMEngine:
         if "cnt" in rec:
             self._eplb_record(rec["cnt"])
         if "moe_drop" in rec:
-            self._moe_record(rec["moe_drop"], rec["moe_cnt"])
+            self._moe_record(rec["moe_drop"], rec["moe_cnt"],
+                             gemm_plan=self._moe_gemm_plan)
         self.programs.record_complete(rec["prog"])
         if rec["sampled"] is None:
             parts.to("apply")

@@ -214,6 +214,8 @@ def main() -> None:
               f"[attn={eng.attn_backend}, moe={eng.moe_backend}, "
               f"moe_dispatch={eng.moe_dispatch}] "
               f"[attn_geometry {eng.attn_geometry}]"
+              + (f" [moe_gemm {eng.moe_gemm_geometry}]"
+                 if eng.model_cfg.is_moe else "")
               + (f" [ssm={ssm}, state={eng.model_cfg.mamba_state_dtype}, "
                  "prefix_reuse=off]" if ssm else ""), flush=True)
         await _serve_until_fatal(server.async_engine, server.stop)

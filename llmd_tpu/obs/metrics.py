@@ -813,6 +813,28 @@ class EngineMetrics:
             "routed copies over the mean expert's, averaged over the layers "
             "(1.0 = every expert the same load; moe_ep_load_imbalance is per "
             "EP rank and says nothing on one chip)")
+        self.moe_gemm_blocks = reg.counter(
+            "llmd_tpu:moe_gemm_blocks_total",
+            "Blocks of the experts' ragged grouped GEMM calls by what they "
+            "cost in bank traffic, summed over the layers of a unified step: "
+            "fetch (the first block of an expert's run: its bank tile comes "
+            "in), reuse (a further block of the same expert: the tile is "
+            "resident), padding (no rows: no fetch and no product). Booked "
+            "on the host from the routed-copy counts a unified step returns "
+            "and the block rows and block count its program was built with "
+            "(ops/grouped_gemm.bank_fetch_plan), on one device without EPLB "
+            "or DBO. A fused decode call returns its counts summed over its "
+            "k steps, so it books nothing; its plan has the same number of "
+            "blocks and the same order",
+            labelnames=("outcome",))
+        self.moe_backend_info = reg.gauge(
+            "llmd_tpu:engine_moe_backend",
+            "Resolved expert-GEMM backend, routing dispatch, and the grid "
+            "the ragged grouped GEMM is traced with in the unified step, as "
+            "gemm=<order>x<bf of moe_wi>x<bf of moe_wo> (fb = F tile outer, "
+            "block inner; bf the width of a bank tile; none where another "
+            "backend serves) (info-style: value 1 on the selected label set)",
+            labelnames=("backend", "dispatch", "gemm"))
         self.moe_ep_imbalance = reg.gauge(
             "llmd_tpu:moe_ep_load_imbalance",
             "Per-EP-rank expert-load imbalance (max/mean routed tokens per "

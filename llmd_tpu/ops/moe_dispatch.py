@@ -58,6 +58,13 @@ def pick_block_size(tokens_k: int, slots: int, pallas: bool) -> int:
     return max(8, bc) if pallas else bc
 
 
+def plan_blocks(copies: int, slots: int, bc: int) -> int:
+    """Blocks of ``bc`` rows in the padded buffer of ``copies`` routed copies
+    over ``slots`` expert slots: the worst case (every slot's last block
+    part-empty), so the shape is static."""
+    return (copies + bc - 1) // bc + slots
+
+
 def _row_plan(slot: jax.Array, S: int, bc: int):
     """Static-shape placement of N routed copies into a block-aligned
     buffer. ``slot`` is [N] int32 in [0, S]; S is the padding sentinel.
@@ -74,7 +81,7 @@ def _row_plan(slot: jax.Array, S: int, bc: int):
     cnt_pad = ((cnt + bc - 1) // bc) * bc
     starts = jnp.cumsum(cnt) - cnt            # raw sorted-order starts
     starts_pad = jnp.cumsum(cnt_pad) - cnt_pad  # block-aligned starts
-    Tp = ((N + bc - 1) // bc + S) * bc        # worst-case padding, static
+    Tp = plan_blocks(N, S, bc) * bc           # worst-case padding, static
     sc = jnp.minimum(ss, S - 1)
     pos_in_slot = jnp.arange(N, dtype=jnp.int32) - starts[sc]
     row_sorted = jnp.where(ss < S, starts_pad[sc] + pos_in_slot, Tp)

@@ -132,6 +132,91 @@ def test_sorted_pallas_interpret_matches_xla_backend():
                                rtol=1e-5, atol=1e-5)
 
 
+# ------------------------------------- the ragged grouped GEMM's fetch plan
+
+# (bc, copies by slot) of one layer's plan: a decode-like one (few copies a
+# slot, blocks of 8 rows) and a chunk-like one (blocks of 32). Both hold a
+# slot no copy reached, a slot of more than bc copies (two adjacent blocks)
+# and, the worst-case padding being what it is, a run of padding blocks at
+# the end; in the third the last slots are the unrouted ones, so the padding
+# run follows a real block that is not the last slot's.
+RAGGED_PLANS = {
+    "decode-like": (8, [3, 0, 19, 8, 1, 5]),
+    "chunk-like": (32, [24, 40, 0, 31, 70, 7]),
+    "last-slots-unrouted": (8, [9, 2, 0, 11, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("bf", [None, 128])  # the rule's tile (whole F); two tiles
+@pytest.mark.parametrize("slot_offset", [0, 12])  # into a stack of 3 layers' banks
+@pytest.mark.parametrize("plan", sorted(RAGGED_PLANS))
+def test_ragged_grouped_gemm_matches_the_gathered_einsum(plan, slot_offset, bf):
+    """The Pallas kernel (interpret mode) against `_experts_xla` on the same
+    blocks, both banks and the activation between them; and the fetch plan the
+    host books from the counts against the plan the kernel is handed."""
+    from llmd_tpu.ops import moe_dispatch as md
+    from llmd_tpu.ops.grouped_gemm import bank_fetch_plan, ragged_grouped_gemm
+
+    bc, counts = RAGGED_PLANS[plan]
+    S, D, Fe = len(counts), 32, 128
+    rng = np.random.default_rng(sum(counts))
+    slot = np.repeat(np.arange(S + 1), counts + [5]).astype(np.int32)  # 5 sentinels
+    rng.shuffle(slot)
+    row, block_slot, block_rows, Tp = md._row_plan(jnp.asarray(slot), S, bc)
+    nb = Tp // bc
+    assert nb == md.plan_blocks(len(slot), S, bc)
+    xr = rng.standard_normal((len(slot), D)).astype(np.float32)
+    xs = jnp.zeros((Tp, D), jnp.float32).at[row].set(jnp.asarray(xr),
+                                                     mode="drop")
+    wi = jnp.asarray(rng.standard_normal((3 * S, D, 2 * Fe)), jnp.float32) * 0.1
+    wo = jnp.asarray(rng.standard_normal((3 * S, Fe, D)), jnp.float32) * 0.1
+    xb, slots = xs.reshape(nb, bc, D), block_slot + slot_offset
+
+    want = md._experts_xla(xb, slots, block_rows, wi, wo, None, None,
+                           act=jax.nn.relu)
+    gate_up = ragged_grouped_gemm(xb, wi, slots, block_rows, interpret=True,
+                                  bf=bf)
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    got = ragged_grouped_gemm(jax.nn.relu(gate) * up, wo, slots, block_rows,
+                              interpret=True, bf=None if bf is None else 32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    rows = np.asarray(block_rows)
+    assert not np.asarray(got)[rows == 0].any()  # padding blocks: zeros
+
+    # the fetch plan: for a sorted block_slot the fetches are its runs over
+    # the blocks that hold rows, padding adds none, the three sum to nb
+    fetch, reuse, padding = bank_fetch_plan(counts, bc, nb)
+    real = np.asarray(block_slot)[rows > 0]
+    assert (np.diff(real) >= 0).all()
+    assert fetch == 1 + int((np.diff(real) != 0).sum()) == sum(c > 0 for c in counts)
+    assert reuse == len(real) - fetch and reuse >= 1
+    assert padding == int((rows == 0).sum()) and padding >= 2
+    assert fetch + reuse + padding == nb
+    assert (rows[len(real):] == 0).all()  # the padding blocks are one run at the end
+
+
+def test_bank_fetch_plan_sums_over_layers_and_tile_rule_reads_shapes_only():
+    from llmd_tpu.ops.grouped_gemm import (RGG_TILE_BUDGET, bank_fetch_plan,
+                                           pick_bank_tile, rgg_vmem_bytes)
+
+    a, b = RAGGED_PLANS["decode-like"][1], RAGGED_PLANS["last-slots-unrouted"][1]
+    one = [bank_fetch_plan(c, 8, 12) for c in (a, b)]
+    assert bank_fetch_plan(np.asarray([a, b]), 8, 12) == tuple(
+        map(sum, zip(*one)))
+    assert bank_fetch_plan(np.zeros((2, 6), np.int32), 8, 12) == (0, 0, 24)
+    # the cell's four calls take the whole F: one DMA an expert
+    for bc in (8, 32):
+        assert pick_bank_tile(2560, 1536, bc) == 1536
+        assert pick_bank_tile(768, 2560, bc) == 2560
+    # a bank too wide for the budget: the widest lane-aligned divisor that fits
+    bf = pick_bank_tile(8192, 8192, 128)
+    assert bf % 128 == 0 and 8192 % bf == 0 and bf < 8192
+    assert rgg_vmem_bytes(128, 8192, bf, 2) <= RGG_TILE_BUDGET
+    assert rgg_vmem_bytes(128, 8192, 2 * bf, 2) > RGG_TILE_BUDGET
+    assert pick_bank_tile(32, 200, 8) == 200  # no multiple of 128 divides it
+
+
 # ------------------------------------------------------------- drop-free
 
 
@@ -314,6 +399,49 @@ def test_engine_sorted_vs_einsum_greedy_parity_and_drops():
     if eng_e.stats.moe_dropped_tokens == 0:
         # nothing dropped -> identical math -> identical greedy outputs
         assert out_s == out_e
+
+
+# greedy tokens of the tiny MoE model through the Pallas kernel (interpret
+# mode) at 52c1282, the tree before the kernel's grid was turned
+TOKENS_BEFORE_ISSUE_35 = {
+    "req-0": [120, 54, 211, 243, 219, 103, 104, 148],
+    "req-1": [268, 114, 271, 53, 137, 187, 9, 83],
+    "req-2": [28, 152, 201, 270, 269, 175, 12, 131],
+}
+
+
+@pytest.mark.parametrize("moe_matmul", ["pallas", "einsum"])
+def test_engine_tokens_are_what_they_were_and_blocks_are_booked(moe_matmul):
+    """The same greedy tokens as before the change through `sorted_moe_local`,
+    by either block backend; and every unified step books nb blocks a layer on
+    ``moe_gemm_blocks_total``, by the plan its program was built with."""
+    from llmd_tpu.core.request import SamplingParams
+    from llmd_tpu.ops.moe_dispatch import pick_block_size, plan_blocks
+    from tests.test_unified_ahead import _count
+
+    eng = _tiny_engine(moe_matmul=moe_matmul)
+    cfg = eng.model_cfg
+    pallas = moe_matmul == "pallas"
+    assert eng.moe_backend == ("pallas_grouped_gemm" if pallas else "xla_einsum")
+    out = eng.generate([list(range(3, 30)), list(range(40, 55)), [5, 9, 2]],
+                       SamplingParams(max_tokens=8, temperature=0.0))
+    assert out == TOKENS_BEFORE_ISSUE_35
+
+    copies = eng.cfg.batched_tokens * cfg.moe_top_k
+    bc = pick_block_size(copies, cfg.moe_num_experts, pallas)
+    nb = plan_blocks(copies, cfg.moe_num_experts, bc)
+    assert eng._moe_gemm_plan.keywords == {"bc": bc, "nb": nb}
+    booked = {o: _count(eng, "moe_gemm_blocks_total", f'outcome="{o}"')
+              for o in ("fetch", "reuse", "padding")}
+    steps = _count(eng, "engine_program_dispatches_total",
+                   'program="unified"')
+    assert steps > 0 and booked["fetch"] > 0 and booked["padding"] > 0
+    assert sum(booked.values()) == steps * cfg.num_layers * nb
+    gemm = "fbx{}x{}".format(2 * cfg.moe_intermediate_size,
+                             cfg.hidden_size) if pallas else "none"
+    assert _count(eng, "engine_moe_backend",
+                  f'backend="{eng.moe_backend}",dispatch="sorted",'
+                  f'gemm="{gemm}"') == 1
 
 
 def test_engine_eplb_rebalance_no_recompile_on_sorted():

@@ -297,6 +297,38 @@ def test_grouped_gemms_lower_for_tpu():
         _spec((nb,), jnp.int32), _spec((nb,), jnp.int32))
 
 
+@pytest.mark.parametrize("bc", [8, 32], ids=["decode", "unified"])
+@pytest.mark.parametrize("bank,D,F", [("moe_wi", 2560, 1536),
+                                      ("moe_wo", 768, 2560)])
+def test_ragged_grouped_gemm_compiles_for_v5e_at_the_cells_shapes(one_chip,
+                                                                  bank, D, F,
+                                                                  bc):
+    """The experts' kernel at smallthinker-21b-a3b's widths (64 experts a
+    layer, four layers' banks stacked: 256 slots; 112 blocks of 8 rows in the
+    fused decode call and of 32 in the unified step) with the tile the rule
+    gives, the whole F: an expert's bank double-buffered (15.7 MB for
+    ``moe_wi``, 16.4 MB with the activation and output blocks) leaves
+    nothing of Mosaic's default 16 MiB of scoped VMEM, so the call asks for
+    its own limit and the compiler has to grant it."""
+    from llmd_tpu.ops.grouped_gemm import (RGG_VMEM_LIMIT, pick_bank_tile,
+                                           rgg_vmem_bytes)
+
+    nb, slots = 112, 4 * 64
+    assert pick_bank_tile(D, F, bc, 2) == F
+    assert 16e6 < rgg_vmem_bytes(32, 2560, 1536, 2) < RGG_VMEM_LIMIT
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(ragged_grouped_gemm).lower(
+        spec((nb, bc, D), jnp.bfloat16), spec((slots, D, F), jnp.bfloat16),
+        spec((nb,), jnp.int32), spec((nb,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged_grouped_gemm" in text
+    # the bank is read where it lies: no copy of it among the temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
 def test_attention_heads_must_split_over_tp():
     """K/V pairs of one head must stay on one device: a layout that cannot
     split is an error at trace time, never a silently wrong shard."""
