@@ -13,10 +13,8 @@ import time
 import traceback
 from typing import AsyncIterator, Callable, Optional
 
-import jax
-
 from llmd_tpu.core.request import SamplingParams
-from llmd_tpu.engine.engine import EngineOutput, LLMEngine
+from llmd_tpu.engine.engine import EngineOutput, LLMEngine, _StepParts
 
 
 class EngineDeadError(RuntimeError):
@@ -24,6 +22,12 @@ class EngineDeadError(RuntimeError):
 
 
 class AsyncLLMEngine:
+    # The loop from inside (PERF.md section 3): each turn's wall time goes to
+    # one of these parts, as llmd.loop.* profiler spans (step() carries
+    # llmd.step itself) and, from the same readings, as
+    # llmd_tpu:engine_loop_seconds_total{part}.
+    loop_parts: tuple[str, ...] = ("lock", "step", "deliver", "idle")
+
     def __init__(self, engine: LLMEngine, idle_sleep_s: float = 0.002) -> None:
         self.engine = engine
         self._idle_sleep = idle_sleep_s
@@ -54,16 +58,40 @@ class AsyncLLMEngine:
         if self._thread:
             self._thread.join(timeout=10)
 
+    def _loop_turn(self, first: str) -> _StepParts:
+        """The timeline of one turn of the loop thread, ``first`` running."""
+        return _StepParts("loop", "llmd.loop", first, self.loop_parts)
+
+    def _loop_counters(self) -> dict:
+        """The loop counter's children by part, taken once a loop."""
+        return {p: self.engine.metrics.loop_seconds.labels(part=p)
+                for p in self.loop_parts}
+
+    def _book_turn(self, parts: _StepParts, booked: dict) -> None:
+        parts.to(None)
+        for part, sec in parts.seconds.items():
+            booked[part].inc(sec)
+
+    def _deliver(self, outputs: list[EngineOutput], parts: _StepParts) -> None:
+        """Hand a step's outputs to their streams, as the turn's ``deliver``."""
+        if not outputs:
+            return
+        parts.to("deliver")
+        n = 0
+        for out in outputs:
+            with self._lock:
+                entry = self._streams.get(out.request_id)
+                if out.finished:
+                    self._streams.pop(out.request_id, None)
+            if entry is None:
+                continue
+            loop, q = entry
+            loop.call_soon_threadsafe(q.put_nowait, out)
+            n += 1
+        self.engine.metrics.outputs_delivered.inc(n)
+
     def _run(self) -> None:
-        # The loop from inside (PERF.md section 3): each iteration's wall
-        # time goes to one of four parts, as llmd.loop.* profiler spans
-        # (step() carries llmd.step itself) and, from the same readings, as
-        # llmd_tpu:engine_loop_seconds_total{part}.
-        m = self.engine.metrics
-        lock_s, step_s, deliver_s, idle_s = (
-            m.loop_seconds.labels(part=p)
-            for p in ("lock", "step", "deliver", "idle"))
-        delivered = m.outputs_delivered
+        booked = self._loop_counters()
         while not self._stop.is_set():
             # heartbeat BEFORE taking the lock: a step wedged on the device
             # holds the lock, so stamping inside it would mask the stall the
@@ -71,10 +99,9 @@ class AsyncLLMEngine:
             mon = getattr(self.engine, "monitor", None)
             if mon is not None:
                 mon.heartbeat()
-            t0 = time.perf_counter()
-            with jax.profiler.TraceAnnotation("llmd.loop.lock"):
-                self._lock.acquire()
-            t1 = time.perf_counter()
+            parts = self._loop_turn("lock")
+            self._lock.acquire()
+            parts.to("step", annotate=False)
             try:
                 try:
                     has_work = self.engine.has_work()
@@ -85,30 +112,11 @@ class AsyncLLMEngine:
                 traceback.print_exc()
                 self._die(e)
                 return
-            t2 = time.perf_counter()
-            if outputs:
-                n = 0
-                with jax.profiler.TraceAnnotation("llmd.loop.deliver"):
-                    for out in outputs:
-                        with self._lock:
-                            entry = self._streams.get(out.request_id)
-                            if out.finished:
-                                self._streams.pop(out.request_id, None)
-                        if entry is None:
-                            continue
-                        loop, q = entry
-                        loop.call_soon_threadsafe(q.put_nowait, out)
-                        n += 1
-                delivered.inc(n)
-            t3 = time.perf_counter()
+            self._deliver(outputs, parts)
             if not has_work:
-                with jax.profiler.TraceAnnotation("llmd.loop.idle"):
-                    time.sleep(self._idle_sleep)
-            t4 = time.perf_counter()
-            lock_s.inc(t1 - t0)
-            step_s.inc(t2 - t1)
-            deliver_s.inc(t3 - t2)
-            idle_s.inc(t4 - t3)
+                parts.to("idle")
+                time.sleep(self._idle_sleep)
+            self._book_turn(parts, booked)
 
     def _die(self, exc: BaseException) -> None:
         with self._lock:

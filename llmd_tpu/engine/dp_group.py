@@ -242,13 +242,22 @@ class DPAsyncEngine(AsyncLLMEngine):
             self.worker.close()
             self._next_register = time.monotonic() + self.register_retry_interval_s
 
+    # the base loop's parts, and the coordination plane's own: registration
+    # and the wave's round trip
+    loop_parts = AsyncLLMEngine.loop_parts + ("coordinate",)
+
     def _run(self) -> None:  # overrides the base loop
+        booked = self._loop_counters()
         while not self._stop.is_set():
+            parts = self._loop_turn("coordinate")
             if not self.registered:
                 self._try_register()
+            parts.to("lock")
             with self._lock:
+                parts.to("step", annotate=False)
                 has_work = self.engine.has_work()
             if self.registered:
+                parts.to("coordinate")
                 try:
                     step = self.worker.report(has_work)
                 except (OSError, ConnectionError, json.JSONDecodeError):
@@ -261,10 +270,14 @@ class DPAsyncEngine(AsyncLLMEngine):
             else:
                 step = has_work
             if not step:
+                parts.to("idle")
                 time.sleep(self._idle_sleep)
+                self._book_turn(parts, booked)
                 continue
+            parts.to("lock")
             try:
                 with self._lock:
+                    parts.to("step", annotate=False)
                     outputs = self.engine.step()
             except Exception as e:  # boundary: same contract as the base loop
                 traceback.print_exc()
@@ -276,16 +289,10 @@ class DPAsyncEngine(AsyncLLMEngine):
                 # pace the loop (on real multi-host SPMD the collective itself
                 # would block here)
                 self.empty_steps += 1
+                parts.to("idle")
                 time.sleep(self._idle_sleep)
-            for out in outputs:
-                with self._lock:
-                    entry = self._streams.get(out.request_id)
-                    if out.finished:
-                        self._streams.pop(out.request_id, None)
-                if entry is None:
-                    continue
-                loop, q = entry
-                loop.call_soon_threadsafe(q.put_nowait, out)
+            self._deliver(outputs, parts)
+            self._book_turn(parts, booked)
         self.worker.close()
 
 

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from llmd_tpu.core.kv_events import KVEvent
+from llmd_tpu.core.kv_events import KVEvent, block_keys_for_tokens
 from llmd_tpu.core.request import SamplingParams
 from llmd_tpu.engine.config import EngineConfig
 from llmd_tpu.engine.kv_manager import PageAllocator, Sequence
@@ -127,7 +127,12 @@ def _profile_phase(name: str):
     return deco
 
 
-STEP_PARTS = ("plan", "pack", "dispatch", "sample", "wait", "apply", "book")
+# The parts a step program's host time is split into, in the order a unified
+# step runs them; admission and the rest of step() have their own.
+STEP_PARTS = ("plan", "pack", "stage", "transfer", "dispatch", "sample",
+              "wait", "apply", "book")
+ADMIT_PARTS = ("hash", "match", "place")
+TURN_PARTS = ("route", "tail")
 
 # Fused-decode calls kept in flight: one behind the running one, so the device
 # goes back-to-back while the finished call's tokens cross back to the host.
@@ -137,18 +142,20 @@ DECODE_CHAIN_DEPTH = 2
 
 
 class _StepParts:
-    """One step program's host time, split into STEP_PARTS on one set of
-    ``perf_counter`` readings: ``to(part)`` closes the running part and opens
-    the next (``None`` pauses, e.g. around a nested program). Each part is a
-    ``<span>.<part>`` profiler annotation while it runs (free with no
-    capture), and its seconds land in ``seconds`` for the part counter
-    (``LLMEngine._book_parts``) and the ``EngineStats.time_*`` splits."""
+    """One stretch of the step thread's time (a step program, admission, the
+    rest of ``step()``, a turn of the loop), split into the parts ``names``
+    on one set of ``perf_counter`` readings: ``to(part)`` closes the running
+    part and opens the next (``None`` pauses, e.g. around a nested program).
+    Each part is a ``<span>.<part>`` profiler annotation while it runs (free
+    with no capture), and its seconds land in ``seconds`` for the part
+    counter (``LLMEngine._book_parts``)."""
 
     __slots__ = ("program", "span", "seconds", "_part", "_t", "_ann")
 
-    def __init__(self, program: str, span: str, part: Optional[str]) -> None:
+    def __init__(self, program: str, span: str, part: Optional[str],
+                 names: tuple[str, ...] = STEP_PARTS) -> None:
         self.program, self.span = program, span
-        self.seconds = dict.fromkeys(STEP_PARTS, 0.0)
+        self.seconds = dict.fromkeys(names, 0.0)
         self._part: Optional[str] = None
         self._ann = None
         self.to(part)
@@ -169,7 +176,9 @@ class _StepParts:
 
 def _step_phase(program: str, span: str, first: str, outer: bool = True):
     """A step-loop phase that splits its time: the method gets a running
-    ``_StepParts`` as its first argument, closed on every way out. With
+    ``_StepParts`` as its first argument, closed and booked on every way
+    out: a turn that dispatched nothing (an empty plan, a drained chain)
+    leaves its seconds on the part counter and no duration sample. With
     ``outer`` the whole call also sits in the ``span`` annotation, as
     ``_profile_phase`` would put it."""
     def deco(fn):
@@ -179,7 +188,7 @@ def _step_phase(program: str, span: str, first: str, outer: bool = True):
             try:
                 return fn(self, parts, *args, **kwargs)
             finally:
-                parts.to(None)
+                self._book_parts(parts)
         return _profile_phase(span)(timed) if outer else timed
     return deco
 
@@ -225,18 +234,8 @@ class EngineStats:
     time_prefill_steps: float = 0.0  # wall inside unified (mixed/prefill) steps
     time_decode_steps: float = 0.0  # wall inside fused decode calls
     time_spec_steps: float = 0.0  # wall inside speculative verify steps
-    # The same readings feed engine_step_part_seconds_total, which splits
-    # them further (STEP_PARTS).
-    time_host_pack: float = 0.0  # row choice + numpy staging (plan + pack)
-    # unified/verify step: ENQUEUE time of the jitted call (the dispatch is
-    # asynchronous, no device sync). Fused decode:
-    # the blocking read of the sampled tokens in _decode_process
-    time_device: float = 0.0
-    time_device_decode: float = 0.0  # the decode-call share of time_device
-    # host handling after the dispatch; in the unified step this HOLDS the
-    # wait for the device (the np.asarray read in _sample_apply: of the
-    # PREVIOUS step, since the unified step runs one ahead)
-    time_postprocess: float = 0.0
+    # engine_step_part_seconds_total splits the same readings by part
+    # (STEP_PARTS).
     n_unified_steps: int = 0
     n_decode_calls: int = 0  # fused decode calls PROCESSED (results applied)
     n_decode_dispatches: int = 0  # fused decode calls LAUNCHED; must equal
@@ -266,8 +265,8 @@ class EngineStats:
     time_mask_build: float = 0.0
     # Device-resident decode steady state (PERF.md Lever 12): host pack wall
     # that was hidden behind an in-flight device chain (a dispatch or process
-    # was pending when the pack ran) lands here instead of time_host_pack, so
-    # time_host_pack keeps meaning SERIALIZED host time on the critical path.
+    # was pending when the pack ran): everything of a chained dispatch before
+    # its jitted call. A chain start's is serialized, and is not counted here.
     time_pack_overlap: float = 0.0
     # dispatches that reused the in-flight chain's device-resident outputs
     # (tokens/positions/kv-lens/FSM) instead of a full host re-pack
@@ -337,6 +336,13 @@ class LLMEngine:
         self.metrics.cache_config.labels(
             block_size=engine_cfg.page_size,
             num_gpu_blocks=engine_cfg.num_pages).set(1)
+        # the children the step thread books at every turn, taken once: the
+        # part counter's by (program, part) as each is first booked
+        self._part_seconds: dict = {}
+        self._admissions = {
+            o: self.metrics.admissions.labels(outcome=o)
+            for o in ("admitted", "no_seat", "no_pages", "never_fits")}
+        self._admit_duration = self.metrics.step_duration.labels(phase="admit")
         self.tracer = global_tracer()
         # always-on per-request lifecycle timelines; EngineServer exposes
         # this recorder at /debug/requests (obs.events)
@@ -1613,18 +1619,38 @@ class LLMEngine:
         """Move waiting → running while slots + pages allow; reuse cached prefixes.
 
         Each DP rank admits independently (own queue, own batch-slot range, own
-        page partition) — a saturated rank never head-of-line-blocks another."""
-        for rank in range(self.num_ranks):
-            self._try_admit_rank(rank)
+        page partition) — a saturated rank never head-of-line-blocks another.
 
-    def _try_admit_rank(self, rank: int) -> None:
+        Admission is a program of the step ledger: its seconds go to
+        ``program="admit"`` by part (hash, match, place) and, from the same
+        sum, to ``step_duration{phase="admit"}``; a step whose queues are
+        empty books nothing."""
+        if not any(self.waitq):
+            return
+        parts = _StepParts("admit", "llmd.admit", None, ADMIT_PARTS)
+        try:
+            for rank in range(self.num_ranks):
+                self._try_admit_rank(rank, parts)
+        finally:
+            total = self._book_parts(parts)
+        if total:
+            self._admit_duration.observe(total)
+
+    def _try_admit_rank(self, rank: int, parts: _StepParts) -> None:
+        """One attempt on the queue's head at a time, each with one outcome
+        on ``admissions_total``: ``place`` is the seat search and, after
+        ``hash`` (the prompt's block keys) and ``match`` (what of them the
+        cache tiers hold, and the page budget), everything that seats the
+        sequence or turns it away."""
         waiting = self.waitq[rank]
         alloc = self.allocs[rank]
         lo = rank * self.slots_per_rank
         hi = lo + self.slots_per_rank
         while waiting:
+            parts.to("place")
             slot = next((i for i in range(lo, hi) if self.running[i] is None), None)
             if slot is None:
+                self._admissions["no_seat"].inc()
                 return
             seq = waiting[0]
             if seq.pages:
@@ -1636,11 +1662,15 @@ class LLMEngine:
                 # seq itself is leaking.
                 self._free_seq(seq)
             ps = self.cfg.page_size
-            # prefix-cache lookup over complete prompt blocks
-            from llmd_tpu.core.kv_events import block_keys_for_tokens
-
+            # prefix-cache lookup over complete prompt blocks. A head held
+            # for want of pages is hashed and matched again at every step
+            # until pages free: admit_hashed_tokens_total growing with
+            # admissions_total{outcome="no_pages"} is that made visible
+            parts.to("hash")
             keys = block_keys_for_tokens(seq.token_ids[: seq.prompt_len], ps,
                                          seq.lora_key, seq.mm_hashes())
+            self.metrics.admit_hashed_tokens.inc(seq.prompt_len)
+            parts.to("match")
             hit_pages = alloc.match_prefix(keys) if self.prefix_reuse else []
             # never reuse the whole prompt — the final token's logits must be computed
             max_reuse = max(0, (seq.prompt_len - 1) // ps)
@@ -1663,6 +1693,7 @@ class LLMEngine:
                 1 for pid in hit_pages
                 if (info := alloc.pages.get(pid)) is not None and info.refs == 0
             )
+            parts.to("place")
             if need_new > alloc.num_pages:
                 # can never fit (prompt + generated tokens outgrew the pool, e.g. after
                 # a preemption late in generation): finish with length, don't starve
@@ -1676,9 +1707,12 @@ class LLMEngine:
                     request_id=seq.request_id, new_token_ids=[], finished=True,
                     finish_reason="length", prompt_len=seq.prompt_len,
                 ))
+                self._admissions["never_fits"].inc()
                 continue
             if alloc.num_free - hits_in_lru < need_new:
-                return  # head-of-line blocks; FCFS admission (within this rank)
+                # head-of-line blocks; FCFS admission (within this rank)
+                self._admissions["no_pages"].inc()
+                return
             for pid in hit_pages:
                 alloc.acquire_cached(pid)
             n_hbm = len(hit_pages)
@@ -1711,6 +1745,7 @@ class LLMEngine:
                                pages=len(seq.pages))
             if self.lora_registry is not None:
                 self.lora_registry.on_running(seq.lora_id)
+            self._admissions["admitted"].inc()
 
     def _reload_offloaded(self, seq: Sequence, keys: list[int], n_hbm: int,
                           n_offload: int) -> list[int]:
@@ -1798,12 +1833,15 @@ class LLMEngine:
         ))
 
     def _preempt_one(self, rank: int = 0,
-                     exclude: Optional[Sequence] = None) -> bool:
+                     exclude: Optional[Sequence] = None,
+                     parts: Optional[_StepParts] = None) -> bool:
         """Evict the rank's most recently arrived running seq back to waiting
         (recompute semantics). Pages are rank-partitioned, so only a same-rank
         victim frees memory the caller can use. ``exclude`` is the seq the
         caller is trying to schedule: evicting it frees its own pages only to
-        reset it to token zero — a thrash loop, never progress."""
+        reset it to token zero — a thrash loop, never progress. ``parts`` is
+        the caller's running plan, paused around the read of the step in
+        flight, which books its own seconds."""
         # Read the unified step in flight BEFORE choosing a victim: evicting
         # a row whose token is not applied yet would drop that token — full
         # re-prefill, re-defer, re-evict, a tight-pool ping-pong with zero
@@ -1812,7 +1850,11 @@ class LLMEngine:
         # row's last token on the host, so no row is ever evicted with a
         # token in flight. Preemption is the rare slow path, so the extra
         # device read here is noise.
+        if parts is not None:
+            parts.to(None)
         self._flush_pending_sample()
+        if parts is not None:
+            parts.to("plan")
         victims = [s for s in self.running
                    if s is not None and s.rank == rank and s is not exclude]
         if not victims:
@@ -1845,45 +1887,63 @@ class LLMEngine:
         self._n_steps += 1
         with jax.profiler.StepTraceAnnotation("llmd.step",
                                               step_num=self._n_steps):
-            self._outputs = []
-            if self.offload is not None:
-                self._offload_drain()
-            with jax.profiler.TraceAnnotation("llmd.admit"):
-                self._try_admit()
-            with jax.profiler.TraceAnnotation("llmd.route"):
+            # what step() does outside admission and the step program, on the
+            # same ledger (program="step"; spans llmd.route, llmd.tail), so
+            # that the parts of all programs cover the step thread's turn
+            turn = _StepParts("step", "llmd", "tail", TURN_PARTS)
+            try:
+                self._outputs = []
+                if self.offload is not None:
+                    self._offload_drain()
+                turn.to(None)
+                with jax.profiler.TraceAnnotation("llmd.admit"):
+                    self._try_admit()
+                turn.to("route")
                 program = self.programs.route(self)
-            program.run(self)
-            self.stats.num_waiting = sum(len(q) for q in self.waitq)
-            self.stats.num_running = sum(
-                1 for s in self.running if s is not None)
-            self.stats.kv_utilization = (
-                sum(a.num_active for a in self.allocs)
-                / max(1, self.cfg.num_pages))
-            m = self.metrics
-            m.requests_waiting.set(self.stats.num_waiting)
-            m.requests_running.set(self.stats.num_running)
-            m.kv_usage.set(self.stats.kv_utilization)
-            m.batch_occupancy.labels(kind="running").observe(
-                self.stats.num_running)
-            m.batch_occupancy.labels(kind="waiting").observe(
-                self.stats.num_waiting)
-            if self._eplb is not None:
-                self._eplb_tick()
-            now = time.perf_counter()
-            for out in self._outputs:
-                out.t_step = now
-            return self._outputs
+                turn.to(None)
+                program.run(self)
+                turn.to("tail")
+                self.stats.num_waiting = sum(len(q) for q in self.waitq)
+                self.stats.num_running = sum(
+                    1 for s in self.running if s is not None)
+                self.stats.kv_utilization = (
+                    sum(a.num_active for a in self.allocs)
+                    / max(1, self.cfg.num_pages))
+                m = self.metrics
+                m.requests_waiting.set(self.stats.num_waiting)
+                m.requests_running.set(self.stats.num_running)
+                m.kv_usage.set(self.stats.kv_utilization)
+                m.batch_occupancy.labels(kind="running").observe(
+                    self.stats.num_running)
+                m.batch_occupancy.labels(kind="waiting").observe(
+                    self.stats.num_waiting)
+                if self._eplb is not None:
+                    self._eplb_tick()
+                now = time.perf_counter()
+                for out in self._outputs:
+                    out.t_step = now
+                return self._outputs
+            finally:
+                self._book_parts(turn)
 
     def _book_parts(self, parts: _StepParts) -> float:
-        """Close ``parts`` and add its seconds to the part counter; returns
+        """Close ``parts`` and move its seconds to the part counter; returns
         their sum, which is what the phase's step_duration sample must be so
-        that the two agree."""
+        that the two agree. Booked seconds leave ``parts``: a second call
+        books only what ran since."""
         parts.to(None)
         total = 0.0
-        for part, sec in parts.seconds.items():
+        seconds, children = parts.seconds, self._part_seconds
+        for part, sec in seconds.items():
             if sec:
-                self.metrics.step_part_seconds.labels(
-                    program=parts.program, part=part).inc(sec)
+                key = (parts.program, part)
+                child = children.get(key)
+                if child is None:
+                    child = children[key] = (
+                        self.metrics.step_part_seconds.labels(
+                            program=parts.program, part=part))
+                child.inc(sec)
+                seconds[part] = 0.0
                 total += sec
         return total
 
@@ -2027,9 +2087,11 @@ class LLMEngine:
         read, and a row of that step rides along as a decode row whose input
         token the program takes on the device (``_unified``). ``parts``
         splits the host time in the order it runs: plan (row choice, pages,
-        preemption), pack (numpy staging), dispatch (transfers, with the
-        sampling parameters of a step in which a row samples, + the
-        asynchronous jitted call, which picks the step's tokens too), apply
+        preemption), pack (numpy staging), stage (flight records, counters,
+        the choice of the step function, the sampling parameters of a step in
+        which a row samples), transfer (the ``jnp.asarray`` calls), dispatch
+        (the asynchronous jitted call, which picks the step's tokens too,
+        and nothing else), apply
         (this step's per-row state), sample (the record of what the step
         left to read; for a batch with a constrained row also its bias and
         the biased sampler's dispatch), wait (the blocking read of the
@@ -2075,7 +2137,8 @@ class LLMEngine:
             # the row computes position num_computed, whether its token is
             # on the host (len(token_ids) - 1) or in flight (len(token_ids))
             if not self._ensure_pages(s, s.num_computed + 1):
-                if not self._preempt_one(s.rank, exclude=s) or s.slot < 0:
+                if (not self._preempt_one(s.rank, exclude=s, parts=parts)
+                        or s.slot < 0):
                     self._finish_if_outgrew_pool(s)
                     continue
                 if not self._ensure_pages(s, s.num_computed + 1):
@@ -2092,7 +2155,8 @@ class LLMEngine:
             if n <= 0:
                 continue
             if not self._ensure_pages(s, s.num_computed + n):
-                if not self._preempt_one(s.rank, exclude=s) or s.slot < 0:
+                if (not self._preempt_one(s.rank, exclude=s, parts=parts)
+                        or s.slot < 0):
                     self._finish_if_outgrew_pool(s)
                     continue
                 if not self._ensure_pages(s, s.num_computed + n):
@@ -2104,6 +2168,7 @@ class LLMEngine:
             # nothing schedulable — the step in flight may be WHY (rows it
             # ends are not planned ahead, and hold slots/pages until it is
             # read): read it so the next step can make progress
+            parts.to(None)
             self._flush_pending_sample()
             return
         # the step in flight, as the plan left it: a preemption reads it
@@ -2176,12 +2241,11 @@ class LLMEngine:
             cu[i + 1] = off
         cu[len(plan) + 1 :] = off
 
-        parts.to("dispatch")
+        parts.to("stage")
         for s in first_chunks:
             # a (re)prefill's first chunk goes to the device now: the
             # ledger's schedule phase ends here
             self.flight.record(s.request_id, "dispatched")
-        mm_args = ((jnp.asarray(mm_embeds), jnp.asarray(mm_mask)) if is_vl else ())
         # ring-eligible: ONE fresh self-contained prefill chunk at offset 0
         # (positions 0..n-1, no prior KV) — the only regime where causality by
         # row index equals causality by position and in-chunk q/k/v are the
@@ -2210,18 +2274,30 @@ class LLMEngine:
         prev_sampled = prev["sampled"] if prev is not None else None
         if prev_sampled is None:
             prev_sampled = self._zero_sampled
-        state_kw = {}
         if recurrent:
-            state_kw["state_slots"] = jnp.asarray(row_slots)
             self._count_ssm_tokens(step_prog, chunk=off - n_dec, decode=n_dec)
         sampling, samples = self._sampling_state(sample_list)
+        # the step's arrays cross to the device here, one transfer each, and
+        # not in the call's argument list: what `dispatch` then holds is the
+        # call's own enqueue
+        parts.to("transfer")
+        mm_args = ((jnp.asarray(mm_embeds), jnp.asarray(mm_mask)) if is_vl else ())
+        state_kw = ({"state_slots": jnp.asarray(row_slots)}
+                    if recurrent else {})
+        args = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(sids),
+                jnp.asarray(pts), jnp.asarray(lens), jnp.asarray(cu),
+                jnp.asarray([len(plan)], jnp.int32), jnp.asarray(lora_tok))
+        parts.to("dispatch")
         logits, sampled, pools, cnt, moe_drop = step_fn(
-            self._run_params(), self._pools(), jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(sids), jnp.asarray(pts), jnp.asarray(lens), jnp.asarray(cu),
-            jnp.asarray([len(plan)], jnp.int32), jnp.asarray(lora_tok),
+            self._run_params(), self._pools(), *args,
             prev_sampled, *sampling, *mm_args, **state_kw,
         )
         self._keep_pools(pools)
+        # the host's handles on the transferred arrays go here, on the
+        # transfers' account (0.2-0.3 ms a step on the chip's host), and not
+        # when the frame ends, which is after the parts are booked
+        parts.to("transfer")
+        del args, mm_args, state_kw
         parts.to("apply")
 
         # goodput classification reads pre-postprocess sequence state: the
@@ -2267,6 +2343,7 @@ class LLMEngine:
         parts.to("sample")
         rec = self._sample_dispatch(sample_list, logits, sampled, sampling,
                                     ahead_rows=ahead_rows)
+        del logits  # its handle too goes inside a part (the record keeps none)
         rec["prog"] = step_prog
         self.metrics.sampler_steps.labels(
             program="unified",
@@ -2285,14 +2362,8 @@ class LLMEngine:
             self._sample_apply(prev, parts)
         self._pending_sample = rec
         parts.to("book")
-        sec = parts.seconds
-        host_pack = sec["plan"] + sec["pack"]
-        post = sec["apply"] + sec["sample"] + sec["wait"]
-        wall = host_pack + sec["dispatch"] + post
+        wall = sum(parts.seconds.values())  # all of the step but its booking
         st = self.stats
-        st.time_host_pack += host_pack
-        st.time_device += sec["dispatch"]
-        st.time_postprocess += post
         st.time_prefill_steps += wall
         st.n_unified_steps += 1
         n_pre = sum(n for _, n, d in plan if not d)
@@ -2480,7 +2551,8 @@ class LLMEngine:
                 return draft[:i]
         return draft
 
-    def _spec_try_verify(self) -> bool:
+    @_step_phase("verify", "llmd.spec_verify", "plan", outer=False)
+    def _spec_try_verify(self, parts: _StepParts) -> bool:
         """Decode-path speculation gate; True = a verify step ran (replacing
         this step's fused decode call).
 
@@ -2524,7 +2596,9 @@ class LLMEngine:
                     s.spec_flips += 1
         if not probed:
             return False
+        parts.to(None)
         self._flush_pending_decode()
+        parts.to("plan")
         active = [s for s in self._decode_ready() if s.slot >= 0]
         if not active:
             return True  # the flush retired/changed the batch; step done
@@ -2546,7 +2620,8 @@ class LLMEngine:
             if draft and not self._ensure_pages(s, len(s.token_ids) + len(draft)):
                 draft = []  # shed the draft before shedding a sequence
             if not self._ensure_pages(s, len(s.token_ids)):
-                if not self._preempt_one(s.rank, exclude=s) or s.slot < 0:
+                if (not self._preempt_one(s.rank, exclude=s, parts=parts)
+                        or s.slot < 0):
                     self._finish_if_outgrew_pool(s)
                     continue
                 if not self._ensure_pages(s, len(s.token_ids)):
@@ -2568,19 +2643,23 @@ class LLMEngine:
                     s.spec_armed = False
                     s.spec_flips += 1
             return False
-        self._step_spec_verify(plan)
+        self._step_spec_verify(plan, parts)
         return True
 
     @_profile_phase("llmd.spec_verify")
-    def _step_spec_verify(self, plan: list[tuple[Sequence, list[int]]]) -> None:
+    def _step_spec_verify(self, plan: list[tuple[Sequence, list[int]]],
+                          parts: _StepParts) -> None:
         """Pack each sequence's draft as a short self-contained chunk (its
         last real token + the draft) through the verify program, accept the
         longest greedy-matching prefix plus one bonus token, and roll back
         the rejected tail — host token state never contains a draft token
         unless verification proved it, so ``maybe_commit_blocks`` can never
         commit an unverified page, and surplus draft pages release straight
-        back to the allocator's free list."""
-        t0 = time.perf_counter()
+        back to the allocator's free list. ``parts`` comes running from
+        ``_spec_try_verify`` (plan) and goes on through pack, the train of
+        every step program (stage, transfer, dispatch), wait (the read of
+        the greedy tokens), apply and book."""
+        parts.to("pack")
         t0_ns = time.time_ns()
         NT = self._verify_nt()
         B = self.cfg.max_batch_size
@@ -2615,37 +2694,36 @@ class LLMEngine:
             off += n
             cu[i + 1] = off
         cu[len(plan) + 1 :] = off
-        tm = time.perf_counter()
+        parts.to("stage")
         # constrained rows ride the masked variant: dense [G,S,V] bias/next
         # tables + per-packed-row FSM entry states (None = no constrained
-        # row). Stage wall self-accounts into time_mask_build, so the pack
-        # split below stops at tm — the two stats stay disjoint.
+        # row); their staging also accounts itself into time_mask_build
         mask = self._spec_stage_verify_masks(plan)
-        prog = "verify" if mask is None else "verify_masked"
-        t1 = time.perf_counter()
+        prog = parts.program = "verify" if mask is None else "verify_masked"
         self.programs.record_dispatch(prog)
+        parts.to("transfer")
+        args = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(sids),
+                jnp.asarray(pts), jnp.asarray(lens), jnp.asarray(cu),
+                jnp.asarray([len(plan)], jnp.int32), jnp.asarray(lora_tok))
+        parts.to("dispatch")
         if mask is None:
             fsm_out = None
             greedy, self.cache, cnt, moe_drop = self._verify_fn(
-                self._run_params(), self.cache, jnp.asarray(toks),
-                jnp.asarray(pos), jnp.asarray(sids), jnp.asarray(pts),
-                jnp.asarray(lens), jnp.asarray(cu),
-                jnp.asarray([len(plan)], jnp.int32), jnp.asarray(lora_tok),
-            )
+                self._run_params(), self.cache, *args)
         else:
             greedy, fsm_out, self.cache, cnt, moe_drop = self._verify_masked_fn(
-                self._run_params(), self.cache, jnp.asarray(toks),
-                jnp.asarray(pos), jnp.asarray(sids), jnp.asarray(pts),
-                jnp.asarray(lens), jnp.asarray(cu),
-                jnp.asarray([len(plan)], jnp.int32), jnp.asarray(lora_tok),
+                self._run_params(), self.cache, *args,
                 mask["fsm0"], mask["gidx"], mask["bias_tab"], mask["next_tab"],
             )
+        parts.to("transfer")
+        del args  # the handles' release, as in _step_unified
+        parts.to("wait")
         # llmd-lint: allow[hot-host-sync] designed sync point: verify needs the greedy tokens on host to accept/reject the draft
         g = np.asarray(greedy)  # [NT] (device sync point)
         # llmd-lint: allow[hot-host-sync] same designed sync point: the per-position FSM states ride the readback the greedy tokens already paid for
         fsm = np.asarray(fsm_out) if fsm_out is not None else None
         self.programs.record_complete(prog)
-        t2 = time.perf_counter()
+        parts.to("apply")
         if self._eplb is not None:
             self._eplb_record(cnt)
         if self.model_cfg.is_moe:
@@ -2733,17 +2811,14 @@ class LLMEngine:
                 num_cached_prompt_tokens=s.num_cached_prompt,
                 prompt_len=s.prompt_len,
             ))
-        t3 = time.perf_counter()
+        parts.to("book")
+        sec = parts.seconds
+        wall = sum(sec.values()) - sec["plan"]  # the step, without its plan
         st = self.stats
-        st.time_host_pack += tm - t0
-        st.time_device += t2 - t1
-        st.time_postprocess += t3 - t2
-        st.time_spec_steps += t3 - t0
+        st.time_spec_steps += wall
         st.n_spec_verify_steps += 1
         if n_tokens:
             self.metrics.decode_tokens.inc(n_tokens)
-        self.metrics.step_duration.labels(phase="spec_verify").observe(
-            t3 - t0, exemplar=self._trace_exemplar([s for s, _, _, _ in rows]))
         if self.util is not None:
             # verify burns its whole NT budget (PR 15 measured 6.4x padding
             # here — the standing padding_efficiency series); kept tokens
@@ -2754,12 +2829,16 @@ class LLMEngine:
                 kv_read_tokens=int(lens[: len(plan)].sum()),
                 kv_write_tokens=off)
             self.util.record(
-                prog, cost, t3 - t0,
+                prog, cost, wall,
                 committed=n_tokens,
                 spec_rejected=self.stats.spec_rejected - spec_rej0,
                 compile_counts=self.programs.compile_counts())
         self._emit_step_spans("spec_verify", (s for s, _, _, _ in rows), t0_ns,
                               len(plan), n_tokens)
+        # last, and from the parts' own sum (see _step_unified)
+        self.metrics.step_duration.labels(phase="spec_verify").observe(
+            self._book_parts(parts),
+            exemplar=self._trace_exemplar([s for s, _, _, _ in rows]))
 
     def _spec_release_tail(self, s: Sequence) -> None:
         """Roll back KV pages grown for rejected draft tokens: trim the page
@@ -2980,7 +3059,12 @@ class LLMEngine:
           re-derives only ``steps_left`` (the per-row hard budget) and, when a
           row grew a page, the page tables. One small upload instead of nine,
           and the pack wall is overlapped with the in-flight device chain
-          (accounted as time_pack_overlap, not time_host_pack).
+          (accounted as time_pack_overlap).
+
+        ``parts`` runs pack (numpy staging), then the train every step
+        program issues: stage (mask tables, flight records, the key split),
+        transfer (the ``jnp.asarray`` calls), dispatch (the jitted call
+        alone), and book.
         """
         B = self.cfg.max_batch_size
         fast = chain is not None
@@ -3008,21 +3092,22 @@ class LLMEngine:
                                      np.int32)
                     for s in active:
                         pts_np[s.slot, : len(s.pages)] = s.pages
-                    pts_dev = jnp.asarray(pts_np)
                     pages_sig = tuple(len(s.pages) for s in active)
                 else:
-                    pts_np, pts_dev, pages_sig = (chain["pts_np"],
-                                                  chain["pts_dev"], sig)
-                toks_in, pos_in, lens_in = (chain["last_toks"],
-                                            chain["pos_out"],
-                                            chain["lens_out"])
-                temp_dev, tk_dev, tp_dev, lora_dev = (
-                    chain["temp_dev"], chain["tk_dev"], chain["tp_dev"],
-                    chain["lora_dev"])
-                steps_dev = jnp.asarray(steps_left)
-                mask = chain["mask"]
-                fsm_in = chain["fsm_out"]
-                sampler_path = chain["sampler_path"]
+                    pts_np, pages_sig = chain["pts_np"], sig
+            parts.to("stage")
+            mask = chain["mask"]
+            fsm_in = chain["fsm_out"]
+            sampler_path = chain["sampler_path"]
+            self._key, sub = jax.random.split(self._key)
+            parts.to("transfer")
+            pts_dev = jnp.asarray(pts_np) if pages_changed else chain["pts_dev"]
+            toks_in, pos_in, lens_in = (chain["last_toks"], chain["pos_out"],
+                                        chain["lens_out"])
+            temp_dev, tk_dev, tp_dev, lora_dev = (
+                chain["temp_dev"], chain["tk_dev"], chain["tp_dev"],
+                chain["lora_dev"])
+            steps_dev = jnp.asarray(steps_left)
         else:
             pos = np.full((B,), -1, np.int32)
             pts_np = np.full((B, self.cfg.max_pages_per_seq), -1, np.int32)
@@ -3048,13 +3133,7 @@ class LLMEngine:
                 steps_left[i] = max(0, min(s.max_tokens - gen,
                                            self.cfg.max_model_len - eff_len, k))
             pages_sig = tuple(len(s.pages) for s in active)
-            pts_dev = jnp.asarray(pts_np)
-            pos_in, lens_in = jnp.asarray(pos), jnp.asarray(lens_np)
-            temp_dev, tk_dev, tp_dev = (jnp.asarray(temp), jnp.asarray(tk),
-                                        jnp.asarray(tp))
-            lora_dev = jnp.asarray(lora_idx)
-            steps_dev = jnp.asarray(steps_left)
-            toks_in = jnp.asarray(toks)
+            parts.to("stage")
             mask = (self._stage_chain_masks(active)
                     if any(s.structured is not None or s.logit_bias
                            for s in active) else None)
@@ -3066,19 +3145,16 @@ class LLMEngine:
             for s in active:
                 self.flight.record(s.request_id, "chain_dispatch", k=k,
                                    masked=mask is not None)
-        self._key, sub = jax.random.split(self._key)
+            self._key, sub = jax.random.split(self._key)
+            parts.to("transfer")
+            pts_dev = jnp.asarray(pts_np)
+            pos_in, lens_in = jnp.asarray(pos), jnp.asarray(lens_np)
+            temp_dev, tk_dev, tp_dev = (jnp.asarray(temp), jnp.asarray(tk),
+                                        jnp.asarray(tp))
+            lora_dev = jnp.asarray(lora_idx)
+            steps_dev = jnp.asarray(steps_left)
+            toks_in = jnp.asarray(toks)
         parts.to("dispatch")
-        sec = parts.seconds
-        host_pack = sec["plan"] + sec["pack"]
-        if fast:
-            # the device is still executing chain N while this pack ran: its
-            # wall is hidden, not serialized — keep time_host_pack honest
-            self.stats.time_pack_overlap += host_pack
-            self.metrics.step_duration.labels(phase="pack_overlap").observe(
-                host_pack)
-        else:
-            self.stats.time_host_pack += host_pack
-            self.metrics.step_duration.labels(phase="pack").observe(host_pack)
         if mask is not None:
             (toks_out, last_toks, pos_out, lens_out, fsm_out, pools,
              cnt, moe_drop) = self._decode_multi_masked_fn(
@@ -3097,6 +3173,14 @@ class LLMEngine:
             fsm_out = None
         self._keep_pools(pools)
         parts.to("book")
+        sec = parts.seconds
+        # the host's wall before the call: serialized at a chain start,
+        # hidden behind the running call of the chain otherwise
+        host_pack = sec["plan"] + sec["pack"] + sec["stage"] + sec["transfer"]
+        if fast:
+            self.stats.time_pack_overlap += host_pack
+        self.metrics.step_duration.labels(
+            phase="pack_overlap" if fast else "pack").observe(host_pack)
         self.stats.time_decode_steps += host_pack + sec["dispatch"]
         self.stats.n_decode_dispatches += 1
         prog = parts.program = "decode" if mask is None else "decode_masked"
@@ -3216,9 +3300,6 @@ class LLMEngine:
         sec = parts.seconds
         wall = sec["wait"] + sec["apply"]
         st = self.stats
-        st.time_device += sec["wait"]
-        st.time_device_decode += sec["wait"]
-        st.time_postprocess += sec["apply"]
         st.time_decode_steps += wall
         st.n_decode_calls += 1
         self.programs.record_complete(rec["prog"])

@@ -459,34 +459,65 @@ class EngineMetrics:
         self.step_duration = reg.histogram(
             "llmd_tpu:engine_step_duration_seconds",
             "Engine step wall time by phase "
-            "(unified, decode_dispatch, decode_process, spec_verify; pack = "
-            "serialized host pack at a chain boundary, pack_overlap = chained "
-            "fast-path pack hidden behind the in-flight device call, "
+            "(unified, decode_dispatch, decode_process, spec_verify; admit = "
+            "a step's admission, where it looked at a waiting sequence; "
+            "pack = the host's wall before the jitted call at a chain "
+            "boundary, serialized; pack_overlap = the same of a chained "
+            "dispatch, hidden behind the in-flight device call; "
             "chain_stage = dense grammar/bias table staging per chain). "
-            "engine_step_part_seconds_total splits unified, decode_dispatch "
-            "and decode_process",
+            "engine_step_part_seconds_total splits admit, unified, "
+            "decode_dispatch, decode_process and spec_verify",
             labelnames=("phase",))
-        # The step loop from inside (PERF.md section 3): host seconds of each
-        # step program by part, from the same perf_counter readings as the
+        # The step thread's whole turn on one ledger (PERF.md section 3):
+        # seconds of each step program, of admission and of the rest of
+        # step() by part, from the same perf_counter readings as the
         # llmd.<phase>.<part> profiler spans. Per program the parts sum to
-        # step_duration_sum of its phases (unified; decode_dispatch +
-        # decode_process for decode).
+        # step_duration_sum of its phases (admit; unified; decode_dispatch +
+        # decode_process for decode; spec_verify for verify); over all
+        # programs they sum to engine_loop_seconds_total{part="step"} less
+        # the loop's has_work().
         self.step_part_seconds = reg.counter(
             "llmd_tpu:engine_step_part_seconds_total",
-            "Host wall seconds of a step program by part: plan (row choice, "
-            "pages, preemption), pack (numpy staging), dispatch (transfers + "
-            "the asynchronous jitted call, which picks the step's tokens), "
-            "sample (the record of the tokens to read; a constrained "
-            "batch's bias and biased sampler), wait (the blocking read "
-            "of sampled tokens: the device's share), apply (per-row state), "
-            "book (metrics, flight, utilisation). program=sample is a "
-            "deferred prefill sample read outside a unified step",
+            "Wall seconds of the step thread by program and part. A step "
+            "program (unified, decode, verify): plan (row choice, pages, "
+            "preemption), pack (numpy staging), stage (flight records, "
+            "counters, the choice of the step function, sampling "
+            "parameters, mask tables, the key split), transfer (the "
+            "host-to-device copies of the step's arrays and, after the call, "
+            "the release of the host's handles on them), dispatch (the "
+            "asynchronous jitted call alone, which picks the step's "
+            "tokens: its enqueue; until ISSUE 36 this part also held stage "
+            "and transfer), sample (the record of the tokens to read; a "
+            "constrained batch's bias and biased sampler), wait (the "
+            "blocking read of sampled tokens: the device's share), apply "
+            "(per-row state), book (metrics, flight, utilisation). "
+            "program=sample is a read of the unified step in flight outside "
+            "a unified step. program=admit: hash (the prompt's block keys), "
+            "match (what the cache tiers hold of them, the page budget), "
+            "place (the seat search, the sequence seated or turned away). "
+            "program=step, the rest of step(): route (the choice of the "
+            "program), tail (offload drain, gauges, occupancy, the stamp "
+            "on the outputs)",
             labelnames=("program", "part"))
+        self.admissions = reg.counter(
+            "llmd_tpu:admissions_total",
+            "Attempts of admission on a waiting queue's head by outcome: "
+            "admitted, no_seat (every seat of the rank taken), no_pages (the "
+            "head is held until pages free: it is hashed and matched again "
+            "at every step meanwhile), never_fits (needs more pages than "
+            "the pool has: finished with 'length')",
+            labelnames=("outcome",))
+        self.admit_hashed_tokens = reg.counter(
+            "llmd_tpu:admit_hashed_tokens_total",
+            "Prompt tokens admission hashed into block keys for the prefix "
+            "cache, a held head's at every attempt")
         self.loop_seconds = reg.counter(
             "llmd_tpu:engine_loop_seconds_total",
             "Wall seconds of the engine loop thread by part: lock (waiting "
             "for the engine lock), step (has_work + step()), deliver (the "
-            "hand-off of outputs to their streams), idle (sleep with no work)",
+            "hand-off of outputs to their streams), idle (sleep with no "
+            "work), and under data parallelism coordinate (registration "
+            "and the wave's round trip to the coordinator)",
             labelnames=("part",))
         self.outputs_delivered = reg.counter(
             "llmd_tpu:engine_outputs_delivered_total",

@@ -142,10 +142,13 @@ def test_dp_engine_drops_to_solo_after_coordinator_outage():
     """After a report() failure the engine must deregister and serve solo on the
     paced re-register schedule — NOT re-attempt a blocking connect every step."""
     from llmd_tpu.engine.dp_group import DPAsyncEngine, DPWorkerSync
+    from llmd_tpu.obs.metrics import EngineMetrics, Registry
 
     class FakeEngine:
         def __init__(self):
             self.stepped = 0
+            # the loop books its turns here, as the base loop does
+            self.metrics = EngineMetrics(Registry())
 
         def has_work(self):
             return True
