@@ -35,10 +35,14 @@ class EngineConfig:
     dp_ranks: int = 1
     # Scheduling
     max_queue: int = 1024
-    # Multi-step decode: run N decode iterations in one on-device lax.scan (one host
-    # round-trip per N tokens). Stop/max_tokens handled post-hoc by truncation.
-    # Calls are dispatched chained on the previous call's device-resident
-    # sampled tokens and read one call later (engine.DECODE_CHAIN_DEPTH).
+    # Multi-step decode: a fused call runs up to N decode iterations in one
+    # on-device loop (one host round-trip a call). N is the cap and the height
+    # of the call's token buffer: the host gives each call the length at which
+    # its first row is known to end by max_tokens or max_model_len
+    # (engine.decode_call_steps, no shorter than engine.DECODE_MIN_STEPS);
+    # stop tokens are handled post-hoc by truncation. Calls are dispatched
+    # chained on the previous call's device-resident sampled tokens and read
+    # one call later (engine.DECODE_CHAIN_DEPTH).
     decode_steps: int = 1
     # KV offload tier (pages of CPU-side cache; 0 = disabled) — K3 equivalent
     # (TPU_OFFLOAD_NUM_CPU_CHUNKS / STAGING_BLOCKS knobs of the reference connector).

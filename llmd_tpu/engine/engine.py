@@ -6,8 +6,10 @@ continuous batching on accelerator'), built XLA-first:
 - exactly two compiled programs after warmup — ``_unified_fn`` (flat mixed batch:
   several sequences' prefill chunks + decode tokens packed into a fixed
   ``max_num_batched_tokens`` budget, the --max-num-batched-tokens analogue) and
-  ``_decode_multi_fn`` (fixed slot batch, k fused decode iterations under
-  ``lax.scan``) — both static-shaped; the host scheduler packs work into them,
+  ``_decode_multi_fn`` (fixed slot batch, up to k fused decode iterations in
+  one device loop whose length the host works out for each call from its
+  rows' budgets, ``decode_call_steps``) — both static-shaped; the host
+  scheduler packs work into them,
 - prefill batches ACROSS sequences: 32 arriving requests chunk-prefill together up
   to the token budget instead of one sequence per step,
 - prefill never pays the [N, vocab] logits matmul — only each sequence's last
@@ -137,8 +139,39 @@ TURN_PARTS = ("route", "tail")
 # Fused-decode calls kept in flight: one behind the running one, so the device
 # goes back-to-back while the finished call's tokens cross back to the host.
 # Costs up to DECODE_CHAIN_DEPTH * decode_steps speculative tokens a sequence
-# at EOS.
+# at a stop token or stop string, which the host cannot foresee; an ending by
+# max_tokens or max_model_len it can, and ``decode_call_steps`` ends the call
+# there.
 DECODE_CHAIN_DEPTH = 2
+
+# The fewest steps a fused call is given while a row has more than that left.
+# A chain's start costs the host a read, an apply, a deliver and a full pack
+# (6-9 ms a call and 2.4-4.4 ms of deliver on the chip's host, PERF.md
+# section 5: about one device step), which a call of a step or two cannot
+# amortise. From the sweep of 2, 4, 8 and 16 on the chip (PERF.md section 6):
+# the shorter the call, the sooner a finished row's seat and answer are free,
+# and the more starts a token pays for.
+DECODE_MIN_STEPS = 4
+
+
+def decode_call_steps(left: list[int], cap: int) -> tuple[int, str]:
+    """How many steps the next fused call runs, and what set that number.
+
+    ``left`` holds, for each row of the call, the tokens it may still take
+    once the calls in flight have landed (``max_tokens`` and ``max_model_len``
+    both counted; zero or less: the calls in flight already end the row, and
+    it takes no step of this one). The call ends where its first row is known
+    to end, so that row's answer leaves and its seat is free: ``n`` is the
+    smallest positive entry, no less than ``DECODE_MIN_STEPS``, no more than
+    ``cap`` (``EngineConfig.decode_steps``) and no more than the largest
+    entry, past which no row has a step to take. The second value is the
+    ``bound`` label of ``llmd_tpu:decode_call_steps_total``: ``ending`` (a
+    row's own budget), ``floor`` or ``cap``."""
+    live = [r for r in left if r > 0]
+    r_min, r_max = min(live, default=cap), max(live, default=cap)
+    n = min(max(r_min, DECODE_MIN_STEPS), r_max, cap)
+    return n, ("cap" if n == cap else
+               "floor" if r_min < n < r_max else "ending")
 
 
 class _StepParts:
@@ -820,16 +853,42 @@ class LLMEngine:
                 return pos
             return jnp.where(i < steps_left, pos, -1)
 
+        def _fused_steps(body, carry, steps_left):
+            """Run a fused call's ``body(carry, i) -> (carry, (tokens [B],
+            expert counts, drops))`` for ``max(steps_left)`` steps, at most
+            ``k_steps``: the call is as long as its longest row, which the
+            host decides (``decode_call_steps``), and the program is one
+            whatever that length. Returns the last carry, the tokens by step
+            ``[k_steps, B]`` (zeros from the first step that did not run),
+            and the counts and drops summed over the steps that ran."""
+            n_steps = jnp.minimum(jnp.max(steps_left), k_steps)
+            tok, cnt, drop = jax.eval_shape(
+                lambda c: body(c, jnp.int32(0))[1], carry)
+
+            def step(i, st):
+                carry, toks_out, cnts, drops = st
+                carry, (nxt, cnt, drop) = body(carry, i)
+                return (carry, toks_out.at[i].set(nxt), cnts + cnt,
+                        drops + drop)
+
+            return jax.lax.fori_loop(
+                0, n_steps, step,
+                (carry, jnp.zeros((k_steps,) + tok.shape, tok.dtype),
+                 jnp.zeros(cnt.shape, cnt.dtype),
+                 jnp.zeros(drop.shape, drop.dtype)))
+
         def _decode_multi(params, cache, tokens, positions, page_tables, kv_lens,
                           temp, top_k, top_p, key, steps_left, lora_idx):
-            """k decode iterations fused on-device (lax.scan): feed sampled token back
-            each step; one host round-trip per k tokens instead of per token.
+            """Up to k decode iterations fused on-device (``_fused_steps``):
+            feed the sampled token back each step; one host round-trip a call
+            instead of per token.
 
             ``steps_left [B]`` caps each row device-side (0 = idle slot): rows
             freeze once their per-row budget (max_tokens / max_model_len
-            remaining) is spent, so a fused call may safely overrun a sequence's
-            end — required by the pipelined dispatch path, where the host reads
-            results one call behind.
+            remaining, clipped to the length the host gave the call) is spent,
+            so a fused call may safely overrun a sequence's end — required by
+            the pipelined dispatch path, where the host reads results one call
+            behind — and the call ends with its longest row.
             """
             tokens = _bind(tokens, "dp")
             positions = _bind(positions, "dp")
@@ -859,15 +918,13 @@ class LLMEngine:
                 lens = jnp.where(act, lens + 1, lens)
                 return (cache, nxt, pos, lens, key), (nxt, cnt, drop)
 
-            (cache, last_toks, pos_out, lens_out, _), (toks_out, cnts, drops) = jax.lax.scan(
-                body, (cache, tokens, positions, kv_lens, key),
-                jnp.arange(k_steps, dtype=jnp.int32),
-            )
+            (cache, last_toks, pos_out, lens_out, _), toks_out, cnts, drops = (
+                _fused_steps(body, (cache, tokens, positions, kv_lens, key),
+                             steps_left))
             # last_toks/pos_out/lens_out: device-resident chain point for the
             # next pipelined call — a chained dispatch reuses them instead of
             # re-packing positions and kv lens on the host
-            return (toks_out, last_toks, pos_out, lens_out, cache, cnts.sum(0),
-                    drops.sum())
+            return (toks_out, last_toks, pos_out, lens_out, cache, cnts, drops)
 
         def _decode_multi_masked(params, cache, tokens, positions, page_tables,
                                  kv_lens, temp, top_k, top_p, key, steps_left,
@@ -880,7 +937,7 @@ class LLMEngine:
             ``next_tab [G, S, V] i32``. Slot 0 of both tables is the zero
             no-op grammar, so unconstrained rows ride along unbiased.
 
-            The FSM state is part of the scan carry and of the return value:
+            The FSM state is part of the loop's carry and of the return value:
             a chained dispatch passes the previous call's ``fsm_out`` back in,
             keeping the automaton device-resident for the whole chain. Frozen
             rows (``steps_left`` spent) hold their state, mirroring the
@@ -918,14 +975,12 @@ class LLMEngine:
                 lens = jnp.where(act, lens + 1, lens)
                 return (cache, nxt, pos, lens, key, st), (nxt, cnt, drop)
 
-            (cache, last_toks, pos_out, lens_out, _, fsm_out), (toks_out, cnts,
-                                                                drops) = (
-                jax.lax.scan(
-                    body, (cache, tokens, positions, kv_lens, key, fsm_state),
-                    jnp.arange(k_steps, dtype=jnp.int32),
-                ))
+            ((cache, last_toks, pos_out, lens_out, _, fsm_out), toks_out, cnts,
+             drops) = _fused_steps(
+                body, (cache, tokens, positions, kv_lens, key, fsm_state),
+                steps_left)
             return (toks_out, last_toks, pos_out, lens_out, fsm_out, cache,
-                    cnts.sum(0), drops.sum())
+                    cnts, drops)
 
         def _embed(params, cache, tokens, positions, page_tables, kv_lens,
                    cu_q_lens, lora_idx):
@@ -2405,6 +2460,13 @@ class LLMEngine:
         change (finish, preemption, new prefill) flushes first. The
         unpipelined reading is ``_flush_pending_decode()`` after every step.
 
+        A call runs ``n <= cfg.decode_steps`` steps, worked out for each call
+        from the budgets of its rows and the steps in flight
+        (``decode_call_steps``): it ends where its first row is known to end,
+        so a flush waits out short calls and a finished row's seat is free
+        at once. An ending the host cannot foresee (a stop token) is
+        speculated past, up to ``DECODE_CHAIN_DEPTH`` calls.
+
         ``parts`` times the dispatch side (plan here, the rest in
         ``_decode_dispatch``); it is paused around every nested program,
         which keeps its own.
@@ -2414,41 +2476,33 @@ class LLMEngine:
             parts.to(None)
             self._flush_pending_decode()
             return
-        B = self.cfg.max_batch_size
         k = max(1, self.cfg.decode_steps)
         q = self._pending_decode
         off = sum(rec["k"] for rec in q)
 
         # The host knows every row's HARD budget (max_tokens / max_model_len)
         # without any device read: if the steps already in flight cover it for
-        # every row, one more speculative call would run k scan steps of
-        # fully-masked compute — measured as 2 wasted calls (64 of 192
+        # every row, one more speculative call would run its steps on rows
+        # that are all spent — measured as 2 wasted calls (64 of 192
         # step-slots) per request wave at OSL 128 / k=32. Drain the oldest
         # call instead; its results change membership and the normal flush
         # path takes over. Checked BEFORE _ensure_pages so a provably-useless
         # call cannot demand pages (or degrade to a unified step) either.
         # (EOS-before-budget still speculates — that is the pipeline's
         # purpose; this clamp only removes provably-useless calls.)
-        if q:
-            horizon = max(
-                min(s.max_tokens - (len(s.token_ids) + off - s.prompt_len),
-                    self.cfg.max_model_len - (len(s.token_ids) + off))
-                for s in active)
-            if horizon <= 0:
-                parts.to(None)
-                self._decode_process(q.pop(0))
-                return
+        left = self._decode_budgets(active, off)
+        if q and max(left) <= 0:
+            parts.to(None)
+            self._decode_process(q.pop(0))
+            return
+        # the same budgets say where the call's first row ends: the call is
+        # that long, within DECODE_MIN_STEPS and k (decode_call_steps)
+        n, bound = decode_call_steps(left, k)
 
-        # A k-step scan writes KV for positions len-1 .. len+off+k-2 → needs
-        # len+off+k-1 slots. If the pool can't cover the horizon, flush and
-        # degrade to a single unified step (decode rows only) rather than
-        # preempting sequences that could progress.
-        ok = all(
-            self._ensure_pages(
-                s, min(len(s.token_ids) + off + k - 1, self.cfg.max_model_len))
-            for s in active if s.slot >= 0
-        )
-        if not ok:
+        # If the pool can't cover the call's steps, flush and degrade to a
+        # single unified step (decode rows only) rather than preempting
+        # sequences that could progress.
+        if not self._reserve_decode_pages(active, off, n):
             parts.to(None)
             self._flush_pending_decode()
             self._step_unified()
@@ -2470,8 +2524,8 @@ class LLMEngine:
             same = {(s.request_id, s.slot) for s in active} == {
                 (s.request_id, slot) for s, slot in q[-1]["rows"]}
             if same:
-                rec = self._decode_dispatch(active, k, chain=q[-1], parts=parts,
-                                            off=off)
+                rec = self._decode_dispatch(active, n, bound, chain=q[-1],
+                                            parts=parts, off=off)
                 q.append(rec)
                 # keep DECODE_CHAIN_DEPTH calls in flight: the queued call
                 # behind the running one lets the device go back-to-back while
@@ -2486,7 +2540,34 @@ class LLMEngine:
             active = [s for s in self._decode_ready() if s.slot >= 0]
             if not active:
                 return
-        q.append(self._decode_dispatch(active, k, chain=None, parts=parts))
+            # the flush landed the chain's tokens and retired the rows they
+            # ended: the new chain's first call is as long as the rows that
+            # stay allow, which may be longer than the pages reserved above
+            n, bound = decode_call_steps(self._decode_budgets(active, 0), k)
+            if not self._reserve_decode_pages(active, 0, n):
+                parts.to(None)
+                self._step_unified()
+                return
+        q.append(self._decode_dispatch(active, n, bound, chain=None,
+                                       parts=parts))
+
+    def _decode_budgets(self, active: list[Sequence], off: int) -> list[int]:
+        """The tokens each row may still take once the ``off`` steps in
+        flight have landed, by ``max_tokens`` and by ``max_model_len``; zero
+        or less for a row those steps already end."""
+        cap = self.cfg.max_model_len
+        return [min(s.max_tokens - (len(s.token_ids) + off - s.prompt_len),
+                    cap - (len(s.token_ids) + off)) for s in active]
+
+    def _reserve_decode_pages(self, active: list[Sequence], off: int,
+                              n: int) -> bool:
+        """Pages for a fused call of ``n`` steps behind ``off`` steps in
+        flight: it writes KV for positions len-1 .. len+off+n-2, so a row
+        needs len+off+n-1 slots. False when the pool cannot give them."""
+        return all(
+            self._ensure_pages(
+                s, min(len(s.token_ids) + off + n - 1, self.cfg.max_model_len))
+            for s in active if s.slot >= 0)
 
     def _flush_pending_decode(self) -> None:
         q, self._pending_decode = self._pending_decode, []
@@ -3044,11 +3125,15 @@ class LLMEngine:
             self.stats.n_decode_dispatches % len(self._pack_bufs)]
 
     @_profile_phase("llmd.decode_dispatch")
-    def _decode_dispatch(self, active: list[Sequence], k: int, chain: Optional[dict],
-                         parts: _StepParts, off: int = 0) -> dict:
+    def _decode_dispatch(self, active: list[Sequence], k: int, bound: str,
+                         chain: Optional[dict], parts: _StepParts,
+                         off: int = 0) -> dict:
         """Pack host state (+ the un-processed offset across ALL in-flight calls)
-        and launch one fused k-step decode chained on ``chain``'s device-resident
-        outputs. Returns the in-flight record; results are NOT read.
+        and launch one fused decode call of ``k`` steps (``decode_call_steps``'
+        length and what set it, ``bound``) chained on ``chain``'s
+        device-resident outputs: every row's ``steps_left`` is clipped to
+        ``k``, and the program runs as long as its longest row. Returns the
+        in-flight record; results are NOT read.
 
         Two pack regimes (PERF.md Lever 12):
 
@@ -3071,6 +3156,7 @@ class LLMEngine:
         # the fast path's pack already sits in llmd.pack_overlap
         parts.to("pack", annotate=not fast)
         ctx_lens: list[int] = []  # context the call's first step reads, by row
+        budgets = self._decode_budgets(active, off)
         if fast:
             with jax.profiler.TraceAnnotation("llmd.pack_overlap"):
                 steps_left = self._pack_buf()["steps_left"]
@@ -3078,13 +3164,8 @@ class LLMEngine:
                 sig = chain["pages_sig"]
                 pages_changed = False
                 for j, s in enumerate(active):
-                    i = s.slot
-                    eff_len = len(s.token_ids) + off  # host view + in-flight
-                    ctx_lens.append(eff_len)
-                    gen = eff_len - s.prompt_len
-                    steps_left[i] = max(0, min(s.max_tokens - gen,
-                                               self.cfg.max_model_len - eff_len,
-                                               k))
+                    ctx_lens.append(len(s.token_ids) + off)  # host view + in-flight
+                    steps_left[s.slot] = max(0, min(budgets[j], k))
                     if len(s.pages) != sig[j]:
                         pages_changed = True
                 if pages_changed:
@@ -3118,7 +3199,7 @@ class LLMEngine:
             tk = np.zeros((B,), np.int32)
             tp = np.ones((B,), np.float32)
             toks = np.zeros((B,), np.int32)
-            for s in active:
+            for s, budget in zip(active, budgets):
                 i = s.slot
                 eff_len = len(s.token_ids) + off  # host view + in-flight tokens
                 ctx_lens.append(eff_len)
@@ -3129,16 +3210,14 @@ class LLMEngine:
                 lora_idx[i] = self._lora_slot(s)
                 sp: SamplingParams = s.sampling
                 temp[i], tk[i], tp[i] = sp.temperature, sp.top_k, sp.top_p
-                gen = eff_len - s.prompt_len
-                steps_left[i] = max(0, min(s.max_tokens - gen,
-                                           self.cfg.max_model_len - eff_len, k))
+                steps_left[i] = max(0, min(budget, k))
             pages_sig = tuple(len(s.pages) for s in active)
             parts.to("stage")
             mask = (self._stage_chain_masks(active)
                     if any(s.structured is not None or s.logit_bias
                            for s in active) else None)
             fsm_in = mask["fsm0"] if mask is not None else None
-            # the branch each of the call's k scan steps takes in the
+            # the branch each of the call's k steps takes in the
             # sampler, by the test the unified step makes (_sampling_state)
             sampler_path = ("biased" if mask is not None else
                             "topk" if (temp > 0.0).any() else "argmax")
@@ -3194,6 +3273,7 @@ class LLMEngine:
         self.metrics.program_rows.labels(program=prog).inc(len(active))
         self.metrics.sampler_steps.labels(program="decode",
                                           path=sampler_path).inc(k)
+        self.metrics.decode_call_steps.labels(bound=bound).inc(k)
         if chain is not None:
             self.stats.n_chained_dispatches += 1
         # Start the device->host copy of everything _decode_process will read:
@@ -3207,7 +3287,7 @@ class LLMEngine:
                 arr.copy_to_host_async()
             except (AttributeError, RuntimeError):
                 break
-        # analytic cost of this call, from its packed shape: the scan runs k
+        # analytic cost of this call, from its packed shape: the loop runs k
         # steps over all B slots (masked rows compute too), each step streams
         # the weights once and each active row reads its resident KV per step.
         # Stashed on the rec; _decode_process joins it with the measured wall
@@ -3225,7 +3305,8 @@ class LLMEngine:
             "util_cost": util_cost,
             "rows": [(s, s.slot) for s in active], "prog": prog,
             "toks_out": toks_out, "last_toks": last_toks, "cnt": cnt, "k": k,
-            "moe_drop": moe_drop,
+            # by slot; a copy: the fast path's array is a pack buffer
+            "steps": steps_left.copy(), "moe_drop": moe_drop,
             # device-resident chain point for the next pipelined dispatch
             "pos_out": pos_out, "lens_out": lens_out, "fsm_out": fsm_out,
             "mask": mask, "pts_np": pts_np, "pts_dev": pts_dev,
@@ -3251,13 +3332,16 @@ class LLMEngine:
             self._moe_record(rec["moe_drop"], rec["cnt"])
         parts.to("apply")
         now = time.monotonic()
+        k, steps = rec["k"], rec["steps"]
         for s, slot in rec["rows"]:
             if s.finished or s.slot != slot or self.running[slot] is not s:
                 continue  # aborted / preempted / replaced while in flight
-            new = [int(t) for t in toks_out[:, slot]]
+            # a row's tokens are the first steps[slot] of its column: the
+            # steps it was given, at most the call's k. Past them the buffer
+            # holds no token (zeros from the step the call ended at)
             kept: list[int] = []
             finished, reason = False, None
-            for t in new:
+            for t in toks_out[:steps[slot], slot].tolist():
                 kept.append(t)
                 s.token_ids.append(t)
                 finished, reason = self._check_finish(s, t)
@@ -3305,17 +3389,18 @@ class LLMEngine:
         self.programs.record_complete(rec["prog"])
         if n_tokens:
             self.metrics.decode_tokens.inc(n_tokens)
-        # the call ran k steps on every seat: tokens kept, step-slots of rows
-        # whose sequence finished before the k-th step or left in flight,
-        # and the slots of seats that held no row
-        k, n_rows = rec["k"], len(rec["rows"])
+        # the call ran its k steps (rec["k"]: the length it was given) on
+        # every seat: tokens kept, step-slots of rows whose sequence finished
+        # before the k-th step or left in flight, and the slots of seats that
+        # held no row
+        n_rows = len(rec["rows"])
         seats = self.metrics.decode_seat_steps
         seats.labels(outcome="kept").inc(n_tokens)
         seats.labels(outcome="finished").inc(k * n_rows - n_tokens)
         seats.labels(outcome="empty").inc(
             k * (self.cfg.max_batch_size - n_rows))
         if self.util is not None and rec.get("util_cost") is not None:
-            # kept tokens commit; everything else the B x k scan computed
+            # kept tokens commit; everything else the B x k loop computed
             # (masked slots, post-EOS steps, rows preempted in flight) is the
             # padding residual
             self.util.record(

@@ -2,7 +2,7 @@
 
 One program for the whole decode batch; per-slot parameters arrive as arrays so a mixed
 batch (greedy + sampled + different temperatures) is a single XLA launch. The step
-programs inline it (jit-in-jit): the unified step and each scan step of the fused
+programs inline it (jit-in-jit): the unified step and each step of the fused
 decode call pick their own tokens.
 """
 
@@ -99,7 +99,7 @@ def sample_tokens_biased(
     argmax/sample — the grammar-mask / logit_bias path (llmd_tpu/structured).
     Also inlined (jit-in-jit) by the fused masked decode program
     (engine.py `_decode_multi_masked`), which gathers each row's bias from
-    the staged dense tables per scan step — same sampler, bitwise-identical
+    the staged dense tables per step — same sampler, bitwise-identical
     tokens whether the bias rides a unified step or a device chain.
     After a unified step it is a program of its own, over the step's logits
     and the host-built bias, so engines that never see a structured request

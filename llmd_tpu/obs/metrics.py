@@ -524,11 +524,21 @@ class EngineMetrics:
             "EngineOutputs the loop handed to a request stream")
         self.decode_seat_steps = reg.counter(
             "llmd_tpu:decode_seat_steps_total",
-            "Step-slots of fused decode calls (k steps x max_batch_size "
-            "seats each) by outcome: kept (a token the request got), "
-            "finished (the row's sequence ended before the k-th step, or "
-            "left while the call was in flight), empty (the seat held no row)",
+            "Step-slots of fused decode calls (the n steps a call was given "
+            "x max_batch_size seats) by outcome: kept (a token the request "
+            "got), finished (the row's sequence ended before the n-th step, "
+            "or left while the call was in flight), empty (the seat held no "
+            "row)",
             labelnames=("outcome",))
+        self.decode_call_steps = reg.counter(
+            "llmd_tpu:decode_call_steps_total",
+            "Steps given to fused decode calls (n a dispatched call, at most "
+            "decode_steps) by what set n: ending (the first row the host "
+            "knows to end, by max_tokens or max_model_len), floor (that "
+            "ending was nearer than DECODE_MIN_STEPS), cap (no row ends "
+            "within decode_steps). Over engine_program_dispatches_total of "
+            "the decode programs: steps a call",
+            labelnames=("bound",))
         self.unified_decode_rows = reg.counter(
             "llmd_tpu:unified_decode_rows_total",
             "Decode rows of unified steps by where the row's input token "

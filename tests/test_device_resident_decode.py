@@ -16,12 +16,16 @@ import re
 
 import conftest  # noqa: F401
 import numpy as np
+import pytest
 
 from llmd_tpu.core.request import SamplingParams
 from llmd_tpu.engine import EngineConfig, LLMEngine
+from llmd_tpu.engine.engine import DECODE_MIN_STEPS
 from llmd_tpu.engine.tokenizer import ByteTokenizer
 from llmd_tpu.models import get_model_config
 from llmd_tpu.structured import GrammarCache, compile_grammar
+from tests.test_pipeline_decode import poison_tail
+from tests.test_step_tracing import _samples
 
 TOK = ByteTokenizer()
 CHOICES = ["red", "green", "blue"]
@@ -215,3 +219,43 @@ def test_dense_tables_match_host_automaton():
             adv = g.advance(s, int(tid))
             assert nxt[s, tid] == (s if adv is None else adv), (s, int(tid))
     assert g.dense_tables() is g.dense_tables()  # cached on the grammar
+
+
+# ---------------------------------------------------- ISSUE 38: a call's length
+CAP = 2 * DECODE_MIN_STEPS  # decode_steps over the floor: the rule can act
+
+
+@pytest.mark.parametrize("poison", [False, True])
+def test_masked_calls_of_unequal_lengths_match_unified_degrade(poison):
+    """The masked fused program under calls whose lengths the host works out
+    (budgets 8 and 16 among rows that stop on the grammar's EOS, which the
+    host cannot foresee): tokens as the 1-token unified degrade gives them,
+    FSM state carried across calls of unequal lengths, and with ``poison``
+    every entry of the token buffer that is no token of its row (past the
+    call's length, past the row's steps) overwritten: none may be read."""
+    outs = []
+    for fused in (True, False):
+        eng = (_engine(decode_steps=CAP) if fused
+               else _engine(decode_steps=CAP, structured_table_max_elems=1))
+        if fused and poison:
+            eng._decode_multi_masked_fn = poison_tail(
+                eng._decode_multi_masked_fn, TOK.encode("~")[0])
+        _add_mixed(eng)
+        eng.add_request("long", TOK.encode("emit bits"),
+                        _sp(max_tokens=2 * CAP + 3,
+                            guided_regex=r"[ab]{%d}" % (2 * CAP + 2)))
+        toks, fins = _drain(eng)
+        outs.append(toks)
+        assert eng.stats.structured_violations == 0
+        assert fins["choice"] == "stop" and fins["regex"] == "stop"
+        assert fins["long"] == "stop"
+        if fused:
+            assert eng.stats.structured_chain_stages > 0
+            bounds = _samples(eng.registry,
+                              "llmd_tpu:decode_call_steps_total")
+            assert len(bounds) > 1, bounds
+    assert outs[0] == outs[1], "fused masked decode diverged from host path"
+    assert TOK.decode(outs[0]["bias"]) == "zzzzzzzz"
+    assert len(outs[0]["long"]) == 2 * CAP + 3  # the grammar's EOS the last
+    assert re.fullmatch(r"[ab]{%d}" % (2 * CAP + 2),
+                        TOK.decode(outs[0]["long"]))

@@ -19,8 +19,10 @@ import pytest
 
 from llmd_tpu.core.request import SamplingParams
 from llmd_tpu.engine import EngineConfig, LLMEngine
+from llmd_tpu.engine.engine import DECODE_MIN_STEPS as M, decode_call_steps
 from llmd_tpu.models import get_model_config
 from tests.test_pipeline_prefill_sample import drive, generate
+from tests.test_step_tracing import _samples, _total
 
 
 def _cfg(**kw) -> EngineConfig:
@@ -225,3 +227,221 @@ def test_chain_equals_flush_every_step(case):
         assert got["r1"] == [7] * 11
     else:
         assert [len(got[f"r{i}"]) for i in range(4)] == budgets
+
+
+# ------------------------------------------------- the length of a fused call
+# A call runs n <= decode_steps steps, n worked out by the host from its rows'
+# budgets and the steps in flight (engine.decode_call_steps). K is a cap well
+# over DECODE_MIN_STEPS, so that all three bounds can set n.
+K = 16
+
+
+@pytest.mark.parametrize("left,cap,want", [
+    # no row ends within the cap: the call is the cap long
+    ([40, 99, 17], K, (K, "cap")),
+    ([K, 99], K, (K, "cap")),
+    # the first ending sets it
+    ([K - 1, 99], K, (K - 1, "ending")),
+    ([99, M + 3, 40], K, (M + 3, "ending")),
+    ([M, 99], K, (M, "ending")),
+    # an ending nearer than the floor: the floor
+    ([M - 1, 99], K, (M, "floor")),
+    ([1, 99, 3], K, (M, "floor")),
+    # rows the steps in flight already end take no part
+    ([0, -5, M + 2, 99], K, (M + 2, "ending")),
+    ([0, 2, 99], K, (M, "floor")),
+    ([-3, 99], K, (K, "cap")),
+    # no row has a step past the largest budget: the call ends there
+    ([2, M - 1], K, (M - 1, "ending")),
+    ([M - 1], K, (M - 1, "ending")),
+    ([0, M, -1], K, (M, "ending")),
+    # a cap at or under the floor is the length whenever a row outlasts it
+    ([1, 99], 4, (4, "cap")),
+    ([1, 99], M, (M, "cap")),
+    ([1, 3], 4, (3, "ending")),
+    ([99], 1, (1, "cap")),
+])
+def test_call_length_rule(left, cap, want):
+    """n = clamp(r_min, DECODE_MIN_STEPS, cap) over the rows that still have
+    a step, never past the last row's budget; the label says what set it."""
+    assert decode_call_steps(left, cap) == want
+
+
+def _staggered(sync: bool, case: str):
+    """Six rows over the budgets that sit on every edge of the rule, two
+    arrivals so that chains start, hold and break; ``sync`` is the same
+    engine read after every step."""
+    from tests.test_structured import TOK
+
+    greedy = dict(temperature=0.0, ignore_eos=True)
+    budgets = [1, M - 1, M, K - 1, K, 3 * K + 1]
+    kw = dict(max_batch_size=6, decode_steps=K, num_pages=256)
+    model, seed = "tiny", 0
+    prompts = [PROMPTS[i % 4][: 20 + 3 * i] for i in range(6)]
+    sps = [SamplingParams(max_tokens=n, **greedy) for n in budgets]
+    arrivals = (0, 0, 0, 2, 2, 2)
+    if case == "model_len":
+        # r5 is cut by the model's length, 9 tokens short of its budget
+        kw["max_model_len"] = len(prompts[5]) + 3 * K + 1 - 9
+    elif case == "recurrent":
+        model, seed = "tiny-jamba", 3
+        kw.update(page_size=4, max_model_len=96)
+    elif case == "masked":
+        prompts[:2] = [TOK.encode("emit bits"), TOK.encode("say")]
+        sps[0] = SamplingParams(max_tokens=3 * K, temperature=0.0,
+                                guided_regex=r"[ab]{%d}" % (3 * K - 2),
+                                stop_token_ids=(TOK.eos_id,))
+        sps[1] = SamplingParams(max_tokens=M + 3,
+                                logit_bias={7: 5.0, 9: -100.0}, **greedy)
+    elif case == "prefill_mid_chain":
+        arrivals = (0, 0, 0, 0, 0, 5)  # r5 arrives while a chain is running
+        budgets[5] = M + 2
+        sps[5] = SamplingParams(max_tokens=M + 2, **greedy)
+    eng = LLMEngine(get_model_config(model), _cfg(**kw), seed=seed,
+                    tokenizer=TOK)
+    rows: dict[int, list] = {}
+    for i, at in enumerate(arrivals):
+        rows.setdefault(at, []).append((f"r{i}", prompts[i], sps[i]))
+    return drive(eng, oracle=sync, unchained=sync, arrivals=rows), eng, budgets
+
+
+@pytest.mark.parametrize("case", ["budgets", "stop", "model_len", "masked",
+                                  "recurrent", "prefill_mid_chain"])
+def test_call_length_keeps_the_streams(case):
+    """Calls of unequal lengths give the tokens the unchained engine gives:
+    across the budgets on every edge of the rule, a stop token the host
+    cannot foresee, the model-length cap, a masked (grammar) batch, a model
+    with recurrent layers (a spent row must not step its state) and a prefill
+    that arrives mid-chain."""
+    if case == "stop":
+        # seed 4 keeps the first greedy tokens distinct (see above); the stop
+        # token is r0's token M + 2: past the first call of a chain
+        sp = SamplingParams(max_tokens=3 * K, temperature=0.0, ignore_eos=True)
+        kw = dict(decode_steps=K, num_pages=256)
+        probe, _ = _run(PROMPTS[:2], sp, False, seed=4, **kw)
+        stop_tok = probe["req-0"][M + 1]
+        at = probe["req-0"].index(stop_tok)
+        sp = SamplingParams(max_tokens=3 * K, temperature=0.0,
+                            stop_token_ids=(stop_tok,))
+        got, eng = _run(PROMPTS[:2], sp, True, seed=4, **kw)
+        ref, _ = _run(PROMPTS[:2], sp, False, seed=4, **kw)
+        assert got == ref
+        assert got["req-0"][-1] == stop_tok and len(got["req-0"]) == at + 1
+        return
+    got, eng, budgets = _staggered(False, case)
+    ref, ref_eng, _ = _staggered(True, case)
+    assert got == ref and len(got) == 6
+    assert eng.stats.n_chained_dispatches > 0
+    assert ref_eng.stats.n_chained_dispatches == 0
+    steps = _samples(eng.registry, "llmd_tpu:decode_call_steps_total")
+    # calls shorter than the cap ran
+    assert sum(steps.values()) < K * eng.stats.n_decode_dispatches, steps
+    lens = [len(got[f"r{i}"]) for i in range(6)]
+    if case == "model_len":
+        assert lens == budgets[:5] + [budgets[5] - 9]
+    elif case == "masked":
+        assert eng.stats.structured_violations == 0
+        assert re.fullmatch(r"[ab]{%d}" % (3 * K - 2), eng.tokenizer.decode(
+            got["r0"][:-1] if got["r0"][-1] == eng.tokenizer.eos_id
+            else got["r0"]))
+        assert got["r1"] == [7] * len(got["r1"]) and lens[2:] == budgets[2:]
+    else:
+        assert lens == budgets
+
+
+POISON = 191  # a token id of the tiny vocabulary that no greedy stream holds
+
+
+def poison_tail(fn, token: int):
+    """A fused program whose token buffer holds ``token`` wherever it holds
+    no token of its row: at and past the call's length, and in a row's column
+    past the steps the row was given (``steps_left``, argument 10)."""
+    import jax.numpy as jnp
+
+    def poisoned(*args):
+        toks_out, *rest = fn(*args)
+        ran = jnp.arange(toks_out.shape[0])[:, None] < args[10][None, :]
+        return (jnp.where(ran, toks_out, token), *rest)
+
+    return poisoned
+
+
+@pytest.mark.parametrize("case", ["budgets", "prefill_mid_chain"])
+def test_no_token_past_a_rows_steps_reaches_the_sequence(case, monkeypatch):
+    """The buffer's rows at and past a call's length, and a row's column past
+    the steps it was given, are not tokens: poisoned here, none may reach
+    ``token_ids``, and the streams stay the unchained engine's."""
+    ref, _, budgets = _staggered(True, case)
+    assert not any(POISON in v for v in ref.values())
+    init = LLMEngine.__init__
+
+    def poisoned_init(self, *a, **kw):
+        init(self, *a, **kw)
+        self._decode_multi_fn = poison_tail(self._decode_multi_fn, POISON)
+
+    monkeypatch.setattr(LLMEngine, "__init__", poisoned_init)
+    got, eng, _ = _staggered(False, case)
+    assert eng.stats.n_chained_dispatches > 0
+    assert got == ref
+    assert not any(POISON in s.token_ids[s.prompt_len:]
+                   for s in eng.seqs.values())
+
+
+def test_counters_add_up_over_calls_of_unequal_lengths():
+    """``off`` (the steps in flight), the seat ledger, the sampler's steps and
+    the call-length counter all count the n steps a call was given: over a
+    chain of calls of unequal lengths they agree with each other and with
+    the tokens delivered."""
+    got, eng, budgets = _staggered(False, "budgets")
+    seats = _samples(eng.registry, "llmd_tpu:decode_seat_steps_total")
+    steps = sum(_samples(eng.registry,
+                         "llmd_tpu:decode_call_steps_total").values())
+    sampler = _total(eng.registry, "llmd_tpu:sampler_steps_total",
+                     'program="decode"')
+    calls = eng.stats.n_decode_dispatches
+    assert calls == eng.stats.n_decode_calls > 3
+    # calls of unequal lengths ran: fewer steps than calls x the cap
+    assert calls < steps < calls * K
+    assert steps == sampler
+    assert sum(seats.values()) == steps * eng.cfg.max_batch_size
+    assert seats['{outcome="kept"}'] == eng.stats.decode_tokens_fused
+    # every token came from a unified step (a row's first) or a fused call
+    assert sum(budgets) == sum(len(v) for v in got.values())
+    assert eng.stats.decode_tokens_fused <= sum(budgets) - len(budgets)
+
+
+def test_off_counts_the_steps_given(monkeypatch):
+    """A chained call is packed from the host's view plus the steps in
+    flight: with calls of unequal lengths in flight, ``off`` must be the sum
+    of their lengths, or a row's position would slip."""
+    seen = []
+    dispatch = LLMEngine._decode_dispatch
+
+    def spy(self, active, k, bound, chain, parts, off=0):
+        seen.append((k, bound, off, [r["k"] for r in self._pending_decode]))
+        return dispatch(self, active, k, bound, chain, parts, off)
+
+    monkeypatch.setattr(LLMEngine, "_decode_dispatch", spy)
+    _staggered(False, "budgets")
+    assert all(off == sum(ks) for _, _, off, ks in seen)
+    assert len({k for k, *_ in seen}) > 1, seen  # unequal lengths
+    assert any(off and k != ks[-1] for k, _, off, ks in seen), seen
+
+
+def test_one_program_whatever_the_length():
+    """The length is a value, not a shape: calls of different lengths hit
+    one compiled executable of the fused program."""
+    eng = LLMEngine(get_model_config("tiny"),
+                    _cfg(decode_steps=K, num_pages=256))
+    sp = [SamplingParams(max_tokens=n, temperature=0.0, ignore_eos=True)
+          for n in (K + 3, 3 * K)]
+    for i, s in enumerate(sp):
+        eng.add_request(f"r{i}", PROMPTS[i], s)
+    while eng.has_work():
+        eng.step()
+    steps = _samples(eng.registry, "llmd_tpu:decode_call_steps_total")
+    assert len(steps) == 3, steps  # ending, floor and cap each set a call
+    assert eng.stats.n_decode_dispatches > 3
+    assert eng.programs.compile_counts()["decode"] == 1
+    compiles = _samples(eng.registry, "llmd_tpu:program_compiles_total")
+    assert compiles['{program="decode"}'] == 1, compiles

@@ -250,7 +250,9 @@ def test_a_head_held_for_pages_is_hashed_again_at_every_step():
 def test_decode_seat_steps_exact_for_a_constructed_batch():
     """Three rows on four seats, k = 4, six tokens each: the first comes from
     the prefill's sample, five from fused calls. Unpipelined, that is two
-    calls: 4 kept a row, then 1 kept and 3 step-slots past the end."""
+    calls: 4 kept a row, then a call of one step (no row has a second token
+    to take, so the call ends there: ISSUE 38) with 1 kept a row. No
+    step-slot past a row's end is run or booked."""
     eng = _engine()
     # all three prefill in one unified step (32 tokens, its whole budget): a
     # prompt left for a second step would find the others riding in it as
@@ -263,8 +265,10 @@ def test_decode_seat_steps_exact_for_a_constructed_batch():
     got = {o: seats[f'{{outcome="{o}"}}']
            for o in ("kept", "finished", "empty")}
     assert eng.stats.n_decode_calls == 2
-    assert got == {"kept": 15.0, "finished": 9.0, "empty": 8.0}
+    assert got == {"kept": 15.0, "finished": 0.0, "empty": 5.0}
     assert got["kept"] == eng.stats.decode_tokens_fused
+    assert _samples(eng.registry, "llmd_tpu:decode_call_steps_total") == {
+        '{bound="cap"}': 4.0, '{bound="ending"}': 1.0}
 
 
 def test_decode_seat_steps_partition_every_call_when_pipelined():
@@ -272,10 +276,14 @@ def test_decode_seat_steps_partition_every_call_when_pipelined():
     prompts = [list(range(10, 30)), list(range(40, 52))]
     eng.generate(prompts, SamplingParams(max_tokens=11, **GREEDY))
     seats = _samples(eng.registry, "llmd_tpu:decode_seat_steps_total")
-    k, seats_per_call = BASE["decode_steps"], BASE["max_batch_size"]
-    assert sum(seats.values()) == k * seats_per_call * eng.stats.n_decode_calls
+    # a call books the steps it was given on every seat: 4, 4 and the 2 its
+    # rows had left
+    steps = sum(_samples(eng.registry,
+                         "llmd_tpu:decode_call_steps_total").values())
+    assert steps == 10 and eng.stats.n_decode_calls == 3
+    assert sum(seats.values()) == steps * BASE["max_batch_size"]
     assert seats['{outcome="kept"}'] == eng.stats.decode_tokens_fused
-    assert seats['{outcome="empty"}'] == k * 2 * eng.stats.n_decode_calls
+    assert seats['{outcome="empty"}'] == steps * 2
 
 
 # ------------------------------------------------------------ context tokens
