@@ -160,21 +160,21 @@ def parity_child(args) -> int:
                                "shape": list(got.shape)}
 
     if cfg.is_mla:
-        from llmd_tpu.ops.mla_decode import mla_paged_attention_latent
+        from llmd_tpu.ops.mla_attention import mla_paged_attention
 
         real = cfg.mla_kv_lora_rank + cfg.mla_rope_dim
         dhp = padded_head_dim(real)
         q, pt, pos, slots, lens, cu, ns = paged_case(
-            [1] * B, cfg.num_heads, dhp, real)
+            [8, 1, 1, 1], cfg.num_heads, dhp, real)
         cache = fill_pool(1, 1, dhp, real)
         kw = dict(scale=(cfg.mla_qk_nope_dim + cfg.mla_rope_dim) ** -0.5,
                   cu_q_lens=cu, num_seqs=ns)
-        got = jax.jit(lambda *a: mla_paged_attention_latent(
+        got = jax.jit(lambda *a: mla_paged_attention(
             *a, interpret=on_cpu, mesh=mesh, **kw))(q, cache, pt, pos, slots,
                                                     lens)
         want = jax.jit(lambda *a: ragged_paged_attention_xla(*a, **kw))(
             q, cache, pt, pos, slots, lens)
-        compare("pallas_mla_latent_decode", got, want)
+        compare("pallas_mla_ragged_paged_attention", got, want)
     elif not on_cpu:  # the upstream ragged kernel has no interpret mode
         from llmd_tpu.ops.paged_attention import paged_attention_tpu
 
@@ -220,7 +220,7 @@ def parity_child(args) -> int:
     # so the smoke can hold the server's /metrics against it
     if cfg.is_mla:
         out["expect_attn"] = ("xla_mla_absorbed" if on_cpu
-                              else "pallas_mla_latent_decode")
+                              else "pallas_mla_ragged_paged_attention")
     else:
         pack = pack_factor(cfg)
         out["expect_attn"] = (

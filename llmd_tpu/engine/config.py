@@ -66,9 +66,9 @@ class EngineConfig:
     # "reference" = gather+mask (models.transformer.ragged_paged_attention_xla).
     # A rule on the platform, never a trial compile: a selected kernel that
     # fails to compile fails the engine at its first step.
-    # MLA models: the mixed-batch programs always run the absorbed XLA impl;
-    # the fused-decode program takes the latent-width Pallas kernel
-    # (ops/mla_decode) on TPU under "auto", anywhere under "pallas".
+    # MLA models: every step program takes the latent kernel for ragged rows
+    # (ops/mla_attention) on TPU under "auto" and anywhere under "pallas"
+    # (interpreter mode on the CPU); the absorbed XLA impl is the CPU's.
     attn_impl: str = "auto"
     # Long-context sequence parallelism: when mesh.sp > 1, serve self-contained
     # single-sequence prefill steps through the zig-zag ring-attention program

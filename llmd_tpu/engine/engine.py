@@ -54,7 +54,7 @@ from llmd_tpu.engine.sampling import (
     sample_tokens,
     sample_tokens_biased,
 )
-from llmd_tpu.engine.programs import ProgramRegistry, select_decode_attn_impl
+from llmd_tpu.engine.programs import ProgramRegistry
 from llmd_tpu.engine.spec import propose_ngram_draft
 from llmd_tpu.structured import (
     NEG_BIAS,
@@ -642,7 +642,9 @@ class LLMEngine:
             # attends over chunk activations, not the pool)
             attn = make_packed_attn(attn, model_cfg, self.kv_pack)
             self.attn_backend += f"+packed{self.kv_pack}"
-        attn_decode = select_decode_attn_impl(self, attn)
+        # the fused-decode-shaped programs take the same impl: the ragged
+        # kernels (GQA and latent) serve one-row-a-sequence calls too
+        attn_decode = attn
         if model_cfg.has_recurrent and self.attn_backend.startswith("pallas"):
             # a recurrent layer carries the last bit of an attention layer's
             # result on, so a prompt's chunks are handed to the kernel cut at
@@ -1000,6 +1002,17 @@ class LLMEngine:
             return jnp.sum(hidden.astype(jnp.float32) * valid, axis=0), cache
 
         donate = dict(donate_argnums=(1,))  # cache is donated — updated in place in HBM
+        if cfg.moe_scoring == "sigmoid":
+            # Every rounding the program states is made. Left free, XLA keeps
+            # a bf16 value in float32 where it fuses producer and consumer,
+            # and what it fuses follows a program's shapes: on the chip a
+            # decode row's hidden state through the fused decode call and
+            # through the unified step parted by a bf16 step inside a scanned
+            # expert layer, the next layer chose another expert, and tokens
+            # served cold and from the prefix cache parted (PR 39, seed
+            # 2147485403). With this routing only, as `combine_in_order`: the
+            # softmax models' compiled programs stay what their cells measured
+            donate["compiler_options"] = {"xla_allow_excess_precision": False}
         # Step-program registry (engine/programs.py): every compiled program
         # is a declarative entry. Routable entries carry an eligibility
         # predicate + run hook (registration order = priority; step() is just
@@ -1074,17 +1087,23 @@ class LLMEngine:
         mode = self.cfg.attn_impl
         if self.model_cfg.is_mla:
             # Absorbed MLA runs as MQA with head_dim = latent rank + rope dim
-            # (typically 288–640 lanes) — past the GQA Pallas kernel's
-            # supported head sizes; the XLA impl handles the mixed-batch
-            # programs (unified/verify/embed) at any width. The fused-decode
-            # program upgrades to the latent-width Pallas kernel in
-            # programs.select_decode_attn_impl — decode is where the KV
-            # stream lives.
-            # xla_mla_absorbed is the DESIGNED mixed-batch backend for MLA,
-            # not a degradation — provenance lives in attn_backend alone so
-            # fallback alerts stay quiet on healthy MLA engines
-            self.attn_backend = "xla_mla_absorbed"
-            return ragged_paged_attention_xla
+            # (288-640 lanes) over the single-plane pool: past the GQA Pallas
+            # kernel's head sizes. On a TPU every step program (unified,
+            # verify, embed and the fused decode call alike) takes the latent
+            # kernel for ragged rows (ops/mla_attention); the XLA gather is
+            # the CPU reference and serves no step on the chip.
+            if mode == "reference" or (
+                    mode == "auto" and jax.default_backend() != "tpu"):
+                # the CPU's designed backend, not a degradation: the reason
+                # stays empty so that real fallbacks are observable
+                self.attn_backend = "xla_mla_absorbed"
+                return ragged_paged_attention_xla
+            from llmd_tpu.ops.mla_attention import mla_paged_attention
+
+            self.attn_backend = "pallas_mla_ragged_paged_attention"
+            return functools.partial(
+                mla_paged_attention, interpret=self._pallas_interpret,
+                mesh=self.mesh)
         if mode == "reference":
             self.attn_backend = "xla_reference"
             return ragged_paged_attention_xla
@@ -1098,14 +1117,24 @@ class LLMEngine:
         return functools.partial(paged_attention_tpu, mesh=self.mesh)
 
     def _attn_geometry(self) -> str:
-        """The (bkv, bq) block geometry the ragged Pallas kernel is traced
-        with in the two step programs that carry the load, as
-        ``unified=<bkv>x<bq> decode=<bkv>x<bq>``; ``none`` where another
-        backend serves. It is a function of static shapes, so it is known
+        """The (bkv, bq) block geometry the ragged Pallas kernel (the GQA
+        one or the latent one) is traced with in the two step programs that
+        carry the load, as ``unified=<bkv>x<bq> decode=<bkv>x<bq>``; ``none``
+        where another backend serves. It is a function of static shapes, so it is known
         here. A model with window layers adds the period of windows its
         layers are traced with (any backend), as ``window=0,4096,4096,4096``."""
         window = (" window=" + ",".join(map(str, self.model_cfg.attn_window_pattern))
                   if self.model_cfg.has_window else "")
+        programs = (("unified", self.cfg.batched_tokens),
+                    ("decode", self.cfg.max_batch_size))
+        if self.attn_backend.startswith("pallas_mla_ragged_paged_attention"):
+            from llmd_tpu.ops.mla_attention import pick_block_sizes
+
+            return " ".join(
+                "{}={}x{}".format(prog, *pick_block_sizes(
+                    n, self.cfg.max_batch_size, self.cfg.page_size,
+                    self.cfg.max_pages_per_seq))
+                for prog, n in programs)
         if not self.attn_backend.startswith("pallas_ragged_paged_attention"):
             return "none" + window
         from llmd_tpu.ops.paged_attention import call_geometry
@@ -1114,12 +1143,7 @@ class LLMEngine:
             "{}={}x{}".format(prog, *call_geometry(
                 (n, self.model_cfg.num_heads, self.cache.shape[-1]),
                 self.cache.shape, self.cfg.max_pages_per_seq))
-            for prog, n in (("unified", self.cfg.batched_tokens),
-                            ("decode", self.cfg.max_batch_size))) + window
-
-    # (the fused-decode attention-impl selector lives in
-    # llmd_tpu.engine.programs.select_decode_attn_impl — it is step-program
-    # metadata, resolved once at startup before the programs are registered)
+            for prog, n in programs) + window
 
     def _select_moe_impl(self):
         """Pick the MoE expert-GEMM path by rule: Pallas grouped GEMM for
@@ -1240,7 +1264,7 @@ class LLMEngine:
         from llmd_tpu.parallel.eplb import ExpertLoadTracker
 
         e = self.cfg.eplb
-        E, L = self.model_cfg.moe_num_experts, self.model_cfg.num_layers
+        E, L = self.model_cfg.moe_num_experts, self.model_cfg.num_moe_layers
         ep = max(1, self.cfg.mesh.ep)
         S = E + e.num_redundant_experts
         S += (-S) % ep  # slot dim shards evenly over the ep axis
@@ -1422,13 +1446,21 @@ class LLMEngine:
         self._eplb_active = True
 
     def _count_attn_kv(self, program: str, kv_lens, q_lens) -> None:
-        """``attn_kv_tokens_total`` of one dispatched call, from the lengths
-        the step already packed."""
+        """``attn_kv_tokens_total``, ``attn_query_tokens_total`` and
+        ``attn_query_key_pairs_total`` of one dispatched call, from the
+        lengths the step already packed."""
         for kind, n in attn_kv_tokens(self.model_cfg, kv_lens, q_lens,
                                       self.cfg.page_size,
                                       self._window_align).items():
             self.metrics.attn_kv_tokens.labels(program=program,
                                                layers=kind).inc(n)
+        # a row's queries are its last q tokens: query i of q sees kv - q + i
+        # + 1 keys, q * kv - q * (q - 1) / 2 in all
+        kv, q = np.asarray(kv_lens, np.int64), np.asarray(q_lens, np.int64)
+        self.metrics.attn_query_tokens.labels(program=program).inc(
+            int(q.sum()))
+        self.metrics.attn_qk_pairs.labels(program=program).inc(
+            int((q * kv - q * (q - 1) // 2).sum()))
 
     def _count_ssm_tokens(self, program: str, chunk: int, decode: int) -> None:
         """``ssm_scan_tokens_total`` of one dispatched call: the tokens one
@@ -1486,7 +1518,9 @@ class LLMEngine:
     def _moe_record(self, drop, cnt, gemm_plan=None) -> None:
         """What a step's mixture layers report. ``drop``: every routed copy
         the legacy einsum path dropped past capacity C (the sorted path
-        returns a structural 0 — moe_check asserts the scrape stays 0).
+        returns a structural 0 — moe_check asserts the scrape stays 0); under
+        sigmoid routing a vector that carries the bias's moved choices and
+        the routed copies beside it (``moe_block``).
         ``cnt`` [L, E]: routed copies by layer and expert, whose busiest
         expert over the mean, averaged over layers, is the step's
         ``moe_expert_load_max_over_mean``. Called where the step's outputs
@@ -1497,7 +1531,12 @@ class LLMEngine:
         `_moe_gemm_geometry`), for ``moe_gemm_blocks_total``."""
         if not self.model_cfg.is_moe:
             return
-        n = int(np.asarray(drop))
+        drop = np.asarray(drop)
+        if drop.ndim:  # sigmoid routing: [dropped, bias_moved, routed]
+            self.metrics.moe_bias_moved.inc(int(drop[1]))
+            self.metrics.moe_routed_copies.inc(int(drop[2]))
+            drop = drop[0]
+        n = int(drop)
         self.stats.moe_dropped_tokens += n
         self.metrics.moe_dropped_tokens.labels(
             path=self.stats.moe_dispatch or "einsum").inc(n)

@@ -26,28 +26,24 @@ n_decode_calls``). Adding a program meant touching all three.
 * ``compile_counts()`` exposes each program's jit cache size, the
   recompile-storm probe ``test_paged_attention.py`` pins for fused decode.
 
-``select_decode_attn_impl`` (the fused-decode attention-impl selector,
-formerly ``LLMEngine._select_decode_attn_impl``) lives here too: it is
-program metadata — which attention kernel the *decode-shaped* programs
-compile against — not engine state.
+(The fused-decode attention-impl selector that lived here is gone with
+PR 39: the ragged Pallas kernels, GQA and latent, serve mixed batches and
+one-row-a-sequence calls alike, so every program takes the engine's one impl.)
 """
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
-
-import jax
 
 
 @dataclass
 class ProgramSpec:
     """One registry entry: a compiled program plus its routing metadata.
 
-    ``attn`` is provenance only ("mixed" = unified-shape attention impl,
-    "decode" = the fused-decode impl from ``select_decode_attn_impl``);
-    the actual kernel was bound when the program was traced.
+    ``attn`` is provenance only ("mixed" = a unified-shape program,
+    "decode" = a fused-decode-shaped one; both bind the engine's one
+    attention impl); the actual kernel was bound when the program was traced.
     """
 
     name: str
@@ -139,35 +135,3 @@ class ProgramRegistry:
             if callable(size):
                 out[name] = size()
         return out
-
-
-def select_decode_attn_impl(engine, unified_attn):
-    """Attention impl for the FUSED-DECODE-shaped programs only.
-
-    GQA engines share the unified impl (the ragged Pallas kernel already
-    serves mixed batches). MLA engines upgrade to the latent-width Pallas
-    decode kernel (`ops.mla_decode`): the fused-decode batch is exactly
-    its shape — one query row per slot over the single-plane latent pool —
-    while unified/verify/embed (mixed chunk shapes) keep the XLA absorbed
-    reference, and ``attn_backend`` becomes ``pallas_mla_latent_decode``.
-
-    `attn_impl` semantics on MLA: "auto" takes the kernel on TPU only
-    (interpreter-mode Pallas is orders of magnitude slower than the XLA
-    reference on CPU meshes); explicit "pallas" forces it anywhere —
-    interpret mode on CPU; "reference" keeps the XLA impl everywhere. The
-    choice is a rule on the platform: a kernel that fails to compile
-    raises at the first decode step, it is never swapped for another.
-    """
-    if not engine.model_cfg.is_mla:
-        return unified_attn
-    mode = engine.cfg.attn_impl
-    if mode == "reference":
-        return unified_attn
-    if mode == "auto" and jax.default_backend() != "tpu":
-        return unified_attn
-    from llmd_tpu.ops.mla_decode import mla_paged_attention_latent
-
-    engine.attn_backend = "pallas_mla_latent_decode"
-    return functools.partial(
-        mla_paged_attention_latent,
-        interpret=engine._pallas_interpret, mesh=engine.mesh)

@@ -53,7 +53,11 @@ class ModelConfig:
     # What the recurrent SSM state is held in between steps; the conv window
     # is held in the model's dtype.
     mamba_state_dtype: str = "float32"
-    # MoE (0 experts = dense). Every layer is a mixture layer of one shape.
+    # MoE (0 experts = dense). The mixture layers are of one shape; the first
+    # ``moe_leading_dense_layers`` layers (DeepSeek's ``first_k_dense_replace``)
+    # are dense SwiGLU layers of width ``moe_dense_intermediate_size`` instead,
+    # run before the scanned expert stack with leaves of their own
+    # (``wi`` / ``wo_mlp`` [k, ...]; the expert leaves are [L - k, ...]).
     moe_num_experts: int = 0
     moe_top_k: int = 2
     moe_intermediate_size: int = 0
@@ -66,6 +70,16 @@ class ModelConfig:
     # input) or "attn_norm" (the layer's pre-attention normed stream: logits
     # are computed before attention and carried to the expert block).
     moe_router_input: str = "mlp_norm"
+    moe_leading_dense_layers: int = 0
+    moe_dense_intermediate_size: int = 0
+    # How router logits become weights. "softmax": softmax over all experts,
+    # top-k, renormalise. "sigmoid" (DeepSeek-V3's ``noaux_tc``): s =
+    # sigmoid(logits); the choice is top-k of s + ``router_bias`` (a trained
+    # buffer, ``e_score_correction_bias``; only with ``moe_router_bias``), the
+    # weights are s at the choice, renormalised, times ``moe_routed_scaling``.
+    moe_scoring: str = "softmax"
+    moe_router_bias: bool = False
+    moe_routed_scaling: float = 1.0
     # Dual-batch overlap: split MoE tokens into two independent half-batches so XLA
     # overlaps one half's all-to-all with the other's expert GEMMs (--enable-dbo).
     moe_dbo: bool = False
@@ -93,6 +107,10 @@ class ModelConfig:
     mla_rope_dim: int = 0
     mla_qk_nope_dim: int = 0  # per-head non-RoPE q/k dim (score dot in latent space)
     mla_v_head_dim: int = 0  # per-head value dim after W_UV re-expansion
+    # q-side low-rank projection (``q_lora_rank``): q = RMSNorm(h W_qa) W_qb
+    # with leaves ``mla_wqa`` / ``mla_q_norm`` / ``mla_wqb``; 0 = the one
+    # matrix ``mla_wq``.
+    mla_q_lora_rank: int = 0
 
     def __post_init__(self):
         # a file of published keys gives lists; the config must stay hashable
@@ -138,6 +156,23 @@ class ModelConfig:
             raise ValueError(f"moe_activation={self.moe_activation!r}")
         if self.moe_router_input not in ("mlp_norm", "attn_norm"):
             raise ValueError(f"moe_router_input={self.moe_router_input!r}")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_scoring={self.moe_scoring!r}")
+        if self.moe_scoring == "softmax" and (
+                self.moe_router_bias or self.moe_routed_scaling != 1.0):
+            raise ValueError(
+                "moe_router_bias and moe_routed_scaling belong to "
+                "moe_scoring='sigmoid'")
+        if self.mla_q_lora_rank and not self.is_mla:
+            raise ValueError("mla_q_lora_rank needs mla_kv_lora_rank")
+        k = self.moe_leading_dense_layers
+        if k and not (self.is_moe and 0 < k < self.num_layers
+                      and self.moe_dense_intermediate_size > 0
+                      and self.layer_period == 1):
+            raise ValueError(
+                f"moe_leading_dense_layers={k}: a mixture model of more "
+                "layers than that, with moe_dense_intermediate_size stated "
+                "and attention layers of one kind")
 
     @property
     def layer_period(self) -> int:
@@ -186,6 +221,18 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.moe_num_experts > 0
+
+    @property
+    def num_moe_layers(self) -> int:
+        """Mixture layers: what the expert leaves and counts are stacked by."""
+        return (self.num_layers - self.moe_leading_dense_layers
+                if self.is_moe else 0)
+
+    @property
+    def layered_init(self) -> bool:
+        """The stack is drawn a layer at a time (transformer.init_params)."""
+        return bool(self.mla_q_lora_rank or self.moe_leading_dense_layers
+                    or self.moe_scoring != "softmax")
 
     @property
     def q_per_kv(self) -> int:

@@ -16,6 +16,11 @@ Supported architectures (config.json ``architectures[0]``):
   inner RMSNorms on dt, B and C) around one NoPE attention layer every
   ``attn_layer_period``, a dense SwiGLU MLP in every layer, each kind's leaves
   stacked over the layers of that kind (``_load_jamba_params``)
+- ``Glm4MoeLiteForCausalLM`` / ``DeepseekV3ForCausalLM`` with one routing
+  group — latent attention with a q-side low-rank projection, leading dense
+  layers, sigmoid routing with a selection bias and a scaling factor, shared
+  experts (``_load_latent_moe_params``); the multi-token prediction layer past
+  ``num_hidden_layers`` is skipped
 
 Handles single-file ``model.safetensors`` and sharded
 ``model.safetensors.index.json`` checkpoints; weights are cast to the target dtype
@@ -26,7 +31,9 @@ reference implementation).
 from __future__ import annotations
 
 import json
+import logging
 import os
+import re
 from typing import Optional
 
 import jax
@@ -41,7 +48,11 @@ _ARCH_FAMILY = {
     "Qwen2ForCausalLM": "qwen2",
     "Qwen3ForCausalLM": "qwen3",
     "JambaForCausalLM": "jamba",
+    "Glm4MoeLiteForCausalLM": "latent_moe",
+    "DeepseekV3ForCausalLM": "latent_moe",
 }
+
+log = logging.getLogger(__name__)
 
 
 def is_hf_checkpoint(path: str) -> bool:
@@ -61,6 +72,8 @@ def config_from_hf(path: str, dtype: str = "bfloat16") -> ModelConfig:
         )
     if family == "jamba":
         return _jamba_config(hf, path, arch, dtype)
+    if family == "latent_moe":
+        return _latent_moe_config(hf, path, arch, dtype)
     scaling = hf.get("rope_scaling")
     if scaling and scaling.get("rope_type", scaling.get("type", "default")) != "default":
         # Loading would succeed but produce silently wrong logits (scaled RoPE
@@ -139,6 +152,157 @@ def _jamba_config(hf: dict, path: str, arch: str, dtype: str) -> ModelConfig:
         mamba_dt_rank=-(-D // 16) if rank == "auto" else int(rank),
         mamba_conv_bias=bool(hf.get("mamba_conv_bias", True)),
     )
+
+
+def _latent_moe_config(hf: dict, path: str, arch: str, dtype: str) -> ModelConfig:
+    """Glm4MoeLiteConfig / DeepseekV3Config -> ModelConfig; what the program
+    cannot express is refused by the key's name."""
+    only = {"n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+            "attention_bias": False, "rope_scaling": None,
+            "topk_method": "noaux_tc", "hidden_act": "silu",
+            "partial_rotary_factor": 1, "moe_layer_freq": 1}
+    for key, want in only.items():
+        if hf.get(key, want) != want:
+            raise ValueError(f"{key}={hf[key]!r} in {path}: the program has "
+                             f"only {key}={want!r} for {arch}")
+    dn, dr = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
+    width = int(hf["moe_intermediate_size"])
+    return ModelConfig(
+        name=os.path.basename(os.path.normpath(path)) or arch,
+        vocab_size=int(hf["vocab_size"]),
+        hidden_size=int(hf["hidden_size"]),
+        intermediate_size=width,  # one shared expert's width
+        num_layers=int(hf["num_hidden_layers"]),
+        num_heads=int(hf["num_attention_heads"]),
+        num_kv_heads=int(hf["num_attention_heads"]),
+        head_dim=dn + dr,
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        max_position=int(hf.get("max_position_embeddings", 32768)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        dtype=dtype,
+        mla_kv_lora_rank=int(hf["kv_lora_rank"]),
+        mla_rope_dim=dr,
+        mla_qk_nope_dim=dn,
+        mla_v_head_dim=int(hf["v_head_dim"]),
+        mla_q_lora_rank=int(hf.get("q_lora_rank") or 0),
+        moe_num_experts=int(hf["n_routed_experts"]),
+        moe_top_k=int(hf["num_experts_per_tok"]),
+        moe_intermediate_size=width,
+        moe_num_shared_experts=int(hf.get("n_shared_experts") or 0),
+        moe_leading_dense_layers=int(hf.get("first_k_dense_replace", 0)),
+        moe_dense_intermediate_size=int(hf["intermediate_size"]),
+        moe_scoring="sigmoid",
+        moe_router_bias=True,
+        moe_routed_scaling=float(hf.get("routed_scaling_factor", 1.0)),
+    )
+
+
+def _load_latent_moe_params(src: "_TensorSource", cfg: ModelConfig,
+                            rope_interleave: bool) -> dict:
+    """The family's published tensors onto the program's leaves.
+
+    ``q_a_proj`` / ``q_a_layernorm`` / ``q_b_proj`` -> ``mla_wqa`` /
+    ``mla_q_norm`` / ``mla_wqb`` (``q_proj`` -> ``mla_wq`` without a q rank);
+    ``kv_a_proj_with_mqa`` [r + dr, D] -> ``mla_wdkv`` [D, r] and ``mla_wkr``
+    [D, dr]; ``kv_a_layernorm`` -> ``mla_kv_norm``; ``kv_b_proj`` [H * (dn +
+    dv), r] split a head into ``mla_wuk`` [H, dn, r] and ``mla_wuv`` [H, r,
+    dv]; ``mlp.gate.weight`` / ``e_score_correction_bias`` -> ``router`` /
+    ``router_bias`` (float32); ``mlp.experts.N.*`` -> the fused banks;
+    ``mlp.shared_experts.*`` -> ``shared_wi`` / ``shared_wo``; a leading
+    dense layer's ``mlp.*`` -> ``wi`` / ``wo_mlp``. Attention leaves are
+    stacked over all layers, expert leaves over the mixture layers.
+
+    ``rope_interleave``: the checkpoint pairs adjacent rope lanes (2i, 2i+1);
+    the program pairs lane i with i + dr/2, so the rope columns of ``q_b_proj``
+    and ``kv_a_proj_with_mqa`` are permuted (evens, then odds): the same
+    rotation, and a dot product does not see a permutation both sides share.
+
+    Tensors of layers past ``num_hidden_layers`` (the multi-token prediction
+    layer, which the published modelling code drops on load too) are skipped
+    with one log line; any other tensor this mapping does not consume is
+    refused by name."""
+    dt = cfg.jax_dtype
+    L, D, H = cfg.num_layers, cfg.hidden_size, cfg.num_heads
+    r, dr = cfg.mla_kv_lora_rank, cfg.mla_rope_dim
+    dn, dv, rq = cfg.mla_qk_nope_dim, cfg.mla_v_head_dim, cfg.mla_q_lora_rank
+    k, E = cfg.moe_leading_dense_layers, cfg.moe_num_experts
+    used: set[str] = set()
+
+    def g(name: str) -> np.ndarray:
+        used.add(name)
+        return src.get(name)
+
+    lane = (np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+            if rope_interleave else np.arange(dr))
+
+    def stack(fn, layers=range(L), dtype=dt) -> jax.Array:
+        return jnp.asarray(np.stack([fn(f"model.layers.{l}.") for l in layers]),
+                           dtype)
+
+    def q_heads(w):  # [H * (dn + dr), in] -> [in, H, dn + dr], rope lanes paired
+        w = w.T.reshape(-1, H, dn + dr)
+        return np.concatenate([w[..., :dn], w[..., dn:][..., lane]], axis=-1)
+
+    def fused(gate, up):
+        return np.concatenate([gate.T, up.T], axis=-1)
+
+    a = "self_attn."
+    p = {
+        "embed": jnp.asarray(g("model.embed_tokens.weight"), dt),
+        "final_norm": jnp.asarray(g("model.norm.weight"), dt),
+        "attn_norm": stack(lambda l: g(l + "input_layernorm.weight")),
+        "mlp_norm": stack(lambda l: g(l + "post_attention_layernorm.weight")),
+        "mla_wdkv": stack(lambda l: g(l + a + "kv_a_proj_with_mqa.weight")[:r].T),
+        "mla_wkr": stack(
+            lambda l: g(l + a + "kv_a_proj_with_mqa.weight")[r:][lane].T),
+        "mla_kv_norm": stack(lambda l: g(l + a + "kv_a_layernorm.weight")),
+        "mla_wuk": stack(lambda l: g(l + a + "kv_b_proj.weight").reshape(
+            H, dn + dv, r)[:, :dn]),
+        "mla_wuv": stack(lambda l: g(l + a + "kv_b_proj.weight").reshape(
+            H, dn + dv, r)[:, dn:].transpose(0, 2, 1)),
+        "wo": stack(lambda l: g(l + a + "o_proj.weight").T.reshape(H, dv, D)),
+    }
+    if rq:
+        p["mla_wqa"] = stack(lambda l: g(l + a + "q_a_proj.weight").T)
+        p["mla_q_norm"] = stack(lambda l: g(l + a + "q_a_layernorm.weight"))
+        p["mla_wqb"] = stack(lambda l: q_heads(g(l + a + "q_b_proj.weight")))
+    else:
+        p["mla_wq"] = stack(lambda l: q_heads(g(l + a + "q_proj.weight")))
+    mix = range(k, L)
+    p["router"] = stack(lambda l: g(l + "mlp.gate.weight").T, mix)
+    p["router_bias"] = stack(
+        lambda l: g(l + "mlp.gate.e_score_correction_bias"), mix, jnp.float32)
+    p["moe_wi"] = stack(lambda l: np.stack([
+        fused(g(l + f"mlp.experts.{e}.gate_proj.weight"),
+              g(l + f"mlp.experts.{e}.up_proj.weight")) for e in range(E)]), mix)
+    p["moe_wo"] = stack(lambda l: np.stack([
+        g(l + f"mlp.experts.{e}.down_proj.weight").T for e in range(E)]), mix)
+    if cfg.moe_num_shared_experts:
+        p["shared_wi"] = stack(lambda l: fused(
+            g(l + "mlp.shared_experts.gate_proj.weight"),
+            g(l + "mlp.shared_experts.up_proj.weight")), mix)
+        p["shared_wo"] = stack(
+            lambda l: g(l + "mlp.shared_experts.down_proj.weight").T, mix)
+    if k:
+        p["wi"] = stack(lambda l: fused(g(l + "mlp.gate_proj.weight"),
+                                        g(l + "mlp.up_proj.weight")), range(k))
+        p["wo_mlp"] = stack(lambda l: g(l + "mlp.down_proj.weight").T, range(k))
+    if not cfg.tie_embeddings:
+        p["unembed"] = jnp.asarray(g("lm_head.weight").T, dt)
+    left = sorted(set(src.names()) - used)
+    past = [n for n in left
+            if (m := re.match(r"model\.layers\.(\d+)\.", n)) and int(m[1]) >= L]
+    if past:
+        log.info("%s: %d tensors of layers past num_hidden_layers=%d skipped "
+                 "(the multi-token prediction layer is not served)",
+                 src.path, len(past), L)
+    unknown = sorted(set(left) - set(past))
+    if unknown:
+        raise ValueError(
+            f"{src.path}: tensors this loader maps onto no leaf: "
+            f"{unknown[:8]}{' ...' if len(unknown) > 8 else ''}")
+    return p
 
 
 def _load_jamba_params(src: "_TensorSource", cfg: ModelConfig) -> dict:
@@ -262,6 +426,10 @@ def load_params(
     src = _TensorSource(path)
     if cfg.has_recurrent:
         return _load_jamba_params(src, cfg)
+    if cfg.is_mla:
+        with open(os.path.join(path, "config.json")) as f:
+            interleave = bool(json.load(f).get("rope_interleave", True))
+        return _load_latent_moe_params(src, cfg, interleave)
     D, H, Hk, Dh = cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     L, F = cfg.num_layers, cfg.intermediate_size
 

@@ -79,6 +79,21 @@ MODEL_REGISTRY: dict[str, ModelConfig] = {
         mla_v_head_dim=16, moe_num_experts=8, moe_top_k=2,
         moe_intermediate_size=128, moe_num_shared_experts=1,
     ),
+    # The latent-attention mixture family with every mechanism GLM-4.7-Flash
+    # (glm4_moe_lite) and DeepSeek-V3 add, at CI size: a q-side low-rank
+    # projection, one leading dense layer, sigmoid routing with a selection
+    # bias and a scaling factor, one shared expert, untied head.
+    "tiny-glm": ModelConfig(
+        name="tiny-glm", vocab_size=288, hidden_size=128,
+        intermediate_size=96, num_layers=3, num_heads=4, num_kv_heads=4,
+        head_dim=48, rms_eps=1e-5, tie_embeddings=False,
+        mla_kv_lora_rank=64, mla_rope_dim=16, mla_qk_nope_dim=32,
+        mla_v_head_dim=48, mla_q_lora_rank=48,
+        moe_num_experts=8, moe_top_k=2, moe_intermediate_size=96,
+        moe_num_shared_experts=1, moe_leading_dense_layers=1,
+        moe_dense_intermediate_size=320, moe_scoring="sigmoid",
+        moe_router_bias=True, moe_routed_scaling=1.8,
+    ),
     # DeepSeek-R1/V3-class wide-EP shape with TRUE MLA latent KV (shape-
     # faithful scaled stand-in for the reference's north-star model,
     # guides/wide-ep-lws/README.md): per-token KV is rank+rope = 160 floats

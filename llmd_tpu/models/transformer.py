@@ -69,8 +69,13 @@ def param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
         # TP shards over heads for W_Q/W_UK/W_UV/W_O; the latent path
         # (W_DKV/W_KR, the per-token shared c_kv) is replicated — it is tiny
         # and every head's shard needs the full latent (DeepSeek TP layout).
-        axes |= {
+        axes |= ({
+            "mla_wqa": ("layers", "embed", None),
+            "mla_q_norm": ("layers", None),
+            "mla_wqb": ("layers", None, "heads", "head_dim"),
+        } if cfg.mla_q_lora_rank else {
             "mla_wq": ("layers", "embed", "heads", "head_dim"),
+        }) | {
             "mla_wdkv": ("layers", "embed", None),
             "mla_wkr": ("layers", "embed", None),
             "mla_kv_norm": ("layers", None),
@@ -105,7 +110,11 @@ def param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
                 "shared_wi": ("layers", "embed", "mlp"),
                 "shared_wo": ("layers", "mlp", "embed"),
             }
-    else:
+        if cfg.moe_router_bias:
+            axes["router_bias"] = ("layers", "experts")
+    if not cfg.is_moe or cfg.moe_leading_dense_layers:
+        # (a mixture model's leading dense layers: [k, ...], where the expert
+        # leaves above are [L - k, ...])
         axes |= {"wi": ("layers", "embed", "mlp"), "wo_mlp": ("layers", "mlp", "embed")}
     if not cfg.tie_embeddings:
         axes["unembed"] = ("embed", "vocab")
@@ -132,6 +141,97 @@ def param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
     return axes
 
 
+def _stack_drawer(key: jax.Array, dt, splits: int = 24):
+    """``draw(n, shape, scale)``: a stacked leaf [n, *shape] of scaled
+    normals, drawn a layer at a time inside one jitted ``lax.map`` (float32
+    draw, cast, next layer), each call from the next of ``splits`` keys."""
+    keys = iter(jax.random.split(key, splits))
+
+    def draw(n, shape, scale):
+        @jax.jit
+        def stack(ks):
+            return lax.map(lambda k: (jax.random.normal(k, shape, jnp.float32)
+                                      * scale).astype(dt), ks)
+        return stack(jax.random.split(next(keys), n))
+
+    return draw, keys
+
+
+# What ``router_bias`` is drawn at: the checkpoint's is a trained buffer of
+# the order of the spread of a token's sigmoid scores; at zero, a program that
+# dropped it, or added it into the weights, could not be told from a sound
+# one. 0.1 moves the choice of a fifth of the routed copies at 8 experts and
+# of nearly half at 64 (random weights; ``moe_bias_moved_choices_total``
+# counts them: 47-48% in GLM-4.7-Flash's cell on the chip, PR 39).
+ROUTER_BIAS_SCALE = 0.1
+
+
+def _init_layered_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
+    """Random-init params of a mixture model with a q-side low-rank
+    projection, leading dense layers or sigmoid routing (``cfg.layered_init``;
+    MLA or GQA attention), each stacked leaf drawn a layer at a time as
+    ``_init_hybrid_params`` does: ``init_params``'s eager draw holds two
+    float32 copies of a whole stacked leaf, 2 x 9.7 GB for six expert banks
+    of [64, 2048, 3072].
+
+    Attention leaves and both norms are [L, ...]; the expert leaves
+    (``router``, ``router_bias``, ``moe_wi``, ``moe_wo``, ``shared_*``) are
+    [L - k, ...] and the leading dense layers' ``wi`` / ``wo_mlp`` [k, ...],
+    k = ``cfg.moe_leading_dense_layers``."""
+    dt = cfg.jax_dtype
+    L, D, H = cfg.num_layers, cfg.hidden_size, cfg.num_heads
+    draw, keys = _stack_drawer(key, dt)
+    s = D ** -0.5
+    p: dict[str, jax.Array] = {
+        "embed": draw(1, (cfg.vocab_size, D), 0.02)[0],
+        "final_norm": jnp.ones((D,), dt),
+        "attn_norm": jnp.ones((L, D), dt),
+        "mlp_norm": jnp.ones((L, D), dt),
+    }
+    if cfg.is_mla:
+        r, dr = cfg.mla_kv_lora_rank, cfg.mla_rope_dim
+        dn, dv, rq = cfg.mla_qk_nope_dim, cfg.mla_v_head_dim, cfg.mla_q_lora_rank
+        if rq:
+            p["mla_wqa"] = draw(L, (D, rq), s)
+            p["mla_q_norm"] = jnp.ones((L, rq), dt)
+            p["mla_wqb"] = draw(L, (rq, H, dn + dr), rq ** -0.5)
+        else:
+            p["mla_wq"] = draw(L, (D, H, dn + dr), s)
+        p["mla_wdkv"] = draw(L, (D, r), s)
+        p["mla_wkr"] = draw(L, (D, dr), s)
+        p["mla_kv_norm"] = jnp.ones((L, r), dt)
+        p["mla_wuk"] = draw(L, (H, dn, r), dn ** -0.5)
+        p["mla_wuv"] = draw(L, (H, r, dv), r ** -0.5)
+        p["wo"] = draw(L, (H, dv, D), (H * dv) ** -0.5)
+    else:
+        Hk, Dh = cfg.num_kv_heads, cfg.head_dim
+        p["wq"] = draw(L, (D, H, Dh), s)
+        p["wk"] = draw(L, (D, Hk, Dh), s)
+        p["wv"] = draw(L, (D, Hk, Dh), s)
+        p["wo"] = draw(L, (H, Dh, D), (H * Dh) ** -0.5)
+    assert cfg.is_moe and not (cfg.qk_norm or cfg.attn_bias), cfg
+    k, F = cfg.moe_leading_dense_layers, cfg.intermediate_size
+    Le, E = L - k, cfg.moe_num_experts
+    Fe = cfg.moe_intermediate_size or F
+    p["router"] = draw(Le, (D, E), s)
+    if cfg.moe_router_bias:
+        p["router_bias"] = (jax.random.normal(next(keys), (Le, E), jnp.float32)
+                            * ROUTER_BIAS_SCALE)
+    p["moe_wi"] = draw(Le, (E, D, 2 * Fe), s)
+    p["moe_wo"] = draw(Le, (E, Fe, D), Fe ** -0.5)
+    if cfg.moe_num_shared_experts:
+        Fs = F * cfg.moe_num_shared_experts
+        p["shared_wi"] = draw(Le, (D, 2 * Fs), s)
+        p["shared_wo"] = draw(Le, (Fs, D), Fs ** -0.5)
+    if k:
+        Fd = cfg.moe_dense_intermediate_size
+        p["wi"] = draw(k, (D, 2 * Fd), s)
+        p["wo_mlp"] = draw(k, (Fd, D), Fd ** -0.5)
+    if not cfg.tie_embeddings:
+        p["unembed"] = draw(1, (D, cfg.vocab_size), s)[0]
+    return p
+
+
 def _init_hybrid_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
     """Random-init params of a model with mamba layers. Each stacked leaf is
     drawn a layer at a time inside one jitted ``lax.map`` (float32 draw, cast,
@@ -155,15 +255,7 @@ def _init_hybrid_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array
                        cfg.head_dim, cfg.intermediate_size)
     Di, N, K, R = (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv,
                    cfg.mamba_dt_rank)
-    keys = iter(jax.random.split(key, 16))
-
-    def norm(n, shape, scale):
-        @jax.jit
-        def draw(ks):
-            return lax.map(lambda k: (jax.random.normal(k, shape, jnp.float32)
-                                      * scale).astype(dt), ks)
-        return draw(jax.random.split(next(keys), n))
-
+    norm, keys = _stack_drawer(key, dt, 16)
     s = D ** -0.5
     p: dict[str, jax.Array] = {
         "embed": norm(1, (cfg.vocab_size, D), 0.02)[0],
@@ -203,6 +295,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
     """Random-init params (scaled normal); shapes match param_logical_axes."""
     if cfg.has_recurrent:
         return _init_hybrid_params(cfg, key)
+    if cfg.layered_init:
+        return _init_layered_params(cfg, key)
     dt = cfg.jax_dtype
     L, D, H, Hk, Dh = cfg.num_layers, cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     F = cfg.intermediate_size
@@ -321,6 +415,7 @@ def moe_block(
     return_dropped: bool = False,
     logits: Optional[jax.Array] = None,
     slot_offset: Optional[jax.Array] = None,
+    router_bias: Optional[jax.Array] = None,
 ):
     """Top-k routed MoE with capacity-based dispatch (XLA-friendly static shapes).
 
@@ -358,6 +453,16 @@ def moe_block(
     layer's bank along the slot axis, ``[L*E, ...]``, and this layer's experts
     begin at ``slot_offset``; only a ``dispatch_impl`` that says
     ``stacked_banks`` takes them so (no EPLB: its slots are per layer).
+
+    ``cfg.moe_scoring == "sigmoid"`` (DeepSeek-V3's ``noaux_tc`` with one
+    group): scores are sigmoids of the logits, the choice is the top-k of
+    score + ``router_bias`` [E] (float32; the bias enters the choice and
+    nothing else), the weights are the scores at the choice, renormalised
+    and times ``cfg.moe_routed_scaling``. With ``return_dropped`` the third
+    result is then ``[dropped, bias_moved, routed]`` int32: the routed copies
+    whose expert the bias changed (``top_k(s + b)`` against ``top_k(s)``) and
+    all routed copies, of live tokens, for
+    ``llmd_tpu:moe_bias_moved_choices_total`` / ``moe_routed_copies_total``.
     """
     T, D = x.shape
     E, k = cfg.moe_num_experts, cfg.moe_top_k
@@ -365,9 +470,19 @@ def moe_block(
 
     if logits is None:
         logits = router_logits(x, router)
-    weights = jax.nn.softmax(logits, axis=-1)
-    topw, topi = lax.top_k(weights, k)  # [T, k]
-    topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-9)
+    sigmoid = cfg.moe_scoring == "sigmoid"
+    if sigmoid:
+        scores = jax.nn.sigmoid(logits)
+        _, plain = lax.top_k(scores, k)  # the choice the bias did not move
+        topi = plain if router_bias is None else lax.top_k(
+            scores + router_bias.astype(jnp.float32)[None, :], k)[1]
+        topw = jnp.take_along_axis(scores, topi, axis=-1)
+        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-20) \
+            * cfg.moe_routed_scaling
+    else:
+        weights = jax.nn.softmax(logits, axis=-1)
+        topw, topi = lax.top_k(weights, k)  # [T, k]
+        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-9)
     # Padding tokens (prefill chunk tail, idle decode slots) must not consume
     # expert capacity nor pollute the EPLB load stats.
     valid = (
@@ -376,6 +491,10 @@ def moe_block(
         else jnp.ones((T, 1), jnp.int32)
     )  # [T, 1]
     counts = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.int32) * valid[..., None], axis=(0, 1))
+    if sigmoid:
+        chosen = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.int32), axis=1)
+        unmoved = jnp.sum(jax.nn.one_hot(plain, E, dtype=jnp.int32), axis=1)
+        bias_moved = jnp.sum(chosen * (1 - unmoved) * valid)
 
     if eplb is not None:
         replica_slots, replica_counts = eplb  # [E, R], [E]
@@ -390,6 +509,13 @@ def moe_block(
         "slot_offset": slot_offset, "num_slots": E}
     assert not stacked or (eplb is None and getattr(
         dispatch_impl, "stacked_banks", False)), "stacked banks: sorted local dispatch only"
+    if sigmoid and getattr(dispatch_impl, "ordered_combine", False):
+        # a token's copies are summed in the order of its choice, so that its
+        # result does not depend on the rows beside it (ops/moe_dispatch.
+        # combine_in_order). With this routing only: the softmax models'
+        # step programs stay the StableHLO they were (ROADMAP: move them over
+        # in a change of their own, with their cells measured)
+        stacked["ordered_combine"] = True
     if dispatch_impl is not None:
         def half(x, idx, topw, valid):
             y = dispatch_impl(x, idx, topw, valid, wi, wo, wi_scale, wo_scale,
@@ -450,6 +576,8 @@ def moe_block(
         dropped = jnp.zeros((), jnp.int32)
     else:
         dropped = jnp.sum(counts) - kept  # routed minus kept == capacity drops
+    if sigmoid:
+        dropped = jnp.stack([dropped, bias_moved, jnp.sum(counts)])
     return y, counts, dropped
 
 
@@ -933,9 +1061,10 @@ def forward_core(
 
     Serves batched/chunked prefill and decode in ONE program: the engine packs
     whatever fits its token budget. Returns (hidden [N, D] final-normed, updated
-    cache, expert_counts [L, E], moe_dropped scalar int32 — routed copies the
-    legacy capacity path dropped this step, 0 on the sorted path and for dense
-    models). Callers unembed whichever rows they need (the
+    cache, expert_counts [mixture layers, E], moe_dropped scalar int32 — routed
+    copies the legacy capacity path dropped this step, 0 on the sorted path and
+    for dense models; ``[dropped, bias_moved, routed]`` under sigmoid routing,
+    see ``moe_block``). Callers unembed whichever rows they need (the
     engine only unembeds each sequence's last row — prefill never pays the full
     [N, vocab] logits matmul).
 
@@ -992,20 +1121,27 @@ def forward_core(
         # bias/qk-norm/LoRA-on-attn are GQA-family features; none of the MLA
         # checkpoints combine them (registry enforces the shapes)
         assert not (cfg.qk_norm or cfg.attn_bias), "MLA excludes qk_norm/attn_bias"
-        attn_keys = ("mla_wq", "mla_wdkv", "mla_wkr", "mla_kv_norm",
-                     "mla_wuk", "mla_wuv") + _variants("wo")
+        attn_keys = (("mla_wqa", "mla_q_norm", "mla_wqb")
+                     if cfg.mla_q_lora_rank else ("mla_wq",)) + (
+            "mla_wdkv", "mla_wkr", "mla_kv_norm",
+            "mla_wuk", "mla_wuv") + _variants("wo")
     else:
         attn_keys = _variants("wq", "wk", "wv", "wo")
-    stacked_keys = ("attn_norm", "mlp_norm") + attn_keys + (
+    # leaves every layer has, then the feed-forward's: a mixture layer's
+    # (stacked by mixture layer) or a dense layer's
+    every_keys = ("attn_norm", "mlp_norm") + attn_keys + (
         ("q_norm", "k_norm") if cfg.qk_norm else ()
-    ) + (("bq", "bk", "bv", "bo") if cfg.attn_bias else ()) + (
-        ("router",) + _variants("moe_wi", "moe_wo")
+    ) + (("bq", "bk", "bv", "bo") if cfg.attn_bias else ())
+    dense_keys = _variants("wi", "wo_mlp") if (
+        not cfg.is_moe or cfg.moe_leading_dense_layers) else ()
+    expert_keys = (
+        ("router",) + (("router_bias",) if cfg.moe_router_bias else ())
+        + _variants("moe_wi", "moe_wo")
         + (_variants("shared_wi", "shared_wo") if cfg.moe_num_shared_experts else ())
-        if cfg.is_moe
-        else _variants("wi", "wo_mlp")
-    )
+    ) if cfg.is_moe else ()
     if "eplb_replica_slots" in params:
-        stacked_keys += ("eplb_replica_slots", "eplb_replica_counts")
+        expert_keys += ("eplb_replica_slots", "eplb_replica_counts")
+    stacked_keys = every_keys + (expert_keys if cfg.is_moe else dense_keys)
     # A dispatch that indexes the expert banks by slot takes every layer's
     # bank as one stack and the layer's offset into it: a bank scanned as a
     # layer's slice is copied out of the stack each step, which took as long
@@ -1014,6 +1150,7 @@ def forward_core(
         cfg.is_moe and getattr(moe_dispatch_impl, "stacked_banks", False)
         and "eplb_replica_slots" not in params) else ()
     stacked_keys = tuple(k for k in stacked_keys if k not in bank_keys)
+    expert_keys = tuple(k for k in expert_keys if k not in bank_keys)
     banks = {k: params[k].reshape((-1,) + params[k].shape[2:])
              for k in bank_keys}
     has_lora = "lora_A_wq" in params
@@ -1035,9 +1172,12 @@ def forward_core(
     assert not (cfg.has_window and cfg.is_mla), \
         "MLA has no sliding-window layers"
 
-    def layer(carry, lp, l, window, use_rope):
+    def layer(carry, lp, l, window, use_rope, moe_ordinal=None):
         """Layer ``l`` (traced index) with parameters ``lp``; ``window`` (0 =
-        full attention) and ``use_rope`` are static: the kind of the layer."""
+        full attention) and ``use_rope`` are static: the kind of the layer.
+        ``moe_ordinal``: which of the mixture layers it is where leading
+        dense layers precede them (None: every layer is one, ``l``); the
+        feed-forward is the dense MLP where ``lp`` holds no router."""
         x, flat_cache = carry  # flat_cache: [L*P*ps, 2Hk, Dhp] slot view (in-place carry)
 
         def _mm(key, pattern, xin):
@@ -1052,7 +1192,7 @@ def forward_core(
 
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         early_logits = router_logits(h, lp["router"]) if (
-            cfg.is_moe and cfg.moe_router_input == "attn_norm") else None
+            "router" in lp and cfg.moe_router_input == "attn_norm") else None
         if cfg.is_mla:
             # Absorbed MLA (DeepSeek-V2 §2.1.2 inference form): the pool holds
             # one shared [c_kv ; k_rope] vector per token, queries project into
@@ -1068,7 +1208,12 @@ def forward_core(
                 return t if Dhp == Dkv else jnp.pad(
                     t, ((0, 0), (0, 0), (0, Dhp - Dkv)))
 
-            q = jnp.einsum("nd,dhk->nhk", h, lp["mla_wq"])  # [N, H, dn+dr]
+            if cfg.mla_q_lora_rank:
+                c_q = rms_norm(jnp.einsum("nd,dr->nr", h, lp["mla_wqa"]),
+                               lp["mla_q_norm"], cfg.rms_eps)
+                q = jnp.einsum("nr,rhk->nhk", c_q, lp["mla_wqb"])
+            else:
+                q = jnp.einsum("nd,dhk->nhk", h, lp["mla_wq"])  # [N, H, dn+dr]
             q_rope = rope(q[..., dn:], positions, cfg.rope_theta)
             c = jnp.einsum("nd,dr->nr", h, lp["mla_wdkv"])  # [N, r] latent
             c = rms_norm(c, lp["mla_kv_norm"], cfg.rms_eps)
@@ -1124,7 +1269,17 @@ def forward_core(
             # latent-weighted sum [..., :rank] re-expands per head via W_UV
             o_heads = jnp.einsum("nhr,hrv->nhv",
                                  attn[..., :cfg.mla_kv_lora_rank], lp["mla_wuv"])
-            o = _mm("wo", "nhv,hvd->nd", o_heads)
+            # one product over the H * dv lanes of a row: contracted over
+            # (h, v) as two axes, XLA picks how to split the sum by the
+            # number of rows, and on the chip a decode row's projection
+            # through a 64-row and a 256-row program parted by a bf16 step
+            # (PR 39: greedy tokens served cold and from the prefix cache
+            # parted). A plain [N, H*dv] x [H*dv, D] product adds a row's
+            # terms in one order whatever N.
+            flat = {k: v.reshape((-1,) + v.shape[2:])
+                    for k, v in lp.items() if k in ("wo", "wo_q")}
+            o = _weight_mm({**lp, **flat}, "wo", "nk,kd->nd",
+                           o_heads.reshape(N, -1))
         else:
             attn = attn[..., :Dh]
             o = _mm("wo", "nhk,hkd->nd", attn)
@@ -1137,7 +1292,7 @@ def forward_core(
         x = x + o
 
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        if cfg.is_moe:
+        if cfg.is_moe and "router" in lp:
             eplb = (
                 (lp["eplb_replica_slots"], lp["eplb_replica_counts"])
                 if "eplb_replica_slots" in lp
@@ -1157,7 +1312,10 @@ def forward_core(
                 dispatch_impl=moe_dispatch_impl,
                 return_dropped=True,
                 logits=early_logits,
-                slot_offset=l * cfg.moe_num_experts if banks else None,
+                slot_offset=(l if moe_ordinal is None else moe_ordinal)
+                * cfg.moe_num_experts if banks else None,
+                **({"router_bias": lp["router_bias"]}
+                   if cfg.moe_router_bias else {}),
             )
             if cfg.moe_num_shared_experts:
                 if "shared_wi_q" in lp:
@@ -1184,6 +1342,35 @@ def forward_core(
         return (x, {"kv": flat_cache.reshape(Ptot, ps, HkC, Dhp), **state},
                 jnp.zeros((cfg.num_layers, 0), jnp.int32),
                 jnp.zeros((), jnp.int32))
+
+    if cfg.moe_leading_dense_layers:
+        # Leading dense layers, then a scan over the mixture layers. A layer
+        # takes its leaves from the whole stacks by index (layer ``l`` of the
+        # attention leaves and norms, ordinal ``j`` of the expert leaves), as
+        # ``_hybrid_stack`` does: the stacks stay loop invariants.
+        k = cfg.moe_leading_dense_layers
+        kind = (cfg.attn_window_pattern[0], cfg.rope_pattern[0])
+
+        def take(keys, i):
+            return {key: lax.dynamic_index_in_dim(params[key], i, 0,
+                                                  keepdims=False)
+                    for key in keys}
+
+        carry = (x, cache.reshape(Ptot * ps, HkC, Dhp))
+        with jax.named_scope("leading_dense_layers"):
+            for l in range(k):
+                carry, _ = layer(
+                    carry, {**take(every_keys, l), **take(dense_keys, l)},
+                    jnp.int32(l), *kind)
+        with jax.named_scope("expert_layers"):
+            (x, flat_cache), (expert_counts, dropped) = lax.scan(
+                lambda c, j: layer(
+                    c, {**take(every_keys, k + j), **take(expert_keys, j)},
+                    k + j, *kind, moe_ordinal=j),
+                carry, jnp.arange(cfg.num_moe_layers, dtype=jnp.int32))
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        return (x, flat_cache.reshape(Ptot, ps, HkC, Dhp), expert_counts,
+                dropped.sum(0))
 
     # One trace for any depth: the scan runs over periods of the attention
     # pattern, and a period's layers are written out in the body so that each
@@ -1219,6 +1406,9 @@ def forward_core(
         expert_counts = expert_counts.reshape((cfg.num_layers,)
                                               + expert_counts.shape[2:])
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    if cfg.moe_scoring == "sigmoid":  # [dropped, bias_moved, routed]
+        return (x, flat_cache.reshape(Ptot, ps, HkC, Dhp), expert_counts,
+                dropped.sum(0))
     return x, flat_cache.reshape(Ptot, ps, HkC, Dhp), expert_counts, dropped.sum()
 
 

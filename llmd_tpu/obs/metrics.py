@@ -579,6 +579,21 @@ class EngineMetrics:
             "(models.transformer.window_view). A model without window "
             "layers feeds full only",
             labelnames=("program", "layers"))
+        self.attn_query_tokens = reg.counter(
+            "llmd_tpu:attn_query_tokens_total",
+            "Query tokens one attention layer is given, summed over the rows "
+            "of each dispatch (a fused decode call: at its first step, one a "
+            "row)",
+            labelnames=("program",))
+        self.attn_qk_pairs = reg.counter(
+            "llmd_tpu:attn_query_key_pairs_total",
+            "Pairs of a query and a key it may see (causal) that one "
+            "attention layer is given, summed over the rows of each dispatch "
+            "(a fused decode call: at its first step): a row of q queries "
+            "over kv resident tokens holds q * kv - q * (q - 1) / 2. What an "
+            "attention kernel's operations are proportional to, whatever "
+            "implements it (perfbench: mla_mixed_attention_roofline)",
+            labelnames=("program",))
         self.ssm_scan_tokens = reg.counter(
             "llmd_tpu:ssm_scan_tokens_total",
             "Tokens one mamba layer's selective scan is given, per dispatch, "
@@ -847,6 +862,19 @@ class EngineMetrics:
             "(sorted is drop-free by construction — a non-zero sorted series "
             "is a dispatch bug; einsum counts routed - kept per step)",
             labelnames=("path",))
+        self.moe_bias_moved = reg.counter(
+            "llmd_tpu:moe_bias_moved_choices_total",
+            "Routed copies whose expert the router's selection bias changed "
+            "(sigmoid routing: top_k(s + b) against top_k(s), live tokens, "
+            "summed over the mixture layers); over moe_routed_copies_total "
+            "it is the share of the routing the bias decides: 0 where the "
+            "program dropped the bias, and what a checkpoint's load "
+            "balancing moved where it is served")
+        self.moe_routed_copies = reg.counter(
+            "llmd_tpu:moe_routed_copies_total",
+            "Routed copies of live tokens, summed over the mixture layers "
+            "(tokens x experts a token x layers), counted under sigmoid "
+            "routing beside moe_bias_moved_choices_total")
         self.moe_expert_load = reg.gauge(
             "llmd_tpu:moe_expert_load_max_over_mean",
             "Of the last step that routed tokens (a unified step, or the k "

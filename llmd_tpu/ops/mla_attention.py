@@ -1,0 +1,280 @@
+"""Latent attention over the single-plane pool for ragged rows: one Pallas
+kernel for the unified step (chunks of a prefill and decode rows in one flat
+batch) and the fused decode call.
+
+Absorbed MLA is MQA whose one key/value head is the pool's row ``[c_kv ;
+k_rope]`` (lane-padded, 576 -> 640 at GLM-4.7-Flash's widths): a token's row
+is read once and serves the score product and the weighted sum. The XLA
+reference (`models.transformer.ragged_paged_attention_xla`) gathers every page
+of ``max_model_len`` for each of 32 query tokens and casts it to float32; the
+page-a-grid-step decode kernel (`ops/mla_decode.py`) takes one 16-token page
+(20 KB) a grid step. Neither survives contexts of 16k tokens and more. This
+kernel walks a row's keys in blocks of ``bkv`` pages.
+
+The grid is the batch rows; everything else is loops inside the kernel over
+what the row really holds, with q, the pool and the output left in HBM:
+
+- a row's queries go in blocks of ``bq`` tokens, all (padded) heads of a token
+  as rows of one matrix ``[bq * Hp, Dhp]`` (Hp: the heads padded to a multiple
+  of 32, so that the fold is a relabelling of tiles and not a relayout),
+- for each query block, the row's KV blocks up to its last query's position,
+  fetched page by page into one of two VMEM buffers while the other is
+  computed on (the page table is scalar-prefetched),
+- online softmax in float32 (m, l, acc in VMEM scratch); a block that lies
+  wholly past a query multiplies its state by exactly 1 and adds exactly 0,
+  so a token's result does not depend on the chunk that brought it or on the
+  rows beside it: cold and prefix-cached requests, and the two step programs,
+  block a row's keys alike as long as they share ``bkv``
+  (`ops/paged_attention.py`, "One bkv an engine"),
+- the output block leaves token by token, only for tokens the row has; rows of
+  the flat batch that no sequence owns stay zero (the output aliases a zeroed
+  buffer).
+
+Causality derives from ``kv_len - q_len + local index`` as in the GQA kernel:
+a row's queries are its last ``q_len`` tokens. ``-1`` page-table entries are
+clamped to page 0 for the DMA's sake; they lie past ``kv_len`` and are masked.
+
+The kernel's name on the device trace is ``mla_ragged_paged_attention``: the
+benchmark's ``attn_dev_share`` reads attention by the pattern
+``ragged_paged_attention``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from llmd_tpu.ops.paged_attention import VMEM_LIMIT, shard_over_heads
+
+NEG_INF = -0.7 * float(np.finfo(np.float32).max)
+_MINOR = 128  # lane width of the m / l scratch rows; column 0 is meaningful
+HEAD_TILE = 32  # heads are padded to a power of two at least this: whole bf16 tiles
+
+# Pages a KV block: 1,024 tokens at pages of 16. A page is one DMA of 20 KB
+# (640 lanes of bf16), a third of the GQA cells' pages, and the calls are
+# bound by the page fetches' fixed costs: at 64 rows decoding over 1.14 M
+# tokens 16 / 32 / 64 pages a block read 3,925 / 2,947 / 2,529 us a call
+# against a byte floor of 1,608, and a unified step with one 128-token chunk
+# beside them 5,383 / 4,121 / 3,699 (`tools/mla_attn_sweep.py`, chip, PR 39).
+# The fetch loop is unrolled per page: a block of 64 costs 3.8 s to trace and
+# lower a call site where 32 costs 2.4.
+KV_BLOCK_TOKENS = 1024
+KV_BLOCK_MAX_PAGES = 64
+
+
+def pick_block_sizes(num_tokens: int, num_rows: int, page_size: int,
+                     pages_per_seq: int) -> tuple[int, int]:
+    """(pages a KV block, query tokens a query block) of a call with these
+    static shapes. bkv reads the page size and a row's page budget and never
+    the token budget: an engine's programs, and a cold and a cached request,
+    must block a row's keys alike. bq reads the token budget: one query row a
+    sequence (the fused decode call: ``num_tokens == num_rows``) takes a block
+    of 1, a unified step blocks of 16 (a chunk reads its context once a query
+    block; its decode rows take the block-of-1 path inside the kernel)."""
+    bkv = max(1, min(pages_per_seq, KV_BLOCK_MAX_PAGES,
+                     KV_BLOCK_TOKENS // page_size))
+    while pages_per_seq % bkv:  # whole blocks of the page table
+        bkv -= 1
+    return bkv, 1 if num_tokens <= num_rows else min(16, num_tokens)
+
+
+def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
+            q_hbm, pool_hbm, o_init_hbm,  # HBM
+            o_hbm,  # HBM, aliased to o_init_hbm
+            q_buf, kv_buf, o_buf, m_ref, l_ref, acc_ref,  # VMEM scratch
+            q_sem, kv_sem, o_sem,
+            *, bq: int, bkv: int, maxp: int, scale: float):
+    del o_init_hbm
+    b = pl.program_id(0)
+    ps = kv_buf.shape[1] // bkv
+    T = bkv * ps
+    Hp, Dhp = q_buf.shape[1], q_buf.shape[2]
+    shift = Hp.bit_length() - 1  # Hp is a power of two (the wrapper's pad)
+    q_start = cu_ref[b]
+    q_len = cu_ref[b + 1] - q_start
+    kv_len = kv_lens_ref[b]
+
+    def fetch(j, slot):
+        """The page copies of the row's KV block ``j`` into buffer ``slot``."""
+        return [pltpu.make_async_copy(
+            pool_hbm.at[pt_ref[b * maxp + j * bkv + i]],
+            kv_buf.at[slot, pl.ds(i * ps, ps)], kv_sem.at[slot])
+            for i in range(bkv)]
+
+    def query_block(qb, nq: int):
+        """Query block ``qb`` of the row in blocks of ``nq`` tokens (static)."""
+        R = nq * Hp
+        t0 = q_start + qb * nq  # the block's first row of the flat batch
+        n_valid = jnp.minimum(nq, q_len - qb * nq)
+        first_pos = kv_len - q_len + qb * nq
+        n_kv = (first_pos + n_valid - 1) // T + 1
+        load = pltpu.make_async_copy(q_hbm.at[pl.ds(t0, nq)],
+                                     q_buf.at[pl.ds(0, nq)], q_sem)
+        load.start()
+        for c in fetch(0, 0):
+            c.start()
+        m_ref[pl.ds(0, R)] = jnp.full((R, _MINOR), NEG_INF, jnp.float32)
+        l_ref[pl.ds(0, R)] = jnp.zeros((R, _MINOR), jnp.float32)
+        acc_ref[pl.ds(0, R)] = jnp.zeros((R, Dhp), jnp.float32)
+        load.wait()
+        q = q_buf[pl.ds(0, nq)].reshape(R, Dhp)
+
+        def kv_block(j, _):
+            slot = j % 2
+
+            @pl.when(j + 1 < n_kv)
+            def _prefetch():
+                for c in fetch(j + 1, 1 - slot):
+                    c.start()
+
+            for c in fetch(j, slot):
+                c.wait()
+            kv = kv_buf[slot]  # [T, Dhp]: keys and values at once
+            s = lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            tok = lax.shift_right_logical(
+                lax.broadcasted_iota(jnp.int32, (R, T), 0), shift)
+            key = j * T + lax.broadcasted_iota(jnp.int32, (R, T), 1)
+            mask = key <= first_pos + tok
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[pl.ds(0, R)]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(mask, jnp.exp(s - m_new[:, :1]), 0.0)
+            l_ref[pl.ds(0, R)] = l_ref[pl.ds(0, R)] * alpha + jnp.sum(
+                p, axis=1, keepdims=True)
+            acc_ref[pl.ds(0, R)] = acc_ref[pl.ds(0, R)] * alpha[:, :1] + \
+                lax.dot_general(p.astype(kv.dtype), kv,
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            m_ref[pl.ds(0, R)] = m_new
+            return 0
+
+        lax.fori_loop(0, n_kv, kv_block, 0)
+        out = acc_ref[pl.ds(0, R)] / l_ref[pl.ds(0, R)][:, :1]
+        o_buf[pl.ds(0, nq)] = out.reshape(nq, Hp, Dhp).astype(o_buf.dtype)
+        # token by token, and only the row's own: the flat batch's next rows
+        # are another sequence's
+        for t in range(nq):
+            @pl.when(t < n_valid)
+            def _store(t=t):
+                pltpu.make_async_copy(o_buf.at[t], o_hbm.at[t0 + t],
+                                      o_sem).start()
+        for t in range(nq):
+            @pl.when(t < n_valid)
+            def _stored(t=t):
+                pltpu.make_async_copy(o_buf.at[t], o_hbm.at[t0 + t],
+                                      o_sem).wait()
+
+    live = (b < nseq_ref[0]) & (kv_len > 0)
+
+    @pl.when(live & (q_len == 1))
+    def _decode_row():
+        query_block(0, 1)
+
+    if bq > 1:
+        @pl.when(live & (q_len > 1))
+        def _chunk():
+            def body(qb, _):
+                query_block(qb, bq)
+                return 0
+
+            lax.fori_loop(0, (q_len + bq - 1) // bq, body, 0)
+
+
+def mla_ragged_pallas(
+    q: jax.Array,  # [N, H, Dhp] flat query tokens (lane-padded latent width)
+    layer_cache: jax.Array,  # [P, ps, 1, Dhp] single-plane latent pool
+    kv_lens: jax.Array,  # [B] tokens resident incl. this step's
+    page_tables: jax.Array,  # [B, maxp], clamped >= 0
+    cu_q_lens: jax.Array,  # [B+1]
+    num_seqs: jax.Array,  # [1]
+    *,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """The raw kernel call. Returns [N, H, Dhp]: the latent-weighted sums
+    (lanes past the real latent width are zero, as the stored rows' are), zero
+    for rows of the flat batch that no live sequence owns."""
+    N, H, Dhp = q.shape
+    P, ps, planes, _ = layer_cache.shape
+    assert planes == 1, "the single-plane latent pool"
+    B, maxp = page_tables.shape
+    bkv, bq = pick_block_sizes(N, B, ps, maxp)
+    Hp = max(HEAD_TILE, 1 << (H - 1).bit_length())  # a power of two
+    # heads padded to whole tiles, and bq rows past the batch's end so that a
+    # query block's load stays in bounds (what it reads there is not stored)
+    qp = jnp.pad(q, ((0, bq), (0, Hp - H), (0, 0)))
+    kernel = functools.partial(_kernel, bq=bq, bkv=bkv, maxp=maxp,
+                               scale=scale)
+    rows = bq * Hp
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[hbm, hbm, hbm],
+            out_specs=hbm,
+            scratch_shapes=[
+                pltpu.VMEM((bq, Hp, Dhp), q.dtype),  # q block
+                pltpu.VMEM((2, bkv * ps, Dhp), layer_cache.dtype),
+                pltpu.VMEM((bq, Hp, Dhp), q.dtype),  # output block
+                pltpu.VMEM((rows, _MINOR), jnp.float32),  # m
+                pltpu.VMEM((rows, _MINOR), jnp.float32),  # l
+                pltpu.VMEM((rows, Dhp), jnp.float32),  # acc
+                pltpu.SemaphoreType.DMA(()),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA(()),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((N + bq, Hp, Dhp), q.dtype),
+        # the output starts as zeros: a row no sequence owns is never written
+        input_output_aliases={6: 0},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        name="mla_ragged_paged_attention",
+    )(page_tables.reshape(-1).astype(jnp.int32), kv_lens.astype(jnp.int32),
+      cu_q_lens.astype(jnp.int32), num_seqs.astype(jnp.int32),
+      qp, layer_cache.reshape(P, ps, Dhp), jnp.zeros_like(qp))
+    return out[:N, :H]
+
+
+def mla_paged_attention(
+    q: jax.Array,  # [N, H, Dhp] flat query tokens (lane-padded)
+    layer_cache: jax.Array,  # [P, ps, 1, Dhp]
+    page_tables: jax.Array,  # [B, maxp] (-1 = unmapped)
+    positions: jax.Array,  # [N] (unused: causality derives from kv/cu lens)
+    seq_slots: jax.Array,  # [N] (unused on this path)
+    kv_lens: jax.Array,  # [B]
+    *,
+    scale: float,
+    cu_q_lens: jax.Array,  # [B+1]
+    num_seqs: jax.Array,  # [1]
+    chunk_k: "jax.Array | None" = None,  # unused (ring-attn impls only)
+    chunk_v: "jax.Array | None" = None,  # unused (ring-attn impls only)
+    interpret: bool = False,  # True only when the selecting platform is CPU
+    mesh=None,  # engine mesh: the kernel runs per device under shard_map
+) -> jax.Array:
+    """Uniform-signature adapter (drop-in for ragged_paged_attention_xla) for
+    a latent-attention engine's step programs, mixed batches and decode calls
+    alike."""
+    del positions, seq_slots, chunk_k, chunk_v
+    page_tables = jnp.maximum(page_tables, 0)
+    if layer_cache.dtype == jnp.float8_e4m3fn:
+        # fp8 latent pages are stored at scale 1.0: upcasting is the dequant
+        layer_cache = layer_cache.astype(q.dtype)
+    call = functools.partial(mla_ragged_pallas, scale=scale,
+                             interpret=interpret)
+    if mesh is not None:
+        # heads split over tp; the latent plane is replicated
+        call = shard_over_heads(call, mesh, q, layer_cache, shard_kv=False)
+    return call(q, layer_cache, kv_lens, page_tables, cu_q_lens, num_seqs)

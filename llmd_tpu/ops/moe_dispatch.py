@@ -169,11 +169,30 @@ def combine_stage(ye, row, tok, wf, T: int):
     return jnp.zeros((T, D), ye.dtype).at[tok].add(g * wf[:, None])
 
 
+def combine_in_order(ye, row, wf, T: int):
+    """``combine_stage`` with a token's copies added in the order of its
+    choice, in float32, as elementwise adds: the copies of token t are rows
+    ``t*k .. t*k + k - 1`` of the flat (token, k) order, so the sum is a
+    reshape and k - 1 adds, and a token's result depends on nothing beside
+    it. ``combine_stage``'s scatter-add rounds to the model's type after
+    every add and applies a token's updates in an order that follows the
+    array's size: on the chip a decode row's sum through a 64-row call and
+    through a 256-row call parted by a bf16 step (PR 39: `cold_equals_cached`
+    read false), where the expert GEMMs' rows were equal bit for bit."""
+    Tp, D = ye.shape
+    g = (ye[jnp.minimum(row, Tp - 1)] * wf[:, None]).reshape(T, -1, D)
+    acc = g[:, 0].astype(jnp.float32)
+    for j in range(1, g.shape[1]):
+        acc = acc + g[:, j].astype(jnp.float32)
+    return acc.astype(ye.dtype)
+
+
 def sorted_moe_local(x, idx, topw, valid, wi, wo, wi_scale=None,
                      wo_scale=None, *, use_pallas: bool = False,
                      interpret: bool = False,
                      bc: Optional[int] = None, act=jax.nn.silu,
-                     slot_offset=None, num_slots: Optional[int] = None):
+                     slot_offset=None, num_slots: Optional[int] = None,
+                     ordered_combine: bool = False):
     """Single-shard token-sorted MoE: gather/scatter only, no collective.
 
     ``slot_offset`` (a traced scalar) with ``num_slots``: ``wi``/``wo`` (and
@@ -182,7 +201,8 @@ def sorted_moe_local(x, idx, topw, valid, wi, wo, wi_scale=None,
     GEMMs index the bank by a block's slot, so they read the layer's experts
     out of the whole stack, where a layer's bank sliced out of it first is a
     copy of the bank every step (as long as the GEMMs themselves on the
-    chip)."""
+    chip). ``ordered_combine``: `combine_in_order` in `combine_stage`'s
+    place."""
     T, D = x.shape
     S = num_slots or wi.shape[0]
     if bc is None:
@@ -197,6 +217,8 @@ def sorted_moe_local(x, idx, topw, valid, wi, wo, wi_scale=None,
                            wo_scale, use_pallas=use_pallas, interpret=interpret,
                            act=act)
     with jax.named_scope("moe_combine"):
+        if ordered_combine:
+            return combine_in_order(ye, row, wf, T)
         return combine_stage(ye, row, tok, wf, T)
 
 
@@ -295,16 +317,19 @@ def make_sorted_dispatch(mesh=None, *, use_pallas: bool = False,
     """
     if mesh is None:
         def impl(x, idx, topw, valid, wi, wo, wi_scale=None, wo_scale=None,
-                 act=jax.nn.silu, slot_offset=None, num_slots=None):
+                 act=jax.nn.silu, slot_offset=None, num_slots=None,
+                 ordered_combine=False):
             return sorted_moe_local(x, idx, topw, valid, wi, wo, wi_scale,
                                     wo_scale, use_pallas=use_pallas,
                                     interpret=interpret, act=act,
                                     slot_offset=slot_offset,
-                                    num_slots=num_slots)
+                                    num_slots=num_slots,
+                                    ordered_combine=ordered_combine)
         # forward_core may hand this impl the whole stack of banks and a
         # layer's offset (sorted_moe_local); the mesh impl below shards one
         # layer's bank over ep and takes it sliced
         impl.stacked_banks = True
+        impl.ordered_combine = True  # takes the argument
         return impl
 
     from jax.sharding import PartitionSpec as P

@@ -117,6 +117,27 @@ def make_hf_checkpoint(
             sliding_window=None, pad_token_id=0, bos_token_id=1,
             eos_token_id=2)
         model = transformers.JambaForCausalLM(cfg)
+    elif family == "glm4_moe_lite":
+        # The latent-attention mixture block of GLM-4.7-Flash at toy widths,
+        # built as DeepseekV3ForCausalLM (the block and the tensor names this
+        # family shares with it; one routing group): a q-side rank, one
+        # leading dense layer, sigmoid routing, one shared expert. The
+        # selection bias is a trained buffer that starts at zero: drawn
+        # non-zero here, so that a loader that dropped it would show.
+        cfg = transformers.DeepseekV3Config(
+            **{**common, "rms_norm_eps": 1e-5}, moe_intermediate_size=48,
+            n_routed_experts=8, num_experts_per_tok=2, n_shared_experts=1,
+            n_group=1, topk_group=1, norm_topk_prob=True,
+            routed_scaling_factor=1.8, first_k_dense_replace=1,
+            q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=24, rope_scaling=None,
+            attention_bias=False, pad_token_id=0, bos_token_id=1,
+            eos_token_id=2)
+        model = transformers.DeepseekV3ForCausalLM(cfg)
+        with torch.no_grad():
+            for layer in model.model.layers[cfg.first_k_dense_replace:]:
+                layer.mlp.gate.weight.normal_(0.0, 0.5)
+                layer.mlp.gate.e_score_correction_bias.normal_(0.0, 0.1)
     else:
         raise ValueError(f"unknown family {family!r}")
     model = model.to(getattr(torch, torch_dtype))

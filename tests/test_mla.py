@@ -121,9 +121,10 @@ def test_preemption_recompute_continues():
 
 def test_attn_backend_provenance():
     eng = _engine()
-    # auto off-TPU: the absorbed XLA impl is the DESIGNED backend for the
-    # mixed-batch programs (and for decode on CPU), not a fallback — the
-    # reason field must stay empty so real fallbacks are observable
+    # auto off-TPU: the absorbed XLA impl is the CPU's designed backend for
+    # every step program (on a TPU all of them take ops/mla_attention), not a
+    # fallback — the reason field must stay empty so real fallbacks are
+    # observable
     assert eng.attn_backend == "xla_mla_absorbed"
     assert eng.attn_fallback_reason is None
     assert eng.kv_pack == 1  # nothing to pack: one shared latent head
@@ -173,7 +174,7 @@ def test_lora_on_mla_raises():
         _engine(lora=LoRAConfig(max_adapters=2, rank=4))
 
 
-# -------------------------------------------------- latent-width Pallas decode
+# ------------------------------------- the latent Pallas kernel, decode rows
 
 
 def _latent_op_inputs(dtype):
@@ -200,24 +201,34 @@ def _latent_op_inputs(dtype):
             jnp.asarray(pt), jnp.asarray(kv_lens))
 
 
+def _decode_rows(q, kv_lens) -> dict:
+    """One query row a sequence, as the fused decode call packs them."""
+    import jax.numpy as jnp
+
+    B = q.shape[0]
+    return dict(positions=kv_lens - 1, seq_slots=jnp.arange(B, dtype=jnp.int32),
+                kv_lens=kv_lens, scale=(64 + 16) ** -0.5,
+                cu_q_lens=jnp.arange(B + 1, dtype=jnp.int32),
+                num_seqs=jnp.asarray([B], jnp.int32))
+
+
 def _latent_parity(dtype, tol):
     import jax.numpy as jnp
 
     from llmd_tpu.models.transformer import ragged_paged_attention_xla
-    from llmd_tpu.ops.mla_decode import mla_paged_attention_latent
+    from llmd_tpu.ops.mla_attention import mla_paged_attention
 
     q, cache, pt, kv_lens = _latent_op_inputs(dtype)
-    B = q.shape[0]
-    kw = dict(positions=kv_lens - 1, seq_slots=jnp.arange(B, dtype=jnp.int32),
-              kv_lens=kv_lens, scale=(64 + 16) ** -0.5)
+    kw = _decode_rows(q, kv_lens)
     ref = ragged_paged_attention_xla(q, cache, pt, **kw)
-    got = mla_paged_attention_latent(q, cache, pt, interpret=True, **kw)
+    got = mla_paged_attention(q, cache, pt, interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
 
 
 def test_latent_decode_kernel_parity_fp32():
-    """The latent Pallas decode kernel vs the XLA reference, elementwise: the
+    """The latent Pallas kernel on decode rows vs the XLA reference,
+    elementwise: the
     online-softmax accumulation over pages must match the gather+mask softmax
     at fp32 to float-roundoff, across empty/partial/full page tables."""
     import jax.numpy as jnp
@@ -238,16 +249,14 @@ def test_latent_decode_kernel_tp4_split_matches_unsharded():
     import jax
     import jax.numpy as jnp
 
-    from llmd_tpu.ops.mla_decode import mla_paged_attention_latent
+    from llmd_tpu.ops.mla_attention import mla_paged_attention
     from llmd_tpu.parallel.mesh import MeshConfig, build_mesh
 
     q, cache, pt, kv_lens = _latent_op_inputs(jnp.float32)
-    B = q.shape[0]
-    kw = dict(positions=kv_lens - 1, seq_slots=jnp.arange(B, dtype=jnp.int32),
-              kv_lens=kv_lens, scale=(64 + 16) ** -0.5)
-    want = mla_paged_attention_latent(q, cache, pt, interpret=True, **kw)
+    kw = _decode_rows(q, kv_lens)
+    want = mla_paged_attention(q, cache, pt, interpret=True, **kw)
     got = jax.jit(functools.partial(
-        mla_paged_attention_latent, scale=kw.pop("scale"), interpret=True,
+        mla_paged_attention, scale=kw.pop("scale"), interpret=True,
         mesh=build_mesh(MeshConfig(tp=4))))(q, cache, pt, **kw)
     assert np.abs(np.asarray(want)).max() > 0.1
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -255,14 +264,14 @@ def test_latent_decode_kernel_tp4_split_matches_unsharded():
 
 
 def test_explicit_pallas_latent_decode_serves_with_parity():
-    """attn_impl='pallas' on MLA (formerly a ValueError) now routes the fused-
-    decode program through the latent Pallas kernel — interpret-mode off-TPU —
-    while mixed-batch programs keep the absorbed XLA impl. Greedy tokens must
+    """attn_impl='pallas' on MLA routes every step program (the unified step
+    and the fused decode call) through the latent Pallas kernel, in interpret
+    mode off-TPU. Greedy tokens must
     match the pure-reference engine exactly, and the backend/fallback
     provenance must show a deliberate selection, not a silent fallback."""
     sp = SamplingParams(max_tokens=8, temperature=0.0)
     eng = _engine(attn_impl="pallas")
-    assert eng.attn_backend == "pallas_mla_latent_decode"
+    assert eng.attn_backend == "pallas_mla_ragged_paged_attention"
     assert eng.attn_fallback_reason is None
     got = eng.generate(PROMPTS[:2], sp)
     ref = _engine(attn_impl="reference").generate(PROMPTS[:2], sp)

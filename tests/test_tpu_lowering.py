@@ -21,7 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from llmd_tpu.models import get_model_config
 from llmd_tpu.models.transformer import padded_head_dim
 from llmd_tpu.ops.grouped_gemm import grouped_gemm, ragged_grouped_gemm
-from llmd_tpu.ops.mla_decode import mla_paged_attention_latent
+from llmd_tpu.ops.mla_attention import mla_paged_attention
 from llmd_tpu.ops.packed_kv import make_packed_attn, pack_factor
 from llmd_tpu.ops.paged_attention import paged_attention_tpu
 from llmd_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -253,13 +253,13 @@ def test_paged_attention_lowers_under_tp4_sharding():
     _lower_for_tpu(fn, *args)
 
 
-def test_mla_latent_decode_lowers_for_tpu():
+def test_mla_latent_kernel_lowers_for_tpu():
     cfg = get_model_config("moe-wide-mla")
     dhp = padded_head_dim(cfg.mla_kv_lora_rank + cfg.mla_rope_dim)
     B, maxp = 16, 8
 
     def fn(q, cache, pt, pos, slots, lens, cu, ns):
-        return mla_paged_attention_latent(
+        return mla_paged_attention(
             q, cache, pt, pos, slots, lens, scale=0.125, cu_q_lens=cu,
             num_seqs=ns, interpret=False)
 
@@ -267,14 +267,14 @@ def test_mla_latent_decode_lowers_for_tpu():
                                    (B * maxp, 16, 1, dhp), B, maxp))
 
 
-def test_mla_latent_decode_lowers_under_tp4_sharding():
+def test_mla_latent_kernel_lowers_under_tp4_sharding():
     cfg = get_model_config("moe-wide-mla")
     dhp = padded_head_dim(cfg.mla_kv_lora_rank + cfg.mla_rope_dim)
     B, maxp = 16, 8
     mesh = build_mesh(MeshConfig(tp=4))
 
     def fn(q, cache, pt, pos, slots, lens, cu, ns):
-        return mla_paged_attention_latent(
+        return mla_paged_attention(
             q, cache, pt, pos, slots, lens, scale=0.125, cu_q_lens=cu,
             num_seqs=ns, interpret=False, mesh=mesh)
 
@@ -282,6 +282,37 @@ def test_mla_latent_decode_lowers_under_tp4_sharding():
                       maxp, mesh=mesh, q_spec=P(None, "tp", None),
                       cache_spec=P())
     _lower_for_tpu(fn, *args)
+
+
+@pytest.mark.parametrize("n", [64, 256], ids=["decode", "unified"])
+def test_mla_latent_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip, n):
+    """The latent kernel at glm-4.7-flash's shapes (20 heads padded to 32
+    over a 640-lane single-plane pool of 7 x 28,672 pages, page tables of
+    1,280 entries for 64 rows in scalar memory, 64 pages a KV block) and both
+    step programs' token budgets goes through Mosaic and the TPU compiler
+    here: the manual page copies in dynamic loops, the scalar-prefetched
+    table's size and the VMEM of two KV buffers are what interpret mode
+    cannot refuse."""
+    from llmd_tpu.ops.mla_attention import pick_block_sizes
+
+    B, maxp, H, lanes, pages = 64, 1280, 20, 640, 7 * 28672
+    assert pick_block_sizes(n, B, 16, maxp) == (64, 1 if n == B else 16)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, cache, pt, lens, cu, ns):
+        return mla_paged_attention(q, cache, pt, None, None, lens,
+                                   scale=1 / 16, cu_q_lens=cu, num_seqs=ns)
+
+    i32 = jnp.int32
+    compiled = jax.jit(fn).lower(
+        spec((n, H, lanes), jnp.bfloat16),
+        spec((pages, 16, 1, lanes), jnp.bfloat16), spec((B, maxp), i32),
+        spec((B,), i32), spec((B + 1,), i32), spec((1,), i32)).compile()
+    assert "mla_ragged_paged_attention" in compiled.as_text()
+    # the pool is read in place: no temporary of its size
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
 
 
 def test_grouped_gemms_lower_for_tpu():

@@ -50,8 +50,7 @@ HOT_PATHS: dict[str, object] = {
     ],
     "llmd_tpu/engine/spec.py": "*",
     # step-program registry: the dispatch/complete ledger and routing run
-    # once per engine step. select_decode_attn_impl is startup-only and
-    # stays unchecked.
+    # once per engine step.
     "llmd_tpu/engine/programs.py": [
         "record_dispatch",
         "record_complete",
