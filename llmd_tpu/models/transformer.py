@@ -617,16 +617,19 @@ def init_state(cfg: ModelConfig, seats: int) -> dict[str, jax.Array]:
     """The recurrent-state pool of a model with mamba layers, a slot a seat:
     ``ssm`` [Lm, seats + 1, N, Di] in ``cfg.mamba_state_dtype`` (d_state on
     the sublanes, d_inner on the lanes: what ops/selective_scan reads) and
-    ``conv`` [Lm, seats + 1, K - 1, Di] in the model's dtype (the last K - 1
-    pre-conv rows). Slot ``seats`` is scratch: a unified step's padding rows
-    are mapped to it, so that every row of a call has a slot to name and none
-    of them a seat's. ``forward_core`` takes the pool in the ``cache``
-    argument, as ``{"kv": pool, **state}``."""
-    shape = (cfg.num_mamba_layers, seats + 1)
+    ``conv`` [Lm, K - 1, seats + 1, Di] in the model's dtype: the last K - 1
+    pre-conv rows of every slot, kept as planes whose two minor dimensions
+    are (slots, d_inner), so that both step programs move whole tiles
+    (``conv_window`` says what they moved slot major). Slot ``seats`` is
+    scratch: a unified step's padding rows are mapped to it, so that every
+    row of a call has a slot to name and none of them a seat's.
+    ``forward_core`` takes the pool in the ``cache`` argument, as
+    ``{"kv": pool, **state}``."""
+    Lm, S = cfg.num_mamba_layers, seats + 1
     return {
-        "ssm": jnp.zeros(shape + (cfg.mamba_d_state, cfg.mamba_d_inner),
+        "ssm": jnp.zeros((Lm, S, cfg.mamba_d_state, cfg.mamba_d_inner),
                          jnp.dtype(cfg.mamba_state_dtype)),
-        "conv": jnp.zeros(shape + (cfg.mamba_d_conv - 1, cfg.mamba_d_inner),
+        "conv": jnp.zeros((Lm, cfg.mamba_d_conv - 1, S, cfg.mamba_d_inner),
                           cfg.jax_dtype),
     }
 
@@ -847,72 +850,169 @@ def _inner_norms(cfg: ModelConfig, dbc: jax.Array, w: jax.Array) -> tuple:
     return out[:, :R], out[:, R:R + Nst], out[:, R + Nst:]
 
 
+def mamba_vectors(cfg: ModelConfig, params: dict) -> dict:
+    """The mamba layers' vectors, float32 and side by side, as ``mamba_mixer``
+    takes them: ``mamba_vec`` [Lm, K + 3, Di] (conv taps, conv bias, dt bias,
+    D) and ``mamba_norms`` [Lm, R + 2 Nst]. A layer then slices two arrays
+    where it would slice and convert eight (each a device operation of its
+    own a layer a step, which a trace pays for by the event)."""
+    f32 = lambda k: params[k].astype(jnp.float32)  # noqa: E731
+    bias = (f32("mamba_conv_b") if cfg.mamba_conv_bias
+            else jnp.zeros_like(f32("mamba_d")))
+    return {"mamba_vec": jnp.concatenate(
+                [f32("mamba_conv_w")] + [v[:, None] for v in (
+                    bias, f32("mamba_dt_bias"), f32("mamba_d"))], axis=1),
+            "mamba_norms": jnp.concatenate(
+                [f32("mamba_dt_norm"), f32("mamba_b_norm"),
+                 f32("mamba_c_norm")], axis=-1)}
+
+
+def window_plan(conv_shape: tuple, n_tokens: int, row_slots, seq_slots,
+                cu_q_lens: jax.Array, live: jax.Array, fresh: jax.Array) -> dict:
+    """What ``conv_window`` needs to know of a call's packing, which is the
+    same for every layer: worked out once a call, outside the scans over the
+    layers (inside them the compiler leaves every small index operation in
+    the loop, a device operation of a microsecond or more a layer each).
+
+    The fused decode call (``row_slots`` None) needs its rows' flags only. A
+    unified step is seen from two sides. By slot: ``held`` [S] whether a live
+    row holds the slot, ``zeroed`` [S] whether that row is fresh, ``n`` [S]
+    its chunk's length, and for the window's plane k the chunk row that
+    becomes the slot's row k (``last`` [K-1, S], where ``from_chunk``). By
+    token: its slot, its offset into its chunk (held to 0 .. K-2) and, for
+    tap t, whether it reads the window (``in_window`` [K-1, N])."""
+    if row_slots is None:
+        return {"live": live, "fresh": fresh}
+    _, k1, S, _ = conv_shape
+    B, N = live.shape[0], n_tokens
+    hit = (row_slots[None, :] == jnp.arange(S, dtype=jnp.int32)[:, None]) \
+        & live[None, :]  # [S, B]: at most one live row a slot
+    held, row = hit.any(axis=1), jnp.argmax(hit, axis=1)
+    n = jnp.where(held, (cu_q_lens[1:] - cu_q_lens[:-1])[row], 0)
+    e = n - k1 + jnp.arange(k1, dtype=jnp.int32)[:, None]  # [K-1, S]
+    b = jnp.clip(seq_slots, 0, B - 1)
+    off = jnp.arange(N, dtype=jnp.int32) - cu_q_lens[b]
+    return {"held": held, "zeroed": held & fresh[row], "n": n,
+            "last": jnp.clip(cu_q_lens[row] + e, 0, N - 1),
+            "from_chunk": e >= 0,
+            "slot": row_slots[b], "off": jnp.clip(off, 0, k1 - 1),
+            "in_window": (off >= 0) & (
+                off < k1 - jnp.arange(k1, dtype=jnp.int32)[:, None])}
+
+
+def conv_window(conv: jax.Array, o, xr: jax.Array, plan: dict):
+    """The causal conv's inputs for the pre-conv rows ``xr`` [N, Di] of mamba
+    layer ``o``, and the window pool after them: returns (taps, conv), taps[k]
+    [N, Di] being the row K - 1 - k tokens before each token of its sequence.
+
+    ``conv`` [Lm, K - 1, S, Di] holds every slot's window (its last K - 1
+    pre-conv rows, oldest first) as planes: window row k of all the slots of
+    a layer is one dense [S, Di] array, so whatever is read, chosen or written
+    here has (rows, d_inner) as its two minor dimensions and fills its tiles.
+    Kept slot major, [S, K - 1, Di], the K - 1 = 3 rows would sit alone on a
+    bf16 sublane tile; the TPU compiler therefore kept that pool as planes of
+    its own accord and paid for the difference: a transposition of the whole
+    pool at every forward, where the program folded the layer into the slot
+    axis, and a layer's update at an offset off the tile's boundary (PERF.md
+    section 5, "since PR 40"). The layer is the pool's leading axis (``o``
+    traced), so a layer's planes start on a tile boundary whatever S is.
+    ``plan`` is the call's ``window_plan``.
+
+    A token fewer than j tokens into its row's chunk finds the row j tokens
+    back in the window, any other in ``xr``; a fresh row's window reads as
+    zeros. Afterwards a live row's window is the last K - 1 rows of
+    [window ; chunk]; a row that is not live leaves its slot as it is. In the
+    fused decode call (row b is seat b and brings one token) the taps are the
+    planes' first B rows, and the new window is plane k <- plane k + 1, last
+    plane <- ``xr``."""
+    K, S = conv.shape[1] + 1, conv.shape[2]
+    N, Di = xr.shape
+    zero = jnp.zeros((), conv.dtype)
+    if "live" in plan:  # the fused decode call: row b is seat b
+        assert N == plan["live"].shape[0], "one token a seat"
+        win = lax.dynamic_slice(conv, (o, 0, 0, 0), (1, K - 1, N, Di))[0]
+        use = jnp.where(plan["fresh"][None, :, None], zero, win)
+        new_win = jnp.concatenate([use[1:], xr[None]], axis=0)  # [K-1, B, Di]
+        conv = lax.dynamic_update_slice(
+            conv, jnp.where(plan["live"][None, :, None], new_win, win)[None],
+            (o, 0, 0, 0))
+        return [use[k] for k in range(K - 1)] + [xr], conv
+    # A unified step. On the chip a gather moves some 190 MB a millisecond
+    # and a scatter some 45, where a dense select runs at the memory's rate
+    # (PERF.md section 6, PR 40), so the window is worked on where it lies,
+    # slot by slot and dense, and rows are gathered twice only: the window
+    # rows of a chunk's first K - 1 tokens, one row of (K - 1) x Di a token,
+    # and the chunks' last K - 1 rows, one a slot and plane. The layer's
+    # planes are taken out of the pool and put back whole: given the pool
+    # itself (64 MB at jamba2-3b's sizes) as a gather's operand, the TPU
+    # compiler moves all of it to VMEM and back around every layer.
+    planes = lax.dynamic_index_in_dim(conv, o, 0, keepdims=False)
+    use = jnp.where(plan["zeroed"][None, :, None], zero, planes)
+    # wall[off, s]: the window rows a token `off` tokens into slot s's chunk
+    # still reads, side by side in its taps' order: tap t reads row t + off
+    none = jnp.zeros((S, Di), conv.dtype)
+    wall = jnp.stack([jnp.concatenate(
+        [use[t + off] if t + off < K - 1 else none for t in range(K - 1)],
+        axis=1) for off in range(K - 1)])  # [K-1, S, (K-1) Di]
+    before = wall.at[plan["off"], plan["slot"]].get(
+        mode="promise_in_bounds")  # [N, (K-1) Di]
+    # xr[i - j] where the row j tokens back lies in the chunk. The rows before
+    # the array's start, which no token of a chunk is given, take the array's
+    # first element and not a literal: around a literal XLA's CPU backend
+    # compiles those rows apart and rounds their conv otherwise (no fused
+    # multiply-add), and the tests' float32 runs then show a prompt's split.
+    def back(j):
+        return lax.pad(xr, xr[0, 0], ((j, -j, 0), (0, 0, 0)))
+
+    taps = [jnp.where(plan["in_window"][t][:, None],
+                      before[:, t * Di:(t + 1) * Di], back(K - 1 - t))
+            for t in range(K - 1)] + [xr]
+    # the last K-1 rows of [window ; chunk]: plane k takes a row of the chunk,
+    # or window row k + n
+    new = []
+    for k in range(K - 1):
+        kept = use[k]
+        for m in range(1, K - 1 - k):
+            kept = jnp.where((plan["n"] == m)[:, None], use[k + m], kept)
+        last = xr.at[plan["last"][k]].get(mode="promise_in_bounds")
+        row = jnp.where(plan["from_chunk"][k][:, None], last, kept)
+        new.append(jnp.where(plan["held"][:, None], row, planes[k]))
+    return taps, lax.dynamic_update_index_in_dim(conv, jnp.stack(new), o, 0)
+
+
 def mamba_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
-                ssm: jax.Array, base, row_slots, seq_slots: jax.Array,
+                ssm: jax.Array, o, plan: dict, row_slots,
                 cu_q_lens: jax.Array, live: jax.Array, fresh: jax.Array,
                 scan_impl, mm):
-    """One mamba layer's mixer on the normed rows ``h`` [N, D] of a flat mixed
-    batch; returns (out [N, D], conv pool, ssm pool).
+    """Mamba layer ``o``'s mixer (``o`` traced: the layer's ordinal among the
+    mamba layers) on the normed rows ``h`` [N, D] of a flat mixed batch;
+    returns (out [N, D], conv pool, ssm pool).
 
-    ``conv`` [Lm * S, K - 1, Di] and ``ssm`` [Lm * S, Nst, Di] are the pools
-    with the layer folded into the slot axis; ``base`` is this layer's first
-    row of them (traced). ``row_slots`` [B] names each batch row's slot, or is
-    None where row b is seat b and brings one token (the fused decode call:
-    the window is then a slice and the conv elementwise, no gather). Rows
-    that are not ``live`` leave both pools as they are; a ``fresh`` row (first
-    position 0) starts from a zero window and a zero state.
+    ``conv`` [Lm, K - 1, S, Di] is the conv window's pool, a layer's slots
+    the rows of K - 1 planes (``conv_window``, with the call's ``plan``);
+    ``ssm`` [Lm * S, Nst, Di] the state pool with the layer folded into the
+    slot axis, as the scan kernel indexes it. ``row_slots`` [B] names each
+    batch row's slot, or is None where row b is seat b and brings one token
+    (the fused decode call: no gather). Rows that are not ``live`` leave both
+    pools as they are; a ``fresh`` row (first position 0) starts from a zero
+    window and a zero state.
 
     Matrix products run in the model's dtype with float32 results; the conv,
     the three inner norms, softplus, the recurrence (``scan_impl``,
     ops/selective_scan) and the gate are float32. ``mm(key, pattern, x)`` is
     the layer's int8-aware weight product. ``lp`` holds the layer's matrices
-    and ``mamba_a_log`` as stored, and its vectors as ``_hybrid_stack`` packs
+    and ``mamba_a_log`` as stored, and its vectors as ``mamba_vectors`` packs
     them: ``mamba_vec`` and ``mamba_norms``."""
-    N = h.shape[0]
     B = live.shape[0]
-    Di, K, R, Nst = (cfg.mamba_d_inner, cfg.mamba_d_conv, cfg.mamba_dt_rank,
-                     cfg.mamba_d_state)
+    Di, K = cfg.mamba_d_inner, cfg.mamba_d_conv
     dt_ = cfg.jax_dtype
     xz = mm("mamba_in", "nd,de->ne", h)
     xr, z = xz[:, :Di], xz[:, Di:]  # pre-conv rows, gate (model dtype)
     vec = lp["mamba_vec"]  # [K + 3, Di] float32: conv taps, conv bias, dt bias, D
-    w_c = vec[:K]
-    if row_slots is None:
-        assert N == B, "one token a seat where no row -> slot map is given"
-        win = lax.dynamic_slice_in_dim(conv, base, B, axis=0)  # [B, K-1, Di]
-        use = jnp.where(fresh[:, None, None], jnp.zeros_like(win), win)
-        taps = [use[:, k] for k in range(K - 1)] + [xr]
-        new_win = jnp.concatenate([use[:, 1:], xr[:, None]], axis=1)
-        conv = lax.dynamic_update_slice_in_dim(
-            conv, jnp.where(live[:, None, None], new_win, win), base, axis=0)
-        slots = base + jnp.arange(B, dtype=jnp.int32)
-    else:
-        slots = base + row_slots
-        win = conv[slots]  # [B, K-1, Di]
-        win = jnp.where(fresh[:, None, None], jnp.zeros_like(win), win)
-        b = jnp.clip(seq_slots, 0, B - 1)
-        off = jnp.arange(N, dtype=jnp.int32) - cu_q_lens[b]  # row offset
-        win_tok = win[b]  # [N, K-1, Di]
-        taps = []
-        for j in range(K - 1, 0, -1):  # the row j tokens back
-            tap = jnp.pad(xr, ((j, 0), (0, 0)))[:N]  # xr[i - j], in the chunk
-            for o in range(j):  # before the chunk: window row K-1-j+o
-                tap = jnp.where((off == o)[:, None], win_tok[:, K - 1 - j + o],
-                                tap)
-            taps.append(tap)
-        taps.append(xr)
-        # the window after the call: the last K-1 rows of [window ; chunk]
-        n = cu_q_lens[1:] - cu_q_lens[:-1]  # [B]
-        e = n[:, None] - (K - 1) + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
-        from_x = xr[jnp.clip(cu_q_lens[:-1, None] + e, 0, N - 1)]
-        from_win = jnp.take_along_axis(
-            win, jnp.clip(e + (K - 1), 0, K - 2)[:, :, None], axis=1)
-        new_win = jnp.where((e >= 0)[:, :, None], from_x, from_win)
-        conv = conv.at[jnp.where(live, slots, conv.shape[0])].set(
-            new_win, mode="drop")
+    taps, conv = conv_window(conv, o, xr, plan)
     acc = vec[K]
     for k in range(K):
-        acc = acc + w_c[k] * taps[k].astype(jnp.float32)
+        acc = acc + vec[k] * taps[k].astype(jnp.float32)
     x = jax.nn.silu(acc)  # [N, Di] float32
     dbc = mm("mamba_x", "ne,er->nr", x.astype(dt_), jnp.float32)
     dlow, Bm, Cm = _inner_norms(cfg, dbc, lp["mamba_norms"])
@@ -920,6 +1020,8 @@ def mamba_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
         mm("mamba_dt", "nr,re->ne", dlow.astype(dt_), jnp.float32)
         + vec[K + 1])
     A = -jnp.exp(lp["mamba_a_log"].astype(jnp.float32))  # [Nst, Di]
+    slots = o * conv.shape[2] + (
+        jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
     y, ssm = scan_impl(x, delta, Bm, Cm, A, ssm, slots, cu_q_lens, live, fresh)
     y = y + vec[K + 2] * x
     y = y * jax.nn.silu(z.astype(jnp.float32))
@@ -954,6 +1056,15 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
     inner loop would be copied out. ``attention_layer`` is ``forward_core``'s
     own layer, given the attention ordinal as its pool offset.
 
+    The state pools ride the scans as carries, updated in place. The SSM pool
+    is folded to [Lm * S, Nst, Di] on the way in and unfolded on the way out
+    (its minor dimensions are whole tiles, so neither moves a byte) because
+    the scan kernel names a state block by one index; the conv window's pool
+    keeps its layer as a leading axis of its own, which a mamba layer indexes
+    by its ordinal: folded into the slot axis, every layer but the first
+    would start off a sublane tile's boundary (S = seats + 1), and the fold
+    itself was a relayout of the pool at every call's entry and exit.
+
     Returns (x, flat KV pool, state)."""
     from llmd_tpu.ops.selective_scan import row_flags, selective_scan_xla
 
@@ -961,25 +1072,16 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
     scan_impl = scan_impl or selective_scan_xla
     Lm, S1 = state["ssm"].shape[:2]
     ssm = state["ssm"].reshape((Lm * S1,) + state["ssm"].shape[2:])
-    conv = state["conv"].reshape((Lm * S1,) + state["conv"].shape[2:])
+    conv = state["conv"]
     live, fresh = row_flags(positions, cu_q_lens)
+    plan = window_plan(conv.shape, x.shape[0], state_slots, seq_slots,
+                       cu_q_lens, live, fresh)
 
     def present(*keys):
         return tuple(v for k in keys for v in (k, k + "_q", k + "_scale")
                      if v in params)
 
-    # a mamba layer's vectors, float32 and side by side: a layer then slices
-    # two arrays where it would slice and convert eight (each a device
-    # operation of its own a layer a step, which a trace pays for by the event)
-    f32 = lambda k: params[k].astype(jnp.float32)  # noqa: E731
-    bias = (f32("mamba_conv_b") if cfg.mamba_conv_bias
-            else jnp.zeros_like(f32("mamba_d")))
-    params = dict(params, mamba_vec=jnp.concatenate(
-        [f32("mamba_conv_w")] + [v[:, None] for v in (
-            bias, f32("mamba_dt_bias"), f32("mamba_d"))], axis=1),
-        mamba_norms=jnp.concatenate(
-            [f32("mamba_dt_norm"), f32("mamba_b_norm"), f32("mamba_c_norm")],
-            axis=-1))
+    params = dict(params, **mamba_vectors(cfg, params))
     shared = present("attn_norm", "mlp_norm", "wi", "wo_mlp")
     own = {"mamba": present("mamba_in", "mamba_x", "mamba_dt", "mamba_a_log",
                             "mamba_out", "mamba_vec", "mamba_norms"),
@@ -1004,8 +1106,8 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
 
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         o_mix, conv, ssm = mamba_mixer(
-            cfg, lp, h, conv, ssm, o * S1, state_slots, seq_slots, cu_q_lens,
-            live, fresh, scan_impl, mm)
+            cfg, lp, h, conv, ssm, o, plan, state_slots, cu_q_lens, live,
+            fresh, scan_impl, mm)
         x = x + o_mix
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         y = swiglu(h, None, None, mm=mm) if "wi_q" in lp else swiglu(
@@ -1033,7 +1135,7 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
         one_period, (x, flat_cache, conv, ssm),
         jnp.arange(cfg.num_layers // period, dtype=jnp.int32))
     return x, flat_cache, {"ssm": ssm.reshape(state["ssm"].shape),
-                           "conv": conv.reshape(state["conv"].shape)}
+                           "conv": conv}
 
 
 def forward_core(

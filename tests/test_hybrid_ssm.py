@@ -111,6 +111,13 @@ def _serve(cfg, params, tokens, chunks, pools=None, slot=2, scan_impl=None):
     return np.concatenate(out), pools
 
 
+def _slots(pools, k: str, s):
+    """Slots ``s`` (one or a list) of the state pool ``k``: the slot is the
+    SSM pool's second axis, and the third of the conv window's, which keeps a
+    layer's slots as the rows of its K - 1 planes."""
+    return np.take(np.asarray(pools[k]), s, axis=2 if k == "conv" else 1)
+
+
 def _worst(a, b) -> float:
     return float(np.abs(np.asarray(a) - np.asarray(b)).max())
 
@@ -265,10 +272,10 @@ def test_the_state_after_a_prompt_does_not_depend_on_the_split(
     assert _worst(got, want) < TOLERANCE
     assert np.array_equal(got, whole)
     for k in ("ssm", "conv"):
-        assert np.array_equal(p0[k][:, 2], p1[k][:, 2])
+        assert np.array_equal(_slots(p0, k, 2), _slots(p1, k, 2))
         # the poisoned slots of the other seats and the scratch slot
-        assert np.array_equal(p1[k][:, [0, 1, 3, 4]],
-                              _pools(poison=7.0)[k][:, [0, 1, 3, 4]])
+        assert np.array_equal(_slots(p1, k, [0, 1, 3, 4]),
+                              _slots(_pools(poison=7.0), k, [0, 1, 3, 4]))
 
 
 def test_decode_rows_through_the_slots_equal_the_full_forward(params, tokens,
@@ -292,9 +299,123 @@ def test_decode_rows_through_the_slots_equal_the_full_forward(params, tokens,
                                    jnp.asarray([1, t + 1, 1, 1], jnp.int32))
         assert _worst(unembed(CFG, params, hidden[1]), want[t]) < TOLERANCE
     for k in ("ssm", "conv"):
-        assert np.array_equal(np.asarray(pools[k])[:, [0, 2, 3, 4]],
-                              before[k][:, [0, 2, 3, 4]])
-        assert not np.array_equal(np.asarray(pools[k])[:, 1], before[k][:, 1])
+        assert np.array_equal(_slots(pools, k, [0, 2, 3, 4]),
+                              _slots(before, k, [0, 2, 3, 4]))
+        assert not np.array_equal(_slots(pools, k, 1), _slots(before, k, 1))
+
+
+# ------------------------------------ (c') the window's pool, plane by plane
+
+def _mixer_case(chunk):
+    """A mamba layer's call as both step programs pack it. ``chunk`` None:
+    the fused decode call (row b is seat b, one token each): a row under way,
+    a fresh row, a frozen row (position -1) and another under way. Otherwise
+    a unified step over NT flat tokens: a chunk of ``chunk`` tokens from
+    position 5 in slot 2, a fresh chunk as long in slot 0, a frozen row of
+    one token in slot 3 and a padding row on the scratch slot.
+    Returns (positions [N], seq_slots [N], cu_q_lens [B + 1], row_slots)."""
+    if chunk is None:
+        pos = np.asarray([9, 0, -1, 30], np.int32)
+        return (pos, np.arange(ROWS, dtype=np.int32),
+                np.arange(ROWS + 1, dtype=np.int32), None)
+    n = 2 * NT
+    cu = np.asarray([0, chunk, 2 * chunk, 2 * chunk + 1, 2 * chunk + 1],
+                    np.int32)
+    pos, seq = np.full((n,), -1, np.int32), np.full((n,), ROWS - 1, np.int32)
+    pos[:chunk], pos[chunk:2 * chunk] = 5 + np.arange(chunk), np.arange(chunk)
+    for b in range(ROWS):
+        seq[cu[b]:cu[b + 1]] = b
+    return pos, seq, cu, np.asarray([2, 0, 3, SEATS], np.int32)
+
+
+def _window_by_slot(cu, row_slots, live, fresh):
+    """``conv_window``'s semantics, plainly and slot-major, for a call whose
+    packing is known (numpy arrays): a slot's window [K - 1, Di] is its last
+    K - 1 pre-conv rows, oldest first. A live row's tokens read
+    [its window ; its chunk] (a fresh row's window is zeros), and the last
+    K - 1 rows of that are its window afterwards; any other row leaves its
+    slot alone. Takes and returns the pool of planes, transposed on the way
+    in and out."""
+    def conv_window(conv, o, xr, *_):
+        k1 = conv.shape[1]
+        window = conv[o].transpose(1, 0, 2)  # [S, K - 1, Di]
+        taps = jnp.zeros((k1 + 1,) + xr.shape, xr.dtype)
+        for b in np.flatnonzero(live):
+            slot = b if row_slots is None else row_slots[b]
+            old = jnp.zeros_like(window[slot]) if fresh[b] else window[slot]
+            n = cu[b + 1] - cu[b]
+            seq = jnp.concatenate([old, xr[cu[b]:cu[b + 1]]])
+            for k in range(k1 + 1):  # the row K - 1 - k tokens back
+                taps = taps.at[k, cu[b]:cu[b + 1]].set(seq[k:k + n])
+            window = window.at[slot].set(seq[n:])
+        return list(taps), conv.at[o].set(window.transpose(1, 0, 2))
+    return conv_window
+
+
+@pytest.mark.parametrize("chunk", [None, 1, 2, 3, 40],
+                         ids=["fused_decode", "chunk_1", "chunk_2", "chunk_3",
+                              "chunk_40"])
+def test_the_mixer_equals_a_slot_major_reference_bit_for_bit(chunk, params,
+                                                             monkeypatch):
+    """``mamba_mixer`` over the pool of planes [Lm, K - 1, S, Di] against the
+    same mixer with a plain slot-major window in ``conv_window``'s place: the
+    output of every live token, the window pool and the state pool are equal
+    to the last bit, in both step programs' packings, for chunks shorter
+    than, as long as and longer than the window, a fresh row, a frozen row
+    (slot untouched) and a padding row (no slot written, the scratch slot
+    included)."""
+    from llmd_tpu.models import transformer
+    from llmd_tpu.models.transformer import (
+        _weight_mm, mamba_mixer, mamba_vectors, window_plan)
+
+    layer = 4  # the ordinal among the mamba layers: not the pool's first
+    pos, seq, cu, row_slots = _mixer_case(chunk)
+    live, fresh = row_flags(jnp.asarray(pos), jnp.asarray(cu))
+    rng = np.random.default_rng(5)
+    state = {k: jnp.asarray(rng.normal(size=v.shape), v.dtype)
+             for k, v in init_state(CFG, SEATS).items()}
+    ssm = state["ssm"].reshape((-1,) + state["ssm"].shape[2:])
+    h = jnp.asarray(rng.normal(size=(len(pos), CFG.hidden_size)), jnp.float32)
+    lp = {k: v[layer] for k, v in {
+        **params, **mamba_vectors(CFG, params)}.items() if k.startswith("mamba")}
+
+    def mixer(conv):
+        slots = None if row_slots is None else jnp.asarray(row_slots)
+        plan = window_plan(conv.shape, len(pos), slots, jnp.asarray(seq),
+                           jnp.asarray(cu), live, fresh)
+        return mamba_mixer(
+            CFG, lp, h, conv, ssm, jnp.int32(layer), plan, slots,
+            jnp.asarray(cu), live, fresh, selective_scan_xla,
+            lambda key, pattern, x, out=None: _weight_mm(lp, key, pattern, x,
+                                                         out))
+
+    def run(window):
+        """The mixer with ``window`` in ``conv_window``'s place, its taps
+        behind a barrier: the CPU compiler otherwise fuses the conv's sum
+        with whatever made the taps, and rounds it (a fused multiply-add here,
+        none there) as the window's code is written."""
+        def behind_a_barrier(*a):
+            taps, pool = window(*a)
+            return jax.lax.optimization_barrier(taps), pool
+        monkeypatch.setattr(transformer, "conv_window", behind_a_barrier)
+        # a function object each: ``jax.jit`` would hand the second run the
+        # first one's program
+        return jax.jit(lambda c: mixer(c))(state["conv"])
+
+    out, conv, ssm1 = run(transformer.conv_window)
+    want, want_conv, ssm0 = run(_window_by_slot(
+        cu, row_slots, np.asarray(live), np.asarray(fresh)))
+    tokens = np.concatenate([np.arange(cu[b], cu[b + 1])
+                             for b in np.flatnonzero(live)])
+    assert len(tokens) == (3 if chunk is None else 2 * chunk)
+    assert np.array_equal(np.asarray(out)[tokens], np.asarray(want)[tokens])
+    assert np.array_equal(ssm0, ssm1)
+    assert np.array_equal(conv, want_conv)
+    # the reference moved the live rows' slots of this layer and nothing else
+    moved = {(int(l), int(s)) for l, _, s, _ in
+             np.argwhere(np.asarray(want_conv != state["conv"]))}
+    assert moved == {(layer, s) for s in ([0, 1, 3] if chunk is None
+                                          else [2, 0])}
 
 
 # ------------------------------------------------ (d) the kernel itself

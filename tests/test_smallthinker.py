@@ -423,9 +423,13 @@ def test_model_config_refuses_a_pattern_that_does_not_divide_the_depth():
 # operands live inside the Pallas call's wrapper, which no model without a
 # mixture layer reaches and SmallThinker reaches on the TPU only (here it
 # lowers through `_experts_xla`, whose plan `_row_plan` lays out as before).
+# jamba2-3b again at ISSUE 40's tree, which moves that program on purpose: the
+# conv window's pool as planes [Lm, K - 1, S, Di] (`conv_window`); the other
+# three read as they did, and hold a change meant for a model with recurrent
+# layers to that model.
 PARENT_STABLEHLO = {
     "jamba2-3b":
-        "b7aea2ecef0317e454042f52ce2afa9343051215ea199be12ad20774e8bd8a0f",
+        "98101b63b2aa04bc502040c6c748472fa54a63c6e02560bb648a365a30bc40e3",
     "qwen2.5-1.5b":
         "013a1476f5f5b99c3751ef04ac296a74c38b91a40b4de08b9dc62eacaeeb240c",
     "mistral-7b-v0.3":

@@ -177,6 +177,48 @@ def test_selective_scan_lowers_for_tpu():
         _spec((5,), jnp.int32), _spec((4,), jnp.bool_), _spec((4,), jnp.bool_))
 
 
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_hybrid_step_programs_keep_no_window_row_on_the_sublanes(program):
+    """A model with mamba layers (tiny-jamba, bf16, the Pallas scan) through
+    ``forward_core`` as the fused decode call packs it (row b is seat b) and
+    as a unified step does (a row -> slot map): the conv window's pool is
+    planes of [slots, d_inner], so the lowered program holds no bf16 value
+    whose second-minor extent is K - 1: the slot-major pool's shape, which
+    the TPU compiler kept as planes of its own accord and transposed at
+    every forward."""
+    import re
+
+    from llmd_tpu.models.transformer import (
+        forward_core, init_cache, init_params, init_state)
+    from llmd_tpu.ops.selective_scan import selective_scan_pallas
+
+    cfg = get_model_config("tiny-jamba")
+    B, N, maxp = 8, 8 if program == "decode" else 32, 4
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    pools = jax.eval_shape(lambda: {"kv": init_cache(cfg, 32, 16),
+                                    **init_state(cfg, B)})
+    assert pools["conv"].shape == (cfg.num_mamba_layers, cfg.mamba_d_conv - 1,
+                                   B + 1, cfg.mamba_d_inner)
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32)
+
+    def step(params, pools, tokens, positions, seq_slots, pt, lens, cu, slots):
+        return forward_core(
+            cfg, params, pools, tokens, positions, seq_slots, pt, lens,
+            cu_q_lens=cu, num_seqs=jnp.asarray([B], jnp.int32),
+            state_slots=slots if program == "unified" else None,
+            scan_impl=selective_scan_pallas)
+
+    text = _lower_for_tpu(step, params, pools, i32(N), i32(N), i32(N),
+                          i32(B, maxp), i32(B), i32(B + 1), i32(B))
+    assert "selective_scan" in text
+    rows = cfg.mamba_d_conv - 1
+    assert rows not in (B, B + 1, N)  # or the pattern would find a batch
+    found = sorted(set(re.findall(rf"tensor<(?:\d+x)*{rows}x\d+xbf16>", text)))
+    assert not found, found
+
+
 @pytest.mark.parametrize("heads,kv_heads,geometry,block,window", [
     (4, 2, (32, 8), 512, None),     # the even ratios' pair
     (14, 2, (64, 4), 1024, None),   # seven query heads a KV head
