@@ -71,12 +71,14 @@ from llmd_tpu.models.transformer import (
     forward_core,
     init_cache,
     init_params,
+    init_compressed_keys,
     init_state,
     param_logical_axes,
     ragged_paged_attention_xla,
     unembed,
     window_first_page,
 )
+from llmd_tpu.ops.lightning_attention import BLOCK as LIGHTNING_BLOCK
 from llmd_tpu.parallel.mesh import build_mesh
 
 
@@ -591,8 +593,26 @@ class LLMEngine:
         self.state: dict[str, jax.Array] = {}
         if model_cfg.has_recurrent:
             self.state = init_state(model_cfg, engine_cfg.max_batch_size)
-            self.metrics.ssm_state_slots.set_function(
-                lambda: sum(s is not None for s in self.running))
+            for has, gauge in (
+                    (model_cfg.has_mamba, self.metrics.ssm_state_slots),
+                    (model_cfg.has_lightning, self.metrics.linear_state_slots)):
+                if has:
+                    gauge.set_function(
+                        lambda: sum(s is not None for s in self.running))
+        if model_cfg.sparse_topk:
+            # the compressed-key plane, a key a page of the pool: it rides
+            # with the state pools (both are donated with the KV pool)
+            if not model_cfg.has_recurrent or self.mesh is not None or \
+                    self.kv_pack > 1:
+                raise ValueError(
+                    "sparse_topk: served beside recurrent layers (whose "
+                    "engine reuses no prefix and moves no page), on one "
+                    "device, over the padded KV layout")
+            from llmd_tpu.ops.sparse_select import geometry
+
+            geometry(model_cfg, engine_cfg.page_size)  # the stride is a page
+            self.state["ck"] = init_compressed_keys(
+                model_cfg, engine_cfg.num_pages, dtype=self.kv_dtype)
 
         self._eplb = None
         if engine_cfg.eplb is not None and model_cfg.is_moe:
@@ -657,16 +677,30 @@ class LLMEngine:
         ssm_kw: dict = {}
         self.ssm_backend: Optional[str] = None
         if model_cfg.has_recurrent:
-            from llmd_tpu.ops.selective_scan import make_selective_scan
-
             impl = "pallas" if self.attn_backend.startswith("pallas") else "xla"
-            ssm_kw["scan_impl"] = make_selective_scan(
-                impl, interpret=self._pallas_interpret)
-            self.ssm_backend = f"{impl}_selective_scan"
+            if model_cfg.has_mamba:
+                from llmd_tpu.ops.selective_scan import make_selective_scan
+
+                ssm_kw["scan_impl"] = make_selective_scan(
+                    impl, interpret=self._pallas_interpret)
+                self.ssm_backend = f"{impl}_selective_scan"
+                state_dtype = model_cfg.mamba_state_dtype
+            else:
+                from llmd_tpu.ops.lightning_attention import (
+                    make_lightning_attention,
+                )
+
+                ssm_kw["lin_impl"] = make_lightning_attention(
+                    impl, interpret=self._pallas_interpret)
+                self.ssm_backend = f"{impl}_lightning_attention"
+                state_dtype = model_cfg.lightning_state_dtype
             self.metrics.ssm_backend_info.labels(
-                impl=self.ssm_backend,
-                state_dtype=model_cfg.mamba_state_dtype,
+                impl=self.ssm_backend, state_dtype=state_dtype,
                 prefix_reuse="off").set(1)
+        if model_cfg.sparse_topk:
+            # the one-query rows of the selected page tables go to the impl
+            # the fused decode call has (never cut at KV blocks)
+            ssm_kw["query_attn_impl"] = attn_decode
         moe_impl = self._select_moe_impl()
         moe_dispatch_impl = self._select_moe_dispatch()
         self.stats.attn_backend = self.attn_backend
@@ -1002,7 +1036,7 @@ class LLMEngine:
             return jnp.sum(hidden.astype(jnp.float32) * valid, axis=0), cache
 
         donate = dict(donate_argnums=(1,))  # cache is donated — updated in place in HBM
-        if cfg.moe_scoring == "sigmoid":
+        if cfg.moe_scoring == "sigmoid" or cfg.has_lightning:
             # Every rounding the program states is made. Left free, XLA keeps
             # a bf16 value in float32 where it fuses producer and consumer,
             # and what it fuses follows a program's shapes: on the chip a
@@ -1139,9 +1173,12 @@ class LLMEngine:
             return "none" + window
         from llmd_tpu.ops.paged_attention import call_geometry
 
+        # (with sparse selection a call brings one KV head's query heads)
+        heads = self.model_cfg.num_heads // (
+            self.model_cfg.num_kv_heads if self.model_cfg.sparse_topk else 1)
         return " ".join(
             "{}={}x{}".format(prog, *call_geometry(
-                (n, self.model_cfg.num_heads, self.cache.shape[-1]),
+                (n, heads, self.cache.shape[-1]),
                 self.cache.shape, self.cfg.max_pages_per_seq))
             for prog, n in programs) + window
 
@@ -1445,7 +1482,8 @@ class LLMEngine:
         self._eplb_tracker.record(np.asarray(cnt))
         self._eplb_active = True
 
-    def _count_attn_kv(self, program: str, kv_lens, q_lens) -> None:
+    def _count_attn_kv(self, program: str, kv_lens, q_lens,
+                       steps=None) -> None:
         """``attn_kv_tokens_total``, ``attn_query_tokens_total`` and
         ``attn_query_key_pairs_total`` of one dispatched call, from the
         lengths the step already packed."""
@@ -1454,6 +1492,8 @@ class LLMEngine:
                                       self._window_align).items():
             self.metrics.attn_kv_tokens.labels(program=program,
                                                layers=kind).inc(n)
+        if self.model_cfg.sparse_topk:
+            self._count_sparse_rows(program, kv_lens, q_lens, steps)
         # a row's queries are its last q tokens: query i of q sees kv - q + i
         # + 1 keys, q * kv - q * (q - 1) / 2 in all
         kv, q = np.asarray(kv_lens, np.int64), np.asarray(q_lens, np.int64)
@@ -1462,11 +1502,60 @@ class LLMEngine:
         self.metrics.attn_qk_pairs.labels(program=program).inc(
             int((q * kv - q * (q - 1) // 2).sum()))
 
+    def _count_sparse_rows(self, program: str, kv_lens, q_lens,
+                           steps=None) -> None:
+        """``sparse_attn_rows_total``, ``sparse_attn_qk_pairs_total`` and
+        ``attn_kv_tokens_total{layers="sparse"}`` of one dispatched call: its
+        queries by the path they take, the (query, key) pairs the rule asks
+        for, and the tokens their tables hold: a row with a query below
+        ``sparse_dense_len`` its resident tokens once (today's call), and
+        every query past it the tokens of its selected blocks, which is one
+        table a query also where a chunk brings many: the sum can pass what
+        ``layers="full"`` counts once a row. A fused decode call (``steps``
+        [rows]: the steps each row has left) books its rows' queries step by
+        step and the tokens at its first step, as ``layers="full"`` does."""
+        from llmd_tpu.ops.sparse_select import selected_tokens
+
+        kv, q = np.asarray(kv_lens, np.int64), np.asarray(q_lens, np.int64)
+        cfg = self.model_cfg
+        held = n_sparse = n_all = pairs = 0
+        one = kv[q == 1]  # decode rows: one query, so like against like
+        if len(one):
+            self.metrics.sparse_decode_kv_tokens.labels(tokens="held").inc(
+                int(selected_tokens(cfg, one).sum()))
+            self.metrics.sparse_decode_kv_tokens.labels(tokens="context").inc(
+                int(one.sum()))
+        for i, (k, n) in enumerate(zip(kv, q)):
+            if n <= 0:
+                continue
+            seen = np.arange(k - n + 1, k + 1)  # keys each query sees
+            past = seen[seen >= cfg.sparse_dense_len]
+            held += int(selected_tokens(cfg, past).sum()) + (
+                int(k) if len(past) < n else 0)
+            if steps is not None:  # one query a step, a key more each
+                seen = k + np.arange(int(steps[i]))
+                past = seen[seen >= cfg.sparse_dense_len]
+            n_sparse += len(past)
+            n_all += len(seen)
+            pairs += int(selected_tokens(cfg, past).sum()
+                         + seen[seen < cfg.sparse_dense_len].sum())
+        for path, n in (("sparse", n_sparse), ("dense", n_all - n_sparse)):
+            if n:
+                self.metrics.sparse_attn_rows.labels(path=path).inc(n)
+        self.metrics.sparse_attn_qk_pairs.labels(program=program).inc(pairs)
+        self.metrics.attn_kv_tokens.labels(program=program,
+                                           layers="sparse").inc(held)
+
     def _count_ssm_tokens(self, program: str, chunk: int, decode: int) -> None:
         """``ssm_scan_tokens_total`` of one dispatched call: the tokens one
-        mamba layer's scan is given, by the kind of row that brings them."""
+        mamba layer's scan is given, by the kind of row that brings them
+        (``linear_attn_tokens_total`` for a model with lightning layers)."""
+        lightning = self.model_cfg.has_lightning
         for rows, n in (("chunk", chunk), ("decode", decode)):
-            if n:
+            if n and lightning:
+                self.metrics.linear_attn_tokens.labels(
+                    rows="prefill" if rows == "chunk" else rows).inc(n)
+            elif n:
                 self.metrics.ssm_scan_tokens.labels(program=program,
                                                     rows=rows).inc(n)
 
@@ -1504,7 +1593,7 @@ class LLMEngine:
                      "prefill/decode disaggregation transfers pages, not "
                      "recurrent state"),
             "lora": (engine_cfg.lora is not None,
-                     "the mamba mixer has no adapter hook"),
+                     "a recurrent mixer has no adapter hook"),
             "mesh.tp": (engine_cfg.mesh.tp > 1,
                         "one KV head and the mixer's channels are not "
                         "sharded"),
@@ -2244,8 +2333,20 @@ class LLMEngine:
                 break
             if s.slot < 0:
                 continue  # preempted while packing decode rows
-            n = min(self.cfg.prefill_chunk, self._prefill_target(s) - s.num_computed,
-                    budgets[s.rank])
+            left = self._prefill_target(s) - s.num_computed
+            n = min(self.cfg.prefill_chunk, left, budgets[s.rank])
+            if self.model_cfg.has_lightning and n < left:
+                # a lightning layer groups its sums by blocks counted from a
+                # chunk's first token: every chunk but a prompt's last ends
+                # on a block's boundary, so the blocks are the prompt's own
+                n -= n % LIGHTNING_BLOCK
+                if (left - n == 1 and n > LIGHTNING_BLOCK
+                        and self.model_cfg.sparse_topk):
+                    # a token that comes alone takes the selected-table call
+                    # and one in a chunk the block-masked call: no prompt is
+                    # left a last chunk of one token (but by a budget of one
+                    # block, which has nothing to give)
+                    n -= LIGHTNING_BLOCK
             if n <= 0:
                 continue
             if not self._ensure_pages(s, s.num_computed + n):
@@ -2317,8 +2418,11 @@ class LLMEngine:
             if recurrent:
                 row_slots[i] = s.slot
                 if start == 0:  # the row starts from a zero state
-                    self.metrics.ssm_state_resets.labels(
-                        cause="recompute" if s.recompute else "admit").inc()
+                    if self.model_cfg.has_lightning:
+                        self.metrics.linear_state_resets.inc()
+                    else:
+                        self.metrics.ssm_state_resets.labels(
+                            cause="recompute" if s.recompute else "admit").inc()
                     s.recompute = False
             if is_vl and s.mm_items and not is_decode:
                 ph = self.model_cfg.mm_placeholder_id
@@ -3305,7 +3409,8 @@ class LLMEngine:
         ctx_tokens = sum(ctx_lens)
         self.programs.record_dispatch(prog)
         self.metrics.program_kv_read_tokens.labels(program=prog).inc(ctx_tokens)
-        self._count_attn_kv(prog, ctx_lens, np.ones(len(ctx_lens), np.int64))
+        self._count_attn_kv(prog, ctx_lens, np.ones(len(ctx_lens), np.int64),
+                            steps=[steps_left[s.slot] for s in active])
         if self.state:
             # a row takes as many of the call's k steps as it has left
             self._count_ssm_tokens(prog, chunk=0, decode=int(steps_left.sum()))

@@ -44,6 +44,10 @@ class ModelConfig:
     # ``[num_attn_layers, ...]``; norms and the MLP ``[num_layers, ...]``),
     # only attention layers have pages in the KV pool, and a mamba layer
     # keeps a recurrent state per seat beside it (transformer.init_state).
+    # "lightning" is a linear-attention mixer in attention's place: a matrix
+    # state a head a seat (ops/lightning_attention), leaves ``lin_*``
+    # stacked ``[num_lightning_layers, ...]``. A period may be as long as the
+    # stack (a published list of layer types that repeats nothing).
     layer_kinds: tuple = ("attention",)
     mamba_d_inner: int = 0  # channels of the mixer (expand * hidden_size)
     mamba_d_state: int = 16  # SSM state a channel
@@ -53,6 +57,37 @@ class ModelConfig:
     # What the recurrent SSM state is held in between steps; the conv window
     # is held in the model's dtype.
     mamba_state_dtype: str = "float32"
+    # Lightning layers: heads of ``lightning_head_dim`` lanes for q, k and v
+    # alike, q/k RMSNorm a head then RoPE over all lanes, decay ``exp(-2^(-8
+    # (h + 1) / H))`` a token, the output an RMSNorm a head and a sigmoid gate
+    # from the layer's input. The state is held in this type between steps.
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    lightning_state_dtype: str = "float32"
+    # Attention layers' output times ``sigmoid(h W_g)`` (leaf ``wg``) before
+    # the output projection.
+    attn_output_gate: bool = False
+    # Block-sparse attention (0 = every layer attends to all keys). A query
+    # that sees ``sparse_dense_len`` keys or more attends to the first
+    # ``sparse_init_blocks`` blocks of ``sparse_block_size`` tokens, the
+    # blocks of its window and the ``sparse_topk`` best of the rest, ranked
+    # by compressed keys (the mean of ``sparse_kernel_size`` keys every
+    # ``sparse_kernel_stride``; ops/sparse_select). A KV head's query heads
+    # share one selection.
+    sparse_topk: int = 0
+    sparse_block_size: int = 64
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_window: int = 2048
+    sparse_init_blocks: int = 1
+    sparse_dense_len: int = 8192
+    # Scalars on the stream (muP): the embedding times ``embed_scale``, every
+    # mixer's and feed-forward's output times ``residual_scale`` before it
+    # joins the stream, the final hidden state times ``logit_scale`` before
+    # the head. 1.0 leaves the program as it is without them.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
     # MoE (0 experts = dense). The mixture layers are of one shape; the first
     # ``moe_leading_dense_layers`` layers (DeepSeek's ``first_k_dense_replace``)
     # are dense SwiGLU layers of width ``moe_dense_intermediate_size`` instead,
@@ -120,11 +155,12 @@ class ModelConfig:
                            tuple(bool(r) for r in self.rope_pattern))
         object.__setattr__(self, "layer_kinds",
                            tuple(str(k) for k in self.layer_kinds))
-        if set(self.layer_kinds) - {"attention", "mamba"} or \
+        if set(self.layer_kinds) - {"attention", "mamba", "lightning"} or \
                 "attention" not in self.layer_kinds:
             raise ValueError(
-                f"layer_kinds {self.layer_kinds}: a period of 'attention' and "
-                "'mamba' layers with at least one attention layer")
+                f"layer_kinds {self.layer_kinds}: a period of 'attention', "
+                "'mamba' and 'lightning' layers with at least one attention "
+                "layer")
         if self.has_recurrent:
             if self.num_layers % len(self.layer_kinds) or \
                     len(self.attn_window_pattern) != 1:
@@ -133,19 +169,37 @@ class ModelConfig:
                     f"divides num_layers={self.num_layers}, over attention "
                     "layers of one kind (attn_window_pattern and rope_pattern "
                     "of one entry)")
-            if min(self.mamba_d_inner, self.mamba_d_state, self.mamba_dt_rank,
-                   self.mamba_d_conv - 1) < 1:
+            if self.has_mamba and min(
+                    self.mamba_d_inner, self.mamba_d_state, self.mamba_dt_rank,
+                    self.mamba_d_conv - 1) < 1:
                 raise ValueError(
                     "a model with mamba layers states mamba_d_inner, "
                     "mamba_d_state, mamba_dt_rank and mamba_d_conv >= 2")
-            if self.mamba_state_dtype not in ("float32", "bfloat16"):
+            if self.has_lightning and min(self.lightning_heads,
+                                          self.lightning_head_dim) < 1:
                 raise ValueError(
-                    f"mamba_state_dtype={self.mamba_state_dtype!r}")
-            if self.is_moe or self.is_mla or self.qk_norm or self.attn_bias:
+                    "a model with lightning layers states lightning_heads "
+                    "and lightning_head_dim")
+            for key in ("mamba_state_dtype", "lightning_state_dtype"):
+                if getattr(self, key) not in ("float32", "bfloat16"):
+                    raise ValueError(f"{key}={getattr(self, key)!r}")
+            if self.is_moe or self.is_mla or self.attn_bias:
                 raise ValueError(
-                    "mamba layers stand over the dense MLP and beside plain "
-                    "GQA attention layers only (no mixture, MLA, q/k norm or "
-                    "attention bias)")
+                    "recurrent layers stand over the dense MLP and beside "
+                    "GQA attention layers only (no mixture, MLA or attention "
+                    "bias)")
+        if self.sparse_topk:
+            st = self.sparse_kernel_stride
+            if self.is_mla or self.has_window or any(self.rope_pattern) or \
+                    self.sparse_kernel_size != 2 * st or \
+                    self.sparse_block_size % st or \
+                    self.sparse_window % self.sparse_block_size or \
+                    self.sparse_init_blocks < 0:
+                raise ValueError(
+                    "sparse_topk: GQA attention layers without RoPE or a "
+                    "window (a compacted page table is exact only there), "
+                    "kernels of two strides, blocks of whole strides and a "
+                    "window of whole blocks")
         if len(self.attn_window_pattern) != len(self.rope_pattern) or \
                 self.num_layers % len(self.rope_pattern):
             raise ValueError(
@@ -180,19 +234,42 @@ class ModelConfig:
         return len(self.attn_window_pattern)
 
     @property
-    def has_recurrent(self) -> bool:
-        """Some layer keeps a recurrent state per sequence (mamba)."""
+    def has_mamba(self) -> bool:
         return "mamba" in self.layer_kinds
 
     @property
-    def num_mamba_layers(self) -> int:
+    def has_lightning(self) -> bool:
+        return "lightning" in self.layer_kinds
+
+    @property
+    def has_recurrent(self) -> bool:
+        """Some layer keeps a recurrent state per sequence."""
+        return self.has_mamba or self.has_lightning
+
+    def _layers_of(self, kind: str) -> int:
         return (self.num_layers // len(self.layer_kinds)
-                * self.layer_kinds.count("mamba"))
+                * self.layer_kinds.count(kind))
+
+    @property
+    def num_mamba_layers(self) -> int:
+        return self._layers_of("mamba")
+
+    @property
+    def num_lightning_layers(self) -> int:
+        return self._layers_of("lightning")
 
     @property
     def num_attn_layers(self) -> int:
-        """Layers with pages in the KV pool: the pool folds this many."""
-        return self.num_layers - self.num_mamba_layers
+        """Layers with pages in the KV pool."""
+        return self._layers_of("attention")
+
+    @property
+    def kv_pool_folds(self) -> int:
+        """What the KV pool folds into its page axis: the attention layers,
+        and with sparse selection each layer's KV heads too (a head's pages
+        are then pages of their own, which a selected page table names)."""
+        return self.num_attn_layers * (
+            self.num_kv_heads if self.sparse_topk else 1)
 
     @property
     def layer_runs(self) -> tuple:
@@ -245,7 +322,7 @@ class ModelConfig:
     @property
     def kv_cache_heads(self) -> int:
         """KV heads as stored in the paged pool (1 for MLA's shared latent)."""
-        return 1 if self.is_mla else self.num_kv_heads
+        return 1 if self.is_mla or self.sparse_topk else self.num_kv_heads
 
     @property
     def kv_cache_head_dim(self) -> int:

@@ -129,6 +129,22 @@ MODEL_REGISTRY: dict[str, ModelConfig] = {
         mamba_d_inner=5120, mamba_d_state=16, mamba_d_conv=4,
         mamba_dt_rank=160,
     ),
+    # Lightning linear-attention layers around block-sparse NoPE attention
+    # layers (MiniCPM-SALA's two kinds, a sparse pair back to back), at CI
+    # size with the selection's sizes shrunk so that it bites at a hundred
+    # tokens (pages of 2): the matrix-state pool, the compressed-key plane and
+    # the selected page tables on the serving surface.
+    "tiny-sala": ModelConfig(
+        name="tiny-sala", vocab_size=288, hidden_size=128,
+        intermediate_size=256, num_layers=7, num_heads=4, num_kv_heads=2,
+        head_dim=32, tie_embeddings=False, qk_norm=True, rope_pattern=(False,),
+        layer_kinds=("lightning", "attention", "lightning", "lightning",
+                     "attention", "attention", "lightning"),
+        lightning_heads=4, lightning_head_dim=32, attn_output_gate=True,
+        sparse_topk=2, sparse_block_size=8, sparse_kernel_size=4,
+        sparse_kernel_stride=2, sparse_window=16, sparse_dense_len=64,
+        embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5, logit_scale=0.25,
+    ),
 }
 
 

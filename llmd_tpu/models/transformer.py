@@ -92,6 +92,8 @@ def param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
         }
     if cfg.qk_norm:
         axes |= {"q_norm": ("layers", "head_dim"), "k_norm": ("layers", "head_dim")}
+    if cfg.attn_output_gate:
+        axes["wg"] = ("layers", "embed", "heads", "head_dim")
     if cfg.attn_bias:
         axes |= {
             "bq": ("layers", "heads", "head_dim"),
@@ -118,7 +120,18 @@ def param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
         axes |= {"wi": ("layers", "embed", "mlp"), "wo_mlp": ("layers", "mlp", "embed")}
     if not cfg.tie_embeddings:
         axes["unembed"] = ("embed", "vocab")
-    if cfg.has_recurrent:
+    if cfg.has_lightning:
+        axes |= {
+            "lin_wq": ("layers", None, "embed"),
+            "lin_wk": ("layers", None, "embed"),
+            "lin_wv": ("layers", None, "embed"),
+            "lin_wg": ("layers", None, "embed"),
+            "lin_wo": ("layers", None, "embed"),
+            "lin_q_norm": ("layers", "head_dim"),
+            "lin_k_norm": ("layers", "head_dim"),
+            "lin_o_norm": ("layers", "head_dim"),
+        }
+    if cfg.has_mamba:
         # each kind's leaves are stacked over the layers of that kind (their
         # leading axis is still 'layers': attention leaves [num_attn_layers,
         # ...] as above, mamba leaves [num_mamba_layers, ...]); the mixer's
@@ -291,8 +304,81 @@ def _init_hybrid_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array
     return p
 
 
+# what the sparse layers' q/k norm weights are drawn around
+SPARSE_QK_GAIN = 1.8
+
+
+def _init_lightning_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
+    """Random-init params of a model with lightning layers, each stacked
+    leaf drawn a layer at a time as ``_init_hybrid_params`` does (12 layers
+    at MiniCPM-SALA's widths are 7.9 GB of finished leaves; the largest
+    float32 draw in flight is one layer's ``wi``, 0.54 GB).
+
+    Attention leaves are [La, ...] (``wg`` the output gate's, ``q_norm`` /
+    ``k_norm`` a head's RMSNorm weights), lightning leaves [Ll, ...]:
+    ``lin_wq`` / ``lin_wk`` / ``lin_wv`` / ``lin_wg`` [Hl * Dl, D] (out by
+    in, a head's lanes side by side) and ``lin_wo`` [Hl * Dl, D] (in by out):
+    plain matrices with the hidden size minor. Kept [D, Hl, Dl] or [D, Hl *
+    Dl], the TPU compiler transposed the whole stacks of the four input
+    leaves at every call's entry (the compiled text for a described v5e: four
+    copies of 302 MB a step), and the three norms a head ``lin_q_norm``
+    / ``lin_k_norm`` / ``lin_o_norm`` [Dl]; norms and the MLP [L, ...]. The norms' weights are
+    drawn around 1 (a program that left one out, or put it on the wrong
+    side of RoPE, must not read as a sound one), but for the sparse layers'
+    ``q_norm`` / ``k_norm``, drawn around ``SPARSE_QK_GAIN``: with gains of 1
+    a query's softmax over thousands of random keys is near uniform, its
+    output the mean of all values (near nothing), and a program that never
+    selected would read as a sound one; with these a score has a standard
+    deviation near 3.2, some tens of keys hold a query's weight as in a
+    trained model, and which blocks were selected decides the output (at
+    2.2 the check's sound readings spread too widely: PERF.md section 2)."""
+    dt = cfg.jax_dtype
+    L, La, Ll = cfg.num_layers, cfg.num_attn_layers, cfg.num_lightning_layers
+    D, H, Hk, Dh, F = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+                       cfg.head_dim, cfg.intermediate_size)
+    Hl, Dl = cfg.lightning_heads, cfg.lightning_head_dim
+    norm, keys = _stack_drawer(key, dt, 24)
+    s = D ** -0.5
+
+    def around_one(n, width, gain=1.0):
+        return (gain * (1.0 + 0.1 * jax.random.normal(
+            next(keys), (n, width), jnp.float32))).astype(dt)
+
+    p: dict[str, jax.Array] = {
+        "embed": norm(1, (cfg.vocab_size, D), 0.02)[0],
+        "final_norm": jnp.ones((D,), dt),
+        "attn_norm": jnp.ones((L, D), dt),
+        "mlp_norm": jnp.ones((L, D), dt),
+        "wq": norm(La, (D, H, Dh), s),
+        "wk": norm(La, (D, Hk, Dh), s),
+        "wv": norm(La, (D, Hk, Dh), s),
+        "wo": norm(La, (H, Dh, D), (H * Dh) ** -0.5),
+        "wi": norm(L, (D, 2 * F), s),
+        "wo_mlp": norm(L, (F, D), F ** -0.5),
+        "lin_wq": norm(Ll, (Hl * Dl, D), s),
+        "lin_wk": norm(Ll, (Hl * Dl, D), s),
+        "lin_wv": norm(Ll, (Hl * Dl, D), s),
+        "lin_wg": norm(Ll, (Hl * Dl, D), s),
+        "lin_wo": norm(Ll, (Hl * Dl, D), (Hl * Dl) ** -0.5),
+        "lin_q_norm": around_one(Ll, Dl),
+        "lin_k_norm": around_one(Ll, Dl),
+        "lin_o_norm": around_one(Ll, Dl),
+    }
+    if cfg.attn_output_gate:
+        p["wg"] = norm(La, (D, H, Dh), s)
+    if cfg.qk_norm:
+        p["q_norm"] = around_one(La, Dh, SPARSE_QK_GAIN)
+        p["k_norm"] = around_one(La, Dh, SPARSE_QK_GAIN)
+    if not cfg.tie_embeddings:
+        p["unembed"] = norm(1, (D, cfg.vocab_size), s)[0]
+    return p
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
     """Random-init params (scaled normal); shapes match param_logical_axes."""
+    if cfg.has_lightning:
+        assert not cfg.has_mamba, "lightning and mamba layers in one stack"
+        return _init_lightning_params(cfg, key)
     if cfg.has_recurrent:
         return _init_hybrid_params(cfg, key)
     if cfg.layered_init:
@@ -334,6 +420,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
     if cfg.qk_norm:
         p["q_norm"] = jnp.ones((L, Dh), dt)
         p["k_norm"] = jnp.ones((L, Dh), dt)
+    if cfg.attn_output_gate:
+        p["wg"] = norm((L, D, H, Dh), s)
     if cfg.attn_bias:
         p["bq"] = jnp.zeros((L, H, Dh), dt)
         p["bk"] = jnp.zeros((L, Hk, Dh), dt)
@@ -607,31 +695,53 @@ def init_cache(cfg: ModelConfig, num_pages: int, page_size: int,
         assert cfg.kv_cache_heads % pack == 0
     rows = 1 if cfg.is_mla else 2 * (cfg.kv_cache_heads // pack)
     return jnp.zeros(
-        (cfg.num_attn_layers * num_pages, page_size, rows,
+        (cfg.kv_pool_folds * num_pages, page_size, rows,
          padded_head_dim(cfg.kv_cache_head_dim)),
         dtype if dtype is not None else cfg.jax_dtype,
     )
 
 
 def init_state(cfg: ModelConfig, seats: int) -> dict[str, jax.Array]:
-    """The recurrent-state pool of a model with mamba layers, a slot a seat:
-    ``ssm`` [Lm, seats + 1, N, Di] in ``cfg.mamba_state_dtype`` (d_state on
-    the sublanes, d_inner on the lanes: what ops/selective_scan reads) and
-    ``conv`` [Lm, K - 1, seats + 1, Di] in the model's dtype: the last K - 1
-    pre-conv rows of every slot, kept as planes whose two minor dimensions
-    are (slots, d_inner), so that both step programs move whole tiles
-    (``conv_window`` says what they moved slot major). Slot ``seats`` is
-    scratch: a unified step's padding rows are mapped to it, so that every
-    row of a call has a slot to name and none of them a seat's.
-    ``forward_core`` takes the pool in the ``cache`` argument, as
+    """The recurrent-state pools of a model with recurrent layers, a slot a
+    seat. Mamba layers: ``ssm`` [Lm, seats + 1, N, Di] in
+    ``cfg.mamba_state_dtype`` (d_state on the sublanes, d_inner on the lanes:
+    what ops/selective_scan reads) and ``conv`` [Lm, K - 1, seats + 1, Di] in
+    the model's dtype: the last K - 1 pre-conv rows of every slot, kept as
+    planes whose two minor dimensions are (slots, d_inner), so that both step
+    programs move whole tiles (``conv_window`` says what they moved slot
+    major). Lightning layers: ``lin`` [Ll, seats + 1, Hl, Dl, Dl] in
+    ``cfg.lightning_state_dtype``, a matrix a head (ops/lightning_attention).
+    Slot ``seats`` is scratch: a unified step's padding rows are mapped to
+    it, so that every row of a call has a slot to name and none of them a
+    seat's. ``forward_core`` takes the pools in the ``cache`` argument, as
     ``{"kv": pool, **state}``."""
-    Lm, S = cfg.num_mamba_layers, seats + 1
-    return {
-        "ssm": jnp.zeros((Lm, S, cfg.mamba_d_state, cfg.mamba_d_inner),
-                         jnp.dtype(cfg.mamba_state_dtype)),
-        "conv": jnp.zeros((Lm, cfg.mamba_d_conv - 1, S, cfg.mamba_d_inner),
-                          cfg.jax_dtype),
-    }
+    S = seats + 1
+    state: dict[str, jax.Array] = {}
+    if cfg.has_mamba:
+        Lm = cfg.num_mamba_layers
+        state["ssm"] = jnp.zeros(
+            (Lm, S, cfg.mamba_d_state, cfg.mamba_d_inner),
+            jnp.dtype(cfg.mamba_state_dtype))
+        state["conv"] = jnp.zeros(
+            (Lm, cfg.mamba_d_conv - 1, S, cfg.mamba_d_inner), cfg.jax_dtype)
+    if cfg.has_lightning:
+        state["lin"] = jnp.zeros(
+            (cfg.num_lightning_layers, S, cfg.lightning_heads,
+             cfg.lightning_head_dim, cfg.lightning_head_dim),
+            jnp.dtype(cfg.lightning_state_dtype))
+    return state
+
+
+def init_compressed_keys(cfg: ModelConfig, num_pages: int,
+                         dtype=None) -> jax.Array:
+    """The compressed-key plane of a model with sparse selection: one key a
+    page of the folded KV pool, ``[folds * P, Dhp]`` in the pool's type (a
+    sixteenth of K at pages of 16). Rides with the recurrent-state pools
+    (``{"kv": pool, "ck": plane, ...}``); ops/sparse_select writes and reads
+    it. An entry belongs to its page: nothing allocates or frees it."""
+    return jnp.zeros((cfg.kv_pool_folds * num_pages,
+                      padded_head_dim(cfg.kv_cache_head_dim)),
+                     dtype if dtype is not None else cfg.jax_dtype)
 
 
 # float8_e4m3fn has no inf: values past ±448 convert to nan, so fp8 cache
@@ -1028,9 +1138,82 @@ def mamba_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
     return mm("mamba_out", "ne,ed->nd", y.astype(dt_)), conv, ssm
 
 
+def _head_mean_sq(xf: jax.Array) -> jax.Array:
+    """Mean of squares over the lanes of each head of float32 ``xf`` [N, H,
+    D], as [N, H, 1]: one matrix product at the highest precision, since the
+    matrix unit adds a row's terms in one order whatever the number of rows.
+    A ``reduce`` is lowered as the array's shape suggests (``_inner_norms``
+    has the story), and here too a recurrent state stands behind the norm: on
+    the chip a decode row's q through the 32-row and the 256-row program
+    parted by a bf16 step now and then (PR 42)."""
+    N, H, D = xf.shape
+    ones = jnp.full((D, 128), 1.0 / D, jnp.float32)
+    return jnp.dot((xf * xf).reshape(N * H, D), ones,
+                   precision=lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)[:, :1].reshape(N, H, 1)
+
+
+def head_rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    """``rms_norm`` over the lanes of each head of ``x`` [N, H, D], its sum
+    of squares by ``_head_mean_sq``."""
+    xf = x.astype(jnp.float32)
+    return (xf * lax.rsqrt(_head_mean_sq(xf) + eps)).astype(x.dtype) * w
+
+
+def _head_norm(y: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the lanes of each head of float32 ``y`` [N, H, D], the
+    result float32 too (the lightning layers' output norm)."""
+    return y * lax.rsqrt(_head_mean_sq(y) + eps) * w.astype(jnp.float32)
+
+
+def lightning_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, lin: jax.Array,
+                    o, positions: jax.Array, row_slots, cu_q_lens: jax.Array,
+                    live: jax.Array, fresh: jax.Array, lin_impl, mm):
+    """Lightning layer ``o``'s mixer (``o`` traced: the layer's ordinal among
+    the lightning layers) on the normed rows ``h`` [N, D] of a flat mixed
+    batch; returns (out [N, D], state pool).
+
+    ``lin`` [Ll * S, Hl, Dl, Dl] is the matrix-state pool with the layer
+    folded into the slot axis, as the kernel indexes it; ``row_slots`` [B]
+    names each batch row's slot, or is None where row b is seat b (the fused
+    decode call). q and k take an RMSNorm a head and RoPE over all lanes; the
+    recurrence (``lin_impl``, ops/lightning_attention) gives float32 rows,
+    which take an RMSNorm a head and the sigmoid gate before the output
+    projection. ``mm(key, pattern, x)`` is the layer's int8-aware weight
+    product."""
+    from llmd_tpu.ops.lightning_attention import head_slopes
+
+    B, N = live.shape[0], h.shape[0]
+    Hl, Dl = cfg.lightning_heads, cfg.lightning_head_dim
+
+    def heads(key):
+        return mm(key, "nd,ed->ne", h).reshape(N, Hl, Dl)
+
+    q = head_rms_norm(heads("lin_wq"), lp["lin_q_norm"], cfg.rms_eps)
+    k = head_rms_norm(heads("lin_wk"), lp["lin_k_norm"], cfg.rms_eps)
+    v = heads("lin_wv")
+    q = rope(q, positions, cfg.rope_theta)
+    k = rope(k, positions, cfg.rope_theta)
+    slots = o * (lin.shape[0] // cfg.num_lightning_layers) + (
+        jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
+    y, lin = lin_impl(q, k, v, head_slopes(cfg.lightning_heads), lin, slots,
+                      cu_q_lens, live, fresh,
+                      scale=cfg.lightning_head_dim ** -0.5)
+    y = _head_norm(y, lp["lin_o_norm"], cfg.rms_eps)
+    gate = jax.nn.sigmoid(heads("lin_wg").astype(jnp.float32))
+    return mm("lin_wo", "ne,ed->nd",
+              (y * gate).astype(cfg.jax_dtype).reshape(N, Hl * Dl)), lin
+
+
 # ---------------------------------------------------------------------------
 # Full forward over the scanned layer stack
 # ---------------------------------------------------------------------------
+
+
+def _joined(cfg: ModelConfig, x: jax.Array, y: jax.Array) -> jax.Array:
+    """The stream and a block's output: ``x + cfg.residual_scale * y`` (1.0
+    leaves the program the plain sum it was)."""
+    return x + y if cfg.residual_scale == 1.0 else x + y * cfg.residual_scale
 
 
 def _weight_mm(lp: dict, key: str, pattern: str, xin: jax.Array, out=None):
@@ -1044,7 +1227,8 @@ def _weight_mm(lp: dict, key: str, pattern: str, xin: jax.Array, out=None):
 
 
 def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
-                  positions, seq_slots, cu_q_lens, state_slots, scan_impl):
+                  positions, seq_slots, cu_q_lens, state_slots, scan_impl,
+                  lin_impl=None):
     """The layer stack of a model whose layers differ in kind and in
     parameter shapes: a scan over the periods of ``cfg.layer_kinds`` whose
     body runs the period's runs of one kind (7 mamba, 1 attention, 6 mamba),
@@ -1059,33 +1243,49 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
     The state pools ride the scans as carries, updated in place. The SSM pool
     is folded to [Lm * S, Nst, Di] on the way in and unfolded on the way out
     (its minor dimensions are whole tiles, so neither moves a byte) because
-    the scan kernel names a state block by one index; the conv window's pool
+    the scan kernel names a state block by one index, and so is a lightning
+    layer's matrix-state pool ([Ll * S, Hl, Dl, Dl]); the conv window's pool
     keeps its layer as a leading axis of its own, which a mamba layer indexes
     by its ordinal: folded into the slot axis, every layer but the first
     would start off a sublane tile's boundary (S = seats + 1), and the fold
-    itself was a relayout of the pool at every call's entry and exit.
+    itself was a relayout of the pool at every call's entry and exit. The
+    compressed-key plane of a model with sparse selection (``ck``) is handed
+    to the attention layers and back.
 
     Returns (x, flat KV pool, state)."""
     from llmd_tpu.ops.selective_scan import row_flags, selective_scan_xla
 
     assert cu_q_lens is not None, "a model with recurrent layers needs cu_q_lens"
-    scan_impl = scan_impl or selective_scan_xla
-    Lm, S1 = state["ssm"].shape[:2]
-    ssm = state["ssm"].reshape((Lm * S1,) + state["ssm"].shape[2:])
-    conv = state["conv"]
+    pools = {k: state[k] for k in ("ck",) if k in state}
+    if cfg.has_mamba:
+        scan_impl = scan_impl or selective_scan_xla
+        Lm, S1 = state["ssm"].shape[:2]
+        pools["ssm"] = state["ssm"].reshape((Lm * S1,) + state["ssm"].shape[2:])
+        pools["conv"] = state["conv"]
     live, fresh = row_flags(positions, cu_q_lens)
-    plan = window_plan(conv.shape, x.shape[0], state_slots, seq_slots,
-                       cu_q_lens, live, fresh)
+    if cfg.has_mamba:
+        plan = window_plan(pools["conv"].shape, x.shape[0], state_slots,
+                           seq_slots, cu_q_lens, live, fresh)
+    if cfg.has_lightning:
+        from llmd_tpu.ops.lightning_attention import lightning_attention_xla
+
+        lin_impl = lin_impl or lightning_attention_xla
+        pools["lin"] = state["lin"].reshape((-1,) + state["lin"].shape[2:])
 
     def present(*keys):
         return tuple(v for k in keys for v in (k, k + "_q", k + "_scale")
                      if v in params)
 
-    params = dict(params, **mamba_vectors(cfg, params))
+    if cfg.has_mamba:
+        params = dict(params, **mamba_vectors(cfg, params))
     shared = present("attn_norm", "mlp_norm", "wi", "wo_mlp")
     own = {"mamba": present("mamba_in", "mamba_x", "mamba_dt", "mamba_a_log",
                             "mamba_out", "mamba_vec", "mamba_norms"),
-           "attention": present("wq", "wk", "wv", "wo")}
+           "attention": present("wq", "wk", "wv", "wo", "wg", "q_norm",
+                                "k_norm"),
+           "lightning": present("lin_wq", "lin_wk", "lin_wv", "lin_wg",
+                                "lin_wo", "lin_q_norm", "lin_k_norm",
+                                "lin_o_norm")}
 
     def leaves(keys, i):
         return {k: lax.dynamic_index_in_dim(params[k], i, 0, keepdims=False)
@@ -1093,26 +1293,34 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
 
     def one_layer(kind, carry, l, o):
         """Layer ``l``, the ``o``-th of its kind."""
-        x, flat_cache, conv, ssm = carry
+        x, flat_cache, pools = carry
         lp = {**leaves(shared, l), **leaves(own[kind], o)}
         if kind == "attention":
-            (x, flat_cache), _ = attention_layer(
-                (x, flat_cache), lp, o, cfg.attn_window_pattern[0],
+            ck = (pools["ck"],) if "ck" in pools else ()
+            (x, flat_cache, *ck), _ = attention_layer(
+                (x, flat_cache, *ck), lp, o, cfg.attn_window_pattern[0],
                 cfg.rope_pattern[0])
-            return x, flat_cache, conv, ssm
+            return x, flat_cache, ({**pools, "ck": ck[0]} if ck else pools)
 
         def mm(key, pattern, xin, out=None):
             return _weight_mm(lp, key, pattern, xin, out)
 
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        o_mix, conv, ssm = mamba_mixer(
-            cfg, lp, h, conv, ssm, o, plan, state_slots, cu_q_lens, live,
-            fresh, scan_impl, mm)
-        x = x + o_mix
+        if kind == "mamba":
+            o_mix, conv, ssm = mamba_mixer(
+                cfg, lp, h, pools["conv"], pools["ssm"], o, plan, state_slots,
+                cu_q_lens, live, fresh, scan_impl, mm)
+            pools = {**pools, "conv": conv, "ssm": ssm}
+        else:
+            o_mix, lin = lightning_mixer(
+                cfg, lp, h, pools["lin"], o, positions, state_slots,
+                cu_q_lens, live, fresh, lin_impl, mm)
+            pools = {**pools, "lin": lin}
+        x = _joined(cfg, x, o_mix)
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         y = swiglu(h, None, None, mm=mm) if "wi_q" in lp else swiglu(
             h, lp["wi"], lp["wo_mlp"])
-        return x + y, flat_cache, conv, ssm
+        return _joined(cfg, x, y), flat_cache, pools
 
     period = len(cfg.layer_kinds)
     count = {k: cfg.layer_kinds.count(k) for k in own}
@@ -1131,11 +1339,11 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
             seen[kind] += n
         return carry, None
 
-    (x, flat_cache, conv, ssm), _ = lax.scan(
-        one_period, (x, flat_cache, conv, ssm),
+    (x, flat_cache, pools), _ = lax.scan(
+        one_period, (x, flat_cache, pools),
         jnp.arange(cfg.num_layers // period, dtype=jnp.int32))
-    return x, flat_cache, {"ssm": ssm.reshape(state["ssm"].shape),
-                           "conv": conv}
+    return x, flat_cache, {k: v.reshape(state[k].shape)
+                           for k, v in pools.items()}
 
 
 def forward_core(
@@ -1157,7 +1365,9 @@ def forward_core(
     mm_mask: Optional[jax.Array] = None,  # [N] True where tokens[i] is a placeholder
     moe_dispatch_impl=None,
     state_slots: Optional[jax.Array] = None,  # [B] row -> state slot (recurrent models)
-    scan_impl=None,  # ops/selective_scan impl (recurrent models)
+    scan_impl=None,  # ops/selective_scan impl (mamba layers)
+    lin_impl=None,  # ops/lightning_attention impl (lightning layers)
+    query_attn_impl=None,  # attention impl for one-query rows (sparse selection)
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Run a flat mixed batch through the model, writing K/V into the paged cache.
 
@@ -1187,6 +1397,12 @@ def forward_core(
     with one token, the fused decode call. A row whose first position is -1
     (padding, idle, frozen) leaves its slot untouched, one whose first
     position is 0 starts from zero state. ``cu_q_lens`` is required.
+
+    A model with sparse selection (``cfg.sparse_topk``) keeps its KV heads
+    as pages of their own in the pool and a compressed-key plane ``ck``
+    beside the state pools; its attention layers hand ``attn_impl`` the
+    call's rows a KV head at a time and ``query_attn_impl`` (default: the
+    same) the one-query rows of the selected page tables (ops/sparse_select).
     """
     state = None
     if cfg.has_recurrent:
@@ -1195,11 +1411,13 @@ def forward_core(
     N = tokens.shape[0]
     Ptot, ps, HkC, Dhp = cache.shape
     Dh = cfg.head_dim
-    P = Ptot // cfg.num_attn_layers  # pages per layer with pages
+    P = Ptot // cfg.kv_pool_folds  # pages per layer (and KV head) with pages
     B = page_tables.shape[0]
     if attn_impl is None:
         attn_impl = ragged_paged_attention_xla
     x = params["embed"][tokens].astype(cfg.jax_dtype)  # [N, D]
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
     if mm_embeds is not None:
         # inject the encode stage's embedding rows at media placeholder
         # positions (E/PD contract: encode workers produce, prefill consumes)
@@ -1233,7 +1451,8 @@ def forward_core(
     # (stacked by mixture layer) or a dense layer's
     every_keys = ("attn_norm", "mlp_norm") + attn_keys + (
         ("q_norm", "k_norm") if cfg.qk_norm else ()
-    ) + (("bq", "bk", "bv", "bo") if cfg.attn_bias else ())
+    ) + (("bq", "bk", "bv", "bo") if cfg.attn_bias else ()) + (
+        _variants("wg") if cfg.attn_output_gate else ())
     dense_keys = _variants("wi", "wo_mlp") if (
         not cfg.is_moe or cfg.moe_leading_dense_layers) else ()
     expert_keys = (
@@ -1280,7 +1499,9 @@ def forward_core(
         ``moe_ordinal``: which of the mixture layers it is where leading
         dense layers precede them (None: every layer is one, ``l``); the
         feed-forward is the dense MLP where ``lp`` holds no router."""
-        x, flat_cache = carry  # flat_cache: [L*P*ps, 2Hk, Dhp] slot view (in-place carry)
+        # flat_cache: [L*P*ps, 2Hk, Dhp] slot view (in-place carry); with
+        # sparse selection the compressed-key plane rides behind it
+        x, flat_cache, *planes = carry
 
         def _mm(key, pattern, xin):
             """Weight matmul, int8-aware: per-OUTPUT-channel scales commute
@@ -1291,6 +1512,11 @@ def forward_core(
                 return jnp.einsum(pattern, xin, lp[key])
             y = jnp.einsum(pattern, xin, lp[key + "_q"].astype(xin.dtype))
             return y * lp[key + "_scale"].astype(xin.dtype)
+
+        def gated(attn, h):  # cfg.attn_output_gate
+            return (attn.astype(jnp.float32) * jax.nn.sigmoid(
+                _mm("wg", "nd,dhk->nhk", h).astype(jnp.float32))
+                    ).astype(attn.dtype)
 
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         early_logits = router_logits(h, lp["router"]) if (
@@ -1345,13 +1571,53 @@ def forward_core(
                 # Per-head RMSNorm over head_dim before RoPE (Qwen3 semantics) — on
                 # the FULL projection output incl. bias and LoRA delta, matching the
                 # HF/PEFT order (adapters are trained against normalised q/k).
-                q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
-                k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
+                # (beside recurrent layers the sums of squares are matrix
+                # products: a row's result must not depend on the program)
+                qk = head_rms_norm if cfg.has_recurrent else rms_norm
+                q = qk(q, lp["q_norm"], cfg.rms_eps)
+                k = qk(k, lp["k_norm"], cfg.rms_eps)
             if use_rope:
                 q = rope(q, positions, cfg.rope_theta)
                 k = rope(k, positions, cfg.rope_theta)
             q_attn, k_w, v_w = pad_heads(q), pad_heads(k), pad_heads(v)
             scale = Dh ** -0.5
+        if cfg.sparse_topk:
+            # a KV head's pages are pages of their own: head g of attention
+            # layer l at fold l * Hk + g (ops/sparse_select)
+            from llmd_tpu.ops import sparse_select
+
+            Hkn = cfg.num_kv_heads
+            base = (l * Hkn + jnp.arange(Hkn, dtype=jnp.int32)) * P
+            slots_h = jnp.where(slots[:, None] >= 0,
+                                slots[:, None] + base[None, :] * ps, -1)
+            flat_cache = write_kv(flat_cache, k_w.reshape(N * Hkn, 1, Dhp),
+                                  v_w.reshape(N * Hkn, 1, Dhp),
+                                  slots_h.reshape(-1))
+            planes = [sparse_select.write_compressed_keys(
+                planes[0], flat_cache, page_tables, positions, seq_slots,
+                base, ps)]
+            attn = sparse_select.sparse_paged_attention(
+                cfg, q_attn, flat_cache, planes[0], page_tables, positions,
+                seq_slots, kv_lens, cu_q_lens, num_seqs, l, P, ps, scale,
+                attn_impl, query_attn_impl or attn_impl)
+            attn = attn[..., :Dh]
+            if cfg.attn_output_gate:
+                attn = gated(attn, h)
+            # one product over the H * Dh lanes of a row, as the latent
+            # layers' is (below): contracted over (h, k) as two axes, XLA
+            # splits the sum by the number of rows, and on the chip a decode
+            # row's projection through the 32-row and the 256-row program
+            # parted by a bf16 step now and then (PR 42: greedy tokens served
+            # alone and beside a prefilling neighbour parted)
+            flat = {k: v.reshape((-1,) + v.shape[2:])
+                    for k, v in lp.items() if k in ("wo", "wo_q")}
+            x = _joined(cfg, x, _weight_mm({**lp, **flat}, "wo", "nk,kd->nd",
+                                     attn.reshape(N, -1)))
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+            y = swiglu(h, None, None, mm=_mm) if "wi_q" in lp else swiglu(
+                h, lp["wi"], lp["wo_mlp"])
+            return (_joined(cfg, x, y), flat_cache, *planes), (
+                jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32))
         # shared paged plumbing — this layer's slice of the pool: slots/pages
         # shifted by the layer offset, KV written, attention over the pool
         slots_l = jnp.where(slots >= 0, slots + l * (P * ps), -1)
@@ -1384,6 +1650,8 @@ def forward_core(
                            o_heads.reshape(N, -1))
         else:
             attn = attn[..., :Dh]
+            if cfg.attn_output_gate:
+                attn = gated(attn, h)
             o = _mm("wo", "nhk,hkd->nd", attn)
             if cfg.attn_bias:
                 o = o + lp["bo"]
@@ -1391,7 +1659,7 @@ def forward_core(
                 attn_flat = attn.reshape(N, cfg.num_heads * Dh)
                 o = o + apply_lora(attn_flat, lp["lora_A_wo"], lp["lora_B_wo"],
                                    lora_indices, lora_scale)
-        x = x + o
+        x = _joined(cfg, x, o)
 
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         if cfg.is_moe and "router" in lp:
@@ -1433,13 +1701,13 @@ def forward_core(
             drop = jnp.zeros((), jnp.int32)
             y = swiglu(h, None, None, mm=_mm) if "wi_q" in lp else swiglu(
                 h, lp["wi"], lp["wo_mlp"])
-        x = x + y
-        return (x, flat_cache), (cnt, drop)
+        x = _joined(cfg, x, y)
+        return (x, flat_cache, *planes), (cnt, drop)
 
     if state is not None:
         x, flat_cache, state = _hybrid_stack(
             cfg, params, layer, x, cache.reshape(Ptot * ps, HkC, Dhp), state,
-            positions, seq_slots, cu_q_lens, state_slots, scan_impl)
+            positions, seq_slots, cu_q_lens, state_slots, scan_impl, lin_impl)
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         return (x, {"kv": flat_cache.reshape(Ptot, ps, HkC, Dhp), **state},
                 jnp.zeros((cfg.num_layers, 0), jnp.int32),
@@ -1516,6 +1784,8 @@ def forward_core(
 
 def unembed(cfg: ModelConfig, params: dict[str, jax.Array], hidden: jax.Array) -> jax.Array:
     """hidden [..., D] → logits [..., vocab] (fp32)."""
+    if cfg.logit_scale != 1.0:
+        hidden = hidden.astype(jnp.float32) * cfg.logit_scale
     if "unembed_q" in params:  # weight-only int8 (models/quant.py)
         logits = jnp.einsum("...d,dv->...v", hidden.astype(jnp.float32),
                             params["unembed_q"].astype(jnp.float32))

@@ -182,7 +182,11 @@ def kv_bytes_per_token(cfg, kv_cache_dtype: Optional[str] = None) -> int:
     dtype_bytes = 1 if kv_cache_dtype == "fp8" else (
         2 if cfg.dtype == "bfloat16" else 4)
     planes = 1 if getattr(cfg, "is_mla", False) else 2
-    return planes * cfg.kv_cache_heads * cfg.kv_cache_head_dim * dtype_bytes
+    # (sparse selection keeps a KV head's pages apart: the pool's rows hold
+    # one head, and a token has one row a head)
+    heads = cfg.num_kv_heads if getattr(cfg, "sparse_topk", 0) \
+        else cfg.kv_cache_heads
+    return planes * heads * cfg.kv_cache_head_dim * dtype_bytes
 
 
 def decode_hbm_gb_per_token(cfg, quantize_weights: Optional[str],

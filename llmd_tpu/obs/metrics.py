@@ -620,6 +620,51 @@ class EngineMetrics:
             "boundary) (info-style: value 1 on the selected label set; absent "
             "for a model without recurrent layers)",
             labelnames=("impl", "state_dtype", "prefix_reuse"))
+        self.linear_attn_tokens = reg.counter(
+            "llmd_tpu:linear_attn_tokens_total",
+            "Tokens one lightning (linear-attention) layer's recurrence is "
+            "given, per dispatch, from the lengths the step packed: "
+            "rows=prefill the tokens of prefill chunks, rows=decode those of "
+            "decode rows (a fused decode call: the steps its live rows have "
+            "left). A model without lightning layers feeds neither",
+            labelnames=("rows",))
+        self.linear_state_resets = reg.counter(
+            "llmd_tpu:linear_state_resets_total",
+            "Rows dispatched from position 0, which start a lightning "
+            "layer's matrix state from zero (a sequence's first prefill "
+            "chunk, or the first of one that was preempted)")
+        self.linear_state_slots = reg.gauge(
+            "llmd_tpu:linear_state_slots_in_use",
+            "Matrix-state slots whose seat holds a sequence (a seat owns its "
+            "slot; 0 for a model without lightning layers)")
+        self.sparse_attn_rows = reg.counter(
+            "llmd_tpu:sparse_attn_rows_total",
+            "Query rows (tokens) a sparse-attention layer is given, per "
+            "dispatch, from the positions the step packed: path=dense those "
+            "that see fewer keys than sparse_dense_len and attend to all of "
+            "them, path=sparse those that attend through a selected page "
+            "table. A model without sparse selection feeds neither",
+            labelnames=("path",))
+        self.sparse_attn_qk_pairs = reg.counter(
+            "llmd_tpu:sparse_attn_qk_pairs_total",
+            "(query, key) pairs a sparse-attention layer's rule asks for, "
+            "per dispatch, from the positions the step packed: a query below "
+            "sparse_dense_len the keys it sees, a query past it the tokens "
+            "of its selected blocks (what the demand of the benchmark's "
+            "sparse_mixed_attention_roofline counts; attn_query_key_pairs_total "
+            "counts every visible key)",
+            labelnames=("program",))
+        self.sparse_decode_kv_tokens = reg.counter(
+            "llmd_tpu:sparse_decode_kv_tokens_total",
+            "Of a sparse-attention layer's decode rows (one query a row, in "
+            "a unified step or a fused decode call, a fused call at its "
+            "first step): tokens=held the tokens their tables hold (a row "
+            "past sparse_dense_len its selected blocks', a row below it its "
+            "resident tokens), tokens=context the tokens a dense read of the "
+            "same rows would. Like with like, which attn_kv_tokens_total's "
+            "layers=sparse over layers=full is not where a chunk brings many "
+            "queries a row",
+            labelnames=("tokens",))
         self.program_rows = reg.counter(
             "llmd_tpu:program_rows_total",
             "Sequences (rows) packed into each dispatch",

@@ -12,6 +12,7 @@ benchmark's shapes, and ``chip_smoke.py`` on the chip for the rest.
 """
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,8 @@ from llmd_tpu.ops.mla_attention import mla_paged_attention
 from llmd_tpu.ops.packed_kv import make_packed_attn, pack_factor
 from llmd_tpu.ops.paged_attention import paged_attention_tpu
 from llmd_tpu.parallel.mesh import MeshConfig, build_mesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _lower_for_tpu(fn, *args):
@@ -409,3 +412,98 @@ def test_attention_heads_must_split_over_tp():
     fn, q_shape, cache_shape = _llama_packed_attn(mesh)  # 4 packed heads / 8
     with pytest.raises(ValueError, match="do not split over tp=8"):
         jax.eval_shape(fn, *_attn_args(q_shape(64), cache_shape, 64, 64))
+
+
+@pytest.mark.parametrize("n", [32, 256], ids=["decode", "unified"])
+def test_lightning_attention_compiles_for_v5e_at_the_cells_shapes(one_chip, n):
+    """The lightning layers' kernel at minicpm-sala-9b's widths (32 heads of
+    128, 6 layers of 33 slots folded into the pool, float32 state) and both
+    step programs' token budgets goes through Mosaic and the TPU compiler
+    here: the row-by-row loads of a block that starts off a sublane tile, the
+    transposed product k^T v at 16 rows and the VMEM of the resident blocks
+    are what interpret mode cannot refuse."""
+    from llmd_tpu.ops.lightning_attention import lightning_attention_pallas
+
+    H, D, seats = 32, 128, 32
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (spec((n, H, D), jnp.bfloat16),) * 3 + (
+        spec((H,), jnp.float32), spec((6 * (seats + 1), H, D, D), jnp.float32),
+        spec((seats,), jnp.int32), spec((seats + 1,), jnp.int32),
+        spec((seats,), jnp.bool_), spec((seats,), jnp.bool_))
+    compiled = jax.jit(
+        lambda *a: lightning_attention_pallas(*a, scale=D ** -0.5),
+        donate_argnums=(4,)).lower(*args).compile()
+    assert "lightning_attention" in compiled.as_text()
+    # in place: the 0.42 GB pool is neither copied nor a temporary
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_sparse_hybrid_step_programs_compile_for_v5e_at_the_cells_sizes(
+        one_chip, program):
+    """minicpm-sala-9b's whole forward (the file's layers at the published
+    widths, the cell's pools: 32,768 pages a fold, 32 seats, tables of 1,280
+    pages)
+    through the TPU compiler as each step program packs it, with the Pallas
+    attention and lightning kernels: the selected page tables' calls (256
+    one-query rows of 388 pages in scalar memory), the KV heads folded into
+    the pool, and no stacked leaf copied whole at the call's entry (kept [D,
+    heads, lanes], the lightning q, k, v and gate matrices were transposed
+    there: 1.2 GB of temporaries a call)."""
+    import functools
+    import json
+    import sys
+
+    from llmd_tpu.models.transformer import (
+        forward_core, init_cache, init_compressed_keys, init_params,
+        init_state)
+    from llmd_tpu.ops.lightning_attention import lightning_attention_pallas
+
+    sys.path.append(os.path.join(ROOT, "perfbench"))
+    try:
+        from reference import hybrid_lightning_sparse as family
+    finally:
+        sys.path.remove(os.path.join(ROOT, "perfbench"))
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "minicpm-sala-9b.json")) as f:
+        conf = json.load(f)
+    cfg, e = family.model_config(conf), conf["engine"]
+    B, N = e["max_batch_size"], e["max_batch_size"] if program == "decode" \
+        else e["prefill_chunk"]
+    maxp = e["max_model_len"] // e["page_size"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    pools = on_chip(jax.eval_shape(lambda: {
+        "kv": init_cache(cfg, e["num_pages"], e["page_size"]),
+        "ck": init_compressed_keys(cfg, e["num_pages"]),
+        **init_state(cfg, B)}))
+    attn = functools.partial(paged_attention_tpu,
+                             split_at_kv_blocks=program == "unified")
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, pools, tokens, positions, seq_slots, pt, lens, cu, ns,
+             slots):
+        return forward_core(
+            cfg, params, pools, tokens, positions, seq_slots, pt, lens,
+            cu_q_lens=cu, num_seqs=ns, attn_impl=attn,
+            query_attn_impl=paged_attention_tpu,
+            state_slots=slots if program == "unified" else None,
+            lin_impl=lightning_attention_pallas)[:2]
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, i32(N), i32(N), i32(N), i32(B, maxp), i32(B),
+        i32(B + 1), i32(1), i32(B)).compile()
+    text = compiled.as_text()
+    assert "lightning_attention" in text
+    assert "ragged_paged_attention_kernel" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 1024 * 1024

@@ -47,11 +47,16 @@ CELLS = {
     "mistral": ("mistral-7b-v0.3", "sessions-closed"),
     "smallthinker": ("smallthinker-21b-a3b", "sessions-long-closed"),
     "jamba": ("jamba2-3b", "reasoning-closed"),
+    # with --heads 16/1: a sparse layer's call brings one KV head's sixteen
+    # query heads over that head's own pages (ModelConfig.kv_pool_folds)
+    "sala": ("minicpm-sala-9b", "longdoc-closed"),
 }
 # live rows of a fused decode call and decode rows of a unified step
 # (PERF.md section 5: decode_seat_steps_total, program_rows_total)
-LIVE_DECODE = {"qwen": 48, "mistral": 32, "smallthinker": 53, "jamba": 58}
-UNIFIED_DECODE = {"qwen": 49, "mistral": 30, "smallthinker": 53, "jamba": 58}
+LIVE_DECODE = {"qwen": 48, "mistral": 32, "smallthinker": 53, "jamba": 58,
+               "sala": 14}
+UNIFIED_DECODE = {"qwen": 49, "mistral": 30, "smallthinker": 53, "jamba": 58,
+                  "sala": 14}
 
 
 def _load(kind: str, name: str) -> dict:
