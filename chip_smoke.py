@@ -180,8 +180,10 @@ def parity_child(args) -> int:
 
         pack = pack_factor(cfg)
         dhp = padded_head_dim(cfg.head_dim)
+        # decode rows ahead of a chunk, as the engine's plan orders a
+        # unified step: the two-call form (ops/paged_attention.step_geometry)
         q, pt, pos, slots, lens, cu, ns = paged_case(
-            [8, 1, 1, 1], cfg.num_heads, dhp, cfg.head_dim)
+            [1, 1, 8, 1], cfg.num_heads, dhp, cfg.head_dim)
         cache = fill_pool(pack, cfg.num_kv_heads, dhp, cfg.head_dim)
         kw = dict(scale=cfg.head_dim ** -0.5, cu_q_lens=cu, num_seqs=ns)
         pallas = functools.partial(paged_attention_tpu, mesh=mesh)

@@ -1153,10 +1153,13 @@ class LLMEngine:
     def _attn_geometry(self) -> str:
         """The (bkv, bq) block geometry the ragged Pallas kernel (the GQA
         one or the latent one) is traced with in the two step programs that
-        carry the load, as ``unified=<bkv>x<bq> decode=<bkv>x<bq>``; ``none``
-        where another backend serves. It is a function of static shapes, so it is known
-        here. A model with window layers adds the period of windows its
-        layers are traced with (any backend), as ``window=0,4096,4096,4096``."""
+        carry the load, as ``unified=<bkv>x<bq> decode=<bkv>x<bq>``; a GQA
+        unified step that hands its decode rows and its chunks to the kernel
+        in two calls names both pairs, ``unified=32x8+32x64``
+        (`ops/paged_attention.step_geometry`); ``none`` where another backend
+        serves. It is a function of static shapes, so it is known here. A
+        model with window layers adds the period of windows its layers are
+        traced with (any backend), as ``window=0,4096,4096,4096``."""
         window = (" window=" + ",".join(map(str, self.model_cfg.attn_window_pattern))
                   if self.model_cfg.has_window else "")
         programs = (("unified", self.cfg.batched_tokens),
@@ -1171,15 +1174,18 @@ class LLMEngine:
                 for prog, n in programs)
         if not self.attn_backend.startswith("pallas_ragged_paged_attention"):
             return "none" + window
-        from llmd_tpu.ops.paged_attention import call_geometry
+        from llmd_tpu.ops.paged_attention import format_geometry, step_geometry
 
-        # (with sparse selection a call brings one KV head's query heads)
+        # (with sparse selection a call brings one KV head's query heads; a
+        # model with recurrent layers has its unified step's rows cut at KV
+        # blocks, __init__)
         heads = self.model_cfg.num_heads // (
             self.model_cfg.num_kv_heads if self.model_cfg.sparse_topk else 1)
         return " ".join(
-            "{}={}x{}".format(prog, *call_geometry(
-                (n, heads, self.cache.shape[-1]),
-                self.cache.shape, self.cfg.max_pages_per_seq))
+            prog + "=" + format_geometry(step_geometry(
+                (n, heads, self.cache.shape[-1]), self.cache.shape,
+                self.cfg.max_batch_size, self.cfg.max_pages_per_seq,
+                self.model_cfg.has_recurrent))
             for prog, n in programs) + window
 
     def _select_moe_impl(self):

@@ -23,6 +23,9 @@ module owns the serving-stack integration:
       Mistral-7B 32/8, N=64 decode, 129.7k        2215        (32, 8)    894
       Mistral-7B 32/8, N=256 unified, 137.0k      2731        (32, 16)  1253
 
+  (The unified rows are the step as one call, which it was until PR 44:
+  "A unified step is two calls", below.)
+
   A third head layout, 28/4 heads of 128 (SmallThinker: 7 query heads a KV
   head, 32 KB a page; `--cells smallthinker --windows 0,4096 --bkv
   16,32,48,64,96 --bq 4,8,16,32`, chip, PR 33; 53 rows decoding, 425.7k
@@ -91,6 +94,39 @@ module owns the serving-stack integration:
   more, not worth a fourth branch of the rule. One KV head's pages are 4 KB
   and a row's context 830 tokens, so every pair reads at a fifth of the byte
   bound or less: the calls are the fixed costs of their blocks.
+
+  **A unified step is two calls** (`step_geometry`; PR 44). Since PR 38 some
+  58 of a unified step's 64 rows bring one query, and one call carried them
+  at the bq chosen for the chunk beside them (16; 8 at seven heads a KV head)
+  where the fused decode call carries such rows at 8 (4): a decode row owns
+  one row of its query block and pays for all of them, a chunk reads its
+  whole context once a query block and wants many. So the step's leading
+  one-query rows go to the kernel as a call of their own at the fused decode
+  call's pair, and the rest at a bq of their own, over one bkv: every query
+  walks the KV blocks it walked, and the result is the one call's bit for
+  bit (`diff` 0.0 in every row below). The unified step of each cell as one
+  call and as two by the chunks' bq (`--programs unified --split 0,1`, chip,
+  PR 44; us a layer call; `.` = the one call as it was served, `*` = the
+  rule's; the last column is the best of two calls against `.`):
+
+      heads q/kv, rows, context tokens    one call, bq =     two calls, chunk bq =
+                                            8    16    32      8    16    32    64
+      Qwen 12/2, 51 rows, 40.7k             -   .219   267     -    213   204  *205   -6%
+      Mistral 32/8, 33 rows, 137.0k         -  .1246  1453     -   1215  1154 *1140   -9%
+      SmallThinker 28/4, 56 rows, 448.3k .2210  2738  4513   2018 *1863  1912    -   -16%
+      the same, window 4096, 230.0k      .1430  1713  2761   1330 *1210  1237    -   -15%
+      Jamba 20/1, 61 rows, 48.4k            -   .228   373     -    181   195   188  (-21%)
+
+  Even ratios take 64 for the chunks, the swept odd ones 16
+  (`CHUNK_QUERIES_PER_BLOCK`); at 64 a call of seven heads a KV head takes
+  15 s to compile (PR 43's sweep) and reads slower than at 16. Qwen's 12/2
+  reads 6% faster in two calls at 64 or at 32, so it takes the rule every
+  layout takes. Jamba's row is its layout, not its engine: a model with
+  recurrent layers hands the kernel its rows cut at KV blocks
+  (`split_rows_at_kv_blocks`), up to twice the rows and no longer decode rows
+  first, and keeps the one call (two attention layers of 28: 0.09 ms a step).
+  After it Mistral's decode rows in a unified step read at 85% of their
+  KV-byte bound (PERF.md section 6, PR 44).
 
   **One bkv an engine.** The kernel's online softmax blocks a row's keys from
   its page table's first entry, so two programs, or a cold and a cached
@@ -188,9 +224,10 @@ def pick_block_sizes(num_tokens: int, page_size: int, pages_per_seq: int,
     short model length is one block a sequence; nothing past its page table
     is fetched). bq by the token budget N, which is all a trace can see of
     which step program calls: up to 128 (the fused decode call, one query row
-    a sequence) 8 rows; up to 512 (the unified step: decode rows, then
-    prefill chunks, each of which reads its whole context once per query
-    block) 16; larger prefill budgets keep the 64 they had (not swept).
+    a sequence) 8 rows; up to 512 (a unified step whose rows go to the kernel
+    in one call, decode rows and chunks together: a model with recurrent
+    layers; every other unified step makes two calls, `step_geometry`) 16;
+    larger prefill budgets keep the 64 they had (not swept).
 
     ``heads_per_kv`` (query heads a KV head; 0 = not given) is the one thing
     the rule reads of the head layout. Even ratios (12/2 and 32/8 heads of
@@ -223,7 +260,11 @@ def call_geometry(q_shape, cache_shape, pages_per_seq: int) -> tuple[int, int]:
     ``tp``, so a shard has the ratio of the whole model and the pair is the
     same under ``tp`` as on one device, which planes a device are not."""
     return pick_block_sizes(q_shape[0], cache_shape[1], pages_per_seq,
-                            q_shape[1] // max(1, cache_shape[2] // 2))
+                            _heads_per_kv(q_shape, cache_shape))
+
+
+def _heads_per_kv(q_shape, cache_shape) -> int:
+    return q_shape[1] // max(1, cache_shape[2] // 2)
 
 
 def window_align_pages(q_shape, cache_shape, pages_per_seq: int) -> int:
@@ -232,6 +273,83 @@ def window_align_pages(q_shape, cache_shape, pages_per_seq: int) -> int:
     (any ``q_shape[0]`` gives the same). What the engine counts a window
     layer's reads with (``attn_kv_tokens_total``)."""
     return call_geometry(q_shape, cache_shape, pages_per_seq)[0]
+
+
+# Query rows a block of the chunk rows' call in a unified step that makes two
+# calls (`step_geometry`): the sweep's best at the even ratios, a quarter of it
+# at the swept odd ones (module docstring).
+CHUNK_QUERIES_PER_BLOCK = 64
+
+
+def step_geometry(q_shape, cache_shape, num_rows: int, pages_per_seq: int,
+                  split_at_kv_blocks: bool = False
+                  ) -> tuple[tuple[int, int], ...]:
+    """The kernel calls `paged_attention_tpu` makes for a call of these
+    static shapes over ``num_rows`` rows, a (bkv, bq) pair each, with one bkv.
+
+    One call at `call_geometry`'s pair where a row brings one query (the
+    fused decode call: no more tokens than rows), where the call has one row,
+    and where the rows are re-cut at their KV blocks' ends
+    (``split_at_kv_blocks``: a model with recurrent layers; the cut makes up
+    to twice the rows of another structure, and such a model's attention
+    layers are few). Every other call is a unified step's, whose rows are
+    one-query decode rows first and prefill chunks after them, and makes
+    two: the decode rows as a call of ``num_rows`` tokens at the fused decode
+    call's pair, and the rest at `CHUNK_QUERIES_PER_BLOCK` (a quarter of it
+    at a swept odd number of query heads a KV head). What a decode row wants
+    of bq (few rows: it owns one of the block's) and what a chunk wants (many:
+    it reads its whole context once a query block) conflict, and no one pair
+    serves both (module docstring)."""
+    num_tokens = q_shape[0]
+    if split_at_kv_blocks or not 1 < num_rows < num_tokens:
+        return (call_geometry(q_shape, cache_shape, pages_per_seq),)
+    rows = call_geometry((num_rows, *q_shape[1:]), cache_shape, pages_per_seq)
+    odd = _heads_per_kv(q_shape, cache_shape) in SWEPT_ODD_HEADS_PER_KV
+    chunk_bq = CHUNK_QUERIES_PER_BLOCK // 4 if odd else CHUNK_QUERIES_PER_BLOCK
+    return (rows, (rows[0], min(chunk_bq, num_tokens)))
+
+
+def format_geometry(pairs) -> str:
+    """``32x8+32x64``: a call's pairs as the ``geometry`` label names them."""
+    return "+".join(f"{bkv}x{bq}" for bkv, bq in pairs)
+
+
+def decode_rows_and_chunks(page_tables, kv_lens, cu_q_lens, num_seqs):
+    """A unified step's rows as two calls' rows: ``(n_dec, decode, chunks)``,
+    each of the two the ``(kv_lens, page_tables, cu_q_lens, num_seqs)`` of a
+    kernel call over the step's own query tokens.
+
+    ``n_dec`` is the length of the longest prefix of live rows that bring one
+    query (the engine's plan puts decode rows first; a chunk of one token
+    that follows them rides along: the result is the same). The decode call
+    is told of those rows, over the first ``B`` query tokens. The chunk call
+    keeps the token axis as it is and takes the rows from the last decode row
+    on: that row stands in for all of them, a row of ``n_dec`` queries over
+    one token of context, so the first chunk's queries stay where they are
+    (no copy of q, none of the output) at the price of one KV block a query
+    block up to ``n_dec``. What the stand-in row computes is not read.
+
+    Each call is told of one row at the least: told of none, the upstream
+    kernel leaves the page copy it starts for row 0 unwaited and the chip
+    halts. A step with no decode row hands the decode call its first row as
+    one query over one token; a step with nothing else hands the chunk call
+    the stand-in alone."""
+    B = kv_lens.shape[0]
+    row = jnp.arange(B, dtype=jnp.int32)
+    q_len = cu_q_lens[1:] - cu_q_lens[:-1]
+    one = (row < num_seqs[0]) & (q_len == 1)
+    n_dec = jnp.sum(jnp.cumprod(one.astype(jnp.int32))).astype(jnp.int32)
+    told = jnp.maximum(n_dec, 1)
+    decode = (jnp.where(row < n_dec, kv_lens, 1), page_tables,
+              jnp.minimum(jnp.arange(B + 1, dtype=jnp.int32), told),
+              told[None])
+    shift = jnp.maximum(n_dec - 1, 0)
+    lens = jnp.roll(kv_lens, -shift)
+    cu = cu_q_lens[jnp.minimum(jnp.arange(B + 1, dtype=jnp.int32) + shift, B)]
+    chunks = (lens.at[0].set(jnp.where(n_dec > 0, 1, lens[0])),
+              jnp.roll(page_tables, -shift, axis=0),
+              cu.at[0].set(0), num_seqs.astype(jnp.int32) - shift)
+    return n_dec, decode, chunks
 
 
 def split_rows_at_kv_blocks(page_tables, kv_lens, cu_q_lens, num_seqs,
@@ -309,7 +427,10 @@ def paged_attention_tpu(
     call's and the result is its result bit for bit); causality derives from
     ``kv_len - q_len``, which the shift leaves as it was."""
     del positions, seq_slots, chunk_k, chunk_v
-    bkv, bq = call_geometry(q.shape, layer_cache.shape, page_tables.shape[1])
+    B = page_tables.shape[0]
+    pairs = step_geometry(q.shape, layer_cache.shape, B, page_tables.shape[1],
+                          split_at_kv_blocks)
+    bkv = pairs[0][0]
     if sliding_window is not None:
         from llmd_tpu.models.transformer import window_view
 
@@ -334,22 +455,31 @@ def paged_attention_tpu(
         # packing). True for llama-1b both padded (16) and packed (8); NOT for
         # tiny CI models with 2 combined heads, which the kernel rejects.
         extra = {"k_scale": 1.0, "v_scale": 1.0}
-    call = functools.partial(
-        _kernel(),
-        sm_scale=scale,
-        num_kv_pages_per_block=bkv,
-        num_queries_per_block=bq,
-        vmem_limit_bytes=VMEM_LIMIT,
-        sliding_window=sliding_window,
-        **extra,
-    )
-    if mesh is not None:
-        call = shard_over_heads(call, mesh, q, layer_cache, shard_kv=True)
-    return call(
-        q,
-        layer_cache,
-        kv_lens.astype(jnp.int32),
-        page_tables.astype(jnp.int32),
-        cu_q_lens.astype(jnp.int32),
-        num_seqs.astype(jnp.int32),
-    )
+
+    def kernel(q, bq, kv_lens, page_tables, cu_q_lens, num_seqs):
+        call = functools.partial(
+            _kernel(),
+            sm_scale=scale,
+            num_kv_pages_per_block=bkv,
+            num_queries_per_block=bq,
+            vmem_limit_bytes=VMEM_LIMIT,
+            sliding_window=sliding_window,
+            **extra,
+        )
+        if mesh is not None:
+            call = shard_over_heads(call, mesh, q, layer_cache, shard_kv=True)
+        return call(q, layer_cache, kv_lens.astype(jnp.int32),
+                    page_tables.astype(jnp.int32), cu_q_lens.astype(jnp.int32),
+                    num_seqs.astype(jnp.int32))
+
+    if len(pairs) == 1:
+        return kernel(q, pairs[0][1], kv_lens, page_tables, cu_q_lens, num_seqs)
+    # both calls walk a row's KV blocks of bkv pages from its table's first
+    # entry, as the one call did: each token's result is that call's, bit for
+    # bit, from whichever call owns its row
+    n_dec, decode, chunks = decode_rows_and_chunks(
+        page_tables, kv_lens, cu_q_lens, num_seqs)
+    head = kernel(q[:B], pairs[0][1], *decode)
+    out = kernel(q, pairs[1][1], *chunks)
+    head = jnp.where((jnp.arange(B) < n_dec)[:, None, None], head, out[:B])
+    return jax.lax.dynamic_update_slice_in_dim(out, head, 0, axis=0)

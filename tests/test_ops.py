@@ -199,7 +199,9 @@ def test_pallas_adapter_glue_with_stub_kernel(monkeypatch):
     np.testing.assert_array_equal(np.asarray(captured["cu_q_lens"]), cu)
     np.testing.assert_array_equal(np.asarray(captured["num_seqs"]), [3])
     assert captured["sm_scale"] == 0.11
-    bkv, bq = pa.pick_block_sizes(q.shape[0], 16, 4)
+    # (the last of the step's two calls, the chunks': with a chunk in the
+    # first row it is handed every row as it came)
+    bkv, bq = pa.step_geometry(q.shape, kv.shape, *pt.shape)[-1]
     assert captured["num_kv_pages_per_block"] == bkv
     assert captured["num_queries_per_block"] == bq
     assert captured["vmem_limit_bytes"] == pa.VMEM_LIMIT

@@ -127,6 +127,26 @@ def test_one_kv_block_an_engine(layout, pages):
     assert blocks == {window_align_pages(*_shapes(256, LAYOUTS[layout]), pages)}
 
 
+def _record_kernel(monkeypatch):
+    """A recorder in the kernel's place: every invocation's (query tokens,
+    rows, bkv, bq) and what it was handed."""
+    import jax.numpy as jnp
+
+    import llmd_tpu.ops.paged_attention as pa
+
+    seen = []
+
+    def stub(q, kv, kv_lens, page_tables, cu_q_lens, num_seqs, **kw):
+        seen.append(((q.shape[0], page_tables.shape[0],
+                      kw["num_kv_pages_per_block"],
+                      kw["num_queries_per_block"]),
+                     (kv_lens, page_tables, cu_q_lens, num_seqs)))
+        return jnp.zeros_like(q)
+
+    monkeypatch.setattr(pa, "_kernel", lambda: stub)
+    return seen
+
+
 @pytest.mark.parametrize("heads", [8, 14])  # four and seven a KV head
 def test_full_and_window_calls_of_both_programs_block_alike(monkeypatch, heads):
     """What the kernel is handed, through a stub: the same bkv whatever the
@@ -137,14 +157,7 @@ def test_full_and_window_calls_of_both_programs_block_alike(monkeypatch, heads):
 
     import llmd_tpu.ops.paged_attention as pa
 
-    seen = []
-
-    def stub(q, kv, kv_lens, page_tables, cu_q_lens, num_seqs, **kw):
-        seen.append((kw["num_kv_pages_per_block"], kw["num_queries_per_block"],
-                     np.asarray(page_tables), np.asarray(kv_lens)))
-        return jnp.zeros_like(q)
-
-    monkeypatch.setattr(pa, "_kernel", lambda: stub)
+    calls = _record_kernel(monkeypatch)
     ps, maxp, B = 16, 256, 2
     pt = np.arange(B * maxp, dtype=np.int32).reshape(B, maxp)
     lens = np.asarray([3000, 1700], np.int32)
@@ -157,12 +170,26 @@ def test_full_and_window_calls_of_both_programs_block_alike(monkeypatch, heads):
                 jnp.asarray(pt), None, None, jnp.asarray(lens), scale=1.0,
                 cu_q_lens=jnp.asarray(cu), num_seqs=jnp.asarray([B], np.int32),
                 **({"sliding_window": window} if window else {}))
+    seen = [(bkv, bq, np.asarray(page_tables), np.asarray(kv_lens))
+            for (_, _, bkv, bq), (kv_lens, page_tables, _, _) in calls]
     want_bkv = 64 if heads == 14 else 32
     assert {bkv for bkv, *_ in seen} == {want_bkv}
+    # the fused call's one; the unified step's two (its decode row, its chunk)
     assert [bq for _, bq, *_ in seen] == (
-        [2, 2, 8, 8] if heads == 14 else [2, 2, 16, 16])
-    for (_, _, full_pt, full_lens), (_, _, win_pt, win_lens) in (
-            seen[0:2], seen[2:4]):
+        [2, 2, 2, 16, 2, 16] if heads == 14 else [2, 2, 2, 64, 2, 64])
+
+    def rows(calls):
+        """A step's rows as the kernel was handed them: the decode row from
+        the decode rows' call, the chunk from the chunks'."""
+        if len(calls) == 1:
+            return calls[0][2:]
+        (_, _, dec_pt, dec_lens), (_, _, chunk_pt, chunk_lens) = calls
+        return (np.concatenate([dec_pt[:1], chunk_pt[1:]]),
+                np.concatenate([dec_lens[:1], chunk_lens[1:]]))
+
+    for full, win in ((seen[0:1], seen[1:2]), (seen[2:4], seen[4:6])):
+        (full_pt, full_lens), (win_pt, win_lens) = rows(full), rows(win)
+        np.testing.assert_array_equal(full_lens, lens)
         shift = (full_lens - win_lens) // ps
         assert (shift % want_bkv == 0).all() and shift.max() > 0
         for b in range(B):
@@ -219,21 +246,34 @@ def test_environment_leaves_the_geometry_unchanged(monkeypatch, tmp_path):
 
 
 def test_engine_reports_the_geometry_its_programs_trace():
-    """`engine_attn_backend{geometry}` is `pick_block_sizes` at the unified
-    step's and the fused decode call's static shapes; `none` where the XLA
-    reference serves."""
+    """`engine_attn_backend{geometry}` is `step_geometry` at the unified
+    step's and the fused decode call's static shapes, both pairs of a step
+    that makes two calls; `none` where the XLA reference serves."""
     def mk(**kw):
         return LLMEngine(get_model_config("tiny"), EngineConfig(
             page_size=8, num_pages=32, max_model_len=64, max_batch_size=2,
             prefill_chunk=16, **kw))
 
     eng = mk(attn_impl="pallas")
-    # 8 pages a sequence (clamps bkv); N = 16 tokens unified, 2 seats decode
+    # 8 pages a sequence (clamps bkv); N = 16 tokens unified, 2 seats decode:
+    # the step's decode rows at the fused call's pair, its chunks at 16
     assert (pick_block_sizes(16, 8, 8), pick_block_sizes(2, 8, 8)) \
         == ((8, 8), (8, 2))
-    assert eng.attn_geometry == "unified=8x8 decode=8x2"
-    assert 'geometry="unified=8x8 decode=8x2"' in eng.metrics.registry.expose()
+    assert eng.attn_geometry == "unified=8x2+8x16 decode=8x2"
+    assert 'geometry="unified=8x2+8x16 decode=8x2"' \
+        in eng.metrics.registry.expose()
     assert mk().attn_geometry == "none"
+
+
+def test_an_engine_whose_rows_are_cut_reports_one_pair():
+    """A model with recurrent layers keeps the single call in its unified
+    step (its rows are cut at KV blocks), and the label stays as it was."""
+    import test_hybrid_ssm
+
+    eng = test_hybrid_ssm._engine(attn_impl="pallas")
+    assert eng.attn_geometry == "unified=24x8 decode=24x4"
+    assert 'geometry="unified=24x8 decode=24x4"' \
+        in eng.metrics.registry.expose()
 
 
 def test_engine_shifts_window_layers_by_the_block_it_reports():
@@ -243,9 +283,9 @@ def test_engine_shifts_window_layers_by_the_block_it_reports():
     from dataclasses import replace
 
     for heads, kv_heads, pages, geometry in (
-            (4, 2, 128, "unified=32x16 decode=32x4"),
-            (6, 2, 128, "unified=64x8 decode=64x4"),
-            (6, 2, 48, "unified=48x8 decode=48x4")):
+            (4, 2, 128, "unified=32x4+32x64 decode=32x4"),
+            (6, 2, 128, "unified=64x4+64x16 decode=64x4"),
+            (6, 2, 48, "unified=48x4+48x16 decode=48x4")):
         cfg = replace(get_model_config("tiny"), num_heads=heads,
                       num_kv_heads=kv_heads, attn_window_pattern=(0, 64),
                       rope_pattern=(0, 1))
@@ -256,6 +296,229 @@ def test_engine_shifts_window_layers_by_the_block_it_reports():
         assert eng._window_align == int(geometry.split("=")[1].split("x")[0])
         assert eng._window_align == window_align_pages(
             (4, heads, 128), eng.cache.shape, pages)
+
+
+# ------------------------------------------- a unified step's two kernel calls
+
+# one KV head's twenty query heads (Jamba2-3B's attention layers)
+JAMBA = (20, 128, 2)
+
+
+@pytest.mark.parametrize("layout,pages,kw,unified,decode", [
+    # the cells' five layouts at their model lengths: which calls a unified
+    # step of 256 tokens over 64 rows makes, and the fused decode call's one
+    (QWEN, 256, {}, [(64, 32, 8), (256, 32, 64)], (32, 8)),
+    (MISTRAL, 512, {}, [(64, 32, 8), (256, 32, 64)], (32, 8)),
+    (SMALLTHINKER, 1024, {}, [(64, 64, 4), (256, 64, 16)], (64, 4)),
+    (SMALLTHINKER, 1024, {"sliding_window": 4096},
+     [(64, 64, 4), (256, 64, 16)], (64, 4)),
+    # rows cut at their KV blocks (a model with recurrent layers): one call
+    # over twice the rows, as before
+    (JAMBA, 192, {"split_at_kv_blocks": True}, [(256, 32, 16)], (32, 8)),
+], ids=["qwen", "mistral", "smallthinker-full", "smallthinker-window",
+        "jamba-rows-cut"])
+def test_which_calls_a_unified_step_makes(monkeypatch, layout, pages, kw,
+                                          unified, decode):
+    """The decode rows of a unified step go to the kernel at the fused decode
+    call's pair, as a call of as many query tokens as the step has rows, and
+    the chunks at a pair of their own, over one bkv; a call whose rows are cut
+    at their KV blocks stays one. Read from static shapes alone."""
+    import jax.numpy as jnp
+
+    import llmd_tpu.ops.paged_attention as pa
+
+    seen = _record_kernel(monkeypatch)
+    heads, width, planes = layout
+    B, N = 64, 256
+    cache = jnp.zeros((8, 16, planes, width), jnp.bfloat16)
+    rows = (jnp.zeros((B, pages), jnp.int32), None, None,
+            jnp.full((B,), 40, jnp.int32))
+
+    def call(n, cu, **kw):
+        del seen[:]
+        pa.paged_attention_tpu(
+            jnp.zeros((n, heads, width), jnp.bfloat16), cache, *rows, scale=1.0,
+            cu_q_lens=jnp.asarray(cu, jnp.int32),
+            num_seqs=jnp.asarray([B], jnp.int32), **kw)
+        return [(n, bkv, bq) for (n, _, bkv, bq), _ in seen]
+
+    cut = kw.get("split_at_kv_blocks", False)
+    cu = list(range(B)) + [N]  # 63 decode rows and a chunk
+    assert call(N, cu, **kw) == unified
+    assert len({bkv for _, bkv, _ in unified}) == 1
+    assert pa.step_geometry((N, heads, width), cache.shape, B, pages, cut) \
+        == tuple((bkv, bq) for _, bkv, bq in unified)
+    assert pa.window_align_pages((N, heads, width), cache.shape, pages) \
+        == unified[0][1]
+    window = {k: v for k, v in kw.items() if k == "sliding_window"}
+    assert call(B, range(B + 1), **window) == [(B, *decode)]
+    assert decode[0] == unified[0][1]
+    assert pa.step_geometry((B, heads, width), cache.shape, B, pages) \
+        == (decode,)
+    # one row is one call whatever it brings (the embeddings program)
+    assert pa.step_geometry((N, heads, width), cache.shape, 1, pages) \
+        == (pa.call_geometry((N, heads, width), cache.shape, pages),)
+
+
+# (query tokens, rows, bkv, bq) of every kernel invocation a program traces,
+# read at the parent of PR 44: a model with recurrent layers keeps them
+TINY_CALLS = {
+    "test_hybrid_ssm": ("unified=24x8 decode=24x4",
+                        [(16, 8, 24, 8)], [(4, 4, 24, 4)] * 2),
+    "test_minicpm_sala": ("unified=32x8 decode=32x4",
+                          [(32, 8, 32, 8), (32, 32, 20, 8)] * 4,
+                          [(4, 4, 32, 4), (4, 4, 20, 4)] * 8),
+}
+
+
+@pytest.mark.parametrize("module", sorted(TINY_CALLS))
+def test_recurrent_models_programs_call_the_kernel_as_they_did(monkeypatch,
+                                                               module):
+    """The step programs of the tiny Jamba and the tiny MiniCPM-SALA engine
+    make the kernel calls they made before a unified step's rows went two
+    ways: their rows are cut at KV blocks (one call), and a sparse layer's
+    one-query rows already go to the decode impl."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    seen = _record_kernel(monkeypatch)
+    eng = importlib.import_module(module)._engine(attn_impl="pallas")
+    geometry, unified, decode = TINY_CALLS[module]
+    assert eng.attn_geometry == geometry
+    B, NT = eng.cfg.max_batch_size, eng.cfg.batched_tokens
+    maxp = eng.cfg.max_pages_per_seq
+
+    def i(*shape):
+        return jnp.zeros(shape, jnp.int32)
+
+    eng._unified_fn.lower(
+        eng.params, eng._pools(), i(NT), i(NT), i(NT), i(B, maxp), i(B),
+        i(B + 1), i(1), i(NT), eng._zero_sampled, *eng._greedy_state,
+        state_slots=i(B))
+    assert [shape for shape, _ in seen] == unified
+    del seen[:]
+    eng._decode_multi_fn.lower(
+        eng.params, eng._pools(), i(B), i(B), i(B, maxp), i(B),
+        *eng._greedy_state, i(B), i(B))
+    assert [shape for shape, _ in seen] == decode
+
+
+def test_what_each_of_the_two_calls_is_told():
+    """The rows the two calls are handed (`decode_rows_and_chunks`): the
+    decode call the longest prefix of live one-query rows; the chunk call the
+    rows from the last of those on, that one standing in for all of them over
+    one token of context, on the step's own token axis. Neither is ever told
+    of no row (the upstream kernel halts the chip on that)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llmd_tpu.ops.paged_attention import decode_rows_and_chunks
+
+    B, maxp = 6, 4
+    pt = np.arange(B * maxp, dtype=np.int32).reshape(B, maxp)
+    lens = np.asarray([30, 31, 32, 50, 7, 1], np.int32)
+
+    def told(q_lens, live):
+        cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
+        n_dec, decode, chunks = decode_rows_and_chunks(
+            jnp.asarray(pt), jnp.asarray(lens), jnp.asarray(cu),
+            jnp.asarray([live], jnp.int32))
+        return int(n_dec), *(
+            [np.asarray(a).tolist() for a in call] for call in (decode, chunks))
+
+    # three decode rows, a chunk of 9, a chunk of one token, a dead row
+    n, (dl, dp, dcu, dn), (cl, cp, ccu, cn) = told([1, 1, 1, 9, 1, 0], 5)
+    assert (n, dn, cn) == (3, [3], [3])
+    assert dl[:3] == [30, 31, 32] and dcu == [0, 1, 2, 3, 3, 3, 3]
+    assert dp == pt.tolist()
+    assert cl[:3] == [1, 50, 7] and ccu[:4] == [0, 3, 12, 13]
+    assert cp[:3] == pt[2:5].tolist()
+    # a one-token chunk right behind the decode rows rides with them
+    n, (_, _, dcu, dn), (cl, _, ccu, cn) = told([1, 1, 1, 1, 9, 0], 5)
+    assert (n, dn, cn) == (4, [4], [2])
+    assert cl[:2] == [1, 7] and ccu[:3] == [0, 4, 13]
+    # no decode row: the decode call takes the first row as one query over
+    # one token, the chunk call every row as it came
+    n, (dl, _, dcu, dn), (cl, cp, ccu, cn) = told([9, 4, 0, 0, 0, 0], 2)
+    assert (n, dn, cn) == (0, [1], [2])
+    assert dl[0] == 1 and dcu[:3] == [0, 1, 1]
+    assert (cl, cp, ccu) == (lens.tolist(), pt.tolist(),
+                             [0, 9, 13, 13, 13, 13, 13])
+    # nothing but decode rows: the chunk call is left the stand-in alone
+    n, (_, _, dcu, dn), (cl, _, ccu, cn) = told([1] * 6, 6)
+    assert (n, dn, cn) == (6, [6], [1])
+    assert dcu == list(range(7)) and cl[0] == 1 and ccu[:2] == [0, 6]
+    # a dead row's queries are nobody's: the prefix ends at the live rows
+    assert told([1, 1, 1, 1, 1, 1], 2)[0] == 2
+
+
+def _interpret_case(q_lens, seq_lens, heads, window=None):
+    """A unified step of 32 tokens over 8 rows of 64-token pages (so that a
+    KV block is 8 pages, 16 at seven query heads a KV head, and interpret
+    mode stays cheap), pages scattered over the pool."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    ps, Hk, D, N, B, maxp = 64, 2, 128, 32, 8, 48
+    rng = np.random.default_rng(0)
+    P = sum(-(-n // ps) for n in seq_lens) + 3
+    free = rng.permutation(P)
+    pt = np.full((B, maxp), -1, np.int32)
+    lens, cu = np.ones((B,), np.int32), np.zeros((B + 1,), np.int32)
+    used = 0
+    for b, (n, q) in enumerate(zip(seq_lens, q_lens)):
+        pages = -(-n // ps)
+        pt[b, :pages] = free[used:used + pages]
+        lens[b], used, cu[b + 1] = n, used + pages, cu[b] + q
+    cu[len(seq_lens) + 1:] = cu[len(seq_lens)]
+    args = (jnp.asarray(rng.standard_normal((N, heads, D)), jnp.bfloat16),
+            jnp.asarray(rng.standard_normal((P, ps, 2 * Hk, D)), jnp.bfloat16),
+            jnp.asarray(pt), None, None, jnp.asarray(lens))
+    kw = dict(scale=D ** -0.5, cu_q_lens=jnp.asarray(cu),
+              num_seqs=jnp.asarray([len(seq_lens)], jnp.int32))
+    if window:
+        kw["sliding_window"] = window
+    return args, kw, cu
+
+
+@pytest.mark.parametrize("q_lens,seq_lens,heads,window", [
+    ([1, 1, 1, 18, 5], [300, 700, 1100, 600, 40], 4, None),
+    ([18, 5], [600, 40], 4, None),
+    ([1, 1, 1], [300, 700, 1100], 4, None),
+    ([1, 1, 18, 1], [300, 700, 600, 33], 4, None),
+    ([1, 1, 1, 18, 5], [300, 700, 2100, 1500, 40], 14, 640),
+    ([1, 1, 12], [512, 1024, 1024], 4, None),
+], ids=["mixed", "no-decode-rows", "only-decode-rows", "one-token-chunk",
+        "window-layer", "kv-len-on-a-blocks-end"])
+def test_two_calls_equal_the_single_call_bit_for_bit(monkeypatch, q_lens,
+                                                     seq_lens, heads, window):
+    """The upstream kernel in interpret mode: every token of the two-call
+    form equals, to the last bit, the single call over all the step's rows at
+    the pair of the call that owns the token. (Against one pair for all
+    tokens the chip reads 0.0 too, `tools/attn_sweep.py --split 0,1`; the
+    CPU's interpreter rounds a few elements otherwise by the block's shape.)"""
+    import functools
+
+    import numpy as np
+    from jax.experimental import pallas as pl
+
+    import llmd_tpu.ops.paged_attention as pa
+
+    # the upstream wrapper takes no interpret flag: give its pallas_call one
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+    args, kw, cu = _interpret_case(q_lens, seq_lens, heads, window)
+    pairs = pa.step_geometry(args[0].shape, args[1].shape, *args[2].shape)
+    assert len(pairs) == 2 and pairs[0][1] != pairs[1][1]
+    got = np.asarray(pa.paged_attention_tpu(*args, **kw), np.float32)
+    n_dec = next((b for b, q in enumerate(q_lens) if q != 1), len(q_lens))
+    for pair, tokens in ((pairs[0], slice(0, cu[n_dec])),
+                         (pairs[1], slice(cu[n_dec], cu[len(q_lens)]))):
+        monkeypatch.setattr(pa, "step_geometry", lambda *a, pair=pair: (pair,))
+        want = np.asarray(pa.paged_attention_tpu(*args, **kw), np.float32)
+        np.testing.assert_array_equal(got[tokens], want[tokens])
+    assert np.isfinite(got[:cu[len(q_lens)]]).all()
 
 
 # -------------------------------------------------- b128 scaling regression

@@ -114,7 +114,9 @@ def test_cell_shapes_compile_for_v5e_with_the_rules_geometry(one_chip, config,
                                                              window, n):
     """The geometry `pick_block_sizes` gives the cells' step programs goes
     through Mosaic and the TPU compiler here: one it refuses (VMEM, tiling, an
-    unaligned slice) fails this test and not the cell. A window layer's call
+    unaligned slice) fails this test and not the cell (at 256 tokens the
+    program is the unified step's, two kernel calls where the rows are not
+    cut: `test_a_unified_steps_two_calls_compile_for_v5e`). A window layer's call
     (the kernel's mask and page tables shifted by whole KV blocks) beside the
     full layer's, and (window -1) the call whose rows are cut at their KV
     blocks' ends, which a model with recurrent layers makes: twice the rows
@@ -136,6 +138,39 @@ def test_cell_shapes_compile_for_v5e_with_the_rules_geometry(one_chip, config,
             for a in _attn_args(q_shape, cache_shape, 64, maxp)]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "ragged_paged_attention_kernel" in text
+
+
+@pytest.mark.parametrize("config,window,calls", [
+    ("mistral-7b-v0.3", 0, ((32, 8), (32, 64))),
+    ("smallthinker", 0, ((64, 4), (64, 16))),
+    ("smallthinker", 4096, ((64, 4), (64, 16))),
+])
+def test_a_unified_steps_two_calls_compile_for_v5e(one_chip, config, window,
+                                                   calls):
+    """The unified step at Mistral's and SmallThinker's published widths is
+    two kernel calls in the compiled program, the decode rows' over 64 query
+    tokens and the chunks' over 256, at the pairs `step_geometry` names: the
+    chunk pair (64 query rows a block at 32/8 heads: the kernel's largest
+    scratch) is one no single call ran before."""
+    import re
+
+    from llmd_tpu.ops.paged_attention import step_geometry
+
+    heads, kv_heads, maxp = CELL_LAYOUTS[config]
+    kw = {"sliding_window": window} if window else {}
+
+    def fn(q, cache, pt, pos, slots, lens, cu, ns):
+        return paged_attention_tpu(q, cache, pt, pos, slots, lens,
+                                   scale=128 ** -0.5, cu_q_lens=cu,
+                                   num_seqs=ns, **kw)
+
+    q_shape, cache_shape = (256, heads, 128), (1024, 16, 2 * kv_heads, 128)
+    assert step_geometry(q_shape, cache_shape, 64, maxp) == calls
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in _attn_args(q_shape, cache_shape, 64, maxp)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert sorted(int(n) for n in re.findall(
+        r"%ragged_paged_attention_kernel\S* = bf16\[(\d+),", text)) == [64, 256]
 
 
 @pytest.mark.parametrize("n,state", [(64, jnp.float32), (256, jnp.float32),
@@ -296,6 +331,23 @@ def test_paged_attention_lowers_under_tp4_sharding():
                       q_spec=P(None, "tp", None),
                       cache_spec=P(None, None, "tp", None))
     _lower_for_tpu(fn, *args)
+
+
+@pytest.mark.parametrize("case", ["tp4", "fp8"])
+def test_a_unified_steps_two_calls_lower_where_no_cell_runs_them(case):
+    """The two-call form of a unified step (256 tokens over 64 rows) on the
+    paths no benchmark cell runs: under the engine's tp layout (each call in
+    its own shard_map, the decode rows' over a slice of the queries) and over
+    fp8 pages, both through the packed-KV wrapper."""
+    mesh = build_mesh(MeshConfig(tp=4)) if case == "tp4" else None
+    fn, q_shape, cache_shape = _llama_packed_attn(mesh)
+    args = list(_attn_args(q_shape(256), cache_shape, 64, 64, mesh=mesh,
+                           q_spec=P(None, "tp", None),
+                           cache_spec=P(None, None, "tp", None)))
+    if case == "fp8":
+        args[1] = _spec(cache_shape, jnp.float8_e4m3fn)
+    text = _lower_for_tpu(fn, *args)
+    assert text.count("tpu_custom_call") >= 2
 
 
 def test_mla_latent_kernel_lowers_for_tpu():

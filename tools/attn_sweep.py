@@ -16,10 +16,13 @@ tokens of context read, which says whether the fixed part of a KV block or the
 work per query row sets the time, and beside them the seconds the pair took to
 trace and lower (the kernel's page-fetch loop is unrolled in Python, so a
 larger block costs every launch that much more per call site, compile cache
-or not). ``pick_block_sizes`` (the rule) is marked in the table. A pair the
+or not). The rule's own (`step_geometry`) is marked in the table. A pair the
 compiler refuses is recorded with its error. ``--heads 12/4,24/4`` puts other
 head counts over a cell's pool and contexts: which property of a layout the
-best pair follows.
+best pair follows. ``--split 0,1`` times a unified step's rows as one call at
+each pair and as two (the one-query rows at the fused decode call's pair, the
+chunks at the pair's bq; `paged_attention_tpu`), the one call first: every
+row's ``diff`` is against the first row's output and must read 0.0.
 
     python tools/attn_sweep.py                  # on the chip
     python tools/attn_sweep.py --compile-only   # here: which pairs Mosaic takes
@@ -182,7 +185,8 @@ def window_diff(pa, case, operands) -> float:
         served = pa.paged_attention_tpu(q, cache, pts, None, None, lens,
                                         scale=scale, cu_q_lens=cu,
                                         num_seqs=ns, sliding_window=w)
-        bkv, bq = pa.call_geometry(q.shape, cache.shape, pts.shape[1])
+        # (bq: the chunk rows' where the step makes two calls; any reads alike)
+        bkv, bq = pa.step_geometry(q.shape, cache.shape, *pts.shape)[-1]
         masked = pa._kernel()(q, cache, lens, jnp.maximum(pts, 0), cu, ns,
                               sm_scale=scale, sliding_window=w,
                               num_kv_pages_per_block=bkv,
@@ -222,15 +226,17 @@ def _operands(case, seed):
             jnp.asarray([case["num_seqs"]], jnp.int32))
 
 
-def measure(pa, case, geometry, reps, operands, chip):
-    """One row of the report: compile (and, with operands, time) the kernel at
-    ``geometry``. ``pick_block_sizes`` is replaced for the trace, so the call
-    goes through `paged_attention_tpu` as the engine's does."""
+def measure(pa, case, calls, reps, operands, chip):
+    """One row of the report: compile (and, with operands, time) the kernel
+    calls ``calls`` (one (bkv, bq) pair, or the decode rows' and the chunks').
+    ``step_geometry`` is replaced for the trace, so the call goes through
+    `paged_attention_tpu` as the engine's does."""
     import jax
     import numpy as np
 
-    rule, row = pa.pick_block_sizes, dict(zip(("bkv", "bq"), geometry))
-    pa.pick_block_sizes = lambda *a, **k: geometry
+    rule = pa.step_geometry
+    row = dict(zip(("bkv", "bq"), calls[-1]), split=len(calls) > 1)
+    pa.step_geometry = lambda *a, **k: calls
     try:
         # timed apart: a launch pays trace + lowering even on a cache hit
         t0 = time.perf_counter()
@@ -255,7 +261,7 @@ def measure(pa, case, geometry, reps, operands, chip):
     except Exception as e:  # the compiler's words are the result
         row["error"] = f"{type(e).__name__}: {e}"[:400]
     finally:
-        pa.pick_block_sizes = rule
+        pa.step_geometry = rule
     return row
 
 
@@ -278,6 +284,10 @@ def main() -> None:
                     help="query/KV head counts to put in the cells' place, "
                          "e.g. 24/4,32/4: which property of a layout the "
                          "best pair follows (empty = the configuration's own)")
+    ap.add_argument("--split", default="0",
+                    help="0, 1 or 0,1: a unified step's rows as one call at "
+                         "each pair, or as two (one-query rows at the fused "
+                         "decode call's pair, chunks at the pair's bq)")
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "attn_sweep.json"))
@@ -326,31 +336,38 @@ def main() -> None:
             case["ctx_tokens"] = int((case["kv_lens"] - first * case["page_size"]
                                       )[:case["num_seqs"]].sum())
         q, cache = _shapes(case)[:2]
-        chosen = pa.call_geometry(q.shape, cache.shape, case["pages_per_seq"])
+        rows = case["page_tables"].shape[0]
+        chosen = pa.step_geometry(q.shape, cache.shape, rows,
+                                  case["pages_per_seq"])
         case["floor_us"] = (case["ctx_tokens"] * case["kv_bytes_per_token"]
                             / (peak_gbs * 1e9) * 1e6)
         shape = {k: case[k] for k in (
             "cell", "program", "N", "heads", "kv_heads", "page_size",
             "pages_per_seq", "num_seqs", "ctx_tokens", "floor_us", "window")}
-        shape.update(seed=seed, rule=list(chosen), results=[])
+        shape.update(seed=seed, rule=[list(c) for c in chosen], results=[])
         print(f"\n## {cell} {case['heads']}/{case['kv_heads']} {program} "
               f"N={case['N']} seed={seed} window={window}: "
               f"{case['num_seqs']} rows, {case['ctx_tokens']} context tokens, "
-              f"{case['floor_us']:.0f} us at {peak_gbs:.0f} GB/s; rule {chosen}",
-              flush=True)
+              f"{case['floor_us']:.0f} us at {peak_gbs:.0f} GB/s; rule "
+              f"{pa.format_geometry(chosen)}", flush=True)
         operands = None if args.compile_only else _operands(case, seed)
-        first = None  # the first pair's output: every other is compared to it
-        for geometry in pairs + [chosen] * (chosen not in pairs):
-            bkv, bq = geometry
-            if bkv > case["pages_per_seq"] or bq > case["N"]:
-                continue
-            row = measure(pa, case, geometry, args.reps, operands, chip)
-            mark = " <- rule" if geometry == chosen else ""
+        # a split call's one-query rows take the fused decode call's bq
+        rows_bq = pa.call_geometry((rows, *q.shape[1:]), cache.shape,
+                                   case["pages_per_seq"])[1]
+        grid = [((bkv, bq),) if split == "0" else ((bkv, rows_bq), (bkv, bq))
+                for split in args.split.split(",") for bkv, bq in pairs
+                if bkv <= case["pages_per_seq"] and bq <= case["N"]
+                and (split == "0" or rows < case["N"])]
+        first = None  # the first row's output: every other is compared to it
+        for calls in grid + [chosen] * (chosen not in grid):
+            row = measure(pa, case, calls, args.reps, operands, chip)
+            name = pa.format_geometry(calls)
+            mark = " <- rule" if calls == chosen else ""
             if "us_per_call" in row:
                 out = row.pop("out")
                 first = out if first is None else first
                 row["max_diff"] = float(np.abs(out - first).max())
-                print(f"bkv={bkv:3d} bq={bq:3d}: {row['us_per_call']:8.1f} "
+                print(f"{name:>14}: {row['us_per_call']:8.1f} "
                       f"us/call {row['us_per_128_ctx']:6.3f} us/128tok "
                       f"{100 * row['roofline']:5.1f}% of bytes "
                       f"diff {row['max_diff']:.4f} "
@@ -360,7 +377,7 @@ def main() -> None:
                 said = row.get("error") or (
                     f"trace+lower {row['lower_s']:.2f} s, compiled in "
                     f"{row['compile_s']:.1f} s")
-                print(f"bkv={bkv:3d} bq={bq:3d}: {said}{mark}", flush=True)
+                print(f"{name:>14}: {said}{mark}", flush=True)
             shape["results"].append(row)
         if window and operands is not None:
             shape["served_vs_masked_only"] = window_diff(pa, case, operands)
