@@ -1136,8 +1136,8 @@ class LLMEngine:
 
             self.attn_backend = "pallas_mla_ragged_paged_attention"
             return functools.partial(
-                mla_paged_attention, interpret=self._pallas_interpret,
-                mesh=self.mesh)
+                mla_paged_attention, rank=self.model_cfg.mla_kv_lora_rank,
+                interpret=self._pallas_interpret, mesh=self.mesh)
         if mode == "reference":
             self.attn_backend = "xla_reference"
             return ragged_paged_attention_xla
@@ -1156,7 +1156,9 @@ class LLMEngine:
         carry the load, as ``unified=<bkv>x<bq> decode=<bkv>x<bq>``; a GQA
         unified step that hands its decode rows and its chunks to the kernel
         in two calls names both pairs, ``unified=32x8+32x64``
-        (`ops/paged_attention.step_geometry`); ``none`` where another backend
+        (`ops/paged_attention.step_geometry`); the latent kernel adds what its
+        two products see, the rows of a chunk's query block on a device and
+        the value lanes, ``rows=320 v=512``; ``none`` where another backend
         serves. It is a function of static shapes, so it is known here. A
         model with window layers adds the period of windows its layers are
         traced with (any backend), as ``window=0,4096,4096,4096``."""
@@ -1165,13 +1167,22 @@ class LLMEngine:
         programs = (("unified", self.cfg.batched_tokens),
                     ("decode", self.cfg.max_batch_size))
         if self.attn_backend.startswith("pallas_mla_ragged_paged_attention"):
-            from llmd_tpu.ops.mla_attention import pick_block_sizes
+            from llmd_tpu.ops.mla_attention import (
+                chunk_fold, pick_block_sizes, value_lanes)
 
+            def pair(n):
+                return pick_block_sizes(n, self.cfg.max_batch_size,
+                                        self.cfg.page_size,
+                                        self.cfg.max_pages_per_seq)
+
+            bq = pair(self.cfg.batched_tokens)[1]
+            heads = self.model_cfg.num_heads // (
+                self.mesh.shape["tp"] if self.mesh is not None else 1)
             return " ".join(
-                "{}={}x{}".format(prog, *pick_block_sizes(
-                    n, self.cfg.max_batch_size, self.cfg.page_size,
-                    self.cfg.max_pages_per_seq))
-                for prog, n in programs)
+                ["{}={}x{}".format(prog, *pair(n)) for prog, n in programs]
+                + [f"rows={bq * chunk_fold(bq, heads)}",
+                   "v={}".format(value_lanes(self.model_cfg.mla_kv_lora_rank,
+                                             self.cache.shape[-1]))])
         if not self.attn_backend.startswith("pallas_ragged_paged_attention"):
             return "none" + window
         from llmd_tpu.ops.paged_attention import format_geometry, step_geometry

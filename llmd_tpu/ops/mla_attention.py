@@ -14,12 +14,23 @@ kernel walks a row's keys in blocks of ``bkv`` pages.
 The grid is the batch rows; everything else is loops inside the kernel over
 what the row really holds, with q, the pool and the output left in HBM:
 
-- a row's queries go in blocks of ``bq`` tokens, all (padded) heads of a token
-  as rows of one matrix ``[bq * Hp, Dhp]`` (Hp: the heads padded to a multiple
-  of 32, so that the fold is a relabelling of tiles and not a relayout),
+- a row's queries go in blocks of ``bq`` tokens, loaded with all (padded)
+  heads of a token as rows of one matrix ``[bq * Hp, Dhp]`` (Hp: the heads
+  padded to a power of two at least 32, so that this fold is a relabelling of
+  tiles and not a relayout). Where the model's own heads fill whole tiles
+  (``bq * H`` a multiple of 16: 320 rows for 512 at 20 heads and bq 16) a
+  chunk's block is compacted to them once a query block, by a product with a
+  0/1 selection matrix (exact: one input times 1.0 plus zeros), and the output
+  block is spread back the same way: the two products of every KV block then
+  see no padded head (`chunk_fold`). A one-query row keeps the 32-row fold:
+  at one token 20 rows and 32 cost the matrix unit the same,
 - for each query block, the row's KV blocks up to its last query's position,
   fetched page by page into one of two VMEM buffers while the other is
   computed on (the page table is scalar-prefetched),
+- the score product runs over all ``Dhp`` lanes, the weighted sum over the
+  value lanes only (``V``: the latent rank rounded up to a lane tile, 512 of
+  640 at GLM's widths; the rope keys' lanes and the padding are nobody's
+  values, `value_lanes`), and the output's lanes past ``V`` are zero,
 - online softmax in float32 (m, l, acc in VMEM scratch); a block that lies
   wholly past a query multiplies its state by exactly 1 and adds exactly 0,
   so a token's result does not depend on the chunk that brought it or on the
@@ -33,6 +44,30 @@ what the row really holds, with q, the pool and the output left in HBM:
 Causality derives from ``kv_len - q_len + local index`` as in the GQA kernel:
 a row's queries are its last ``q_len`` tokens. ``-1`` page-table entries are
 clamped to page 0 for the DMA's sake; they lie past ``kv_len`` and are masked.
+
+What the two extents are worth (`tools/mla_attn_sweep.py --bkv 64`, TPU v5
+lite, PR 45; 64 rows over 1.14 M cached tokens of 16.4k-19.5k a row, 64 pages
+a KV block; us a call, before = the parent commit's file in the same chip
+call, after = this file; every row's result equal to the padded kernel's in
+the value lanes, ``diff`` 0.0; the last column is the first call's seconds, a
+call site's trace, lower and compile):
+
+    shape                          bq   before   after   first call s
+    fused decode call (64 x 1)      1    2,523   2,480   1.6 -> 1.9
+    + one 128-token chunk           8    3,876   3,430   3.5 -> 3.9
+      (63 rows decode, 256 tokens) 16*   3,702   3,236   3.8 -> 3.9
+                                   32    3,644   3,188   5.9 -> 6.0
+    + one 256-token chunk           8    5,232   4,386   4.2 -> 3.5
+      (63 rows decode, 320 tokens) 16*   4,907   4,021   4.6 -> 4.3
+                                   32    4,752   3,897   5.9 -> 5.2
+
+(the 256-token chunk's "before" is the padded kernel kept in the tool, which
+read the parent's own numbers within 0.5% at the other two shapes). A chunk's
+part of a call, the call less the decode call: 1,179 -> 757 us at 128 tokens
+and 2,384 -> 1,541 at 256 (0.64 of it stays, where the matrix work is 0.56:
+what is left beside the products is a KV block's weights latched once for
+fewer rows). The decode rows move by 2%: a one-query row waits on its 64 page
+fetches a block, not on the matrix unit.
 
 The kernel's name on the device trace is ``mla_ragged_paged_attention``: the
 benchmark's ``attn_dev_share`` reads attention by the pattern
@@ -55,6 +90,7 @@ from llmd_tpu.ops.paged_attention import VMEM_LIMIT, shard_over_heads
 NEG_INF = -0.7 * float(np.finfo(np.float32).max)
 _MINOR = 128  # lane width of the m / l scratch rows; column 0 is meaningful
 HEAD_TILE = 32  # heads are padded to a power of two at least this: whole bf16 tiles
+ROW_TILE = 16  # rows of a bf16 tile
 
 # Pages a KV block: 1,024 tokens at pages of 16. A page is one DMA of 20 KB
 # (640 lanes of bf16), a third of the GQA cells' pages, and the calls are
@@ -84,18 +120,40 @@ def pick_block_sizes(num_tokens: int, num_rows: int, page_size: int,
     return bkv, 1 if num_tokens <= num_rows else min(16, num_tokens)
 
 
+def padded_heads(heads: int) -> int:
+    """The heads a token's rows are padded to in HBM and in the query and
+    output buffers: a power of two, whole bf16 tiles."""
+    return max(HEAD_TILE, 1 << (heads - 1).bit_length())
+
+
+def chunk_fold(bq: int, heads: int) -> int:
+    """Rows a token takes in the matrix a chunk's query block hands the two
+    products: the model's heads where ``bq`` tokens of them are whole bf16
+    tiles and fewer than the padded ones, else the padded heads (a power of
+    two at least 32 keeps the load's own fold)."""
+    hp = padded_heads(heads)
+    return heads if heads < hp and (bq * heads) % ROW_TILE == 0 else hp
+
+
+def value_lanes(rank: "int | None", lanes: int) -> int:
+    """Lanes of a pool row that are values: the latent rank rounded up to a
+    lane tile (a static slice at a tile's edge), never more than the row. A
+    caller that names no rank gets the whole row."""
+    return lanes if rank is None else min(lanes, -(-rank // _MINOR) * _MINOR)
+
+
 def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
             q_hbm, pool_hbm, o_init_hbm,  # HBM
             o_hbm,  # HBM, aliased to o_init_hbm
             q_buf, kv_buf, o_buf, m_ref, l_ref, acc_ref,  # VMEM scratch
             q_sem, kv_sem, o_sem,
-            *, bq: int, bkv: int, maxp: int, scale: float):
+            *, bq: int, hq: int, bkv: int, maxp: int, scale: float):
     del o_init_hbm
     b = pl.program_id(0)
     ps = kv_buf.shape[1] // bkv
     T = bkv * ps
     Hp, Dhp = q_buf.shape[1], q_buf.shape[2]
-    shift = Hp.bit_length() - 1  # Hp is a power of two (the wrapper's pad)
+    V = o_buf.shape[2]
     q_start = cu_ref[b]
     q_len = cu_ref[b + 1] - q_start
     kv_len = kv_lens_ref[b]
@@ -107,9 +165,10 @@ def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
             kv_buf.at[slot, pl.ds(i * ps, ps)], kv_sem.at[slot])
             for i in range(bkv)]
 
-    def query_block(qb, nq: int):
-        """Query block ``qb`` of the row in blocks of ``nq`` tokens (static)."""
-        R = nq * Hp
+    def query_block(qb, nq: int, h: int):
+        """Query block ``qb`` of the row in blocks of ``nq`` tokens of ``h``
+        rows each (both static; ``h`` is Hp, or the chunks' ``hq``)."""
+        R = nq * h
         t0 = q_start + qb * nq  # the block's first row of the flat batch
         n_valid = jnp.minimum(nq, q_len - qb * nq)
         first_pos = kv_len - q_len + qb * nq
@@ -121,9 +180,20 @@ def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
             c.start()
         m_ref[pl.ds(0, R)] = jnp.full((R, _MINOR), NEG_INF, jnp.float32)
         l_ref[pl.ds(0, R)] = jnp.zeros((R, _MINOR), jnp.float32)
-        acc_ref[pl.ds(0, R)] = jnp.zeros((R, Dhp), jnp.float32)
+        acc_ref[pl.ds(0, R)] = jnp.zeros((R, V), jnp.float32)
+        # a row's token, and with it the last key it may see
+        row = lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+        tok = sum((row >= t * h).astype(jnp.int32) for t in range(1, nq))
+        last_key = first_pos + tok
         load.wait()
-        q = q_buf[pl.ds(0, nq)].reshape(R, Dhp)
+        q = q_buf[pl.ds(0, nq)].reshape(nq * Hp, Dhp)
+        if h < Hp:
+            # row r of the matrix is padded row r + tok * (Hp - h)
+            sel = (lax.broadcasted_iota(jnp.int32, (R, nq * Hp), 1)
+                   == row + tok * (Hp - h)).astype(q.dtype)
+            q = lax.dot_general(sel, q, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32
+                                ).astype(q.dtype)
 
         def kv_block(j, _):
             slot = j % 2
@@ -135,13 +205,11 @@ def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
 
             for c in fetch(j, slot):
                 c.wait()
-            kv = kv_buf[slot]  # [T, Dhp]: keys and values at once
-            s = lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+            # [T, Dhp]: keys, and in the first V lanes values
+            s = lax.dot_general(q, kv_buf[slot], (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-            tok = lax.shift_right_logical(
-                lax.broadcasted_iota(jnp.int32, (R, T), 0), shift)
             key = j * T + lax.broadcasted_iota(jnp.int32, (R, T), 1)
-            mask = key <= first_pos + tok
+            mask = key <= last_key
             s = jnp.where(mask, s, NEG_INF)
             m_prev = m_ref[pl.ds(0, R)]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -150,39 +218,43 @@ def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
             l_ref[pl.ds(0, R)] = l_ref[pl.ds(0, R)] * alpha + jnp.sum(
                 p, axis=1, keepdims=True)
             acc_ref[pl.ds(0, R)] = acc_ref[pl.ds(0, R)] * alpha[:, :1] + \
-                lax.dot_general(p.astype(kv.dtype), kv,
+                lax.dot_general(p.astype(kv_buf.dtype),
+                                kv_buf[slot, :, pl.ds(0, V)],
                                 (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
             m_ref[pl.ds(0, R)] = m_new
             return 0
 
         lax.fori_loop(0, n_kv, kv_block, 0)
-        out = acc_ref[pl.ds(0, R)] / l_ref[pl.ds(0, R)][:, :1]
-        o_buf[pl.ds(0, nq)] = out.reshape(nq, Hp, Dhp).astype(o_buf.dtype)
+        out = (acc_ref[pl.ds(0, R)] / l_ref[pl.ds(0, R)][:, :1]).astype(
+            o_buf.dtype)
+        if h < Hp:
+            # back to the padded rows; a padded head's row selects nothing
+            out = lax.dot_general(sel, out, (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32
+                                  ).astype(o_buf.dtype)
+        o_buf[pl.ds(0, nq)] = out.reshape(nq, Hp, V)
         # token by token, and only the row's own: the flat batch's next rows
-        # are another sequence's
-        for t in range(nq):
-            @pl.when(t < n_valid)
-            def _store(t=t):
-                pltpu.make_async_copy(o_buf.at[t], o_hbm.at[t0 + t],
-                                      o_sem).start()
-        for t in range(nq):
-            @pl.when(t < n_valid)
-            def _stored(t=t):
-                pltpu.make_async_copy(o_buf.at[t], o_hbm.at[t0 + t],
-                                      o_sem).wait()
+        # are another sequence's. Lanes past V keep the zeros they alias.
+        stores = [pltpu.make_async_copy(
+            o_buf.at[t], o_hbm.at[t0 + t, :, pl.ds(0, V)], o_sem)
+            for t in range(nq)]
+        for t, c in enumerate(stores):
+            pl.when(t < n_valid)(c.start)
+        for t, c in enumerate(stores):
+            pl.when(t < n_valid)(c.wait)
 
     live = (b < nseq_ref[0]) & (kv_len > 0)
 
     @pl.when(live & (q_len == 1))
     def _decode_row():
-        query_block(0, 1)
+        query_block(0, 1, Hp)
 
     if bq > 1:
         @pl.when(live & (q_len > 1))
         def _chunk():
             def body(qb, _):
-                query_block(qb, bq)
+                query_block(qb, bq, hq)
                 return 0
 
             lax.fori_loop(0, (q_len + bq - 1) // bq, body, 0)
@@ -197,23 +269,24 @@ def mla_ragged_pallas(
     num_seqs: jax.Array,  # [1]
     *,
     scale: float,
+    rank: "int | None" = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """The raw kernel call. Returns [N, H, Dhp]: the latent-weighted sums
-    (lanes past the real latent width are zero, as the stored rows' are), zero
-    for rows of the flat batch that no live sequence owns."""
+    """The raw kernel call. Returns [N, H, Dhp]: the latent-weighted sums in
+    the value lanes (`value_lanes` of ``rank``; zero past them), zero for
+    rows of the flat batch that no live sequence owns."""
     N, H, Dhp = q.shape
     P, ps, planes, _ = layer_cache.shape
     assert planes == 1, "the single-plane latent pool"
     B, maxp = page_tables.shape
     bkv, bq = pick_block_sizes(N, B, ps, maxp)
-    Hp = max(HEAD_TILE, 1 << (H - 1).bit_length())  # a power of two
+    Hp, V, hq = padded_heads(H), value_lanes(rank, Dhp), chunk_fold(bq, H)
     # heads padded to whole tiles, and bq rows past the batch's end so that a
     # query block's load stays in bounds (what it reads there is not stored)
     qp = jnp.pad(q, ((0, bq), (0, Hp - H), (0, 0)))
-    kernel = functools.partial(_kernel, bq=bq, bkv=bkv, maxp=maxp,
+    kernel = functools.partial(_kernel, bq=bq, hq=hq, bkv=bkv, maxp=maxp,
                                scale=scale)
-    rows = bq * Hp
+    rows = max(Hp, bq * hq)  # a one-query row's Hp, or a chunk's block
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     out = pl.pallas_call(
         kernel,
@@ -225,10 +298,10 @@ def mla_ragged_pallas(
             scratch_shapes=[
                 pltpu.VMEM((bq, Hp, Dhp), q.dtype),  # q block
                 pltpu.VMEM((2, bkv * ps, Dhp), layer_cache.dtype),
-                pltpu.VMEM((bq, Hp, Dhp), q.dtype),  # output block
+                pltpu.VMEM((bq, Hp, V), q.dtype),  # output block
                 pltpu.VMEM((rows, _MINOR), jnp.float32),  # m
                 pltpu.VMEM((rows, _MINOR), jnp.float32),  # l
-                pltpu.VMEM((rows, Dhp), jnp.float32),  # acc
+                pltpu.VMEM((rows, V), jnp.float32),  # acc
                 pltpu.SemaphoreType.DMA(()),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA(()),
@@ -261,6 +334,7 @@ def mla_paged_attention(
     num_seqs: jax.Array,  # [1]
     chunk_k: "jax.Array | None" = None,  # unused (ring-attn impls only)
     chunk_v: "jax.Array | None" = None,  # unused (ring-attn impls only)
+    rank: "int | None" = None,  # the model's kv_lora_rank: the value lanes
     interpret: bool = False,  # True only when the selecting platform is CPU
     mesh=None,  # engine mesh: the kernel runs per device under shard_map
 ) -> jax.Array:
@@ -272,7 +346,7 @@ def mla_paged_attention(
     if layer_cache.dtype == jnp.float8_e4m3fn:
         # fp8 latent pages are stored at scale 1.0: upcasting is the dequant
         layer_cache = layer_cache.astype(q.dtype)
-    call = functools.partial(mla_ragged_pallas, scale=scale,
+    call = functools.partial(mla_ragged_pallas, scale=scale, rank=rank,
                              interpret=interpret)
     if mesh is not None:
         # heads split over tp; the latent plane is replicated

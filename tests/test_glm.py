@@ -10,6 +10,7 @@ gather.
 
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import os
@@ -410,6 +411,106 @@ def test_a_token_does_not_depend_on_its_chunk_or_its_program(small_blocks):
     assert (d == u).all() and np.abs(np.asarray(d, np.float32)).max() > 0.1
 
 
+# 20 heads over rows of 512 value lanes + 64 rope lanes in 640, as
+# glm-4.7-flash has them: decode rows, a chunk behind a cached prefix that is
+# no multiple of bq, a chunk that starts its row, a seat nobody has (and -1
+# pages past every row's own)
+WIDE = dict(heads=20, lanes=640, real=576)
+WIDE_ROWS = ([1, 21, 0, 1, 19], [40, 58, 0, 64, 19], 48, 6)
+
+
+def test_the_real_extents_return_the_padded_products_bits(small_blocks):
+    """The parent commit's kernel is this one told of 32 heads and of no
+    rank (heads padded to whole tiles in the fold, the weighted sum over all
+    640 lanes): a matrix product's row does not depend on the rows beside
+    it, nor a value lane's sum on the lanes beside it."""
+    q_lens, kv_lens, nt, rows = WIDE_ROWS
+    args, kw, n = _ragged(jnp.bfloat16, q_lens, kv_lens, nt, rows, **WIDE)
+    assert (args[2] == -1).any() and n == 42
+    assert mla_attention.chunk_fold(16, 20) == 20
+    assert mla_attention.chunk_fold(16, 32) == 32
+    assert mla_attention.value_lanes(512, 640) == 512
+    assert mla_attention.value_lanes(None, 640) == 640
+    got = mla_attention.mla_paged_attention(
+        *args, rank=512, interpret=True, **kw)
+    padded = jnp.pad(args[0], ((0, 0), (0, 12), (0, 0)))
+    old = mla_attention.mla_paged_attention(
+        padded, *args[1:], interpret=True, **kw)[:, :20]
+    assert got.shape == old.shape == (nt, 20, 640)
+    assert (got[..., :512] == old[..., :512]).all()
+    assert not np.asarray(got[..., 512:], np.float32).any()
+    assert np.abs(np.asarray(old[:n, :, 512:576], np.float32)).max() > 0.1
+    assert np.abs(np.asarray(got[:n], np.float32)).max() > 0.1
+    assert not np.asarray(got[n:], np.float32).any()
+    want = ragged_paged_attention_xla(*args, **kw)
+    assert _worst(got[:n, :, :512].astype(jnp.float32),
+                  want[:n, :, :512].astype(jnp.float32)) < 2e-2
+
+
+def _scratch_rows(fn, *args) -> int:
+    """Rows of the kernel's accumulator in the traced call."""
+    eqn, = [e for e in jax.make_jaxpr(fn)(*args).eqns
+            if e.primitive.name == "pallas_call"]
+    acc = eqn.params["jaxpr"].invars[-4].aval  # ..., acc, three semaphores
+    return acc.shape[0]
+
+
+@pytest.mark.parametrize("heads,padded,at_bq8,at_bq16", [
+    (16, 32, 16, 16), (20, 32, 20, 20), (32, 32, 32, 32), (40, 64, 40, 40),
+    (128, 128, 128, 128),  # DeepSeek's: the load's own fold
+    (10, 32, 10, 10), (5, 32, 32, 5),  # 20 heads over tp 2 and 4
+    (4, 32, 4, 4), (1, 32, 32, 1)])  # the tiny models, whole and over tp 4
+def test_a_chunks_query_block_is_folded_by_the_rule(heads, padded, at_bq8,
+                                                    at_bq16):
+    """Rows a token: the model's heads where bq tokens of them are whole
+    bf16 tiles and fewer than the padded heads, else the padded heads."""
+    assert mla_attention.padded_heads(heads) == padded
+    assert mla_attention.chunk_fold(8, heads) == at_bq8
+    assert mla_attention.chunk_fold(16, heads) == at_bq16
+
+
+@pytest.mark.parametrize("bq", [8, 16])
+@pytest.mark.parametrize("heads", [16, 20, 32, 40])
+def test_the_folded_kernel_equals_the_xla_gather(small_blocks, monkeypatch,
+                                                 heads, bq):
+    rule = mla_attention.pick_block_sizes
+    monkeypatch.setattr(
+        mla_attention, "pick_block_sizes", lambda n, rows, ps, mp: (
+            rule(n, rows, ps, mp)[0], 1 if n <= rows else bq))
+    q_lens, kv_lens, nt, rows = RAGGED["a_batch_of_both"]
+    args, kw, n = _ragged(jnp.float32, q_lens, kv_lens, nt, rows, heads=heads)
+
+    def kernel(*a):
+        return mla_attention.mla_paged_attention(
+            *a, rank=64, interpret=True, **kw)
+
+    assert _scratch_rows(kernel, *args) == max(
+        mla_attention.padded_heads(heads),
+        bq * mla_attention.chunk_fold(bq, heads))
+    want = ragged_paged_attention_xla(*args, **kw)
+    got = kernel(*args)
+    assert _worst(got[:n], want[:n]) < 5e-6
+    assert not np.asarray(got[n:]).any()
+
+
+def test_a_tp_split_of_twenty_heads_matches_the_unsharded_call(small_blocks):
+    """Ten heads a shard: the fold reads the shard's heads (160 rows a query
+    block), and a head's rows do not depend on the heads beside them."""
+    from llmd_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    q_lens, kv_lens, nt, rows = WIDE_ROWS
+    args, kw, n = _ragged(jnp.bfloat16, q_lens, kv_lens, nt, rows, **WIDE)
+    assert mla_attention.chunk_fold(16, 10) == 10
+    want = mla_attention.mla_paged_attention(
+        *args, rank=512, interpret=True, **kw)
+    got = jax.jit(functools.partial(
+        mla_attention.mla_paged_attention, rank=512, interpret=True,
+        mesh=build_mesh(MeshConfig(tp=2)), scale=kw["scale"]))(
+            *args, cu_q_lens=kw["cu_q_lens"], num_seqs=kw["num_seqs"])
+    assert np.abs(np.asarray(want[:n], np.float32)).max() > 0.1
+    assert (got == want).all()
+
+
 def test_the_kv_block_reads_the_layout_and_never_the_token_budget():
     for n in (64, 256, 2048):
         assert mla_attention.pick_block_sizes(n, 64, 16, 1280)[0] == 64
@@ -515,7 +616,8 @@ def test_the_pallas_engine_names_the_latent_kernel_on_both_programs(served,
     eng = _engine(attn_impl="pallas")
     assert eng.attn_backend == "pallas_mla_ragged_paged_attention"
     assert eng.attn_fallback_reason is None
-    assert eng.attn_geometry == "unified=64x16 decode=64x1"
+    # four heads: a chunk's 16 tokens are 64 rows and not 512; rank 64 in 128
+    assert eng.attn_geometry == "unified=64x16 decode=64x1 rows=64 v=128"
     assert eng.generate(prompts, GREEDY) == served[1]
     assert served[0].attn_backend == "xla_mla_absorbed"
     assert served[0].attn_geometry == "none"

@@ -129,6 +129,14 @@ def test_attn_backend_provenance():
     assert eng.attn_fallback_reason is None
     assert eng.kv_pack == 1  # nothing to pack: one shared latent head
     assert eng.sp_attn_backend is None  # no mesh on this engine → no sp ring
+    assert eng.attn_geometry == "none"
+    # the latent kernel's label ties a reading to what was traced: the block
+    # pairs, the rows a chunk's query block hands the matrix unit (16 tokens
+    # of 4 heads, not of 32 padded ones) and the value lanes (rank 64 -> 128)
+    eng = _engine(attn_impl="pallas")
+    geometry = "unified=32x16 decode=32x1 rows=64 v=128"
+    assert eng.attn_geometry == geometry
+    assert f'geometry="{geometry}"' in eng.metrics.registry.expose()
 
 
 @pytest.mark.slow  # ~18s: MoE x MLA composed engine, two serving runs

@@ -381,26 +381,34 @@ def test_mla_latent_kernel_lowers_under_tp4_sharding():
     _lower_for_tpu(fn, *args)
 
 
-@pytest.mark.parametrize("n", [64, 256], ids=["decode", "unified"])
-def test_mla_latent_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip, n):
-    """The latent kernel at glm-4.7-flash's shapes (20 heads padded to 32
-    over a 640-lane single-plane pool of 7 x 28,672 pages, page tables of
-    1,280 entries for 64 rows in scalar memory, 64 pages a KV block) and both
-    step programs' token budgets goes through Mosaic and the TPU compiler
-    here: the manual page copies in dynamic loops, the scalar-prefetched
-    table's size and the VMEM of two KV buffers are what interpret mode
-    cannot refuse."""
-    from llmd_tpu.ops.mla_attention import pick_block_sizes
+@pytest.mark.parametrize("n,H", [(64, 20), (256, 20), (256, 10), (256, 5)],
+                         ids=["decode", "unified", "unified_a_shard_of_tp2",
+                              "unified_a_shard_of_tp4"])
+def test_mla_latent_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip, n,
+                                                                H):
+    """The latent kernel at glm-4.7-flash's shapes (20 heads, a chunk's
+    query block folded to 320 rows by a 0/1 product and spread back by its
+    transpose, the weighted sum over 512 of the 640 lanes of a single-plane
+    pool of 7 x 28,672 pages, page tables of 1,280 entries for 64 rows in
+    scalar memory, 64 pages a KV block) and both step programs' token
+    budgets goes through Mosaic and the TPU compiler here: the manual page
+    copies in dynamic loops, the scalar-prefetched table's size, the VMEM of
+    two KV buffers, the lane slice of the value product and of the output's
+    copies are what interpret mode cannot refuse."""
+    from llmd_tpu.ops.mla_attention import (
+        chunk_fold, pick_block_sizes, value_lanes)
 
     B, maxp, H, lanes, pages = 64, 1280, 20, 640, 7 * 28672
     assert pick_block_sizes(n, B, 16, maxp) == (64, 1 if n == B else 16)
+    assert (chunk_fold(16, H), value_lanes(512, lanes)) == (20, 512)
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def fn(q, cache, pt, lens, cu, ns):
         return mla_paged_attention(q, cache, pt, None, None, lens,
-                                   scale=1 / 16, cu_q_lens=cu, num_seqs=ns)
+                                   scale=1 / 16, cu_q_lens=cu, num_seqs=ns,
+                                   rank=512)
 
     i32 = jnp.int32
     compiled = jax.jit(fn).lower(

@@ -5,20 +5,27 @@ Times `ops.mla_attention.mla_paged_attention` as the engine calls it (a bf16
 single-plane pool ``[P, 16, 1, 640]``, page tables as wide as the model
 length) at the two shapes ``glm47flash-docs`` serves: the fused decode call
 (64 query rows, one a sequence, contexts of 16.4k-19.5k tokens) and the
-unified step (256 tokens: decode rows, then one chunk of a question behind its
-document). Every (bkv, bq) pair is one Mosaic compile and ``--reps`` calls;
-the report is microseconds a call beside the call's byte and operation floors
+unified step (decode rows, then one chunk of a question behind its document,
+of 128 and of 256 tokens). Every (bkv, bq) pair is two Mosaic compiles and
+``--reps`` calls of each: the kernel as the engine binds it (a chunk's query
+block folded to the model's 20 heads, the weighted sum over the 512 value
+lanes: ``us_a_call``) and the same kernel told of 32 heads and of no rank,
+which is the kernel as it was before PR 45 (heads padded in the fold, the
+weighted sum over all 640 lanes: ``us_padded``, kept here as the comparison);
+``diff`` is the largest difference between the two in the value lanes (must
+be 0.0). Beside them the call's byte and operation floors
 (`perfbench/kernels/mla_attention.py`: what the benchmark's roofline metrics
 divide by). ``*`` marks the rule's pair (`pick_block_sizes`).
 
-Before the sweep, three checks that need the chip: the kernel against the XLA
+Before the sweep, four checks that need the chip: the kernel against the XLA
 gather on a short mixed batch (bf16; the largest difference and the
-reference's own scale), a chunk computed whole against the same chunk in two
-calls, and a decode row through the decode call's geometry against the same
-row through the unified step's (both bit for bit: a token must not depend on
-its chunking or on the program that decoded it).
+reference's own scale), the same batch through the padded kernel, a chunk
+computed whole against the same chunk in two calls, and a decode row through
+the decode call's geometry against the same row through the unified step's
+(the last three bit for bit: a token must not depend on the rows or lanes
+beside it, on its chunking or on the program that decoded it).
 
-    python tools/mla_attn_sweep.py                  # on the chip, ~3 min
+    python tools/mla_attn_sweep.py --bkv 64         # on the chip, ~4 min
     python tools/mla_attn_sweep.py --compile-only   # here: what Mosaic takes
 """
 
@@ -91,11 +98,16 @@ def main() -> int:
     with open(os.path.join(ROOT, "perfbench", "peaks.json")) as f:
         peaks = json.load(f)["TPU v5 lite"]
 
-    def call(q, pool, b, interpret=False):
+    def call(q, pool, b, interpret=False, rank=RANK):
         pt, pos, slots, kl, cu, ns = (jnp.asarray(a) for a in b)
         return mod.mla_paged_attention(
             q, pool, pt, pos, slots, kl, scale=SCALE, cu_q_lens=cu,
-            num_seqs=ns, interpret=interpret)
+            num_seqs=ns, rank=rank, interpret=interpret)
+
+    def padded(q, pool, b, interpret=False):
+        """The kernel as it was before PR 45: 32 heads, every lane a value."""
+        return call(jnp.pad(q, ((0, 0), (0, mod.HEAD_TILE - H), (0, 0))),
+                    pool, b, interpret, rank=None)[:, :H]
 
     def geometry(bkv, bq):
         mod.pick_block_sizes = lambda n, rows, ps, mp: (
@@ -151,12 +163,21 @@ def main() -> int:
     got = call(q, pool, b, interp)
     n = int(b[4][len(q_lens)])
     print(json.dumps({"check": "kernel_vs_xla_bf16", "max_abs_diff": float(
-        jnp.abs(got[:n].astype(jnp.float32)
-                - want[:n].astype(jnp.float32)).max()),
+        jnp.abs(got[:n, :, :RANK].astype(jnp.float32)
+                - want[:n, :, :RANK].astype(jnp.float32)).max()),
         "reference_abs_mean": float(jnp.abs(want[:n].astype(
             jnp.float32)).mean()),
         "rows_no_sequence_owns_are_zero": bool(
             (got[n:] == 0).all())}), flush=True)
+    old = padded(q, pool, b, interp)
+    print(json.dumps({
+        "check": "real_extents_vs_padded",
+        "same_bits_in_the_value_lanes": bool(
+            (got[..., :RANK] == old[..., :RANK]).all()),
+        "zeros_past_them": bool((got[..., RANK:] == 0).all()),
+        "padded_rope_lanes_abs_max": float(jnp.abs(
+            old[:n, :, RANK:RANK + ROPE].astype(jnp.float32)).max())}),
+        flush=True)
     # (2) a chunk whole and in two calls; (3) a decode row by both programs
     rng2 = np.random.default_rng(1)
     one = batch(rng2, np, [40], [1000], 128, 8, sp, pages)
@@ -187,7 +208,8 @@ def main() -> int:
     # the sweep, at the cell's shapes
     ctx = [int(c) for c in rng.integers(16448, 19456, size=64)]
     shapes = {"decode": ([1] * 64, ctx, 64),
-              "unified": ([1] * 63 + [128], ctx, 256)}
+              "unified128": ([1] * 63 + [128], ctx, 256),
+              "unified256": ([1] * 63 + [256], ctx, 320)}
     for name, (q_lens, kv_lens, N) in shapes.items():
         b = batch(rng, np, q_lens, kv_lens, N, B, maxp, pages)
         q = queries(N)
@@ -203,23 +225,31 @@ def main() -> int:
         for bkv in map(int, args.bkv.split(",")):
             for bq in bqs:
                 geometry(bkv, bq)
-                f = jax.jit(functools.partial(call, b=b, interpret=interp))
                 try:
-                    t = time.time()
-                    f(q, pool).block_until_ready()
-                    first_s = time.time() - t
-                    t = time.time()
-                    for _ in range(args.reps):
-                        out = f(q, pool)
-                    out.block_until_ready()
-                    us = (time.time() - t) / args.reps * 1e6
+                    us, first_s, outs = {}, {}, {}
+                    for kind, fn in (("a_call", call), ("padded", padded)):
+                        f = jax.jit(functools.partial(fn, b=b,
+                                                      interpret=interp))
+                        t = time.time()
+                        f(q, pool).block_until_ready()
+                        first_s[kind] = round(time.time() - t, 2)
+                        t = time.time()
+                        for _ in range(args.reps):
+                            out = f(q, pool)
+                        out.block_until_ready()
+                        us[kind] = (time.time() - t) / args.reps * 1e6
+                        outs[kind] = out[..., :RANK].astype(jnp.float32)
                     mark = "*" if (bkv, bq) == rule(N, B, PS, maxp) else ""
                     print(json.dumps({
                         "shape": name, "bkv": bkv, "bq": bq, "rule": mark,
-                        "us_a_call": round(us, 1), "first_call_s": round(
-                            first_s, 2),
+                        "us_a_call": round(us["a_call"], 1),
+                        "us_padded": round(us["padded"], 1),
+                        "diff": float(jnp.abs(outs["a_call"]
+                                              - outs["padded"]).max()),
+                        "first_call_s": first_s,
                         "roofline_share": round(
-                            max(floors.values()) / us, 3)}), flush=True)
+                            max(floors.values()) / us["a_call"], 3)}),
+                        flush=True)
                 except Exception as e:  # noqa: BLE001: the compiler's words
                     print(json.dumps({"shape": name, "bkv": bkv, "bq": bq,
                                       "refused": str(e)[-400:]}), flush=True)
