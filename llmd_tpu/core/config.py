@@ -174,8 +174,3 @@ class FrameworkConfig:
                     self.plugins.append(spec)
                     by_name[spec.name] = spec
                 prof.plugins.append(ProfilePluginRef(plugin_ref="max-score-picker"))
-
-
-def load_config(path: str, known_types: Optional[set[str]] = None) -> FrameworkConfig:
-    with open(path) as f:
-        return FrameworkConfig.from_yaml(f.read(), known_types)

@@ -35,7 +35,6 @@ continuous batching on accelerator'), built XLA-first:
 from __future__ import annotations
 
 import functools
-import os
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass
@@ -47,14 +46,11 @@ import numpy as np
 
 from llmd_tpu.core.kv_events import KVEvent, block_keys_for_tokens
 from llmd_tpu.core.request import SamplingParams
+from llmd_tpu.engine.backends import resolve
 from llmd_tpu.engine.config import EngineConfig
 from llmd_tpu.engine.kv_manager import PageAllocator, Sequence
-from llmd_tpu.engine.sampling import (
-    greedy_tokens,
-    sample_tokens,
-    sample_tokens_biased,
-)
-from llmd_tpu.engine.programs import ProgramRegistry
+from llmd_tpu.engine.programs import ProgramRegistry, build_step_programs
+from llmd_tpu.engine.sampling import sample_tokens_biased
 from llmd_tpu.engine.spec import propose_ngram_draft
 from llmd_tpu.structured import (
     NEG_BIAS,
@@ -68,14 +64,11 @@ from llmd_tpu.obs.events import FlightRecorder
 from llmd_tpu.obs.metrics import Registry, register_engine_metrics
 from llmd_tpu.obs.tracing import global_tracer
 from llmd_tpu.models.transformer import (
-    forward_core,
     init_cache,
     init_params,
     init_compressed_keys,
     init_state,
     param_logical_axes,
-    ragged_paged_attention_xla,
-    unembed,
     window_first_page,
 )
 from llmd_tpu.ops.lightning_attention import BLOCK as LIGHTNING_BLOCK
@@ -554,16 +547,13 @@ class LLMEngine:
                          else model_cfg.jax_dtype)
         from llmd_tpu.ops.packed_kv import pack_factor
 
-        if engine_cfg.kv_layout not in ("auto", "padded", "packed"):
-            raise ValueError(f"unknown kv_layout={engine_cfg.kv_layout!r} "
-                             "(supported: 'auto', 'padded', 'packed')")
-        if engine_cfg.spec_mode not in ("off", "ngram"):
-            raise ValueError(f"unknown spec_mode={engine_cfg.spec_mode!r} "
-                             "(supported: 'off', 'ngram')")
-        if engine_cfg.structured_mode not in ("auto", "off"):
-            raise ValueError(
-                f"unknown structured_mode={engine_cfg.structured_mode!r} "
-                "(supported: 'auto', 'off')")
+        for name, known in (("kv_layout", ("auto", "padded", "packed")),
+                            ("spec_mode", ("off", "ngram")),
+                            ("structured_mode", ("auto", "off"))):
+            if getattr(engine_cfg, name) not in known:
+                raise ValueError(
+                    f"unknown {name}={getattr(engine_cfg, name)!r} "
+                    f"(supported: {', '.join(map(repr, known))})")
         # cumulative prefix-cache effectiveness (feeds the hit-ratio gauge)
         self._prefix_cached_total = 0
         self._prefix_prompt_total = 0
@@ -615,6 +605,7 @@ class LLMEngine:
                 model_cfg, engine_cfg.num_pages, dtype=self.kv_dtype)
 
         self._eplb = None
+        self._eplb_slots: Optional[int] = None
         if engine_cfg.eplb is not None and model_cfg.is_moe:
             self._init_eplb()
 
@@ -647,77 +638,26 @@ class LLMEngine:
                 self._lora_params = shard_pytree(
                     self._lora_params, self.mesh, lora_param_logical_axes(model_cfg))
 
-        cfg = model_cfg
-        mesh = self.mesh
-        # Pallas kernels run in interpret mode on the CPU platform only (an
-        # explicit attn_impl/moe_matmul="pallas" under tests); on a TPU the
-        # selected kernel goes through Mosaic or the engine fails
-        self._pallas_interpret = jax.default_backend() == "cpu"
-        attn = self._select_attn_impl()
-        if self.kv_pack > 1:
-            from llmd_tpu.ops.packed_kv import make_packed_attn
-
-            # the paged impls (Pallas or XLA) run against the packed pool via
-            # slot-placed queries; the ring program below stays unwrapped (it
-            # attends over chunk activations, not the pool)
-            attn = make_packed_attn(attn, model_cfg, self.kv_pack)
-            self.attn_backend += f"+packed{self.kv_pack}"
-        # the fused-decode-shaped programs take the same impl: the ragged
-        # kernels (GQA and latent) serve one-row-a-sequence calls too
-        attn_decode = attn
-        if model_cfg.has_recurrent and self.attn_backend.startswith("pallas"):
-            # a recurrent layer carries the last bit of an attention layer's
-            # result on, so a prompt's chunks are handed to the kernel cut at
-            # its KV blocks' ends (ops/paged_attention.split_rows_at_kv_blocks);
-            # the fused call's rows bring one query each and are never cut
-            attn = functools.partial(attn, split_at_kv_blocks=True)
-        # what only a model with recurrent layers hands forward_core: the
-        # selective scan (the Pallas kernel wherever the Pallas attention
-        # kernel serves, the XLA form elsewhere)
-        ssm_kw: dict = {}
-        self.ssm_backend: Optional[str] = None
-        if model_cfg.has_recurrent:
-            impl = "pallas" if self.attn_backend.startswith("pallas") else "xla"
-            if model_cfg.has_mamba:
-                from llmd_tpu.ops.selective_scan import make_selective_scan
-
-                ssm_kw["scan_impl"] = make_selective_scan(
-                    impl, interpret=self._pallas_interpret)
-                self.ssm_backend = f"{impl}_selective_scan"
-                state_dtype = model_cfg.mamba_state_dtype
-            else:
-                from llmd_tpu.ops.lightning_attention import (
-                    make_lightning_attention,
-                )
-
-                ssm_kw["lin_impl"] = make_lightning_attention(
-                    impl, interpret=self._pallas_interpret)
-                self.ssm_backend = f"{impl}_lightning_attention"
-                state_dtype = model_cfg.lightning_state_dtype
+        # which kernel serves which program (engine/backends.py), once; the
+        # labels stay on the engine for those that read them there
+        # (perfbench/engine_child.py, serve.py's start-up line, tests)
+        bk = self.backends = resolve(
+            model_cfg, engine_cfg, self.mesh, kv_pack=self.kv_pack,
+            cache_shape=self.cache.shape, eplb_slots=self._eplb_slots)
+        self.attn_backend = self.stats.attn_backend = bk.attn_backend
+        self.attn_fallback_reason = bk.attn_fallback_reason
+        self.attn_geometry = bk.attn_geometry
+        self.moe_backend = self.stats.moe_backend = bk.moe_backend
+        self.moe_fallback_reason = bk.moe_fallback_reason
+        self.moe_dispatch = self.stats.moe_dispatch = bk.moe_dispatch
+        self.moe_dispatch_fallback_reason = bk.moe_dispatch_fallback_reason
+        self.moe_gemm_geometry = bk.moe_gemm_geometry
+        self.ssm_backend = bk.ssm_backend
+        self.sp_attn_backend = self.stats.sp_attn_backend = bk.sp_attn_backend
+        if bk.ssm_backend is not None:
             self.metrics.ssm_backend_info.labels(
-                impl=self.ssm_backend, state_dtype=state_dtype,
+                impl=bk.ssm_backend, state_dtype=bk.ssm_state_dtype,
                 prefix_reuse="off").set(1)
-        if model_cfg.sparse_topk:
-            # the one-query rows of the selected page tables go to the impl
-            # the fused decode call has (never cut at KV blocks)
-            ssm_kw["query_attn_impl"] = attn_decode
-        moe_impl = self._select_moe_impl()
-        moe_dispatch_impl = self._select_moe_dispatch()
-        self.stats.attn_backend = self.attn_backend
-        self.attn_geometry = self._attn_geometry()
-        # what a window layer's page table is shifted by is rounded down to
-        # the ragged kernel's KV block; the XLA impl shifts by whole pages
-        self._window_align = 1
-        if self.attn_backend.startswith("pallas_ragged_paged_attention"):
-            from llmd_tpu.ops.paged_attention import window_align_pages
-
-            self._window_align = window_align_pages(
-                (engine_cfg.max_batch_size, model_cfg.num_heads,
-                 self.cache.shape[-1]),
-                self.cache.shape, engine_cfg.max_pages_per_seq)
-        self.stats.moe_backend = self.moe_backend
-        self.stats.moe_dispatch = self.moe_dispatch
-        self.moe_gemm_geometry, self._moe_gemm_plan = self._moe_gemm_geometry()
         if model_cfg.is_moe:
             self.metrics.moe_backend_info.labels(
                 backend=self.moe_backend, dispatch=self.moe_dispatch,
@@ -732,582 +672,34 @@ class LLMEngine:
         self.stats.kv_layout = (f"packed-{self.kv_pack}" if self.kv_pack > 1
                                 else "padded")
         use_lora = self.lora_registry is not None
-        lora_scale = engine_cfg.lora.scale if use_lora else 1.0
-        NT = self.cfg.batched_tokens
-        B = engine_cfg.max_batch_size
-        k_steps = max(1, engine_cfg.decode_steps)
-
-        def _bind(x, *axes):
-            """GSPMD sharding constraint by mesh axis names (no-op off-mesh)."""
-            if mesh is None:
-                return x
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*axes)))
-
-        def _make_unified(attn_fn):
-            def _unified(params, cache, tokens, positions, seq_slots, page_tables,
-                         kv_lens, cu_q_lens, num_seqs, lora_tok, prev_sampled,
-                         temp, top_k, top_p, key, mm_embeds=None, mm_mask=None,
-                         state_slots=None):
-                """Flat mixed batch (prefill chunks + decode tokens); returns each
-                sequence's last-row logits [B, vocab] and the token picked
-                from them [B], by the sampler every program shares
-                (``sample_tokens``: argmax alone when no row samples). The
-                logits are read only by a batch with a constrained row, whose
-                bias the host builds (``_sample_dispatch``).
-
-                The step runs one ahead of the host: a decode row whose input
-                token is still on the device packs ``-(row + 1)``, the row it
-                had in the previous step, and takes the token from that
-                step's ``prev_sampled [B]`` here (zeros after a flush: no
-                token is negative then, and the program is the same)."""
-                tokens = jnp.where(
-                    tokens < 0,
-                    prev_sampled[jnp.clip(-tokens - 1, 0, B - 1)].astype(jnp.int32),
-                    tokens)
-                # flat token dim shards over dp×sp jointly: data-parallel decode
-                # rows and sequence-parallel long prefills ride the same constraint
-                tokens = _bind(tokens, ("dp", "sp"))
-                positions = _bind(positions, ("dp", "sp"))
-                seq_slots = _bind(seq_slots, ("dp", "sp"))
-                hidden, cache, cnt, drop = forward_core(
-                    cfg, params, cache, tokens, positions, seq_slots, page_tables,
-                    kv_lens, cu_q_lens=cu_q_lens, num_seqs=num_seqs,
-                    attn_impl=attn_fn, moe_matmul_impl=moe_impl,
-                    lora_indices=lora_tok if use_lora else None,
-                    lora_scale=lora_scale,
-                    mm_embeds=mm_embeds, mm_mask=mm_mask,
-                    moe_dispatch_impl=moe_dispatch_impl,
-                    # rows are in plan order, not seat order: a model with
-                    # recurrent layers is sent each row's state slot
-                    **(dict(ssm_kw, state_slots=state_slots) if ssm_kw else {}),
-                )
-                last_rows = jnp.clip(cu_q_lens[1 : B + 1] - 1, 0, NT - 1)  # [B]
-                logits = unembed(cfg, params, hidden[last_rows])  # [B, vocab]
-                sampled = sample_tokens(logits.astype(jnp.float32), key, temp,
-                                        top_k, top_p)
-                return logits, sampled, cache, cnt, drop
-
-            return _unified
-
-        def _make_verify(attn_fn):
-            def _verify(params, cache, tokens, positions, seq_slots, page_tables,
-                        kv_lens, cu_q_lens, num_seqs, lora_tok):
-                """Speculative verify: the same flat mixed-batch packing as
-                ``_unified``, extended to return the greedy token at EVERY
-                packed position instead of only each sequence's last row —
-                prompt-lookup drafts are checked against the continuation of
-                every chunk position. The [NT, vocab] logits never leave the
-                device; the host reads only [NT] int32 argmax tokens."""
-                tokens = _bind(tokens, ("dp", "sp"))
-                positions = _bind(positions, ("dp", "sp"))
-                seq_slots = _bind(seq_slots, ("dp", "sp"))
-                hidden, cache, cnt, drop = forward_core(
-                    cfg, params, cache, tokens, positions, seq_slots, page_tables,
-                    kv_lens, cu_q_lens=cu_q_lens, num_seqs=num_seqs,
-                    attn_impl=attn_fn, moe_matmul_impl=moe_impl,
-                    lora_indices=lora_tok if use_lora else None,
-                    lora_scale=lora_scale,
-                    moe_dispatch_impl=moe_dispatch_impl,
-                )
-                greedy = greedy_tokens(unembed(cfg, params, hidden))  # [NT]
-                return greedy, cache, cnt, drop
-
-            return _verify
-
-        def _make_verify_masked(attn_fn):
-            def _verify_masked(params, cache, tokens, positions, seq_slots,
-                               page_tables, kv_lens, cu_q_lens, num_seqs,
-                               lora_tok, fsm0, gidx, bias_tab, next_tab):
-                """``_verify`` with the structured-outputs glue fused in: per
-                packed position, gather the row's grammar bias at its CURRENT
-                FSM state (advanced along the draft via ``next_tab``), apply
-                it before the greedy argmax, and return the would-be state
-                after each greedy token — so acceptance is computed against
-                grammar-legal tokens only and the host adopts the state at
-                the last accepted position instead of resyncing the automaton
-                (rejected tails roll back FSM state for free, exactly as
-                ``_spec_release_tail`` rolls back KV pages).
-
-                ``fsm0/gidx [B]`` are indexed by PACKED ROW (the verify
-                plan's order, same as ``sids``), not by slot: ``fsm0`` is the
-                state after the row's full committed history — its first
-                packed token is the last committed token, so position 0
-                masks with ``fsm0`` directly and position j>0 masks with
-                ``fsm0`` advanced through draft[0..j-1]. Slot 0 of both
-                tables is the zero no-op grammar: unconstrained rows gather
-                a zero bias and the f32 cast is monotonic, so their argmax
-                is bitwise the unmasked ``greedy_tokens`` result.
-                """
-                tokens_b = _bind(tokens, ("dp", "sp"))
-                positions_b = _bind(positions, ("dp", "sp"))
-                seq_slots_b = _bind(seq_slots, ("dp", "sp"))
-                hidden, cache, cnt, drop = forward_core(
-                    cfg, params, cache, tokens_b, positions_b, seq_slots_b,
-                    page_tables, kv_lens, cu_q_lens=cu_q_lens,
-                    num_seqs=num_seqs, attn_impl=attn_fn,
-                    moe_matmul_impl=moe_impl,
-                    lora_indices=lora_tok if use_lora else None,
-                    lora_scale=lora_scale,
-                    moe_dispatch_impl=moe_dispatch_impl,
-                )
-                logits = unembed(cfg, params, hidden).astype(jnp.float32)  # [NT, V]
-                valid = positions >= 0  # padding rows must not touch any state
-                first = jnp.concatenate(
-                    [jnp.ones((1,), bool), seq_slots[1:] != seq_slots[:-1]])
-
-                # FSM states depend only on the INPUT draft tokens, not on the
-                # argmax results, so a scalar scan over packed positions
-                # suffices: each row's running state advances through its own
-                # draft (position j masks with the state after draft[0..j-1]).
-                def advance(st, x):
-                    tok, row, is_first, ok = x
-                    cur = jnp.where(is_first, st[row],
-                                    next_tab[gidx[row], st[row], tok])
-                    st = st.at[row].set(jnp.where(ok, cur, st[row]))
-                    return st, jnp.where(ok, cur, 0)
-
-                _, cur_states = jax.lax.scan(
-                    advance, fsm0, (tokens, seq_slots, first, valid))
-                g_rows = gidx[seq_slots]  # [NT]
-                greedy = jnp.argmax(logits + bias_tab[g_rows, cur_states],
-                                    axis=-1).astype(jnp.int32)
-                fsm_next = next_tab[g_rows, cur_states, greedy]  # [NT]
-                return greedy, fsm_next, cache, cnt, drop
-
-            return _verify_masked
-
-        def _live_pos(pos, i, steps_left):
-            """The positions a fused call's step ``i`` hands the model. A row
-            that has spent its steps keeps its position in the carry and
-            computes on (its KV write lands on a position it will write
-            again); a recurrent layer's state must not take that step, and
-            forward_core leaves the slot of a row at position -1 untouched:
-            the model with recurrent layers is told so."""
-            if not cfg.has_recurrent:
-                return pos
-            return jnp.where(i < steps_left, pos, -1)
-
-        def _fused_steps(body, carry, steps_left):
-            """Run a fused call's ``body(carry, i) -> (carry, (tokens [B],
-            expert counts, drops))`` for ``max(steps_left)`` steps, at most
-            ``k_steps``: the call is as long as its longest row, which the
-            host decides (``decode_call_steps``), and the program is one
-            whatever that length. Returns the last carry, the tokens by step
-            ``[k_steps, B]`` (zeros from the first step that did not run),
-            and the counts and drops summed over the steps that ran."""
-            n_steps = jnp.minimum(jnp.max(steps_left), k_steps)
-            tok, cnt, drop = jax.eval_shape(
-                lambda c: body(c, jnp.int32(0))[1], carry)
-
-            def step(i, st):
-                carry, toks_out, cnts, drops = st
-                carry, (nxt, cnt, drop) = body(carry, i)
-                return (carry, toks_out.at[i].set(nxt), cnts + cnt,
-                        drops + drop)
-
-            return jax.lax.fori_loop(
-                0, n_steps, step,
-                (carry, jnp.zeros((k_steps,) + tok.shape, tok.dtype),
-                 jnp.zeros(cnt.shape, cnt.dtype),
-                 jnp.zeros(drop.shape, drop.dtype)))
-
-        def _decode_multi(params, cache, tokens, positions, page_tables, kv_lens,
-                          temp, top_k, top_p, key, steps_left, lora_idx):
-            """Up to k decode iterations fused on-device (``_fused_steps``):
-            feed the sampled token back each step; one host round-trip a call
-            instead of per token.
-
-            ``steps_left [B]`` caps each row device-side (0 = idle slot): rows
-            freeze once their per-row budget (max_tokens / max_model_len
-            remaining, clipped to the length the host gave the call) is spent,
-            so a fused call may safely overrun a sequence's end — required by
-            the pipelined dispatch path, where the host reads results one call
-            behind — and the call ends with its longest row.
-            """
-            tokens = _bind(tokens, "dp")
-            positions = _bind(positions, "dp")
-            page_tables = _bind(page_tables, "dp", None)
-            kv_lens = _bind(kv_lens, "dp")
-            seq_slots = jnp.arange(B, dtype=jnp.int32)
-            cu = jnp.arange(B + 1, dtype=jnp.int32)
-            ns = jnp.array([B], jnp.int32)
-
-            def body(carry, i):
-                cache, toks, pos, lens, key = carry
-                hidden, cache, cnt, drop = forward_core(
-                    cfg, params, cache, toks, _live_pos(pos, i, steps_left),
-                    seq_slots, page_tables, lens,
-                    cu_q_lens=cu, num_seqs=ns, attn_impl=attn_decode,
-                    moe_matmul_impl=moe_impl,
-                    lora_indices=lora_idx if use_lora else None,
-                    lora_scale=lora_scale,
-                    moe_dispatch_impl=moe_dispatch_impl, **ssm_kw,
-                )
-                logits = unembed(cfg, params, hidden)  # [B, vocab]
-                key, sub = jax.random.split(key)
-                nxt = sample_tokens(logits, sub, temp, top_k, top_p)
-                act = i < steps_left
-                nxt = jnp.where(act, nxt, 0)
-                pos = jnp.where(act, pos + 1, pos)
-                lens = jnp.where(act, lens + 1, lens)
-                return (cache, nxt, pos, lens, key), (nxt, cnt, drop)
-
-            (cache, last_toks, pos_out, lens_out, _), toks_out, cnts, drops = (
-                _fused_steps(body, (cache, tokens, positions, kv_lens, key),
-                             steps_left))
-            # last_toks/pos_out/lens_out: device-resident chain point for the
-            # next pipelined call — a chained dispatch reuses them instead of
-            # re-packing positions and kv lens on the host
-            return (toks_out, last_toks, pos_out, lens_out, cache, cnts, drops)
-
-        def _decode_multi_masked(params, cache, tokens, positions, page_tables,
-                                 kv_lens, temp, top_k, top_p, key, steps_left,
-                                 lora_idx, fsm_state, gidx, bias_tab, next_tab):
-            """``_decode_multi`` with the structured-outputs glue fused in:
-            per step, each row gathers its grammar's bias row at its current
-            FSM state from ``bias_tab [G, S, V]``, samples through the same
-            biased sampler the host path uses (f32 cast first — bitwise parity
-            with ``_sample_dispatch``), and advances its automaton through
-            ``next_tab [G, S, V] i32``. Slot 0 of both tables is the zero
-            no-op grammar, so unconstrained rows ride along unbiased.
-
-            The FSM state is part of the loop's carry and of the return value:
-            a chained dispatch passes the previous call's ``fsm_out`` back in,
-            keeping the automaton device-resident for the whole chain. Frozen
-            rows (``steps_left`` spent) hold their state, mirroring the
-            host-side freeze in ``StructuredState.sync``.
-            """
-            tokens = _bind(tokens, "dp")
-            positions = _bind(positions, "dp")
-            page_tables = _bind(page_tables, "dp", None)
-            kv_lens = _bind(kv_lens, "dp")
-            seq_slots = jnp.arange(B, dtype=jnp.int32)
-            cu = jnp.arange(B + 1, dtype=jnp.int32)
-            ns = jnp.array([B], jnp.int32)
-
-            def body(carry, i):
-                cache, toks, pos, lens, key, st = carry
-                hidden, cache, cnt, drop = forward_core(
-                    cfg, params, cache, toks, _live_pos(pos, i, steps_left),
-                    seq_slots, page_tables, lens,
-                    cu_q_lens=cu, num_seqs=ns, attn_impl=attn_decode,
-                    moe_matmul_impl=moe_impl,
-                    lora_indices=lora_idx if use_lora else None,
-                    lora_scale=lora_scale,
-                    moe_dispatch_impl=moe_dispatch_impl, **ssm_kw,
-                )
-                logits = unembed(cfg, params, hidden).astype(jnp.float32)
-                row_bias = bias_tab[gidx, st]  # [B, vocab]
-                key, sub = jax.random.split(key)
-                nxt = sample_tokens_biased(logits, row_bias, sub, temp, top_k,
-                                           top_p)
-                new_st = next_tab[gidx, st, nxt]  # [B]
-                act = i < steps_left
-                st = jnp.where(act, new_st, st)
-                nxt = jnp.where(act, nxt, 0)
-                pos = jnp.where(act, pos + 1, pos)
-                lens = jnp.where(act, lens + 1, lens)
-                return (cache, nxt, pos, lens, key, st), (nxt, cnt, drop)
-
-            ((cache, last_toks, pos_out, lens_out, _, fsm_out), toks_out, cnts,
-             drops) = _fused_steps(
-                body, (cache, tokens, positions, kv_lens, key, fsm_state),
-                steps_left)
-            return (toks_out, last_toks, pos_out, lens_out, fsm_out, cache,
-                    cnts, drops)
-
-        def _embed(params, cache, tokens, positions, page_tables, kv_lens,
-                   cu_q_lens, lora_idx):
-            """Prefill chunk returning the sum of valid positions' final hidden
-            states — the pooling accumulator for /v1/embeddings."""
-            tokens = _bind(tokens, ("dp", "sp"))
-            positions = _bind(positions, ("dp", "sp"))
-            seq_slots = jnp.zeros_like(tokens)
-            hidden, cache, _cnt, _drop = forward_core(
-                cfg, params, cache, tokens, positions, seq_slots, page_tables,
-                kv_lens, cu_q_lens=cu_q_lens, num_seqs=jnp.array([1], jnp.int32),
-                attn_impl=attn, moe_matmul_impl=moe_impl,
-                lora_indices=lora_idx if use_lora else None, lora_scale=lora_scale,
-                moe_dispatch_impl=moe_dispatch_impl,
-            )
-            valid = (positions >= 0).astype(jnp.float32)[:, None]
-            return jnp.sum(hidden.astype(jnp.float32) * valid, axis=0), cache
-
-        donate = dict(donate_argnums=(1,))  # cache is donated — updated in place in HBM
-        if cfg.moe_scoring == "sigmoid" or cfg.has_lightning:
-            # Every rounding the program states is made. Left free, XLA keeps
-            # a bf16 value in float32 where it fuses producer and consumer,
-            # and what it fuses follows a program's shapes: on the chip a
-            # decode row's hidden state through the fused decode call and
-            # through the unified step parted by a bf16 step inside a scanned
-            # expert layer, the next layer chose another expert, and tokens
-            # served cold and from the prefix cache parted (PR 39, seed
-            # 2147485403). With this routing only, as `combine_in_order`: the
-            # softmax models' compiled programs stay what their cells measured
-            donate["compiler_options"] = {"xla_allow_excess_precision": False}
-        # Step-program registry (engine/programs.py): every compiled program
-        # is a declarative entry. Routable entries carry an eligibility
-        # predicate + run hook (registration order = priority; step() is just
-        # `route(self).run(self)`); variants without one (masked/ring, embed)
-        # are dispatched BY a routable program. jax.jit is lazy throughout —
-        # registering costs nothing until a program's first dispatch, so
-        # spec_mode="off" engines never compile the verify programs and
-        # unconstrained serving never compiles the masked ones. The engine
-        # keeps its `self._*_fn` aliases: tests and the hot-path linter key
-        # on the `self._*_fn(...)` call spelling.
+        progs = build_step_programs(
+            model_cfg, engine_cfg, self.mesh, bk, use_lora=use_lora,
+            lora_scale=engine_cfg.lora.scale if use_lora else 1.0)
+        # the registry (engine/programs.py): registration order is routing
+        # priority, and a program without hooks is dispatched BY a routed
+        # one. The `self._*_fn` aliases stay: tests and the hot-path linter
+        # key on the `self._*_fn(...)` call spelling.
         self.programs = ProgramRegistry(
             on_dispatch=lambda name:
                 self.metrics.program_dispatches.labels(program=name).inc())
-        _register = self.programs.register
-        self._unified_fn = _register(
-            "unified", jax.jit(_make_unified(attn), **donate), attn="mixed",
-            eligible=LLMEngine._unified_eligible,
-            run=LLMEngine._run_unified_program)
-        self._verify_fn = _register(
-            "verify", jax.jit(_make_verify(attn), **donate), attn="mixed",
-            eligible=lambda eng: eng.cfg.spec_mode == "ngram",
-            run=LLMEngine._run_verify_program)
-        self._verify_masked_fn = _register(
-            "verify_masked", jax.jit(_make_verify_masked(attn), **donate),
-            attn="mixed")
-        self._decode_multi_fn = _register(
-            "decode", jax.jit(_decode_multi, **donate), attn="decode",
-            eligible=lambda eng: True,  # terminal entry: always routable
-            run=LLMEngine._run_decode_program)
-        self._decode_multi_masked_fn = _register(
-            "decode_masked", jax.jit(_decode_multi_masked, **donate),
-            attn="decode")
-        self._embed_fn = _register("embed", jax.jit(_embed, **donate),
-                                   attn="mixed")
-
-        # SP long-context prefill: a second unified program whose attention is
-        # the zig-zag ring over the sp axis (ops/ring_attention.py), engaged
-        # host-side for self-contained single-sequence prefill steps only —
-        # the regime where the S² attention term lives and context parallelism
-        # pays (SURVEY §5 long-context; compiled lazily on first eligible step)
-        self._unified_ring_fn = None
-        self.sp_attn_backend: Optional[str] = None
-        if (mesh is not None and engine_cfg.mesh.sp > 1
-                and engine_cfg.sp_ring_attention and NT % engine_cfg.mesh.sp == 0
-                and not cfg.has_window):  # the ring has no sliding window
-            # MLA composes: absorbed attention is MQA over the latent (Hk=1,
-            # G=H in the ring's grouped layout) and the latent rides the ICI
-            # ring at rank+rope width — 4-8x fewer ring bytes than GQA KV.
-            # Parity pinned by tests/test_mla.py::test_ring_prefill_parity_under_sp.
-            from llmd_tpu.ops.ring_attention import make_ring_attn_impl
-
-            # ONE layout decision, passed down — sp_flash_prefill would
-            # otherwise re-derive it independently and a future change to its
-            # degrade condition would make this provenance label lie
-            layout = "zigzag" if NT % (2 * engine_cfg.mesh.sp) == 0 else "contiguous"
-            ring = make_ring_attn_impl(mesh, axis_name="sp",
-                                       zigzag=(layout == "zigzag"))
-            self._unified_ring_fn = _register(
-                "unified_ring", jax.jit(_make_unified(ring), **donate),
-                attn="mixed")
-            self.sp_attn_backend = f"ring_{layout}(sp={engine_cfg.mesh.sp})"
-            self.stats.sp_attn_backend = self.sp_attn_backend
-
-    # ------------------------------------------------------- kernel selection
-    def _select_attn_impl(self):
-        """Pick the attention kernel by rule: the Pallas ragged-paged-attention
-        kernel on TPU, the XLA gather+mask reference on CPU. There is no
-        trial compile: a kernel the rule selected either compiles at the
-        serving shape or the engine fails at its first step. Records
-        provenance in ``attn_backend`` / ``attn_fallback_reason``."""
-        self.attn_fallback_reason: Optional[str] = None
-        mode = self.cfg.attn_impl
-        if self.model_cfg.is_mla:
-            # Absorbed MLA runs as MQA with head_dim = latent rank + rope dim
-            # (288-640 lanes) over the single-plane pool: past the GQA Pallas
-            # kernel's head sizes. On a TPU every step program (unified,
-            # verify, embed and the fused decode call alike) takes the latent
-            # kernel for ragged rows (ops/mla_attention); the XLA gather is
-            # the CPU reference and serves no step on the chip.
-            if mode == "reference" or (
-                    mode == "auto" and jax.default_backend() != "tpu"):
-                # the CPU's designed backend, not a degradation: the reason
-                # stays empty so that real fallbacks are observable
-                self.attn_backend = "xla_mla_absorbed"
-                return ragged_paged_attention_xla
-            from llmd_tpu.ops.mla_attention import mla_paged_attention
-
-            self.attn_backend = "pallas_mla_ragged_paged_attention"
-            return functools.partial(
-                mla_paged_attention, rank=self.model_cfg.mla_kv_lora_rank,
-                interpret=self._pallas_interpret, mesh=self.mesh)
-        if mode == "reference":
-            self.attn_backend = "xla_reference"
-            return ragged_paged_attention_xla
-        if mode == "auto" and jax.default_backend() != "tpu":
-            self.attn_backend = "xla_reference"
-            self.attn_fallback_reason = f"backend={jax.default_backend()} (non-TPU)"
-            return ragged_paged_attention_xla
-        from llmd_tpu.ops.paged_attention import paged_attention_tpu
-
-        self.attn_backend = "pallas_ragged_paged_attention"
-        return functools.partial(paged_attention_tpu, mesh=self.mesh)
-
-    def _attn_geometry(self) -> str:
-        """The (bkv, bq) block geometry the ragged Pallas kernel (the GQA
-        one or the latent one) is traced with in the two step programs that
-        carry the load, as ``unified=<bkv>x<bq> decode=<bkv>x<bq>``; a GQA
-        unified step that hands its decode rows and its chunks to the kernel
-        in two calls names both pairs, ``unified=32x8+32x64``
-        (`ops/paged_attention.step_geometry`); the latent kernel adds what its
-        two products see, the rows of a chunk's query block on a device and
-        the value lanes, ``rows=320 v=512``; ``none`` where another backend
-        serves. It is a function of static shapes, so it is known here. A
-        model with window layers adds the period of windows its layers are
-        traced with (any backend), as ``window=0,4096,4096,4096``."""
-        window = (" window=" + ",".join(map(str, self.model_cfg.attn_window_pattern))
-                  if self.model_cfg.has_window else "")
-        programs = (("unified", self.cfg.batched_tokens),
-                    ("decode", self.cfg.max_batch_size))
-        if self.attn_backend.startswith("pallas_mla_ragged_paged_attention"):
-            from llmd_tpu.ops.mla_attention import (
-                chunk_fold, pick_block_sizes, value_lanes)
-
-            def pair(n):
-                return pick_block_sizes(n, self.cfg.max_batch_size,
-                                        self.cfg.page_size,
-                                        self.cfg.max_pages_per_seq)
-
-            bq = pair(self.cfg.batched_tokens)[1]
-            heads = self.model_cfg.num_heads // (
-                self.mesh.shape["tp"] if self.mesh is not None else 1)
-            return " ".join(
-                ["{}={}x{}".format(prog, *pair(n)) for prog, n in programs]
-                + [f"rows={bq * chunk_fold(bq, heads)}",
-                   "v={}".format(value_lanes(self.model_cfg.mla_kv_lora_rank,
-                                             self.cache.shape[-1]))])
-        if not self.attn_backend.startswith("pallas_ragged_paged_attention"):
-            return "none" + window
-        from llmd_tpu.ops.paged_attention import format_geometry, step_geometry
-
-        # (with sparse selection a call brings one KV head's query heads; a
-        # model with recurrent layers has its unified step's rows cut at KV
-        # blocks, __init__)
-        heads = self.model_cfg.num_heads // (
-            self.model_cfg.num_kv_heads if self.model_cfg.sparse_topk else 1)
-        return " ".join(
-            prog + "=" + format_geometry(step_geometry(
-                (n, heads, self.cache.shape[-1]), self.cache.shape,
-                self.cfg.max_batch_size, self.cfg.max_pages_per_seq,
-                self.model_cfg.has_recurrent))
-            for prog, n in programs) + window
-
-    def _select_moe_impl(self):
-        """Pick the MoE expert-GEMM path by rule: Pallas grouped GEMM for
-        bf16 banks on TPU, XLA einsum on CPU and for int8 banks."""
-        self.moe_fallback_reason: Optional[str] = None
-        if not self.model_cfg.is_moe:
-            self.moe_backend = "n/a (dense model)"
-            return None
-        if self.cfg.quantize_weights == "int8":
-            # int8 expert banks run the scaled-einsum path (moe_block);
-            # the Pallas grouped GEMM is bf16-only — an EXPLICIT pallas
-            # request conflicts and must fail loudly, like every other
-            # explicit-mode contract in backend selection
-            if self.cfg.moe_matmul == "pallas":
-                raise ValueError(
-                    "moe_matmul='pallas' (grouped GEMM, bf16-only) is "
-                    "incompatible with quantize_weights='int8'")
-            self.moe_backend = "xla_einsum (int8 weights)"
-            self.moe_fallback_reason = "int8 weights (grouped GEMM is bf16-only)"
-            return None
-        mode = self.cfg.moe_matmul
-        if mode == "einsum":
-            self.moe_backend = "xla_einsum"
-            return None
-        if mode == "auto" and jax.default_backend() != "tpu":
-            self.moe_backend = "xla_einsum"
-            self.moe_fallback_reason = f"backend={jax.default_backend()} (non-TPU)"
-            return None
-        from llmd_tpu.ops.grouped_gemm import make_moe_matmul
-
-        self.moe_backend = "pallas_grouped_gemm"
-        return make_moe_matmul(interpret=self._pallas_interpret)
-
-    def _moe_gemm_geometry(self) -> tuple[str, Optional[Callable]]:
-        """(label, plan) of the ragged grouped GEMM in the unified step, both
-        functions of static shapes. The label is the kernel's grid, as
-        ``<order>x<bf of moe_wi>x<bf of moe_wo>``; ``none`` where the Pallas
-        kernel does not serve. The plan is `bank_fetch_plan` at the block rows
-        and blocks a layer of a unified step's sorted dispatch, which
-        `_moe_record` books ``moe_gemm_blocks_total`` with; None where the
-        step's [L, E] counts do not say what the plan held (a mesh's shards,
-        EPLB's replica slots, DBO's halves, the einsum dispatch)."""
-        from llmd_tpu.ops.grouped_gemm import (RGG_ORDER, bank_fetch_plan,
-                                               pick_bank_tile)
-        from llmd_tpu.ops.moe_dispatch import pick_block_size, plan_blocks
-
-        cfg = self.model_cfg
-        if self.moe_dispatch != "sorted":
-            return "none", None
-        pallas = self.moe_backend == "pallas_grouped_gemm"
-        copies = self.cfg.batched_tokens * cfg.moe_top_k
-        bc = pick_block_size(copies, cfg.moe_num_experts, pallas)
-        plan = None
-        if self.mesh is None and self._eplb is None and not cfg.moe_dbo:
-            plan = functools.partial(
-                bank_fetch_plan, bc=bc,
-                nb=plan_blocks(copies, cfg.moe_num_experts, bc))
-        if not pallas:
-            return "none", plan
-        item = jnp.dtype(cfg.jax_dtype).itemsize
-        label = "{}x{}x{}".format(
-            RGG_ORDER,
-            pick_bank_tile(cfg.hidden_size, 2 * cfg.moe_intermediate_size,
-                           bc, item),
-            pick_bank_tile(cfg.moe_intermediate_size, cfg.hidden_size, bc,
-                           item))
-        return label, plan
-
-    def _select_moe_dispatch(self):
-        """Pick the MoE routing-dispatch path (orthogonal to the expert-GEMM
-        backend above): token-sorted drop-free (ops/moe_dispatch) vs the
-        legacy capacity-einsum reference. ``EngineConfig.moe_dispatch`` =
-        auto|sorted|einsum; auto honours LLMD_MOE_DISPATCH and otherwise
-        resolves to sorted everywhere — einsum stays as the parity
-        reference and kill switch. Returns the dispatch_impl closure (or
-        None for einsum); provenance in ``moe_dispatch`` /
-        ``moe_dispatch_fallback_reason``."""
-        self.moe_dispatch_fallback_reason: Optional[str] = None
-        if not self.model_cfg.is_moe:
-            self.moe_dispatch = "n/a (dense model)"
-            return None
-        mode = self.cfg.moe_dispatch
-        if mode == "auto":
-            mode = os.environ.get("LLMD_MOE_DISPATCH", "") or "sorted"
-        if mode not in ("sorted", "einsum"):
-            raise ValueError(
-                f"moe_dispatch must be auto|sorted|einsum, got {mode!r}")
-        if mode == "einsum":
-            self.moe_dispatch = "einsum"
-            return None
-        # slot dim must divide the ep axis for the bucketed all_to_all;
-        # EPLB already rounds its slot count up (_init_eplb), so only the
-        # bare expert count can mismatch
-        ep = max(1, self.cfg.mesh.ep) if self.mesh is not None else 1
-        S = self._eplb_slots if self._eplb is not None \
-            else self.model_cfg.moe_num_experts
-        if S % ep:
-            self.moe_dispatch = "einsum"
-            self.moe_dispatch_fallback_reason = (
-                f"expert slots ({S}) do not divide the ep axis ({ep})")
-            return None
-        from llmd_tpu.ops.moe_dispatch import make_sorted_dispatch
-
-        # expert GEMMs ride the ragged Pallas kernel exactly when the
-        # einsum path would have used the grouped Pallas kernel (bf16 on
-        # TPU); CPU and int8 banks use the gathered-einsum block backend
-        use_pallas = self.moe_backend == "pallas_grouped_gemm"
-        self.moe_dispatch = "sorted"
-        return make_sorted_dispatch(self.mesh, use_pallas=use_pallas,
-                                    interpret=self._pallas_interpret)
+        routed = {
+            "unified": (LLMEngine._unified_eligible,
+                        LLMEngine._run_unified_program),
+            "verify": (lambda eng: eng.cfg.spec_mode == "ngram",
+                       LLMEngine._run_verify_program),
+            # terminal entry: always routable
+            "decode": (lambda eng: True, LLMEngine._run_decode_program)}
+        for name, fn in progs.items():
+            eligible, run = routed.get(name, (None, None))
+            self.programs.register(name, fn, eligible=eligible, run=run)
+        self._unified_fn = progs["unified"]
+        self._verify_fn = progs["verify"]
+        self._verify_masked_fn = progs["verify_masked"]
+        self._decode_multi_fn = progs["decode"]
+        self._decode_multi_masked_fn = progs["decode_masked"]
+        self._embed_fn = progs["embed"]
+        # the sp ring's (backends.py::_ring_attn_impl); None where not wired
+        self._unified_ring_fn = progs.get("unified_ring")
 
     # ----------------------------------------------------------------- EPLB
     # Wide-EP expert load balancing (reference --enable-eplb, wide-ep
@@ -1506,7 +898,7 @@ class LLMEngine:
         lengths the step already packed."""
         for kind, n in attn_kv_tokens(self.model_cfg, kv_lens, q_lens,
                                       self.cfg.page_size,
-                                      self._window_align).items():
+                                      self.backends.window_align).items():
             self.metrics.attn_kv_tokens.labels(program=program,
                                                layers=kind).inc(n)
         if self.model_cfg.sparse_topk:
@@ -1634,7 +1026,7 @@ class LLMEngine:
         path), so the two small reads add no device sync of their own.
         ``gemm_plan``: `bank_fetch_plan` at the geometry those counts were
         laid out in, where one step's counts say it (a unified step's:
-        `_moe_gemm_geometry`), for ``moe_gemm_blocks_total``."""
+        `Backends.moe_gemm_plan`), for ``moe_gemm_blocks_total``."""
         if not self.model_cfg.is_moe:
             return
         drop = np.asarray(drop)
@@ -3791,7 +3183,7 @@ class LLMEngine:
             self._eplb_record(rec["cnt"])
         if "moe_drop" in rec:
             self._moe_record(rec["moe_drop"], rec["moe_cnt"],
-                             gemm_plan=self._moe_gemm_plan)
+                             gemm_plan=self.backends.moe_gemm_plan)
         self.programs.record_complete(rec["prog"])
         if rec["sampled"] is None:
             parts.to("apply")

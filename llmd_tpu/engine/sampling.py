@@ -17,9 +17,8 @@ import jax.numpy as jnp
 def greedy_tokens(logits: jax.Array) -> jax.Array:
     """Greedy token per row, matching `sample_tokens`' temperature<=0 branch
     bitwise: argmax over float32 logits. The speculative verify program
-    (engine._make_verify) uses this on every packed position, so accepted
-    draft tokens are exactly what sequential greedy decoding would emit.
-    """
+    (programs.py `_verify`) uses this on every packed position, so accepted
+    draft tokens are exactly what sequential greedy decoding would emit."""
     return jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
 
 
@@ -98,27 +97,11 @@ def sample_tokens_biased(
     """`sample_tokens` with an additive logit bias applied ON DEVICE before
     argmax/sample — the grammar-mask / logit_bias path (llmd_tpu/structured).
     Also inlined (jit-in-jit) by the fused masked decode program
-    (engine.py `_decode_multi_masked`), which gathers each row's bias from
+    (programs.py `_decode_multi_masked`), which gathers each row's bias from
     the staged dense tables per step — same sampler, bitwise-identical
     tokens whether the bias rides a unified step or a device chain.
     After a unified step it is a program of its own, over the step's logits
-    and the host-built bias, so engines that never see a structured request
-    never compile it (the spec.py lazy-jit pattern) and the unified program
-    keeps its exact HLO: unbiased batches stay bitwise identical."""
+    and the host-built bias: engines that never see a structured request
+    never compile it, and the unified program keeps its exact HLO."""
     return _sample_core(logits + bias, key, temperature, top_k, top_p,
                         top_k_max)
-
-
-def apply_penalties(
-    logits: jax.Array,  # [B, V]
-    output_mask: jax.Array,  # [B, V] bool: token appeared in output
-    presence: jax.Array,  # [B]
-    frequency_counts: jax.Array,  # [B, V] float
-    frequency: jax.Array,  # [B]
-    repetition: jax.Array,  # [B] (1.0 = off)
-) -> jax.Array:
-    logits = logits - presence[:, None] * output_mask
-    logits = logits - frequency[:, None] * frequency_counts
-    rep = repetition[:, None]
-    penalized = jnp.where(logits > 0, logits / rep, logits * rep)
-    return jnp.where(output_mask, penalized, logits)

@@ -130,14 +130,6 @@ class KVEventSubscriberManager:
 
             loop.call_soon_threadsafe(_spawn)
 
-    def subscribe_pod(self, pod_address: str, zmq_address: str) -> None:
-        """Explicit subscription (tests / static wiring)."""
-        if pod_address in self._tasks:
-            return
-        self._tasks[pod_address] = asyncio.get_running_loop().create_task(
-            self._run_pod(pod_address, zmq_address)
-        )
-
     # ---------------------------------------------------------------- receive
     def _handle(self, topic: bytes, payload: bytes) -> None:
         # topic kv@<pod_addr>@<model> — the pod address inside the topic is

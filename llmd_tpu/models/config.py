@@ -312,10 +312,6 @@ class ModelConfig:
                     or self.moe_scoring != "softmax")
 
     @property
-    def q_per_kv(self) -> int:
-        return self.num_heads // self.num_kv_heads
-
-    @property
     def is_mla(self) -> bool:
         return self.mla_kv_lora_rank > 0
 

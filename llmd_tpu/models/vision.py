@@ -24,30 +24,12 @@ payload. Real deployments plug a decoder in front; the serving contract (bytes â
 from __future__ import annotations
 
 import hashlib
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from llmd_tpu.models.config import ModelConfig
-
-
-def vision_param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
-    """Sharding axes for the vision tower (replicated by default â€” it is tiny
-    next to the language stack; encode workers scale out, not shard)."""
-    return {
-        "v_patch": (None, "embed"),
-        "v_pos": (None, "embed"),
-        "v_norm1": ("layers", "embed"),
-        "v_qkv": ("layers", "embed", None),
-        "v_out": ("layers", "embed", "embed"),
-        "v_norm2": ("layers", "embed"),
-        "v_mlp_in": ("layers", "embed", "mlp"),
-        "v_mlp_out": ("layers", "mlp", "embed"),
-        "v_final_norm": ("embed",),
-        "v_proj": ("embed", None),
-    }
 
 
 def init_vision_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:

@@ -189,14 +189,6 @@ def kv_bytes_per_token(cfg, kv_cache_dtype: Optional[str] = None) -> int:
     return planes * heads * cfg.kv_cache_head_dim * dtype_bytes
 
 
-def decode_hbm_gb_per_token(cfg, quantize_weights: Optional[str],
-                            max_batch_size: int) -> float:
-    """Offline per-token weights traffic: one full weight pass
-    amortized over the decode batch (GB/token)."""
-    return (weight_bytes(cfg, quantize_weights) / 1e9
-            / max(1, max_batch_size))
-
-
 def moe_comm_bytes_per_token(cfg) -> int:
     """MoE dispatch/combine traffic per slot token: every layer ships each
     of the top-k routed copies of the D-wide activation to its expert and
@@ -442,13 +434,6 @@ class UtilLedger:
             return None
         _, b = self.achieved(program)
         return None if b is None else b / self.peak_bytes
-
-    def moe_comm_total(self) -> float:
-        """Cumulative MoE all-to-all bytes across all programs — the bench
-        JSON ``moe_comm_bytes`` key reads this, so the offline number and
-        the hbm_bytes fold that feeds program_mbu share one accumulator."""
-        with self._lock:
-            return sum(c[4] for c in self._cost.values())
 
     def compiles(self) -> Dict[str, int]:
         with self._lock:

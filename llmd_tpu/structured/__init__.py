@@ -19,7 +19,6 @@ flow control/admission); ``compile_grammar`` does the vocab lift engine-side.
 from __future__ import annotations
 
 import hashlib
-import json
 from typing import Any, Optional
 
 from llmd_tpu.structured.cache import (
@@ -166,12 +165,3 @@ def compile_grammar(kind: str, payload, tokenizer, vocab_size: int,
 
     return cache.get_or_compile(
         grammar_key(kind, regex, tokenizer, vocab_size), build)
-
-
-def canonical_payload(kind: str, payload) -> str:
-    """Stable textual form of a spec (flight-recorder provenance)."""
-    if kind == "json_schema":
-        return json.dumps(payload, sort_keys=True)
-    if kind == "choice":
-        return json.dumps(payload)
-    return str(payload)

@@ -12,6 +12,13 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
+# One OpenMP thread a test process. scikit-learn's gradient boosting (the
+# predictor's fits) runs on the libgomp it ships with, a thread a core, and a
+# libgomp thread spins while it waits for the next parallel region: six xdist
+# workers' pools on eight cores spin against each other (one fit of 2,000
+# rows: 10 s alone, not done after 20 min as one of six; 6 s each at one
+# thread). No program code reads the name; XLA's CPU pool is not OpenMP.
+os.environ.setdefault("OMP_NUM_THREADS", "1")
 
 import asyncio  # noqa: E402
 

@@ -430,7 +430,7 @@ def test_engine_tokens_are_what_they_were_and_blocks_are_booked(moe_matmul):
     copies = eng.cfg.batched_tokens * cfg.moe_top_k
     bc = pick_block_size(copies, cfg.moe_num_experts, pallas)
     nb = plan_blocks(copies, cfg.moe_num_experts, bc)
-    assert eng._moe_gemm_plan.keywords == {"bc": bc, "nb": nb}
+    assert eng.backends.moe_gemm_plan.keywords == {"bc": bc, "nb": nb}
     booked = {o: _count(eng, "moe_gemm_blocks_total", f'outcome="{o}"')
               for o in ("fetch", "reuse", "padding")}
     steps = _count(eng, "engine_program_dispatches_total",

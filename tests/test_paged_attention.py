@@ -277,7 +277,7 @@ def test_an_engine_whose_rows_are_cut_reports_one_pair():
 
 
 def test_engine_shifts_window_layers_by_the_block_it_reports():
-    """`_window_align` (what `attn_kv_tokens_total{layers="window"}` rounds
+    """`Backends.window_align` (what `attn_kv_tokens_total{layers="window"}` rounds
     by) is the bkv of the geometry label, at an even and at an odd number of
     query heads a KV head."""
     from dataclasses import replace
@@ -293,8 +293,8 @@ def test_engine_shifts_window_layers_by_the_block_it_reports():
             page_size=16, num_pages=pages + 8, max_model_len=16 * pages,
             max_batch_size=4, prefill_chunk=256, attn_impl="pallas"))
         assert eng.attn_geometry == geometry + " window=0,64"
-        assert eng._window_align == int(geometry.split("=")[1].split("x")[0])
-        assert eng._window_align == window_align_pages(
+        assert eng.backends.window_align == int(geometry.split("=")[1].split("x")[0])
+        assert eng.backends.window_align == window_align_pages(
             (4, heads, 128), eng.cache.shape, pages)
 
 

@@ -7,6 +7,7 @@ The oracle is the same engine read synchronously
 same rows, so greedy tokens are equal token for token; only the moment the
 host reads them differs. What differs by design is told by the counters
 (``unified_decode_rows_total{token}``, ``unified_ahead_rows_total{outcome}``).
+The section "equal to the oracle" is ``tests/test_unified_ahead_oracle.py``.
 """
 
 from __future__ import annotations
@@ -16,6 +17,7 @@ import pytest
 
 from llmd_tpu.core.request import SamplingParams
 from llmd_tpu.engine import EngineConfig, LLMEngine
+from llmd_tpu.engine.engine import STEP_PARTS
 from llmd_tpu.models import get_model_config
 from tests.test_pipeline_prefill_sample import drive
 
@@ -49,35 +51,6 @@ def _arrivals(sp, every: int = 2, prompts=PROMPTS) -> dict:
     return {i * every: [(f"r{i}", p, sp)] for i, p in enumerate(prompts)}
 
 
-# ------------------------------------------------------- equal to the oracle
-
-@pytest.mark.parametrize("kw", [
-    dict(),
-    dict(prefill_chunk=8, max_batch_size=3),       # seats fewer than requests
-    dict(prefill_chunk=32, max_num_batched_tokens=40),  # budget cuts chunks
-    dict(page_size=4, num_pages=256),              # a block commits every 4
-    dict(decode_steps=1),
-], ids=["base", "seats3", "budget40", "page4", "k1"])
-def test_greedy_mixed_traffic_equals_the_oracle_and_the_fused_path(kw):
-    sp = SamplingParams(max_tokens=12, **GREEDY)
-    eng = _engine(**kw)
-    got = drive(eng, arrivals=_arrivals(sp))
-    oracle_eng = _engine(**kw)
-    oracle = drive(oracle_eng, oracle=True, arrivals=_arrivals(sp))
-    assert got == oracle
-    assert all(len(v) == 12 for v in got.values()) and len(got) == len(PROMPTS)
-    # every request alone: one prefill, then fused decode calls only
-    for i, p in enumerate(PROMPTS):
-        solo = _engine(**kw)
-        assert solo.generate([p], sp)["req-0"] == got[f"r{i}"], i
-        assert solo.stats.n_decode_calls > 0
-    a, o = _ahead(eng), _ahead(oracle_eng)
-    assert a["device"] > 0 and a["kept"] == a["device"] and not a["discarded"]
-    assert o["device"] == 0 and o["host"] > 0  # the oracle never rides ahead
-    _assert_no_row_wasted(eng, got)
-    _assert_no_row_wasted(oracle_eng, oracle)
-
-
 def _assert_no_row_wasted(eng: LLMEngine, got: dict) -> None:
     """Every decode row of a unified step gave its request a token: the
     tokens delivered are one per request from its prefill's sample, one per
@@ -87,86 +60,6 @@ def _assert_no_row_wasted(eng: LLMEngine, got: dict) -> None:
         len(got) + a["device"] + a["host"] + eng.stats.decode_tokens_fused)
     assert (a["device"] + a["host"]
             == eng.stats.total_decode_tokens - eng.stats.decode_tokens_fused)
-
-
-def _other_engine(case: str) -> LLMEngine:
-    if case == "moe":
-        return _engine("tiny-moe")
-    if case == "moe-dp2":
-        return _engine("tiny-moe", dp_ranks=2)
-    if case == "fp8":
-        return _engine(kv_cache_dtype="fp8")
-    if case == "vl":
-        return _engine("tiny-vl")
-    from llmd_tpu.models.lora import LoRAConfig
-
-    eng = _engine(lora=LoRAConfig(max_adapters=2, rank=4))
-    eng.load_lora_adapter("a1")
-    return eng
-
-
-@pytest.mark.parametrize("case", ["moe", "moe-dp2", "fp8", "lora", "vl"])
-def test_greedy_equals_the_oracle_on_other_models(case):
-    """MoE: the step's drop count and expert counts are read with its
-    sample, a step late; two dp ranks share each unified step; rows of two
-    LoRA slots ride ahead side by side; a VL prefill row sits beside rows
-    whose token is on the device."""
-    sp = SamplingParams(max_tokens=8, **GREEDY)
-
-    def arrivals():
-        extra = [{} for _ in range(4)]
-        prompts = PROMPTS[:4]
-        if case == "moe-dp2":
-            extra = [dict(rank=i % 2) for i in range(4)]
-        elif case == "lora":
-            extra = [dict(lora_id="a1") if i % 2 else {} for i in range(4)]
-        elif case == "vl":
-            from llmd_tpu.disagg.encode import VisionRunner
-
-            cfg = get_model_config("tiny-vl")
-            vl = (list(range(10, 20)) + [cfg.mm_placeholder_id] * cfg.mm_tokens
-                  + list(range(30, 40)))
-            prompts = [PROMPTS[0], vl, PROMPTS[1], vl]
-            extra = [{}, dict(mm_items=VisionRunner(cfg).encode([b"image-A"])),
-                     {}, dict(mm_items=VisionRunner(cfg).encode([b"image-B"]))]
-        return {2 * i: [(f"r{i}", p, sp, kw)]
-                for i, (p, kw) in enumerate(zip(prompts, extra))}
-
-    eng = _other_engine(case)
-    got = drive(eng, arrivals=arrivals())
-    oracle = drive(_other_engine(case), oracle=True, arrivals=arrivals())
-    assert got == oracle and all(len(v) == 8 for v in got.values())
-    assert len(got) == 4 and _ahead(eng)["device"] > 0
-    assert _ahead(eng)["discarded"] == 0
-    assert eng.stats.moe_dropped_tokens == 0
-    if case == "lora":  # the adapter reaches the rows that ride ahead
-        assert got["r1"] != drive(_engine(), arrivals={
-            0: [("r1", PROMPTS[1], sp)]})["r1"]
-    if case == "vl":
-        assert got["r1"] != got["r3"]
-
-
-def test_equal_to_the_oracle_with_a_prefix_cache_hit_and_kv_events():
-    """Blocks are committed only over tokens the host holds: the cache's
-    content after a run ahead is the oracle's, block for block."""
-    sp = SamplingParams(max_tokens=20, **GREEDY)
-
-    def run(oracle):
-        events = []
-        eng = LLMEngine(get_model_config("tiny"), EngineConfig(**BASE),
-                        event_sink=events.extend)
-        got = drive(eng, oracle=oracle, arrivals=_arrivals(sp))
-        again = drive(eng, oracle=oracle,
-                      arrivals={0: [("again", PROMPTS[2] + got["r2"][:9], sp)]})
-        stored = sorted(h for e in events if type(e).__name__ == "BlockStored"
-                        for h in e.block_hashes)
-        return got, again, stored, eng.seqs
-
-    got, again, stored, seqs = run(False)
-    o_got, o_again, o_stored, _ = run(True)
-    assert (got, again) == (o_got, o_again)
-    assert stored == o_stored and stored
-    assert not seqs
 
 
 # ------------------------------------------------------------ ends of a row
@@ -414,19 +307,33 @@ def test_generate_quiesces_and_counts_balance():
 
 
 def test_parts_sum_to_the_step_histogram_one_step_ahead():
-    """The unified step's parts still cover its duration (2%), with the wait
-    now the read of the previous step."""
+    """The unified step's parts still cover its duration, with the wait now
+    the read of the previous step: every part of ``STEP_PARTS`` (the seven
+    this test named before ISSUE 36 left out ``stage`` and ``transfer``, 4%
+    of a drive whose first dispatch compiles and 13% of one that does not),
+    read as growth over a second drive, in which nothing compiles."""
     eng = _engine()
     sp = SamplingParams(max_tokens=16, **GREEDY)
+
+    def read() -> dict:
+        got = {p: _count(eng, "engine_step_part_seconds_total",
+                         f'program="unified",part="{p}"')
+               for p in STEP_PARTS}
+        got["hist"] = _count(eng, "engine_step_duration_seconds_sum",
+                             'phase="unified"')
+        got["ahead"] = _ahead(eng)["device"]
+        return got
+
     drive(eng, arrivals=_arrivals(sp))
-    assert _ahead(eng)["device"] > 0
-    parts = {p: _count(eng, "engine_step_part_seconds_total",
-                       f'program="unified",part="{p}"')
-             for p in ("plan", "pack", "dispatch", "apply", "sample", "wait",
-                       "book")}
-    hist = _count(eng, "engine_step_duration_seconds_sum", 'phase="unified"')
+    before = read()
+    drive(eng, arrivals=_arrivals(sp, prompts=[
+        [t + 100 for t in p] for p in PROMPTS]))  # no prefix is cached
+    parts = {k: v - before[k] for k, v in read().items()}
+    hist, ahead = parts.pop("hist"), parts.pop("ahead")
+    assert ahead > 0
     assert all(v > 0 for v in parts.values()), parts
-    assert abs(sum(parts.values()) - hist) <= 0.02 * hist, (parts, hist)
+    # the histogram's sample is the parts' own sum: equal but for rounding
+    assert abs(sum(parts.values()) - hist) <= 1e-6 * hist, (parts, hist)
     n = _count(eng, "engine_step_duration_seconds_count", 'phase="unified"')
     assert n == eng.stats.n_unified_steps
 
