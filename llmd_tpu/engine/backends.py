@@ -31,7 +31,8 @@ class Backends:
     arguments of those names, ``attn_impl`` the unified-shape programs'
     (unified, verify, embed) and ``attn_decode_impl`` the fused decode
     calls'; ``core_kwargs`` is what only a model with recurrent layers hands
-    forward_core (``scan_impl`` or ``lin_impl``, ``query_attn_impl``)."""
+    forward_core (``scan_impl``, ``ssd_impl`` or ``lin_impl``,
+    ``query_attn_impl``)."""
 
     attn_impl: Callable
     attn_decode_impl: Callable
@@ -106,6 +107,13 @@ def resolve(model_cfg: ModelConfig, engine_cfg: EngineConfig, mesh, *,
             core_kwargs["scan_impl"] = make_selective_scan(
                 impl, interpret=interpret)
             ssm_backend = f"{impl}_selective_scan"
+            ssm_state_dtype = model_cfg.mamba_state_dtype
+        elif model_cfg.has_mamba2:
+            from llmd_tpu.ops.mamba2_ssd import BLOCK, make_mamba2_ssd
+
+            core_kwargs["ssd_impl"] = make_mamba2_ssd(
+                impl, interpret=interpret)
+            ssm_backend = f"{impl}_mamba2_ssd_block{BLOCK}"
             ssm_state_dtype = model_cfg.mamba_state_dtype
         else:
             from llmd_tpu.ops.lightning_attention import (
@@ -303,22 +311,26 @@ def _moe_gemm_label_and_plan(
         return "none", None
     pallas = moe_backend == "pallas_grouped_gemm"
     copies = engine_cfg.batched_tokens * cfg.moe_top_k
-    bc = pick_block_size(copies, cfg.moe_num_experts, pallas)
+    # (the slots a layer's bank holds here: all the experts, or the share
+    # `moe_held_count` names, whose counts `moe_block` then returns by slot)
+    bc = pick_block_size(copies, cfg.moe_bank_slots, pallas)
     plan = None
     if plannable and not cfg.moe_dbo:
         plan = functools.partial(
             bank_fetch_plan, bc=bc,
-            nb=plan_blocks(copies, cfg.moe_num_experts, bc))
+            nb=plan_blocks(copies, cfg.moe_bank_slots, bc))
+    held = "" if not cfg.moe_held_count else " held={}-{}/{}".format(
+        cfg.moe_held_first, cfg.moe_held_first + cfg.moe_held_count - 1,
+        cfg.moe_num_experts)
     if not pallas:
-        return "none", plan
+        return "none" + held, plan
     item = jnp.dtype(cfg.jax_dtype).itemsize
     label = "{}x{}x{}".format(
         RGG_ORDER,
-        pick_bank_tile(cfg.hidden_size, 2 * cfg.moe_intermediate_size,
-                       bc, item),
-        pick_bank_tile(cfg.moe_intermediate_size, cfg.hidden_size, bc,
-                       item))
-    return label, plan
+        pick_bank_tile(cfg.hidden_size, (2 if cfg.moe_gated else 1)
+                       * cfg.moe_bank_width, bc, item),
+        pick_bank_tile(cfg.moe_bank_width, cfg.hidden_size, bc, item))
+    return label + held, plan
 
 
 def _moe_dispatch_impl(model_cfg: ModelConfig, engine_cfg: EngineConfig,

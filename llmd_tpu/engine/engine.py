@@ -72,6 +72,7 @@ from llmd_tpu.models.transformer import (
     window_first_page,
 )
 from llmd_tpu.ops.lightning_attention import BLOCK as LIGHTNING_BLOCK
+from llmd_tpu.ops.mamba2_ssd import BLOCK as MAMBA2_BLOCK
 from llmd_tpu.parallel.mesh import build_mesh
 
 
@@ -339,7 +340,7 @@ class LLMEngine:
                     f"batched_tokens ({engine_cfg.batched_tokens}) must be at "
                     f"least dp_ranks={R} (each rank needs a token budget)")
         if model_cfg.has_recurrent:
-            self._refuse_with_recurrent_layers(engine_cfg)
+            self._refuse_with_recurrent_layers(engine_cfg, model_cfg)
         # A cached page stands for a prefix only where every layer's state at
         # its boundary is in the pool: a recurrent layer's is not (snapshots
         # at block boundaries are not written), so such a model reuses none.
@@ -581,10 +582,15 @@ class LLMEngine:
         # the recurrent-state pool beside the KV pool: a seat owns a slot
         # (no allocator), one more is the padding rows' scratch
         self.state: dict[str, jax.Array] = {}
+        # tokens of a block of the recurrence's kernel, which a prompt's
+        # chunks start on multiples of (0: the recurrence runs token by token)
+        self._state_block = (LIGHTNING_BLOCK if model_cfg.has_lightning
+                             else MAMBA2_BLOCK if model_cfg.has_mamba2 else 0)
         if model_cfg.has_recurrent:
             self.state = init_state(model_cfg, engine_cfg.max_batch_size)
             for has, gauge in (
-                    (model_cfg.has_mamba, self.metrics.ssm_state_slots),
+                    (model_cfg.has_mamba or model_cfg.has_mamba2,
+                     self.metrics.ssm_state_slots),
                     (model_cfg.has_lightning, self.metrics.linear_state_slots)):
                 if has:
                     gauge.set_function(
@@ -981,7 +987,8 @@ class LLMEngine:
         self.cache = pools
 
     @staticmethod
-    def _refuse_with_recurrent_layers(engine_cfg: EngineConfig) -> None:
+    def _refuse_with_recurrent_layers(engine_cfg: EngineConfig,
+                                      model_cfg: ModelConfig) -> None:
         """What a model with recurrent layers cannot be combined with, each
         refused by its name: the state a seat's slot holds exists nowhere
         else, so nothing that rolls a sequence back, moves it or splits its
@@ -1006,6 +1013,18 @@ class LLMEngine:
             "mesh.tp": (engine_cfg.mesh.tp > 1,
                         "one KV head and the mixer's channels are not "
                         "sharded"),
+            # a mixture beside recurrent layers (a stack of single
+            # sublayers) runs its experts on one device, without replicas
+            "eplb": (engine_cfg.eplb is not None and model_cfg.is_moe,
+                     "the hybrid stack indexes the expert banks by layer and "
+                     "held slot; replica slots are not wired into it"),
+            "moe_dbo": (model_cfg.moe_dbo,
+                        "the two half-batches would each need the rows' "
+                        "state plan"),
+            "mesh.ep": (engine_cfg.mesh.ep > 1 and model_cfg.is_moe,
+                        "a layer's experts over real devices need their "
+                        "exchange; this engine holds a stated share of them "
+                        "(moe_held_first, moe_held_count) on one device"),
         }
         for name, (bad, reason) in why.items():
             if bad:
@@ -1033,6 +1052,8 @@ class LLMEngine:
         if drop.ndim:  # sigmoid routing: [dropped, bias_moved, routed]
             self.metrics.moe_bias_moved.inc(int(drop[1]))
             self.metrics.moe_routed_copies.inc(int(drop[2]))
+            if drop.shape[0] > 3:  # and, of a share of the experts, [held]
+                self.metrics.moe_held_copies.inc(int(drop[3]))
             drop = drop[0]
         n = int(drop)
         self.stats.moe_dropped_tokens += n
@@ -1744,11 +1765,12 @@ class LLMEngine:
                 continue  # preempted while packing decode rows
             left = self._prefill_target(s) - s.num_computed
             n = min(self.cfg.prefill_chunk, left, budgets[s.rank])
-            if self.model_cfg.has_lightning and n < left:
-                # a lightning layer groups its sums by blocks counted from a
-                # chunk's first token: every chunk but a prompt's last ends
-                # on a block's boundary, so the blocks are the prompt's own
-                n -= n % LIGHTNING_BLOCK
+            if self._state_block and n < left:
+                # a lightning or Mamba-2 layer groups its sums by blocks
+                # counted from a chunk's first token: every chunk but a
+                # prompt's last ends on a block's boundary, so the blocks
+                # are the prompt's own
+                n -= n % self._state_block
                 if (left - n == 1 and n > LIGHTNING_BLOCK
                         and self.model_cfg.sparse_topk):
                     # a token that comes alone takes the selected-table call

@@ -48,6 +48,12 @@ class ModelConfig:
     # state a head a seat (ops/lightning_attention), leaves ``lin_*``
     # stacked ``[num_lightning_layers, ...]``. A period may be as long as the
     # stack (a published list of layer types that repeats nothing).
+    # "mamba2" is a Mamba-2 mixer: heads of a matrix state [head_dim,
+    # d_state] whose decay is a gate's output, a token (ops/mamba2_ssd;
+    # leaves ``m2_*`` stacked ``[num_mamba2_layers, ...]``); "experts" is a
+    # layer that is the mixture feed-forward alone (leaves ``[num_moe_layers,
+    # ...]``). Either makes the stack one of single sublayers
+    # (``single_sublayer``, below).
     layer_kinds: tuple = ("attention",)
     mamba_d_inner: int = 0  # channels of the mixer (expand * hidden_size)
     mamba_d_state: int = 16  # SSM state a channel
@@ -57,6 +63,17 @@ class ModelConfig:
     # What the recurrent SSM state is held in between steps; the conv window
     # is held in the model's dtype.
     mamba_state_dtype: str = "float32"
+    # Mamba-2 layers: ``mamba2_heads`` heads of ``mamba2_head_dim`` channels,
+    # B and C in ``mamba2_groups`` groups of ``mamba2_d_state`` (head h reads
+    # group h // (heads / groups)), a causal depthwise conv of
+    # ``mamba2_d_conv`` taps over x, B and C alike, a gated RMSNorm a group
+    # before the output projection. The state [heads, head_dim, d_state] a
+    # seat is held in ``mamba_state_dtype``, the conv window in the model's.
+    mamba2_heads: int = 0
+    mamba2_head_dim: int = 0
+    mamba2_groups: int = 1
+    mamba2_d_state: int = 0
+    mamba2_d_conv: int = 4
     # Lightning layers: heads of ``lightning_head_dim`` lanes for q, k and v
     # alike, q/k RMSNorm a head then RoPE over all lanes, decay ``exp(-2^(-8
     # (h + 1) / H))`` a token, the output an RMSNorm a head and a sigmoid gate
@@ -99,8 +116,25 @@ class ModelConfig:
     moe_num_shared_experts: int = 0
     moe_capacity_factor: float = 1.25
     # The gate's activation in an expert: act(gate) * up. "silu" (SwiGLU) or
-    # "relu" (ReGLU).
+    # "relu" (ReGLU). With ``moe_gated`` false an expert (and the shared
+    # expert) is two products with the activation between, ``W_down act(W_up
+    # u)``, and "relu2" (the square of relu) is the one such activation.
     moe_activation: str = "silu"
+    moe_gated: bool = True
+    # The shared expert's width where it is not ``intermediate_size *
+    # moe_num_shared_experts`` (0).
+    moe_shared_intermediate_size: int = 0
+    # The experts this device holds of every mixture layer: ``moe_held_count``
+    # of them from ``moe_held_first`` on (0 = all). The router still scores
+    # all ``moe_num_experts`` and takes its top-k of them; a routed copy
+    # whose expert is not held is left out of the sum (another device's
+    # part), costs no GEMM row and no bank fetch, and the banks are stacked
+    # ``[layers, moe_held_count, ...]``.
+    moe_held_first: int = 0
+    moe_held_count: int = 0
+    # What ``router_bias`` is drawn at (transformer.ROUTER_BIAS_SCALE has
+    # the story); a family states its own where 0.1 skews its load.
+    moe_router_bias_scale: float = 0.1
     # Which normed stream the router reads: "mlp_norm" (the expert block's own
     # input) or "attn_norm" (the layer's pre-attention normed stream: logits
     # are computed before attention and carried to the expert block).
@@ -155,12 +189,23 @@ class ModelConfig:
                            tuple(bool(r) for r in self.rope_pattern))
         object.__setattr__(self, "layer_kinds",
                            tuple(str(k) for k in self.layer_kinds))
-        if set(self.layer_kinds) - {"attention", "mamba", "lightning"} or \
+        if set(self.layer_kinds) - {"attention", "mamba", "lightning",
+                                    "mamba2", "experts"} or \
                 "attention" not in self.layer_kinds:
             raise ValueError(
                 f"layer_kinds {self.layer_kinds}: a period of 'attention', "
-                "'mamba' and 'lightning' layers with at least one attention "
-                "layer")
+                "'mamba', 'lightning', 'mamba2' and 'experts' layers with at "
+                "least one attention layer")
+        if self.single_sublayer and (
+                set(self.layer_kinds) - {"attention", "mamba2", "experts"}
+                or ("experts" in self.layer_kinds) != self.is_moe
+                or self.sparse_topk or self.moe_leading_dense_layers
+                or self.moe_router_input != "mlp_norm"):
+            raise ValueError(
+                f"layer_kinds {self.layer_kinds}: 'mamba2' and 'experts' "
+                "layers stand in a stack of single sublayers beside "
+                "attention layers only, 'experts' exactly where the model "
+                "is a mixture")
         if self.has_recurrent:
             if self.num_layers % len(self.layer_kinds) or \
                     len(self.attn_window_pattern) != 1:
@@ -180,14 +225,29 @@ class ModelConfig:
                 raise ValueError(
                     "a model with lightning layers states lightning_heads "
                     "and lightning_head_dim")
+            if self.has_mamba2 and (min(
+                    self.mamba2_heads, self.mamba2_head_dim,
+                    self.mamba2_d_state, self.mamba2_d_conv - 1) < 1
+                    or self.mamba2_groups < 1
+                    or self.mamba2_heads % self.mamba2_groups):
+                raise ValueError(
+                    "a model with mamba2 layers states mamba2_heads (a "
+                    "whole number a group), mamba2_head_dim, "
+                    "mamba2_d_state and mamba2_d_conv >= 2")
             for key in ("mamba_state_dtype", "lightning_state_dtype"):
                 if getattr(self, key) not in ("float32", "bfloat16"):
                     raise ValueError(f"{key}={getattr(self, key)!r}")
-            if self.is_moe or self.is_mla or self.attn_bias:
+            if self.is_moe and not self.single_sublayer:
                 raise ValueError(
-                    "recurrent layers stand over the dense MLP and beside "
-                    "GQA attention layers only (no mixture, MLA or attention "
-                    "bias)")
+                    "a mixture beside recurrent layers is a stack of single "
+                    "sublayers with layers of kind 'experts'; a recurrent "
+                    "mixer over a mixture feed-forward in one layer is not "
+                    "served")
+            if self.is_mla or self.attn_bias:
+                raise ValueError(
+                    "recurrent layers stand beside GQA attention layers "
+                    "without bias only (MLA and an attention bias beside "
+                    "recurrent layers are not served)")
         if self.sparse_topk:
             st = self.sparse_kernel_stride
             if self.is_mla or self.has_window or any(self.rope_pattern) or \
@@ -206,8 +266,20 @@ class ModelConfig:
                 f"attn_window_pattern {self.attn_window_pattern} and "
                 f"rope_pattern {self.rope_pattern} must be one period of equal "
                 f"length that divides num_layers={self.num_layers}")
-        if self.moe_activation not in ("silu", "relu"):
-            raise ValueError(f"moe_activation={self.moe_activation!r}")
+        if self.moe_activation not in (
+                ("silu", "relu") if self.moe_gated else ("relu2",)):
+            raise ValueError(
+                f"moe_activation={self.moe_activation!r} with "
+                f"moe_gated={self.moe_gated}: gated experts take 'silu' or "
+                "'relu', non-gated ones 'relu2'")
+        if self.moe_held_count and not (
+                self.is_moe and self.moe_held_first >= 0 and
+                self.moe_held_first + self.moe_held_count
+                <= self.moe_num_experts):
+            raise ValueError(
+                f"moe_held_first={self.moe_held_first} and moe_held_count="
+                f"{self.moe_held_count}: a range of the model's "
+                f"{self.moe_num_experts} experts")
         if self.moe_router_input not in ("mlp_norm", "attn_norm"):
             raise ValueError(f"moe_router_input={self.moe_router_input!r}")
         if self.moe_scoring not in ("softmax", "sigmoid"):
@@ -242,9 +314,22 @@ class ModelConfig:
         return "lightning" in self.layer_kinds
 
     @property
+    def has_mamba2(self) -> bool:
+        return "mamba2" in self.layer_kinds
+
+    @property
+    def single_sublayer(self) -> bool:
+        """A layer is ONE sublayer (``x += Sub(RMSNorm(x; attn_norm_l))``):
+        a mixer of its kind or, kind "experts", the feed-forward; an
+        attention layer then has no feed-forward behind it and the stack no
+        ``mlp_norm``. So wherever a period holds a 'mamba2' or an 'experts'
+        layer; elsewhere every layer is its mixer and then a feed-forward."""
+        return bool({"mamba2", "experts"} & set(self.layer_kinds))
+
+    @property
     def has_recurrent(self) -> bool:
         """Some layer keeps a recurrent state per sequence."""
-        return self.has_mamba or self.has_lightning
+        return self.has_mamba or self.has_lightning or self.has_mamba2
 
     def _layers_of(self, kind: str) -> int:
         return (self.num_layers // len(self.layer_kinds)
@@ -257,6 +342,19 @@ class ModelConfig:
     @property
     def num_lightning_layers(self) -> int:
         return self._layers_of("lightning")
+
+    @property
+    def num_mamba2_layers(self) -> int:
+        return self._layers_of("mamba2")
+
+    @property
+    def mamba2_d_inner(self) -> int:
+        return self.mamba2_heads * self.mamba2_head_dim
+
+    @property
+    def mamba2_conv_dim(self) -> int:
+        """Channels the Mamba-2 conv runs over: x, B and C side by side."""
+        return self.mamba2_d_inner + 2 * self.mamba2_groups * self.mamba2_d_state
 
     @property
     def num_attn_layers(self) -> int:
@@ -302,8 +400,39 @@ class ModelConfig:
     @property
     def num_moe_layers(self) -> int:
         """Mixture layers: what the expert leaves and counts are stacked by."""
+        if self.single_sublayer:
+            return self._layers_of("experts")
         return (self.num_layers - self.moe_leading_dense_layers
                 if self.is_moe else 0)
+
+    @property
+    def moe_bank_slots(self) -> int:
+        """Expert slots a mixture layer's banks hold on this device."""
+        return self.moe_held_count or self.moe_num_experts
+
+    @property
+    def moe_bank_width(self) -> int:
+        """The F of the expert banks as stored. Non-gated experts' width is
+        rounded up to whole lane tiles of 128 with zero columns of ``moe_wi``
+        and zero rows of ``moe_wo`` (exact: the activation of 0 is 0 and
+        meets a zero row): at 1,856 the TPU compiler copied the whole stack
+        of banks, 3.7 GB, into a padded layout at every call's entry."""
+        f = self.moe_intermediate_size or self.intermediate_size
+        return f if self.moe_gated else -(-f // 128) * 128
+
+    @property
+    def mamba2_in_width(self) -> int:
+        """Columns of a Mamba-2 layer's in-projection as stored: z, the
+        conv's channels, and dt a head rounded up to a whole lane tile with
+        zero columns (10,304 -> 10,368 at the published sizes: unaligned,
+        the compiler transposed the stack at every call's entry)."""
+        return (self.mamba2_d_inner + self.mamba2_conv_dim
+                + -(-self.mamba2_heads // 128) * 128)
+
+    @property
+    def moe_shared_width(self) -> int:
+        return self.moe_shared_intermediate_size or (
+            self.intermediate_size * self.moe_num_shared_experts)
 
     @property
     def layered_init(self) -> bool:

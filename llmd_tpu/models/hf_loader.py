@@ -66,6 +66,18 @@ def config_from_hf(path: str, dtype: str = "bfloat16") -> ModelConfig:
     archs = hf.get("architectures") or []
     arch = archs[0] if archs else "LlamaForCausalLM"
     family = _ARCH_FAMILY.get(arch)
+    if arch == "NemotronHForCausalLM" or hf.get("model_type") == "nemotron_h":
+        # The program serves the family (Mamba-2, attention and expert
+        # layers of one sublayer each: ModelConfig.single_sublayer); what is
+        # refused is the mapping of a checkpoint's tensors onto its leaves,
+        # which waits until a checkpoint's tensor index is in the repository
+        # to be written against.
+        raise ValueError(
+            f"model_type nemotron_h ({arch}) in {path}: the program serves "
+            "this family from drawn weights (models/registry.py "
+            "'tiny-nemotron-h', perfbench/configs/nemotron-3-nano-30b-a3b."
+            "json), but this loader has no tensor map for nemotron_h "
+            "checkpoints yet")
     if family is None:
         raise ValueError(
             f"unsupported architecture {arch!r}; supported: {sorted(_ARCH_FAMILY)}"

@@ -15,7 +15,7 @@ scale applies to the matmul OUTPUT, a [*, out] elementwise multiply that
 fuses into the surrounding graph.
 
 Quantized: the dense per-layer projections (wq/wk/wv/wo, wi/wo_mlp; a mamba
-layer's in and out projections), the
+or mamba2 layer's in and out projections), the
 MoE expert banks and shared experts (per-expert per-output-channel scales;
 the expert GEMMs then run the einsum path — the Pallas grouped GEMM is
 bf16-only), and the unembedding. Kept bf16: norms, biases and the router
@@ -51,13 +51,15 @@ _CONTRACT: dict[str, tuple[str, ...]] = {
     "shared_wo": ("mlp",),
     "mamba_in": ("embed",),
     "mamba_out": ("mamba_inner",),
+    "m2_in": ("embed",),
+    "m2_out": ("mamba_inner",),
     # (the unembedding quantizes via its own branch below: its source can be
     # embed.T under tie_embeddings, which has no entry in the axes dict)
 }
 
 QUANTIZABLE_LAYER_KEYS = ("wq", "wk", "wv", "wo", "wi", "wo_mlp",
                           "moe_wi", "moe_wo", "shared_wi", "shared_wo",
-                          "mamba_in", "mamba_out")
+                          "mamba_in", "mamba_out", "m2_in", "m2_out")
 
 
 def _quantize_one(w: jax.Array, contract_axes: tuple[int, ...]):

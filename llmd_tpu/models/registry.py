@@ -145,6 +145,28 @@ MODEL_REGISTRY: dict[str, ModelConfig] = {
         sparse_kernel_stride=2, sparse_window=16, sparse_dense_len=64,
         embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5, logit_scale=0.25,
     ),
+    # A stack of single sublayers at CI size (Nemotron-H's layout, the period
+    # MEMEM*E twice): Mamba-2 layers (4 heads of 16 channels, 2 groups of B
+    # and C, state 16), NoPE attention layers without a feed-forward, and
+    # expert layers of 8 non-gated relu^2 experts, top-2 by sigmoid scores
+    # with a selection bias and a scaling factor, beside a shared expert of
+    # a width of its own. The Mamba-2 recurrence, the one-sublayer stack,
+    # experts beside recurrent layers and (with ``moe_held_*``) a device's
+    # share of the experts on the serving surface.
+    "tiny-nemotron-h": ModelConfig(
+        name="tiny-nemotron-h", vocab_size=288, hidden_size=128,
+        intermediate_size=96, num_layers=14, num_heads=4, num_kv_heads=2,
+        head_dim=32, rms_eps=1e-5, tie_embeddings=False,
+        rope_pattern=(False,),
+        layer_kinds=("mamba2", "experts", "mamba2", "experts", "mamba2",
+                     "attention", "experts"),
+        mamba2_heads=4, mamba2_head_dim=16, mamba2_groups=2,
+        mamba2_d_state=16, mamba2_d_conv=4,
+        moe_num_experts=8, moe_top_k=2, moe_intermediate_size=96,
+        moe_num_shared_experts=1, moe_shared_intermediate_size=160,
+        moe_gated=False, moe_activation="relu2", moe_scoring="sigmoid",
+        moe_router_bias=True, moe_routed_scaling=2.5,
+    ),
 }
 
 

@@ -42,6 +42,7 @@ import numpy as np
 from jax import lax
 
 from llmd_tpu.models.config import ModelConfig
+from llmd_tpu.ops.moe_dispatch import expert_hidden
 
 LANE = 128
 
@@ -131,6 +132,23 @@ def param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
             "lin_k_norm": ("layers", "head_dim"),
             "lin_o_norm": ("layers", "head_dim"),
         }
+    if cfg.single_sublayer:
+        # a layer is one sublayer: no ``mlp_norm``; the expert leaves are
+        # stacked by 'experts' layer, the banks over the experts held here,
+        # non-gated ones [.., D, F] where gated ones are [.., D, 2F]
+        for gone in ("mlp_norm", "wi", "wo_mlp"):
+            axes.pop(gone, None)
+    if cfg.has_mamba2:
+        axes |= {
+            "m2_in": ("layers", "embed", "mamba_inner"),
+            "m2_conv_w": ("layers", None, "mamba_inner"),
+            "m2_conv_b": ("layers", "mamba_inner"),
+            "m2_dt_bias": ("layers", None),
+            "m2_a_log": ("layers", None),
+            "m2_d": ("layers", None),
+            "m2_norm": ("layers", "mamba_inner"),
+            "m2_out": ("layers", "mamba_inner", "embed"),
+        }
     if cfg.has_mamba:
         # each kind's leaves are stacked over the layers of that kind (their
         # leading axis is still 'layers': attention leaves [num_attn_layers,
@@ -160,11 +178,15 @@ def _stack_drawer(key: jax.Array, dt, splits: int = 24):
     draw, cast, next layer), each call from the next of ``splits`` keys."""
     keys = iter(jax.random.split(key, splits))
 
-    def draw(n, shape, scale):
+    def draw(n, shape, scale, pad=None):
+        # ``pad``: zeros appended along each axis of a layer's leaf
         @jax.jit
         def stack(ks):
-            return lax.map(lambda k: (jax.random.normal(k, shape, jnp.float32)
-                                      * scale).astype(dt), ks)
+            def one(k):
+                x = (jax.random.normal(k, shape, jnp.float32)
+                     * scale).astype(dt)
+                return x if pad is None else jnp.pad(x, [(0, p) for p in pad])
+            return lax.map(one, ks)
         return stack(jax.random.split(next(keys), n))
 
     return draw, keys
@@ -374,8 +396,92 @@ def _init_lightning_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Ar
     return p
 
 
+def _init_sublayer_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
+    """Random-init params of a stack of single sublayers (``cfg.
+    single_sublayer``: Mamba-2, attention and expert layers), each stacked
+    leaf drawn a layer at a time as ``_init_hybrid_params`` does: the largest
+    float32 draw in flight is one layer's bank of held experts, [64, 2688,
+    1856] = 1.28 GB at Nemotron-3-Nano's widths beside 9.9 GB of finished
+    leaves.
+
+    ``attn_norm`` [L, D] is every layer's one pre-norm; there is no
+    ``mlp_norm``. Attention leaves [La, ...]. Expert leaves [Le, ...]:
+    ``router`` [D, E] and ``router_bias`` [E] over ALL the model's experts,
+    the banks ``moe_wi`` [Eh, D, F] / ``moe_wo`` [Eh, F, D] over the ``Eh =
+    cfg.moe_bank_slots`` experts held here (slot s is expert
+    ``cfg.moe_held_first + s``; non-gated: one up-projection, no gate half),
+    their F rounded up to whole lane tiles with zero columns and rows
+    (``cfg.moe_bank_width``), the shared expert ``shared_wi`` [D, Fs] /
+    ``shared_wo`` [Fs, D]. Mamba-2 leaves [Lm, ...]: ``m2_in`` [D, Di + C +
+    H, zero columns up to ``cfg.mamba2_in_width``] (gate z, then the conv's
+    channels x | B | C, then dt a head: the published in-projection's
+    order), ``m2_conv_w`` [K, C] (tap k multiplies the row K-1-k tokens
+    back), ``m2_conv_b`` [C], ``m2_dt_bias`` / ``m2_a_log`` / ``m2_d`` [H],
+    ``m2_norm`` [Di] (the gated norm's weight, a group's lanes side by side),
+    ``m2_out`` [Di, D]. The draw follows Mamba-2's own initialisation where
+    the recurrence feels it: A = -(uniform in 1..16) a head, dt's bias the
+    inverse softplus of a step log-uniform in [1e-3, 1e-1]; D and the norm's
+    weight are drawn around 1 so that a program that dropped one, or put the
+    gate on the wrong side of the norm, does not read as a sound one."""
+    dt = cfg.jax_dtype
+    L, La, Lm, Le = (cfg.num_layers, cfg.num_attn_layers,
+                     cfg.num_mamba2_layers, cfg.num_moe_layers)
+    D, H, Hk, Dh = cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    Di, C, Hm, K = (cfg.mamba2_d_inner, cfg.mamba2_conv_dim, cfg.mamba2_heads,
+                    cfg.mamba2_d_conv)
+    norm, keys = _stack_drawer(key, dt, 24)
+    s = D ** -0.5
+    assert not (cfg.qk_norm or cfg.attn_bias or cfg.attn_output_gate), cfg
+
+    def around_one(n, width):
+        return (1.0 + 0.1 * jax.random.normal(
+            next(keys), (n, width), jnp.float32)).astype(dt)
+
+    p: dict[str, jax.Array] = {
+        "embed": norm(1, (cfg.vocab_size, D), 0.02)[0],
+        "final_norm": jnp.ones((D,), dt),
+        "attn_norm": jnp.ones((L, D), dt),
+        "wq": norm(La, (D, H, Dh), s),
+        "wk": norm(La, (D, Hk, Dh), s),
+        "wv": norm(La, (D, Hk, Dh), s),
+        "wo": norm(La, (H, Dh, D), (H * Dh) ** -0.5),
+        "m2_in": norm(Lm, (D, Di + C + Hm), s,
+                      pad=(0, cfg.mamba2_in_width - (Di + C + Hm))),
+        "m2_conv_w": norm(Lm, (K, C), K ** -0.5),
+        "m2_conv_b": norm(Lm, (C,), 0.2),
+        "m2_d": around_one(Lm, Hm),
+        "m2_norm": around_one(Lm, Di),
+        "m2_out": norm(Lm, (Di, D), Di ** -0.5),
+    }
+    step = jnp.exp(jax.random.uniform(
+        next(keys), (Lm, Hm), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    p["m2_dt_bias"] = (step + jnp.log(-jnp.expm1(-step))).astype(dt)
+    p["m2_a_log"] = jnp.log(jax.random.uniform(
+        next(keys), (Lm, Hm), jnp.float32, 1.0, 16.0)).astype(dt)
+    if cfg.is_moe:
+        E, Eh = cfg.moe_num_experts, cfg.moe_bank_slots
+        Fe = cfg.moe_intermediate_size or cfg.intermediate_size
+        up = (2 if cfg.moe_gated else 1)
+        p["router"] = norm(Le, (D, E), s)
+        if cfg.moe_router_bias:
+            p["router_bias"] = (jax.random.normal(
+                next(keys), (Le, E), jnp.float32) * cfg.moe_router_bias_scale)
+        more = cfg.moe_bank_width - Fe  # zero columns / rows (non-gated)
+        p["moe_wi"] = norm(Le, (Eh, D, up * Fe), s, pad=(0, 0, more))
+        p["moe_wo"] = norm(Le, (Eh, Fe, D), Fe ** -0.5, pad=(0, more, 0))
+        if cfg.moe_num_shared_experts:
+            Fs = cfg.moe_shared_width
+            p["shared_wi"] = norm(Le, (D, up * Fs), s)
+            p["shared_wo"] = norm(Le, (Fs, D), Fs ** -0.5)
+    if not cfg.tie_embeddings:
+        p["unembed"] = norm(1, (D, cfg.vocab_size), s)[0]
+    return p
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
     """Random-init params (scaled normal); shapes match param_logical_axes."""
+    if cfg.single_sublayer:
+        return _init_sublayer_params(cfg, key)
     if cfg.has_lightning:
         assert not cfg.has_mamba, "lightning and mamba layers in one stack"
         return _init_lightning_params(cfg, key)
@@ -480,7 +586,13 @@ def swiglu(x: jax.Array, wi: jax.Array, wo: jax.Array, mm=None) -> jax.Array:
     return mm("wo_mlp", "...f,fd->...d", jax.nn.silu(gate) * up)
 
 
-MOE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+def relu2(x: jax.Array) -> jax.Array:
+    """The square of relu: the one activation of non-gated experts."""
+    r = jax.nn.relu(x)
+    return r * r
+
+
+MOE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu, "relu2": relu2}
 
 
 def router_logits(h: jax.Array, router: jax.Array) -> jax.Array:
@@ -504,6 +616,7 @@ def moe_block(
     logits: Optional[jax.Array] = None,
     slot_offset: Optional[jax.Array] = None,
     router_bias: Optional[jax.Array] = None,
+    gather_rows: bool = False,
 ):
     """Top-k routed MoE with capacity-based dispatch (XLA-friendly static shapes).
 
@@ -551,10 +664,25 @@ def moe_block(
     whose expert the bias changed (``top_k(s + b)`` against ``top_k(s)``) and
     all routed copies, of live tokens, for
     ``llmd_tpu:moe_bias_moved_choices_total`` / ``moe_routed_copies_total``.
+
+    ``cfg.moe_gated`` false: an expert is two products with the activation
+    between (``wi`` [S, D, F]), and the dispatch is told so. ``gather_rows``:
+    the sorted dispatch gathers its buffer and scatters no row
+    (ops/moe_dispatch.dispatch_stage: the same buffer, and what the scatter
+    did on the chip); the hybrid stack's expert layers ask for it.
+    ``cfg.moe_held_count``: the banks hold experts ``moe_held_first ..
+    moe_held_first + moe_held_count - 1`` only (a device's share of the
+    layer). The router scores and chooses over all E; a routed copy whose
+    expert is not held is marked invalid before dispatch (no GEMM row, no
+    bank fetch) and the slot index is the expert's less ``moe_held_first``.
+    The counts returned are then by held slot [Eh] and the third result
+    gains a fourth entry, the held copies of live tokens
+    (``llmd_tpu:moe_held_copies_total``).
     """
     T, D = x.shape
     E, k = cfg.moe_num_experts, cfg.moe_top_k
     act = MOE_ACTIVATIONS[cfg.moe_activation]
+    held = cfg.moe_held_count > 0
 
     if logits is None:
         logits = router_logits(x, router)
@@ -583,6 +711,13 @@ def moe_block(
         chosen = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.int32), axis=1)
         unmoved = jnp.sum(jax.nn.one_hot(plain, E, dtype=jnp.int32), axis=1)
         bias_moved = jnp.sum(chosen * (1 - unmoved) * valid)
+    if held:
+        assert eplb is None and sigmoid, "a share of the experts: no EPLB"
+        routed = jnp.sum(counts)
+        first, E = cfg.moe_held_first, cfg.moe_held_count
+        valid = valid * ((topi >= first) & (topi < first + E)).astype(jnp.int32)
+        topi = jnp.clip(topi - first, 0, E - 1)  # [T, k] held slot
+        counts = counts[first:first + E]
 
     if eplb is not None:
         replica_slots, replica_counts = eplb  # [E, R], [E]
@@ -604,6 +739,10 @@ def moe_block(
         # step programs stay the StableHLO they were (ROADMAP: move them over
         # in a change of their own, with their cells measured)
         stacked["ordered_combine"] = True
+    if not cfg.moe_gated:
+        stacked["gated"] = False
+    if gather_rows:
+        stacked["gather_rows"] = True
     if dispatch_impl is not None:
         def half(x, idx, topw, valid):
             y = dispatch_impl(x, idx, topw, valid, wi, wo, wi_scale, wo_scale,
@@ -631,16 +770,16 @@ def moe_block(
         if matmul_impl is not None and wi_scale is None:
             slot_counts = jnp.sum(disp2, axis=(0, 2)).astype(jnp.int32)  # [S]
             gate_up = matmul_impl(xe, wi, slot_counts)
-            gate, up = jnp.split(gate_up, 2, axis=-1)
-            ye = matmul_impl(act(gate) * up, wo, slot_counts)
+            ye = matmul_impl(expert_hidden(gate_up, act, cfg.moe_gated), wo,
+                             slot_counts)
         else:
             # int8 expert banks: per-expert per-output-channel scales commute
             # out of the dot (see models/quant.py) — [S, 2F] / [S, D]
             gate_up = jnp.einsum("ecd,edf->ecf", xe, wi.astype(x.dtype))
             if wi_scale is not None:
                 gate_up = gate_up * wi_scale[:, None, :].astype(x.dtype)
-            gate, up = jnp.split(gate_up, 2, axis=-1)
-            ye = jnp.einsum("ecf,efd->ecd", act(gate) * up,
+            ye = jnp.einsum("ecf,efd->ecd",
+                            expert_hidden(gate_up, act, cfg.moe_gated),
                             wo.astype(x.dtype))
             if wo_scale is not None:
                 ye = ye * wo_scale[:, None, :].astype(x.dtype)
@@ -665,7 +804,9 @@ def moe_block(
     else:
         dropped = jnp.sum(counts) - kept  # routed minus kept == capacity drops
     if sigmoid:
-        dropped = jnp.stack([dropped, bias_moved, jnp.sum(counts)])
+        dropped = jnp.stack([dropped, bias_moved, routed, jnp.sum(counts)]
+                            if held else
+                            [dropped, bias_moved, jnp.sum(counts)])
     return y, counts, dropped
 
 
@@ -724,6 +865,18 @@ def init_state(cfg: ModelConfig, seats: int) -> dict[str, jax.Array]:
             jnp.dtype(cfg.mamba_state_dtype))
         state["conv"] = jnp.zeros(
             (Lm, cfg.mamba_d_conv - 1, S, cfg.mamba_d_inner), cfg.jax_dtype)
+    if cfg.has_mamba2:
+        # the pools of a Mamba-1 model under their names, a head's matrix
+        # state [head_dim, d_state] held transposed and a group's heads side
+        # by side: ``ssm`` [Lm2, seats + 1, groups, d_state, heads / groups
+        # * head_dim] (ops/mamba2_ssd has the reason), ``conv`` over x, B
+        # and C alike
+        Lm, G = cfg.num_mamba2_layers, cfg.mamba2_groups
+        state["ssm"] = jnp.zeros(
+            (Lm, S, G, cfg.mamba2_d_state, cfg.mamba2_d_inner // G),
+            jnp.dtype(cfg.mamba_state_dtype))
+        state["conv"] = jnp.zeros(
+            (Lm, cfg.mamba2_d_conv - 1, S, cfg.mamba2_conv_dim), cfg.jax_dtype)
     if cfg.has_lightning:
         state["lin"] = jnp.zeros(
             (cfg.num_lightning_layers, S, cfg.lightning_heads,
@@ -1166,6 +1319,69 @@ def _head_norm(y: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return y * lax.rsqrt(_head_mean_sq(y) + eps) * w.astype(jnp.float32)
 
 
+def mamba2_vectors(cfg: ModelConfig, params: dict) -> dict:
+    """The Mamba-2 layers' vectors, float32 and side by side, as
+    ``mamba2_mixer`` takes them: ``m2_vec`` [Lm, K + 1, C] (conv taps, conv
+    bias) and ``m2_heads`` [Lm, 3, H] (dt bias, A = -exp(A_log), D)."""
+    f32 = lambda k: params[k].astype(jnp.float32)  # noqa: E731
+    return {"m2_vec": jnp.concatenate(
+                [f32("m2_conv_w"), f32("m2_conv_b")[:, None]], axis=1),
+            "m2_heads": jnp.stack(
+                [f32("m2_dt_bias"), -jnp.exp(f32("m2_a_log")), f32("m2_d")],
+                axis=1)}
+
+
+def mamba2_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
+                 ssm: jax.Array, o, plan: dict, row_slots,
+                 cu_q_lens: jax.Array, live: jax.Array, fresh: jax.Array,
+                 ssd_impl, mm):
+    """Mamba-2 layer ``o``'s mixer (``o`` traced: the layer's ordinal among
+    the Mamba-2 layers) on the normed rows ``h`` [N, D] of a flat mixed
+    batch; returns (out [N, D], conv pool, ssm pool).
+
+    ``conv`` [Lm, K - 1, S, C] is the conv window's pool over the C = Di + 2
+    G Nst channels of x, B and C (``conv_window``, with the call's ``plan``);
+    ``ssm`` [Lm * S, G, Nst, Di / G] the state pool with the layer folded into
+    the slot axis, as the kernel indexes it. ``row_slots``, ``live`` and
+    ``fresh`` as for ``mamba_mixer``.
+
+    One in-projection gives the gate z, the conv's rows and dt a head. The
+    conv and silu are float32 and their result is rounded to the model's
+    type (x, B and C go to the matrix unit as they are); softplus, the
+    recurrence (``ssd_impl``, ops/mamba2_ssd), the skip, the gate and the
+    norm are float32. The gate comes before the norm, and the norm runs over
+    each group's Di / G lanes, its sum of squares a matrix product
+    (``_head_mean_sq``: a recurrent state stands behind it). ``lp`` holds
+    the layer's matrices and norm weight as stored and its vectors as
+    ``mamba2_vectors`` packs them."""
+    B, N = live.shape[0], h.shape[0]
+    Di, C, K = cfg.mamba2_d_inner, cfg.mamba2_conv_dim, cfg.mamba2_d_conv
+    H, G, Nst = cfg.mamba2_heads, cfg.mamba2_groups, cfg.mamba2_d_state
+    dt_ = cfg.jax_dtype
+    zxd = mm("m2_in", "nd,de->ne", h)
+    z, xr, dtr = zxd[:, :Di], zxd[:, Di:Di + C], zxd[:, Di + C:Di + C + H]
+    vec = lp["m2_vec"]  # [K + 1, C] float32: conv taps, conv bias
+    taps, conv = conv_window(conv, o, xr, plan)
+    acc = vec[K]
+    for k in range(K):
+        acc = acc + vec[k] * taps[k].astype(jnp.float32)
+    xbc = jax.nn.silu(acc).astype(dt_)  # [N, C]
+    x = xbc[:, :Di].reshape(N, H, Di // H)
+    Bm = xbc[:, Di:Di + G * Nst].reshape(N, G, Nst)
+    Cm = xbc[:, Di + G * Nst:].reshape(N, G, Nst)
+    hv = lp["m2_heads"]  # [3, H] float32: dt bias, A, D
+    delta = jax.nn.softplus(dtr.astype(jnp.float32) + hv[0])
+    slots = o * conv.shape[2] + (
+        jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
+    y, ssm = ssd_impl(x, delta, hv[1], Bm, Cm, ssm, slots, cu_q_lens, live,
+                      fresh)
+    y = y + hv[2][None, :, None] * x.astype(jnp.float32)
+    y = y.reshape(N, Di) * jax.nn.silu(z.astype(jnp.float32))
+    y = _head_norm(y.reshape(N, G, Di // G),
+                   lp["m2_norm"].reshape(G, Di // G), cfg.rms_eps)
+    return mm("m2_out", "ne,ed->nd", y.reshape(N, Di).astype(dt_)), conv, ssm
+
+
 def lightning_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, lin: jax.Array,
                     o, positions: jax.Array, row_slots, cu_q_lens: jax.Array,
                     live: jax.Array, fresh: jax.Array, lin_impl, mm):
@@ -1228,7 +1444,8 @@ def _weight_mm(lp: dict, key: str, pattern: str, xin: jax.Array, out=None):
 
 def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
                   positions, seq_slots, cu_q_lens, state_slots, scan_impl,
-                  lin_impl=None):
+                  lin_impl=None, ssd_impl=None, expert_layer=None,
+                  expert_keys=()):
     """The layer stack of a model whose layers differ in kind and in
     parameter shapes: a scan over the periods of ``cfg.layer_kinds`` whose
     body runs the period's runs of one kind (7 mamba, 1 attention, 6 mamba),
@@ -1252,7 +1469,15 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
     compressed-key plane of a model with sparse selection (``ck``) is handed
     to the attention layers and back.
 
-    Returns (x, flat KV pool, state)."""
+    A stack of single sublayers (``cfg.single_sublayer``) has layers of kind
+    'mamba2' (``mamba2_mixer`` on the ``ssm`` and ``conv`` pools), 'attention'
+    (``attention_layer`` stops after the mixer) and 'experts':
+    ``expert_layer(h, lp, ordinal) -> (y, counts, drops)``, forward_core's
+    own mixture feed-forward, on the layer's leaves ``expert_keys`` (by its
+    ordinal among the expert layers). Their counts [Le, slots] and drops
+    ride the scans' carry and are returned.
+
+    Returns (x, flat KV pool, state, expert counts, drops)."""
     from llmd_tpu.ops.selective_scan import row_flags, selective_scan_xla
 
     assert cu_q_lens is not None, "a model with recurrent layers needs cu_q_lens"
@@ -1262,8 +1487,14 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
         Lm, S1 = state["ssm"].shape[:2]
         pools["ssm"] = state["ssm"].reshape((Lm * S1,) + state["ssm"].shape[2:])
         pools["conv"] = state["conv"]
+    if cfg.has_mamba2:
+        from llmd_tpu.ops.mamba2_ssd import mamba2_ssd_xla
+
+        ssd_impl = ssd_impl or mamba2_ssd_xla
+        pools["ssm"] = state["ssm"].reshape((-1,) + state["ssm"].shape[2:])
+        pools["conv"] = state["conv"]
     live, fresh = row_flags(positions, cu_q_lens)
-    if cfg.has_mamba:
+    if cfg.has_mamba or cfg.has_mamba2:
         plan = window_plan(pools["conv"].shape, x.shape[0], state_slots,
                            seq_slots, cu_q_lens, live, fresh)
     if cfg.has_lightning:
@@ -1278,6 +1509,17 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
 
     if cfg.has_mamba:
         params = dict(params, **mamba_vectors(cfg, params))
+    if cfg.has_mamba2:
+        params = dict(params, **mamba2_vectors(cfg, params))
+    counted = ()
+    if cfg.single_sublayer and cfg.is_moe:
+        # what the expert layers report, by their ordinal, on the carry
+        counted = ("moe_cnt", "moe_drop")
+        _, cnt0, drop0 = jax.eval_shape(
+            lambda: expert_layer(x, {k: params[k][0] for k in expert_keys}, 0))
+        pools["moe_cnt"] = jnp.zeros((cfg.num_moe_layers,) + cnt0.shape,
+                                     cnt0.dtype)
+        pools["moe_drop"] = jnp.zeros(drop0.shape, drop0.dtype)
     shared = present("attn_norm", "mlp_norm", "wi", "wo_mlp")
     own = {"mamba": present("mamba_in", "mamba_x", "mamba_dt", "mamba_a_log",
                             "mamba_out", "mamba_vec", "mamba_norms"),
@@ -1285,7 +1527,10 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
                                 "k_norm"),
            "lightning": present("lin_wq", "lin_wk", "lin_wv", "lin_wg",
                                 "lin_wo", "lin_q_norm", "lin_k_norm",
-                                "lin_o_norm")}
+                                "lin_o_norm"),
+           "mamba2": present("m2_in", "m2_norm", "m2_out", "m2_vec",
+                             "m2_heads"),
+           "experts": tuple(expert_keys)}
 
     def leaves(keys, i):
         return {k: lax.dynamic_index_in_dim(params[k], i, 0, keepdims=False)
@@ -1306,6 +1551,19 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
             return _weight_mm(lp, key, pattern, xin, out)
 
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        if kind == "experts":
+            y, cnt, drop = expert_layer(h, lp, o)
+            return _joined(cfg, x, y), flat_cache, {
+                **pools,
+                "moe_cnt": lax.dynamic_update_index_in_dim(
+                    pools["moe_cnt"], cnt, o, 0),
+                "moe_drop": pools["moe_drop"] + drop}
+        if kind == "mamba2":
+            o_mix, conv, ssm = mamba2_mixer(
+                cfg, lp, h, pools["conv"], pools["ssm"], o, plan, state_slots,
+                cu_q_lens, live, fresh, ssd_impl, mm)
+            return _joined(cfg, x, o_mix), flat_cache, {
+                **pools, "conv": conv, "ssm": ssm}
         if kind == "mamba":
             o_mix, conv, ssm = mamba_mixer(
                 cfg, lp, h, pools["conv"], pools["ssm"], o, plan, state_slots,
@@ -1342,8 +1600,9 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
     (x, flat_cache, pools), _ = lax.scan(
         one_period, (x, flat_cache, pools),
         jnp.arange(cfg.num_layers // period, dtype=jnp.int32))
-    return x, flat_cache, {k: v.reshape(state[k].shape)
-                           for k, v in pools.items()}
+    return (x, flat_cache,
+            {k: v.reshape(state[k].shape) for k, v in pools.items()
+             if k not in counted}, *(pools[k] for k in counted))
 
 
 def forward_core(
@@ -1367,6 +1626,7 @@ def forward_core(
     state_slots: Optional[jax.Array] = None,  # [B] row -> state slot (recurrent models)
     scan_impl=None,  # ops/selective_scan impl (mamba layers)
     lin_impl=None,  # ops/lightning_attention impl (lightning layers)
+    ssd_impl=None,  # ops/mamba2_ssd impl (mamba2 layers)
     query_attn_impl=None,  # attention impl for one-query rows (sparse selection)
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Run a flat mixed batch through the model, writing K/V into the paged cache.
@@ -1449,7 +1709,8 @@ def forward_core(
         attn_keys = _variants("wq", "wk", "wv", "wo")
     # leaves every layer has, then the feed-forward's: a mixture layer's
     # (stacked by mixture layer) or a dense layer's
-    every_keys = ("attn_norm", "mlp_norm") + attn_keys + (
+    every_keys = ("attn_norm",) + (
+        () if cfg.single_sublayer else ("mlp_norm",)) + attn_keys + (
         ("q_norm", "k_norm") if cfg.qk_norm else ()
     ) + (("bq", "bk", "bv", "bo") if cfg.attn_bias else ()) + (
         _variants("wg") if cfg.attn_output_gate else ())
@@ -1483,7 +1744,8 @@ def forward_core(
         stacked_keys += tuple(f"lora_{ab}_{t}" for t in LORA_TARGETS for ab in "AB")
         if lora_indices is None:
             lora_indices = jnp.zeros((N,), jnp.int32)
-    layer_params = {k: params[k] for k in stacked_keys}
+    layer_params = ({k: params[k] for k in stacked_keys}
+                    if state is None else {})  # (a hybrid stack indexes)
 
     def pad_heads(t):  # [N, h, Dh] → [N, h, Dhp]
         if Dhp == Dh:
@@ -1492,6 +1754,58 @@ def forward_core(
 
     assert not (cfg.has_window and cfg.is_mla), \
         "MLA has no sliding-window layers"
+
+    def expert_layer(h, lp, ordinal, early_logits=None, mm=None):
+        """The mixture feed-forward of the ``ordinal``-th mixture layer on
+        the normed rows ``h`` with the layer's leaves ``lp``: routed experts
+        (``moe_block``, the banks a layer's or the whole stack's) and the
+        shared expert. Returns (y, counts by expert slot, drops). Both
+        stacks call it: ``layer`` below and ``_hybrid_stack``."""
+        if mm is None:
+            def mm(key, pattern, xin):
+                return _weight_mm(lp, key, pattern, xin)
+        eplb = (
+            (lp["eplb_replica_slots"], lp["eplb_replica_counts"])
+            if "eplb_replica_slots" in lp
+            else None
+        )
+        mw = {**lp, **banks}  # a layer's bank, or the whole stack
+        quant_moe = "moe_wi_q" in mw  # int8 expert banks: einsum path only
+        y, cnt, drop = moe_block(
+            cfg, h, lp["router"],
+            mw["moe_wi_q" if quant_moe else "moe_wi"],
+            mw["moe_wo_q" if quant_moe else "moe_wo"],
+            eplb=eplb,
+            matmul_impl=None if quant_moe else moe_matmul_impl,
+            token_mask=(positions >= 0),
+            wi_scale=mw["moe_wi_scale"] if quant_moe else None,
+            wo_scale=mw["moe_wo_scale"] if quant_moe else None,
+            dispatch_impl=moe_dispatch_impl,
+            return_dropped=True,
+            logits=early_logits,
+            slot_offset=ordinal * cfg.moe_bank_slots if banks else None,
+            # (the expert layers of a stack beside recurrent layers: new
+            # programs all. The accepted mixtures' step programs stay the
+            # StableHLO they were; ROADMAP: move them over in a change of
+            # their own, with their cells measured)
+            gather_rows=state is not None,
+            **({"router_bias": lp["router_bias"]}
+               if cfg.moe_router_bias else {}),
+        )
+        if cfg.moe_num_shared_experts:
+            if not cfg.moe_gated:
+                y = y + mm("shared_wo", "nf,fd->nd",
+                           MOE_ACTIVATIONS[cfg.moe_activation](
+                               mm("shared_wi", "nd,df->nf", h)))
+            elif "shared_wi_q" in lp:
+                def _shared_mm(key, pattern, xin):
+                    return mm({"wi": "shared_wi",
+                               "wo_mlp": "shared_wo"}[key], pattern, xin)
+
+                y = y + swiglu(h, None, None, mm=_shared_mm)
+            else:
+                y = y + swiglu(h, lp["shared_wi"], lp["shared_wo"])
+        return y, cnt, drop
 
     def layer(carry, lp, l, window, use_rope, moe_ordinal=None):
         """Layer ``l`` (traced index) with parameters ``lp``; ``window`` (0 =
@@ -1661,41 +1975,14 @@ def forward_core(
                                    lora_indices, lora_scale)
         x = _joined(cfg, x, o)
 
+        if cfg.single_sublayer:  # the layer is its mixer alone
+            return (x, flat_cache, *planes), (
+                jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         if cfg.is_moe and "router" in lp:
-            eplb = (
-                (lp["eplb_replica_slots"], lp["eplb_replica_counts"])
-                if "eplb_replica_slots" in lp
-                else None
-            )
-            mw = {**lp, **banks}  # a layer's bank, or the whole stack
-            quant_moe = "moe_wi_q" in mw  # int8 expert banks: einsum path only
-            y, cnt, drop = moe_block(
-                cfg, h, lp["router"],
-                mw["moe_wi_q" if quant_moe else "moe_wi"],
-                mw["moe_wo_q" if quant_moe else "moe_wo"],
-                eplb=eplb,
-                matmul_impl=None if quant_moe else moe_matmul_impl,
-                token_mask=(positions >= 0),
-                wi_scale=mw["moe_wi_scale"] if quant_moe else None,
-                wo_scale=mw["moe_wo_scale"] if quant_moe else None,
-                dispatch_impl=moe_dispatch_impl,
-                return_dropped=True,
-                logits=early_logits,
-                slot_offset=(l if moe_ordinal is None else moe_ordinal)
-                * cfg.moe_num_experts if banks else None,
-                **({"router_bias": lp["router_bias"]}
-                   if cfg.moe_router_bias else {}),
-            )
-            if cfg.moe_num_shared_experts:
-                if "shared_wi_q" in lp:
-                    def _shared_mm(key, pattern, xin):
-                        return _mm({"wi": "shared_wi",
-                                    "wo_mlp": "shared_wo"}[key], pattern, xin)
-
-                    y = y + swiglu(h, None, None, mm=_shared_mm)
-                else:
-                    y = y + swiglu(h, lp["shared_wi"], lp["shared_wo"])
+            y, cnt, drop = expert_layer(
+                h, lp, l if moe_ordinal is None else moe_ordinal,
+                early_logits, _mm)
         else:
             cnt = jnp.zeros((0,), jnp.int32)
             drop = jnp.zeros((), jnp.int32)
@@ -1705,13 +1992,14 @@ def forward_core(
         return (x, flat_cache, *planes), (cnt, drop)
 
     if state is not None:
-        x, flat_cache, state = _hybrid_stack(
+        x, flat_cache, state, *counted = _hybrid_stack(
             cfg, params, layer, x, cache.reshape(Ptot * ps, HkC, Dhp), state,
-            positions, seq_slots, cu_q_lens, state_slots, scan_impl, lin_impl)
+            positions, seq_slots, cu_q_lens, state_slots, scan_impl, lin_impl,
+            ssd_impl, expert_layer, expert_keys)
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         return (x, {"kv": flat_cache.reshape(Ptot, ps, HkC, Dhp), **state},
-                jnp.zeros((cfg.num_layers, 0), jnp.int32),
-                jnp.zeros((), jnp.int32))
+                *(counted or (jnp.zeros((cfg.num_layers, 0), jnp.int32),
+                              jnp.zeros((), jnp.int32))))
 
     if cfg.moe_leading_dense_layers:
         # Leading dense layers, then a scan over the mixture layers. A layer

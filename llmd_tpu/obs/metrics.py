@@ -920,6 +920,13 @@ class EngineMetrics:
             "Routed copies of live tokens, summed over the mixture layers "
             "(tokens x experts a token x layers), counted under sigmoid "
             "routing beside moe_bias_moved_choices_total")
+        self.moe_held_copies = reg.counter(
+            "llmd_tpu:moe_held_copies_total",
+            "Routed copies of live tokens whose expert this device holds "
+            "(ModelConfig.moe_held_first, moe_held_count), summed over the "
+            "mixture layers: what the expert GEMMs are given. Over "
+            "moe_routed_copies_total, the share of the layer's work done "
+            "here; fed only by a model that holds a share of its experts")
         self.moe_expert_load = reg.gauge(
             "llmd_tpu:moe_expert_load_max_over_mean",
             "Of the last step that routed tokens (a unified step, or the k "

@@ -65,14 +65,16 @@ def plan_blocks(copies: int, slots: int, bc: int) -> int:
     return (copies + bc - 1) // bc + slots
 
 
-def _row_plan(slot: jax.Array, S: int, bc: int):
+def _row_plan(slot: jax.Array, S: int, bc: int, sources: bool = False):
     """Static-shape placement of N routed copies into a block-aligned
     buffer. ``slot`` is [N] int32 in [0, S]; S is the padding sentinel.
 
     Returns (row [N], block_slot [nb], block_rows [nb], Tp): ``row[i]``
     is entry i's row in the padded buffer (== Tp for sentinels, which a
     mode="drop" scatter discards); block b holds rows of expert slot
-    ``block_slot[b]`` with ``block_rows[b]`` of them real.
+    ``block_slot[b]`` with ``block_rows[b]`` of them real. With ``sources``
+    a fifth result, ``src`` [Tp]: the entry each row of the padded buffer
+    holds (N where it holds none), the inverse of ``row``.
     """
     N = slot.shape[0]
     order = jnp.argsort(slot, stable=True)
@@ -95,21 +97,38 @@ def _row_plan(slot: jax.Array, S: int, bc: int):
         0, S - 1)
     block_rows = jnp.clip(starts_pad[block_slot] + cnt[block_slot] - bstart,
                           0, bc)
-    return row, block_slot, block_rows, Tp
+    if not sources:
+        return row, block_slot, block_rows, Tp
+    # block b's rows hold the entries that follow, in sorted order, the
+    # ones its slot's earlier blocks hold
+    j = jnp.arange(bc, dtype=jnp.int32)
+    first = starts[block_slot] + bstart - starts_pad[block_slot]
+    at = jnp.clip(first[:, None] + j[None, :], 0, N - 1)
+    src = jnp.where(j[None, :] < block_rows[:, None], order[at], N)
+    return row, block_slot, block_rows, Tp, src.reshape(Tp)
+
+
+def expert_hidden(gate_up, act, gated: bool):
+    """What an expert's second product reads: ``act(gate) * up`` of the
+    first product's halves, or, without a gate, ``act`` of all of it."""
+    if not gated:
+        return act(gate_up)
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    return act(gate) * up
 
 
 def _experts_xla(xb, block_slot, block_rows, wi, wo, wi_scale, wo_scale,
-                 act=jax.nn.silu):
+                 act=jax.nn.silu, gated: bool = True):
     """Gathered batched-einsum expert MLP over [nb, bc, D] blocks — the
     CPU / int8 backend. Dead rows are zero in ``xb`` and act(0)*0 == 0 for
-    the model's gate activation ``act`` (silu, relu), so no masking is
-    needed; per-slot int8 scales gather with the bank."""
+    the model's gate activation ``act`` (silu, relu; without a gate, relu^2
+    of 0 is 0 too), so no masking is needed; per-slot int8 scales gather
+    with the bank."""
     dt = xb.dtype
     gate_up = jnp.einsum("bcd,bdf->bcf", xb, wi[block_slot].astype(dt))
     if wi_scale is not None:
         gate_up = gate_up * wi_scale[block_slot][:, None, :].astype(dt)
-    gate, up = jnp.split(gate_up, 2, axis=-1)
-    ye = jnp.einsum("bcf,bfd->bcd", act(gate) * up,
+    ye = jnp.einsum("bcf,bfd->bcd", expert_hidden(gate_up, act, gated),
                     wo[block_slot].astype(dt))
     if wo_scale is not None:
         ye = ye * wo_scale[block_slot][:, None, :].astype(dt)
@@ -117,13 +136,12 @@ def _experts_xla(xb, block_slot, block_rows, wi, wo, wi_scale, wo_scale,
 
 
 def _experts_pallas(xb, block_slot, block_rows, wi, wo, wi_scale, wo_scale,
-                    interpret, act=jax.nn.silu):
+                    interpret, act=jax.nn.silu, gated: bool = True):
     """Pallas ragged grouped GEMM backend (bf16 banks; int8 stays on the
     XLA path, mirroring the engine's einsum-path policy)."""
     gate_up = ragged_grouped_gemm(xb, wi, block_slot, block_rows,
                                   interpret=interpret)
-    gate, up = jnp.split(gate_up, 2, axis=-1)
-    ye = ragged_grouped_gemm(act(gate) * up, wo, block_slot,
+    ye = ragged_grouped_gemm(expert_hidden(gate_up, act, gated), wo, block_slot,
                              block_rows, interpret=interpret)
     return ye
 
@@ -133,32 +151,48 @@ def _experts_pallas(xb, block_slot, block_rows, wi, wo, wi_scale, wo_scale,
 # --------------------------------------------------------------------------
 
 
-def dispatch_stage(x, idx, topw, valid, S: int, bc: int):
-    """Sort + scatter: flat (token, k) copies into the block buffer."""
+def dispatch_stage(x, idx, topw, valid, S: int, bc: int,
+                   gather_rows: bool = False):
+    """Sort + scatter: flat (token, k) copies into the block buffer.
+
+    ``gather_rows``: the buffer is gathered, each of its rows from the copy
+    `_row_plan` says it holds, and no scatter is made; the same buffer bit
+    for bit. XLA compiles the scatter of 384 rows as a sort of their
+    indices, a gather and a scatter in that order, and in the fused decode
+    call of a stack of Mamba-2, attention and expert layers that scatter did
+    not return from the chip when idle seats led the live ones (PR 47,
+    PERF.md section 6: the same indices with the live rows leading ran, the
+    dropped copies sent in bounds did not, the gathered buffer did)."""
     T, D = x.shape
     k = idx.shape[1]
     slot = jnp.where(valid > 0, idx, S).reshape(T * k)
-    row, block_slot, block_rows, Tp = _row_plan(slot, S, bc)
+    row, block_slot, block_rows, Tp, *src = _row_plan(slot, S, bc, gather_rows)
     tok = (jnp.arange(T * k, dtype=jnp.int32) // k)
-    xs = jnp.zeros((Tp, D), x.dtype).at[row].set(x[tok], mode="drop")
+    if gather_rows:
+        of = jnp.minimum(src[0], T * k - 1) // k  # the token a row holds
+        xs = jnp.where((src[0] < T * k)[:, None], x[of], 0).astype(x.dtype)
+    else:
+        xs = jnp.zeros((Tp, D), x.dtype).at[row].set(x[tok], mode="drop")
     wf = jnp.where(slot < S, topw.reshape(T * k), 0).astype(x.dtype)
     return xs, row, tok, wf, block_slot, block_rows
 
 
 def experts_stage(xs, block_slot, block_rows, wi, wo, wi_scale=None,
                   wo_scale=None, *, use_pallas: bool = False,
-                  interpret: bool = False, act=jax.nn.silu):
+                  interpret: bool = False, act=jax.nn.silu,
+                  gated: bool = True):
     """Per-block expert MLP on the sorted buffer: [Tp, D] -> [Tp, D];
-    ``act`` is the gate's activation."""
+    ``act`` is the gate's activation, or with ``gated`` false the activation
+    between an expert's two products (``wi`` [S, D, F])."""
     Tp, D = xs.shape
     bc = Tp // block_slot.shape[0]
     xb = xs.reshape(-1, bc, D)
     if use_pallas and wi_scale is None:
         ye = _experts_pallas(xb, block_slot, block_rows, wi, wo, wi_scale,
-                             wo_scale, interpret, act)
+                             wo_scale, interpret, act, gated)
     else:
         ye = _experts_xla(xb, block_slot, block_rows, wi, wo, wi_scale,
-                          wo_scale, act)
+                          wo_scale, act, gated)
     return ye.reshape(Tp, D)
 
 
@@ -192,7 +226,8 @@ def sorted_moe_local(x, idx, topw, valid, wi, wo, wi_scale=None,
                      interpret: bool = False,
                      bc: Optional[int] = None, act=jax.nn.silu,
                      slot_offset=None, num_slots: Optional[int] = None,
-                     ordered_combine: bool = False):
+                     ordered_combine: bool = False, gated: bool = True,
+                     gather_rows: bool = False):
     """Single-shard token-sorted MoE: gather/scatter only, no collective.
 
     ``slot_offset`` (a traced scalar) with ``num_slots``: ``wi``/``wo`` (and
@@ -202,20 +237,20 @@ def sorted_moe_local(x, idx, topw, valid, wi, wo, wi_scale=None,
     out of the whole stack, where a layer's bank sliced out of it first is a
     copy of the bank every step (as long as the GEMMs themselves on the
     chip). ``ordered_combine``: `combine_in_order` in `combine_stage`'s
-    place."""
+    place. ``gather_rows``: `dispatch_stage`'s."""
     T, D = x.shape
     S = num_slots or wi.shape[0]
     if bc is None:
         bc = pick_block_size(T * idx.shape[1], S, use_pallas and wi_scale is None)
     with jax.named_scope("moe_dispatch"):
         xs, row, tok, wf, block_slot, block_rows = dispatch_stage(
-            x, idx, topw, valid, S, bc)
+            x, idx, topw, valid, S, bc, gather_rows)
         if slot_offset is not None:
             block_slot = block_slot + slot_offset
     with jax.named_scope("moe_experts"):
         ye = experts_stage(xs, block_slot, block_rows, wi, wo, wi_scale,
                            wo_scale, use_pallas=use_pallas, interpret=interpret,
-                           act=act)
+                           act=act, gated=gated)
     with jax.named_scope("moe_combine"):
         if ordered_combine:
             return combine_in_order(ye, row, wf, T)
@@ -318,13 +353,14 @@ def make_sorted_dispatch(mesh=None, *, use_pallas: bool = False,
     if mesh is None:
         def impl(x, idx, topw, valid, wi, wo, wi_scale=None, wo_scale=None,
                  act=jax.nn.silu, slot_offset=None, num_slots=None,
-                 ordered_combine=False):
+                 ordered_combine=False, gated=True, gather_rows=False):
             return sorted_moe_local(x, idx, topw, valid, wi, wo, wi_scale,
                                     wo_scale, use_pallas=use_pallas,
                                     interpret=interpret, act=act,
                                     slot_offset=slot_offset,
                                     num_slots=num_slots,
-                                    ordered_combine=ordered_combine)
+                                    ordered_combine=ordered_combine,
+                                    gated=gated, gather_rows=gather_rows)
         # forward_core may hand this impl the whole stack of banks and a
         # layer's offset (sorted_moe_local); the mesh impl below shards one
         # layer's bank over ep and takes it sliced

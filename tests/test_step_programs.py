@@ -54,6 +54,7 @@ VARIANTS = {
                                       prefill_chunk=16), STATEFUL),
     "tiny-sala": ("tiny-sala", dict(page_size=2, num_pages=512,
                                     max_model_len=256), STATEFUL),
+    "tiny-nemotron-h": ("tiny-nemotron-h", dict(num_pages=128), STATEFUL),
     "tiny-moe+pallas": ("tiny-moe", PALLAS, ("unified", "decode")),
     "tiny-glm+pallas": ("tiny-glm", dict(PALLAS, page_size=4),
                         ("unified", "decode")),
@@ -63,6 +64,8 @@ VARIANTS = {
     "tiny-sala+pallas": ("tiny-sala", dict(
         PALLAS, page_size=2, num_pages=512, max_model_len=256),
         ("unified", "decode")),
+    "tiny-nemotron-h+pallas": ("tiny-nemotron-h", dict(PALLAS, num_pages=128),
+                               ("unified", "decode")),
     # what the bodies pass beside the bound core: adapter indices, the
     # multimodal arrays, a mesh's constraints and the ring variant
     "tiny+lora": ("tiny", dict(lora=LoRAConfig(max_adapters=2, rank=4)),
@@ -157,6 +160,19 @@ PARENT_STABLEHLO = {
         "197efbc7e3898cdef077e5e8a742829eb93ba3951c2bb851c82e190ebacc8235",
     "tiny+sp2/decode":
         "6477f848a077b22d92af852454f576f08fcdd7ce24ff764c8a6d4fe4cbce2563",
+    # new in ISSUE 47 (a stack of single sublayers: Mamba-2, attention and
+    # non-gated expert layers), taken on that PR's tree; every row above is
+    # as it was
+    "tiny-nemotron-h/unified":
+        "4ba0dc6c0da23074e2f942129dea71307c33af06922104fc2409a21b95f4278f",
+    "tiny-nemotron-h/decode":
+        "f6413cfafcf58280b1c9a140604b66b6a312594cf59f42fe86457a260d4dbe30",
+    "tiny-nemotron-h/decode_masked":
+        "b1dca1964bf690f96ecaeecd76e7ae2cf04b502320b1ff52c66444a7d2e70b40",
+    "tiny-nemotron-h+pallas/unified":
+        "9086d86eaa36ccd07f66dca287128194201b659f7a89866f87bd3582c9008def",
+    "tiny-nemotron-h+pallas/decode":
+        "ec22882aee6cd5dc18204cd2416d5966a212bff732d1a55ae71e211bc8ebe03a",
 }
 
 

@@ -501,6 +501,85 @@ def test_lightning_attention_compiles_for_v5e_at_the_cells_shapes(one_chip, n):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
 
 
+@pytest.mark.parametrize("n,rows", [(64, 64), (320, 64)],
+                         ids=["decode", "unified"])
+def test_mamba2_ssd_compiles_for_v5e_at_the_cells_shapes(one_chip, n, rows):
+    """The Mamba-2 layers' kernel at nemotron-3-nano-30b-a3b's widths (64
+    heads of 64 channels, 8 groups of state 128, 6 layers of 65 slots folded
+    into the pool, float32 state) at both step programs' token budgets goes
+    through Mosaic and the TPU compiler here: the row-by-row loads of a block
+    that starts off a sublane tile, the transposition that makes a token's B
+    and C columns, the transposed products of the cumulative sums and of the
+    state's update, and the VMEM of the resident blocks are what interpret
+    mode cannot refuse."""
+    from llmd_tpu.ops.mamba2_ssd import mamba2_ssd_pallas
+
+    H, P, G, N, seats = 64, 64, 8, 128, 64
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (spec((n, H, P), jnp.bfloat16), spec((n, H), jnp.float32),
+            spec((H,), jnp.float32), spec((n, G, N), jnp.bfloat16),
+            spec((n, G, N), jnp.bfloat16),
+            spec((6 * (seats + 1), G, N, H // G * P), jnp.float32),
+            spec((rows,), jnp.int32), spec((rows + 1,), jnp.int32),
+            spec((rows,), jnp.bool_), spec((rows,), jnp.bool_))
+    compiled = jax.jit(mamba2_ssd_pallas, donate_argnums=(5,)).lower(
+        *args).compile()
+    assert "mamba2_ssd" in compiled.as_text()
+    # in place: the 0.82 GB pool is neither copied nor a temporary
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+def test_mamba2_ssd_lowers_for_tpu():
+    from llmd_tpu.ops.mamba2_ssd import mamba2_ssd_pallas
+
+    n, H, P, G, N, rows = 48, 8, 32, 2, 16, 4
+    _lower_for_tpu(
+        mamba2_ssd_pallas, _spec((n, H, P), jnp.bfloat16),
+        _spec((n, H), jnp.float32), _spec((H,), jnp.float32),
+        _spec((n, G, N), jnp.bfloat16), _spec((n, G, N), jnp.bfloat16),
+        _spec((9, G, N, H // G * P), jnp.float32), _spec((rows,), jnp.int32),
+        _spec((rows + 1,), jnp.int32), _spec((rows,), jnp.bool_),
+        _spec((rows,), jnp.bool_))
+
+
+@pytest.mark.parametrize("bc", [8, 32], ids=["decode", "unified"])
+@pytest.mark.parametrize("bank,D,F", [("moe_wi", 2688, 1920),
+                                      ("moe_wo", 1920, 2688)])
+def test_non_gated_banks_compile_for_v5e_where_they_lie(one_chip, bank, D, F,
+                                                        bc):
+    """The experts' kernel at nemotron-3-nano-30b-a3b's widths: 64 held
+    experts a layer, six layers' banks stacked (384 slots), the width 1,856
+    stored as 1,920 (``ModelConfig.moe_bank_width``). The tile is the whole
+    F, and the bank is read where it lies: at 1,856 the compiler copied the
+    whole stack into a padded layout at every call's entry (3.7 GB; the
+    engine did not fit the chip)."""
+    from llmd_tpu.models.config import ModelConfig
+    from llmd_tpu.ops.grouped_gemm import pick_bank_tile
+    from llmd_tpu.ops.moe_dispatch import pick_block_size, plan_blocks
+
+    assert ModelConfig(
+        name="x", vocab_size=8, hidden_size=8, intermediate_size=1856,
+        num_layers=1, num_heads=1, num_kv_heads=1, head_dim=8,
+        moe_num_experts=2, moe_top_k=1, moe_gated=False,
+        moe_activation="relu2").moe_bank_width == 1920
+    copies = {8: 64 * 6, 32: 320 * 6}[bc]
+    assert pick_block_size(copies, 64, True) == bc
+    nb, slots = plan_blocks(copies, 64, bc), 6 * 64
+    assert pick_bank_tile(D, F, bc, 2) == F
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(ragged_grouped_gemm).lower(
+        spec((nb, bc, D), jnp.bfloat16), spec((slots, D, F), jnp.bfloat16),
+        spec((nb,), jnp.int32), spec((nb,), jnp.int32)).compile()
+    assert "ragged_grouped_gemm" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
 @pytest.mark.parametrize("program", ["decode", "unified"])
 def test_sparse_hybrid_step_programs_compile_for_v5e_at_the_cells_sizes(
         one_chip, program):
