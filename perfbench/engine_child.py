@@ -66,7 +66,9 @@ def engine_config(conf: dict, **over):
         max_model_len=e["max_model_len"], max_batch_size=e["max_batch_size"],
         prefill_chunk=e["prefill_chunk"], decode_steps=e["decode_steps"],
         quantize_weights=conf["weights"]["quantize"])
-    return EngineConfig(**{**fields, **over})
+    # a control's only (tests/manifest-*.json): fields the engine is given
+    # behind the file's stated ones, which the check goes on reading
+    return EngineConfig(**{**fields, **e.get("unstated", {}), **over})
 
 
 def main() -> None:

@@ -20,7 +20,11 @@ bytes an element:
               token; every query in and its output out, 2 * H * d * e
 The compressed keys a selection reads are left out (they are read by the
 selection, not by the kernel), so the demand is low and no reading can pass
-100%. The least time is the larger of bytes over the chip's memory bandwidth
+100%. The tokens are the program's count, once a row: exact for traffic that
+shares nothing (``longdoc-closed``), where every cached token is distinct. A
+cell whose rows stand behind one document would have to count a document
+once first (``kernels/cached_tokens.py``, as the other attention rooflines
+do), or a kernel that fetched it once could read over 100%. The least time is the larger of bytes over the chip's memory bandwidth
 and operations over its bf16 matrix rate.
 
 Per dispatch of the module, from the counters over the part of the window

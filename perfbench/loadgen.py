@@ -58,6 +58,7 @@ class Generator:
         self.load = Load()
         self.inflight = 0
         self.ctx_tokens = {}  # request index -> context tokens now (decoding)
+        self.shared = {}  # request index -> (tenant, its tokens of the prompt)
         self.session = None
         ses = self.mix.get("sessions")
         self.history = {}
@@ -127,6 +128,8 @@ class Generator:
                 if req.turn == 0 or req.lane not in self.history:
                     self.history[req.lane] = list(self.system[req.tenant])
                 prompt = self.history[req.lane] + new
+                self.shared[req.index] = (req.tenant,
+                                          len(self.system[req.tenant]))
             else:
                 prompt = new
             rec.prompt_tokens = len(prompt)
@@ -137,6 +140,7 @@ class Generator:
             finally:
                 self.inflight -= 1
                 self.ctx_tokens.pop(req.index, None)
+                self.shared.pop(req.index, None)
             rec.ok = len(out) == req.max_tokens
             if not rec.ok:
                 rec.error = f"{len(out)} tokens of {req.max_tokens}"
@@ -147,6 +151,17 @@ class Generator:
         finally:
             if lock is not None:
                 lock.release()
+
+    def decoding_rows(self) -> list:
+        """(context tokens now, tenant, shared) of every request that has
+        its first token and not yet its last: of a session turn's context the
+        first ``shared`` tokens are its tenant's system prompt, the same
+        tokens at the same positions for every lane of the tenant; without
+        sessions tenant None and nothing shared. What the attention
+        rooflines' byte demand is counted from
+        (``kernels/cached_tokens.py``)."""
+        return [(n, *self.shared.get(i, (None, 0)))
+                for i, n in self.ctx_tokens.items()]
 
     # ------------------------------------------------------------- set-up
     async def warm_sessions(self) -> int:

@@ -21,7 +21,11 @@ Source kinds:
   prom_gauge_mean {where, series}              mean of the polled values
   trace {path: [keys...]}                      a number of the reduction
   trace_ops {pattern, of: "busy"|"seconds"}    operations by name pattern
-  trace_module {pattern, per: "execution"|"seconds", divide_config?}
+  trace_module {pattern, per: "execution"|"seconds"|"step", step_op?}
+        ``step``: the programs' seconds in the capture over the steps they
+        ran there, counted as the executions of ``step_op`` inside them: an
+        operation that runs once a step, by name pattern, ``{key}`` filled
+        from the configuration (the head's logits, ``f32[rows, vocab_size]``)
   kernel_roofline {kernel, pattern, module}    see ``kernels/``
   device {field}
   scale {of, by} / difference {a, b} / ratio {a, b}   arithmetic on sources
@@ -45,13 +49,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 def load(name: str) -> dict:
     with open(os.path.join(HERE, "metrics", name + ".json")) as f:
         return json.load(f)
-
-
-def _conf(config: dict, dotted: str):
-    v = config
-    for k in dotted.split("."):
-        v = v[k]
-    return v
 
 
 def _delta(ctx, where, series, labels):
@@ -116,6 +113,15 @@ def read(src: dict, ctx: dict):
         if not n:
             return None
         v = sum(m["seconds"] for m in ms)
+        if src["per"] == "step":
+            # a fused call runs as many steps as its rows' budgets give it
+            # (engine.decode_steps is only the cap), so the steps are counted
+            # where they ran; of several operations that match, each runs
+            # once a step and the most often seen has them all
+            op = re.compile(src["step_op"].format(**ctx["config"]))
+            steps = sum(max((o["count"] for n, o in m.get("ops", {}).items()
+                             if op.search(n)), default=0) for m in ms)
+            return v / steps if steps else None
         if src["per"] == "execution":
             # executions the capture holds whole, where it holds any: one
             # that its edge cut is shorter than it was
@@ -123,8 +129,6 @@ def read(src: dict, ctx: dict):
             if whole:
                 v, n = sum(m["whole_seconds"] for m in ms), whole
             v /= n
-        if "divide_config" in src:
-            v /= _conf(ctx["config"], src["divide_config"])
         return v
     if kind == "kernel_roofline":
         mod = importlib.import_module("kernels." + src["kernel"])

@@ -25,7 +25,10 @@ end-to-end ones.
 
 The last line of standard output is the result, one JSON object. Lines before
 it say what the run did: the split of set-up, the counts behind every
-percentile, the check. Exit code 0 only with a result; without a TPU, with
+percentile, the check (every position's gap error and deficit with it;
+``--check-only`` stops there, for fitting a check's limit). The result's
+last key, ``check``, holds every number ``correct`` compared beside its
+limit, and standard error ends with the same. Exit code 0 only with a result; without a TPU, with
 fewer chips than the cell asks for, or on a ``device_kind`` missing from
 ``peaks.json``, no result and a non-zero code. ``--cpu`` is for rehearsing the
 harness at a tiny size (``tests/``): its result names the CPU and carries no
@@ -37,6 +40,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import math
 import os
 import random
 import shutil
@@ -57,6 +61,7 @@ import estimators as est  # noqa: E402
 import prom  # noqa: E402
 import readers  # noqa: E402
 import traffic  # noqa: E402
+from kernels.cached_tokens import decode_means  # noqa: E402
 from loadgen import Generator  # noqa: E402
 
 READY_TIMEOUT_S = 1000.0  # a first run compiles; the driver allows it 1200 s
@@ -181,6 +186,29 @@ def gap_summary(errors) -> dict:
     return {"positions": len(err), "median": q50, "q25": q25, "max": top}
 
 
+def clean_half(errors, per_prompt: int, floor: float) -> float:
+    """The arithmetic's share of the positions' gap errors, read where an
+    expert choice that flipped has left it alone. A model with recurrent
+    layers carries a flip on through the state to every later position of
+    the same prompt, so flips foul whole prompts, more or fewer of them from
+    seed to seed, and the median over all positions reads how many prompts
+    were fouled (nemotron: 0.059 to 0.197 over sound seeds, PERF.md section
+    2). Taken instead: of each prompt the lower quartile of its positions'
+    errors (the positions a flip inside the served span has not reached), and
+    of the half of the prompts that read lowest the geometric mean of those.
+    A lower precision moves every prompt, the cleanest too; a fault that
+    spares half the prompts is not this number's to find (``margin`` reads
+    every position)."""
+    err = list(errors)
+    low = sorted(gap_summary(err[i:i + per_prompt])["q25"]
+                 for i in range(0, len(err), per_prompt))
+    low = [max(v, floor) for v in low[:max(1, len(low) // 2)]]
+    return math.exp(sum(map(math.log, low)) / len(low))
+
+
+JUDGED = ("median", "clean_half")
+
+
 async def gap_probe(gen: Generator, probe: dict, prompts: list, served: list,
                     top2: list) -> dict:
     """How far the served path's logits lie from the reference's, read
@@ -193,11 +221,13 @@ async def gap_probe(gen: Generator, probe: dict, prompts: list, served: list,
     reference's gap find that gap to ``width / 2**rounds``; the error of a
     position is its distance from the reference's, at most ``width``.
 
-    The number judged is the median over the positions. Arithmetic of a
-    lower precision moves every position; an expert choice that flips at a
-    near tie of the router moves the positions it touches by far more and
-    leaves the others alone, so while under half the positions see a flip the
-    median reads the arithmetic and the maximum reads the ties."""
+    The number judged is the median over the positions, unless the file's
+    ``gap_probe.judged`` names ``clean_half``. Arithmetic of a lower
+    precision moves every position; an expert choice that flips at a near tie
+    of the router moves the positions it touches by far more and leaves the
+    others alone, so while under half the positions see a flip the median
+    reads the arithmetic and the maximum reads the ties. Where a recurrent
+    state carries a flip on, over half of them can: ``clean_half``."""
     ctx = [(list(p) + list(s[:j]), *t) for p, s, ts in
            zip(prompts, served, top2) for j, t in enumerate(ts)]
     w = probe["width"]
@@ -215,9 +245,19 @@ async def gap_probe(gen: Generator, probe: dict, prompts: list, served: list,
                 hi[i] = mid[i]
             else:
                 lo[i] = mid[i]
-    return {"gap_error": gap_summary(abs((l + h) / 2 - g) for l, h, (*_, g)
-                                     in zip(lo, hi, ctx)),
-            "limit": probe["limit"], "resolution": w / 2 ** probe["rounds"]}
+    err = [abs((l + h) / 2 - g) for l, h, (*_, g) in zip(lo, hi, ctx)]
+    res = w / 2 ** probe["rounds"]
+    summary = gap_summary(err)
+    judged = probe.get("judged", "median")
+    if judged not in JUDGED:
+        raise SystemExit(f"gap_probe.judged {judged!r} is none of {JUDGED}")
+    if judged == "clean_half":
+        if len({len(ts) for ts in top2}) != 1:
+            raise SystemExit("clean_half: a prompt served fewer tokens")
+        summary["clean_half"] = clean_half(err, len(top2[0]), res)
+    return {"gap_error": summary, "judged": judged, "read": summary[judged],
+            "limit": probe["limit"], "resolution": res,
+            "errors": [round(e, 4) for e in err]}
 
 
 async def check_outputs(gen: Generator, session, control: str, eurl: str,
@@ -267,6 +307,7 @@ async def check_outputs(gen: Generator, session, control: str, eurl: str,
         "lengths_ok": all(len(c) == n_out for c in cold),
         "reference_worst_deficit": worst, "margin": chk["margin"],
         "reference_argmax_agree": agree,
+        "deficits": [round(d, 3) for ds in ref["deficits"] for d in ds],
         "served_tokens": sum(len(c) for c in cold),
         "prompt_tokens": [min(map(len, prompts)), max(map(len, prompts))],
         "prefix_cached_tokens": {"cold": c1 - c0, "cached": c2 - c1},
@@ -281,8 +322,8 @@ async def check_outputs(gen: Generator, session, control: str, eurl: str,
         out["gap_probe"] = await gap_probe(gen, chk["gap_probe"], prompts,
                                            cold, ref["top2"])
         out["gap_probe"]["seconds"] = time.time() - t3
-        read = out["gap_probe"]["gap_error"]["median"]
-        out["ok"] = bool(out["ok"] and read <= chk["gap_probe"]["limit"])
+        out["ok"] = bool(out["ok"] and out["gap_probe"]["read"]
+                         <= chk["gap_probe"]["limit"])
     out["engine_split"] = setup["split"]
     return out
 
@@ -291,7 +332,9 @@ async def check_outputs(gen: Generator, session, control: str, eurl: str,
 
 def generator_facts(load, samples: list, until: float | None = None) -> dict:
     """What the client saw of the window's requests; with ``until``, of those
-    that were complete by then (a failed one counts if it was due by then)."""
+    that were complete by then (a failed one counts if it was due by then).
+    ``samples`` are the decoding rows at each moment they were sampled
+    (``Generator.decoding_rows``)."""
     win = [r for r in load.records if r.in_window and (
         until is None or (r.last <= until if r.ok else r.due <= until))]
     late = [(r.sent - r.free) * 1e3 for r in win if r.sent]
@@ -313,10 +356,11 @@ def generator_facts(load, samples: list, until: float | None = None) -> dict:
         "prompt_tokens_sent": sum(r.prompt_tokens for r in win),
         "lane_blocked": load.lane_blocked,
     }
-    if samples:
-        facts["decode_ctx_tokens_mean"] = (
-            sum(s[1] for s in samples) / len(samples))
-        facts["decoding_mean"] = sum(s[0] for s in samples) / len(samples)
+    means = decode_means({"decode_rows": samples}) if samples else None
+    if means:
+        (facts["decode_ctx_tokens_mean"],
+         facts["decode_unique_ctx_tokens_mean"],
+         facts["decoding_mean"]) = means
     return facts
 
 
@@ -410,6 +454,8 @@ async def run(args, manifest: dict, cell: dict, conf: dict, mix: dict,
                                         args.seed)
             split["check_and_warm_up"] = time.time() - t
             say(note="check", **check)
+            if args.check_only:
+                return {"check_only": True, "ok": check["ok"]}
             t = time.time()
             warmed = await gen.warm_sessions()
             split["session_histories"] = time.time() - t
@@ -438,8 +484,7 @@ async def run(args, manifest: dict, cell: dict, conf: dict, mix: dict,
                     if time.monotonic() < t_cap:
                         polls.append((time.monotonic(),
                                       await scrape(session, eurl)))
-                    dec = list(gen.ctx_tokens.values())
-                    samples.append((time.monotonic(), len(dec), sum(dec)))
+                    samples.append((time.monotonic(), gen.decoding_rows()))
                     await asyncio.sleep(POLL_S)
                 await cap
 
@@ -472,6 +517,19 @@ async def run(args, manifest: dict, cell: dict, conf: dict, mix: dict,
                                     "llmd_tpu:program_compiles_total") or 0)
     correct = bool(check["ok"] and not failed and compiles == 0
                    and notes["tokens_in_window"] > 0)
+    # every number `correct` compared, beside its limit: the result line's
+    # last key and this run's last lines on standard error
+    probe = check.get("gap_probe")
+    compared = {
+        "cold_equals_cached": [check["cold_equals_cached"], True],
+        "lengths_ok": [check["lengths_ok"], True],
+        "served_dtype_ok": [check["served_dtype_ok"], True],
+        "worst_deficit": [check["reference_worst_deficit"], check["margin"]],
+        **({"gap_" + probe["judged"]: [probe["read"], probe["limit"]]}
+           if probe else {}),
+        "failed_requests": [len(failed), 0],
+        "compiles_in_window": [compiles, 0],
+        "tokens_in_window_over": [notes["tokens_in_window"], 0]}
     units = {m["name"]: m["unit"] for m in manifest["end_to_end"]
              + manifest["per_layer"]}
     result = {"correct": correct, "attempted": len(win),
@@ -486,6 +544,7 @@ async def run(args, manifest: dict, cell: dict, conf: dict, mix: dict,
         vals["setup_s"] = setup_s
         for k, v in vals.items():
             result["metrics"][k] = {"value": est.finite(v), "unit": units[k]}
+        result["check"] = compared
         return result
 
     # the traced run: reduce the trace in a process of its own, then let
@@ -505,9 +564,10 @@ async def run(args, manifest: dict, cell: dict, conf: dict, mix: dict,
                 say(note="trace_reduction_failed", stderr=out.stderr[-2000:])
     shutil.rmtree(os.path.join(out_dir, "profile"), ignore_errors=True)
     lo, hi = captured.get("from", load.t0), captured.get("to", load.t1)
-    in_trace = [(n, s) for t, n, s in samples if lo <= t <= hi] or \
-        [(n, s) for _, n, s in samples]
+    in_trace = [rows for t, rows in samples if lo <= t <= hi] or \
+        [rows for _, rows in samples]
     ctx = {"gen": generator_facts(load, in_trace, captured.get("from")),
+           "decode_rows": in_trace,
            "before": before, "after": captured.get("counters", after),
            "polls": {"engine": [p for _, p in polls]},
            "trace": trace, "device": device, "config": conf}
@@ -533,6 +593,7 @@ async def run(args, manifest: dict, cell: dict, conf: dict, mix: dict,
         say(note="trace_modules", modules=trace["modules"])
     # the end-to-end numbers of a traced run, for the tracing overhead
     say(note="traced_end_to_end", **{k: est.finite(v) for k, v in vals.items()})
+    result["check"] = compared
     return result
 
 
@@ -551,6 +612,9 @@ def main() -> int:
     ap.add_argument("--manifest", default=os.path.join(ROOT, "BENCHMARK.json"))
     ap.add_argument("--cpu", action="store_true",
                     help="rehearse on the CPU (tests only)")
+    ap.add_argument("--check-only", action="store_true",
+                    help="setting a check's limit only: stop after the "
+                         "check line, with no window and no result")
     ap.add_argument("--rate", type=float, default=None,
                     help="knee sweep only: offer this rate instead of the "
                          "cell's")
@@ -594,6 +658,8 @@ def main() -> int:
                                  out_dir))
     finally:
         kids.stop()
+    for name, (value, limit) in result.get("check", {}).items():
+        print(f"check {name}: {value} (limit {limit})", file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0
 

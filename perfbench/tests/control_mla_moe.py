@@ -94,7 +94,8 @@ def int8_in_parts(cfg, params: dict) -> dict:
     return low
 
 
-def read(conf: dict, seed: int, cpu: bool = False) -> dict:
+def read(conf: dict, seed: int, cpu: bool = False, only=None,
+         errors: bool = False) -> dict:
     from llmd_tpu.jax_init import init_jax
 
     init_jax(cpu)
@@ -124,18 +125,31 @@ def read(conf: dict, seed: int, cpu: bool = False) -> dict:
     out = {"seed": seed, "layers": cfg.num_layers, "positions": len(gap),
            "prompt_tokens": [min(map(len, prompts)), max(map(len, prompts))]}
 
+    probe = conf["check"].get("gap_probe", {})
+
+    def as_probed(err) -> dict:
+        """``clean_half`` as the served probe would read it: it finds no
+        error over its ``width``."""
+        if probe.get("judged") != "clean_half":
+            return {}
+        w = probe["width"]
+        return {"clean_half": bench.clean_half(
+            [min(e, w - w / 2 ** probe["rounds"]) for e in err], n,
+            w / 2 ** probe["rounds"])}
+
     def against(sz, stack) -> dict:
         r = rows(sz, stack)
         g = jax.device_get(r[i, a] - r[i, b])
         own = r.argmax(axis=-1)
-        return {"gap_error": bench.gap_summary(
-                    abs(float(x) - float(y)) for x, y in zip(g, gap)),
+        err = [abs(float(x) - float(y)) for x, y in zip(g, gap)]
+        return {"gap_error": {**bench.gap_summary(err), **as_probed(err)},
                 "argmax_agree": int((own == a).sum()),
-                "worst_deficit": float((top[:, 0] - sound[i, own]).max())}
+                "worst_deficit": float((top[:, 0] - sound[i, own]).max()),
+                **({"errors": [round(e, 4) for e in err]} if errors else {})}
 
-    out["top_k-1"] = against(dict(sizes, top_k=sizes["top_k"] - 1), params)
-    for fault, switch in FAULTS:
-        out[fault] = against(dict(sizes, **switch), params)
+    for fault, switch in (("top_k-1", {"top_k": sizes["top_k"] - 1}),) + FAULTS:
+        if only is None or fault in only:
+            out[fault] = against(dict(sizes, **switch), params)
     # one precision lower, last: it takes the stack's bf16 leaves with it
     assert conf["weights"] == {**conf["weights"], "dtype": "bfloat16",
                                "quantize": None}, "a bf16 file's control"
@@ -150,6 +164,10 @@ def main() -> int:
     ap.add_argument("--seeds", default="11,12,13")
     ap.add_argument("--set", action="append", default=[], metavar="KEY=JSON")
     ap.add_argument("--cpu", action="store_true")
+    ap.add_argument("--only", default=None,
+                    help="comma-separated faults to read beside int8")
+    ap.add_argument("--errors", action="store_true",
+                    help="every position's gap error too, in served order")
     args = ap.parse_args()
     with open(args.config) as f:
         conf = json.load(f)
@@ -157,7 +175,10 @@ def main() -> int:
         k, v = kv.split("=", 1)
         conf[k] = json.loads(v)
     for seed in args.seeds.split(","):
-        print(json.dumps(read(conf, int(seed), args.cpu)), flush=True)
+        print(json.dumps(read(
+            conf, int(seed), args.cpu,
+            None if args.only is None else args.only.split(","),
+            args.errors)), flush=True)
     return 0
 
 

@@ -57,6 +57,49 @@ def test_the_probe_finds_the_served_gap_by_bisection():
     assert e["median"] == pytest.approx(0.03, abs=2e-4)
 
 
+def test_clean_half_reads_the_prompts_that_no_flip_fouled():
+    """Four prompts of eight positions: two clean (errors about 0.02), two
+    fouled through (0.25 everywhere). The median over all positions stands
+    between the two kinds; ``clean_half`` reads the clean ones, whichever
+    prompts they are, and a precision that moves every prompt moves it."""
+    clean = [0.004, 0.01, 0.02, 0.02, 0.03, 0.04, 0.05, 0.25]
+    fouled = [0.25] * 8
+    two = bench.clean_half(clean + fouled + fouled + clean, 8, 0.001)
+    assert two == pytest.approx(0.02)
+    assert bench.clean_half(fouled + clean + clean + fouled, 8, 0.001) == two
+    assert bench.gap_summary(clean + fouled + fouled + clean)["median"] > 0.04
+    # three of four fouled: the half still holds a fouled prompt, and says so
+    assert bench.clean_half(clean + fouled * 3, 8, 0.001) == pytest.approx(
+        (0.02 * 0.25) ** 0.5)
+    # every prompt moved threefold
+    low = [3 * e for e in clean]
+    assert bench.clean_half(low + fouled + fouled + low, 8, 0.001) \
+        == pytest.approx(0.06)
+    # an error of nought stands at the probe's resolution, not at log(0)
+    assert bench.clean_half([0.0] * 8 + clean, 8, 0.001) == pytest.approx(0.001)
+
+
+def test_the_probe_judges_what_the_file_names():
+    prompts, served = [[1], [2]], [[7, 8], [9, 9]]
+    top2 = [[[7, 6, 0.30], [8, 5, 0.02]], [[9, 3, 0.10], [2, 9, 0.50]]]
+    own = {(1,): 0.31, (1, 7): 0.03, (2,): 0.9, (2, 9): 0.9}
+
+    def probe(**kw):
+        return asyncio.run(bench.gap_probe(
+            Served(own), {"rounds": 10, "width": 0.128, "limit": 0.02, **kw},
+            prompts, served, top2))
+
+    out = probe()
+    assert out["judged"] == "median" and len(out["errors"]) == 4
+    assert out["read"] == out["gap_error"]["median"]
+    out = probe(judged="clean_half")
+    # prompt one reads 0.01 and 0.01, prompt two is out of the bracket
+    assert out["read"] == out["gap_error"]["clean_half"] \
+        == pytest.approx(0.01, abs=2e-4)
+    with pytest.raises(SystemExit, match="judged"):
+        probe(judged="mean")
+
+
 def test_the_probe_refuses_a_third_token():
     class Other(Served):
         async def complete(self, *a, **kw):

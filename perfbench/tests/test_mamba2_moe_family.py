@@ -37,8 +37,16 @@ def test_rehearsal_through_the_whole_harness():
     assert check["lengths_ok"] and check["served_dtype_ok"]
     assert check["reference_worst_deficit"] <= check["margin"]
     probe = check["gap_probe"]
-    assert probe["gap_error"]["median"] <= probe["limit"]
+    # judged as the cell's file is: the cleaner half of the prompts
+    assert probe["judged"] == "clean_half"
+    assert probe["read"] == probe["gap_error"]["clean_half"] <= probe["limit"]
+    assert probe["read"] <= probe["gap_error"]["median"]
     assert r["correct"] is check["cold_equals_cached"]
+    # every number compared, beside its limit, is the result's last key
+    assert list(r)[-1] == "check"
+    assert r["check"]["gap_clean_half"] == [probe["read"], probe["limit"]]
+    assert r["check"]["worst_deficit"] == [check["reference_worst_deficit"],
+                                           check["margin"]]
     m = r["metrics"]
     # the counters this family feeds, read through their metric files
     assert 30.0 < m["moe_held_copy_share"]["value"] < 70.0
@@ -71,6 +79,8 @@ def test_every_control_is_read_and_parts_from_the_sound_reference():
         assert out[name]["gap_error"]["max"] > 0.0, name
     for name in faults:
         assert out[name]["gap_error"]["max"] > 0.05, name
+    # read as the file's probe judges, the probe's width the most it finds
+    assert 0 < out["int8"]["gap_error"]["clean_half"] <= 0.064
 
 
 def test_the_cells_files_say_what_the_issue_asks():

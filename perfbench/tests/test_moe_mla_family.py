@@ -37,7 +37,7 @@ def test_rehearsal_through_the_whole_harness():
     assert check["lengths_ok"] and check["served_dtype_ok"]
     assert check["reference_worst_deficit"] <= check["margin"]
     probe = check["gap_probe"]
-    assert probe["gap_error"]["median"] <= probe["limit"]
+    assert probe["read"] <= probe["limit"]
     assert r["correct"] is check["cold_equals_cached"]
     m = r["metrics"]
     # the counters this family feeds, read through their metric files

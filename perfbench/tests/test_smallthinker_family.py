@@ -86,7 +86,7 @@ def test_masking_alone_reads_low_and_the_demand_is_the_models():
     # (S + 3 * 64 * 4096) / 4S = 0.634 at a mean context of 8,000
     assert got == pytest.approx((S + 3 * B * 4096) / (4 * S), rel=0.01)
     # per call of a full layer every key and value once, 2,048 B a token
-    full, window = mwa.demand_seconds(S, B, CONF, PEAKS)
+    full, window = mwa.demand_seconds(_ctx(1.0), CONF, PEAKS)
     assert full == pytest.approx(
         (S * 2048 + 2 * B * 28 * 128 * 2) / PEAKS["hbm_bytes_per_s"])
     assert window == pytest.approx(
@@ -96,8 +96,14 @@ def test_masking_alone_reads_low_and_the_demand_is_the_models():
 def test_contexts_inside_the_window_demand_what_they_hold():
     short = dict(_ctx(1.0))
     short["gen"] = {"decode_ctx_tokens_mean": 64 * 1000.0, "decoding_mean": B}
-    full, window = mwa.demand_seconds(64 * 1000.0, B, CONF, PEAKS)
+    full, window = mwa.demand_seconds(short, CONF, PEAKS)
     assert full == window  # min(S, B * window) = S
+    # the rows themselves: each row its own min(context, window), exactly
+    rows = [(1000, None, 0)] * 32 + [(9000, None, 0)] * 32
+    full, window = mwa.demand_seconds({"decode_rows": [rows]}, CONF, PEAKS)
+    shape = (28, 4, 128)
+    assert full == least_seconds(*cost(32 * 10000.0, B, *shape), PEAKS)
+    assert window == least_seconds(*cost(32 * 5096.0, B, *shape), PEAKS)
 
 
 def test_nothing_to_read_is_none_not_an_error():
