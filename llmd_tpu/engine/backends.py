@@ -192,12 +192,14 @@ def _attn_impl(model_cfg: ModelConfig, engine_cfg: EngineConfig, mesh,
             # the CPU's designed backend, not a degradation: the reason
             # stays empty so that real fallbacks are observable
             return ragged_paged_attention_xla, "xla_mla_absorbed", None
-        from llmd_tpu.ops.mla_attention import mla_paged_attention
+        from llmd_tpu.ops import mla_attention
 
-        return (functools.partial(
-            mla_paged_attention, rank=model_cfg.mla_kv_lora_rank,
-            interpret=interpret, mesh=mesh),
-            "pallas_mla_ragged_paged_attention", None)
+        impl = functools.partial(
+            mla_attention.mla_paged_attention,
+            rank=model_cfg.mla_kv_lora_rank, interpret=interpret, mesh=mesh)
+        # the rows' groups are the batch's, not a layer's: once a program
+        impl.plan = mla_attention.plan
+        return impl, "pallas_mla_ragged_paged_attention", None
     if mode == "reference":
         return ragged_paged_attention_xla, "xla_reference", None
     if mode == "auto" and platform != "tpu":

@@ -897,11 +897,24 @@ class LLMEngine:
         self._eplb_tracker.record(np.asarray(cnt))
         self._eplb_active = True
 
-    def _count_attn_kv(self, program: str, kv_lens, q_lens,
-                       steps=None) -> None:
+    def _count_attn_kv(self, program: str, kv_lens, q_lens, *, page_tables,
+                       seats=None, steps=None) -> None:
         """``attn_kv_tokens_total``, ``attn_query_tokens_total`` and
         ``attn_query_key_pairs_total`` of one dispatched call, from the
-        lengths the step already packed."""
+        lengths the step already packed; under the latent kernel also
+        ``latent_decode_kv_blocks_total`` of its one-query rows, from their
+        page tables (the rows' own, or all ``seats``' of which the rows take
+        theirs; a fused call at its first step)."""
+        kv, q = np.asarray(kv_lens, np.int64), np.asarray(q_lens, np.int64)
+        if self.attn_backend == "pallas_mla_ragged_paged_attention":
+            from llmd_tpu.ops.mla_attention import decode_kv_blocks
+
+            if seats is not None:
+                page_tables = page_tables[seats]
+            for blocks, n in zip(("rows", "fetched"), decode_kv_blocks(
+                    page_tables, kv, q, self.cfg.page_size)):
+                self.metrics.latent_decode_kv_blocks.labels(
+                    blocks=blocks).inc(n)
         for kind, n in attn_kv_tokens(self.model_cfg, kv_lens, q_lens,
                                       self.cfg.page_size,
                                       self.backends.window_align).items():
@@ -911,7 +924,6 @@ class LLMEngine:
             self._count_sparse_rows(program, kv_lens, q_lens, steps)
         # a row's queries are its last q tokens: query i of q sees kv - q + i
         # + 1 keys, q * kv - q * (q - 1) / 2 in all
-        kv, q = np.asarray(kv_lens, np.int64), np.asarray(q_lens, np.int64)
         self.metrics.attn_query_tokens.labels(program=program).inc(
             int(q.sum()))
         self.metrics.attn_qk_pairs.labels(program=program).inc(
@@ -1890,7 +1902,8 @@ class LLMEngine:
         self.metrics.program_kv_read_tokens.labels(program=step_prog).inc(
             kv_read_tokens)
         self._count_attn_kv(step_prog, lens[: len(plan)],
-                            np.diff(cu[: len(plan) + 1]))
+                            np.diff(cu[: len(plan) + 1]),
+                            page_tables=pts[: len(plan)])
         self.metrics.program_rows.labels(program=step_prog).inc(len(plan))
         # dispatched here, complete when its record is read (_sample_apply),
         # a step later: the ledger holds it in flight meanwhile
@@ -2841,6 +2854,8 @@ class LLMEngine:
         self.programs.record_dispatch(prog)
         self.metrics.program_kv_read_tokens.labels(program=prog).inc(ctx_tokens)
         self._count_attn_kv(prog, ctx_lens, np.ones(len(ctx_lens), np.int64),
+                            page_tables=pts_np,
+                            seats=[s.slot for s in active],
                             steps=[steps_left[s.slot] for s in active])
         if self.state:
             # a row takes as many of the call's k steps as it has left

@@ -1675,6 +1675,12 @@ def forward_core(
     B = page_tables.shape[0]
     if attn_impl is None:
         attn_impl = ragged_paged_attention_xla
+    # what an impl derives from the batch's layout alone it derives here,
+    # once a program and not once a layer (``plan``: keyword arguments of
+    # every layer's call; the latent kernel's groups of rows)
+    attn_plan = getattr(attn_impl, "plan", None)
+    planned = attn_plan(page_tables, kv_lens, cu_q_lens, num_seqs,
+                        ps) if attn_plan and cu_q_lens is not None else {}
     x = params["embed"][tokens].astype(cfg.jax_dtype)  # [N, D]
     if cfg.embed_scale != 1.0:
         x = x * cfg.embed_scale
@@ -1945,7 +1951,7 @@ def forward_core(
             positions, seq_slots, kv_lens,
             cu_q_lens=cu_q_lens, num_seqs=num_seqs, scale=scale,
             chunk_k=k_w, chunk_v=v_w,
-            **({"sliding_window": window} if window else {}),
+            **({"sliding_window": window} if window else {}), **planned,
         )
         if cfg.is_mla:
             # latent-weighted sum [..., :rank] re-expands per head via W_UV

@@ -665,6 +665,17 @@ class EngineMetrics:
             "layers=sparse over layers=full is not where a chunk brings many "
             "queries a row",
             labelnames=("tokens",))
+        self.latent_decode_kv_blocks = reg.counter(
+            "llmd_tpu:latent_decode_kv_blocks_total",
+            "Of the latent-attention kernel's decode rows (one query a row, "
+            "in a unified step or a fused decode call, a fused call at its "
+            "first step): blocks=rows the KV blocks the rows walk, once a "
+            "row; blocks=fetched the KV blocks the kernel fetches, a block "
+            "that the rows of a group name alike once a group "
+            "(ops/mla_attention.decode_kv_blocks, from the page tables the "
+            "step packed). Booked only where the attention backend is that "
+            "kernel",
+            labelnames=("blocks",))
         self.program_rows = reg.counter(
             "llmd_tpu:program_rows_total",
             "Sequences (rows) packed into each dispatch",

@@ -14,7 +14,7 @@ kernel walks a row's keys in blocks of ``bkv`` pages.
 The grid is the batch rows; everything else is loops inside the kernel over
 what the row really holds, with q, the pool and the output left in HBM:
 
-- a row's queries go in blocks of ``bq`` tokens, loaded with all (padded)
+- a chunk's queries go in blocks of ``bq`` tokens, loaded with all (padded)
   heads of a token as rows of one matrix ``[bq * Hp, Dhp]`` (Hp: the heads
   padded to a power of two at least 32, so that this fold is a relabelling of
   tiles and not a relayout). Where the model's own heads fill whole tiles
@@ -22,10 +22,21 @@ what the row really holds, with q, the pool and the output left in HBM:
   chunk's block is compacted to them once a query block, by a product with a
   0/1 selection matrix (exact: one input times 1.0 plus zeros), and the output
   block is spread back the same way: the two products of every KV block then
-  see no padded head (`chunk_fold`). A one-query row keeps the 32-row fold:
-  at one token 20 rows and 32 cost the matrix unit the same,
-- for each query block, the row's KV blocks up to its last query's position,
-  fetched page by page into one of two VMEM buffers while the other is
+  see no padded head (`chunk_fold`),
+- one-query rows (a decode row: 32 padded head rows) walk in groups of up to
+  ``GROUP_ROWS`` whose tables start on the same pages (`_groups`, derived from
+  the page tables with ``jax.numpy`` once a program and scalar-prefetched: no
+  option, rows behind one cached document or system prompt are seen as they
+  come). The group's first row leads: the leading KV blocks all its members
+  name alike are fetched once and multiplied with the members' queries
+  stacked in one matrix ``[GROUP_ROWS * Hp, Dhp]``, then each member's own
+  blocks with its own queries on its own rows of the scratch; the other
+  members' grid steps do nothing. A row's blocks come in the order and the
+  size they had, a product's row depends on its own input row alone, and m, l
+  and acc are a row's own, so a row's result is bit for bit what it is alone.
+  A row that shares nothing is a group of one with nothing shared,
+- for each query block (or group), the KV blocks up to its last query's
+  position, fetched page by page into one of two VMEM buffers while the other is
   computed on (the page table is scalar-prefetched),
 - the score product runs over all ``Dhp`` lanes, the weighted sum over the
   value lanes only (``V``: the latent rank rounded up to a lane tile, 512 of
@@ -69,6 +80,34 @@ what is left beside the products is a KV block's weights latched once for
 fewer rows). The decode rows move by 2%: a one-query row waits on its 64 page
 fetches a block, not on the matrix unit.
 
+What the shared fetch is worth (`tools/mla_attn_sweep.py --bkv 64 --bq 16
+--shared 4 --groups 4,8 --parent <the parent commit's file>`, TPU v5 lite,
+PR 49; the same 64 rows, every four behind one document of 16,384 tokens and
+1.0-3.0k tokens of their own: 1,139 KV blocks once a row, 371 fetched; us a
+call, parent = the parent commit's file in the same chip call; every row's
+result equal to the parent's in the value lanes, ``diff`` 0.0; first call s as
+above, parent -> this file):
+
+    shape                        parent   G = 4*   G = 8   first call s
+    fused decode call (64 x 1)    2,475    1,118   1,525   1.7 -> 2.6
+    + one 128-token chunk         3,250    1,936   2,336   5.0 -> 5.7
+    + one 256-token chunk         4,038    2,718   3,119   4.6 -> 5.4
+    rows that share nothing (``--shared 0``), G = 4:
+    fused decode call             2,484    2,429
+    + one 128-token chunk         3,249    3,203
+    + one 256-token chunk         4,008    3,994
+
+A shared block's visit costs 3.3 us where its 64 page copies take 2.3: the
+two products over 128 stacked rows are not hidden behind the copies. Issuing
+the next block's copies inside the products' branch, and dropping the mask
+where a block lies before every member's end, each read within 1% of this
+(1,125-1,131 us beside 1,122 in one chip call; not kept). At 4 lanes a
+document a group of 8 is half padding (256 rows a product): slower. One walk
+serves rows with and without company: a second walk for the groups beside the
+parent's for single rows cost 1.7 s more a call site to trace and lower, every
+launch (15 s of ``glm47flash-docs``' ``setup_s``), and was given up; with the
+one walk ``setup_s`` reads as the parent's.
+
 The kernel's name on the device trace is ``mla_ragged_paged_attention``: the
 benchmark's ``attn_dev_share`` reads attention by the pattern
 ``ragged_paged_attention``.
@@ -102,6 +141,12 @@ ROW_TILE = 16  # rows of a bf16 tile
 # lower a call site where 32 costs 2.4.
 KV_BLOCK_TOKENS = 1024
 KV_BLOCK_MAX_PAGES = 64
+
+# One-query rows a group: rows whose leading KV blocks name the same pages
+# have those blocks fetched once and their queries stacked in one matrix of
+# GROUP_ROWS x Hp rows (128 at 32 padded heads: one pass of a 128-row matrix
+# unit where a single row fills a quarter of one).
+GROUP_ROWS = 4
 
 
 def pick_block_sizes(num_tokens: int, num_rows: int, page_size: int,
@@ -142,12 +187,78 @@ def value_lanes(rank: "int | None", lanes: int) -> int:
     return lanes if rank is None else min(lanes, -(-rank // _MINOR) * _MINOR)
 
 
+def _groups(xp, page_tables, kv_lens, q_lens, num_seqs, bkv: int, ps: int,
+            G: int):
+    """The grouping rule, over ``numpy`` or ``jax.numpy`` (``xp``). One-query
+    rows whose tables start on the same page form a sharing set, cut in row
+    order into groups of up to ``G``; a group's first row leads it.
+    Returns ``(members [B, G], size [B], shared [B], n_kv [B])``: a leader's
+    members (itself first, the seats past ``size`` itself again), its size
+    (0 for every row that leads nothing: a member, a chunk, an idle seat) and
+    the leading KV blocks all its members name alike below each one's own
+    ``n_kv``, the blocks a one-query row walks. The unmapped entries of two
+    rows look alike (``-1``, or page 0 once the kernel's call has clamped
+    them): only blocks below ``n_kv`` count."""
+    B, maxp = page_tables.shape
+    rows, seats, walk = xp.arange(B), xp.arange(G), xp.arange(maxp // bkv)
+    blocks = page_tables.reshape(B, maxp // bkv, bkv)
+    n_kv = xp.maximum(kv_lens - 1, 0) // (bkv * ps) + 1
+    one = (rows < num_seqs) & (q_lens == 1) & (kv_lens > 0)
+    first = blocks[:, 0, 0]  # a prefix cache shares a page with all before it
+    same = one[:, None] & one[None, :] & (first[:, None] == first[None, :])
+    # a row's place in its sharing set, and the rows up to G places on from it
+    pos = (same & (rows[None, :] < rows[:, None])).sum(1)
+    hit = same[:, None, :] & (
+        pos[None, None, :] == (pos[:, None] + seats)[:, :, None])
+    found = hit.any(-1)
+    members = xp.where(found, hit.argmax(-1), rows[:, None])
+    size = xp.where(one & (pos % G == 0), found.sum(-1), 0)
+    leader = xp.where(one, (same & (
+        pos[None, :] == (pos - pos % G)[:, None])).argmax(-1), rows)
+    alike = (blocks == blocks[leader]).all(-1) & (
+        walk < xp.minimum(n_kv, n_kv[leader])[:, None])
+    # the first block a row names otherwise than its leader, then the least
+    # over a leader's members
+    shared = xp.where(alike, maxp // bkv, walk).min(-1)[members].min(-1)
+    shared = xp.where(size > 1, shared, 0)  # a row alone walks its own blocks
+    return (members.astype(xp.int32), size.astype(xp.int32),
+            shared.astype(xp.int32), n_kv)
+
+
+def row_groups(page_tables, kv_lens, cu_q_lens, num_seqs, page_size: int):
+    """``(members, size, shared)`` of a call (`_groups`, on the device): what
+    the kernel is told of its one-query rows. A function of the batch's layout
+    alone and the same for every layer's slice of the pool (a layer's page
+    ids are the batch's plus the layer's offset), so a program derives it
+    once and hands it to each layer's call (``plan``)."""
+    bkv, _ = pick_block_sizes(0, 0, page_size, page_tables.shape[1])
+    return _groups(jnp, page_tables, kv_lens, cu_q_lens[1:] - cu_q_lens[:-1],
+                   num_seqs, bkv, page_size, GROUP_ROWS)[:3]
+
+
+def decode_kv_blocks(page_tables, kv_lens, q_lens, page_size: int
+                     ) -> tuple[int, int]:
+    """(KV blocks once a row, KV blocks the kernel fetches) of a call's
+    one-query rows, from the page tables the step packed (numpy arrays, all
+    three): the numpy twin of what `row_groups` derives on the device
+    (``latent_decode_kv_blocks_total``)."""
+    bkv, _ = pick_block_sizes(0, 0, page_size, page_tables.shape[1])
+    members, size, shared, n_kv = _groups(
+        np, page_tables, kv_lens, q_lens, len(kv_lens), bkv, page_size,
+        GROUP_ROWS)
+    tails = (n_kv[members] - shared[:, None]) * (
+        np.arange(GROUP_ROWS) < size[:, None])
+    return (int(n_kv[(q_lens == 1) & (kv_lens > 0)].sum()),
+            int((shared * (size > 0) + tails.sum(1)).sum()))
+
+
 def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
+            member_ref, size_ref, shared_ref,  # the groups (`_groups`)
             q_hbm, pool_hbm, o_init_hbm,  # HBM
             o_hbm,  # HBM, aliased to o_init_hbm
             q_buf, kv_buf, o_buf, m_ref, l_ref, acc_ref,  # VMEM scratch
             q_sem, kv_sem, o_sem,
-            *, bq: int, hq: int, bkv: int, maxp: int, scale: float):
+            *, bq: int, hq: int, bkv: int, maxp: int, G: int, scale: float):
     del o_init_hbm
     b = pl.program_id(0)
     ps = kv_buf.shape[1] // bkv
@@ -158,12 +269,38 @@ def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
     q_len = cu_ref[b + 1] - q_start
     kv_len = kv_lens_ref[b]
 
-    def fetch(j, slot):
-        """The page copies of the row's KV block ``j`` into buffer ``slot``."""
+    def fetch(row, j, slot):
+        """The page copies of row ``row``'s KV block ``j`` into buffer
+        ``slot``."""
         return [pltpu.make_async_copy(
-            pool_hbm.at[pt_ref[b * maxp + j * bkv + i]],
+            pool_hbm.at[pt_ref[row * maxp + j * bkv + i]],
             kv_buf.at[slot, pl.ds(i * ps, ps)], kv_sem.at[slot])
             for i in range(bkv)]
+
+    def attend(q, slot, j, rows, last_key):
+        """KV block ``j`` (in buffer ``slot``) into the online softmax of the
+        scratch rows ``rows``, whose queries are the matrix ``q``."""
+        R = q.shape[0]
+        # [T, Dhp]: keys, and in the first V lanes values
+        s = lax.dot_general(q, kv_buf[slot], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        key = j * T + lax.broadcasted_iota(jnp.int32, (R, T), 1)
+        mask = key <= last_key
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[rows]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new[:, :1]), 0.0)
+        l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[rows] = acc_ref[rows] * alpha[:, :1] + lax.dot_general(
+            p.astype(kv_buf.dtype), kv_buf[slot, :, pl.ds(0, V)],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[rows] = m_new
+
+    def reset(R: int):
+        m_ref[pl.ds(0, R)] = jnp.full((R, _MINOR), NEG_INF, jnp.float32)
+        l_ref[pl.ds(0, R)] = jnp.zeros((R, _MINOR), jnp.float32)
+        acc_ref[pl.ds(0, R)] = jnp.zeros((R, V), jnp.float32)
 
     def query_block(qb, nq: int, h: int):
         """Query block ``qb`` of the row in blocks of ``nq`` tokens of ``h``
@@ -176,11 +313,9 @@ def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
         load = pltpu.make_async_copy(q_hbm.at[pl.ds(t0, nq)],
                                      q_buf.at[pl.ds(0, nq)], q_sem)
         load.start()
-        for c in fetch(0, 0):
+        for c in fetch(b, 0, 0):
             c.start()
-        m_ref[pl.ds(0, R)] = jnp.full((R, _MINOR), NEG_INF, jnp.float32)
-        l_ref[pl.ds(0, R)] = jnp.zeros((R, _MINOR), jnp.float32)
-        acc_ref[pl.ds(0, R)] = jnp.zeros((R, V), jnp.float32)
+        reset(R)
         # a row's token, and with it the last key it may see
         row = lax.broadcasted_iota(jnp.int32, (R, 1), 0)
         tok = sum((row >= t * h).astype(jnp.int32) for t in range(1, nq))
@@ -200,29 +335,12 @@ def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
 
             @pl.when(j + 1 < n_kv)
             def _prefetch():
-                for c in fetch(j + 1, 1 - slot):
+                for c in fetch(b, j + 1, 1 - slot):
                     c.start()
 
-            for c in fetch(j, slot):
+            for c in fetch(b, j, slot):
                 c.wait()
-            # [T, Dhp]: keys, and in the first V lanes values
-            s = lax.dot_general(q, kv_buf[slot], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            key = j * T + lax.broadcasted_iota(jnp.int32, (R, T), 1)
-            mask = key <= last_key
-            s = jnp.where(mask, s, NEG_INF)
-            m_prev = m_ref[pl.ds(0, R)]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.where(mask, jnp.exp(s - m_new[:, :1]), 0.0)
-            l_ref[pl.ds(0, R)] = l_ref[pl.ds(0, R)] * alpha + jnp.sum(
-                p, axis=1, keepdims=True)
-            acc_ref[pl.ds(0, R)] = acc_ref[pl.ds(0, R)] * alpha[:, :1] + \
-                lax.dot_general(p.astype(kv_buf.dtype),
-                                kv_buf[slot, :, pl.ds(0, V)],
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-            m_ref[pl.ds(0, R)] = m_new
+            attend(q, slot, j, pl.ds(0, R), last_key)
             return 0
 
         lax.fori_loop(0, n_kv, kv_block, 0)
@@ -244,14 +362,91 @@ def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
         for t, c in enumerate(stores):
             pl.when(t < n_valid)(c.wait)
 
-    live = (b < nseq_ref[0]) & (kv_len > 0)
+    def group(n, shared):
+        """The ``n`` one-query rows row ``b`` leads: their ``shared`` leading
+        KV blocks fetched once for the members' queries stacked in one matrix,
+        then each member's own blocks on its own rows of the scratch. One loop
+        over the visits ``t``: the shared blocks, then member after member's
+        tail, so the page copies have the call sites of one row's walk."""
+        R = G * Hp
+        member = [member_ref[b * G + g] for g in range(G)]
+        last = [kv_lens_ref[r] - 1 for r in member]  # the key a member ends on
+        tail = [jnp.where(g < n, last[g] // T + 1 - shared, 0)
+                for g in range(G)]
+        ends = [shared + sum(tail[:g + 1]) for g in range(G)]
 
-    @pl.when(live & (q_len == 1))
-    def _decode_row():
-        query_block(0, 1, Hp)
+        def place(t):
+            """(page-table row, KV block, member) of visit ``t``; a shared
+            block is the leader's, member 0."""
+            past = [t >= e for e in ends[:-1]]
+            g = sum(p.astype(jnp.int32) for p in past)
+            start = shared + sum(jnp.where(p, n_t, 0)
+                                 for p, n_t in zip(past, tail))
+            return (member_ref[b * G + g],
+                    jnp.where(t < shared, t, shared + t - start), g)
+
+        loads = [pltpu.make_async_copy(q_hbm.at[pl.ds(cu_ref[r], 1)],
+                                       q_buf.at[pl.ds(g, 1)], q_sem)
+                 for g, r in enumerate(member)]
+        for c in loads:
+            c.start()
+        for c in fetch(b, 0, 0):
+            c.start()
+        reset(R)
+        row = lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+        last_key = functools.reduce(
+            lambda v, g: jnp.where(row >= g * Hp, last[g], v), range(1, G),
+            jnp.full((R, 1), last[0], jnp.int32))
+        for c in loads:
+            c.wait()
+
+        def visit(t, here):
+            slot = t % 2
+            ahead = place(t + 1)
+
+            @pl.when(t + 1 < ends[-1])
+            def _prefetch():
+                for c in fetch(*ahead[:2], 1 - slot):
+                    c.start()
+
+            for c in fetch(b, 0, slot):  # a wait reads the bytes, not the pages
+                c.wait()
+
+            @pl.when(t < shared)
+            def _together():
+                attend(q_buf[pl.ds(0, G)].reshape(R, Dhp), slot, t,
+                       pl.ds(0, R), last_key)
+
+            @pl.when(t >= shared)
+            def _alone():
+                r, j, g = here
+                attend(q_buf[g], slot, j,
+                       pl.ds(pl.multiple_of(g * Hp, Hp), Hp),
+                       kv_lens_ref[r] - 1)
+
+            return ahead
+
+        # the first visit is the leader's first block, shared or its own
+        lax.fori_loop(0, ends[-1], visit, (b, jnp.int32(0), jnp.int32(0)))
+        o_buf[pl.ds(0, G)] = (
+            acc_ref[pl.ds(0, R)] / l_ref[pl.ds(0, R)][:, :1]).astype(
+                o_buf.dtype).reshape(G, Hp, V)
+        stores = [pltpu.make_async_copy(
+            o_buf.at[g], o_hbm.at[cu_ref[r], :, pl.ds(0, V)], o_sem)
+            for g, r in enumerate(member)]
+        for g, c in enumerate(stores):
+            pl.when(g < n)(c.start)
+        for g, c in enumerate(stores):
+            pl.when(g < n)(c.wait)
+
+    # a live one-query row leads its group (of one, where it shares nothing)
+    # or is walked by the row that does: size 0, as a chunk's and an idle seat's
+    @pl.when(size_ref[b] > 0)
+    def _decode_rows():
+        group(size_ref[b], shared_ref[b])
 
     if bq > 1:
-        @pl.when(live & (q_len > 1))
+        @pl.when((b < nseq_ref[0]) & (kv_len > 0) & (q_len > 1))
         def _chunk():
             def body(qb, _):
                 query_block(qb, bq, hq)
@@ -267,7 +462,7 @@ def mla_ragged_pallas(
     page_tables: jax.Array,  # [B, maxp], clamped >= 0
     cu_q_lens: jax.Array,  # [B+1]
     num_seqs: jax.Array,  # [1]
-    *,
+    *groups: jax.Array,  # `row_groups` of the call, where the caller has them
     scale: float,
     rank: "int | None" = None,
     interpret: bool = False,
@@ -281,24 +476,28 @@ def mla_ragged_pallas(
     B, maxp = page_tables.shape
     bkv, bq = pick_block_sizes(N, B, ps, maxp)
     Hp, V, hq = padded_heads(H), value_lanes(rank, Dhp), chunk_fold(bq, H)
+    members, size, shared = groups or row_groups(
+        page_tables, kv_lens, cu_q_lens, num_seqs, ps)
+    G = members.shape[1]
     # heads padded to whole tiles, and bq rows past the batch's end so that a
     # query block's load stays in bounds (what it reads there is not stored)
     qp = jnp.pad(q, ((0, bq), (0, Hp - H), (0, 0)))
     kernel = functools.partial(_kernel, bq=bq, hq=hq, bkv=bkv, maxp=maxp,
-                               scale=scale)
-    rows = max(Hp, bq * hq)  # a one-query row's Hp, or a chunk's block
+                               G=G, scale=scale)
+    rows = max(G * Hp, bq * hq)  # a group's one-query rows, or a chunk's block
+    nq = max(G, bq)  # tokens the query and output buffers hold
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=7,
             grid=(B,),
             in_specs=[hbm, hbm, hbm],
             out_specs=hbm,
             scratch_shapes=[
-                pltpu.VMEM((bq, Hp, Dhp), q.dtype),  # q block
+                pltpu.VMEM((nq, Hp, Dhp), q.dtype),  # q block
                 pltpu.VMEM((2, bkv * ps, Dhp), layer_cache.dtype),
-                pltpu.VMEM((bq, Hp, V), q.dtype),  # output block
+                pltpu.VMEM((nq, Hp, V), q.dtype),  # output block
                 pltpu.VMEM((rows, _MINOR), jnp.float32),  # m
                 pltpu.VMEM((rows, _MINOR), jnp.float32),  # l
                 pltpu.VMEM((rows, V), jnp.float32),  # acc
@@ -309,7 +508,7 @@ def mla_ragged_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((N + bq, Hp, Dhp), q.dtype),
         # the output starts as zeros: a row no sequence owns is never written
-        input_output_aliases={6: 0},
+        input_output_aliases={9: 0},
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -317,6 +516,7 @@ def mla_ragged_pallas(
         name="mla_ragged_paged_attention",
     )(page_tables.reshape(-1).astype(jnp.int32), kv_lens.astype(jnp.int32),
       cu_q_lens.astype(jnp.int32), num_seqs.astype(jnp.int32),
+      members.reshape(-1), size, shared,
       qp, layer_cache.reshape(P, ps, Dhp), jnp.zeros_like(qp))
     return out[:N, :H]
 
@@ -337,6 +537,7 @@ def mla_paged_attention(
     rank: "int | None" = None,  # the model's kv_lora_rank: the value lanes
     interpret: bool = False,  # True only when the selecting platform is CPU
     mesh=None,  # engine mesh: the kernel runs per device under shard_map
+    groups=None,  # `row_groups` of the batch, where the program derived them
 ) -> jax.Array:
     """Uniform-signature adapter (drop-in for ragged_paged_attention_xla) for
     a latent-attention engine's step programs, mixed batches and decode calls
@@ -351,4 +552,14 @@ def mla_paged_attention(
     if mesh is not None:
         # heads split over tp; the latent plane is replicated
         call = shard_over_heads(call, mesh, q, layer_cache, shard_kv=False)
-    return call(q, layer_cache, kv_lens, page_tables, cu_q_lens, num_seqs)
+    return call(q, layer_cache, kv_lens, page_tables, cu_q_lens, num_seqs,
+                *(groups or ()))
+
+
+def plan(page_tables, kv_lens, cu_q_lens, num_seqs, page_size: int) -> dict:
+    """What `models.transformer.forward_core` asks an attention impl for once
+    a program, before its layers: keyword arguments of every layer's call."""
+    # from the tables as the engine packed them: an unmapped entry (-1) is
+    # no layer's page 0, which a layer's clamped table could not tell apart
+    return {"groups": row_groups(page_tables, kv_lens, cu_q_lens, num_seqs,
+                                 page_size)}

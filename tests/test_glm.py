@@ -411,6 +411,215 @@ def test_a_token_does_not_depend_on_its_chunk_or_its_program(small_blocks):
     assert (d == u).all() and np.abs(np.asarray(d, np.float32)).max() > 0.1
 
 
+# ------------------------ (d') one-query rows behind one cached document
+
+G = mla_attention.GROUP_ROWS
+DOC = 6  # pages of the shared document: three KV blocks of two pages
+
+
+def _behind_a_document(rows, share, chunk=None, seats=8, nt=None, num_seqs=None,
+                       heads=4, lanes=128, real=80, maxp=16, pages=256):
+    """One-query rows ``(document pages held, tokens past them)`` (tokens <=
+    0: the context ends that far inside the held pages; pages 0: an idle
+    seat) over a pool that holds the document once (``share``) or once a row
+    under the row's own page ids, then ``chunk`` ``(q_len, kv_len)`` on pages
+    of its own. Same values either way."""
+    rng = np.random.default_rng(3)
+    pool = np.zeros((pages, PS, 1, lanes), np.float32)
+    doc = rng.standard_normal((DOC, PS, 1, real))
+    own = rng.standard_normal((seats, maxp, PS, 1, real))
+    free = list(range(1, pages))  # page 0 is what a -1 entry is clamped to
+    held = [free.pop() for _ in range(DOC)]
+    pool[held, ..., :real] = doc
+    pt = np.full((seats, maxp), -1, np.int32)
+    kl = np.zeros(seats, np.int32)
+    for b, (n_doc, past) in enumerate(rows):
+        ids = held[:n_doc] if share else [free.pop() for _ in range(n_doc)]
+        pool[ids, ..., :real] = doc[:n_doc]
+        mine = [free.pop() for _ in range(-(-max(past, 0) // PS))]
+        pool[mine, ..., :real] = own[b, :len(mine)]
+        pt[b, :n_doc + len(mine)] = ids + mine
+        kl[b] = max(0, n_doc * PS + past)
+    q_lens = [1] * len(rows)
+    if chunk:
+        n = -(-chunk[1] // PS)
+        ids = [free.pop() for _ in range(n)]
+        pool[ids, ..., :real] = rng.standard_normal((n, PS, 1, real))
+        pt[len(rows), :n], kl[len(rows)] = ids, chunk[1]
+        q_lens.append(chunk[0])
+    nt = nt or seats
+    cu = np.zeros(seats + 1, np.int32)
+    cu[1:len(q_lens) + 1] = np.cumsum(q_lens)
+    cu[len(q_lens) + 1:] = cu[len(q_lens)]
+    q = np.zeros((nt, heads, lanes), np.float32)
+    q[..., :real] = rng.standard_normal((nt, heads, real))
+    ns = len(q_lens) if num_seqs is None else num_seqs
+    pos, slots = np.full(nt, -1, np.int32), np.zeros(nt, np.int32)
+    for b, ql in enumerate(q_lens):
+        pos[cu[b]:cu[b + 1]] = np.arange(kl[b] - ql, kl[b])
+        slots[cu[b]:cu[b + 1]] = b
+    args = (jnp.asarray(q, jnp.bfloat16), jnp.asarray(pool, jnp.bfloat16),
+            jnp.asarray(pt), jnp.asarray(pos), jnp.asarray(slots),
+            jnp.asarray(kl))
+    kw = dict(scale=80 ** -0.5, cu_q_lens=jnp.asarray(cu),
+              num_seqs=jnp.asarray([ns], jnp.int32))
+    return args, kw, int(cu[ns])
+
+
+# rows, the call's other arguments, and (size, shared) of the groups led
+BEHIND = {
+    "four_rows_in_the_decode_call": (
+        [(6, 3), (6, 5), (6, 9), (6, 1)], {}, [(4, 3)]),
+    "four_rows_beside_a_chunk": (
+        [(6, 3), (6, 5), (6, 9), (6, 1)], dict(chunk=(20, 33), nt=48),
+        [(4, 3)]),
+    "a_row_alone": ([(6, 3)], {}, [(1, 0)]),
+    "two_rows": ([(6, 3), (6, 11)], {}, [(2, 3)]),
+    "one_row_more_than_a_group": (
+        [(6, 3)] * G + [(6, 2)], {}, [(G, 3), (1, 0)]),
+    "unequal_extents_and_two_groups": (
+        [(6, 3), (4, 5), (6, 9), (2, 1), (6, 2), (6, 4)], dict(nt=48),
+        [(4, 1), (2, 3)]),
+    "a_context_that_ends_inside_the_last_block": (
+        [(6, 3), (6, -2), (6, 6), (6, -3)], {}, [(4, 3)]),
+    "a_row_whose_own_page_closes_the_last_block": (
+        [(6, 3), (5, 2), (6, 6)], {}, [(3, 2)]),
+    "an_idle_seat_among_them": (
+        [(6, 3), (0, 0), (6, 6), (6, 1)], dict(nt=48), [(3, 3)]),
+    "rows_past_num_seqs": (
+        [(6, 3), (6, 4), (6, 6), (6, 1), (6, 2)], dict(num_seqs=3),
+        [(3, 3)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BEHIND))
+def test_rows_behind_one_document_equal_rows_that_own_their_copies(
+        small_blocks, case):
+    """A KV block several one-query rows name alike is fetched once for their
+    stacked queries: bit for bit what the same rows read from a pool where
+    each owns a copy of the document under page ids of its own (no group
+    forms there: every row walks alone, the parent commit's path)."""
+    rows, more, groups = BEHIND[case]
+    args, kw, n = _behind_a_document(rows, True, **more)
+    apart, kw_a, _ = _behind_a_document(rows, False, **more)
+    kl, q_lens = np.asarray(args[5]), np.diff(np.asarray(kw["cu_q_lens"]))
+    ns = int(kw["num_seqs"][0])
+
+    def led(a):
+        _, size, shared, _ = mla_attention._groups(
+            np, np.asarray(a[2]), kl, q_lens, ns, 2, PS, G)
+        return [(int(s), int(x)) for s, x in zip(size, shared) if s]
+
+    assert led(args) == groups
+    assert all(s == 1 for s, _ in led(apart))
+    got = mla_attention.mla_paged_attention(*args, interpret=True, **kw)
+    want = mla_attention.mla_paged_attention(*apart, interpret=True, **kw_a)
+    assert (got == want).all()
+    live = np.flatnonzero(kl[:ns] > 0)
+    assert (np.abs(np.asarray(got, np.float32))[
+        np.asarray(kw["cu_q_lens"])[live]].max(axis=(1, 2)) > 0.1).all()
+    assert not np.asarray(got[n:], np.float32).any()  # rows no sequence owns
+    ref = ragged_paged_attention_xla(*args, **kw)
+    rows = np.asarray(kw["cu_q_lens"])[live]  # an idle seat's row is nobody's
+    assert _worst(got[rows].astype(jnp.float32),
+                  ref[rows].astype(jnp.float32)) < 2e-2
+
+
+def test_a_program_derives_the_groups_once_and_every_layer_takes_them(
+        params, tokens, want):
+    """`forward_core` asks the impl's ``plan`` once, before its layers, from
+    the tables as the engine packed them (-1 where unmapped), and hands what
+    it returns to each layer's call: the same logits as the calls that derive
+    their own."""
+    asked, given = [], []
+
+    def kernel(*a, **kw):
+        given.append(kw.get("groups"))
+        return mla_attention.mla_paged_attention(*a, interpret=True, **kw)
+
+    def plan(page_tables, *a):
+        asked.append(page_tables.shape)
+        return mla_attention.plan(page_tables, *a)
+
+    kernel.plan = plan
+    chunks = (13, 20) + (1,) * 3
+    got = _serve(params, tokens[:36], chunks, attn_impl=kernel)
+    # one trace serves every step: one plan, and a call a stack (the dense
+    # layer, the scanned mixture layers), each with the plan's three arrays
+    assert asked == [(4, 16)] and len(given) == 2
+    assert all(g is not None and [x.shape for x in g] == [
+        (4, mla_attention.GROUP_ROWS), (4,), (4,)] for g in given)
+    assert _worst(got, want[:36]) < TOLERANCE
+    own = _serve(params, tokens[:36], chunks, attn_impl=lambda *a, **kw: (
+        mla_attention.mla_paged_attention(*a, interpret=True, **kw)))
+    assert (got == own).all()
+    impl = _engine(attn_impl="pallas").backends.attn_impl
+    assert impl.plan is mla_attention.plan
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_grouping_rule_agrees_with_its_numpy_twin(seed):
+    """Random tables of rows behind random documents to random extents, with
+    chunks, idle seats and rows past ``num_seqs`` among them: both forms of
+    the rule give the same groups, a group's rows name its shared blocks
+    alike, and no block past a row's own walk is ever called shared, though
+    clamped ``-1`` entries make the unmapped blocks of two rows look alike."""
+    rng = np.random.default_rng(seed)
+    B, maxp, bkv = 16, 24, 2
+    docs = rng.permutation(np.arange(1, 3 * maxp + 1)).reshape(3, maxp)
+    pt, kl = np.full((B, maxp), -1, np.int32), np.zeros(B, np.int32)
+    at = 500
+    for b in range(B):
+        held = int(rng.integers(0, 9)) * int(rng.integers(0, 2))
+        past = int(rng.integers(1, 5 * PS))
+        n = held + -(-past // PS)
+        pt[b, :held] = docs[rng.integers(0, 3), :held]
+        pt[b, held:n] = at + np.arange(n - held)
+        at += n
+        kl[b] = (held * PS + past) * int(rng.integers(0, 5) > 0)
+    q_lens = np.where(rng.integers(0, 6, B) > 0, 1, 7)
+    ns = int(rng.integers(B - 3, B + 1))
+    want = mla_attention._groups(np, pt, kl, q_lens, ns, bkv, PS, G)
+    got = jax.jit(lambda *a: mla_attention._groups(jnp, *a, bkv, PS, G))(
+        jnp.asarray(pt), jnp.asarray(kl), jnp.asarray(q_lens),
+        jnp.asarray([ns]))
+    for a, b in zip(got, want):
+        assert (np.asarray(a) == b).all()
+    members, size, shared, n_kv = want
+    one = (np.arange(B) < ns) & (q_lens == 1) & (kl > 0)
+    assert sorted(int(m) for b in np.flatnonzero(size)
+                  for m in members[b, :size[b]]) == list(np.flatnonzero(one))
+    blocks = np.maximum(pt, 0).reshape(B, -1, bkv)
+    for b in np.flatnonzero(size):
+        mine = members[b, :size[b]]
+        assert size[b] <= G and mine[0] == b
+        assert shared[b] <= n_kv[mine].min()  # never an unmapped block
+        assert (blocks[mine, :shared[b]] == blocks[b, :shared[b]]).all()
+    assert (size > 1).any() or seed  # the first table does form groups
+
+
+def test_the_counter_books_a_shared_block_once():
+    """Four rows behind 16 blocks with one block of their own each: 68 blocks
+    once a row, 20 fetched; the same rows on copies of their own, 68 and 68;
+    a chunk is not a decode row."""
+    ps, bkv, maxp = 16, 64, 1280
+    assert mla_attention.pick_block_sizes(64, 64, ps, maxp)[0] == bkv
+    pt = np.full((5, maxp), -1, np.int32)
+    pt[:4, :16 * bkv] = np.arange(16 * bkv)
+    for b in range(4):
+        pt[b, 16 * bkv:16 * bkv + 3] = 5000 + 10 * b + np.arange(3)
+    pt[4, :17 * bkv] = np.arange(17 * bkv)
+    kl = np.array([16 * 1024 + 40] * 4 + [17 * 1024], np.int64)
+    q = np.array([1, 1, 1, 1, 128])
+    assert mla_attention.decode_kv_blocks(pt, kl, q, ps) == (68, 20)
+    apart = pt.copy()
+    for b in range(4):
+        apart[b, :16 * bkv] += 10000 * (b + 1)
+    assert mla_attention.decode_kv_blocks(apart, kl, q, ps) == (68, 68)
+    assert mla_attention.decode_kv_blocks(pt[:4], kl[:4] * 0, q[:4], ps) == (
+        0, 0)
+
+
 # 20 heads over rows of 512 value lanes + 64 rope lanes in 640, as
 # glm-4.7-flash has them: decode rows, a chunk behind a cached prefix that is
 # no multiple of bq, a chunk that starts its row, a seat nobody has (and -1
@@ -484,8 +693,9 @@ def test_the_folded_kernel_equals_the_xla_gather(small_blocks, monkeypatch,
         return mla_attention.mla_paged_attention(
             *a, rank=64, interpret=True, **kw)
 
+    # a group's stacked one-query rows, or a chunk's folded query block
     assert _scratch_rows(kernel, *args) == max(
-        mla_attention.padded_heads(heads),
+        mla_attention.GROUP_ROWS * mla_attention.padded_heads(heads),
         bq * mla_attention.chunk_fold(bq, heads))
     want = ragged_paged_attention_xla(*args, **kw)
     got = kernel(*args)
@@ -621,6 +831,27 @@ def test_the_pallas_engine_names_the_latent_kernel_on_both_programs(served,
     assert eng.generate(prompts, GREEDY) == served[1]
     assert served[0].attn_backend == "xla_mla_absorbed"
     assert served[0].attn_geometry == "none"
+
+
+def test_the_pallas_engine_walks_a_cached_prefix_once_and_books_it(
+        small_blocks, served, prompts):
+    """Three sequences behind one cached 64-token prefix, 8 tokens a KV
+    block: the second pass's decode rows stand behind the same pages, the
+    kernel walks them as one group, the tokens are the XLA engine's, and the
+    counter says what was fetched. An engine on another backend books none."""
+    eng = _engine(attn_impl="pallas")
+    assert eng.attn_geometry == "unified=2x16 decode=2x1 rows=64 v=128"
+    name = "llmd_tpu:latent_decode_kv_blocks_total"
+    assert eng.generate(prompts, GREEDY) == served[1]
+    cold = _series(eng, name)
+    assert eng.generate(prompts, GREEDY) == served[1]
+    both = _series(eng, name)
+    rows, fetched = (both[name + '{blocks="%s"}' % k]
+                     - cold[name + '{blocks="%s"}' % k]
+                     for k in ("rows", "fetched"))
+    assert 0 < fetched < 0.6 * rows  # 8 of a row's 10-13 blocks are shared
+    assert cold[name + '{blocks="fetched"}'] <= cold[name + '{blocks="rows"}']
+    assert not _series(served[0], name)
 
 
 def test_lora_on_the_family_is_refused_by_name():

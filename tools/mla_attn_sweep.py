@@ -17,6 +17,17 @@ be 0.0). Beside them the call's byte and operation floors
 (`perfbench/kernels/mla_attention.py`: what the benchmark's roofline metrics
 divide by). ``*`` marks the rule's pair (`pick_block_sizes`).
 
+``--shared <lanes a document>`` lays the rows out as the cell's traffic does
+(every so many consecutive rows name the same pages for their first 16,384
+tokens and own the rest; without it every row owns random pages, which no
+group can form over), ``--groups 4,8`` times the kernel at those
+``GROUP_ROWS``, and ``--parent <file>`` (the parent commit's
+``ops/mla_attention.py``, e.g. ``git show HEAD~1:llmd_tpu/ops/mla_attention.py
+> .scratch/parent/mla_attention.py``) times that file in the padded kernel's
+place in the same chip call, every row's ``diff`` against it (must be 0.0);
+``kv_blocks`` is what the counter's twin books for the layout (blocks once a
+row, blocks fetched), ``--shapes decode`` keeps to one shape.
+
 Before the sweep, four checks that need the chip: the kernel against the XLA
 gather on a short mixed batch (bf16; the largest difference and the
 reference's own scale), the same batch through the padded kernel, a chunk
@@ -26,6 +37,8 @@ the decode call's geometry against the same row through the unified step's
 beside it, on its chunking or on the program that decoded it).
 
     python tools/mla_attn_sweep.py --bkv 64         # on the chip, ~4 min
+    python tools/mla_attn_sweep.py --bkv 64 --bq 16 --shared 4 --groups 4,8 \
+        --parent .scratch/parent/mla_attention.py   # ~4 min
     python tools/mla_attn_sweep.py --compile-only   # here: what Mosaic takes
 """
 
@@ -45,17 +58,24 @@ H, DHP, RANK, ROPE, PS = 20, 640, 512, 64, 16
 SCALE = (192 + 64) ** -0.5
 
 
-def batch(rng, np, q_lens, kv_lens, N, B, maxp, pages):
+DOC = 16384  # tokens of a shared document (`docs-sessions-closed`)
+
+
+def batch(rng, np, q_lens, kv_lens, N, B, maxp, pages, shared=0):
     """(page_tables, positions, seq_slots, kv_lens, cu_q_lens, num_seqs) of a
     flat batch whose rows own random pages (disjoint while the pool lasts:
-    the cell's rows share their documents' pages, 1.1 M tokens of context
-    over a pool of 459k)."""
+    1.1 M tokens of context over a pool of 459k). With ``shared`` lanes a
+    document, every ``shared`` consecutive rows name the same pages for their
+    first ``DOC`` tokens, as the cell's rows behind one cached document do,
+    and own the rest."""
     pt = np.full((B, maxp), -1, np.int32)
     perm, at = rng.permutation(pages), 0
     for b, kl in enumerate(kv_lens):
-        n = -(-kl // PS)
-        pt[b, :n] = perm[(at + np.arange(n)) % pages]
-        at += n
+        n, lead = -(-kl // PS), b - b % shared if shared else b
+        doc = DOC // PS if lead != b and min(kl, kv_lens[lead]) >= DOC else 0
+        pt[b, :doc] = pt[lead, :doc]
+        pt[b, doc:n] = perm[(at + np.arange(n - doc)) % pages]
+        at += n - doc
     cu = np.zeros(B + 1, np.int32)
     cu[1:len(q_lens) + 1] = np.cumsum(q_lens)
     cu[len(q_lens) + 1:] = cu[len(q_lens)]
@@ -73,6 +93,16 @@ def main() -> int:
     ap.add_argument("--bkv", default="16,32,64")
     ap.add_argument("--bq", default="8,16,32")
     ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--shapes", default="decode,unified128,unified256")
+    ap.add_argument("--shared", type=int, default=0,
+                    help="lanes a document: rows that share their first "
+                    "16,384 tokens' pages (0: every row owns its pages)")
+    ap.add_argument("--groups", default="",
+                    help="one-query rows a group to time, e.g. 4,8 "
+                    "(default: the kernel's own GROUP_ROWS)")
+    ap.add_argument("--parent", default="",
+                    help="the parent commit's ops/mla_attention.py: timed in "
+                    "the padded kernel's place, every row's diff against it")
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--checks-only", action="store_true")
     ap.add_argument("--cpu", action="store_true")
@@ -98,7 +128,7 @@ def main() -> int:
     with open(os.path.join(ROOT, "perfbench", "peaks.json")) as f:
         peaks = json.load(f)["TPU v5 lite"]
 
-    def call(q, pool, b, interpret=False, rank=RANK):
+    def call(q, pool, b, interpret=False, rank=RANK, mod=mod):
         pt, pos, slots, kl, cu, ns = (jnp.asarray(a) for a in b)
         return mod.mla_paged_attention(
             q, pool, pt, pos, slots, kl, scale=SCALE, cu_q_lens=cu,
@@ -109,9 +139,22 @@ def main() -> int:
         return call(jnp.pad(q, ((0, 0), (0, mod.HEAD_TILE - H), (0, 0))),
                     pool, b, interpret, rank=None)[:, :H]
 
+    # what a timed row is held against: the parent commit's file, or the
+    # padded kernel
+    versus, other = "padded", padded
+    if args.parent:
+        import importlib.util
+
+        spec = importlib.util.spec_from_file_location("parent_mla",
+                                                      args.parent)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+        versus, other = "parent", functools.partial(call, mod=parent)
+
     def geometry(bkv, bq):
-        mod.pick_block_sizes = lambda n, rows, ps, mp: (
-            bkv, 1 if n <= rows else min(bq, n))
+        for m in (mod, parent) if args.parent else (mod,):
+            m.pick_block_sizes = lambda n, rows, ps, mp: (
+                bkv, 1 if n <= rows else min(bq, n))
 
     if args.compile_only:
         from jax.experimental import topologies
@@ -210,50 +253,64 @@ def main() -> int:
     shapes = {"decode": ([1] * 64, ctx, 64),
               "unified128": ([1] * 63 + [128], ctx, 256),
               "unified256": ([1] * 63 + [256], ctx, 320)}
+    own_groups = mod.GROUP_ROWS
+    groups = [int(g) for g in args.groups.split(",") if g] or [own_groups]
     for name, (q_lens, kv_lens, N) in shapes.items():
-        b = batch(rng, np, q_lens, kv_lens, N, B, maxp, pages)
+        if name not in args.shapes.split(","):
+            continue
+        b = batch(rng, np, q_lens, kv_lens, N, B, maxp, pages, args.shared)
         q = queries(N)
-        S, Q = float(sum(kv_lens)), float(sum(q_lens))
+        # the bytes' demand: a shared document's tokens once
+        first = [int(p) for p in b[0][:len(kv_lens), 0]]
+        S = float(sum(kv_lens) - DOC * (len(first) - len(set(first))))
+        Q = float(sum(q_lens))
         P = float(sum(ql * kl - ql * (ql - 1) // 2
                       for ql, kl in zip(q_lens, kv_lens)))
-        ops, byts = cost(S, Q, P, H, RANK, ROPE)
+        ops, byts = cost(float(sum(kv_lens)), Q, P, H, RANK, ROPE,
+                         unique_ctx=S)
         floors = {"bytes_us": byts / peaks["hbm_bytes_per_s"] * 1e6,
                   "ops_us": ops / peaks["bf16_flops"] * 1e6}
         print(json.dumps({"shape": name, "context_tokens": S, "queries": Q,
-                          "pairs": P, **floors}), flush=True)
+                          "pairs": P, "shared": args.shared, **floors}),
+              flush=True)
         bqs = [1] if name == "decode" else list(map(int, args.bq.split(",")))
-        for bkv in map(int, args.bkv.split(",")):
-            for bq in bqs:
-                geometry(bkv, bq)
-                try:
-                    us, first_s, outs = {}, {}, {}
-                    for kind, fn in (("a_call", call), ("padded", padded)):
-                        f = jax.jit(functools.partial(fn, b=b,
-                                                      interpret=interp))
-                        t = time.time()
-                        f(q, pool).block_until_ready()
-                        first_s[kind] = round(time.time() - t, 2)
-                        t = time.time()
-                        for _ in range(args.reps):
-                            out = f(q, pool)
-                        out.block_until_ready()
-                        us[kind] = (time.time() - t) / args.reps * 1e6
-                        outs[kind] = out[..., :RANK].astype(jnp.float32)
-                    mark = "*" if (bkv, bq) == rule(N, B, PS, maxp) else ""
-                    print(json.dumps({
-                        "shape": name, "bkv": bkv, "bq": bq, "rule": mark,
-                        "us_a_call": round(us["a_call"], 1),
-                        "us_padded": round(us["padded"], 1),
-                        "diff": float(jnp.abs(outs["a_call"]
-                                              - outs["padded"]).max()),
-                        "first_call_s": first_s,
-                        "roofline_share": round(
-                            max(floors.values()) / us["a_call"], 3)}),
-                        flush=True)
-                except Exception as e:  # noqa: BLE001: the compiler's words
-                    print(json.dumps({"shape": name, "bkv": bkv, "bq": bq,
-                                      "refused": str(e)[-400:]}), flush=True)
-    mod.pick_block_sizes = rule
+        for bkv, bq, G in ((bkv, bq, G)
+                           for bkv in map(int, args.bkv.split(","))
+                           for bq in bqs for G in groups):
+            geometry(bkv, bq)
+            mod.GROUP_ROWS = G
+            try:
+                us, first_s, outs = {}, {}, {}
+                for kind, fn in (("a_call", call), (versus, other)):
+                    f = jax.jit(functools.partial(fn, b=b,
+                                                  interpret=interp))
+                    t = time.time()
+                    f(q, pool).block_until_ready()
+                    first_s[kind] = round(time.time() - t, 2)
+                    t = time.time()
+                    for _ in range(args.reps):
+                        out = f(q, pool)
+                    out.block_until_ready()
+                    us[kind] = (time.time() - t) / args.reps * 1e6
+                    outs[kind] = out[..., :RANK].astype(jnp.float32)
+                mark = "*" if (bkv, bq) == rule(N, B, PS, maxp) else ""
+                rows, fetched = mod.decode_kv_blocks(
+                    b[0], b[3], np.diff(b[4]), PS)
+                print(json.dumps({
+                    "shape": name, "bkv": bkv, "bq": bq, "rule": mark,
+                    "G": G, "kv_blocks": [rows, fetched],
+                    "us_a_call": round(us["a_call"], 1),
+                    "us_" + versus: round(us[versus], 1),
+                    "diff": float(jnp.abs(outs["a_call"]
+                                          - outs[versus]).max()),
+                    "first_call_s": first_s,
+                    "roofline_share": round(
+                        max(floors.values()) / us["a_call"], 3)}),
+                    flush=True)
+            except Exception as e:  # noqa: BLE001: the compiler's words
+                print(json.dumps({"shape": name, "bkv": bkv, "bq": bq,
+                                  "refused": str(e)[-400:]}), flush=True)
+    mod.pick_block_sizes, mod.GROUP_ROWS = rule, own_groups
     return 0
 
 
