@@ -46,6 +46,9 @@ class Backends:
     window_align: int  # pages a window layer's page table is shifted by
     compiler_options: Optional[dict]  # of every step program's jit
     moe_gemm_plan: Optional[Callable]  # `bank_fetch_plan` of a unified step
+    # (KV block pages, rows a group) of the kernel that walks its one-query
+    # rows in groups (`ops/row_groups.py`); None where none does
+    decode_groups: Optional[tuple[int, int]]
     # labels, as the engine exports them
     attn_backend: str
     attn_fallback_reason: Optional[str]
@@ -88,6 +91,11 @@ def resolve(model_cfg: ModelConfig, engine_cfg: EngineConfig, mesh, *,
     # the fused-decode-shaped programs take the same impl: the ragged
     # kernels (GQA and latent) serve one-row-a-sequence calls too
     attn_decode = attn
+    if gqa_kernel and getattr(attn, "plan", None):
+        # (a packed pool's wrapper has no plan: its rows keep the upstream
+        # call) every row of the fused call brings one query
+        attn_decode = functools.partial(attn, one_query_rows=True)
+        attn_decode.plan = attn.plan
     if model_cfg.has_recurrent and pallas_attn:
         # a recurrent layer carries the last bit of an attention layer's
         # result on, so a prompt's chunks are handed to the kernel cut at
@@ -145,6 +153,19 @@ def resolve(model_cfg: ModelConfig, engine_cfg: EngineConfig, mesh, *,
     moe_gemm_geometry, moe_gemm_plan = _moe_gemm_label_and_plan(
         model_cfg, engine_cfg, moe_backend, moe_dispatch,
         plannable=mesh is None and eplb_slots is None)
+    decode_groups = None
+    if attn_backend == "pallas_mla_ragged_paged_attention":
+        from llmd_tpu.ops import mla_attention
+
+        decode_groups = (mla_attention.pick_block_sizes(
+            0, 0, engine_cfg.page_size, engine_cfg.max_pages_per_seq)[0],
+            mla_attention.GROUP_ROWS)
+    elif getattr(attn, "plan", None):  # the GQA rows kernel serves
+        from llmd_tpu.ops.paged_attention import GROUP_ROWS
+
+        decode_groups = (window_align, GROUP_ROWS)
+        # the rows kernel serves the one-query rows, so many a group
+        attn_geometry += f" groups={decode_groups[1]}"
     compiler_options = None
     if model_cfg.moe_scoring == "sigmoid" or model_cfg.has_lightning:
         # Every rounding the program states is made. Left free, XLA keeps
@@ -164,7 +185,7 @@ def resolve(model_cfg: ModelConfig, engine_cfg: EngineConfig, mesh, *,
         core_kwargs=core_kwargs, ring_attn_impl=ring,
         pallas_attn=pallas_attn, pallas_interpret=interpret,
         window_align=window_align, compiler_options=compiler_options,
-        moe_gemm_plan=moe_gemm_plan,
+        moe_gemm_plan=moe_gemm_plan, decode_groups=decode_groups,
         attn_backend=attn_backend, attn_fallback_reason=attn_reason,
         attn_geometry=attn_geometry,
         moe_backend=moe_backend, moe_fallback_reason=moe_reason,
@@ -205,10 +226,26 @@ def _attn_impl(model_cfg: ModelConfig, engine_cfg: EngineConfig, mesh,
     if mode == "auto" and platform != "tpu":
         return (ragged_paged_attention_xla, "xla_reference",
                 f"backend={platform} (non-TPU)")
-    from llmd_tpu.ops.paged_attention import paged_attention_tpu
+    from llmd_tpu.ops import paged_attention as pa
 
-    return (functools.partial(paged_attention_tpu, mesh=mesh),
-            "pallas_ragged_paged_attention", None)
+    heads_per_kv = model_cfg.num_heads // model_cfg.num_kv_heads
+    # a model with recurrent layers reuses no prefix (engine.py: no two of
+    # its rows name one page) and keeps the upstream call for every row; of
+    # the rest, the layouts the sweep passed hand their one-query rows to
+    # the repo's kernel, in groups derived once a program
+    if model_cfg.has_recurrent or not pa.rows_kernel_serves(
+            heads_per_kv, _pool_dtype(model_cfg, engine_cfg), mesh):
+        impl = functools.partial(pa.paged_attention_tpu, mesh=mesh)
+    else:
+        impl = functools.partial(pa.paged_attention_tpu, mesh=mesh,
+                                 interpret=interpret)
+        impl.plan = functools.partial(pa.plan, heads_per_kv=heads_per_kv)
+    return impl, "pallas_ragged_paged_attention", None
+
+
+def _pool_dtype(model_cfg: ModelConfig, engine_cfg: EngineConfig):
+    return (jnp.float8_e4m3fn if engine_cfg.kv_cache_dtype == "fp8"
+            else model_cfg.jax_dtype)
 
 
 def _attn_blocks_label(model_cfg: ModelConfig, engine_cfg: EngineConfig,
@@ -224,7 +261,9 @@ def _attn_blocks_label(model_cfg: ModelConfig, engine_cfg: EngineConfig,
     the value lanes, ``rows=320 v=512``; ``none`` where another backend
     serves. It is a function of static shapes, so it is known here. A
     model with window layers adds the period of windows its layers are
-    traced with (any backend), as ``window=0,4096,4096,4096``."""
+    traced with (any backend), as ``window=0,4096,4096,4096``. (Where the
+    GQA kernel's one-query rows go to the repo's rows kernel, `resolve` adds
+    the rows a group, ``groups=8``.)"""
     window = (" window=" + ",".join(map(str, model_cfg.attn_window_pattern))
               if model_cfg.has_window else "")
     programs = (("unified", engine_cfg.batched_tokens),

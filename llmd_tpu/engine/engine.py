@@ -901,20 +901,24 @@ class LLMEngine:
                        seats=None, steps=None) -> None:
         """``attn_kv_tokens_total``, ``attn_query_tokens_total`` and
         ``attn_query_key_pairs_total`` of one dispatched call, from the
-        lengths the step already packed; under the latent kernel also
-        ``latent_decode_kv_blocks_total`` of its one-query rows, from their
-        page tables (the rows' own, or all ``seats``' of which the rows take
-        theirs; a fused call at its first step)."""
+        lengths the step already packed; under a kernel that walks its
+        one-query rows in groups (the latent kernel, the GQA rows kernel)
+        also ``latent_`` / ``attn_decode_kv_blocks_total`` of those rows,
+        from their page tables (the rows' own, or all ``seats``' of which the
+        rows take theirs; a fused call at its first step)."""
         kv, q = np.asarray(kv_lens, np.int64), np.asarray(q_lens, np.int64)
-        if self.attn_backend == "pallas_mla_ragged_paged_attention":
-            from llmd_tpu.ops.mla_attention import decode_kv_blocks
+        if self.backends.decode_groups is not None:
+            from llmd_tpu.ops.row_groups import decode_kv_blocks
 
             if seats is not None:
                 page_tables = page_tables[seats]
+            series = (self.metrics.latent_decode_kv_blocks
+                      if self.model_cfg.is_mla
+                      else self.metrics.attn_decode_kv_blocks)
             for blocks, n in zip(("rows", "fetched"), decode_kv_blocks(
-                    page_tables, kv, q, self.cfg.page_size)):
-                self.metrics.latent_decode_kv_blocks.labels(
-                    blocks=blocks).inc(n)
+                    page_tables, kv, q, self.cfg.page_size,
+                    *self.backends.decode_groups)):
+                series.labels(blocks=blocks).inc(n)
         for kind, n in attn_kv_tokens(self.model_cfg, kv_lens, q_lens,
                                       self.cfg.page_size,
                                       self.backends.window_align).items():

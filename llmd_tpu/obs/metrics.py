@@ -672,9 +672,22 @@ class EngineMetrics:
             "first step): blocks=rows the KV blocks the rows walk, once a "
             "row; blocks=fetched the KV blocks the kernel fetches, a block "
             "that the rows of a group name alike once a group "
-            "(ops/mla_attention.decode_kv_blocks, from the page tables the "
+            "(ops/row_groups.decode_kv_blocks, from the page tables the "
             "step packed). Booked only where the attention backend is that "
             "kernel",
+            labelnames=("blocks",))
+        self.attn_decode_kv_blocks = reg.counter(
+            "llmd_tpu:attn_decode_kv_blocks_total",
+            "Of the GQA attention kernel's one-query rows where the repo's "
+            "rows kernel serves them (ragged_paged_attention_rows: a "
+            "unified step's decode rows and a fused decode call's, a fused "
+            "call at its first step): blocks=rows the KV blocks the rows "
+            "walk, once a row; blocks=fetched the KV blocks a full-attention "
+            "layer's call fetches, a block that the rows of a group name "
+            "alike once a group (ops/row_groups.decode_kv_blocks, from the "
+            "page tables the step packed; a window layer's rows keep the "
+            "upstream call and are not counted). Booked only where that "
+            "kernel serves (engine_attn_backend's geometry ends in groups=)",
             labelnames=("blocks",))
         self.program_rows = reg.counter(
             "llmd_tpu:program_rows_total",

@@ -24,7 +24,8 @@ what the row really holds, with q, the pool and the output left in HBM:
   block is spread back the same way: the two products of every KV block then
   see no padded head (`chunk_fold`),
 - one-query rows (a decode row: 32 padded head rows) walk in groups of up to
-  ``GROUP_ROWS`` whose tables start on the same pages (`_groups`, derived from
+  ``GROUP_ROWS`` whose tables start on the same pages (`ops/row_groups.py`,
+  the rule the GQA kernel's one-query rows share, derived from
   the page tables with ``jax.numpy`` once a program and scalar-prefetched: no
   option, rows behind one cached document or system prompt are seen as they
   come). The group's first row leads: the leading KV blocks all its members
@@ -125,6 +126,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from llmd_tpu.ops.paged_attention import VMEM_LIMIT, shard_over_heads
+from llmd_tpu.ops.row_groups import row_groups
 
 NEG_INF = -0.7 * float(np.finfo(np.float32).max)
 _MINOR = 128  # lane width of the m / l scratch rows; column 0 is meaningful
@@ -187,73 +189,8 @@ def value_lanes(rank: "int | None", lanes: int) -> int:
     return lanes if rank is None else min(lanes, -(-rank // _MINOR) * _MINOR)
 
 
-def _groups(xp, page_tables, kv_lens, q_lens, num_seqs, bkv: int, ps: int,
-            G: int):
-    """The grouping rule, over ``numpy`` or ``jax.numpy`` (``xp``). One-query
-    rows whose tables start on the same page form a sharing set, cut in row
-    order into groups of up to ``G``; a group's first row leads it.
-    Returns ``(members [B, G], size [B], shared [B], n_kv [B])``: a leader's
-    members (itself first, the seats past ``size`` itself again), its size
-    (0 for every row that leads nothing: a member, a chunk, an idle seat) and
-    the leading KV blocks all its members name alike below each one's own
-    ``n_kv``, the blocks a one-query row walks. The unmapped entries of two
-    rows look alike (``-1``, or page 0 once the kernel's call has clamped
-    them): only blocks below ``n_kv`` count."""
-    B, maxp = page_tables.shape
-    rows, seats, walk = xp.arange(B), xp.arange(G), xp.arange(maxp // bkv)
-    blocks = page_tables.reshape(B, maxp // bkv, bkv)
-    n_kv = xp.maximum(kv_lens - 1, 0) // (bkv * ps) + 1
-    one = (rows < num_seqs) & (q_lens == 1) & (kv_lens > 0)
-    first = blocks[:, 0, 0]  # a prefix cache shares a page with all before it
-    same = one[:, None] & one[None, :] & (first[:, None] == first[None, :])
-    # a row's place in its sharing set, and the rows up to G places on from it
-    pos = (same & (rows[None, :] < rows[:, None])).sum(1)
-    hit = same[:, None, :] & (
-        pos[None, None, :] == (pos[:, None] + seats)[:, :, None])
-    found = hit.any(-1)
-    members = xp.where(found, hit.argmax(-1), rows[:, None])
-    size = xp.where(one & (pos % G == 0), found.sum(-1), 0)
-    leader = xp.where(one, (same & (
-        pos[None, :] == (pos - pos % G)[:, None])).argmax(-1), rows)
-    alike = (blocks == blocks[leader]).all(-1) & (
-        walk < xp.minimum(n_kv, n_kv[leader])[:, None])
-    # the first block a row names otherwise than its leader, then the least
-    # over a leader's members
-    shared = xp.where(alike, maxp // bkv, walk).min(-1)[members].min(-1)
-    shared = xp.where(size > 1, shared, 0)  # a row alone walks its own blocks
-    return (members.astype(xp.int32), size.astype(xp.int32),
-            shared.astype(xp.int32), n_kv)
-
-
-def row_groups(page_tables, kv_lens, cu_q_lens, num_seqs, page_size: int):
-    """``(members, size, shared)`` of a call (`_groups`, on the device): what
-    the kernel is told of its one-query rows. A function of the batch's layout
-    alone and the same for every layer's slice of the pool (a layer's page
-    ids are the batch's plus the layer's offset), so a program derives it
-    once and hands it to each layer's call (``plan``)."""
-    bkv, _ = pick_block_sizes(0, 0, page_size, page_tables.shape[1])
-    return _groups(jnp, page_tables, kv_lens, cu_q_lens[1:] - cu_q_lens[:-1],
-                   num_seqs, bkv, page_size, GROUP_ROWS)[:3]
-
-
-def decode_kv_blocks(page_tables, kv_lens, q_lens, page_size: int
-                     ) -> tuple[int, int]:
-    """(KV blocks once a row, KV blocks the kernel fetches) of a call's
-    one-query rows, from the page tables the step packed (numpy arrays, all
-    three): the numpy twin of what `row_groups` derives on the device
-    (``latent_decode_kv_blocks_total``)."""
-    bkv, _ = pick_block_sizes(0, 0, page_size, page_tables.shape[1])
-    members, size, shared, n_kv = _groups(
-        np, page_tables, kv_lens, q_lens, len(kv_lens), bkv, page_size,
-        GROUP_ROWS)
-    tails = (n_kv[members] - shared[:, None]) * (
-        np.arange(GROUP_ROWS) < size[:, None])
-    return (int(n_kv[(q_lens == 1) & (kv_lens > 0)].sum()),
-            int((shared * (size > 0) + tails.sum(1)).sum()))
-
-
 def _kernel(pt_ref, kv_lens_ref, cu_ref, nseq_ref,  # scalar prefetch (SMEM)
-            member_ref, size_ref, shared_ref,  # the groups (`_groups`)
+            member_ref, size_ref, shared_ref,  # the groups (`row_groups`)
             q_hbm, pool_hbm, o_init_hbm,  # HBM
             o_hbm,  # HBM, aliased to o_init_hbm
             q_buf, kv_buf, o_buf, m_ref, l_ref, acc_ref,  # VMEM scratch
@@ -476,8 +413,8 @@ def mla_ragged_pallas(
     B, maxp = page_tables.shape
     bkv, bq = pick_block_sizes(N, B, ps, maxp)
     Hp, V, hq = padded_heads(H), value_lanes(rank, Dhp), chunk_fold(bq, H)
-    members, size, shared = groups or row_groups(
-        page_tables, kv_lens, cu_q_lens, num_seqs, ps)
+    members, size, shared = groups or plan(
+        page_tables, kv_lens, cu_q_lens, num_seqs, ps)["groups"]
     G = members.shape[1]
     # heads padded to whole tiles, and bq rows past the batch's end so that a
     # query block's load stays in bounds (what it reads there is not stored)
@@ -561,5 +498,6 @@ def plan(page_tables, kv_lens, cu_q_lens, num_seqs, page_size: int) -> dict:
     a program, before its layers: keyword arguments of every layer's call."""
     # from the tables as the engine packed them: an unmapped entry (-1) is
     # no layer's page 0, which a layer's clamped table could not tell apart
+    bkv, _ = pick_block_sizes(0, 0, page_size, page_tables.shape[1])
     return {"groups": row_groups(page_tables, kv_lens, cu_q_lens, num_seqs,
-                                 page_size)}
+                                 page_size, bkv, GROUP_ROWS)}

@@ -126,7 +126,65 @@ module owns the serving-stack integration:
   (`split_rows_at_kv_blocks`), up to twice the rows and no longer decode rows
   first, and keeps the one call (two attention layers of 28: 0.09 ms a step).
   After it Mistral's decode rows in a unified step read at 85% of their
-  KV-byte bound (PERF.md section 6, PR 44).
+  KV-byte bound (PERF.md section 6, PR 44): a bound that counted a shared
+  prompt once a row ("The one-query rows on a kernel of the repo's", below).
+
+  **The one-query rows on a kernel of the repo's** (`rows_attention`; PR 50).
+  Rows behind one tenant's cached system prompt name the same pages first,
+  and the upstream kernel walks every row's whole table: 8 lanes behind a
+  2,048-token prompt fetch its 4 KV blocks 8 times. The one-query call (a
+  unified step's head call, the fused decode call) therefore goes to a kernel
+  that walks the rows in groups of up to `GROUP_ROWS` whose tables start
+  alike (`ops/row_groups.py`, the latent kernel's rule, derived once a
+  program: `plan`): a KV block the group's rows all name is fetched once and
+  multiplied, a KV head at a time, with their query heads stacked as a query
+  block's tokens (``[G * H/Hk, Dh]``), then each row walks its own blocks,
+  its state stored to its own rows. The arithmetic of a block is the
+  upstream's statement for statement (`_rows_kernel`), so every row's result
+  is the upstream call's bit for bit (``diff`` 0.0 in every row below; D16
+  stays open) and cold and cached requests, and the chunks' call beside it,
+  block alike. Page copies are started 8 a loop turn and waited for once a
+  block; q and the output are whole in VMEM; idle seats of a fused call
+  (one token, no page) all name the same nothing and walk as groups too.
+  `tools/attn_sweep.py --split 1 --shared <lanes a tenant> --groups 4,8,16`
+  (chip, PR 50, TPU v5 lite; us a call, the upstream call in the same
+  process first; the cells' contexts as above, 32 live rows of 64 in
+  Mistral's decode call; blocks = KV blocks fetched / once a row; the last
+  column seconds to trace and lower a call site, upstream first in its
+  process > rows kernel; the unified step's after the decode call's in one
+  process: `rows_attention` is jitted as the upstream wrapper is, so a
+  second site of the same shape costs neither a second trace):
+
+      layout, call, lanes a tenant   upstream    G = 4     G = 8*   blocks    s
+      32/8 decode, 8                  897         562       507^    161/301  4.3>2.1
+      32/8 decode, 4                  904         562     544-557   177/301
+      32/8 decode, none shared      897-902       823     818-828   273/301
+      32/8 unified, 8              1131-1139       -        853     151/255  4.6>0.5
+      32/8 unified, 4                1131         887     892-902   167/255
+      32/8 unified, none shared    1125-1135     1128    1140-1145  255/255
+      12/2 decode, none shared      180-185     185-187   188-195   100/114  2.5>1.0
+      12/2 unified, none shared     200-204     219-224   227-234   106/106
+      28/4 full decode, 4            1324         682     816-854   211/453  4.3>2.5
+      28/4 full decode, none       1324-1335   1365-1375   1670     445/453
+      28/4 full unified, none      1861-1870   1918-1934     -      442/442
+      28/4 window decode, alone     847-857     878-887      -
+
+  (`^` G = 16 read 680 where G = 8 read 617, both at one page copy a loop
+  turn; 28/4 with all 64 copies unrolled, its best: 8 a turn reads 4% slower
+  there.) The rule
+  (`rows_kernel_serves`): a layout takes the kernel where its rows that
+  share nothing are no slower than the upstream call, within 2%, and bit for
+  bit. 32/8 passes (the decode call 9% faster unshared: 32 idle seats walk
+  as 4 groups; the unified step +0.9%). 12/2 (11-16% slower in the unified
+  step: rows of two KV blocks, where a group's fixed cost, some 0.5 us,
+  shows) and 28/4 (3-4% slower unshared, though its full layer's decode call
+  halves behind a shared prompt) keep the upstream call, as do a window
+  layer's rows (the batch's groups do not describe its shifted tables, and
+  alone they are 3% slower), a model with recurrent layers (no prefix reuse:
+  no two rows name one page), fp8 pages and a mesh. What did not move the
+  fixed cost: the groups as a loop inside one grid step (and 0.9 s more to
+  trace), the five group arrays as one scalar-prefetch operand (kept: fewer
+  operands), the rows' last keys as a column in place of a matrix (kept).
 
   **One bkv an engine.** The kernel's online softmax blocks a row's keys from
   its page table's first entry, so two programs, or a cold and a cached
@@ -155,9 +213,15 @@ prefill and decode — causality is derived as kv_len - q_len + local index).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from llmd_tpu.ops.row_groups import row_groups
 
 VMEM_LIMIT = 100 * 1024 * 1024
 
@@ -392,6 +456,326 @@ def split_rows_at_kv_blocks(page_tables, kv_lens, cu_q_lens, num_seqs,
             (num_seqs + jnp.sum(jnp.where(cut, n - 1, 0))).astype(num_seqs.dtype))
 
 
+# One-query rows a group of the rows kernel (`rows_attention`): a tenant's
+# lanes in the sessions traffic, and at four query heads a KV head the 32
+# matrix rows the upstream call's query block of 8 already multiplies. A
+# constant of the layout, swept by `tools/attn_sweep.py --groups` (module
+# docstring).
+GROUP_ROWS = 8
+
+# Query heads a KV head whose one-query rows take the rows kernel
+# (`rows_kernel_serves`): the layouts `tools/attn_sweep.py --groups` showed
+# no slower unshared (within 2%) and bit for bit on the chip. 32/8 passed;
+# 12/2 and 28/4 read the upstream call's bits too but 11-16% and 3-4% slower
+# on rows that share nothing, and keep it (module docstring).
+ROWS_KERNEL_HEADS_PER_KV = (4,)
+
+# Page copies a turn of the rows kernel's fetch loop (the upstream kernel
+# unrolls all bkv in Python, 2.2 s to trace and lower a call site at 32
+# pages): 8 read as fast as all 32 at 32/8 and trace 0.8 s a call site
+# sooner; one page a turn is a scalar loop the products cannot hide (+10%).
+PAGES_A_TURN = 8
+
+
+def rows_kernel_serves(heads_per_kv: int, cache_dtype, mesh) -> bool:
+    """Whether a layout's one-query rows go to the repo's kernel
+    (`rows_attention`) or keep the upstream call: what `tools/attn_sweep.py`
+    showed no slower on rows that share nothing (within 2%) and bit for bit
+    (module docstring). A function of what `pick_block_sizes` reads and of
+    nothing a user sets. A pool that is not bf16 (fp8 pages are dequantized
+    by the upstream kernel) and a mesh (`shard_over_heads`) keep the upstream
+    call: no cell runs them."""
+    return (mesh is None and jnp.dtype(cache_dtype) == jnp.bfloat16
+            and heads_per_kv in ROWS_KERNEL_HEADS_PER_KV)
+
+
+def plan(page_tables, kv_lens, cu_q_lens, num_seqs, page_size: int, *,
+         heads_per_kv: int) -> dict:
+    """What `models.transformer.forward_core` asks the impl for once a
+    program, before its layers: keyword arguments of every layer's call. The
+    groups of the rows the one-query call is told of (`decode_rows_and_chunks`:
+    the leading one-query rows of a unified step, every row of a fused decode
+    call), from the tables as the engine packed them (an unmapped entry, -1,
+    is no layer's page 0), as the one array the kernel prefetches: ``members
+    [B * G]``, ``size [B]``, ``shared [B]`` (`row_groups`), then the rows
+    that lead a group in row order (``[B]``; past them rows that lead
+    nothing) and how many there are (``[1]``)."""
+    _, (lens, _, cu, told), _ = decode_rows_and_chunks(
+        page_tables, kv_lens, cu_q_lens, num_seqs)
+    bkv, _ = pick_block_sizes(0, page_size, page_tables.shape[1],
+                              heads_per_kv)
+    members, size, shared = row_groups(
+        page_tables, lens, cu, told[0], page_size, bkv, GROUP_ROWS)
+    return {"groups": jnp.concatenate([
+        members.reshape(-1), size, shared,
+        jnp.argsort(size == 0, stable=True).astype(jnp.int32),
+        jnp.sum(size > 0, dtype=jnp.int32)[None]])}
+
+
+def _rows_kernel(pt_ref, kv_lens_ref, cu_ref, groups_ref,  # SMEM (`plan`)
+                 q_ref,  # [N, H, Dh] VMEM, whole
+                 pool_hbm,  # [P, ps, 2 * Hk, Dh]
+                 o_ref,  # [N, H, Dh] VMEM, whole
+                 kv_bufs, sems, l_ref, m_ref, acc_ref, q_buf, slot_ref,
+                 *, bkv: int, maxp: int, G: int, sm_scale: float,
+                 mask_value: float):
+    """The one-query rows of a call, a group a grid step (`rows_attention`).
+
+    The arithmetic of a KV block is the upstream kernel's
+    (``kernel.py::flash_attention``), statement for statement, on the shapes
+    it has there at ``num_queries_per_block = G``: a group's queries take the
+    places of a query block's tokens. What differs is which KV blocks a
+    group's rows meet and in which order each row's state is stored."""
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention.kernel import (
+        get_dtype_packing,
+    )
+
+    i = pl.program_id(0)
+    _, _, ps, planes, Dh = kv_bufs.shape
+    Hk = planes // 2
+    B, H = kv_lens_ref.shape[0], q_ref.shape[1]
+    hpk = H // Hk
+    T, R = bkv * ps, G * hpk
+    # the one array of `plan`: members, size, shared, leaders, their count
+    at_size, at_shared, at_lead = B * G, B * G + B, B * G + 2 * B
+    count = groups_ref[B * G + 3 * B]
+
+    def fetch(row, j, slot):
+        """Start the page copies of row ``row``'s KV block ``j`` into buffer
+        ``slot``."""
+        pages = math.gcd(bkv, PAGES_A_TURN)
+
+        def turn(p, _):
+            for u in range(pages):
+                pltpu.make_async_copy(
+                    pool_hbm.at[pt_ref[row * maxp + j * bkv + p * pages + u]],
+                    kv_bufs.at[slot, p * pages + u], sems.at[slot]).start()
+            return 0
+
+        if pages == bkv:
+            turn(0, 0)
+        else:
+            lax.fori_loop(0, bkv // pages, turn, 0)
+
+    def wait(slot):
+        """One wait for a buffer's bkv page copies: a wait reads the bytes
+        its descriptor names, not the pages."""
+        pltpu.make_async_copy(kv_bufs.at[slot], kv_bufs.at[slot],
+                              sems.at[slot]).wait()
+
+    @pl.when(i == 0)
+    def _first():
+        o_ref[...] = jnp.zeros_like(o_ref)  # a row no group owns stays zero
+        slot_ref[0] = 0
+
+        @pl.when(count > 0)
+        def _():
+            fetch(groups_ref[at_lead], 0, 0)
+
+    def strided_load_kv(ref, start, step):
+        # (the upstream's, bf16: a K row and the V row behind it are one
+        # uint32 row)
+        assert ref.dtype == jnp.bfloat16 and step % 2 == 0
+        b = ref.bitcast(jnp.uint32)[start // 2::step // 2, :]
+        k = pltpu.bitcast(b << 16, jnp.float32).astype(jnp.bfloat16)
+        v = pltpu.bitcast(b & jnp.uint32(0xFFFF0000),
+                          jnp.float32).astype(jnp.bfloat16)
+        return k, v
+
+    def fold_on_2nd_minor(vec):
+        if vec.shape[-2] % get_dtype_packing(vec.dtype) != 0:
+            vec = vec.astype(jnp.float32)
+        return vec.reshape(-1, vec.shape[-1])
+
+    def flash_attention(q, k, v, head_l_ref, head_m_ref, head_acc_ref, *,
+                        kv_blk_idx, kv_end, row_ids, store_start, store_end):
+        """``kernel.py::flash_attention`` with the row's own ``kv_len``,
+        last key and stored rows handed in (``kv_end``, ``row_ids``,
+        ``store_start`` / ``store_end`` in members)."""
+        kv_len_start = kv_blk_idx * T
+
+        def masked_store(ref, val, start, end, group=1):
+            iota = lax.broadcasted_iota(jnp.int32, ref.shape, 0) // group
+            pltpu.store(ref, val,
+                        mask=jnp.logical_and(iota >= start, iota < end))
+
+        def load_with_init(ref, init_val):
+            return jnp.where(kv_blk_idx == 0, jnp.full_like(ref, init_val),
+                             ref[...])
+
+        kv_mask = (lax.broadcasted_iota(jnp.int32, k.shape, 0)
+                   < kv_end - kv_len_start)
+        k = jnp.where(kv_mask, k.astype(jnp.float32), 0).astype(k.dtype)
+        v = jnp.where(kv_mask, v.astype(jnp.float32), 0).astype(v.dtype)
+        qk = jnp.einsum("nd,md->nm", q, k,
+                        preferred_element_type=jnp.float32) * sm_scale
+        col_ids = kv_len_start + lax.broadcasted_iota(jnp.int32, (R, T), 1)
+        causal_mask = row_ids < col_ids
+        qk += jnp.where(causal_mask, mask_value, 0.0)
+        m_curr = jnp.max(qk, axis=1, keepdims=True)
+        s_curr = jnp.exp(qk - m_curr)
+        qkv = jnp.dot(s_curr, v, preferred_element_type=jnp.float32)
+        lm_store_shape = head_m_ref.shape
+        m_curr = jnp.broadcast_to(m_curr, lm_store_shape)
+        l_curr = jnp.broadcast_to(s_curr.sum(axis=1, keepdims=True),
+                                  lm_store_shape)
+        m_prev = load_with_init(head_m_ref, -jnp.inf)
+        l_prev = load_with_init(head_l_ref, 0.0)
+        m_next = jnp.maximum(m_prev, m_curr)
+        masked_store(head_m_ref, m_next, store_start, store_end, hpk)
+        alpha = jnp.exp(m_prev - m_next)
+        beta = jnp.exp(m_curr - m_next)
+        l_alpha = alpha * l_prev
+        l_next = l_alpha + beta * l_curr
+        l_next_safe = jnp.where(l_next == 0.0, 1.0, l_next)
+        masked_store(head_l_ref, l_next_safe, store_start, store_end, hpk)
+
+        def broadcast_to_shape(arr, shape):
+            if arr.shape == shape:
+                return arr
+            return jnp.concatenate(
+                [arr for _ in range(shape[1] // arr.shape[1])], axis=1)
+
+        o_curr = load_with_init(head_acc_ref, 0.0).reshape(-1, Dh)
+        l_alpha = broadcast_to_shape(l_alpha, qkv.shape)
+        beta = broadcast_to_shape(beta, qkv.shape)
+        l_next_safe = broadcast_to_shape(l_next_safe, qkv.shape)
+        out = lax.div(l_alpha * o_curr + beta * qkv, l_next_safe)
+        masked_store(head_acc_ref, out.reshape(head_acc_ref.shape),
+                     store_start, store_end)
+
+    def group(b, n, shared):
+        """The ``n`` one-query rows row ``b`` leads: their ``shared`` leading
+        KV blocks fetched once for the members' queries stacked as a query
+        block's tokens, then each member's own blocks, stored to its own rows
+        of the state. One loop over the visits ``t``: the shared blocks, then
+        member after member's tail."""
+        member = [groups_ref[b * G + g] for g in range(G)]
+        lens = [kv_lens_ref[r] for r in member]  # seats past n: the leader's
+        tail = [jnp.where(g < n, (lens[g] - 1) // T + 1 - shared, 0)
+                for g in range(G)]
+        ends = [shared + sum(tail[:g + 1]) for g in range(G)]
+        longest = functools.reduce(jnp.maximum, lens)
+
+        def place(t):
+            """(page-table row, KV block, member) of visit ``t``; a shared
+            block is the leader's, member 0."""
+            past = [t >= e for e in ends[:-1]]
+            g = sum(p.astype(jnp.int32) for p in past)
+            start = shared + sum(jnp.where(p, n_t, 0)
+                                 for p, n_t in zip(past, tail))
+            return (groups_ref[b * G + g],
+                    jnp.where(t < shared, t, shared + t - start), g)
+
+        for g, r in enumerate(member):
+            q_buf[pl.ds(g, 1)] = q_ref[pl.ds(cu_ref[r], 1)]
+        # a stacked row's query position: its member's last key
+        row = lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+        row_ids = functools.reduce(
+            lambda v, g: jnp.where(row >= g * hpk, lens[g] - 1, v),
+            range(1, G), jnp.full((R, 1), lens[0] - 1, jnp.int32))
+        slot0 = slot_ref[0]
+        nxt = groups_ref[at_lead + jnp.minimum(i + 1, B - 1)]
+
+        def visit(t, here):
+            slot = (slot0 + t) % 2
+            ahead = place(t + 1)
+            more = t + 1 < ends[-1]
+
+            # the next visit's block, or the next group's first
+            @pl.when(jnp.logical_or(more, i + 1 < count))
+            def _prefetch():
+                fetch(jnp.where(more, ahead[0], nxt),
+                      jnp.where(more, ahead[1], 0), 1 - slot)
+
+            wait(slot)
+            r, j, g = here
+            together = t < shared
+            kv_ref = kv_bufs.at[slot].reshape(T * planes, Dh)
+            for h in range(Hk):
+                k, v = strided_load_kv(kv_ref, 2 * h, planes)
+                heads = slice(h * hpk, (h + 1) * hpk)
+                flash_attention(
+                    fold_on_2nd_minor(q_buf[:, heads, :]), k, v,
+                    l_ref.at[h], m_ref.at[h], acc_ref.at[:, heads, :],
+                    kv_blk_idx=j,
+                    kv_end=jnp.where(together, longest, kv_lens_ref[r]),
+                    row_ids=row_ids,
+                    store_start=jnp.where(together, 0, g),
+                    store_end=jnp.where(together, n, g + 1))
+            return ahead
+
+        # the first visit is the leader's first block, shared or its own
+        lax.fori_loop(0, ends[-1], visit, (b, jnp.int32(0), jnp.int32(0)))
+        slot_ref[0] = (slot0 + ends[-1]) % 2
+        # the leader last: the seats past n are its row again
+        for g in reversed(range(G)):
+            o_ref[pl.ds(cu_ref[member[g]], 1)] = acc_ref[pl.ds(g, 1)].astype(
+                o_ref.dtype)
+
+    @pl.when(i < count)
+    def _group():
+        b = groups_ref[at_lead + i]
+        group(b, groups_ref[at_size + b], groups_ref[at_shared + b])
+
+
+# (jitted as the upstream wrapper is: a process traces and lowers the kernel
+# once for all its call sites of one shape, the fused call's body twice over)
+@functools.partial(jax.jit, static_argnames=("sm_scale", "bkv", "interpret"))
+def rows_attention(q, layer_cache, kv_lens, page_tables, cu_q_lens, groups,
+                   *, sm_scale: float, bkv: int,
+                   interpret: bool = False) -> jax.Array:
+    """Attention of a call's one-query rows over the combined pool ``[P, ps,
+    2 * Hk, Dh]`` (K even, V odd), the rows walked in ``groups`` (`plan`'s
+    array of the call): a KV block of ``bkv`` pages that a group's rows name
+    alike is fetched once and multiplied with their query heads stacked in
+    one matrix a KV head, then each row walks its own remaining blocks. Every
+    row's result is the upstream kernel's at the same ``bkv``, bit for bit; a
+    row that shares nothing is a group of one on the same walk. A row of
+    ``q`` that no group owns (an idle seat, a row of more than one query)
+    comes back zero."""
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention.kernel import (
+        DEFAULT_MASK_VALUE,
+    )
+
+    N, H, Dh = q.shape
+    _, ps, planes, _ = layer_cache.shape
+    B, maxp = page_tables.shape
+    G = (groups.shape[0] - 1) // B - 3
+    hpk = H // (planes // 2)
+    # whole KV blocks a row of the table: a last block's entries past the
+    # table are page 0, as the unmapped ones (never attended)
+    tables = jnp.pad(page_tables, ((0, 0), (0, -maxp % bkv)))
+    whole = pl.BlockSpec((N, H, Dh), lambda i, *_: (0, 0, 0))
+    lm = pltpu.VMEM((planes // 2, G * hpk, 128), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(
+            _rows_kernel, bkv=bkv, maxp=tables.shape[1], G=G,
+            sm_scale=sm_scale, mask_value=DEFAULT_MASK_VALUE),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),  # the groups, leaders first
+            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((2, bkv, ps, planes, Dh), layer_cache.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                lm, lm,  # l, m
+                pltpu.VMEM((G, H, Dh), jnp.float32),  # acc
+                pltpu.VMEM((G, H, Dh), q.dtype),  # a group's queries
+                pltpu.SMEM((1,), jnp.int32),  # the buffer the next group reads
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        name="ragged_paged_attention_rows",
+    )(tables.reshape(-1).astype(jnp.int32), kv_lens.astype(jnp.int32),
+      cu_q_lens.astype(jnp.int32), groups, q, layer_cache)
+
+
 def paged_attention_tpu(
     q: jax.Array,  # [N, H, Dhp] flat query tokens (lane-padded)
     layer_cache: jax.Array,  # [P, ps, 2*Hk, Dhp]
@@ -408,6 +792,9 @@ def paged_attention_tpu(
     mesh=None,  # engine mesh: the kernel runs per device under shard_map
     sliding_window: "int | None" = None,  # static: a window layer's window
     split_at_kv_blocks: bool = False,  # static: `split_rows_at_kv_blocks`
+    one_query_rows: bool = False,  # static: the fused decode call's rows
+    groups=None,  # `plan` of the batch: the one-query rows' groups
+    interpret: bool = False,  # the rows kernel's; True only on the CPU
 ) -> jax.Array:
     """Uniform-signature adapter over the Pallas kernel (drop-in for
     models.transformer.ragged_paged_attention_xla on TPU).
@@ -425,7 +812,16 @@ def paged_attention_tpu(
     window (``models.transformer.window_view``, rounded down to whole KV
     blocks of ``bkv`` pages so that the blocks that remain are the unshifted
     call's and the result is its result bit for bit); causality derives from
-    ``kv_len - q_len``, which the shift leaves as it was."""
+    ``kv_len - q_len``, which the shift leaves as it was.
+
+    ``groups`` (`plan`, once a program) sends the one-query rows to the
+    repo's kernel (`rows_attention`) in the upstream call's place: a unified
+    step's leading one-query rows, and every row of a call that says its
+    rows bring one query each (``one_query_rows``: the fused decode call).
+    The groups are the batch's tables'; a window layer hands the kernel
+    shifted tables of its own, which they do not describe, and rows that
+    walk alone are no faster there than in the upstream call (module
+    docstring): its rows keep it."""
     del positions, seq_slots, chunk_k, chunk_v
     B = page_tables.shape[0]
     pairs = step_geometry(q.shape, layer_cache.shape, B, page_tables.shape[1],
@@ -472,14 +868,22 @@ def paged_attention_tpu(
                     page_tables.astype(jnp.int32), cu_q_lens.astype(jnp.int32),
                     num_seqs.astype(jnp.int32))
 
+    def rows(q, bq, kv_lens, page_tables, cu_q_lens, num_seqs):
+        if groups is None or sliding_window is not None:
+            return kernel(q, bq, kv_lens, page_tables, cu_q_lens, num_seqs)
+        return rows_attention(q, layer_cache, kv_lens, page_tables,
+                              cu_q_lens, groups, sm_scale=scale, bkv=bkv,
+                              interpret=interpret)
+
     if len(pairs) == 1:
-        return kernel(q, pairs[0][1], kv_lens, page_tables, cu_q_lens, num_seqs)
+        call = rows if one_query_rows else kernel
+        return call(q, pairs[0][1], kv_lens, page_tables, cu_q_lens, num_seqs)
     # both calls walk a row's KV blocks of bkv pages from its table's first
     # entry, as the one call did: each token's result is that call's, bit for
     # bit, from whichever call owns its row
     n_dec, decode, chunks = decode_rows_and_chunks(
         page_tables, kv_lens, cu_q_lens, num_seqs)
-    head = kernel(q[:B], pairs[0][1], *decode)
+    head = rows(q[:B], pairs[0][1], *decode)
     out = kernel(q, pairs[1][1], *chunks)
     head = jnp.where((jnp.arange(B) < n_dec)[:, None, None], head, out[:B])
     return jax.lax.dynamic_update_slice_in_dim(out, head, 0, axis=0)

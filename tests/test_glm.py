@@ -40,7 +40,7 @@ from llmd_tpu.models.config import ModelConfig  # noqa: E402
 from llmd_tpu.models.transformer import (  # noqa: E402
     ROUTER_BIAS_SCALE, forward, forward_core, init_cache, init_params,
     moe_block, ragged_paged_attention_xla, unembed)
-from llmd_tpu.ops import mla_attention  # noqa: E402
+from llmd_tpu.ops import mla_attention, row_groups  # noqa: E402
 from llmd_tpu.ops.moe_dispatch import make_sorted_dispatch  # noqa: E402
 
 with open(os.path.join(ROOT, "perfbench", "tests", "tiny-glm.json")) as f:
@@ -506,7 +506,7 @@ def test_rows_behind_one_document_equal_rows_that_own_their_copies(
     ns = int(kw["num_seqs"][0])
 
     def led(a):
-        _, size, shared, _ = mla_attention._groups(
+        _, size, shared, _ = row_groups._groups(
             np, np.asarray(a[2]), kl, q_lens, ns, 2, PS, G)
         return [(int(s), int(x)) for s, x in zip(size, shared) if s]
 
@@ -579,8 +579,8 @@ def test_the_grouping_rule_agrees_with_its_numpy_twin(seed):
         kl[b] = (held * PS + past) * int(rng.integers(0, 5) > 0)
     q_lens = np.where(rng.integers(0, 6, B) > 0, 1, 7)
     ns = int(rng.integers(B - 3, B + 1))
-    want = mla_attention._groups(np, pt, kl, q_lens, ns, bkv, PS, G)
-    got = jax.jit(lambda *a: mla_attention._groups(jnp, *a, bkv, PS, G))(
+    want = row_groups._groups(np, pt, kl, q_lens, ns, bkv, PS, G)
+    got = jax.jit(lambda *a: row_groups._groups(jnp, *a, bkv, PS, G))(
         jnp.asarray(pt), jnp.asarray(kl), jnp.asarray(q_lens),
         jnp.asarray([ns]))
     for a, b in zip(got, want):
@@ -611,13 +611,14 @@ def test_the_counter_books_a_shared_block_once():
     pt[4, :17 * bkv] = np.arange(17 * bkv)
     kl = np.array([16 * 1024 + 40] * 4 + [17 * 1024], np.int64)
     q = np.array([1, 1, 1, 1, 128])
-    assert mla_attention.decode_kv_blocks(pt, kl, q, ps) == (68, 20)
+    G = mla_attention.GROUP_ROWS
+    assert row_groups.decode_kv_blocks(pt, kl, q, ps, bkv, G) == (68, 20)
     apart = pt.copy()
     for b in range(4):
         apart[b, :16 * bkv] += 10000 * (b + 1)
-    assert mla_attention.decode_kv_blocks(apart, kl, q, ps) == (68, 68)
-    assert mla_attention.decode_kv_blocks(pt[:4], kl[:4] * 0, q[:4], ps) == (
-        0, 0)
+    assert row_groups.decode_kv_blocks(apart, kl, q, ps, bkv, G) == (68, 68)
+    assert row_groups.decode_kv_blocks(pt[:4], kl[:4] * 0, q[:4], ps, bkv,
+                                       G) == (0, 0)
 
 
 # 20 heads over rows of 512 value lanes + 64 rope lanes in 640, as

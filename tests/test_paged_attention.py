@@ -498,16 +498,11 @@ def test_two_calls_equal_the_single_call_bit_for_bit(monkeypatch, q_lens,
     the pair of the call that owns the token. (Against one pair for all
     tokens the chip reads 0.0 too, `tools/attn_sweep.py --split 0,1`; the
     CPU's interpreter rounds a few elements otherwise by the block's shape.)"""
-    import functools
-
     import numpy as np
-    from jax.experimental import pallas as pl
 
     import llmd_tpu.ops.paged_attention as pa
 
-    # the upstream wrapper takes no interpret flag: give its pallas_call one
-    monkeypatch.setattr(pl, "pallas_call",
-                        functools.partial(pl.pallas_call, interpret=True))
+    _interpreted(monkeypatch)
     args, kw, cu = _interpret_case(q_lens, seq_lens, heads, window)
     pairs = pa.step_geometry(args[0].shape, args[1].shape, *args[2].shape)
     assert len(pairs) == 2 and pairs[0][1] != pairs[1][1]
@@ -519,6 +514,335 @@ def test_two_calls_equal_the_single_call_bit_for_bit(monkeypatch, q_lens,
         want = np.asarray(pa.paged_attention_tpu(*args, **kw), np.float32)
         np.testing.assert_array_equal(got[tokens], want[tokens])
     assert np.isfinite(got[:cu[len(q_lens)]]).all()
+
+
+# ------------------------------------- the one-query rows on the repo's kernel
+
+# query heads over two KV heads: four, six and seven a KV head (Mistral's
+# 32/8, Qwen's 12/2, SmallThinker's 28/4)
+ROWS_LAYOUTS = {"32/8": 8, "12/2": 12, "28/4": 14}
+# a row: (pages of the tenant's prompt it stands behind, tokens past them;
+# tokens <= 0: the context ends that far inside the held pages), None an idle
+# seat as the fused call packs it (one token, no page)
+ROWS_CASES = {
+    # nobody shares; a context on a KV block's end, one inside its first page
+    "unshared": [(0, 300), (0, 1024), (0, 1100), (0, 40), None, (0, 513),
+                 None, (0, 2000)],
+    # eight rows behind one prompt of two KV blocks (one at seven heads)
+    "behind_one_prompt": [(16, 300), (16, 70), (16, 513), (16, 1), (16, 640),
+                          (16, 900), (16, 64), (16, 1200)],
+    # two tenants, rows that hold less of the prompt, a context that ends
+    # inside the held pages, an idle seat between them
+    "unequal_extents": [(24, 100), (16, 300), (24, 700), None, (8, 30),
+                        (24, -70), (16, 520), (16, 90)],
+}
+ROWS_TENANT = {"unequal_extents": [0, 1, 0, 0, 1, 0, 1, 1]}
+
+
+def _rows_case(rows, heads, share, tenants=None, n_tokens=None, chunk=None,
+               seed=0):
+    """One-query rows over 64-token pages (a KV block is 8 pages, 16 at seven
+    heads a KV head) whose tenant's prompt the pool holds once (``share``) or
+    once a row under page ids of the row's own, the same values either way;
+    ``chunk`` ``(q_len, kv_len)`` a prefill chunk behind them."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    ps, Hk, D, maxp = 64, 2, 128, 48
+    B = len(rows) + (chunk is not None)
+    N = n_tokens or B
+    rng = np.random.default_rng(seed)
+    tenants = tenants or [0] * len(rows)
+    docs = rng.standard_normal((2, 24, ps, 2 * Hk, D)).astype(np.float32)
+    pool = np.zeros((600, ps, 2 * Hk, D), np.float32)
+    free = list(rng.permutation(np.arange(1, 600)))  # page 0: a clamped -1
+    held = [[free.pop() for _ in range(24)] for _ in docs]
+    for doc, ids in zip(docs, held):
+        pool[ids] = doc
+    pt = np.full((B, maxp), -1, np.int32)
+    lens, q_lens = np.ones(B, np.int32), np.ones(B, np.int32)
+    for b, row in enumerate(rows):
+        if row is None:
+            continue
+        n_doc, past = row
+        ids = held[tenants[b]][:n_doc] if share else [
+            free.pop() for _ in range(n_doc)]
+        pool[ids] = docs[tenants[b], :n_doc]
+        mine = [free.pop() for _ in range(-(-max(past, 0) // ps))]
+        pool[mine] = rng.standard_normal((len(mine), ps, 2 * Hk, D))
+        pt[b, :n_doc + len(mine)] = ids + mine
+        lens[b] = n_doc * ps + past
+    if chunk:
+        q_lens[-1], lens[-1] = chunk
+        ids = [free.pop() for _ in range(-(-chunk[1] // ps))]
+        pool[ids] = rng.standard_normal((len(ids), ps, 2 * Hk, D))
+        pt[-1, :len(ids)] = ids
+    cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
+    args = (jnp.asarray(rng.standard_normal((N, heads, D)), jnp.bfloat16),
+            jnp.asarray(pool, jnp.bfloat16), jnp.asarray(pt), None, None,
+            jnp.asarray(lens))
+    kw = dict(scale=D ** -0.5, cu_q_lens=jnp.asarray(cu),
+              num_seqs=jnp.asarray([B], jnp.int32))
+    return args, kw
+
+
+def _interpreted(monkeypatch):
+    """The upstream wrapper takes no interpret flag: give its pallas_call
+    one (the rows kernel is handed its own)."""
+    import functools
+
+    from jax.experimental import pallas as pl
+
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+
+
+def _led(plan, B=8):
+    """(size, shared) of every group the plan's array holds (members, size,
+    shared, leaders, their count), in the order walked."""
+    import numpy as np
+
+    packed = np.asarray(plan["groups"])
+    G = (len(packed) - 1) // B - 3
+    size, shared, lead = packed[B * G:-1].reshape(3, B)
+    return [(int(size[b]), int(shared[b])) for b in lead[:packed[-1]]]
+
+
+@pytest.mark.parametrize("layout", sorted(ROWS_LAYOUTS))
+@pytest.mark.parametrize("case", sorted(ROWS_CASES))
+def test_the_rows_kernel_returns_the_upstream_calls_bits(monkeypatch, case,
+                                                         layout):
+    """A fused decode call's rows through `rows_attention` (the groups the
+    plan derives from the tables) and through the upstream kernel at the same
+    bkv: every row equal to the last bit, idle seats too, whether the rows
+    stand behind one prompt's pages or own copies of them."""
+    import numpy as np
+
+    import llmd_tpu.ops.paged_attention as pa
+
+    _interpreted(monkeypatch)
+    heads = ROWS_LAYOUTS[layout]
+    rows, tenants = ROWS_CASES[case], ROWS_TENANT.get(case)
+    out, led = {}, {}
+    for share in (True, False):
+        args, kw = _rows_case(rows, heads, share, tenants)
+        plan = pa.plan(args[2], args[5], kw["cu_q_lens"], kw["num_seqs"], 64,
+                       heads_per_kv=heads // 2)
+        led[share] = _led(plan)
+        out[share] = np.asarray(pa.paged_attention_tpu(
+            *args, one_query_rows=True, interpret=True, **plan, **kw),
+            np.float32)
+    want = np.asarray(pa.paged_attention_tpu(*args, **kw), np.float32)
+    np.testing.assert_array_equal(out[False], want)
+    np.testing.assert_array_equal(out[True], want)
+    mine = [b for b, r in enumerate(rows) if r is not None]
+    assert np.isfinite(want).all()
+    assert np.abs(want[mine]).max(axis=(1, 2)).min() > 0.05
+    # on copies of their own only the idle seats (all unmapped) share
+    assert led[False].count((1, 0)) >= len(mine)
+    bkv = pa.pick_block_sizes(0, 64, 48, heads // 2)[0]
+    if case == "behind_one_prompt":  # 16 pages: two KV blocks of 8, one of 16
+        assert led[True] == [(pa.GROUP_ROWS, 16 // bkv)]
+    elif case == "unequal_extents":
+        assert any(n > 1 and s > 0 for n, s in led[True])
+        assert len(led[True]) < len(led[False])
+    else:
+        assert led[True] == led[False]
+
+
+@pytest.mark.parametrize("layout,window", [("32/8", None), ("28/4", None),
+                                           ("28/4", 640)],
+                         ids=["32/8", "28/4", "window-layer"])
+def test_a_unified_steps_head_call_on_the_rows_kernel(monkeypatch, layout,
+                                                      window):
+    """Five decode rows behind one prompt and a chunk beside them: with the
+    plan's groups the step's one-query rows go to the rows kernel and the
+    chunk to the upstream call, and every token is what the two upstream
+    calls give, to the last bit. A window layer hands the kernel shifted
+    tables, which the batch's groups do not describe: its rows keep the
+    upstream call."""
+    import numpy as np
+
+    import llmd_tpu.ops.paged_attention as pa
+
+    _interpreted(monkeypatch)
+    heads = ROWS_LAYOUTS[layout]
+    rows = [(16, 300), (16, 70), (16, 513), (16, 1), (16, 640)]
+    args, kw = _rows_case(rows, heads, True, n_tokens=32, chunk=(20, 700))
+    if window:
+        kw["sliding_window"] = window
+    plan = pa.plan(args[2], args[5], kw["cu_q_lens"], kw["num_seqs"], 64,
+                   heads_per_kv=heads // 2)
+    called = []
+    monkeypatch.setattr(pa, "rows_attention", lambda *a, _f=pa.rows_attention,
+                        **k: called.append(a[0].shape[0]) or _f(*a, **k))
+    got = np.asarray(pa.paged_attention_tpu(*args, interpret=True, **plan,
+                                            **kw), np.float32)
+    want = np.asarray(pa.paged_attention_tpu(*args, **kw), np.float32)
+    np.testing.assert_array_equal(got[:25], want[:25])
+    assert called == ([] if window else [6])  # the step's 6 rows' tokens
+    assert _led(plan, 6)[0][1] > 0 and sum(n for n, _ in _led(plan, 6)) == 5
+
+
+def test_a_step_with_no_decode_row_tells_the_rows_kernel_of_one(monkeypatch):
+    """`decode_rows_and_chunks` hands the head call its first row as one
+    query over one token where the step has no decode row: the plan's groups
+    are that call's (one group of one), not the batch's one-token chunks'."""
+    import numpy as np
+
+    import llmd_tpu.ops.paged_attention as pa
+
+    _interpreted(monkeypatch)
+    import jax.numpy as jnp
+
+    args, kw = _rows_case([(16, 300)], 8, True, n_tokens=32, chunk=(1, 90))
+    # a chunk of 9, then one of 1
+    kw["cu_q_lens"] = jnp.asarray([0, 9, 10], jnp.int32)
+    plan = pa.plan(args[2], args[5], kw["cu_q_lens"], kw["num_seqs"], 64,
+                   heads_per_kv=4)
+    assert _led(plan, 2) == [(1, 0)]
+    got = np.asarray(pa.paged_attention_tpu(*args, interpret=True, **plan,
+                                            **kw), np.float32)
+    want = np.asarray(pa.paged_attention_tpu(*args, **kw), np.float32)
+    np.testing.assert_array_equal(got[:10], want[:10])
+
+
+@pytest.mark.parametrize("heads_per_kv,dtype,mesh,serves", [
+    (4, "bfloat16", None, True), (6, "bfloat16", None, False),
+    (7, "bfloat16", None, False), (2, "bfloat16", None, False),
+    (20, "bfloat16", None, False), (4, "float8_e4m3fn", None, False),
+    (4, "float32", None, False), (4, "bfloat16", object(), False),
+], ids=["32/8", "12/2", "28/4", "unswept-2", "unswept-20", "fp8", "float32",
+        "mesh"])
+def test_which_layouts_take_the_rows_kernel(heads_per_kv, dtype, mesh, serves):
+    """The rule reads what `pick_block_sizes` reads of a layout, the pool's
+    dtype and whether a mesh is set: four query heads a KV head in bf16 on
+    one device take the kernel; six and seven (swept: slower on rows that
+    share nothing), the unswept, fp8 pages and a mesh keep the upstream
+    call."""
+    import llmd_tpu.ops.paged_attention as pa
+
+    assert pa.rows_kernel_serves(heads_per_kv, dtype, mesh) is serves
+
+
+def _gqa4_engine(**kw):
+    from dataclasses import replace
+
+    return LLMEngine(
+        replace(get_model_config("tiny"), num_heads=8, num_kv_heads=2),
+        EngineConfig(page_size=8, num_pages=96, max_model_len=128,
+                     max_batch_size=4, prefill_chunk=32, decode_steps=4,
+                     **kw), seed=3)
+
+
+def test_which_engines_hand_their_rows_to_the_rows_kernel():
+    """`engine/backends.py` binds the plan (and with it the kernel) for a
+    layout the sweep passed on one device; a model with recurrent layers, a
+    layout it did not pass, an fp8 pool and the XLA reference keep what they
+    had. The label names the rows a group."""
+    import test_hybrid_ssm
+
+    import llmd_tpu.ops.paged_attention as pa
+
+    eng = _gqa4_engine(attn_impl="pallas")
+    bk = eng.backends
+    assert bk.attn_impl.plan.func is pa.plan
+    assert bk.attn_decode_impl.plan is bk.attn_impl.plan
+    assert bk.attn_decode_impl.keywords["one_query_rows"] is True
+    assert "one_query_rows" not in bk.attn_impl.keywords
+    assert bk.decode_groups == (16, 8)  # a sequence's 16 pages a KV block
+    assert eng.attn_geometry == "unified=16x4+16x32 decode=16x4 groups=8"
+    for other in (test_hybrid_ssm._engine(attn_impl="pallas"),
+                  _gqa4_engine(attn_impl="pallas", kv_cache_dtype="fp8"),
+                  LLMEngine(get_model_config("tiny"), EngineConfig(
+                      page_size=8, num_pages=32, max_model_len=64,
+                      max_batch_size=2, prefill_chunk=16,
+                      attn_impl="pallas")),
+                  _gqa4_engine()):
+        assert getattr(other.backends.attn_impl, "plan", None) is None
+        assert other.backends.attn_decode_impl is other.backends.attn_impl \
+            or other.model_cfg.has_recurrent
+        assert other.backends.decode_groups is None
+        assert "groups=" not in other.attn_geometry
+
+
+def test_the_engine_walks_a_cached_prompt_once_and_books_it(monkeypatch):
+    """Four sequences behind one cached 64-token prompt, 16 tokens a KV
+    block: the second pass's decode rows name the same pages first, the rows
+    kernel walks them as one group, the tokens are the XLA engine's and those
+    of the engine whose rows keep the upstream call, the counter says what
+    was fetched, `plan` is asked once a program, and an engine on another
+    backend books nothing."""
+    import llmd_tpu.ops.paged_attention as pa
+
+    _interpreted(monkeypatch)
+    monkeypatch.setattr(pa, "KV_BLOCK_TOKENS", 16)
+    asked = []
+    monkeypatch.setattr(pa, "plan", lambda *a, _f=pa.plan, **k: (
+        asked.append(a[0].shape) or _f(*a, **k)))
+    prompts = [[(7 * t + 11) % 250 + 2 for t in range(64)]
+               + [81 + i, 80 + 2 * i] for i in range(4)]
+    sp = SamplingParams(max_tokens=10, temperature=0.0)
+    ref = _gqa4_engine()
+    want = ref.generate(prompts, sp)
+    with monkeypatch.context() as m:  # the parent's binding
+        m.setattr(pa, "rows_kernel_serves", lambda *a: False)
+        upstream = _gqa4_engine(attn_impl="pallas")
+    assert upstream.backends.decode_groups is None
+    assert [upstream.generate(prompts, sp) for _ in range(2)] == [want] * 2
+    eng = _gqa4_engine(attn_impl="pallas")
+    assert eng.attn_geometry == "unified=2x4+2x32 decode=2x4 groups=8"
+    name = "llmd_tpu:attn_decode_kv_blocks_total"
+
+    def series():
+        return {k: float(v) for k, v in (
+            line.rsplit(" ", 1) for line in
+            eng.metrics.registry.expose().splitlines()
+            if line.startswith(name))}
+
+    assert eng.generate(prompts, sp) == want
+    cold = series()
+    assert eng.generate(prompts, sp) == want
+    both = series()
+    rows, fetched = (both[name + '{blocks="%s"}' % k]
+                     - cold[name + '{blocks="%s"}' % k]
+                     for k in ("rows", "fetched"))
+    assert 0 < fetched < 0.6 * rows  # 4 of a row's 5 blocks are shared
+    assert cold[name + '{blocks="fetched"}'] <= cold[name + '{blocks="rows"}']
+    # one plan a trace of a program's body, whatever its layers (two,
+    # scanned): the unified step's, and the fused call's body traced twice
+    # (`_fused_steps`: once for its shapes, once in the loop)
+    assert asked == [(4, 16)] * 3
+    assert name not in ref.metrics.registry.expose().replace(
+        "# HELP " + name, "").replace("# TYPE " + name, "")
+
+
+def test_the_counter_books_a_tenants_blocks_once():
+    """Eight rows behind 4 KV blocks of 32 pages with a block of their own
+    each (Mistral's layout): 40 blocks once a row, 12 fetched at eight rows a
+    group and 16 at four; on copies of their own 40 and 40; a chunk is not a
+    decode row; rows that start on pages of their own never reach the rule."""
+    import numpy as np
+
+    from llmd_tpu.ops import row_groups
+
+    ps, bkv, maxp = 16, 32, 800
+    assert pick_block_sizes(64, ps, maxp, heads_per_kv=4)[0] == bkv
+    pt = np.full((9, maxp), -1, np.int32)
+    pt[:8, :4 * bkv] = np.arange(4 * bkv)
+    for b in range(8):
+        pt[b, 4 * bkv:4 * bkv + 3] = 5000 + 10 * b + np.arange(3)
+    pt[8, :5 * bkv] = np.arange(5 * bkv)
+    kl = np.array([4 * 512 + 40] * 8 + [5 * 512], np.int64)
+    q = np.array([1] * 8 + [128])
+    assert row_groups.decode_kv_blocks(pt, kl, q, ps, bkv, 8) == (40, 12)
+    assert row_groups.decode_kv_blocks(pt, kl, q, ps, bkv, 4) == (40, 16)
+    apart = pt.copy()
+    for b in range(8):
+        apart[b, :4 * bkv] += 10000 * (b + 1)
+    assert row_groups.decode_kv_blocks(apart, kl, q, ps, bkv, 8) == (40, 40)
+    assert row_groups.decode_kv_blocks(pt[:4], kl[:4] * 0, q[:4], ps, bkv,
+                                       8) == (0, 0)
 
 
 # -------------------------------------------------- b128 scaling regression

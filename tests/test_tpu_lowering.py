@@ -173,6 +173,48 @@ def test_a_unified_steps_two_calls_compile_for_v5e(one_chip, config, window,
         r"%ragged_paged_attention_kernel\S* = bf16\[(\d+),", text)) == [64, 256]
 
 
+@pytest.mark.parametrize("n", [64, 256], ids=["decode", "unified"])
+@pytest.mark.parametrize("config,maxp,window", [
+    ("qwen2.5-1.5b", 256, 0), ("mistral-7b-v0.3", 800, 0),
+    ("smallthinker", 1024, 0), ("smallthinker", 1024, 4096)])
+def test_the_rows_kernel_compiles_for_v5e_at_the_cells_shapes(
+        one_chip, config, maxp, window, n):
+    """The one-query rows of the three GQA cells through the repo's kernel
+    (Mistral's layout takes it; the other two are kept compiling for the PR
+    that passes them), planned and called as `forward_core` does: the fused
+    decode call
+    (one device operation, the rows kernel's) and the unified step (the rows
+    kernel for its 64 leading query tokens, the upstream call for its
+    chunks), at the cells' own page budgets (Mistral's 800 pages a sequence
+    are 51,200 table entries in scalar memory); a window layer's rows keep
+    the upstream call."""
+    import re
+
+    import llmd_tpu.ops.paged_attention as pa
+
+    heads, kv_heads, _ = CELL_LAYOUTS[config]
+    hpk = heads // kv_heads
+    assert pa.rows_kernel_serves(hpk, jnp.bfloat16, None) == (hpk == 4)
+    kw = {"sliding_window": window} if window else {}
+
+    def fn(q, cache, pt, pos, slots, lens, cu, ns):
+        plan = pa.plan(pt, lens, cu, ns, 16, heads_per_kv=hpk)
+        return paged_attention_tpu(q, cache, pt, pos, slots, lens,
+                                   scale=128 ** -0.5, cu_q_lens=cu,
+                                   num_seqs=ns, one_query_rows=n == 64,
+                                   **plan, **kw)
+
+    q_shape, cache_shape = (n, heads, 128), (1024, 16, 2 * kv_heads, 128)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in _attn_args(q_shape, cache_shape, 64, maxp)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = re.findall(r"%(ragged_paged_attention_\w+?)[.\d]* = bf16\[(\d+),",
+                       text)
+    rows = "kernel" if window else "rows"
+    assert sorted(names) == [("ragged_paged_attention_kernel", "256")] * (
+        n == 256) + [("ragged_paged_attention_" + rows, "64")]
+
+
 @pytest.mark.parametrize("n,state", [(64, jnp.float32), (256, jnp.float32),
                                      (256, jnp.bfloat16)],
                          ids=["decode", "unified", "unified_bf16_state"])

@@ -24,7 +24,22 @@ each pair and as two (the one-query rows at the fused decode call's pair, the
 chunks at the pair's bq; `paged_attention_tpu`), the one call first: every
 row's ``diff`` is against the first row's output and must read 0.0.
 
+``--shared <lanes a tenant>`` lays the decode rows out as the sessions
+traffic does (every so many consecutive decode rows name the same pages for
+the tenant's system prompt and own the rest; without it every row owns its
+pages and no group forms), ``--groups 4,8,16`` times each pair once more
+with the one-query rows on the repo's kernel at those rows a group
+(`rows_attention`; 0 = the kernel's own `GROUP_ROWS`; every timed row keeps
+the upstream call's row first, so ``diff`` is against it), ``--pages-a-turn``
+the page copies a turn of its fetch loop, and ``--parent <file>`` (the parent
+commit's ``ops/paged_attention.py``, e.g. ``git show HEAD~1:llmd_tpu/ops/
+paged_attention.py > .scratch/parent/paged_attention.py``) times that file
+first in the same chip call; ``kv_blocks`` is what the counter's twin books
+for the layout (blocks once a row, blocks fetched).
+
     python tools/attn_sweep.py                  # on the chip
+    python tools/attn_sweep.py --cells mistral --bkv 32 --bq 64 --split 1 \
+        --shared 8 --groups 4,8,16              # the rows kernel, ~3 min
     python tools/attn_sweep.py --compile-only   # here: which pairs Mosaic takes
 
 ``--compile-only`` compiles for a described v5e without a chip and runs nothing.
@@ -120,7 +135,8 @@ def draw_batch(rng, cell: str, program: str, eng: dict, traffic: dict):
     return kv_lens.astype(np.int32), cu.astype(np.int32), rows
 
 
-def build_case(cell: str, program: str, seed: int, heads: str = ""):
+def build_case(cell: str, program: str, seed: int, heads: str = "",
+               shared: int = 0):
     import numpy as np
 
     cfg_name, traffic_name = CELLS[cell]
@@ -136,9 +152,18 @@ def build_case(cell: str, program: str, seed: int, heads: str = ""):
     pts = np.full((eng["max_batch_size"], maxp), -1, np.int32)
     free = rng.permutation(P)  # a pool after churn: a sequence's pages scatter
     off = 0
+    # decode rows behind a tenant's system prompt name its pages first
+    held = (traffic.get("sessions") or {}).get("system_prompt", 0) // ps
+    n_dec = int((np.diff(cu)[:rows] == 1).sum()) if shared else 0
+    live = rows if program != "decode" else LIVE_DECODE.get(cell, rows)
     for i, n in enumerate(-(-kv_lens // ps)):
-        pts[i, :n] = free[off:off + n]
-        off += n
+        if i >= live:  # an idle seat of the fused call: no page, one token
+            continue
+        lead = i - i % shared if i < n_dec else i
+        doc = held if lead != i and n >= held else 0
+        pts[i, :doc] = pts[lead, :doc]
+        pts[i, doc:n] = free[off:off + n - doc]
+        off += n - doc
     N = eng["max_batch_size"] if program == "decode" else eng["prefill_chunk"]
     return dict(
         cell=cell, program=program, N=N, heads=cfg["num_attention_heads"],
@@ -149,18 +174,25 @@ def build_case(cell: str, program: str, seed: int, heads: str = ""):
         kv_bytes_per_token=2 * cfg["num_key_value_heads"] * cfg["head_dim"] * 2)
 
 
-def _attn_fn(pa, case, reps):
+def _attn_fn(pa, case, reps, rows_kernel=False):
     import jax
 
     scale = case["head_dim"] ** -0.5
     # a window layer's call: the impl shifts the page tables itself
     kw = {"sliding_window": case["window"]} if case.get("window") else {}
+    if rows_kernel:
+        kw["one_query_rows"] = case["program"] == "decode"
 
     def f(q, cache, pts, lens, cu, ns):
+        # the groups once a program, as `forward_core` asks for them
+        plan = pa.plan(pts, lens, cu, ns, case["page_size"],
+                       heads_per_kv=case["heads"] // case["kv_heads"]
+                       ) if rows_kernel else {}
+
         def body(_, qq):
             o = pa.paged_attention_tpu(qq, cache, pts, None, None, lens,
                                        scale=scale, cu_q_lens=cu, num_seqs=ns,
-                                       **kw)
+                                       **kw, **plan)
             return (qq * 0.5 + o * 0.5).astype(qq.dtype)
 
         return jax.lax.fori_loop(0, reps, body, q)
@@ -226,21 +258,26 @@ def _operands(case, seed):
             jnp.asarray([case["num_seqs"]], jnp.int32))
 
 
-def measure(pa, case, calls, reps, operands, chip):
+def measure(pa, case, calls, reps, operands, chip, group=None):
     """One row of the report: compile (and, with operands, time) the kernel
     calls ``calls`` (one (bkv, bq) pair, or the decode rows' and the chunks').
     ``step_geometry`` is replaced for the trace, so the call goes through
-    `paged_attention_tpu` as the engine's does."""
+    `paged_attention_tpu` as the engine's does. ``group``: the one-query rows
+    on the rows kernel at so many rows a group (0: its own), None: on the
+    upstream call."""
     import jax
     import numpy as np
 
-    rule = pa.step_geometry
-    row = dict(zip(("bkv", "bq"), calls[-1]), split=len(calls) > 1)
+    rule, own = pa.step_geometry, getattr(pa, "GROUP_ROWS", None)
+    row = dict(zip(("bkv", "bq"), calls[-1]), split=len(calls) > 1,
+               group=group)
     pa.step_geometry = lambda *a, **k: calls
+    if group:
+        pa.GROUP_ROWS = group
     try:
         # timed apart: a launch pays trace + lowering even on a cache hit
         t0 = time.perf_counter()
-        lowered = _attn_fn(pa, case, reps).lower(
+        lowered = _attn_fn(pa, case, reps, group is not None).lower(
             *(_shapes(case, chip) if operands is None else operands))
         t1 = time.perf_counter()
         fn = lowered.compile()
@@ -262,6 +299,8 @@ def measure(pa, case, calls, reps, operands, chip):
         row["error"] = f"{type(e).__name__}: {e}"[:400]
     finally:
         pa.step_geometry = rule
+        if group:
+            pa.GROUP_ROWS = own
     return row
 
 
@@ -288,6 +327,19 @@ def main() -> None:
                     help="0, 1 or 0,1: a unified step's rows as one call at "
                          "each pair, or as two (one-query rows at the fused "
                          "decode call's pair, chunks at the pair's bq)")
+    ap.add_argument("--shared", type=int, default=0,
+                    help="lanes a tenant: consecutive decode rows that name "
+                         "the same pages for the traffic's system prompt "
+                         "(0: every row owns its pages)")
+    ap.add_argument("--groups", default="",
+                    help="rows a group to time the rows kernel at beside the "
+                         "upstream call, e.g. 4,8,16 (0: the kernel's own)")
+    ap.add_argument("--pages-a-turn", default="0",
+                    help="page copies a turn of the rows kernel's fetch "
+                         "loop, e.g. 4,8,32 (0: the kernel's own)")
+    ap.add_argument("--parent", default="",
+                    help="the parent commit's ops/paged_attention.py: timed "
+                         "first, every row's diff against it")
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "attn_sweep.json"))
@@ -300,6 +352,18 @@ def main() -> None:
 
     import llmd_tpu.ops.paged_attention as pa
     from llmd_tpu.obs.costmodel import chip_peaks
+    from llmd_tpu.ops.row_groups import decode_kv_blocks
+
+    own_turn = pa.PAGES_A_TURN
+    turns = [int(t) for t in args.pages_a_turn.split(",")]
+    parent = None
+    if args.parent:
+        import importlib.util
+
+        spec = importlib.util.spec_from_file_location("parent_pa", args.parent)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+    groups = [int(g) for g in args.groups.split(",") if g]
 
     chip = None
     if args.compile_only:
@@ -326,7 +390,7 @@ def main() -> None:
             args.cells.split(","), args.heads.split(","),
             args.programs.split(","), map(int, args.seeds.split(",")),
             map(int, args.windows.split(","))):
-        case = build_case(cell, program, seed, heads)
+        case = build_case(cell, program, seed, heads, args.shared)
         case["window"] = window
         if window:  # what the window needs read (whole pages), and its floor
             from llmd_tpu.models.transformer import window_first_page
@@ -359,15 +423,29 @@ def main() -> None:
                 if bkv <= case["pages_per_seq"] and bq <= case["N"]
                 and (split == "0" or rows < case["N"])]
         first = None  # the first row's output: every other is compared to it
-        for calls in grid + [chosen] * (chosen not in grid):
-            row = measure(pa, case, calls, args.reps, operands, chip)
-            name = pa.format_geometry(calls)
+        todo = [(mod, calls, g, t)
+                for calls in grid + [chosen] * (chosen not in grid)
+                for mod, g, t in [(parent, None, 0)] * bool(parent)
+                + [(pa, None, 0)] + [(pa, g, t) for t in turns for g in groups]]
+        for mod, calls, g, t in todo:
+            pa.PAGES_A_TURN = t or own_turn
+            row = measure(mod, case, calls, args.reps, operands, chip, g)
+            row["pages_a_turn"] = pa.PAGES_A_TURN
+            name = pa.format_geometry(calls) + (
+                " parent" if mod is parent else "" if g is None else
+                f" rows G={g or pa.GROUP_ROWS} turn={pa.PAGES_A_TURN}")
+            if g is not None:
+                row["kv_blocks"] = decode_kv_blocks(
+                    case["page_tables"], case["kv_lens"].astype(np.int64),
+                    np.diff(case["cu"]) * (np.arange(rows) < case["num_seqs"]),
+                    case["page_size"], calls[0][0], g or pa.GROUP_ROWS)
+                name += " blocks {}/{}".format(*row["kv_blocks"][::-1])
             mark = " <- rule" if calls == chosen else ""
             if "us_per_call" in row:
                 out = row.pop("out")
                 first = out if first is None else first
                 row["max_diff"] = float(np.abs(out - first).max())
-                print(f"{name:>14}: {row['us_per_call']:8.1f} "
+                print(f"{name:>36}: {row['us_per_call']:8.1f} "
                       f"us/call {row['us_per_128_ctx']:6.3f} us/128tok "
                       f"{100 * row['roofline']:5.1f}% of bytes "
                       f"diff {row['max_diff']:.4f} "
@@ -377,7 +455,7 @@ def main() -> None:
                 said = row.get("error") or (
                     f"trace+lower {row['lower_s']:.2f} s, compiled in "
                     f"{row['compile_s']:.1f} s")
-                print(f"{name:>14}: {said}{mark}", flush=True)
+                print(f"{name:>36}: {said}{mark}", flush=True)
             shape["results"].append(row)
         if window and operands is not None:
             shape["served_vs_masked_only"] = window_diff(pa, case, operands)
@@ -387,6 +465,7 @@ def main() -> None:
         os.makedirs(os.path.dirname(args.out), exist_ok=True)
         with open(args.out, "w") as f:
             json.dump(report, f, indent=1)
+    pa.PAGES_A_TURN = own_turn
     print(f"\n# wrote {args.out}")
 
 

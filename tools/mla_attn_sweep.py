@@ -121,6 +121,7 @@ def main() -> int:
     from kernels.mla_attention import cost
     from llmd_tpu.models.transformer import ragged_paged_attention_xla
     from llmd_tpu.ops import mla_attention as mod
+    from llmd_tpu.ops.row_groups import decode_kv_blocks
 
     rule = mod.pick_block_sizes
     rng = np.random.default_rng(0)
@@ -294,8 +295,8 @@ def main() -> int:
                     us[kind] = (time.time() - t) / args.reps * 1e6
                     outs[kind] = out[..., :RANK].astype(jnp.float32)
                 mark = "*" if (bkv, bq) == rule(N, B, PS, maxp) else ""
-                rows, fetched = mod.decode_kv_blocks(
-                    b[0], b[3], np.diff(b[4]), PS)
+                rows, fetched = decode_kv_blocks(
+                    b[0], b[3], np.diff(b[4]), PS, bkv, G)
                 print(json.dumps({
                     "shape": name, "bkv": bkv, "bq": bq, "rule": mark,
                     "G": G, "kv_blocks": [rows, fetched],
