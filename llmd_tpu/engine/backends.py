@@ -31,7 +31,7 @@ class Backends:
     arguments of those names, ``attn_impl`` the unified-shape programs'
     (unified, verify, embed) and ``attn_decode_impl`` the fused decode
     calls'; ``core_kwargs`` is what only a model with recurrent layers hands
-    forward_core (``scan_impl``, ``ssd_impl`` or ``lin_impl``,
+    forward_core (``scan_impl``, ``ssd_impl``, ``lin_impl`` or ``kda_impl``,
     ``query_attn_impl``)."""
 
     attn_impl: Callable
@@ -96,11 +96,13 @@ def resolve(model_cfg: ModelConfig, engine_cfg: EngineConfig, mesh, *,
         # call) every row of the fused call brings one query
         attn_decode = functools.partial(attn, one_query_rows=True)
         attn_decode.plan = attn.plan
-    if model_cfg.has_recurrent and pallas_attn:
+    if model_cfg.has_recurrent and gqa_kernel:
         # a recurrent layer carries the last bit of an attention layer's
         # result on, so a prompt's chunks are handed to the kernel cut at
         # its KV blocks' ends (ops/paged_attention.split_rows_at_kv_blocks);
         # the fused call's rows bring one query each and are never cut
+        # (the latent kernel, beside 'kda' layers, gives a chunk whole and
+        # in two calls the same bits: tools/mla_attn_sweep.py's third check)
         attn = functools.partial(attn, split_at_kv_blocks=True)
     # what only a model with recurrent layers hands forward_core: the
     # selective scan (the Pallas kernel wherever the Pallas attention
@@ -123,6 +125,13 @@ def resolve(model_cfg: ModelConfig, engine_cfg: EngineConfig, mesh, *,
                 impl, interpret=interpret)
             ssm_backend = f"{impl}_mamba2_ssd_block{BLOCK}"
             ssm_state_dtype = model_cfg.mamba_state_dtype
+        elif model_cfg.has_kda:
+            from llmd_tpu.ops.kda_attention import BLOCK, make_kda_attention
+
+            core_kwargs["kda_impl"] = make_kda_attention(
+                impl, interpret=interpret)
+            ssm_backend = f"{impl}_kda_attention_block{BLOCK}"
+            ssm_state_dtype = model_cfg.lightning_state_dtype
         else:
             from llmd_tpu.ops.lightning_attention import (
                 make_lightning_attention,

@@ -584,14 +584,15 @@ class LLMEngine:
         self.state: dict[str, jax.Array] = {}
         # tokens of a block of the recurrence's kernel, which a prompt's
         # chunks start on multiples of (0: the recurrence runs token by token)
-        self._state_block = (LIGHTNING_BLOCK if model_cfg.has_lightning
+        self._state_block = (LIGHTNING_BLOCK if model_cfg.has_matrix_state
                              else MAMBA2_BLOCK if model_cfg.has_mamba2 else 0)
         if model_cfg.has_recurrent:
             self.state = init_state(model_cfg, engine_cfg.max_batch_size)
             for has, gauge in (
                     (model_cfg.has_mamba or model_cfg.has_mamba2,
                      self.metrics.ssm_state_slots),
-                    (model_cfg.has_lightning, self.metrics.linear_state_slots)):
+                    (model_cfg.has_matrix_state,
+                     self.metrics.linear_state_slots)):
                 if has:
                     gauge.set_function(
                         lambda: sum(s is not None for s in self.running))
@@ -980,10 +981,11 @@ class LLMEngine:
     def _count_ssm_tokens(self, program: str, chunk: int, decode: int) -> None:
         """``ssm_scan_tokens_total`` of one dispatched call: the tokens one
         mamba layer's scan is given, by the kind of row that brings them
-        (``linear_attn_tokens_total`` for a model with lightning layers)."""
-        lightning = self.model_cfg.has_lightning
+        (``linear_attn_tokens_total`` for a model with lightning or kda
+        layers)."""
+        linear = self.model_cfg.has_matrix_state
         for rows, n in (("chunk", chunk), ("decode", decode)):
-            if n and lightning:
+            if n and linear:
                 self.metrics.linear_attn_tokens.labels(
                     rows="prefill" if rows == "chunk" else rows).inc(n)
             elif n:
@@ -1070,6 +1072,8 @@ class LLMEngine:
             self.metrics.moe_routed_copies.inc(int(drop[2]))
             if drop.shape[0] > 3:  # and, of a share of the experts, [held]
                 self.metrics.moe_held_copies.inc(int(drop[3]))
+            if drop.shape[0] > 4:  # and, under the group limit, [kept]
+                self.metrics.moe_group_kept_copies.inc(int(drop[4]))
             drop = drop[0]
         n = int(drop)
         self.stats.moe_dropped_tokens += n
@@ -1782,7 +1786,7 @@ class LLMEngine:
             left = self._prefill_target(s) - s.num_computed
             n = min(self.cfg.prefill_chunk, left, budgets[s.rank])
             if self._state_block and n < left:
-                # a lightning or Mamba-2 layer groups its sums by blocks
+                # a lightning, kda or Mamba-2 layer groups its sums by blocks
                 # counted from a chunk's first token: every chunk but a
                 # prompt's last ends on a block's boundary, so the blocks
                 # are the prompt's own
@@ -1865,7 +1869,7 @@ class LLMEngine:
             if recurrent:
                 row_slots[i] = s.slot
                 if start == 0:  # the row starts from a zero state
-                    if self.model_cfg.has_lightning:
+                    if self.model_cfg.has_matrix_state:
                         self.metrics.linear_state_resets.inc()
                     else:
                         self.metrics.ssm_state_resets.labels(

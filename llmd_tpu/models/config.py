@@ -81,8 +81,22 @@ class ModelConfig:
     lightning_heads: int = 0
     lightning_head_dim: int = 0
     lightning_state_dtype: str = "float32"
+    # KDA layers (Kimi Delta Attention): ``kda_heads`` heads of
+    # ``kda_head_dim`` lanes for q, k and v alike behind a causal depthwise
+    # conv of ``kda_d_conv`` taps and SiLU, q and k L2-normed a head, a
+    # log-decay a channel ``kda_gate_lower_bound * sigmoid(exp(A_log) * (W_f x
+    # + dt_bias))`` in (bound, 0), a write strength ``sigmoid(W_b x)`` a head,
+    # the delta-rule state [head_dim, head_dim] a head in
+    # ``lightning_state_dtype`` (the matrix-state pool is the lightning
+    # layers', under its name), the output an RMSNorm a head and a sigmoid
+    # gate a lane.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_d_conv: int = 4
+    kda_gate_lower_bound: float = -5.0
     # Attention layers' output times ``sigmoid(h W_g)`` (leaf ``wg``) before
-    # the output projection.
+    # the output projection: a gate a lane ([D, H, Dh]), or on latent
+    # attention one scalar a head ([D, H]; ``attn_gate_per_head``).
     attn_output_gate: bool = False
     # Block-sparse attention (0 = every layer attends to all keys). A query
     # that sees ``sparse_dense_len`` keys or more attends to the first
@@ -149,6 +163,13 @@ class ModelConfig:
     moe_scoring: str = "softmax"
     moe_router_bias: bool = False
     moe_routed_scaling: float = 1.0
+    # Group-limited choice (DeepSeek-V3's ``n_group`` / ``topk_group``; sigmoid
+    # scoring): the experts stand in ``moe_n_group`` groups of consecutive
+    # experts, a group's score is the sum of its two best ``s + bias``, the
+    # best ``moe_topk_group`` groups are kept and the top-k is taken among
+    # their experts. 1 group is the plain top-k.
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # Dual-batch overlap: split MoE tokens into two independent half-batches so XLA
     # overlaps one half's all-to-all with the other's expert GEMMs (--enable-dbo).
     moe_dbo: bool = False
@@ -190,12 +211,12 @@ class ModelConfig:
         object.__setattr__(self, "layer_kinds",
                            tuple(str(k) for k in self.layer_kinds))
         if set(self.layer_kinds) - {"attention", "mamba", "lightning",
-                                    "mamba2", "experts"} or \
+                                    "mamba2", "experts", "kda"} or \
                 "attention" not in self.layer_kinds:
             raise ValueError(
                 f"layer_kinds {self.layer_kinds}: a period of 'attention', "
-                "'mamba', 'lightning', 'mamba2' and 'experts' layers with at "
-                "least one attention layer")
+                "'mamba', 'lightning', 'mamba2', 'kda' and 'experts' layers "
+                "with at least one attention layer")
         if self.single_sublayer and (
                 set(self.layer_kinds) - {"attention", "mamba2", "experts"}
                 or ("experts" in self.layer_kinds) != self.is_moe
@@ -207,7 +228,8 @@ class ModelConfig:
                 "attention layers only, 'experts' exactly where the model "
                 "is a mixture")
         if self.has_recurrent:
-            if self.num_layers % len(self.layer_kinds) or \
+            if (self.num_layers - self.moe_leading_dense_layers) \
+                    % len(self.layer_kinds) or \
                     len(self.attn_window_pattern) != 1:
                 raise ValueError(
                     f"layer_kinds {self.layer_kinds} must be one period that "
@@ -234,20 +256,55 @@ class ModelConfig:
                     "a model with mamba2 layers states mamba2_heads (a "
                     "whole number a group), mamba2_head_dim, "
                     "mamba2_d_state and mamba2_d_conv >= 2")
+            if self.has_kda and (min(
+                    self.kda_heads, self.kda_head_dim,
+                    self.kda_d_conv - 1) < 1
+                    or not -5.0 <= self.kda_gate_lower_bound < 0
+                    or set(self.layer_kinds) - {"kda", "attention"}):
+                # (the bound: ops/kda_attention takes exp of a block's
+                # summed log-decay, 16 tokens, which must stay a float32)
+                raise ValueError(
+                    "a model with kda layers states kda_heads, kda_head_dim, "
+                    "kda_d_conv >= 2 and a kda_gate_lower_bound in [-5, 0), "
+                    "beside attention layers only")
             for key in ("mamba_state_dtype", "lightning_state_dtype"):
                 if getattr(self, key) not in ("float32", "bfloat16"):
                     raise ValueError(f"{key}={getattr(self, key)!r}")
-            if self.is_moe and not self.single_sublayer:
+            if self.is_moe and not (self.single_sublayer
+                                    or self.recurrent_over_mixture):
                 raise ValueError(
                     "a mixture beside recurrent layers is a stack of single "
-                    "sublayers with layers of kind 'experts'; a recurrent "
-                    "mixer over a mixture feed-forward in one layer is not "
-                    "served")
-            if self.is_mla or self.attn_bias:
+                    "sublayers with layers of kind 'experts', or 'kda' "
+                    "mixers over a sigmoid-routed mixture feed-forward in "
+                    "one layer; a 'mamba' or 'lightning' mixer over a "
+                    "mixture feed-forward is not served")
+            if self.attn_bias or (self.is_mla and not self.has_kda):
                 raise ValueError(
-                    "recurrent layers stand beside GQA attention layers "
-                    "without bias only (MLA and an attention bias beside "
+                    "recurrent layers stand beside attention layers without "
+                    "bias only, and latent attention (MLA) beside 'kda' "
+                    "layers only (MLA beside 'mamba', 'mamba2' or "
+                    "'lightning' layers and an attention bias beside "
                     "recurrent layers are not served)")
+            if self.has_kda and (self.qk_norm or self.sparse_topk):
+                raise ValueError(
+                    "beside kda layers: attention without qk_norm or sparse "
+                    "selection")
+        if self.attn_gate_per_head and not self.has_kda:
+            raise ValueError(
+                "attn_output_gate on latent attention (its head-wise form, "
+                "attn_gate_per_head) is served beside kda layers only")
+        g = self.moe_n_group
+        if (g, self.moe_topk_group) != (1, 1) and not (
+                self.is_moe and self.moe_scoring == "sigmoid" and g > 1
+                and self.moe_num_experts % g == 0
+                and 0 < self.moe_topk_group <= g
+                and self.moe_num_experts // g >= 2
+                and self.moe_topk_group * (self.moe_num_experts // g)
+                >= self.moe_top_k):
+            raise ValueError(
+                f"moe_n_group={g}, moe_topk_group={self.moe_topk_group}: "
+                "sigmoid routing over whole groups of two experts or more, "
+                "the kept groups holding at least moe_top_k experts")
         if self.sparse_topk:
             st = self.sparse_kernel_stride
             if self.is_mla or self.has_window or any(self.rope_pattern) or \
@@ -294,11 +351,14 @@ class ModelConfig:
         k = self.moe_leading_dense_layers
         if k and not (self.is_moe and 0 < k < self.num_layers
                       and self.moe_dense_intermediate_size > 0
-                      and self.layer_period == 1):
+                      and self.layer_period == 1
+                      and (self.layer_kinds == ("attention",)
+                           or self.recurrent_over_mixture)):
             raise ValueError(
                 f"moe_leading_dense_layers={k}: a mixture model of more "
                 "layers than that, with moe_dense_intermediate_size stated "
-                "and attention layers of one kind")
+                "and attention layers of one kind (beside recurrent layers: "
+                "'kda' mixers over the mixture, the leading layers 'kda')")
 
     @property
     def layer_period(self) -> int:
@@ -318,6 +378,32 @@ class ModelConfig:
         return "mamba2" in self.layer_kinds
 
     @property
+    def attn_gate_per_head(self) -> bool:
+        """``attn_output_gate`` is one scalar a head (``wg`` [D, H]): its
+        form on latent attention, whose heads' outputs have no lanes of the
+        hidden size's split to gate one by one."""
+        return self.attn_output_gate and self.is_mla
+
+    @property
+    def has_kda(self) -> bool:
+        return "kda" in self.layer_kinds
+
+    @property
+    def has_matrix_state(self) -> bool:
+        """Some layer keeps a matrix state a head in the ``lin`` pool."""
+        return self.has_lightning or self.has_kda
+
+    @property
+    def recurrent_over_mixture(self) -> bool:
+        """Every layer is a mixer ('kda' or 'attention') and then a
+        feed-forward that is the sigmoid-routed mixture, but for the first
+        ``moe_leading_dense_layers`` layers, which are 'kda' mixers over a
+        dense SwiGLU and stand BEFORE the periods of ``layer_kinds`` (layer
+        ``k + j`` is of kind ``layer_kinds[j % len]``)."""
+        return (self.has_kda and self.is_moe
+                and self.moe_scoring == "sigmoid")
+
+    @property
     def single_sublayer(self) -> bool:
         """A layer is ONE sublayer (``x += Sub(RMSNorm(x; attn_norm_l))``):
         a mixer of its kind or, kind "experts", the feed-forward; an
@@ -329,11 +415,14 @@ class ModelConfig:
     @property
     def has_recurrent(self) -> bool:
         """Some layer keeps a recurrent state per sequence."""
-        return self.has_mamba or self.has_lightning or self.has_mamba2
+        return (self.has_mamba or self.has_lightning or self.has_mamba2
+                or self.has_kda)
 
     def _layers_of(self, kind: str) -> int:
-        return (self.num_layers // len(self.layer_kinds)
-                * self.layer_kinds.count(kind))
+        """(leading dense layers, of kind 'kda', stand before the periods)"""
+        k = self.moe_leading_dense_layers if self.has_kda else 0
+        return ((self.num_layers - k) // len(self.layer_kinds)
+                * self.layer_kinds.count(kind)) + (k if kind == "kda" else 0)
 
     @property
     def num_mamba_layers(self) -> int:
@@ -346,6 +435,14 @@ class ModelConfig:
     @property
     def num_mamba2_layers(self) -> int:
         return self._layers_of("mamba2")
+
+    @property
+    def num_kda_layers(self) -> int:
+        return self._layers_of("kda")
+
+    @property
+    def kda_d_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
 
     @property
     def mamba2_d_inner(self) -> int:

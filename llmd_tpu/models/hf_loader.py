@@ -175,7 +175,11 @@ def _latent_moe_config(hf: dict, path: str, arch: str, dtype: str) -> ModelConfi
             "partial_rotary_factor": 1, "moe_layer_freq": 1}
     for key, want in only.items():
         if hf.get(key, want) != want:
-            raise ValueError(f"{key}={hf[key]!r} in {path}: the program has "
+            # (the group-limited choice itself is served, ModelConfig.
+            # moe_n_group / moe_topk_group, from drawn weights beside 'kda'
+            # layers; what is refused here is this family's checkpoint with
+            # it, which no test has loaded)
+            raise ValueError(f"{key}={hf[key]!r} in {path}: this loader has "
                              f"only {key}={want!r} for {arch}")
     dn, dr = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
     width = int(hf["moe_intermediate_size"])

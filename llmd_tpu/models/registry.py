@@ -167,6 +167,29 @@ MODEL_REGISTRY: dict[str, ModelConfig] = {
         moe_gated=False, moe_activation="relu2", moe_scoring="sigmoid",
         moe_router_bias=True, moe_routed_scaling=2.5,
     ),
+    # 'kda' delta-rule mixers beside one latent-attention layer in a period of
+    # six, each over a mixture feed-forward, behind one leading 'kda' layer
+    # over a dense SwiGLU, at CI size: 8 sigmoid-routed experts in 4 groups,
+    # the best 2 groups kept and the top-2 taken among them, 4 of the 8 held
+    # here, a shared expert, a head-wise gate on the latent layer's output.
+    # The delta-rule recurrence on the matrix-state pool beside a latent KV
+    # pool, a recurrent mixer over experts in one layer and the group-limited
+    # choice on the serving surface.
+    "tiny-ling": ModelConfig(
+        name="tiny-ling", vocab_size=288, hidden_size=128,
+        intermediate_size=64, num_layers=7, num_heads=4, num_kv_heads=4,
+        head_dim=48, tie_embeddings=False, rope_theta=6e6,
+        layer_kinds=("kda", "kda", "kda", "attention", "kda", "kda"),
+        kda_heads=4, kda_head_dim=32, kda_d_conv=4,
+        attn_output_gate=True,
+        mla_kv_lora_rank=64, mla_rope_dim=16, mla_qk_nope_dim=32,
+        mla_v_head_dim=32,
+        moe_num_experts=8, moe_top_k=2, moe_intermediate_size=64,
+        moe_num_shared_experts=1, moe_shared_intermediate_size=64,
+        moe_leading_dense_layers=1, moe_dense_intermediate_size=192,
+        moe_scoring="sigmoid", moe_router_bias=True, moe_routed_scaling=2.5,
+        moe_n_group=4, moe_topk_group=2, moe_held_count=4,
+    ),
 }
 
 

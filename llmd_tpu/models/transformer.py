@@ -209,12 +209,17 @@ def _init_layered_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Arra
     float32 copies of a whole stacked leaf, 2 x 9.7 GB for six expert banks
     of [64, 2048, 3072].
 
-    Attention leaves and both norms are [L, ...]; the expert leaves
-    (``router``, ``router_bias``, ``moe_wi``, ``moe_wo``, ``shared_*``) are
-    [L - k, ...] and the leading dense layers' ``wi`` / ``wo_mlp`` [k, ...],
-    k = ``cfg.moe_leading_dense_layers``."""
+    Both norms are [L, ...] and the attention leaves [La, ...] (La the
+    attention layers: every layer, but beside 'kda' mixers, whose leaves
+    ``_init_kda_params`` adds); the expert leaves (``router``,
+    ``router_bias`` over ALL the model's experts, ``moe_wi``, ``moe_wo`` over
+    the ``cfg.moe_bank_slots`` held here, ``shared_*``) are [L - k, ...] and
+    the leading dense layers' ``wi`` / ``wo_mlp`` [k, ...], k =
+    ``cfg.moe_leading_dense_layers``. A head-wise output gate's ``wg`` is
+    [La, D, H]."""
     dt = cfg.jax_dtype
     L, D, H = cfg.num_layers, cfg.hidden_size, cfg.num_heads
+    La = cfg.num_attn_layers
     draw, keys = _stack_drawer(key, dt)
     s = D ** -0.5
     p: dict[str, jax.Array] = {
@@ -227,35 +232,38 @@ def _init_layered_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Arra
         r, dr = cfg.mla_kv_lora_rank, cfg.mla_rope_dim
         dn, dv, rq = cfg.mla_qk_nope_dim, cfg.mla_v_head_dim, cfg.mla_q_lora_rank
         if rq:
-            p["mla_wqa"] = draw(L, (D, rq), s)
-            p["mla_q_norm"] = jnp.ones((L, rq), dt)
-            p["mla_wqb"] = draw(L, (rq, H, dn + dr), rq ** -0.5)
+            p["mla_wqa"] = draw(La, (D, rq), s)
+            p["mla_q_norm"] = jnp.ones((La, rq), dt)
+            p["mla_wqb"] = draw(La, (rq, H, dn + dr), rq ** -0.5)
         else:
-            p["mla_wq"] = draw(L, (D, H, dn + dr), s)
-        p["mla_wdkv"] = draw(L, (D, r), s)
-        p["mla_wkr"] = draw(L, (D, dr), s)
-        p["mla_kv_norm"] = jnp.ones((L, r), dt)
-        p["mla_wuk"] = draw(L, (H, dn, r), dn ** -0.5)
-        p["mla_wuv"] = draw(L, (H, r, dv), r ** -0.5)
-        p["wo"] = draw(L, (H, dv, D), (H * dv) ** -0.5)
+            p["mla_wq"] = draw(La, (D, H, dn + dr), s)
+        p["mla_wdkv"] = draw(La, (D, r), s)
+        p["mla_wkr"] = draw(La, (D, dr), s)
+        p["mla_kv_norm"] = jnp.ones((La, r), dt)
+        p["mla_wuk"] = draw(La, (H, dn, r), dn ** -0.5)
+        p["mla_wuv"] = draw(La, (H, r, dv), r ** -0.5)
+        p["wo"] = draw(La, (H, dv, D), (H * dv) ** -0.5)
+        if cfg.attn_output_gate:  # head-wise (config.attn_gate_per_head)
+            p["wg"] = draw(La, (D, H), s)
     else:
         Hk, Dh = cfg.num_kv_heads, cfg.head_dim
-        p["wq"] = draw(L, (D, H, Dh), s)
-        p["wk"] = draw(L, (D, Hk, Dh), s)
-        p["wv"] = draw(L, (D, Hk, Dh), s)
-        p["wo"] = draw(L, (H, Dh, D), (H * Dh) ** -0.5)
-    assert cfg.is_moe and not (cfg.qk_norm or cfg.attn_bias), cfg
+        p["wq"] = draw(La, (D, H, Dh), s)
+        p["wk"] = draw(La, (D, Hk, Dh), s)
+        p["wv"] = draw(La, (D, Hk, Dh), s)
+        p["wo"] = draw(La, (H, Dh, D), (H * Dh) ** -0.5)
+    assert cfg.is_moe and cfg.moe_gated \
+        and not (cfg.qk_norm or cfg.attn_bias), cfg
     k, F = cfg.moe_leading_dense_layers, cfg.intermediate_size
-    Le, E = L - k, cfg.moe_num_experts
+    Le, E, Eh = L - k, cfg.moe_num_experts, cfg.moe_bank_slots
     Fe = cfg.moe_intermediate_size or F
     p["router"] = draw(Le, (D, E), s)
     if cfg.moe_router_bias:
         p["router_bias"] = (jax.random.normal(next(keys), (Le, E), jnp.float32)
-                            * ROUTER_BIAS_SCALE)
-    p["moe_wi"] = draw(Le, (E, D, 2 * Fe), s)
-    p["moe_wo"] = draw(Le, (E, Fe, D), Fe ** -0.5)
+                            * cfg.moe_router_bias_scale)
+    p["moe_wi"] = draw(Le, (Eh, D, 2 * Fe), s)
+    p["moe_wo"] = draw(Le, (Eh, Fe, D), Fe ** -0.5)
     if cfg.moe_num_shared_experts:
-        Fs = F * cfg.moe_num_shared_experts
+        Fs = cfg.moe_shared_width
         p["shared_wi"] = draw(Le, (D, 2 * Fs), s)
         p["shared_wo"] = draw(Le, (Fs, D), Fs ** -0.5)
     if k:
@@ -478,8 +486,55 @@ def _init_sublayer_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Arr
     return p
 
 
+def _init_kda_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
+    """Random-init params of a model whose layers are 'kda' mixers and latent
+    attention, each over a mixture feed-forward, behind leading 'kda' layers
+    over a dense SwiGLU (``cfg.recurrent_over_mixture``): the norms, the
+    latent layers', the mixture's, the leading layers' and the table's leaves
+    as ``_init_layered_params`` draws them, and the KDA leaves [Lk, ...] (Lk
+    counts the leading layers too), each drawn a layer at a time:
+    ``kda_wqkv`` [3 Hk Dk, D] (out by in: q's rows, then k's, then v's; plain
+    matrices with the hidden size minor, as the lightning leaves are),
+    ``kda_wf`` (the decay gate's) and ``kda_wg`` (the output gate's)
+    [Hk Dk, D], ``kda_wb`` [Hk, D] (the write strength's), ``kda_wo``
+    [Hk Dk, D] (in by out), ``kda_conv_w`` [K, 3 Hk Dk] (tap k multiplies the
+    row K-1-k tokens back; no bias), ``kda_a_log`` [Hk], ``kda_dt_bias``
+    [Hk Dk], ``kda_o_norm`` [Dk]. ``A_log`` is the log of a uniform draw in
+    [0.5, 2] and ``dt_bias`` uniform in [-5, 0], so that a channel's
+    log-decay ``bound * sigmoid(exp(A_log) (W_f x + dt_bias))`` spreads from
+    near the bound (a channel that forgets in a token) to 1e-4 of it (one
+    that keeps thousands): a state that was rounded, or a gate of another
+    form, then shows; the output norm's weight is drawn around 1."""
+    dt = cfg.jax_dtype
+    D, Lk = cfg.hidden_size, cfg.num_kda_layers
+    Di, Hk, K = cfg.kda_d_inner, cfg.kda_heads, cfg.kda_d_conv
+    assert cfg.is_mla and not cfg.mla_q_lora_rank, cfg
+    rest, own = jax.random.split(key)
+    p = _init_layered_params(cfg, rest)
+    norm, keys = _stack_drawer(own, dt, 12)
+    s = D ** -0.5
+    p.update({
+        "kda_wqkv": norm(Lk, (3 * Di, D), s),
+        "kda_wf": norm(Lk, (Di, D), s),
+        "kda_wg": norm(Lk, (Di, D), s),
+        "kda_wb": norm(Lk, (Hk, D), s),
+        "kda_wo": norm(Lk, (Di, D), Di ** -0.5),
+        "kda_conv_w": norm(Lk, (K, 3 * Di), K ** -0.5),
+        "kda_o_norm": (1.0 + 0.1 * jax.random.normal(
+            next(keys), (Lk, cfg.kda_head_dim), jnp.float32)).astype(dt),
+        "kda_a_log": jnp.log(jax.random.uniform(
+            next(keys), (Lk, Hk), jnp.float32, 0.5, 2.0)),
+        "kda_dt_bias": jax.random.uniform(
+            next(keys), (Lk, Di), jnp.float32, -5.0, 0.0),
+    })
+    return p
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Array]:
     """Random-init params (scaled normal); shapes match param_logical_axes."""
+    if cfg.has_kda:
+        assert cfg.recurrent_over_mixture, "kda layers: over a mixture"
+        return _init_kda_params(cfg, key)
     if cfg.single_sublayer:
         return _init_sublayer_params(cfg, key)
     if cfg.has_lightning:
@@ -677,7 +732,11 @@ def moe_block(
     bank fetch) and the slot index is the expert's less ``moe_held_first``.
     The counts returned are then by held slot [Eh] and the third result
     gains a fourth entry, the held copies of live tokens
-    (``llmd_tpu:moe_held_copies_total``).
+    (``llmd_tpu:moe_held_copies_total``). ``cfg.moe_n_group`` over 1 (the
+    group-limited choice, ``ModelConfig.moe_n_group``): the third result is
+    ``[dropped, bias_moved, routed, held, group_kept]``, the last the routed
+    copies that the plain top-k of the same biased scores would have chosen
+    too (``llmd_tpu:moe_group_kept_copies_total``).
     """
     T, D = x.shape
     E, k = cfg.moe_num_experts, cfg.moe_top_k
@@ -690,8 +749,23 @@ def moe_block(
     if sigmoid:
         scores = jax.nn.sigmoid(logits)
         _, plain = lax.top_k(scores, k)  # the choice the bias did not move
-        topi = plain if router_bias is None else lax.top_k(
-            scores + router_bias.astype(jnp.float32)[None, :], k)[1]
+        biased = scores if router_bias is None else (
+            scores + router_bias.astype(jnp.float32)[None, :])
+        if cfg.moe_n_group > 1:
+            # the group limit: a group's score is the sum of its two best,
+            # the best ``moe_topk_group`` groups are kept, the top-k is
+            # taken among their experts (``ungrouped``: what the plain top-k
+            # of the same scores would have taken, for the counter)
+            _, ungrouped = lax.top_k(biased, k)
+            G = cfg.moe_n_group
+            by_group = biased.reshape(T, G, E // G)
+            group_score = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)
+            _, best = lax.top_k(group_score, cfg.moe_topk_group)
+            kept = jnp.sum(jax.nn.one_hot(best, G, dtype=jnp.int32), axis=1)
+            topi = lax.top_k(jnp.where(
+                kept[:, :, None] > 0, by_group, -jnp.inf).reshape(T, E), k)[1]
+        else:
+            topi = plain if router_bias is None else lax.top_k(biased, k)[1]
         topw = jnp.take_along_axis(scores, topi, axis=-1)
         topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-20) \
             * cfg.moe_routed_scaling
@@ -711,6 +785,9 @@ def moe_block(
         chosen = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.int32), axis=1)
         unmoved = jnp.sum(jax.nn.one_hot(plain, E, dtype=jnp.int32), axis=1)
         bias_moved = jnp.sum(chosen * (1 - unmoved) * valid)
+        if cfg.moe_n_group > 1:
+            group_kept = jnp.sum(chosen * jnp.sum(jax.nn.one_hot(
+                ungrouped, E, dtype=jnp.int32), axis=1) * valid)
     if held:
         assert eplb is None and sigmoid, "a share of the experts: no EPLB"
         routed = jnp.sum(counts)
@@ -803,7 +880,11 @@ def moe_block(
         dropped = jnp.zeros((), jnp.int32)
     else:
         dropped = jnp.sum(counts) - kept  # routed minus kept == capacity drops
-    if sigmoid:
+    if sigmoid and cfg.moe_n_group > 1:
+        dropped = jnp.stack([dropped, bias_moved,
+                             routed if held else jnp.sum(counts),
+                             jnp.sum(counts), group_kept])
+    elif sigmoid:
         dropped = jnp.stack([dropped, bias_moved, routed, jnp.sum(counts)]
                             if held else
                             [dropped, bias_moved, jnp.sum(counts)])
@@ -882,6 +963,16 @@ def init_state(cfg: ModelConfig, seats: int) -> dict[str, jax.Array]:
             (cfg.num_lightning_layers, S, cfg.lightning_heads,
              cfg.lightning_head_dim, cfg.lightning_head_dim),
             jnp.dtype(cfg.lightning_state_dtype))
+    if cfg.has_kda:
+        # the lightning layers' pool under its name, a head's delta-rule
+        # state held transposed (ops/kda_attention), and a conv window over
+        # the q, k and v channels side by side
+        state["lin"] = jnp.zeros(
+            (cfg.num_kda_layers, S, cfg.kda_heads, cfg.kda_head_dim,
+             cfg.kda_head_dim), jnp.dtype(cfg.lightning_state_dtype))
+        state["conv"] = jnp.zeros(
+            (cfg.num_kda_layers, cfg.kda_d_conv - 1, S, 3 * cfg.kda_d_inner),
+            cfg.jax_dtype)
     return state
 
 
@@ -1421,6 +1512,52 @@ def lightning_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, lin: jax.Array,
               (y * gate).astype(cfg.jax_dtype).reshape(N, Hl * Dl)), lin
 
 
+def kda_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
+              lin: jax.Array, o, plan: dict, row_slots, cu_q_lens: jax.Array,
+              live: jax.Array, fresh: jax.Array, kda_impl, mm):
+    """KDA layer ``o``'s mixer (``o`` traced: the layer's ordinal among the
+    kda layers) on the normed rows ``h`` [N, D] of a flat mixed batch;
+    returns (out [N, D], conv pool, state pool).
+
+    ``conv`` [Lk, K - 1, S, 3 Di] is the conv window's pool over the q, k
+    and v channels (``conv_window``, with the call's ``plan``); ``lin`` [Lk *
+    S, Hk, Dk, Dk] the matrix-state pool with the layer folded into the slot
+    axis, as the kernel indexes it. ``row_slots``, ``live`` and ``fresh`` as
+    for ``mamba_mixer``. One product gives the pre-conv rows of q, k and v;
+    the conv, SiLU, the L2 norms of q and k a head (their sums of squares a
+    matrix product, ``_head_mean_sq``: a recurrent state stands behind
+    them), the decay gate, the write strength, the recurrence (``kda_impl``,
+    ops/kda_attention), the output norm a head and the gate a lane are
+    float32."""
+    B, N = live.shape[0], h.shape[0]
+    Hk, Dk, Di, K = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_d_inner, cfg.kda_d_conv
+    f32 = jnp.float32
+    xr = mm("kda_wqkv", "nd,ed->ne", h)  # pre-conv rows (model dtype)
+    taps, conv = conv_window(conv, o, xr, plan)
+    vec = lp["kda_conv_w"].astype(f32)
+    acc = vec[0] * taps[0].astype(f32)
+    for t in range(1, K):
+        acc = acc + vec[t] * taps[t].astype(f32)
+    qkv = jax.nn.silu(acc).reshape(N, 3, Hk, Dk)
+
+    def unit(x):  # x / |x|_2 a head
+        return x * lax.rsqrt(_head_mean_sq(x) * Dk + 1e-6)
+
+    q, k, v = unit(qkv[:, 0]) * Dk ** -0.5, unit(qkv[:, 1]), qkv[:, 2]
+    gate_in = mm("kda_wf", "nd,ed->ne", h, f32) + lp["kda_dt_bias"].astype(f32)
+    g = cfg.kda_gate_lower_bound * jax.nn.sigmoid(
+        jnp.exp(lp["kda_a_log"].astype(f32))[None, :, None]
+        * gate_in.reshape(N, Hk, Dk))
+    b = jax.nn.sigmoid(mm("kda_wb", "nd,hd->nh", h, f32))
+    slots = o * conv.shape[2] + (
+        jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
+    y, lin = kda_impl(q, k, v, g, b, lin, slots, cu_q_lens, live, fresh)
+    y = _head_norm(y, lp["kda_o_norm"], cfg.rms_eps)
+    gate = jax.nn.sigmoid(mm("kda_wg", "nd,ed->ne", h, f32))
+    return mm("kda_wo", "ne,ed->nd",
+              (y.reshape(N, Di) * gate).astype(cfg.jax_dtype)), conv, lin
+
+
 # ---------------------------------------------------------------------------
 # Full forward over the scanned layer stack
 # ---------------------------------------------------------------------------
@@ -1445,7 +1582,7 @@ def _weight_mm(lp: dict, key: str, pattern: str, xin: jax.Array, out=None):
 def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
                   positions, seq_slots, cu_q_lens, state_slots, scan_impl,
                   lin_impl=None, ssd_impl=None, expert_layer=None,
-                  expert_keys=()):
+                  expert_keys=(), kda_impl=None):
     """The layer stack of a model whose layers differ in kind and in
     parameter shapes: a scan over the periods of ``cfg.layer_kinds`` whose
     body runs the period's runs of one kind (7 mamba, 1 attention, 6 mamba),
@@ -1477,6 +1614,14 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
     ordinal among the expert layers). Their counts [Le, slots] and drops
     ride the scans' carry and are returned.
 
+    'kda' mixers over a mixture (``cfg.recurrent_over_mixture``): a layer is
+    its mixer ('kda': ``kda_mixer`` on the ``lin`` and ``conv`` pools;
+    'attention': ``attention_layer``, latent, which runs the feed-forward
+    itself) and then ``expert_layer`` on the leaves of its ordinal among the
+    mixture layers; the ``cfg.moe_leading_dense_layers`` leading layers,
+    'kda' mixers over the dense SwiGLU, are written out before the scan over
+    the periods. Counts and drops ride the carry as above.
+
     Returns (x, flat KV pool, state, expert counts, drops)."""
     from llmd_tpu.ops.selective_scan import row_flags, selective_scan_xla
 
@@ -1493,8 +1638,14 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
         ssd_impl = ssd_impl or mamba2_ssd_xla
         pools["ssm"] = state["ssm"].reshape((-1,) + state["ssm"].shape[2:])
         pools["conv"] = state["conv"]
+    if cfg.has_kda:
+        from llmd_tpu.ops.kda_attention import kda_attention_xla
+
+        kda_impl = kda_impl or kda_attention_xla
+        pools["lin"] = state["lin"].reshape((-1,) + state["lin"].shape[2:])
+        pools["conv"] = state["conv"]
     live, fresh = row_flags(positions, cu_q_lens)
-    if cfg.has_mamba or cfg.has_mamba2:
+    if "conv" in pools:
         plan = window_plan(pools["conv"].shape, x.shape[0], state_slots,
                            seq_slots, cu_q_lens, live, fresh)
     if cfg.has_lightning:
@@ -1512,7 +1663,8 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
     if cfg.has_mamba2:
         params = dict(params, **mamba2_vectors(cfg, params))
     counted = ()
-    if cfg.single_sublayer and cfg.is_moe:
+    lead = cfg.moe_leading_dense_layers  # (over 0 beside 'kda' layers only)
+    if (cfg.single_sublayer or cfg.has_kda) and cfg.is_moe:
         # what the expert layers report, by their ordinal, on the carry
         counted = ("moe_cnt", "moe_drop")
         _, cnt0, drop0 = jax.eval_shape(
@@ -1524,7 +1676,11 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
     own = {"mamba": present("mamba_in", "mamba_x", "mamba_dt", "mamba_a_log",
                             "mamba_out", "mamba_vec", "mamba_norms"),
            "attention": present("wq", "wk", "wv", "wo", "wg", "q_norm",
-                                "k_norm"),
+                                "k_norm", "mla_wq", "mla_wdkv", "mla_wkr",
+                                "mla_kv_norm", "mla_wuk", "mla_wuv"),
+           "kda": present("kda_wqkv", "kda_wf", "kda_wg", "kda_wb", "kda_wo",
+                          "kda_conv_w", "kda_o_norm", "kda_a_log",
+                          "kda_dt_bias"),
            "lightning": present("lin_wq", "lin_wk", "lin_wv", "lin_wg",
                                 "lin_wo", "lin_q_norm", "lin_k_norm",
                                 "lin_o_norm"),
@@ -1536,8 +1692,52 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
         return {k: lax.dynamic_index_in_dim(params[k], i, 0, keepdims=False)
                 for k in keys}
 
+    if cfg.has_kda:
+        # the feed-forward's leaves are taken by the layer that runs it
+        shared = present("attn_norm", "mlp_norm")
+        dense = present("wi", "wo_mlp")
+
+    def counts_kept(pools, cnt, drop, e):
+        return {**pools,
+                "moe_cnt": lax.dynamic_update_index_in_dim(
+                    pools["moe_cnt"], cnt, e, 0),
+                "moe_drop": pools["moe_drop"] + drop}
+
+    def kda_layer(kind, carry, l, o, leading=False):
+        """Layer ``l``, the ``o``-th of its kind ('kda' or 'attention'), of
+        a stack of mixers over a mixture; a ``leading`` layer's feed-forward
+        is the dense one."""
+        x, flat_cache, pools = carry
+        e = l - lead  # the layer's ordinal among the mixture layers
+        lp = {**leaves(shared, l), **leaves(own[kind], o),
+              **(leaves(dense, l) if leading else leaves(expert_keys, e))}
+        if kind == "attention":
+            (x, flat_cache), (cnt, drop) = attention_layer(
+                (x, flat_cache), lp, o, cfg.attn_window_pattern[0],
+                cfg.rope_pattern[0], moe_ordinal=e)
+            return x, flat_cache, counts_kept(pools, cnt, drop, e)
+
+        def mm(key, pattern, xin, out=None):
+            return _weight_mm(lp, key, pattern, xin, out)
+
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        o_mix, conv, lin = kda_mixer(
+            cfg, lp, h, pools["conv"], pools["lin"], o, plan, state_slots,
+            cu_q_lens, live, fresh, kda_impl, mm)
+        pools = {**pools, "conv": conv, "lin": lin}
+        x = _joined(cfg, x, o_mix)
+        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        if leading:
+            y = swiglu(h, None, None, mm=mm) if "wi_q" in lp else swiglu(
+                h, lp["wi"], lp["wo_mlp"])
+            return _joined(cfg, x, y), flat_cache, pools
+        y, cnt, drop = expert_layer(h, lp, e)
+        return _joined(cfg, x, y), flat_cache, counts_kept(pools, cnt, drop, e)
+
     def one_layer(kind, carry, l, o):
         """Layer ``l``, the ``o``-th of its kind."""
+        if cfg.has_kda:
+            return kda_layer(kind, carry, l, o)
         x, flat_cache, pools = carry
         lp = {**leaves(shared, l), **leaves(own[kind], o)}
         if kind == "attention":
@@ -1553,11 +1753,8 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         if kind == "experts":
             y, cnt, drop = expert_layer(h, lp, o)
-            return _joined(cfg, x, y), flat_cache, {
-                **pools,
-                "moe_cnt": lax.dynamic_update_index_in_dim(
-                    pools["moe_cnt"], cnt, o, 0),
-                "moe_drop": pools["moe_drop"] + drop}
+            return _joined(cfg, x, y), flat_cache, counts_kept(
+                pools, cnt, drop, o)
         if kind == "mamba2":
             o_mix, conv, ssm = mamba2_mixer(
                 cfg, lp, h, pools["conv"], pools["ssm"], o, plan, state_slots,
@@ -1587,6 +1784,8 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
         seen = dict.fromkeys(own, 0)
         for kind, j0, n in cfg.layer_runs:
             l0, o0 = i * period + j0, i * count[kind] + seen[kind]
+            if lead:  # the leading layers stand before the periods
+                l0, o0 = l0 + lead, o0 + (lead if kind == "kda" else 0)
             if n == 1:
                 carry = one_layer(kind, carry, l0, o0)
             else:
@@ -1597,9 +1796,12 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
             seen[kind] += n
         return carry, None
 
+    carry = (x, flat_cache, pools)
+    for l in range(lead):
+        carry = kda_layer("kda", carry, l, jnp.int32(l), leading=True)
     (x, flat_cache, pools), _ = lax.scan(
-        one_period, (x, flat_cache, pools),
-        jnp.arange(cfg.num_layers // period, dtype=jnp.int32))
+        one_period, carry,
+        jnp.arange((cfg.num_layers - lead) // period, dtype=jnp.int32))
     return (x, flat_cache,
             {k: v.reshape(state[k].shape) for k, v in pools.items()
              if k not in counted}, *(pools[k] for k in counted))
@@ -1627,6 +1829,7 @@ def forward_core(
     scan_impl=None,  # ops/selective_scan impl (mamba layers)
     lin_impl=None,  # ops/lightning_attention impl (lightning layers)
     ssd_impl=None,  # ops/mamba2_ssd impl (mamba2 layers)
+    kda_impl=None,  # ops/kda_attention impl (kda layers)
     query_attn_impl=None,  # attention impl for one-query rows (sparse selection)
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Run a flat mixed batch through the model, writing K/V into the paged cache.
@@ -1957,6 +2160,10 @@ def forward_core(
             # latent-weighted sum [..., :rank] re-expands per head via W_UV
             o_heads = jnp.einsum("nhr,hrv->nhv",
                                  attn[..., :cfg.mla_kv_lora_rank], lp["mla_wuv"])
+            if cfg.attn_gate_per_head:  # one scalar a head
+                o_heads = (o_heads.astype(jnp.float32) * jax.nn.sigmoid(
+                    _mm("wg", "nd,dh->nh", h).astype(jnp.float32)
+                )[:, :, None]).astype(o_heads.dtype)
             # one product over the H * dv lanes of a row: contracted over
             # (h, v) as two axes, XLA picks how to split the sum by the
             # number of rows, and on the chip a decode row's projection
@@ -2001,7 +2208,7 @@ def forward_core(
         x, flat_cache, state, *counted = _hybrid_stack(
             cfg, params, layer, x, cache.reshape(Ptot * ps, HkC, Dhp), state,
             positions, seq_slots, cu_q_lens, state_slots, scan_impl, lin_impl,
-            ssd_impl, expert_layer, expert_keys)
+            ssd_impl, expert_layer, expert_keys, kda_impl)
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         return (x, {"kv": flat_cache.reshape(Ptot, ps, HkC, Dhp), **state},
                 *(counted or (jnp.zeros((cfg.num_layers, 0), jnp.int32),

@@ -622,21 +622,22 @@ class EngineMetrics:
             labelnames=("impl", "state_dtype", "prefix_reuse"))
         self.linear_attn_tokens = reg.counter(
             "llmd_tpu:linear_attn_tokens_total",
-            "Tokens one lightning (linear-attention) layer's recurrence is "
+            "Tokens one linear-attention layer's recurrence (a lightning or a "
+            "kda layer's) is "
             "given, per dispatch, from the lengths the step packed: "
             "rows=prefill the tokens of prefill chunks, rows=decode those of "
             "decode rows (a fused decode call: the steps its live rows have "
-            "left). A model without lightning layers feeds neither",
+            "left). A model without linear-attention layers feeds neither",
             labelnames=("rows",))
         self.linear_state_resets = reg.counter(
             "llmd_tpu:linear_state_resets_total",
-            "Rows dispatched from position 0, which start a lightning "
+            "Rows dispatched from position 0, which start a linear-attention "
             "layer's matrix state from zero (a sequence's first prefill "
             "chunk, or the first of one that was preempted)")
         self.linear_state_slots = reg.gauge(
             "llmd_tpu:linear_state_slots_in_use",
             "Matrix-state slots whose seat holds a sequence (a seat owns its "
-            "slot; 0 for a model without lightning layers)")
+            "slot; 0 for a model without a linear-attention layer)")
         self.sparse_attn_rows = reg.counter(
             "llmd_tpu:sparse_attn_rows_total",
             "Query rows (tokens) a sparse-attention layer is given, per "
@@ -951,6 +952,13 @@ class EngineMetrics:
             "mixture layers: what the expert GEMMs are given. Over "
             "moe_routed_copies_total, the share of the layer's work done "
             "here; fed only by a model that holds a share of its experts")
+        self.moe_group_kept_copies = reg.counter(
+            "llmd_tpu:moe_group_kept_copies_total",
+            "Routed copies of live tokens that the plain top-k of the same "
+            "biased scores over all experts would have chosen too, summed "
+            "over the mixture layers, under the group-limited choice "
+            "(ModelConfig.moe_n_group over 1). moe_routed_copies_total less "
+            "this is what the group limit moved; fed by no other model")
         self.moe_expert_load = reg.gauge(
             "llmd_tpu:moe_expert_load_max_over_mean",
             "Of the last step that routed tokens (a unified step, or the k "

@@ -56,6 +56,7 @@ VARIANTS = {
     "tiny-sala": ("tiny-sala", dict(page_size=2, num_pages=512,
                                     max_model_len=256), STATEFUL),
     "tiny-nemotron-h": ("tiny-nemotron-h", dict(num_pages=128), STATEFUL),
+    "tiny-ling": ("tiny-ling", dict(num_pages=128), STATEFUL),
     "tiny-moe+pallas": ("tiny-moe", PALLAS, ("unified", "decode")),
     "tiny-glm+pallas": ("tiny-glm", dict(PALLAS, page_size=4),
                         ("unified", "decode")),
@@ -67,6 +68,8 @@ VARIANTS = {
         ("unified", "decode")),
     "tiny-nemotron-h+pallas": ("tiny-nemotron-h", dict(PALLAS, num_pages=128),
                                ("unified", "decode")),
+    "tiny-ling+pallas": ("tiny-ling", dict(PALLAS, num_pages=128),
+                         ("unified", "decode")),
     # four query heads a KV head (Mistral's ratio): the one-query rows of
     # both programs on the repo's rows kernel, in groups planned once
     "tiny-gqa4+pallas": ("tiny", dict(PALLAS, model=dict(
@@ -192,6 +195,19 @@ PARENT_STABLEHLO = {
         "f2b19aad33f9eff09243426bd6f58840e92f8577f2aa4c8c27c2043c085de669",
     "tiny-gqa4+pallas/decode":
         "ca67caa859ca21be992a361c676bcd723933e5a3a74fa3b19a9df2f86cc5f8a5",
+    # new in ISSUE 51 ('kda' mixers beside a latent-attention layer, each
+    # over a group-limited mixture, behind a leading dense layer), taken on
+    # that PR's tree; every row above is as it was on 3f80dd3
+    "tiny-ling/unified":
+        "629b25e4075991c980911d7b8420664d3ab8a2b0a86fca8bdaea735cc52eace1",
+    "tiny-ling/decode":
+        "b96a4bfda76c295a2bd31dd4bbde2127a96da251aef3d9aa8a5857597e177f0f",
+    "tiny-ling/decode_masked":
+        "1cfd1e470d51de414c6d29ad5f9360abb107cdc7f92c9b497662efd9a337572a",
+    "tiny-ling+pallas/unified":
+        "4d6a617c49c5597e5f72aab6c4feb698c3be7c66cd58cb2ff5735ffd70550317",
+    "tiny-ling+pallas/decode":
+        "970aa0670d02932d90d26bc4e34b6cdd8797d6a3bf955d3a19d5c4838046737f",
 }
 
 

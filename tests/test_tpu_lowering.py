@@ -688,3 +688,102 @@ def test_sparse_hybrid_step_programs_compile_for_v5e_at_the_cells_sizes(
     assert "lightning_attention" in text
     assert "ragged_paged_attention_kernel" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 1024 * 1024
+
+
+@pytest.mark.parametrize("n", [64, 320], ids=["decode", "unified"])
+def test_kda_attention_compiles_for_v5e_at_the_cells_shapes(one_chip, n):
+    """The kda layers' kernel at ling-3.0-flash-vl's widths (32 heads of 128,
+    6 layers of 65 slots folded into the pool, float32 state) and both step
+    programs' token budgets goes through Mosaic and the TPU compiler here:
+    float32 products at the highest precision, the transposed product U^T K at
+    16 and at 8 rows, a row of every head's lanes loaded at a dynamic
+    sublane, and the VMEM of eight heads' resident blocks are what interpret
+    mode cannot refuse."""
+    from llmd_tpu.ops.kda_attention import kda_attention_pallas
+
+    H, D, seats = 32, 128, 64
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (spec((n, H, D), jnp.float32),) * 4 + (
+        spec((n, H), jnp.float32),
+        spec((6 * (seats + 1), H, D, D), jnp.float32),
+        spec((seats,), jnp.int32), spec((seats + 1,), jnp.int32),
+        spec((seats,), jnp.bool_), spec((seats,), jnp.bool_))
+    compiled = jax.jit(kda_attention_pallas,
+                       donate_argnums=(5,)).lower(*args).compile()
+    assert "kda_attention" in compiled.as_text()
+    # in place: the 0.82 GB pool is neither copied nor a temporary
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_kda_latent_step_programs_compile_for_v5e_at_the_cells_sizes(
+        one_chip, program):
+    """ling-3.0-flash-vl's whole forward (the file's 7 layers at the
+    published widths, 128 held experts a layer, the cell's pools: 49,152
+    latent pages, 65 matrix-state slots a kda layer) through the TPU compiler
+    as each step program packs it, with the Pallas kda, latent-attention and
+    grouped-GEMM kernels: 10.5 GB of leaves and 1.85 GB of pools as
+    arguments, and no stacked leaf or pool copied at the call's entry."""
+    import functools
+    import json
+    import sys
+
+    from llmd_tpu.models.transformer import (
+        forward_core, init_cache, init_params, init_state)
+    from llmd_tpu.ops import mla_attention
+    from llmd_tpu.ops.grouped_gemm import make_moe_matmul
+    from llmd_tpu.ops.kda_attention import kda_attention_pallas
+    from llmd_tpu.ops.moe_dispatch import make_sorted_dispatch
+
+    sys.path.append(os.path.join(ROOT, "perfbench"))
+    try:
+        from reference import hybrid_kda_mla_moe as family
+    finally:
+        sys.path.remove(os.path.join(ROOT, "perfbench"))
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "ling-3.0-flash-vl.json")) as f:
+        conf = json.load(f)
+    cfg, e = family.model_config(conf), conf["engine"]
+    B = e["max_batch_size"]
+    N = B if program == "decode" else B + e["prefill_chunk"]
+    maxp = e["max_model_len"] // e["page_size"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    pools = on_chip(jax.eval_shape(lambda: {
+        "kv": init_cache(cfg, e["num_pages"], e["page_size"]),
+        **init_state(cfg, B)}))
+    leaves = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert 10.4e9 < leaves < 10.6e9  # 5.23 G parameters in bf16
+    attn = functools.partial(mla_attention.mla_paged_attention,
+                             rank=cfg.mla_kv_lora_rank)
+    attn.plan = mla_attention.plan
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, pools, tokens, positions, seq_slots, pt, lens, cu, ns,
+             slots):
+        return forward_core(
+            cfg, params, pools, tokens, positions, seq_slots, pt, lens,
+            cu_q_lens=cu, num_seqs=ns, attn_impl=attn,
+            moe_matmul_impl=make_moe_matmul(),
+            moe_dispatch_impl=make_sorted_dispatch(None, use_pallas=True),
+            state_slots=slots if program == "unified" else None,
+            kda_impl=kda_attention_pallas)[:2]
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, i32(N), i32(N), i32(N), i32(B, maxp), i32(B),
+        i32(B + 1), i32(1), i32(B)).compile(
+            compiler_options={"xla_allow_excess_precision": False})
+    text = compiled.as_text()
+    assert "kda_attention" in text and "mla_ragged_paged_attention" in text
+    assert "ragged_grouped_gemm" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 1024 * 1024
