@@ -31,17 +31,37 @@ a block, ``K~ = exp(G) * K``, ``K^ = exp(-G) * K``, ``Q~ = exp(G) * Q``:
 negated strictly lower triangle, which is nilpotent at 16 rows. ``exp(-G)``
 is what the bound on the gate is for: a channel's log-decay is above -5 a
 token (``ModelConfig.kda_gate_lower_bound``, refused below that), so ``BLOCK``
-tokens sum above -80 and ``exp(80)`` is a float32. A block of ONE token (every decode row, and a chunk's last
-token where it stands alone) skips the triangle: ``U = b v - (b a k) S_prev``,
-``O = a q S_prev + (q . k) U``.
+tokens sum above -80 and ``exp(80)`` is a float32.
+
+A block of ONE token (every decode row of either step program, and a chunk's
+last token where it stands alone) is the recurrence itself, on the vector
+unit, in one pass over the head's tile ``S^T`` [value lane e, key lane d]
+(16 registers of 8 x 128 at heads of 128) and with no matrix product: at 8 d^2
+operations beside 128 KB of state a head it is bound by the state's bytes,
+and two products a head padded to eight rows were not (tools/kda_sweep.py):
+
+    sp      = S^T * a                     a = exp(g), along every row
+    u[e]    = b v[e] - sum_d sp[e, d] (b k)[d]
+    S^T    <- sp + u[:, None] * k[None, :]
+    o[e]    = sum_d S^T[e, d] q[d]
+
+``a``, ``b k``, ``k`` and ``q`` lie along lanes as they arrive and broadcast
+along the tile's rows; the two sums are lane reductions, whose results come
+back along every lane, so ``u`` needs no broadcast. Only ``v`` and ``o`` are
+columns of the tile (one value a row of it): a grid step's heads' ``b v`` are
+turned once a token ([heads, d] -> [d, heads], one small transpose) and head
+``h``'s column is added into lane ``h`` of the tile of products before its
+reduction, so that ``u`` comes out of the sum whole; the heads' ``o`` columns
+are gathered in a tile lane by lane and turned back the same way.
 
 A block groups its sums by its own boundaries, so a token's result depends on
 where its block starts. The engine therefore starts a prompt's every chunk on
 a multiple of ``BLOCK`` (``engine.py``, the plan of a unified step), as for
 the lightning layers. q, k and v arrive in the model's type, g and b in
-float32; every product runs in float32 at the highest precision and the state
-is float32 between blocks and in the pool (``ModelConfig.
-lightning_state_dtype``, rounded to it once a call).
+float32; every product runs in float32 (a block of several tokens on the
+matrix unit at the highest precision, a block of one in the vector unit's
+float32 lanes) and the state is float32 between blocks and in the pool
+(``ModelConfig.lightning_state_dtype``, rounded to it once a call).
 
 The kernel's name in a device trace is ``kda_attention`` (the benchmark's
 ``kda_attention_dev_share`` and both rooflines match on it).
@@ -131,26 +151,37 @@ def _kernel(cu_ref, slots_ref, flags_ref, q_ref, k_ref, v_ref, g_ref, b_ref,
         lower = (i >= j).astype(F32)
 
         def one_token(t0):
-            """A block of one token: the rank-one update, no triangle."""
-            outs = []
-            row = [x[pl.ds(t0, 1), :] for x in (q_ref, k_ref, v_ref, g_ref,
-                                                 b_ref)]
-            pad = jnp.zeros((7, d), F32)  # a product's rows: a sublane tile
+            """A block of one token: the recurrence in float32 lanes, one
+            pass over each head's tile of the state (the module's words)."""
+            q1, k1, v1, g1, b1 = (x[pl.ds(t0, 1), :] for x in (
+                q_ref, k_ref, v_ref, g_ref, b_ref))  # [1, hb * d] each
+            a1, kb, bv = jnp.exp(g1), k1 * b1, b1 * v1
+            # b v is wanted down a tile's rows and arrives along lanes: the
+            # heads' rows as a [d, d] tile, turned, hold head h in lane h
+            bv = jnp.concatenate(
+                [bv[:, h * d:(h + 1) * d] for h in range(hb)]
+                + [jnp.zeros((d - hb, d), F32)], axis=0).T
+            # q, k, a and b k pass through row 0 of the block scratches: a
+            # head's lanes of a value loaded at t0 do not broadcast along a
+            # tile's rows (Mosaic: "Invalid input layout"), a static load does
+            for scr, x in ((q_scr, q1), (k_scr, k1), (g_scr, a1), (b_scr, kb)):
+                scr[0:1, :] = x
+            lane = lax.broadcasted_iota(jnp.int32, (d, d), 1)
+            o = jnp.zeros((d, d), F32)
             for h in range(hb):
                 lanes = slice(h * d, (h + 1) * d)
-                q1, k1, v1, g1, b1 = (x[:, lanes] for x in row)
-                a1 = jnp.exp(g1)
-                st = s_scr[h]  # [E, D] float32
-                # rows 0 and 1 of one product: (a q) S and (b a k) S
-                both = _dot(jnp.concatenate(
-                    [q1 * a1, k1 * a1 * b1, pad[:6]], axis=0), st, _NT)
-                u = b1 * v1 - both[1:2]
-                qk = jnp.sum(q1 * k1, axis=1, keepdims=True)
-                outs.append(both[0:1] + qk * u)
-                s_scr[h] = st * a1 + _dot(
-                    jnp.concatenate([u, pad], axis=0),
-                    jnp.concatenate([k1, pad], axis=0), _TN)
-            o_ref[pl.ds(t0, 1), :] = jnp.concatenate(outs, axis=1)
+                sp = s_scr[h] * g_scr[0:1, lanes]  # [E, D] float32
+                # u = b v - (b a k) S: head h's b v joins lane h of the
+                # products, and the sum hands u back along every lane
+                u = jnp.sum(jnp.where(lane == h, bv, 0.0)
+                            - sp * b_scr[0:1, lanes], axis=1, keepdims=True)
+                sn = sp + u * k_scr[0:1, lanes]
+                s_scr[h] = sn
+                o = jnp.where(lane == h, jnp.sum(
+                    sn * q_scr[0:1, lanes], axis=1, keepdims=True), o)
+            o = o.T  # head h in row h
+            o_ref[pl.ds(t0, 1), :] = jnp.concatenate(
+                [o[h:h + 1] for h in range(hb)], axis=1)
 
         def many_tokens(t0, r):
             valid = rows < r
@@ -219,9 +250,14 @@ def _kernel(cu_ref, slots_ref, flags_ref, q_ref, k_ref, v_ref, g_ref, b_ref,
 
 
 def head_block(num_heads: int) -> int:
-    """Heads a grid step holds: a state block of 512 KB at heads of 128
-    (tools/kda_sweep.py: 8 heads a step read 0.92 ms a decode call of 64
-    rows on the chip, 4 heads 1.02, 1 head 1.59)."""
+    """Heads a grid step holds: a state block of 512 KB at heads of 128.
+    tools/kda_sweep.py on the chip, a decode call of 64 rows at 8 / 4 / 2 / 1
+    heads a step: 0.52 / 0.61 / 0.81 / 1.38 ms (PR 53; 0.93 / 1.03 / 1.22 /
+    1.60 before a one-token block left the matrix unit). What is left at 8 is
+    the grid's: a call with 32 live rows of 64 takes what one with 55 does,
+    2.0 us a step for the 1 MB it moves in and out. 16 heads a step read 0.48
+    ms and cost every call site 0.65 s more of trace and lower a launch, so 8
+    stays."""
     return next(n for n in (8, 4, 2, 1) if num_heads % n == 0)
 
 

@@ -9,6 +9,7 @@ lanes, 8 experts in 4 groups of which 2 are kept, top-2, 4 held, pages of 4.
 from __future__ import annotations
 
 import functools
+import hashlib
 import json
 import os
 import sys
@@ -564,6 +565,97 @@ def test_a_token_does_not_depend_on_its_chunk_or_its_neighbours():
         ys.append(y[1:])
     assert (np.asarray(jnp.concatenate(ys)) == np.asarray(whole_y[:n])).all()
     assert (np.asarray(pool[3]) == np.asarray(whole_p[3])).all()
+
+
+# ------------------------------------------- the block of one token (ISSUE 53)
+def _decode_rows(gate, nb=64, seed=1):
+    """``nb`` rows of one token in slots of their own, live, idle and fresh
+    mixed; ``gate``: every channel's log-decay at the bound, near nothing,
+    or lane by lane one and the other."""
+    live = np.arange(nb) % 5 != 2
+    fresh = np.arange(nb) % 7 == 3
+    a = _ragged([1] * nb, live, fresh, seed=seed, S=nb + 6)
+    g = {"bound": jnp.full_like(a["g"], -4.999),
+         "near-nothing": jnp.full_like(a["g"], -1e-4),
+         "both": jnp.full_like(a["g"], -4.999).at[:, :, ::2].set(-1e-4),
+         "drawn": a["g"]}[gate]
+    slots = np.random.default_rng(seed).permutation(nb + 6)[:nb]
+    return dict(a, g=g, slots=jnp.asarray(slots, jnp.int32)), live, fresh
+
+
+@pytest.mark.parametrize("gate", ["bound", "near-nothing", "both", "drawn"])
+def test_one_token_rows_agree_with_the_xla_form_and_the_naive_scan(gate):
+    """The fused decode call's shape: 64 blocks of one token, in float32
+    lanes where the parent ran two matrix products a head."""
+    a, live, _ = _decode_rows(gate)
+    y0, p0 = kda_attention_xla(**a)
+    y1, p1 = kda_attention_pallas(**a, interpret=True)
+    y2, p2 = _naive(a)
+    # the tolerance of the ragged cases above: |y| <= 3, float32 against
+    # float64 of the same sums in another order
+    assert _worst(y0, y2) < 2e-5 and _worst(p0, p2) < 2e-5
+    assert _worst(y1, y2) < 2e-5 and _worst(p1, p2) < 2e-5
+    idle = np.asarray(a["slots"])[~live]
+    assert (np.asarray(p1[idle]) == np.asarray(a["pool"][idle])).all()
+    assert not np.asarray(y1[:64])[~live].any()
+    assert np.asarray(y1[:64])[live].any(axis=(1, 2)).all()
+
+
+def test_a_one_token_row_is_the_same_bits_in_both_step_programs_calls():
+    """A decode row of the fused call and the same row beside a 256-token
+    chunk of a unified step: one arithmetic, the same o and the same slot
+    (what ``cold_equals_cached`` rests on in the served cell)."""
+    a, live, fresh = _decode_rows("drawn", nb=8)
+    y0, p0 = kda_attention_pallas(**a, interpret=True)
+    chunk = _ragged([256], np.asarray([True]), np.asarray([True]), seed=2)
+    both = {k: jnp.concatenate([a[k][:8], chunk[k][:256]]) for k in "qkvgb"}
+    spare = sorted(set(range(14)) - set(np.asarray(a["slots"]).tolist()))[0]
+    y1, p1 = kda_attention_pallas(
+        **both, pool=a["pool"],
+        slots=jnp.concatenate([a["slots"], jnp.asarray([spare], jnp.int32)]),
+        cu_q_lens=jnp.asarray(list(range(9)) + [8 + 256], jnp.int32),
+        live=jnp.asarray(list(live) + [True]),
+        fresh=jnp.asarray(list(fresh) + [True]), interpret=True)
+    assert (np.asarray(y0[:8]) == np.asarray(y1[:8])).all()
+    rows = np.asarray(a["slots"])
+    assert (np.asarray(p0[rows]) == np.asarray(p1[rows])).all()
+    assert np.asarray(y1[8:264]).any() and live.any() and not live.all()
+
+
+def test_a_chunks_last_token_alone_is_the_one_token_call():
+    """33 tokens are two blocks and a token that stands alone: the same
+    bits as the 32 tokens in one call and the last in a call of its own,
+    whose state comes from the slot where the chunk's came from the
+    scratch."""
+    n = 2 * BLOCK + 1
+    a = _ragged([n], np.asarray([True]), np.asarray([False]), seed=3)
+    whole_y, whole_p = kda_attention_pallas(**a, interpret=True)
+    ys, pool = [], a["pool"]
+    for at, m in ((0, n - 1), (n - 1, 1)):
+        y, pool = kda_attention_pallas(
+            **{k: a[k][at:at + m] for k in "qkvgb"}, pool=pool,
+            slots=a["slots"], cu_q_lens=jnp.asarray([0, m], jnp.int32),
+            live=a["live"], fresh=a["fresh"], interpret=True)
+        ys.append(y)
+    assert (np.asarray(jnp.concatenate(ys)) == np.asarray(whole_y[:n])).all()
+    assert (np.asarray(pool[3]) == np.asarray(whole_p[3])).all()
+
+
+# sha256 of o and of the pool as the parent's kernel (bbe8e08) gave them in
+# interpret mode on this CPU for RAGGED["one-chunk"] at seed 0: eight blocks
+# of sixteen tokens and one of two, none of one token
+PARENT_BLOCKS = (
+    "2ed6d6f0d8765c7f3ece0ccac33df98689a7544df4a947641745826c79d7d0ac",
+    "e045b0d2df2d9baf64613ebba8ebebd60ec449fd8db753e84e3630a4a37ed763")
+
+
+def test_blocks_of_more_than_one_token_are_the_parents_bits():
+    lens, live, fresh = RAGGED["one-chunk"]
+    assert all(n % BLOCK != 1 for n in lens)
+    a = _ragged(lens, np.asarray(live, bool), np.asarray(fresh, bool))
+    y, pool = kda_attention_pallas(**a, interpret=True)
+    assert tuple(hashlib.sha256(np.asarray(x).tobytes()).hexdigest()
+                 for x in (y, pool)) == PARENT_BLOCKS
 
 
 def test_a_blocks_log_decay_stays_a_float32_at_the_gates_bound():

@@ -8,13 +8,24 @@ step.
 
     chiprun -- python3 tools/kda_sweep.py               # ~2 min
     python3 tools/kda_sweep.py --compile-only           # here: what Mosaic takes
+    git show <parent>:llmd_tpu/ops/kda_attention.py > .scratch/parent_kda.py
+    chiprun -- python3 tools/kda_sweep.py --heads 8,4 \
+        --parent .scratch/parent_kda.py                 # ~1.5 min
 
 One line of JSON a reading. ``decode``: 64 rows of one token (the fused
-call); ``unified``: 63 one-token rows and a chunk of 256 tokens. ``us_a_call``
-is one layer's call; ``gbps`` the live rows' states read and written over it.
-The block is the engine's (``ops/lightning_attention.BLOCK``, 16 tokens: the
-Neumann product is written out for it), so the heads a grid step are the one
-knob.
+call), 55 of them live; ``decode32``: the same with 32 live; ``unified``: 63
+one-token rows and a chunk of 256 tokens. ``us_a_call`` is one layer's call;
+``gbps`` the live rows' states read and written over it; ``first_call``
+lines say what a call site costs a launch before the chip sees it (trace and
+lower: every launch pays it, the compile cache keeps the Mosaic compile
+alone). The block is the engine's (``ops/lightning_attention.BLOCK``, 16
+tokens: the Neumann product is written out for it), so the heads a grid step
+are the one knob. ``--parent <file>`` loads the parent commit's
+``ops/kda_attention.py`` beside the tree's and times it in the same chip
+call: ``parent_us_a_call`` and ``parent_gbps`` beside each reading,
+``chunk_diff_vs_parent`` (the tokens and the slot of a row of more than one
+token: must be 0.0, a block of several tokens is the parent's arithmetic)
+and ``one_token_diff_vs_parent``.
 """
 
 from __future__ import annotations
@@ -33,8 +44,11 @@ H, D, LAYERS, SEATS = 32, 128, 6, 64
 def calls(jnp, np, jax, seed=0):
     """{name: args of a call} at the published shapes."""
     out = {}
-    for name, lens in (("decode", [1] * 64), ("unified", [1] * 63 + [256])):
+    for name, lens in (("decode", [1] * 64), ("decode32", [1] * 64),
+                       ("unified", [1] * 63 + [256])):
         nt, nb = sum(lens), len(lens)
+        live = np.arange(nb) % 2 == 0 if name == "decode32" \
+            else np.arange(nb) % 7 != 3
         k = jax.random.split(jax.random.PRNGKey(seed), 6)
 
         def unit(x):
@@ -51,7 +65,7 @@ def calls(jnp, np, jax, seed=0):
             slots=jnp.asarray(2 * (SEATS + 1) + np.arange(nb), jnp.int32),
             cu_q_lens=jnp.asarray(np.concatenate([[0], np.cumsum(lens)]),
                                   jnp.int32),
-            live=jnp.asarray(np.arange(nb) % 7 != 3),
+            live=jnp.asarray(live),
             fresh=jnp.asarray(np.arange(nb) % 11 == 5))
     return out
 
@@ -60,6 +74,9 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--heads", default="1,2,4,8",
                     help="heads of a slot's state a grid step holds")
+    ap.add_argument("--parent", default="",
+                    help="the parent commit's ops/kda_attention.py: timed "
+                    "beside the tree's in the same call")
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--cpu", action="store_true")
     args = ap.parse_args()
@@ -82,6 +99,8 @@ def main() -> int:
         one = SingleDeviceSharding(topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2").devices[0])
         for name, a in calls(jnp, np, jax).items():
+            if name == "decode32":  # decode's shapes
+                continue
             shapes = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one)
                       for k, v in a.items()}
             for hb in heads:
@@ -99,35 +118,72 @@ def main() -> int:
         print("no TPU", file=sys.stderr)
         return 1
     interpret = args.cpu
+    files = {"tree": kda_attention_pallas}
+    if args.parent:
+        import importlib.util
+
+        spec = importlib.util.spec_from_file_location("parent_kda",
+                                                      args.parent)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+        files["parent"] = parent.kda_attention_pallas
+    n = 1 if args.cpu else 20
     for name, a in calls(jnp, np, jax).items():
         want_y, want_pool = jax.jit(lambda kw: kda_attention_xla(**kw))(a)
-        dead = np.asarray(a["slots"])[~np.asarray(a["live"])]
-        live_rows = int(np.asarray(a["live"]).sum())
+        live = np.asarray(a["live"])
+        dead = np.asarray(a["slots"])[~live]
+        lens = np.diff(np.asarray(a["cu_q_lens"]))
+        long_tokens = np.repeat(lens > 1, lens)  # of a row of several tokens
+        nbytes = int(live.sum()) * 2 * H * D * D * 4
         rest = {k: v for k, v in a.items() if k != "pool"}
         for hb in heads:
-            # the pool is donated, as the engine's step programs donate it:
-            # without, XLA copies the whole pool (0.8 GB) around every call
-            f = jax.jit(lambda pool, kw, hb=hb: kda_attention_pallas(
-                **kw, pool=pool, hb=hb, interpret=interpret),
-                donate_argnums=0)
-            y, pool = f(a["pool"] + 0.0, rest)
-            jax.block_until_ready(pool)
-            first = {"y_max_diff": float(jnp.abs(y - want_y).max()),
-                     "y_scale": float(jnp.abs(want_y).max()),
-                     "pool_max_diff": float(jnp.abs(pool - want_pool).max()),
-                     "dead_slots_bit_for_bit": bool(
-                         (pool[dead] == a["pool"][dead]).all())}
-            n = 1 if args.cpu else 20
-            t = time.time()
-            for _ in range(n):
+            line = {}
+            for which, kernel in files.items():
+                # the pool is donated, as the engine's step programs donate
+                # it: without, XLA copies the whole pool (0.8 GB) every call
+                f = jax.jit(lambda pool, kw, hb=hb, kernel=kernel: kernel(
+                    **kw, pool=pool, hb=hb, interpret=interpret),
+                    donate_argnums=0)
+                pool = a["pool"] + 0.0
+                t = time.time()
+                lowered = f.lower(pool, rest)
+                t_lower = time.time() - t
+                f = lowered.compile()
+                print(json.dumps({
+                    "first_call": name, "heads": hb, "file": which,
+                    "trace_and_lower_s": round(t_lower, 2),
+                    "compile_s": round(time.time() - t - t_lower, 2)}),
+                    flush=True)
                 y, pool = f(pool, rest)
-            jax.block_until_ready(pool)
-            us = (time.time() - t) / n * 1e6
-            print(json.dumps({
-                "call": name, "heads": hb, "served": hb == head_block(H),
-                "us_a_call": round(us, 1),
-                "gbps": round(live_rows * 2 * H * D * D * 4 / us / 1e3, 1),
-                **first}), flush=True)
+                pre = "" if which == "tree" else "parent_"
+                line[pre + "y_max_diff"] = float(jnp.abs(y - want_y).max())
+                line[pre + "pool_max_diff"] = float(
+                    jnp.abs(pool - want_pool).max())
+                if which == "tree":
+                    tree_y, tree_rows = y, pool[a["slots"]]
+                    line.update(y_scale=float(jnp.abs(want_y).max()),
+                                dead_slots_bit_for_bit=bool(
+                                    (pool[dead] == a["pool"][dead]).all()))
+                else:
+                    dy = np.asarray(jnp.abs(tree_y - y).max(axis=(1, 2)))
+                    dp = np.asarray(jnp.abs(
+                        tree_rows - pool[a["slots"]]).max(axis=(1, 2, 3)))
+                    line["one_token_diff_vs_parent"] = float(
+                        dy[~long_tokens].max())
+                    if long_tokens.any():
+                        line["chunk_diff_vs_parent"] = float(max(
+                            dy[long_tokens].max(),
+                            dp[live & (lens > 1)].max()))
+                t = time.time()
+                for _ in range(n):
+                    y, pool = f(pool, rest)
+                jax.block_until_ready(pool)
+                us = (time.time() - t) / n * 1e6
+                line[pre + "us_a_call"] = round(us, 1)
+                line[pre + "gbps"] = round(nbytes / us / 1e3, 1)
+            print(json.dumps({"call": name, "heads": hb,
+                              "served": hb == head_block(H), **line}),
+                  flush=True)
     # a chunk whole and in two calls that start on the block: the same bits
     a = calls(jnp, np, jax)["unified"]
     chunk = slice(63, 63 + 256)
@@ -147,6 +203,18 @@ def main() -> int:
         "check": "a chunk whole and in two calls",
         "y_bit_for_bit": bool((jnp.concatenate(ys) == y).all()),
         "state_bit_for_bit": bool((pool[7] == p[7]).all())}), flush=True)
+    # a one-token row beside a chunk and in a decode-shaped call: the same
+    # bits (a request's tokens pass through both step programs)
+    y, p = f(a["pool"], {k: v for k, v in a.items() if k != "pool"})
+    rows = {k: a[k][:64] for k in "qkvgb"}
+    y2, p2 = f(a["pool"], dict(
+        rows, slots=a["slots"], live=a["live"], fresh=a["fresh"],
+        cu_q_lens=jnp.asarray(np.minimum(np.arange(65), 63), jnp.int32)))
+    s63 = a["slots"][:63]
+    print(json.dumps({
+        "check": "63 one-token rows beside a chunk and in a decode call",
+        "y_bit_for_bit": bool((y[:63] == y2[:63]).all()),
+        "state_bit_for_bit": bool((p[s63] == p2[s63]).all())}), flush=True)
     return 0
 
 
