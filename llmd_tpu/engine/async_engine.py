@@ -68,6 +68,11 @@ class AsyncLLMEngine:
                 for p in self.loop_parts}
 
     def _book_turn(self, parts: _StepParts, booked: dict) -> None:
+        programs = getattr(self.engine, "programs", None)
+        if programs is not None and programs.unread:
+            # a step program compiled in this turn (the warm-up): read its
+            # text once, here and not in step() (read_compiled_programs)
+            self.engine.read_compiled_programs()
         parts.to(None)
         for part, sec in parts.seconds.items():
             booked[part].inc(sec)

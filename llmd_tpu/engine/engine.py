@@ -699,14 +699,31 @@ class LLMEngine:
         for name, fn in progs.items():
             eligible, run = routed.get(name, (None, None))
             self.programs.register(name, fn, eligible=eligible, run=run)
-        self._unified_fn = progs["unified"]
-        self._verify_fn = progs["verify"]
-        self._verify_masked_fn = progs["verify_masked"]
-        self._decode_multi_fn = progs["decode"]
-        self._decode_multi_masked_fn = progs["decode_masked"]
-        self._embed_fn = progs["embed"]
+        # (as registered: a call notes a signature that compiled, for
+        # read_compiled_programs)
+        self._unified_fn = self.programs.fn("unified")
+        self._verify_fn = self.programs.fn("verify")
+        self._verify_masked_fn = self.programs.fn("verify_masked")
+        self._decode_multi_fn = self.programs.fn("decode")
+        self._decode_multi_masked_fn = self.programs.fn("decode_masked")
+        self._embed_fn = self.programs.fn("embed")
         # the sp ring's (backends.py::_ring_attn_impl); None where not wired
-        self._unified_ring_fn = progs.get("unified_ring")
+        self._unified_ring_fn = self.programs.fn("unified_ring")
+
+    def read_compiled_programs(self, keep_text: Optional[list] = None) -> int:
+        """Which part of the model each instruction of the step programs
+        that compiled since the last call belongs to (``ProgramRegistry.
+        read_compiled``), published as ``llmd_tpu:program_part_ops``. Not
+        part of ``step()``: the loop that drives the engine calls it after a
+        step that left ``programs.unread`` non-empty, which is the warm-up."""
+        n = self.programs.read_compiled(keep_text)
+        if n:
+            fam = self.metrics.program_part_ops
+            with fam._lock:  # a scrape sees the old series or the new
+                fam.clear()
+                for labels, value in self.programs.parts.series():
+                    fam.labels(**labels).set(value)
+        return n
 
     # ----------------------------------------------------------------- EPLB
     # Wide-EP expert load balancing (reference --enable-eplb, wide-ep

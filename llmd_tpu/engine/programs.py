@@ -27,11 +27,19 @@ device time by its module name (``jit__unified``, ``jit__decode_multi``).
   never read (a leaked in-flight call).
 * ``compile_counts()`` exposes each program's jit cache size, the
   recompile-storm probe ``test_paged_attention.py`` pins for fused decode.
+* ``read_compiled()`` reads the text of every signature that compiled since
+  it was last called and folds it into ``parts`` (``obs/program_parts.py``):
+  which part of the model (``MODEL_PARTS``, ``models/parts.py``) each
+  instruction of each compiled program belongs to. A registered program is
+  a ``StepProgram``: its call notes the arguments' shapes when the jit cache
+  grew, and nothing else; the text is read outside ``step()``, by the loop
+  that drives the engine.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
@@ -44,7 +52,48 @@ from llmd_tpu.engine.config import EngineConfig
 from llmd_tpu.engine.sampling import (greedy_tokens, sample_tokens,
                                       sample_tokens_biased)
 from llmd_tpu.models.config import ModelConfig
+from llmd_tpu.models.parts import MODEL_PARTS, part  # noqa: F401 (the vocabulary)
 from llmd_tpu.models.transformer import forward_core, unembed
+from llmd_tpu.obs.program_parts import ProgramParts
+
+log = logging.getLogger(__name__)
+
+
+def _signature(tree):
+    """The shapes of a call's arguments, as ``.lower`` takes them: what the
+    jit cache keyed the call on (type, weak type, the sharding of a committed
+    array), so that lowering them again finds the call's own executable.
+    Shapes outlive donation; Python scalars and None stay as they are."""
+    def shape(x):
+        if not hasattr(x, "shape") or not hasattr(x, "dtype"):
+            return x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, weak_type=getattr(x, "weak_type", False),
+            sharding=x.sharding if getattr(x, "committed", False) else None)
+    return jax.tree.map(shape, tree)
+
+
+class StepProgram:
+    """A registered program's jitted function. Calling it calls the function;
+    a call that grew the jit cache (a new signature compiled) leaves the
+    arguments' shapes in ``unread`` for ``ProgramRegistry.read_compiled``.
+    Everything else (``lower``, ``_cache_size``) is the function's own."""
+
+    def __init__(self, fn: Callable, unread: list) -> None:
+        self.fn, self._unread = fn, unread
+        self._size = getattr(fn, "_cache_size", None)
+
+    def __call__(self, *args, **kwargs):
+        if self._size is None:  # not a jitted function: nothing to read
+            return self.fn(*args, **kwargs)
+        n = self._size()
+        out = self.fn(*args, **kwargs)
+        if self._size() != n:
+            self._unread.append((self.fn, _signature((args, kwargs))))
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self.fn, name)
 
 
 @dataclass
@@ -72,17 +121,28 @@ class ProgramRegistry:
         self._specs: dict[str, ProgramSpec] = {}
         self._counters: dict[str, _Counters] = {}
         self._on_dispatch = on_dispatch
+        # (function, argument shapes) of each signature that compiled and
+        # whose text has not been read yet; the maps of those that were
+        self.unread: list = []
+        self.parts = ProgramParts()
 
     # ----------------------------------------------------------- registration
     def register(self, name: str, fn: Optional[Callable] = None, *,
                  eligible: Optional[Callable[[Any], bool]] = None,
                  run: Optional[Callable[[Any], None]] = None) -> None:
-        """Add a program."""
+        """Add a program (``fn`` is kept as a ``StepProgram``)."""
         if name in self._specs:
             raise ValueError(f"program {name!r} already registered")
+        if fn is not None:
+            fn = StepProgram(fn, self.unread)
         self._specs[name] = ProgramSpec(name=name, fn=fn, eligible=eligible,
                                         run=run)
         self._counters[name] = _Counters()
+
+    def fn(self, name: str) -> Optional[Callable]:
+        """The registered program's callable, None where none is."""
+        spec = self._specs.get(name)
+        return spec.fn if spec is not None else None
 
     def specs(self) -> list[ProgramSpec]:
         return list(self._specs.values())
@@ -122,6 +182,32 @@ class ProgramRegistry:
     def counters(self) -> dict[str, tuple[int, int]]:
         return {n: (c.dispatched, c.completed)
                 for n, c in sorted(self._counters.items())}
+
+    def read_compiled(self, keep_text: Optional[list] = None) -> int:
+        """Read the compiled text of every signature in ``unread`` into
+        ``parts``; returns how many were read. Lowering the noted shapes
+        again finds the jit's own lowering and its executable (no trace, no
+        compile), except under ``compiler_options``, where JAX compiles a
+        lowering anew each time it is asked: a load from the persistent
+        cache. Called outside ``step()`` by whoever drives the engine
+        (``AsyncLLMEngine``'s loop), after a step in which a program
+        compiled, which in a served engine is the warm-up. ``keep_text``, a
+        list, gets ``(module name, text)`` a signature (the operator's tool).
+        A text that cannot be read is logged and skipped: serving goes on."""
+        n = 0
+        while self.unread:
+            fn, (args, kwargs) = self.unread.pop(0)
+            try:
+                text = fn.lower(*args, **kwargs).compile().as_text()
+            except Exception:  # noqa: BLE001: a map must never stop serving
+                log.exception("no compiled text of %s",
+                              getattr(fn, "__name__", fn))
+                continue
+            module = self.parts.add(text)
+            if keep_text is not None:
+                keep_text.append((module, text))
+            n += 1
+        return n
 
     def compile_counts(self) -> dict[str, int]:
         """Per-program jit cache sizes (0 for never-traced lazy programs) —
@@ -194,8 +280,9 @@ def build_step_programs(cfg: ModelConfig, engine_cfg: EngineConfig, mesh,
             )
             last_rows = jnp.clip(cu_q_lens[1 : B + 1] - 1, 0, NT - 1)  # [B]
             logits = unembed(cfg, params, hidden[last_rows])  # [B, vocab]
-            sampled = sample_tokens(logits.astype(jnp.float32), key, temp,
-                                    top_k, top_p)
+            with part("sample"):
+                sampled = sample_tokens(logits.astype(jnp.float32), key, temp,
+                                        top_k, top_p)
             return logits, sampled, cache, cnt, drop
 
         return _unified
@@ -227,7 +314,8 @@ def build_step_programs(cfg: ModelConfig, engine_cfg: EngineConfig, mesh,
         logits, cache, cnt, drop = _verify_logits(
             params, cache, tokens, positions, seq_slots, page_tables,
             kv_lens, cu_q_lens, num_seqs, lora_tok)
-        return greedy_tokens(logits), cache, cnt, drop  # [NT]
+        with part("sample"):
+            return greedy_tokens(logits), cache, cnt, drop  # [NT]
 
     def _verify_masked(params, cache, tokens, positions, seq_slots,
                        page_tables, kv_lens, cu_q_lens, num_seqs,
@@ -255,28 +343,29 @@ def build_step_programs(cfg: ModelConfig, engine_cfg: EngineConfig, mesh,
         logits, cache, cnt, drop = _verify_logits(
             params, cache, tokens, positions, seq_slots, page_tables,
             kv_lens, cu_q_lens, num_seqs, lora_tok)
-        logits = logits.astype(jnp.float32)  # [NT, V]
-        valid = positions >= 0  # padding rows must not touch any state
-        first = jnp.concatenate(
-            [jnp.ones((1,), bool), seq_slots[1:] != seq_slots[:-1]])
+        with part("sample"):
+            logits = logits.astype(jnp.float32)  # [NT, V]
+            valid = positions >= 0  # padding rows must not touch any state
+            first = jnp.concatenate(
+                [jnp.ones((1,), bool), seq_slots[1:] != seq_slots[:-1]])
 
-        # FSM states depend only on the INPUT draft tokens, not on the
-        # argmax results, so a scalar scan over packed positions
-        # suffices: each row's running state advances through its own
-        # draft (position j masks with the state after draft[0..j-1]).
-        def advance(st, x):
-            tok, row, is_first, ok = x
-            cur = jnp.where(is_first, st[row],
-                            next_tab[gidx[row], st[row], tok])
-            st = st.at[row].set(jnp.where(ok, cur, st[row]))
-            return st, jnp.where(ok, cur, 0)
+            # FSM states depend only on the INPUT draft tokens, not on the
+            # argmax results, so a scalar scan over packed positions
+            # suffices: each row's running state advances through its own
+            # draft (position j masks with the state after draft[0..j-1]).
+            def advance(st, x):
+                tok, row, is_first, ok = x
+                cur = jnp.where(is_first, st[row],
+                                next_tab[gidx[row], st[row], tok])
+                st = st.at[row].set(jnp.where(ok, cur, st[row]))
+                return st, jnp.where(ok, cur, 0)
 
-        _, cur_states = jax.lax.scan(
-            advance, fsm0, (tokens, seq_slots, first, valid))
-        g_rows = gidx[seq_slots]  # [NT]
-        greedy = jnp.argmax(logits + bias_tab[g_rows, cur_states],
-                            axis=-1).astype(jnp.int32)
-        fsm_next = next_tab[g_rows, cur_states, greedy]  # [NT]
+            _, cur_states = jax.lax.scan(
+                advance, fsm0, (tokens, seq_slots, first, valid))
+            g_rows = gidx[seq_slots]  # [NT]
+            greedy = jnp.argmax(logits + bias_tab[g_rows, cur_states],
+                                axis=-1).astype(jnp.int32)
+            fsm_next = next_tab[g_rows, cur_states, greedy]  # [NT]
         return greedy, fsm_next, cache, cnt, drop
 
     def _live_pos(pos, i, steps_left):
@@ -342,7 +431,8 @@ def build_step_programs(cfg: ModelConfig, engine_cfg: EngineConfig, mesh,
                 lora_indices=lora_idx if use_lora else None,
             )
             logits = unembed(cfg, params, hidden)  # [B, vocab]
-            key, nxt, *moved = pick(logits, key, *held)
+            with part("sample"):
+                key, nxt, *moved = pick(logits, key, *held)
             act = i < steps_left
             held = [jnp.where(act, new, old) for new, old in zip(moved, held)]
             nxt = jnp.where(act, nxt, 0)

@@ -8,8 +8,8 @@ device names. So the platform is explicit: ``cpu=True`` (tests, CI smokes)
 pins the CPU and checks JAX obeyed; otherwise the first device must be a TPU.
 Either way a mismatch exits non-zero.
 
-Compile cache. The cache key is the compiled program's own hash, so the
-directory only has to stay put: ``JAX_COMPILATION_CACHE_DIR`` when the
+Compile cache. The cache key is the compiled program's own hash, metadata
+(scopes, source lines) included, so the directory only has to stay put: ``JAX_COMPILATION_CACHE_DIR`` when the
 environment sets it (JAX reads that variable itself — nothing is set in
 code), otherwise one fixed git-ignored directory in the checkout. A
 per-config, per-pid or temporary directory can only guarantee misses.
@@ -58,4 +58,10 @@ def init_jax(cpu: bool):
     # then recompile exactly those
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # the key holds the program's metadata too. JAX leaves it out by default,
+    # so an executable that another tree compiled from the same operations
+    # under other scopes (or none) would be loaded with that tree's
+    # ``op_name`` paths, and the engine reads which part of the model an
+    # instruction belongs to from exactly those (obs/program_parts.py)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return dev

@@ -42,6 +42,7 @@ import numpy as np
 from jax import lax
 
 from llmd_tpu.models.config import ModelConfig
+from llmd_tpu.models.parts import part
 from llmd_tpu.ops.moe_dispatch import expert_hidden
 
 LANE = 128
@@ -616,6 +617,22 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (xf * lax.rsqrt(var + eps)).astype(x.dtype) * w
 
 
+@part("norm")
+def layer_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    """``rms_norm`` as a layer's own norm (before the mixer, before the
+    feed-forward, the final one): the part ``norm``. The norms inside a mixer
+    or an attention's projections call ``rms_norm`` and stay in their part."""
+    return rms_norm(x, w, eps)
+
+
+@part("ffn")
+def dense_ffn(h: jax.Array, lp: dict, mm) -> jax.Array:
+    """A layer's dense SwiGLU on the normed rows ``h`` with the layer's
+    leaves ``lp`` (``mm``: the layer's int8-aware weight product)."""
+    return swiglu(h, None, None, mm=mm) if "wi_q" in lp else swiglu(
+        h, lp["wi"], lp["wo_mlp"])
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary embedding. x: [..., T, H, Dh]; positions: [..., T]."""
     dh = x.shape[-1]
@@ -743,67 +760,69 @@ def moe_block(
     act = MOE_ACTIVATIONS[cfg.moe_activation]
     held = cfg.moe_held_count > 0
 
-    if logits is None:
-        logits = router_logits(x, router)
-    sigmoid = cfg.moe_scoring == "sigmoid"
-    if sigmoid:
-        scores = jax.nn.sigmoid(logits)
-        _, plain = lax.top_k(scores, k)  # the choice the bias did not move
-        biased = scores if router_bias is None else (
-            scores + router_bias.astype(jnp.float32)[None, :])
-        if cfg.moe_n_group > 1:
-            # the group limit: a group's score is the sum of its two best,
-            # the best ``moe_topk_group`` groups are kept, the top-k is
-            # taken among their experts (``ungrouped``: what the plain top-k
-            # of the same scores would have taken, for the counter)
-            _, ungrouped = lax.top_k(biased, k)
-            G = cfg.moe_n_group
-            by_group = biased.reshape(T, G, E // G)
-            group_score = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)
-            _, best = lax.top_k(group_score, cfg.moe_topk_group)
-            kept = jnp.sum(jax.nn.one_hot(best, G, dtype=jnp.int32), axis=1)
-            topi = lax.top_k(jnp.where(
-                kept[:, :, None] > 0, by_group, -jnp.inf).reshape(T, E), k)[1]
+    with part("moe_router"):
+        if logits is None:
+            logits = router_logits(x, router)
+        sigmoid = cfg.moe_scoring == "sigmoid"
+        if sigmoid:
+            scores = jax.nn.sigmoid(logits)
+            _, plain = lax.top_k(scores, k)  # the choice the bias did not move
+            biased = scores if router_bias is None else (
+                scores + router_bias.astype(jnp.float32)[None, :])
+            if cfg.moe_n_group > 1:
+                # the group limit: a group's score is the sum of its two best,
+                # the best ``moe_topk_group`` groups are kept, the top-k is
+                # taken among their experts (``ungrouped``: what the plain top-k
+                # of the same scores would have taken, for the counter)
+                _, ungrouped = lax.top_k(biased, k)
+                G = cfg.moe_n_group
+                by_group = biased.reshape(T, G, E // G)
+                group_score = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)
+                _, best = lax.top_k(group_score, cfg.moe_topk_group)
+                kept = jnp.sum(jax.nn.one_hot(best, G, dtype=jnp.int32), axis=1)
+                topi = lax.top_k(jnp.where(
+                    kept[:, :, None] > 0, by_group, -jnp.inf).reshape(T, E), k)[1]
+            else:
+                topi = plain if router_bias is None else lax.top_k(biased, k)[1]
+            topw = jnp.take_along_axis(scores, topi, axis=-1)
+            topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-20) \
+                * cfg.moe_routed_scaling
         else:
-            topi = plain if router_bias is None else lax.top_k(biased, k)[1]
-        topw = jnp.take_along_axis(scores, topi, axis=-1)
-        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-20) \
-            * cfg.moe_routed_scaling
-    else:
-        weights = jax.nn.softmax(logits, axis=-1)
-        topw, topi = lax.top_k(weights, k)  # [T, k]
-        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-9)
-    # Padding tokens (prefill chunk tail, idle decode slots) must not consume
-    # expert capacity nor pollute the EPLB load stats.
-    valid = (
-        token_mask.astype(jnp.int32)[:, None]
-        if token_mask is not None
-        else jnp.ones((T, 1), jnp.int32)
-    )  # [T, 1]
-    counts = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.int32) * valid[..., None], axis=(0, 1))
-    if sigmoid:
-        chosen = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.int32), axis=1)
-        unmoved = jnp.sum(jax.nn.one_hot(plain, E, dtype=jnp.int32), axis=1)
-        bias_moved = jnp.sum(chosen * (1 - unmoved) * valid)
-        if cfg.moe_n_group > 1:
-            group_kept = jnp.sum(chosen * jnp.sum(jax.nn.one_hot(
-                ungrouped, E, dtype=jnp.int32), axis=1) * valid)
-    if held:
-        assert eplb is None and sigmoid, "a share of the experts: no EPLB"
-        routed = jnp.sum(counts)
-        first, E = cfg.moe_held_first, cfg.moe_held_count
-        valid = valid * ((topi >= first) & (topi < first + E)).astype(jnp.int32)
-        topi = jnp.clip(topi - first, 0, E - 1)  # [T, k] held slot
-        counts = counts[first:first + E]
+            weights = jax.nn.softmax(logits, axis=-1)
+            topw, topi = lax.top_k(weights, k)  # [T, k]
+            topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-9)
+        # Padding tokens (prefill chunk tail, idle decode slots) must not consume
+        # expert capacity nor pollute the EPLB load stats.
+        valid = (
+            token_mask.astype(jnp.int32)[:, None]
+            if token_mask is not None
+            else jnp.ones((T, 1), jnp.int32)
+        )  # [T, 1]
+        counts = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.int32) * valid[..., None], axis=(0, 1))
+        if sigmoid:
+            chosen = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.int32), axis=1)
+            unmoved = jnp.sum(jax.nn.one_hot(plain, E, dtype=jnp.int32), axis=1)
+            bias_moved = jnp.sum(chosen * (1 - unmoved) * valid)
+            if cfg.moe_n_group > 1:
+                group_kept = jnp.sum(chosen * jnp.sum(jax.nn.one_hot(
+                    ungrouped, E, dtype=jnp.int32), axis=1) * valid)
+        if held:
+            assert eplb is None and sigmoid, "a share of the experts: no EPLB"
+            routed = jnp.sum(counts)
+            first, E = cfg.moe_held_first, cfg.moe_held_count
+            valid = valid * ((topi >= first) & (topi < first + E)).astype(jnp.int32)
+            topi = jnp.clip(topi - first, 0, E - 1)  # [T, k] held slot
+            counts = counts[first:first + E]
 
-    if eplb is not None:
-        replica_slots, replica_counts = eplb  # [E, R], [E]
-        S = wi.shape[0]
-        rc = replica_counts[topi]  # [T, k]
-        choice = (jnp.arange(T, dtype=jnp.int32)[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]) % rc
-        idx = replica_slots[topi, choice]  # [T, k] physical slot ids
-    else:
-        S, idx = E, topi
+        if eplb is not None:
+            replica_slots, replica_counts = eplb  # [E, R], [E]
+            S = wi.shape[0]
+            rc = replica_counts[topi]  # [T, k]
+            choice = (jnp.arange(T, dtype=jnp.int32)[:, None]
+                      + jnp.arange(k, dtype=jnp.int32)[None, :]) % rc
+            idx = replica_slots[topi, choice]  # [T, k] physical slot ids
+        else:
+            S, idx = E, topi
 
     stacked = {} if slot_offset is None else {
         "slot_offset": slot_offset, "num_slots": E}
@@ -833,35 +852,38 @@ def moe_block(
         # moe_capacity_factor is a legacy-path-only knob: the sorted path
         # has no capacity C to overflow
         C = max(1, int(t * k / S * cfg.moe_capacity_factor))
-        onehot = jax.nn.one_hot(idx, S, dtype=jnp.int32) * valid[..., None]  # [t, k, S]
-        flat = onehot.reshape(t * k, S)
-        pos_in_expert = (jnp.cumsum(flat, axis=0) - flat).reshape(t, k, S)
-        keep_i = (pos_in_expert < C).astype(jnp.int32) * onehot  # exact count
-        keep = keep_i.astype(x.dtype)
-        disp = keep[..., None] * jax.nn.one_hot(pos_in_expert, C, dtype=x.dtype)
-        comb = disp * topw[..., None, None].astype(x.dtype)
-        disp2 = disp.sum(1)  # [t, S, C]
-        comb2 = comb.sum(1)
+        with part("moe_dispatch"):
+            onehot = jax.nn.one_hot(idx, S, dtype=jnp.int32) * valid[..., None]  # [t, k, S]
+            flat = onehot.reshape(t * k, S)
+            pos_in_expert = (jnp.cumsum(flat, axis=0) - flat).reshape(t, k, S)
+            keep_i = (pos_in_expert < C).astype(jnp.int32) * onehot  # exact count
+            keep = keep_i.astype(x.dtype)
+            disp = keep[..., None] * jax.nn.one_hot(pos_in_expert, C, dtype=x.dtype)
+            comb = disp * topw[..., None, None].astype(x.dtype)
+            disp2 = disp.sum(1)  # [t, S, C]
+            comb2 = comb.sum(1)
 
-        xe = jnp.einsum("tec,td->ecd", disp2, x)  # all-to-all in, [S, C, D]
-        if matmul_impl is not None and wi_scale is None:
-            slot_counts = jnp.sum(disp2, axis=(0, 2)).astype(jnp.int32)  # [S]
-            gate_up = matmul_impl(xe, wi, slot_counts)
-            ye = matmul_impl(expert_hidden(gate_up, act, cfg.moe_gated), wo,
-                             slot_counts)
-        else:
-            # int8 expert banks: per-expert per-output-channel scales commute
-            # out of the dot (see models/quant.py) — [S, 2F] / [S, D]
-            gate_up = jnp.einsum("ecd,edf->ecf", xe, wi.astype(x.dtype))
-            if wi_scale is not None:
-                gate_up = gate_up * wi_scale[:, None, :].astype(x.dtype)
-            ye = jnp.einsum("ecf,efd->ecd",
-                            expert_hidden(gate_up, act, cfg.moe_gated),
-                            wo.astype(x.dtype))
-            if wo_scale is not None:
-                ye = ye * wo_scale[:, None, :].astype(x.dtype)
-        y = jnp.einsum("tec,ecd->td", comb2, ye)  # all-to-all back
-        kept = jnp.sum(keep_i)  # routed copies that got a capacity slot
+            xe = jnp.einsum("tec,td->ecd", disp2, x)  # all-to-all in, [S, C, D]
+        with part("moe_experts"):
+            if matmul_impl is not None and wi_scale is None:
+                slot_counts = jnp.sum(disp2, axis=(0, 2)).astype(jnp.int32)  # [S]
+                gate_up = matmul_impl(xe, wi, slot_counts)
+                ye = matmul_impl(expert_hidden(gate_up, act, cfg.moe_gated),
+                                 wo, slot_counts)
+            else:
+                # int8 expert banks: per-expert per-output-channel scales
+                # commute out of the dot (see models/quant.py): [S, 2F] / [S, D]
+                gate_up = jnp.einsum("ecd,edf->ecf", xe, wi.astype(x.dtype))
+                if wi_scale is not None:
+                    gate_up = gate_up * wi_scale[:, None, :].astype(x.dtype)
+                ye = jnp.einsum("ecf,efd->ecd",
+                                expert_hidden(gate_up, act, cfg.moe_gated),
+                                wo.astype(x.dtype))
+                if wo_scale is not None:
+                    ye = ye * wo_scale[:, None, :].astype(x.dtype)
+        with part("moe_combine"):
+            y = jnp.einsum("tec,ecd->td", comb2, ye)  # all-to-all back
+            kept = jnp.sum(keep_i)  # routed copies that got a capacity slot
         return y, kept
 
     if half is None:
@@ -994,6 +1016,7 @@ def init_compressed_keys(cfg: ModelConfig, num_pages: int,
 _FP8_MAX = 448.0
 
 
+@part("kv_write")
 def write_kv(flat_cache: jax.Array, k: jax.Array, v: jax.Array, slots: jax.Array) -> jax.Array:
     """Write new tokens' K/V into flat cache slots (in place under donation).
 
@@ -1360,26 +1383,30 @@ def mamba_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
     B = live.shape[0]
     Di, K = cfg.mamba_d_inner, cfg.mamba_d_conv
     dt_ = cfg.jax_dtype
-    xz = mm("mamba_in", "nd,de->ne", h)
-    xr, z = xz[:, :Di], xz[:, Di:]  # pre-conv rows, gate (model dtype)
-    vec = lp["mamba_vec"]  # [K + 3, Di] float32: conv taps, conv bias, dt bias, D
-    taps, conv = conv_window(conv, o, xr, plan)
-    acc = vec[K]
-    for k in range(K):
-        acc = acc + vec[k] * taps[k].astype(jnp.float32)
-    x = jax.nn.silu(acc)  # [N, Di] float32
-    dbc = mm("mamba_x", "ne,er->nr", x.astype(dt_), jnp.float32)
-    dlow, Bm, Cm = _inner_norms(cfg, dbc, lp["mamba_norms"])
-    delta = jax.nn.softplus(
-        mm("mamba_dt", "nr,re->ne", dlow.astype(dt_), jnp.float32)
-        + vec[K + 1])
-    A = -jnp.exp(lp["mamba_a_log"].astype(jnp.float32))  # [Nst, Di]
-    slots = o * conv.shape[2] + (
-        jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
-    y, ssm = scan_impl(x, delta, Bm, Cm, A, ssm, slots, cu_q_lens, live, fresh)
-    y = y + vec[K + 2] * x
-    y = y * jax.nn.silu(z.astype(jnp.float32))
-    return mm("mamba_out", "ne,ed->nd", y.astype(dt_)), conv, ssm
+    with part("mixer_in"):
+        xz = mm("mamba_in", "nd,de->ne", h)
+        xr, z = xz[:, :Di], xz[:, Di:]  # pre-conv rows, gate (model dtype)
+        vec = lp["mamba_vec"]  # [K + 3, Di] float32: conv taps, conv bias, dt bias, D
+        taps, conv = conv_window(conv, o, xr, plan)
+        acc = vec[K]
+        for k in range(K):
+            acc = acc + vec[k] * taps[k].astype(jnp.float32)
+        x = jax.nn.silu(acc)  # [N, Di] float32
+        dbc = mm("mamba_x", "ne,er->nr", x.astype(dt_), jnp.float32)
+        dlow, Bm, Cm = _inner_norms(cfg, dbc, lp["mamba_norms"])
+        delta = jax.nn.softplus(
+            mm("mamba_dt", "nr,re->ne", dlow.astype(dt_), jnp.float32)
+            + vec[K + 1])
+        A = -jnp.exp(lp["mamba_a_log"].astype(jnp.float32))  # [Nst, Di]
+    with part("mixer"):
+        slots = o * conv.shape[2] + (
+            jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
+        y, ssm = scan_impl(x, delta, Bm, Cm, A, ssm, slots, cu_q_lens, live,
+                           fresh)
+    with part("mixer_out"):
+        y = y + vec[K + 2] * x
+        y = y * jax.nn.silu(z.astype(jnp.float32))
+        return mm("mamba_out", "ne,ed->nd", y.astype(dt_)), conv, ssm
 
 
 def _head_mean_sq(xf: jax.Array) -> jax.Array:
@@ -1449,28 +1476,32 @@ def mamba2_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
     Di, C, K = cfg.mamba2_d_inner, cfg.mamba2_conv_dim, cfg.mamba2_d_conv
     H, G, Nst = cfg.mamba2_heads, cfg.mamba2_groups, cfg.mamba2_d_state
     dt_ = cfg.jax_dtype
-    zxd = mm("m2_in", "nd,de->ne", h)
-    z, xr, dtr = zxd[:, :Di], zxd[:, Di:Di + C], zxd[:, Di + C:Di + C + H]
-    vec = lp["m2_vec"]  # [K + 1, C] float32: conv taps, conv bias
-    taps, conv = conv_window(conv, o, xr, plan)
-    acc = vec[K]
-    for k in range(K):
-        acc = acc + vec[k] * taps[k].astype(jnp.float32)
-    xbc = jax.nn.silu(acc).astype(dt_)  # [N, C]
-    x = xbc[:, :Di].reshape(N, H, Di // H)
-    Bm = xbc[:, Di:Di + G * Nst].reshape(N, G, Nst)
-    Cm = xbc[:, Di + G * Nst:].reshape(N, G, Nst)
-    hv = lp["m2_heads"]  # [3, H] float32: dt bias, A, D
-    delta = jax.nn.softplus(dtr.astype(jnp.float32) + hv[0])
-    slots = o * conv.shape[2] + (
-        jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
-    y, ssm = ssd_impl(x, delta, hv[1], Bm, Cm, ssm, slots, cu_q_lens, live,
-                      fresh)
-    y = y + hv[2][None, :, None] * x.astype(jnp.float32)
-    y = y.reshape(N, Di) * jax.nn.silu(z.astype(jnp.float32))
-    y = _head_norm(y.reshape(N, G, Di // G),
-                   lp["m2_norm"].reshape(G, Di // G), cfg.rms_eps)
-    return mm("m2_out", "ne,ed->nd", y.reshape(N, Di).astype(dt_)), conv, ssm
+    with part("mixer_in"):
+        zxd = mm("m2_in", "nd,de->ne", h)
+        z, xr, dtr = zxd[:, :Di], zxd[:, Di:Di + C], zxd[:, Di + C:Di + C + H]
+        vec = lp["m2_vec"]  # [K + 1, C] float32: conv taps, conv bias
+        taps, conv = conv_window(conv, o, xr, plan)
+        acc = vec[K]
+        for k in range(K):
+            acc = acc + vec[k] * taps[k].astype(jnp.float32)
+        xbc = jax.nn.silu(acc).astype(dt_)  # [N, C]
+        x = xbc[:, :Di].reshape(N, H, Di // H)
+        Bm = xbc[:, Di:Di + G * Nst].reshape(N, G, Nst)
+        Cm = xbc[:, Di + G * Nst:].reshape(N, G, Nst)
+        hv = lp["m2_heads"]  # [3, H] float32: dt bias, A, D
+        delta = jax.nn.softplus(dtr.astype(jnp.float32) + hv[0])
+    with part("mixer"):
+        slots = o * conv.shape[2] + (
+            jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
+        y, ssm = ssd_impl(x, delta, hv[1], Bm, Cm, ssm, slots, cu_q_lens,
+                          live, fresh)
+    with part("mixer_out"):
+        y = y + hv[2][None, :, None] * x.astype(jnp.float32)
+        y = y.reshape(N, Di) * jax.nn.silu(z.astype(jnp.float32))
+        y = _head_norm(y.reshape(N, G, Di // G),
+                       lp["m2_norm"].reshape(G, Di // G), cfg.rms_eps)
+        return (mm("m2_out", "ne,ed->nd", y.reshape(N, Di).astype(dt_)),
+                conv, ssm)
 
 
 def lightning_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, lin: jax.Array,
@@ -1496,20 +1527,24 @@ def lightning_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, lin: jax.Array,
     def heads(key):
         return mm(key, "nd,ed->ne", h).reshape(N, Hl, Dl)
 
-    q = head_rms_norm(heads("lin_wq"), lp["lin_q_norm"], cfg.rms_eps)
-    k = head_rms_norm(heads("lin_wk"), lp["lin_k_norm"], cfg.rms_eps)
-    v = heads("lin_wv")
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    slots = o * (lin.shape[0] // cfg.num_lightning_layers) + (
-        jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
-    y, lin = lin_impl(q, k, v, head_slopes(cfg.lightning_heads), lin, slots,
-                      cu_q_lens, live, fresh,
-                      scale=cfg.lightning_head_dim ** -0.5)
-    y = _head_norm(y, lp["lin_o_norm"], cfg.rms_eps)
-    gate = jax.nn.sigmoid(heads("lin_wg").astype(jnp.float32))
-    return mm("lin_wo", "ne,ed->nd",
-              (y * gate).astype(cfg.jax_dtype).reshape(N, Hl * Dl)), lin
+    with part("mixer_in"):
+        q = head_rms_norm(heads("lin_wq"), lp["lin_q_norm"], cfg.rms_eps)
+        k = head_rms_norm(heads("lin_wk"), lp["lin_k_norm"], cfg.rms_eps)
+        v = heads("lin_wv")
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    with part("mixer"):
+        slots = o * (lin.shape[0] // cfg.num_lightning_layers) + (
+            jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
+        y, lin = lin_impl(q, k, v, head_slopes(cfg.lightning_heads), lin,
+                          slots, cu_q_lens, live, fresh,
+                          scale=cfg.lightning_head_dim ** -0.5)
+    with part("mixer_out"):
+        y = _head_norm(y, lp["lin_o_norm"], cfg.rms_eps)
+        with part("mixer_in"):  # a gate: the innermost scope is its part
+            gate = jax.nn.sigmoid(heads("lin_wg").astype(jnp.float32))
+        return mm("lin_wo", "ne,ed->nd",
+                  (y * gate).astype(cfg.jax_dtype).reshape(N, Hl * Dl)), lin
 
 
 def kda_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
@@ -1532,30 +1567,35 @@ def kda_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
     B, N = live.shape[0], h.shape[0]
     Hk, Dk, Di, K = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_d_inner, cfg.kda_d_conv
     f32 = jnp.float32
-    xr = mm("kda_wqkv", "nd,ed->ne", h)  # pre-conv rows (model dtype)
-    taps, conv = conv_window(conv, o, xr, plan)
-    vec = lp["kda_conv_w"].astype(f32)
-    acc = vec[0] * taps[0].astype(f32)
-    for t in range(1, K):
-        acc = acc + vec[t] * taps[t].astype(f32)
-    qkv = jax.nn.silu(acc).reshape(N, 3, Hk, Dk)
 
     def unit(x):  # x / |x|_2 a head
         return x * lax.rsqrt(_head_mean_sq(x) * Dk + 1e-6)
 
-    q, k, v = unit(qkv[:, 0]) * Dk ** -0.5, unit(qkv[:, 1]), qkv[:, 2]
-    gate_in = mm("kda_wf", "nd,ed->ne", h, f32) + lp["kda_dt_bias"].astype(f32)
-    g = cfg.kda_gate_lower_bound * jax.nn.sigmoid(
-        jnp.exp(lp["kda_a_log"].astype(f32))[None, :, None]
-        * gate_in.reshape(N, Hk, Dk))
-    b = jax.nn.sigmoid(mm("kda_wb", "nd,hd->nh", h, f32))
-    slots = o * conv.shape[2] + (
-        jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
-    y, lin = kda_impl(q, k, v, g, b, lin, slots, cu_q_lens, live, fresh)
-    y = _head_norm(y, lp["kda_o_norm"], cfg.rms_eps)
-    gate = jax.nn.sigmoid(mm("kda_wg", "nd,ed->ne", h, f32))
-    return mm("kda_wo", "ne,ed->nd",
-              (y.reshape(N, Di) * gate).astype(cfg.jax_dtype)), conv, lin
+    with part("mixer_in"):
+        xr = mm("kda_wqkv", "nd,ed->ne", h)  # pre-conv rows (model dtype)
+        taps, conv = conv_window(conv, o, xr, plan)
+        vec = lp["kda_conv_w"].astype(f32)
+        acc = vec[0] * taps[0].astype(f32)
+        for t in range(1, K):
+            acc = acc + vec[t] * taps[t].astype(f32)
+        qkv = jax.nn.silu(acc).reshape(N, 3, Hk, Dk)
+        q, k, v = unit(qkv[:, 0]) * Dk ** -0.5, unit(qkv[:, 1]), qkv[:, 2]
+        gate_in = (mm("kda_wf", "nd,ed->ne", h, f32)
+                   + lp["kda_dt_bias"].astype(f32))
+        g = cfg.kda_gate_lower_bound * jax.nn.sigmoid(
+            jnp.exp(lp["kda_a_log"].astype(f32))[None, :, None]
+            * gate_in.reshape(N, Hk, Dk))
+        b = jax.nn.sigmoid(mm("kda_wb", "nd,hd->nh", h, f32))
+    with part("mixer"):
+        slots = o * conv.shape[2] + (
+            jnp.arange(B, dtype=jnp.int32) if row_slots is None else row_slots)
+        y, lin = kda_impl(q, k, v, g, b, lin, slots, cu_q_lens, live, fresh)
+    with part("mixer_out"):
+        y = _head_norm(y, lp["kda_o_norm"], cfg.rms_eps)
+        with part("mixer_in"):  # a gate: the innermost scope is its part
+            gate = jax.nn.sigmoid(mm("kda_wg", "nd,ed->ne", h, f32))
+        return mm("kda_wo", "ne,ed->nd",
+                  (y.reshape(N, Di) * gate).astype(cfg.jax_dtype)), conv, lin
 
 
 # ---------------------------------------------------------------------------
@@ -1720,17 +1760,15 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
         def mm(key, pattern, xin, out=None):
             return _weight_mm(lp, key, pattern, xin, out)
 
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        h = layer_norm(x, lp["attn_norm"], cfg.rms_eps)
         o_mix, conv, lin = kda_mixer(
             cfg, lp, h, pools["conv"], pools["lin"], o, plan, state_slots,
             cu_q_lens, live, fresh, kda_impl, mm)
         pools = {**pools, "conv": conv, "lin": lin}
         x = _joined(cfg, x, o_mix)
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        h = layer_norm(x, lp["mlp_norm"], cfg.rms_eps)
         if leading:
-            y = swiglu(h, None, None, mm=mm) if "wi_q" in lp else swiglu(
-                h, lp["wi"], lp["wo_mlp"])
-            return _joined(cfg, x, y), flat_cache, pools
+            return _joined(cfg, x, dense_ffn(h, lp, mm)), flat_cache, pools
         y, cnt, drop = expert_layer(h, lp, e)
         return _joined(cfg, x, y), flat_cache, counts_kept(pools, cnt, drop, e)
 
@@ -1750,7 +1788,7 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
         def mm(key, pattern, xin, out=None):
             return _weight_mm(lp, key, pattern, xin, out)
 
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        h = layer_norm(x, lp["attn_norm"], cfg.rms_eps)
         if kind == "experts":
             y, cnt, drop = expert_layer(h, lp, o)
             return _joined(cfg, x, y), flat_cache, counts_kept(
@@ -1772,10 +1810,8 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
                 cu_q_lens, live, fresh, lin_impl, mm)
             pools = {**pools, "lin": lin}
         x = _joined(cfg, x, o_mix)
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        y = swiglu(h, None, None, mm=mm) if "wi_q" in lp else swiglu(
-            h, lp["wi"], lp["wo_mlp"])
-        return _joined(cfg, x, y), flat_cache, pools
+        h = layer_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        return _joined(cfg, x, dense_ffn(h, lp, mm)), flat_cache, pools
 
     period = len(cfg.layer_kinds)
     count = {k: cfg.layer_kinds.count(k) for k in own}
@@ -1884,13 +1920,15 @@ def forward_core(
     attn_plan = getattr(attn_impl, "plan", None)
     planned = attn_plan(page_tables, kv_lens, cu_q_lens, num_seqs,
                         ps) if attn_plan and cu_q_lens is not None else {}
-    x = params["embed"][tokens].astype(cfg.jax_dtype)  # [N, D]
-    if cfg.embed_scale != 1.0:
-        x = x * cfg.embed_scale
-    if mm_embeds is not None:
-        # inject the encode stage's embedding rows at media placeholder
-        # positions (E/PD contract: encode workers produce, prefill consumes)
-        x = jnp.where(mm_mask[:, None], mm_embeds.astype(x.dtype), x)
+    with part("embed"):
+        x = params["embed"][tokens].astype(cfg.jax_dtype)  # [N, D]
+        if cfg.embed_scale != 1.0:
+            x = x * cfg.embed_scale
+        if mm_embeds is not None:
+            # inject the encode stage's embedding rows at media placeholder
+            # positions (E/PD contract: encode workers produce, prefill
+            # consumes)
+            x = jnp.where(mm_mask[:, None], mm_embeds.astype(x.dtype), x)
 
     # global slot ids for the new tokens: page_table[seq, pos // ps] * ps + pos % ps
     b = jnp.clip(seq_slots, 0, B - 1)
@@ -2002,18 +2040,19 @@ def forward_core(
                if cfg.moe_router_bias else {}),
         )
         if cfg.moe_num_shared_experts:
-            if not cfg.moe_gated:
-                y = y + mm("shared_wo", "nf,fd->nd",
-                           MOE_ACTIVATIONS[cfg.moe_activation](
-                               mm("shared_wi", "nd,df->nf", h)))
-            elif "shared_wi_q" in lp:
-                def _shared_mm(key, pattern, xin):
-                    return mm({"wi": "shared_wi",
-                               "wo_mlp": "shared_wo"}[key], pattern, xin)
+            with part("ffn"):  # the shared expert is a dense feed-forward
+                if not cfg.moe_gated:
+                    y = y + mm("shared_wo", "nf,fd->nd",
+                               MOE_ACTIVATIONS[cfg.moe_activation](
+                                   mm("shared_wi", "nd,df->nf", h)))
+                elif "shared_wi_q" in lp:
+                    def _shared_mm(key, pattern, xin):
+                        return mm({"wi": "shared_wi",
+                                   "wo_mlp": "shared_wo"}[key], pattern, xin)
 
-                y = y + swiglu(h, None, None, mm=_shared_mm)
-            else:
-                y = y + swiglu(h, lp["shared_wi"], lp["shared_wo"])
+                    y = y + swiglu(h, None, None, mm=_shared_mm)
+                else:
+                    y = y + swiglu(h, lp["shared_wi"], lp["shared_wo"])
         return y, cnt, drop
 
     def layer(carry, lp, l, window, use_rope, moe_ordinal=None):
@@ -2036,74 +2075,78 @@ def forward_core(
             y = jnp.einsum(pattern, xin, lp[key + "_q"].astype(xin.dtype))
             return y * lp[key + "_scale"].astype(xin.dtype)
 
+        @part("attn_out")
         def gated(attn, h):  # cfg.attn_output_gate
             return (attn.astype(jnp.float32) * jax.nn.sigmoid(
                 _mm("wg", "nd,dhk->nhk", h).astype(jnp.float32))
                     ).astype(attn.dtype)
 
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        early_logits = router_logits(h, lp["router"]) if (
-            "router" in lp and cfg.moe_router_input == "attn_norm") else None
-        if cfg.is_mla:
-            # Absorbed MLA (DeepSeek-V2 §2.1.2 inference form): the pool holds
-            # one shared [c_kv ; k_rope] vector per token, queries project into
-            # latent space through W_UK, and the whole thing runs as MQA with
-            # head_dim = rank + rope_dim over the unmodified paged-attention
-            # impl. Scores: q_nope·(W_UK c) + q_rope·k_rope == (W_UK^T q_nope)·c
-            # + q_rope·k_rope; values ARE the latents, re-expanded per head
-            # through W_UV after the softmax-weighted sum.
-            r, dr, dn = cfg.mla_kv_lora_rank, cfg.mla_rope_dim, cfg.mla_qk_nope_dim
-            Dkv = r + dr
+        h = layer_norm(x, lp["attn_norm"], cfg.rms_eps)
+        with part("moe_router"):
+            early_logits = router_logits(h, lp["router"]) if (
+                "router" in lp and cfg.moe_router_input == "attn_norm"
+            ) else None
+        with part("attn_qkv"):
+            if cfg.is_mla:
+                # Absorbed MLA (DeepSeek-V2 §2.1.2 inference form): the pool holds
+                # one shared [c_kv ; k_rope] vector per token, queries project into
+                # latent space through W_UK, and the whole thing runs as MQA with
+                # head_dim = rank + rope_dim over the unmodified paged-attention
+                # impl. Scores: q_nope·(W_UK c) + q_rope·k_rope == (W_UK^T q_nope)·c
+                # + q_rope·k_rope; values ARE the latents, re-expanded per head
+                # through W_UV after the softmax-weighted sum.
+                r, dr, dn = cfg.mla_kv_lora_rank, cfg.mla_rope_dim, cfg.mla_qk_nope_dim
+                Dkv = r + dr
 
-            def pad_kv(t):  # [N, h, Dkv] → [N, h, Dhp]
-                return t if Dhp == Dkv else jnp.pad(
-                    t, ((0, 0), (0, 0), (0, Dhp - Dkv)))
+                def pad_kv(t):  # [N, h, Dkv] → [N, h, Dhp]
+                    return t if Dhp == Dkv else jnp.pad(
+                        t, ((0, 0), (0, 0), (0, Dhp - Dkv)))
 
-            if cfg.mla_q_lora_rank:
-                c_q = rms_norm(jnp.einsum("nd,dr->nr", h, lp["mla_wqa"]),
-                               lp["mla_q_norm"], cfg.rms_eps)
-                q = jnp.einsum("nr,rhk->nhk", c_q, lp["mla_wqb"])
+                if cfg.mla_q_lora_rank:
+                    c_q = rms_norm(jnp.einsum("nd,dr->nr", h, lp["mla_wqa"]),
+                                   lp["mla_q_norm"], cfg.rms_eps)
+                    q = jnp.einsum("nr,rhk->nhk", c_q, lp["mla_wqb"])
+                else:
+                    q = jnp.einsum("nd,dhk->nhk", h, lp["mla_wq"])  # [N, H, dn+dr]
+                q_rope = rope(q[..., dn:], positions, cfg.rope_theta)
+                c = jnp.einsum("nd,dr->nr", h, lp["mla_wdkv"])  # [N, r] latent
+                c = rms_norm(c, lp["mla_kv_norm"], cfg.rms_eps)
+                kr = rope(jnp.einsum("nd,dk->nk", h, lp["mla_wkr"])[:, None, :],
+                          positions, cfg.rope_theta)[:, 0]  # [N, dr] shared key
+                q_lat = jnp.einsum("nhk,hkr->nhr", q[..., :dn], lp["mla_wuk"])
+                q_attn = pad_kv(jnp.concatenate([q_lat, q_rope], axis=-1))
+                k_w = v_w = pad_kv(jnp.concatenate([c, kr], axis=-1)[:, None, :])
+                scale = (dn + dr) ** -0.5
             else:
-                q = jnp.einsum("nd,dhk->nhk", h, lp["mla_wq"])  # [N, H, dn+dr]
-            q_rope = rope(q[..., dn:], positions, cfg.rope_theta)
-            c = jnp.einsum("nd,dr->nr", h, lp["mla_wdkv"])  # [N, r] latent
-            c = rms_norm(c, lp["mla_kv_norm"], cfg.rms_eps)
-            kr = rope(jnp.einsum("nd,dk->nk", h, lp["mla_wkr"])[:, None, :],
-                      positions, cfg.rope_theta)[:, 0]  # [N, dr] shared key
-            q_lat = jnp.einsum("nhk,hkr->nhr", q[..., :dn], lp["mla_wuk"])
-            q_attn = pad_kv(jnp.concatenate([q_lat, q_rope], axis=-1))
-            k_w = v_w = pad_kv(jnp.concatenate([c, kr], axis=-1)[:, None, :])
-            scale = (dn + dr) ** -0.5
-        else:
-            q = _mm("wq", "nd,dhk->nhk", h)
-            k = _mm("wk", "nd,dhk->nhk", h)
-            v = _mm("wv", "nd,dhk->nhk", h)
-            if cfg.attn_bias:
-                q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-            if has_lora:
-                from llmd_tpu.models.lora import apply_lora
+                q = _mm("wq", "nd,dhk->nhk", h)
+                k = _mm("wk", "nd,dhk->nhk", h)
+                v = _mm("wv", "nd,dhk->nhk", h)
+                if cfg.attn_bias:
+                    q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+                if has_lora:
+                    from llmd_tpu.models.lora import apply_lora
 
-                Hq, Hkn = cfg.num_heads, cfg.num_kv_heads
-                q = q + apply_lora(h, lp["lora_A_wq"], lp["lora_B_wq"], lora_indices,
-                                   lora_scale).reshape(N, Hq, Dh)
-                k = k + apply_lora(h, lp["lora_A_wk"], lp["lora_B_wk"], lora_indices,
-                                   lora_scale).reshape(N, Hkn, Dh)
-                v = v + apply_lora(h, lp["lora_A_wv"], lp["lora_B_wv"], lora_indices,
-                                   lora_scale).reshape(N, Hkn, Dh)
-            if cfg.qk_norm:
-                # Per-head RMSNorm over head_dim before RoPE (Qwen3 semantics) — on
-                # the FULL projection output incl. bias and LoRA delta, matching the
-                # HF/PEFT order (adapters are trained against normalised q/k).
-                # (beside recurrent layers the sums of squares are matrix
-                # products: a row's result must not depend on the program)
-                qk = head_rms_norm if cfg.has_recurrent else rms_norm
-                q = qk(q, lp["q_norm"], cfg.rms_eps)
-                k = qk(k, lp["k_norm"], cfg.rms_eps)
-            if use_rope:
-                q = rope(q, positions, cfg.rope_theta)
-                k = rope(k, positions, cfg.rope_theta)
-            q_attn, k_w, v_w = pad_heads(q), pad_heads(k), pad_heads(v)
-            scale = Dh ** -0.5
+                    Hq, Hkn = cfg.num_heads, cfg.num_kv_heads
+                    q = q + apply_lora(h, lp["lora_A_wq"], lp["lora_B_wq"], lora_indices,
+                                       lora_scale).reshape(N, Hq, Dh)
+                    k = k + apply_lora(h, lp["lora_A_wk"], lp["lora_B_wk"], lora_indices,
+                                       lora_scale).reshape(N, Hkn, Dh)
+                    v = v + apply_lora(h, lp["lora_A_wv"], lp["lora_B_wv"], lora_indices,
+                                       lora_scale).reshape(N, Hkn, Dh)
+                if cfg.qk_norm:
+                    # Per-head RMSNorm over head_dim before RoPE (Qwen3 semantics) — on
+                    # the FULL projection output incl. bias and LoRA delta, matching the
+                    # HF/PEFT order (adapters are trained against normalised q/k).
+                    # (beside recurrent layers the sums of squares are matrix
+                    # products: a row's result must not depend on the program)
+                    qk = head_rms_norm if cfg.has_recurrent else rms_norm
+                    q = qk(q, lp["q_norm"], cfg.rms_eps)
+                    k = qk(k, lp["k_norm"], cfg.rms_eps)
+                if use_rope:
+                    q = rope(q, positions, cfg.rope_theta)
+                    k = rope(k, positions, cfg.rope_theta)
+                q_attn, k_w, v_w = pad_heads(q), pad_heads(k), pad_heads(v)
+                scale = Dh ** -0.5
         if cfg.sparse_topk:
             # a KV head's pages are pages of their own: head g of attention
             # layer l at fold l * Hk + g (ops/sparse_select)
@@ -2116,14 +2159,16 @@ def forward_core(
             flat_cache = write_kv(flat_cache, k_w.reshape(N * Hkn, 1, Dhp),
                                   v_w.reshape(N * Hkn, 1, Dhp),
                                   slots_h.reshape(-1))
-            planes = [sparse_select.write_compressed_keys(
-                planes[0], flat_cache, page_tables, positions, seq_slots,
-                base, ps)]
-            attn = sparse_select.sparse_paged_attention(
-                cfg, q_attn, flat_cache, planes[0], page_tables, positions,
-                seq_slots, kv_lens, cu_q_lens, num_seqs, l, P, ps, scale,
-                attn_impl, query_attn_impl or attn_impl)
-            attn = attn[..., :Dh]
+            with part("kv_write"):
+                planes = [sparse_select.write_compressed_keys(
+                    planes[0], flat_cache, page_tables, positions, seq_slots,
+                    base, ps)]
+            with part("attn"):  # (the selection inside is its own part)
+                attn = sparse_select.sparse_paged_attention(
+                    cfg, q_attn, flat_cache, planes[0], page_tables,
+                    positions, seq_slots, kv_lens, cu_q_lens, num_seqs, l, P,
+                    ps, scale, attn_impl, query_attn_impl or attn_impl)
+                attn = attn[..., :Dh]
             if cfg.attn_output_gate:
                 attn = gated(attn, h)
             # one product over the H * Dh lanes of a row, as the latent
@@ -2132,14 +2177,15 @@ def forward_core(
             # row's projection through the 32-row and the 256-row program
             # parted by a bf16 step now and then (PR 42: greedy tokens served
             # alone and beside a prefilling neighbour parted)
-            flat = {k: v.reshape((-1,) + v.shape[2:])
-                    for k, v in lp.items() if k in ("wo", "wo_q")}
-            x = _joined(cfg, x, _weight_mm({**lp, **flat}, "wo", "nk,kd->nd",
-                                     attn.reshape(N, -1)))
-            h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-            y = swiglu(h, None, None, mm=_mm) if "wi_q" in lp else swiglu(
-                h, lp["wi"], lp["wo_mlp"])
-            return (_joined(cfg, x, y), flat_cache, *planes), (
+            with part("attn_out"):
+                flat = {k: v.reshape((-1,) + v.shape[2:])
+                        for k, v in lp.items() if k in ("wo", "wo_q")}
+                o = _weight_mm({**lp, **flat}, "wo", "nk,kd->nd",
+                               attn.reshape(N, -1))
+            x = _joined(cfg, x, o)
+            h = layer_norm(x, lp["mlp_norm"], cfg.rms_eps)
+            return (_joined(cfg, x, dense_ffn(h, lp, _mm)), flat_cache,
+                    *planes), (
                 jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32))
         # shared paged plumbing — this layer's slice of the pool: slots/pages
         # shifted by the layer offset, KV written, attention over the pool
@@ -2149,49 +2195,51 @@ def forward_core(
         # a window layer's impl is told the window, and bounds its own reads
         # by it (window_view); only impls that serve such models take the
         # argument
-        attn = attn_impl(
-            q_attn, flat_cache.reshape(Ptot, ps, HkC, Dhp), pt_l,
-            positions, seq_slots, kv_lens,
-            cu_q_lens=cu_q_lens, num_seqs=num_seqs, scale=scale,
-            chunk_k=k_w, chunk_v=v_w,
-            **({"sliding_window": window} if window else {}), **planned,
-        )
-        if cfg.is_mla:
-            # latent-weighted sum [..., :rank] re-expands per head via W_UV
-            o_heads = jnp.einsum("nhr,hrv->nhv",
-                                 attn[..., :cfg.mla_kv_lora_rank], lp["mla_wuv"])
-            if cfg.attn_gate_per_head:  # one scalar a head
-                o_heads = (o_heads.astype(jnp.float32) * jax.nn.sigmoid(
-                    _mm("wg", "nd,dh->nh", h).astype(jnp.float32)
-                )[:, :, None]).astype(o_heads.dtype)
-            # one product over the H * dv lanes of a row: contracted over
-            # (h, v) as two axes, XLA picks how to split the sum by the
-            # number of rows, and on the chip a decode row's projection
-            # through a 64-row and a 256-row program parted by a bf16 step
-            # (PR 39: greedy tokens served cold and from the prefix cache
-            # parted). A plain [N, H*dv] x [H*dv, D] product adds a row's
-            # terms in one order whatever N.
-            flat = {k: v.reshape((-1,) + v.shape[2:])
-                    for k, v in lp.items() if k in ("wo", "wo_q")}
-            o = _weight_mm({**lp, **flat}, "wo", "nk,kd->nd",
-                           o_heads.reshape(N, -1))
-        else:
-            attn = attn[..., :Dh]
-            if cfg.attn_output_gate:
-                attn = gated(attn, h)
-            o = _mm("wo", "nhk,hkd->nd", attn)
-            if cfg.attn_bias:
-                o = o + lp["bo"]
-            if has_lora:
-                attn_flat = attn.reshape(N, cfg.num_heads * Dh)
-                o = o + apply_lora(attn_flat, lp["lora_A_wo"], lp["lora_B_wo"],
-                                   lora_indices, lora_scale)
+        with part("attn"):
+            attn = attn_impl(
+                q_attn, flat_cache.reshape(Ptot, ps, HkC, Dhp), pt_l,
+                positions, seq_slots, kv_lens,
+                cu_q_lens=cu_q_lens, num_seqs=num_seqs, scale=scale,
+                chunk_k=k_w, chunk_v=v_w,
+                **({"sliding_window": window} if window else {}), **planned,
+            )
+        with part("attn_out"):
+            if cfg.is_mla:
+                # latent-weighted sum [..., :rank] re-expands per head via W_UV
+                o_heads = jnp.einsum("nhr,hrv->nhv",
+                                     attn[..., :cfg.mla_kv_lora_rank], lp["mla_wuv"])
+                if cfg.attn_gate_per_head:  # one scalar a head
+                    o_heads = (o_heads.astype(jnp.float32) * jax.nn.sigmoid(
+                        _mm("wg", "nd,dh->nh", h).astype(jnp.float32)
+                    )[:, :, None]).astype(o_heads.dtype)
+                # one product over the H * dv lanes of a row: contracted over
+                # (h, v) as two axes, XLA picks how to split the sum by the
+                # number of rows, and on the chip a decode row's projection
+                # through a 64-row and a 256-row program parted by a bf16 step
+                # (PR 39: greedy tokens served cold and from the prefix cache
+                # parted). A plain [N, H*dv] x [H*dv, D] product adds a row's
+                # terms in one order whatever N.
+                flat = {k: v.reshape((-1,) + v.shape[2:])
+                        for k, v in lp.items() if k in ("wo", "wo_q")}
+                o = _weight_mm({**lp, **flat}, "wo", "nk,kd->nd",
+                               o_heads.reshape(N, -1))
+            else:
+                attn = attn[..., :Dh]
+                if cfg.attn_output_gate:
+                    attn = gated(attn, h)
+                o = _mm("wo", "nhk,hkd->nd", attn)
+                if cfg.attn_bias:
+                    o = o + lp["bo"]
+                if has_lora:
+                    attn_flat = attn.reshape(N, cfg.num_heads * Dh)
+                    o = o + apply_lora(attn_flat, lp["lora_A_wo"], lp["lora_B_wo"],
+                                       lora_indices, lora_scale)
         x = _joined(cfg, x, o)
 
         if cfg.single_sublayer:  # the layer is its mixer alone
             return (x, flat_cache, *planes), (
                 jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32))
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        h = layer_norm(x, lp["mlp_norm"], cfg.rms_eps)
         if cfg.is_moe and "router" in lp:
             y, cnt, drop = expert_layer(
                 h, lp, l if moe_ordinal is None else moe_ordinal,
@@ -2199,8 +2247,7 @@ def forward_core(
         else:
             cnt = jnp.zeros((0,), jnp.int32)
             drop = jnp.zeros((), jnp.int32)
-            y = swiglu(h, None, None, mm=_mm) if "wi_q" in lp else swiglu(
-                h, lp["wi"], lp["wo_mlp"])
+            y = dense_ffn(h, lp, _mm)
         x = _joined(cfg, x, y)
         return (x, flat_cache, *planes), (cnt, drop)
 
@@ -2209,7 +2256,7 @@ def forward_core(
             cfg, params, layer, x, cache.reshape(Ptot * ps, HkC, Dhp), state,
             positions, seq_slots, cu_q_lens, state_slots, scan_impl, lin_impl,
             ssd_impl, expert_layer, expert_keys, kda_impl)
-        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        x = layer_norm(x, params["final_norm"], cfg.rms_eps)
         return (x, {"kv": flat_cache.reshape(Ptot, ps, HkC, Dhp), **state},
                 *(counted or (jnp.zeros((cfg.num_layers, 0), jnp.int32),
                               jnp.zeros((), jnp.int32))))
@@ -2228,18 +2275,16 @@ def forward_core(
                     for key in keys}
 
         carry = (x, cache.reshape(Ptot * ps, HkC, Dhp))
-        with jax.named_scope("leading_dense_layers"):
-            for l in range(k):
-                carry, _ = layer(
-                    carry, {**take(every_keys, l), **take(dense_keys, l)},
-                    jnp.int32(l), *kind)
-        with jax.named_scope("expert_layers"):
-            (x, flat_cache), (expert_counts, dropped) = lax.scan(
-                lambda c, j: layer(
-                    c, {**take(every_keys, k + j), **take(expert_keys, j)},
-                    k + j, *kind, moe_ordinal=j),
-                carry, jnp.arange(cfg.num_moe_layers, dtype=jnp.int32))
-        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        for l in range(k):
+            carry, _ = layer(
+                carry, {**take(every_keys, l), **take(dense_keys, l)},
+                jnp.int32(l), *kind)
+        (x, flat_cache), (expert_counts, dropped) = lax.scan(
+            lambda c, j: layer(
+                c, {**take(every_keys, k + j), **take(expert_keys, j)},
+                k + j, *kind, moe_ordinal=j),
+            carry, jnp.arange(cfg.num_moe_layers, dtype=jnp.int32))
+        x = layer_norm(x, params["final_norm"], cfg.rms_eps)
         return (x, flat_cache.reshape(Ptot, ps, HkC, Dhp), expert_counts,
                 dropped.sum(0))
 
@@ -2276,13 +2321,14 @@ def forward_core(
     if period > 1:  # [L/period, period, ...] -> [L, ...]
         expert_counts = expert_counts.reshape((cfg.num_layers,)
                                               + expert_counts.shape[2:])
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    x = layer_norm(x, params["final_norm"], cfg.rms_eps)
     if cfg.moe_scoring == "sigmoid":  # [dropped, bias_moved, routed]
         return (x, flat_cache.reshape(Ptot, ps, HkC, Dhp), expert_counts,
                 dropped.sum(0))
     return x, flat_cache.reshape(Ptot, ps, HkC, Dhp), expert_counts, dropped.sum()
 
 
+@part("unembed")
 def unembed(cfg: ModelConfig, params: dict[str, jax.Array], hidden: jax.Array) -> jax.Array:
     """hidden [..., D] → logits [..., vocab] (fp32)."""
     if cfg.logit_scale != 1.0:

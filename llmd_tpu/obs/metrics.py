@@ -914,6 +914,18 @@ class EngineMetrics:
             "(compile_counts() deltas observed at dispatch completion; "
             "growth after warmup = recompile storm)",
             labelnames=("program",))
+        self.program_part_ops = reg.gauge(
+            "llmd_tpu:program_part_ops",
+            "Info series, one a compiled step program and part of the model "
+            "(MODEL_PARTS, models/parts.py; unscoped, ambiguous): ops holds "
+            "the program's instructions of that part, space separated, as "
+            "its compiled text's op_name metadata names them, and the value "
+            "is their count. program is the executable's module name, as a "
+            "device trace's XLA Modules line spells it. stale=1: an "
+            "executable of the program names no part (text not compiled "
+            "from this tree's scopes); read nothing from it. Set once a "
+            "compiled signature, outside step()",
+            labelnames=("program", "part", "stale", "ops"))
         self.program_compile_seconds = reg.histogram(
             "llmd_tpu:program_compile_seconds",
             "Step wall observed when a dispatch completion coincided with a "

@@ -29,9 +29,13 @@ same buckets.
 DBO: callers split the batch in half and invoke this path per half; the
 two halves share no intermediate values, so half A's all-to-all is
 data-independent of half B's expert GEMMs and XLA's scheduler may
-overlap them. Each stage runs under a ``jax.named_scope`` (visible in
-profiles, which is where their split of device time is read) and is
-callable standalone (``tests/test_moe_dispatch.py``).
+overlap them. Each stage runs under a ``jax.named_scope`` of its part of
+the model (``moe_dispatch``, ``moe_experts``, ``moe_combine``:
+``models/parts.py``) and is callable standalone
+(``tests/test_moe_dispatch.py``). A device trace's events do not carry the
+scope; the compiled program's text does, and the engine publishes which
+instruction belongs to which part (``obs/program_parts.py``), which is where
+the stages' split of device time is read.
 """
 
 from __future__ import annotations
