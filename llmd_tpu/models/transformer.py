@@ -352,7 +352,14 @@ def _init_lightning_params(cfg: ModelConfig, key: jax.Array) -> dict[str, jax.Ar
     plain matrices with the hidden size minor. Kept [D, Hl, Dl] or [D, Hl *
     Dl], the TPU compiler transposed the whole stacks of the four input
     leaves at every call's entry (the compiled text for a described v5e: four
-    copies of 302 MB a step), and the three norms a head ``lin_q_norm``
+    copies of 302 MB a step). Out by in, a layer's matrix is read where it
+    lies in its stack once the heads are split on the stack and not on the
+    product's rows (``lightning_views``; split on the rows, each of the four
+    was copied out of its stack before its product, 33.5 MB a leaf and
+    layer: PR 56). The sparse layers' ``wq`` / ``wg`` [D, H, Dh] are still
+    copied out, a layer's 33.5 MB each: 16 heads of one input row share a
+    tile, which no form of the product reads in place (``_hybrid_stack``).
+    The three norms a head are ``lin_q_norm``
     / ``lin_k_norm`` / ``lin_o_norm`` [Dl]; norms and the MLP [L, ...]. The norms' weights are
     drawn around 1 (a program that left one out, or put it on the wrong
     side of RoPE, must not read as a sound one), but for the sparse layers'
@@ -1518,14 +1525,16 @@ def lightning_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, lin: jax.Array,
     recurrence (``lin_impl``, ops/lightning_attention) gives float32 rows,
     which take an RMSNorm a head and the sigmoid gate before the output
     projection. ``mm(key, pattern, x)`` is the layer's int8-aware weight
-    product."""
+    product. The four input leaves come a head apart, [Hl, Dl, D]
+    (``lightning_views``): the product gives [N, Hl, Dl] and no reshape
+    stands between the layer's slice of the stack and the product."""
     from llmd_tpu.ops.lightning_attention import head_slopes
 
     B, N = live.shape[0], h.shape[0]
     Hl, Dl = cfg.lightning_heads, cfg.lightning_head_dim
 
     def heads(key):
-        return mm(key, "nd,ed->ne", h).reshape(N, Hl, Dl)
+        return mm(key, "nd,hkd->nhk", h)
 
     with part("mixer_in"):
         q = head_rms_norm(heads("lin_wq"), lp["lin_q_norm"], cfg.rms_eps)
@@ -1545,6 +1554,21 @@ def lightning_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, lin: jax.Array,
             gate = jax.nn.sigmoid(heads("lin_wg").astype(jnp.float32))
         return mm("lin_wo", "ne,ed->nd",
                   (y * gate).astype(cfg.jax_dtype).reshape(N, Hl * Dl)), lin
+
+
+def lightning_views(cfg: ModelConfig, params: dict) -> dict:
+    """The lightning layers' four input stacks as ``lightning_mixer`` reads
+    them, [Ll, Hl, Dl, D] of the stored [Ll, Hl * Dl, D]: a view of the
+    whole stack taken before the loops (the hidden size stays minor and a
+    head's 128 rows are whole tiles, so nothing moves). Split after the
+    product instead (``"nd,ed->ne"`` and a reshape of the rows), the TPU
+    compiler moved the split onto the layer's matrix, between its slice of
+    the stack and the product, and the slice then ran as an operation of its
+    own: 33.5 MB copied into on-chip memory a leaf and layer before the
+    product read the copy (PERF.md section 5, PR 56)."""
+    Hl, Dl = cfg.lightning_heads, cfg.lightning_head_dim
+    return {k: params[k].reshape(params[k].shape[0], Hl, Dl, -1)
+            for k in ("lin_wq", "lin_wk", "lin_wv", "lin_wg")}
 
 
 def kda_mixer(cfg: ModelConfig, lp: dict, h: jax.Array, conv: jax.Array,
@@ -1629,10 +1653,39 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
     a run of several layers as an inner scan, so that no layer is traced more
     than once a run. A layer takes its leaves from the whole stacks by index
     (layer ``l`` of the norms and the MLP, ordinal ``m`` or ``a`` of its
-    kind's own leaves): the stacks stay loop invariants and the index folds
-    into the product that reads them, where a period's slice handed to an
-    inner loop would be copied out. ``attention_layer`` is ``forward_core``'s
-    own layer, given the attention ordinal as its pool offset.
+    kind's own leaves): the stacks stay loop invariants, where a period's
+    slice handed to an inner loop would be copied out. ``attention_layer`` is
+    ``forward_core``'s own layer, given the attention ordinal as its pool
+    offset.
+
+    Whether the index folds into the product that reads the layer's matrix
+    is the TPU compiler's to say, and it was read from the compiled text of
+    minicpm-sala-9b's programs (``tools/program_parts.py --weight-copies``;
+    PERF.md section 5, PR 56). It folds (the ``dynamic-slice`` sits inside
+    the product's fusion, which streams the matrix out of the stack) for a
+    plain matrix whose slice goes to the product as it is: the MLP's ``wi``
+    / ``wo_mlp``, ``lin_wo``, and since PR 56 the lightning layers'
+    ``lin_wq`` / ``wk`` / ``wv`` / ``wg`` (``lightning_views``: the heads
+    split on the stack before the loops, not on the product's rows) and the
+    sparse layers' ``wo`` (viewed flat before the loops by ``forward_core``,
+    not between slice and product). It did not fold where a reshape of the
+    weight stood between slice and product, or was moved there from the
+    rows by the compiler (``bitcast.600`` behind
+    ``constant_dynamic-slice_fusion.26``-``.29`` and ``slice.99``-``.107``,
+    ``constant_dynamic-slice_fusion.30``): the slice then ran as a copy of
+    33.5 MB into on-chip memory, 46 us before a product of as long. It does
+    not fold for a leaf stored [D, H, Dh] with 32 heads (the sparse layers'
+    ``wq`` and ``wg``; ``wk`` / ``wv``, 2 MB): a tile of the stored matrix
+    holds 16 heads of ONE input row, the product wants a head's [D, Dh], and
+    its fusion relays the matrix out of on-chip memory
+    (``copy_bitcast_fusion`` inside the product), so the slice stays a copy
+    whatever the einsum (weights as the left operand, the gate on flat rows,
+    ``lax.dynamic_slice`` for the clamped index: all compiled to the same
+    copy; a leaf stored [H, D, Dh], as the compiler lays out Jamba's 20
+    heads by itself, folds). Written out, the sparse pair's slices have
+    fixed offsets and the compiler fetches them beside other work: 0.18 ms
+    less a unified step and 0.43 ms MORE a decode-shaped call on the chip,
+    so the pair stays a scan.
 
     The state pools ride the scans as carries, updated in place. The SSM pool
     is folded to [Lm * S, Nst, Di] on the way in and unfolded on the way out
@@ -1693,6 +1746,7 @@ def _hybrid_stack(cfg, params, attention_layer, x, flat_cache, state,
 
         lin_impl = lin_impl or lightning_attention_xla
         pools["lin"] = state["lin"].reshape((-1,) + state["lin"].shape[2:])
+        params = dict(params, **lightning_views(cfg, params))
 
     def present(*keys):
         return tuple(v for k in keys for v in (k, k + "_q", k + "_scale")
@@ -2169,19 +2223,26 @@ def forward_core(
                     positions, seq_slots, kv_lens, cu_q_lens, num_seqs, l, P,
                     ps, scale, attn_impl, query_attn_impl or attn_impl)
                 attn = attn[..., :Dh]
-            if cfg.attn_output_gate:
-                attn = gated(attn, h)
             # one product over the H * Dh lanes of a row, as the latent
             # layers' is (below): contracted over (h, k) as two axes, XLA
             # splits the sum by the number of rows, and on the chip a decode
             # row's projection through the 32-row and the 256-row program
             # parted by a bf16 step now and then (PR 42: greedy tokens served
-            # alone and beside a prefilling neighbour parted)
+            # alone and beside a prefilling neighbour parted). ``wo`` comes
+            # flat, [H * Dh, D] (``forward_core`` views the stack so before
+            # the loops): flattened here, between the layer's slice and the
+            # product, the slice ran as a copy of its own. The gate is
+            # applied to the flat rows too: multiplied a head ([N, H, Dh]),
+            # its product asked for the layer's ``wg`` relaid ({1,3,2,0}),
+            # a second copy of 33.5 MB behind the slice (PERF.md section 5,
+            # PR 56).
             with part("attn_out"):
-                flat = {k: v.reshape((-1,) + v.shape[2:])
-                        for k, v in lp.items() if k in ("wo", "wo_q")}
-                o = _weight_mm({**lp, **flat}, "wo", "nk,kd->nd",
-                               attn.reshape(N, -1))
+                rows = attn.reshape(N, -1)
+                if cfg.attn_output_gate:
+                    rows = (rows.astype(jnp.float32) * jax.nn.sigmoid(
+                        _mm("wg", "nd,dhk->nhk", h).reshape(N, -1).astype(
+                            jnp.float32))).astype(rows.dtype)
+                o = _weight_mm(lp, "wo", "nk,kd->nd", rows)
             x = _joined(cfg, x, o)
             h = layer_norm(x, lp["mlp_norm"], cfg.rms_eps)
             return (_joined(cfg, x, dense_ffn(h, lp, _mm)), flat_cache,
@@ -2252,6 +2313,14 @@ def forward_core(
         return (x, flat_cache, *planes), (cnt, drop)
 
     if state is not None:
+        if cfg.sparse_topk:
+            # the sparse layers' output projection reads its rows flat
+            # (``layer``'s sparse branch): [La, H * Dh, D] of the stored
+            # [La, H, Dh, D], a view of the whole stack (D stays minor)
+            params = dict(params, **{
+                k: params[k].reshape(params[k].shape[0], -1,
+                                     params[k].shape[-1])
+                for k in ("wo", "wo_q") if k in params})
         x, flat_cache, state, *counted = _hybrid_stack(
             cfg, params, layer, x, cache.reshape(Ptot * ps, HkC, Dhp), state,
             positions, seq_slots, cu_q_lens, state_slots, scan_impl, lin_impl,

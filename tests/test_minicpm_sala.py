@@ -9,6 +9,8 @@ blocks of 8, top-2, window 16, kernels of 4 every 2, pages of 2 tokens.
 
 from __future__ import annotations
 
+import functools
+import hashlib
 import json
 import os
 import sys
@@ -552,3 +554,203 @@ def test_the_pallas_recurrence_goes_where_the_pallas_attention_goes():
 def test_what_a_recurrent_state_cannot_be_combined_with_is_refused(name, kw):
     with pytest.raises(ValueError, match=name):
         _engine(**kw)
+
+
+# ------------------ (g) the projections read their layer's matrix in place
+
+# ISSUE 56: the lightning layers' four input leaves are read a head apart
+# from a view of the whole stack, the sparse layers' ``wo`` flat from a view
+# of its stack, and the sparse layers' gate multiplies the flat rows. The same
+# products over the same operands: at the tiny size, in the served precision
+# (bfloat16 leaves), what a unified step and a fused decode call give equals
+# what the parent gave, to the last bit.
+CHANGED_LEAVES = ("lin_wq", "lin_wk", "lin_wv", "lin_wg", "wo", "wg")
+SERVED = replace(CFG, dtype="bfloat16")
+# sha256 of the logits of the live rows and of every pool, by (redrawn leaf,
+# program). Taken on commit 10d8257 (the parent of PR 56) by running this
+# file there: ``python tests/test_minicpm_sala.py`` prints the table.
+PARENT_BITS = {
+    ("lin_wq", "unified"): "c24f98b2faf92bab6103fbaf",
+    ("lin_wq", "decode"): "f4bbd3fc7826326d0bf13327",
+    ("lin_wk", "unified"): "6e9f8df64e9a48c0db39ee85",
+    ("lin_wk", "decode"): "5643bf3c10689cdf390146f3",
+    ("lin_wv", "unified"): "3519450dae8c1e7ef54a63dc",
+    ("lin_wv", "decode"): "1095cc5d40f4aa73fe5e09e2",
+    ("lin_wg", "unified"): "a81ebfd652975a84bf3d0b1e",
+    ("lin_wg", "decode"): "23a18251689ebd29dd92a1d7",
+    ("wo", "unified"): "7c188a5cb5a762478b0f42be",
+    ("wo", "decode"): "2c915310111b919f102e719a",
+    ("wg", "unified"): "aed5523ee1b3a89b48ab3fd6",
+    ("wg", "decode"): "38bf0dbeda6c0124ca0bcf95",
+}
+PARENT_TOKENS = "58cadc95309b28ebc48e5634"
+
+
+def _sha(*arrays) -> str:
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.asarray(a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a)
+        h.update(a.tobytes())
+    return h.hexdigest()[:24]
+
+
+def _flat_step(params, pools, rows, fused=False):
+    """One call of ``forward_core`` as a step program packs it: ``rows`` is
+    [(batch row, tokens, first position)]; a unified step names each row's
+    state slot (row b seat b here), the fused decode call (``fused``) has one
+    token a seat and no slots. Returns (logits of the live tokens, pools)."""
+    n_tok = ROWS if fused else NT
+    toks = np.zeros((n_tok,), np.int32)
+    pos = np.full((n_tok,), -1, np.int32)
+    seq = np.full((n_tok,), rows[-1][0], np.int32)
+    pt = np.full((ROWS, MAXP), -1, np.int32)
+    pt[0, :24], pt[1] = np.arange(24), 24 + np.arange(MAXP)  # (pages of its own)
+    lens, cu, at = np.ones((ROWS,), np.int32), [0], 0
+    for b in range(ROWS):
+        for row, t, start in rows:
+            if row == b:
+                if fused:
+                    at = b
+                toks[at:at + len(t)] = t
+                pos[at:at + len(t)] = np.arange(start, start + len(t))
+                seq[at:at + len(t)] = b
+                lens[b] = start + len(t)
+                at += len(t)
+        cu.append(b + 1 if fused else at)
+    if fused:
+        seq = np.arange(ROWS, dtype=np.int32)
+    live = np.flatnonzero(pos >= 0)
+    hidden, pools, _, _ = _served_core(
+        params, pools, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(seq),
+        jnp.asarray(pt), jnp.asarray(lens),
+        cu_q_lens=jnp.asarray(cu, jnp.int32),
+        num_seqs=jnp.asarray([ROWS if fused else rows[-1][0] + 1], jnp.int32),
+        state_slots=None if fused else jnp.arange(ROWS, dtype=jnp.int32))
+    return np.asarray(unembed(SERVED, params, hidden))[live], pools
+
+
+# (the leaves are arguments: one compile a program for all six cases)
+_served_core = jax.jit(functools.partial(forward_core, SERVED))
+
+
+@functools.lru_cache(maxsize=None)
+def _bits_with(leaf: str) -> dict:
+    """{program: digest} of a served pair of sequences whose ``leaf`` was
+    drawn anew (so that each case hangs on its own leaf): row 0 decodes
+    beside row 1's two chunks (the second ends past ``dense_len``: the
+    selection runs), then both decode in a fused call."""
+    params = init_params(SERVED, jax.random.PRNGKey(0))
+    w = params[leaf]
+    params = dict(params, **{leaf: (
+        float(jnp.std(w.astype(jnp.float32))) * jax.random.normal(
+            jax.random.PRNGKey(7 + CHANGED_LEAVES.index(leaf)), w.shape,
+            jnp.float32)).astype(w.dtype)})
+    rng = np.random.default_rng(11)
+    a = [int(t) for t in rng.integers(0, 288, size=44)]
+    b = [int(t) for t in rng.integers(0, 288, size=151)]
+    pools = _pools(SERVED)
+    _, pools = _flat_step(params, pools, [(0, a[:40], 0)])
+    l1, pools = _flat_step(params, pools, [(0, a[40:41], 40), (1, b[:96], 0)])
+    l2, pools = _flat_step(params, pools, [(0, a[41:42], 41),
+                                           (1, b[96:150], 96)])
+    assert all(np.isfinite(x).all() and x.std() > 0.05 for x in (l1, l2))
+    unified = _sha(l1, l2, *(pools[k] for k in sorted(pools)))
+    l3, pools = _flat_step(params, pools, [(0, a[42:43], 42),
+                                           (1, b[150:151], 150)], fused=True)
+    return {"unified": unified,
+            "decode": _sha(l3, *(pools[k] for k in sorted(pools)))}
+
+
+@pytest.mark.parametrize("program", ["unified", "decode"])
+@pytest.mark.parametrize("leaf", CHANGED_LEAVES)
+def test_a_changed_leafs_new_form_gives_the_parents_bits(leaf, program):
+    assert _bits_with(leaf)[program] == PARENT_BITS[leaf, program]
+
+
+def _engine_bits() -> str:
+    eng = _engine()
+    served = _served(eng.generate(PROMPTS, GREEDY))
+    pools = eng._pools()
+    return _sha(np.asarray(served, np.int32),
+                *(pools[k] for k in sorted(pools)))
+
+
+def test_the_engines_programs_give_the_parents_tokens_and_pools():
+    """Both step programs as the engine compiles and chains them (sampling
+    inside): the greedy tokens of four prompts and every pool behind them."""
+    assert _engine_bits() == PARENT_TOKENS
+
+
+def _weight_paths(jaxpr, held=None, found=None) -> list:
+    """[(sliced shape, [what stands between the slice and the product])] for
+    every ``dot_general`` of a traced program that takes one layer of a
+    stack (a ``dynamic_slice`` of one index of the leading axis of an array
+    of rank 3 up and more than one layer), followed through calls, loops and
+    branches. What may
+    stand between: ``squeeze`` (the sliced axis dropped) and
+    ``convert_element_type`` (an int8 leaf); a ``reshape`` or ``transpose``
+    of the weight is written down as it is."""
+    held = dict(held or {})  # var -> (sliced shape, path)
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        ins = [v for v in eqn.invars if not hasattr(v, "val")]
+        if name == "dynamic_slice":
+            src, sizes = eqn.invars[0].aval, eqn.params["slice_sizes"]
+            if len(sizes) >= 3 and sizes[0] == 1 and src.shape[0] > 1 \
+                    and tuple(sizes[1:]) == src.shape[1:]:
+                held[eqn.outvars[0]] = (tuple(sizes), [])
+            continue
+        mine = [v for v in ins if v in held]
+        if name == "dot_general":
+            found.extend(held[v] for v in mine)
+            continue
+        subs = [j for j in jax.core.jaxprs_in_params(eqn.params)]
+        if subs:
+            for sub in subs:
+                inner = getattr(sub, "jaxpr", sub)
+                # a call's, a scan's and a loop body's operands are their
+                # jaxpr's invars in order; a branch's stand behind the index:
+                # matched from the end
+                _weight_paths(inner, {
+                    b: held[a] for a, b in zip(reversed(eqn.invars),
+                                               reversed(inner.invars))
+                    if not hasattr(a, "val") and a in held}, found)
+            continue
+        if mine and name in ("squeeze", "reshape", "transpose",
+                             "convert_element_type"):
+            shape, path = held[mine[0]]
+            step = [] if name == "convert_element_type" or (
+                name == "squeeze" and eqn.params["dimensions"] == (0,)) \
+                else [f"{name}{eqn.outvars[0].aval.shape}"]
+            held[eqn.outvars[0]] = (shape, path + step)
+    return found
+
+
+@pytest.mark.parametrize("variant,leaves", [
+    # (preset of tests/test_step_programs.py, products of a layer's slice
+    # at least: the mixer's and the feed-forward's)
+    ("tiny-sala", 12), ("tiny-jamba", 8), ("tiny-nemotron-h", 5),
+    ("tiny-ling", 8)])
+@pytest.mark.parametrize("program", ["unified", "decode"])
+def test_no_reshape_of_the_weight_between_its_slice_and_its_product(
+        variant, leaves, program):
+    """The traced step programs of every family that runs the hybrid stack
+    (what ``jit`` lowers): a layer's matrix goes from its slice of the stack
+    to its product as it is. The parent's sparse layers flattened ``wo``
+    there ([H, Dh, D] -> [H * Dh, D]), and the slice ran as a copy."""
+    from test_step_programs import program_call
+
+    fn, args, kw = program_call(variant, program)
+    paths = _weight_paths(fn.trace(*args, **kw).jaxpr.jaxpr)
+    assert len(paths) >= leaves
+    assert not {(shape, tuple(path)) for shape, path in paths if path}
+
+
+if __name__ == "__main__":  # the table of the tree this file runs on
+    print("PARENT_BITS = {")
+    for leaf in CHANGED_LEAVES:
+        for program, bits in _bits_with(leaf).items():
+            print(f'    ("{leaf}", "{program}"): "{bits}",')
+    print("}")
+    print(f'PARENT_TOKENS = "{_engine_bits()}"')

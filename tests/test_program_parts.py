@@ -41,7 +41,8 @@ from llmd_tpu.engine import EngineConfig, LLMEngine  # noqa: E402
 from llmd_tpu.engine.programs import MODEL_PARTS  # noqa: E402
 from llmd_tpu.models import get_model_config  # noqa: E402
 from llmd_tpu.obs.program_parts import (  # noqa: E402
-    AMBIGUOUS, UNSCOPED, ProgramParts, part_of_path, parts_of_text)
+    AMBIGUOUS, UNSCOPED, ProgramParts, part_of_path, parts_of_text,
+    weight_copies)
 
 SERIES = "llmd_tpu:program_part_ops"
 BASE = dict(page_size=8, num_pages=128, max_model_len=128, max_batch_size=4,
@@ -179,6 +180,127 @@ def test_two_signatures_that_disagree_are_ambiguous_and_no_part_is_stale():
     bare.add(TEXT.replace("PART", "x").replace("unembed/", ""))
     assert bare.stale == {"jit__unified"}
     assert {ls["stale"] for ls, _ in bare.series()} == {"1"}
+
+
+# ------------------------------------------- copies of a layer of a leaf
+
+# cut from the compiled unified program of minicpm-sala-9b as PR 55 traced it
+# (chiprun_out/pr54/hlo/minicpm-sala-9b.unified.0.hlo.txt; the cut keeps the
+# entry's parameters, the loops, the copies with their consumers and fused
+# computations, and three products whose own fusion holds the slice)
+CUT = os.path.join(ROOT, "tests", "data",
+                   "minicpm-sala-9b.unified.pr54.cut.hlo.txt")
+# ISSUE 56's table: instruction -> (the product that reads the copy, or the
+# copy behind it). The issue counts thirteen rows; its table names twelve
+# instructions, all here, and the text holds two more, the sparse layers'
+# ``wk`` and ``wv`` (2 MB each).
+TABLE = {
+    "constant_dynamic-slice_fusion.26": "mixer_in/nd,ed->ne/dot_general",
+    "constant_dynamic-slice_fusion.27": "mixer_in/nd,ed->ne/dot_general",
+    "constant_dynamic-slice_fusion.28": "mixer_in/nd,ed->ne/dot_general",
+    "constant_dynamic-slice_fusion.29": "mixer_out/nd,ed->ne/dot_general",
+    "slice.105": "mixer_in/nd,ed->ne/dot_general",
+    "slice.99": "mixer_in/nd,ed->ne/dot_general",
+    "slice.103": "mixer_in/nd,ed->ne/dot_general",
+    "slice.107": "mixer_out/nd,ed->ne/dot_general",
+    "constant_dynamic-slice_fusion.34": "attn_qkv/nd,dhk->nhk/dot_general",
+    "constant_dynamic-slice_fusion.31": "copy.499",
+    "copy.499": "attn_out/nd,dhk->nhk/dot_general",
+    "constant_dynamic-slice_fusion.30": "attn_out/nk,kd->nd/dot_general",
+    "constant_dynamic-slice_fusion.32": "attn_qkv/nd,dhk->nhk/dot_general",
+    "constant_dynamic-slice_fusion.33": "attn_qkv/nd,dhk->nhk/dot_general",
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _cut_rows() -> dict:
+    with open(CUT) as f:
+        return {r["instruction"]: r for r in weight_copies(f.read())}
+
+
+@pytest.mark.parametrize("instruction", sorted(TABLE))
+def test_a_copy_of_a_layers_matrix_is_found_with_its_consumer(instruction):
+    row = _cut_rows()[instruction]
+    small = instruction[-3:] in (".32", ".33")
+    assert row["bytes"] == (2 if small else 32) * 2**20 and not row["async"]
+    assert ("wk" if small else "lin_wq") in row["leaves"]
+    (consumer,) = row["consumers"]
+    assert TABLE[instruction] in (consumer["instruction"],
+                                  "/".join(consumer["op_name"].split("/")[-3:]))
+
+
+def test_a_slice_inside_its_products_fusion_is_no_row():
+    with open(CUT) as f:
+        text = f.read()
+    assert set(_cut_rows()) == set(TABLE)
+    # the cut holds products that read the stack in place: the lightning
+    # layers' ``lin_wo`` and the MLP's two, a dynamic-slice inside each
+    folded = [ln for ln in text.splitlines() if " dynamic-slice(" in ln
+              and not any(f"%{name} = " in ln for name in TABLE)]
+    assert len([ln for ln in folded if "bf16[1,4096,4096]" in ln]) >= 1
+    assert len([ln for ln in folded if "bf16[1,16384,4096]" in ln]) >= 1
+
+
+LEAF_TEXT = """HloModule jit__unified, is_scheduled=true
+
+%fused_slice.1 (p: bf16[4,8,8], i: s32[]) -> bf16[1,8,8] {
+  %p = bf16[4,8,8]{2,1,0} parameter(0)
+  %i = s32[] parameter(1)
+  %z = s32[] constant(0)
+  ROOT %ds.1 = bf16[1,8,8]{2,1,0} dynamic-slice(%p, %i, %z, %z), dynamic_slice_sizes={1,8,8}
+}
+
+%fused_product.2 (p: bf16[4,8,8], i: s32[], x: bf16[2,8]) -> bf16[2,8] {
+  %p.1 = bf16[4,8,8]{2,1,0} parameter(0)
+  %i.1 = s32[] parameter(1)
+  %x.1 = bf16[2,8]{1,0} parameter(2)
+  %z.1 = s32[] constant(0)
+  %ds.2 = bf16[1,8,8]{2,1,0} dynamic-slice(%p.1, %i.1, %z.1, %z.1), dynamic_slice_sizes={1,8,8}
+  %b.2 = bf16[8,8]{1,0} bitcast(%ds.2)
+  ROOT %conv.2 = bf16[2,8]{1,0} convolution(%x.1, %b.2), dim_labels=bf_io->bf
+}
+
+%fused_scale.3 (a: bf16[2,2,2,8]) -> bf16[2,2,2,8] {
+  %a = bf16[2,2,2,8]{3,2,1,0} parameter(0)
+  ROOT %m.3 = bf16[2,2,2,8]{3,2,1,0} multiply(%a, %a)
+}
+
+ENTRY %main.9 (w: bf16[4,8,8], v: bf16[4,8], i: s32[], x: bf16[2,8], pool: bf16[16,4]) -> bf16[2,8] {
+  %w = bf16[4,8,8]{2,1,0} parameter(0), metadata={op_name="params[\'lin_wq\']"}
+  %v = bf16[4,8]{1,0} parameter(1), metadata={op_name="params[\'attn_norm\']"}
+  %i.9 = s32[] parameter(2), metadata={op_name="i"}
+  %x = bf16[2,8]{1,0} parameter(3), metadata={op_name="x"}
+  %pool = bf16[16,4]{1,0} parameter(4), metadata={op_name="cache[\'kv\']"}
+  %fusion.1 = bf16[1,8,8]{2,1,0:S(1)} fusion(%w, %i.9), kind=kLoop, calls=%fused_slice.1, metadata={op_name="jit(_unified)/dynamic_slice"}
+  %bitcast.1 = bf16[2,4,8]{2,1,0} bitcast(%fusion.1)
+  %copy.1 = bf16[2,4,8]{0,2,1} copy(%bitcast.1)
+  %fusion.2 = bf16[2,8]{1,0} fusion(%w, %i.9, %x), kind=kOutput, calls=%fused_product.2, metadata={op_name="jit(_unified)/mixer_out/ne,ed->nd/dot_general"}
+  %scaled.3 = bf16[2,2,2,8]{3,2,1,0} fusion(%copy.1), kind=kLoop, calls=%fused_scale.3, metadata={op_name="jit(_unified)/mixer_in/mul"}
+  %copy.2 = bf16[16,4]{0,1} copy(%pool)
+  %slice.4 = bf16[1,8]{1,0} slice(%v), slice={[1:2], [0:8]}
+  %start.5 = (bf16[4,8,8], bf16[1,8,8]) slice-start(%w), slice={[2:3], [0:8], [0:8]}
+  %done.5 = bf16[1,8,8]{2,1,0:S(1)} slice-done(%start.5)
+  ROOT %add.6 = bf16[2,8]{1,0} add(%fusion.2, %fusion.2)
+}
+"""
+
+
+def test_what_counts_as_a_copy_of_a_layer_and_what_does_not():
+    rows = {r["instruction"]: r for r in weight_copies(LEAF_TEXT)}
+    # the slice as a fusion of its own, the relayout of its view behind it
+    # (consumers are read through bitcasts), and a slice the compiler runs
+    # beside other work; not the product that holds its slice, not
+    # arithmetic on a value of a layer's size, not a vector's slice, not a
+    # pool of as many elements
+    assert set(rows) == {"fusion.1", "copy.1", "done.5"}
+    assert [c["instruction"] for c in rows["fusion.1"]["consumers"]] == [
+        "copy.1"]
+    assert rows["copy.1"]["consumers"] == [{
+        "instruction": "scaled.3", "opcode": "fusion",
+        "op_name": "jit(_unified)/mixer_in/mul"}]
+    assert rows["done.5"]["async"] and not rows["fusion.1"]["async"]
+    assert all(r["leaves"] == ["lin_wq"] and r["bytes"] == 128
+               for r in rows.values())
 
 
 # ------------------------------------------------------------- the reader

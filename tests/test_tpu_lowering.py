@@ -631,9 +631,10 @@ def test_sparse_hybrid_step_programs_compile_for_v5e_at_the_cells_sizes(
     through the TPU compiler as each step program packs it, with the Pallas
     attention and lightning kernels: the selected page tables' calls (256
     one-query rows of 388 pages in scalar memory), the KV heads folded into
-    the pool, and no stacked leaf copied whole at the call's entry (kept [D,
+    the pool, no stacked leaf copied whole at the call's entry (kept [D,
     heads, lanes], the lightning q, k, v and gate matrices were transposed
-    there: 1.2 GB of temporaries a call)."""
+    there: 1.2 GB of temporaries a call), and no layer's matrix copied out
+    of its stack before its product but the four that stay (ISSUE 56)."""
     import functools
     import json
     import sys
@@ -688,6 +689,18 @@ def test_sparse_hybrid_step_programs_compile_for_v5e_at_the_cells_sizes(
     assert "lightning_attention" in text
     assert "ragged_paged_attention_kernel" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 1024 * 1024
+    # no layer's matrix is copied out of its stack before its product but
+    # the sparse pair's [D, heads, lanes] leaves (``_hybrid_stack``'s
+    # docstring: wq and wg, 33.5 MB, wk and wv, 2 MB); the parent copied the
+    # lightning layers' four input matrices and the sparse ``wo`` too, and
+    # relaid ``wg`` behind its slice (14 rows in the unified program)
+    from llmd_tpu.obs.program_parts import weight_copies
+
+    rows = weight_copies(text)
+    assert len(rows) <= 4, [r["instruction"] for r in rows]
+    assert all(r["result"].startswith(("bf16[1,4096,32,128]{3,2,1,0",
+                                       "bf16[1,4096,2,128]{3,2,1,0"))
+               for r in rows), [r["result"] for r in rows]
 
 
 @pytest.mark.parametrize("n", [64, 320], ids=["decode", "unified"])

@@ -9,6 +9,7 @@ and wants to know what they compute.
         --config perfbench/configs/jamba2-3b.json --write-text chiprun_out/hlo
     python3 tools/program_parts.py --cpu --model tiny-jamba        # here
     python3 tools/program_parts.py --text chiprun_out/hlo/jamba2-3b.jit__unified.0.hlo.txt --ops
+    python3 tools/program_parts.py --text chiprun_out/pr56/hlo/*.hlo.txt --weight-copies
 
 ``--config`` builds the engine the benchmark's engine child builds from that
 file (on the chip: the real widths, weights drawn on the device), ``--model``
@@ -22,6 +23,16 @@ without an engine. Instruction names are the compiler's, so a map belongs to
 one build of one configuration: read the map of the build that was traced
 (its ``/metrics``), and use this tool to look inside a program
 (``observability/device-plane.md``, "Device time by part of the model").
+
+``--weight-copies`` (with ``--text``, or behind ``--config`` / ``--model``)
+prints in the maps' place one JSON line for every operation that hands on one
+layer of a stacked weight leaf and computes nothing (``weight_copies`` of
+``llmd_tpu/obs/program_parts.py``: a ``dynamic-slice``, ``slice`` or ``copy``
+outside any product's fusion, with the products that consume it), then one
+line a program with their count and bytes. A product that reads its layer's
+matrix where it lies in the stack leaves no row; a row is a matrix written
+out at the memory's rate before the product starts (PR 56: fourteen such
+copies were 13% of ``minicpmsala-longdoc``'s device time).
 """
 
 from __future__ import annotations
@@ -74,15 +85,21 @@ def main() -> int:
     ap.add_argument("--ops", action="store_true",
                     help="every instruction's name, by part")
     ap.add_argument("--write-text", metavar="DIR")
+    ap.add_argument("--weight-copies", action="store_true",
+                    help="the copies of one layer of a weight leaf, in the "
+                         "maps' place")
     args = ap.parse_args()
 
-    from llmd_tpu.obs.program_parts import ProgramParts
+    from llmd_tpu.obs.program_parts import ProgramParts, weight_copies
 
+    texts: list = []  # (where it is from, compiled text)
     if args.text:
         parts = ProgramParts()
         for path in args.text:
             with open(path) as f:
-                parts.add(f.read())
+                text = f.read()
+            texts.append((path, text))
+            parts.add(text)
     else:
         from llmd_tpu.core.request import SamplingParams
 
@@ -92,7 +109,6 @@ def main() -> int:
                    for j, n in enumerate((chunk // 2 + 8, 24, 9))]
         eng.generate(prompts, SamplingParams(
             max_tokens=2 * eng.cfg.decode_steps + 2, temperature=0.0))
-        texts: list = []
         eng.read_compiled_programs(texts)
         parts = eng.programs.parts
         if args.write_text:
@@ -104,6 +120,17 @@ def main() -> int:
                                        f"{name}.{module}.{n}.hlo.txt"),
                           "w") as f:
                     f.write(text)
+    if args.weight_copies:
+        for source, text in texts:
+            rows = weight_copies(text)
+            for row in rows:
+                print(json.dumps({"program": source, **row}), flush=True)
+            print(json.dumps({
+                "program": source, "weight_copies": len(rows),
+                "bytes": sum(r["bytes"] for r in rows),
+                "bytes_not_async": sum(r["bytes"] for r in rows
+                                       if not r["async"])}), flush=True)
+        return 0
     by: dict = {}
     for labels, count in parts.series():
         line = by.setdefault(labels["program"], {
